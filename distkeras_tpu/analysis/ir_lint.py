@@ -44,6 +44,7 @@ from typing import Any, Callable, Sequence
 
 import jax
 import numpy as np
+from jax.extend.core import Literal
 
 from distkeras_tpu.analysis.findings import Finding
 
@@ -402,7 +403,7 @@ def _audit_jaxpr(closed, spec: TraceSpec) -> list[Finding]:
         uses: dict = {}
         for eqn in jaxpr.eqns:
             for v in eqn.invars:
-                if not isinstance(v, jax.core.Literal):
+                if not isinstance(v, Literal):
                     uses.setdefault(v, []).append(eqn.primitive.name)
         for eqn in jaxpr.eqns:
             prim = eqn.primitive.name
@@ -432,7 +433,7 @@ def _audit_jaxpr(closed, spec: TraceSpec) -> list[Finding]:
                         "where precision is required and keep the rest "
                         "low-precision")
             if prim.endswith("callback") or prim in (
-                    "outside_call", "host_callback_call"):
+                    "outside_call", "host_callback_call", "debug_print"):
                 add("host-callback", "warn",
                     f"host callback `{prim}` inside the jit region",
                     "each call is a device->host round-trip per "

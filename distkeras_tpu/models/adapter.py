@@ -381,10 +381,11 @@ class ModelAdapter:
         variables do not update cross-batch (BatchNorm is rejected by
         the trainers): a replica-local ntv update would diverge.
         """
-        from distkeras_tpu.parallel.compat import shard_map as smap
+        from jax import shard_map as smap
+        from jax.sharding import PartitionSpec as P
+
         from distkeras_tpu.parallel.exchange import (merge_local_params,
                                                      sync_local_tree)
-        from jax.sharding import PartitionSpec as P
 
         compute_loss = self.make_loss_fn()
         optimizer = self.optimizer

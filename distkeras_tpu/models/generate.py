@@ -388,11 +388,10 @@ def _layer_slab_update(cache_all, i, rows, pos):
 
     The decode loop is bandwidth-bound and the cache is its largest
     buffer; the old per-layer ``cache[i]`` + ``jnp.stack`` pattern made
-    XLA materialize a full cache copy every step (measured ~6.5 ms per
-    tensor per step at batch 64 on v5e — the dominant term of the
-    serving b64 cliff in docs/perf_serving.md), where this slab
-    dynamic_update_slice stays in place (~0.1 ms; serving table went
-    3.2k -> 17.9k tok/s at b64, 83% of the HBM roofline).
+    XLA materialize a full cache copy every step (~6.5 ms per tensor
+    per step at batch 64, the dominant term of the b64 decode cliff),
+    where this slab dynamic_update_slice stays in place (~0.1 ms;
+    measured 2026-07-31 on one v5e, not re-measured since).
 
     Uniform-position writes only.  Per-row offsets (speculative
     decoding) keep the per-layer ``_rows_update`` + one final stack:
@@ -463,7 +462,8 @@ def _decode_chunk(params, cache, tokens, pos0, cfg: TransformerConfig,
     quadratic in beam width (b64 W8 S2048 H16 would be ~1 GB/layer;
     at that scale revisit before trusting this path).  This replaced
     the physical parent-gather of the cache, which cost more than the
-    whole attention read (docs/perf_serving.md finding 4).
+    whole attention read (measured 2026-07-31 on one v5e, not
+    re-measured since).
     """
     dtype = jnp.dtype(cfg.dtype)
     b, t_len = tokens.shape
@@ -642,9 +642,9 @@ def top_k_mask(logits, k: int, exact: bool = False):
     By default the k-th value comes from ``lax.approx_max_k`` (recall
     0.99): on TPU the exact ``lax.top_k`` over a [B, 32k] vocab costs
     more than the whole rest of a decode step (~7.8 ms vs 0.7 ms at
-    batch 64 on v5e — measured, docs/perf_serving.md finding 6), while
-    the approximate threshold misidentifies only logits in a ~1% band
-    around the k-th value — sampling-support noise far below the
+    batch 64; measured 2026-07-31 on one v5e, not re-measured since),
+    while the approximate threshold misidentifies only logits in a ~1%
+    band around the k-th value — sampling-support noise far below the
     sampling noise itself.  Pass ``exact=True`` (or
     ``generate(..., exact_top_k=True)``) to restore the exact
     semantics of releases before round 3.
@@ -1237,11 +1237,12 @@ def beam_search(params, prompt, cfg: TransformerConfig,
     # that wrote position s of beam w's hypothesis (see _decode_chunk's
     # beam_anc).  The physical parent-gather it replaces rewrote the
     # whole [L, B*W, S, kv, hd] cache every step and cost more than the
-    # attention itself (docs/perf_serving.md finding 4).  Windowed
-    # configs use it too, rolling decodes included: the ancestor map
-    # is SLOT-indexed — identical to positions until the ring wraps,
-    # and the scan body retires a reused slot's entry in the same step
-    # that overwrites its K/V (_ancestry_attend under the band mask).
+    # attention itself (measured 2026-07-31 on one v5e, not re-measured
+    # since).  Windowed configs use it too, rolling decodes included:
+    # the ancestor map is SLOT-indexed — identical to positions until
+    # the ring wraps, and the scan body retires a reused slot's entry
+    # in the same step that overwrites its K/V (_ancestry_attend under
+    # the band mask).
     # (use_anc resolved with the other argument checks at the top —
     # beam_impl errors must fire before any prompt-pass device work.)
     anc0 = jnp.broadcast_to(

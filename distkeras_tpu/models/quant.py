@@ -165,9 +165,10 @@ def quantize_kv(x):
     ``scale [..., kv_heads]`` = absmax / 127 over head_dim.
 
     The int8 KV cache halves the cache-byte term that dominates batched
-    decode once the loop is at the HBM roofline (docs/perf_serving.md
-    finding 1 — only byte reduction goes faster).  Scales stay f32:
-    they are head_dim x smaller than the data.
+    decode once the loop is at the HBM roofline, where only byte
+    reduction goes faster (measured 2026-07-31 on one v5e, not
+    re-measured since).  Scales stay f32: they are head_dim x smaller
+    than the data.
     """
     amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1)
     scale = jnp.maximum(amax, 1e-12) / 127.0

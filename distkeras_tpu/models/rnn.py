@@ -5,7 +5,7 @@ The reference's deepest sequence model is a Keras LSTM trained with
 distkeras/workers.py) — the kernels were whatever the 2017 Keras
 backend emitted.  On TPU the generic per-timestep LSTM is the worst
 case: two small matmuls per step inside a length-T sequential loop,
-~0.1% MFU measured (BASELINE.md, IMDB-LSTM line).
+~0.1% MFU (measured 2026-07-31 on one v5e, not re-measured since).
 
 :class:`FusedLSTM` is a drop-in, weight-compatible replacement for
 ``keras.layers.LSTM`` restructured for the MXU:

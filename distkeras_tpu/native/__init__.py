@@ -6,11 +6,18 @@ Builds ``native/dataloader.cc`` into a shared library on first use
 compiler is available — the native path is an optimization of the data
 plane, never a requirement (the reference's data plane performance
 likewise came from its substrate, Spark; SURVEY.md §2 native census).
+
+The cached library is named by a hash of its source file's bytes, so a
+binary is only ever loaded if it was built from the ``.cc`` that sits
+in the checkout now: a stale or foreign ``.so`` in this directory
+(``*.so`` is ignored by git, and a copy of the tree carries whatever
+lies on disk) has another name and is never opened.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 
@@ -22,38 +29,55 @@ _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))), "native")
 _PKG_DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_NATIVE_DIR, "dataloader.cc")
-_SO = os.path.join(_PKG_DIR, "_libdkt_data.so")
+_BPE_SRC = os.path.join(_NATIVE_DIR, "tokenizer.cc")
 
 # Build-cache lock (leaf): held across the one-time g++ build — a
 # long first acquire by design, never on a serving/training hot path.
 _lock = TracedLock("native.build")
 _lib = None
 _tried = False
+_bpe_lib = None
+_bpe_tried = False
 
 _DEF_THREADS = min(8, os.cpu_count() or 1)
 
 
+def _artifact(src: str, stem: str) -> str | None:
+    """Where the library built from exactly these source bytes lives;
+    None when the source is missing."""
+    try:
+        with open(src, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    except OSError:
+        return None
+    return os.path.join(_PKG_DIR, f"{stem}.{digest}.so")
+
+
 def _compile(src: str, so: str) -> str | None:
     """g++ one source file into a shared library; None on any failure
-    (no compiler, bad toolchain) — callers fall back to numpy/python."""
-    if not os.path.exists(src):
-        return None
-    cmd = ["g++", "-O3", "-shared", "-fPIC", "-pthread", src, "-o", so]
+    (no compiler, bad toolchain) — callers fall back to numpy/python.
+    Built under a private name and renamed into place, so no process
+    ever opens a half-written library."""
+    tmp = f"{so}.{os.getpid()}.tmp"
+    cmd = ["g++", "-O3", "-shared", "-fPIC", "-pthread", src, "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp, so)
     except (OSError, subprocess.SubprocessError):
         return None
     return so
 
 
-def _build() -> str | None:
-    return _compile(_SRC, _SO)
-
-
-_BPE_SRC = os.path.join(_NATIVE_DIR, "tokenizer.cc")
-_BPE_SO = os.path.join(_PKG_DIR, "_libdkt_bpe.so")
-_bpe_lib = None
-_bpe_tried = False
+def _load(src: str, stem: str):
+    """The library for ``src`` — the cached build of these exact bytes,
+    else a fresh one — or None (no source, no compiler, load error)."""
+    so = _artifact(src, stem)
+    if so is None or not (os.path.exists(so) or _compile(src, so)):
+        return None
+    try:
+        return ctypes.CDLL(so)
+    except OSError:
+        return None
 
 
 def bpe_lib():
@@ -63,13 +87,8 @@ def bpe_lib():
         if _bpe_lib is not None or _bpe_tried:
             return _bpe_lib
         _bpe_tried = True
-        path = (_BPE_SO if os.path.exists(_BPE_SO)
-                else _compile(_BPE_SRC, _BPE_SO))
-        if not path:
-            return None
-        try:
-            handle = ctypes.CDLL(path)
-        except OSError:
+        handle = _load(_BPE_SRC, "_libdkt_bpe")
+        if handle is None:
             return None
         handle.dkt_bpe_train.restype = ctypes.c_int32
         handle.dkt_bpe_train.argtypes = [
@@ -93,12 +112,8 @@ def lib():
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        path = _SO if os.path.exists(_SO) else _build()
-        if not path:
-            return None
-        try:
-            handle = ctypes.CDLL(path)
-        except OSError:
+        handle = _load(_SRC, "_libdkt_data")
+        if handle is None:
             return None
         handle.dkt_gather_f32.argtypes = [
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,

@@ -13,12 +13,15 @@ one set of semantics so tests can pin them against each other:
   chunk-update core (:func:`attention_chunk`) is also the per-hop step
   of ring attention (distkeras_tpu.parallel.ring).
 - :func:`flash_attention` — Pallas TPU kernel (MXU-tiled, VMEM-resident
-  online softmax) on TPU backends; falls back to blockwise elsewhere.
+  online softmax) on TPU backends at kernel-legal shapes; blockwise
+  elsewhere (a dispatch on backend and shape, decided before the
+  kernel is built — a kernel that fails to lower or compile raises).
   On the Pallas path the backward is the FA2 construction (dQ and
   dK/dV kernels rebuilding probabilities per tile from the forward's
-  saved log-sum-exp); the fallback backward recomputes through the
+  saved log-sum-exp); the blockwise backward recomputes through the
   blockwise implementation under ``jax.vjp``.  O(L) residuals either
-  way.
+  way.  Inside a multi-device ``jit`` the kernels run per batch/head
+  shard (:func:`_per_shard`).
 
 All take ``q: [B, Lq, H, D]``, ``k/v: [B, Lkv, H, D]`` and return
 ``[B, Lq, H, D]``.  ``q_offset``/``kv_offset`` give the global positions
@@ -33,6 +36,9 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 # Finite stand-in for -inf: keeps exp()/max() NaN-free when a whole row
 # or chunk is masked (e.g. ring hops entirely in the causal future).
@@ -213,8 +219,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, *refs, causal: bool, scale: float,
     without O(L^2) memory; inference omits the output (and its HBM
     writes) entirely.
 
-    ``segmented``: two extra int32 inputs (q/k segment-id tiles) gate
-    the logits to within-segment pairs — packed-document masking.
+    ``segmented``: two extra int32 inputs (q/k segment-id tiles, laid
+    out by :func:`_segment_operands`) gate the logits to within-segment
+    pairs — packed-document masking.
     """
     if segmented:
         qseg_ref, kseg_ref, *refs = refs
@@ -257,9 +264,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, *refs, causal: bool, scale: float,
             logits = jnp.where(_keep_mask(logits.shape, row0, col0, window),
                                logits, NEG_INF)
         if segmented:
-            logits = jnp.where(
-                qseg_ref[0][:, None] == kseg_ref[0][None, :],
-                logits, NEG_INF)
+            logits = jnp.where(_same_segment(qseg_ref, kseg_ref),
+                               logits, NEG_INF)
         m = m_scr[:, :1]
         l = l_scr[:, :1]
         m_new = jnp.maximum(m, logits.max(axis=-1, keepdims=True))
@@ -286,13 +292,91 @@ def _flash_kernel(q_ref, k_ref, v_ref, *refs, causal: bool, scale: float,
                 lse_ref.shape[1:])
 
 
-try:  # Pallas import is cheap but keep non-TPU environments working.
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+# The TPU (sublane, lane) tile: the last two dims of every block must
+# divide by it (or span the whole array), which a (1, block) tile of a
+# rank-2 [rows, S] array does not — the Mosaic lowering refuses it.
+_SUBLANES, _LANES = 8, 128
 
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAVE_PALLAS = False
+
+def _segment_operands(segment_ids, h: int, q_at, k_at):
+    """Segment ids as kernel operands: ``(in_specs, args)``.
+
+    The q side is lane-broadcast ``[B, S, 128]`` (the kernel reads a
+    ``[block_q, 1]`` column), the k side sublane-broadcast ``[B, 8, S]``
+    (read as a ``[1, block_k]`` row), so both blocks respect the TPU
+    tile and the comparison broadcasts with no in-kernel transpose.
+    Heads share their batch row: the index maps divide the flattened
+    batch*head grid index by ``h`` instead of repeating the ids per
+    head.  ``q_at``/``k_at`` are the ``(block, index_map)`` pairs of the
+    rank-3 q/k tiles; the segment tiles ride the SAME sequence block
+    index, so banded walks stay in lockstep.
+    """
+    seg = segment_ids.astype(jnp.int32)
+    b, s = seg.shape
+    (_, block_q, _), q_map = q_at
+    (_, block_k, _), k_map = k_at
+    specs = [
+        pl.BlockSpec((1, block_q, _LANES),
+                     lambda bh, i, j: (bh // h, q_map(bh, i, j)[1], 0),
+                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, _SUBLANES, block_k),
+                     lambda bh, i, j: (bh // h, 0, k_map(bh, i, j)[1]),
+                     memory_space=pltpu.VMEM),
+    ]
+    return specs, [jnp.broadcast_to(seg[:, :, None], (b, s, _LANES)),
+                   jnp.broadcast_to(seg[:, None, :], (b, _SUBLANES, s))]
+
+
+def _same_segment(qseg_ref, kseg_ref):
+    """[block_q, block_k] within-segment mask from the tiles of
+    :func:`_segment_operands` — the ONE definition all three kernels
+    share."""
+    return qseg_ref[0][:, :1] == kseg_ref[0][:1, :]
+
+
+# The mesh axes (parallel/mesh.py::AXES) that carry the batch and the
+# heads of an attention operand: data parallelism and FSDP shard the
+# batch over ``data``, Megatron TP (transformer.tp_rules,
+# serving_plan) shards the heads over ``model``.
+_SHARD_AXIS = {"b": "data", "h": "model"}
+
+
+def _per_shard(fn, in_dims, out_dims, *arrays):
+    """``fn(*arrays)`` with every device running it on its own
+    batch/head shard.
+
+    A Mosaic kernel cannot be partitioned automatically: inside a
+    multi-device ``jit`` it has to sit in a ``shard_map``, and a
+    ``shard_map`` needs a mesh ``flash_attention`` is never given.  It
+    reads the mesh where JAX already records it: on the operands'
+    types (a ``jit`` argument committed to a ``NamedSharding`` hands
+    its abstract mesh to everything computed from it), else the
+    ambient ``jax.set_mesh``.  Attention is independent across batch
+    and heads, so nothing is gathered: the batch splits over ``data``
+    and the heads over ``model`` wherever the axis divides the
+    dimension, and every other axis sees whole operands —
+    sequence-sharded attention is ring attention's job
+    (parallel/ring.py).  On one device, and where the caller's own
+    ``shard_map`` already made every axis manual, this is ``fn``.
+
+    ``in_dims``/``out_dims`` name each array's dimensions, one letter
+    each; ``fn`` returns a tuple.
+    """
+    mesh = jax.typeof(arrays[0]).sharding.mesh
+    if mesh.empty:
+        mesh = jax.sharding.get_abstract_mesh()
+    auto = [a for a in mesh.axis_names if a not in mesh.manual_axes]
+    if math.prod(mesh.shape[a] for a in auto) == 1:
+        return fn(*arrays)
+    size = dict(zip(in_dims[0], arrays[0].shape))
+    axis = {x: a for x, a in _SHARD_AXIS.items()
+            if a in auto and size[x] % mesh.shape[a] == 0}
+    spec = lambda dims: P(*(axis.get(x) for x in dims))
+    return jax.shard_map(
+        fn, mesh=mesh,
+        in_specs=tuple(spec(d) for d in in_dims),
+        out_specs=tuple(spec(d) for d in out_dims),
+        axis_names=frozenset(auto), check_vma=False)(*arrays)
 
 
 def _banded_cols(row0, j, n_inner: int, block_q: int, block_k: int,
@@ -353,24 +437,47 @@ def _banded_q(window: int, block_q: int, block_k: int, n_qb: int):
 
 def _flash_pallas(q, k, v, causal, scale, block_q, block_k, interpret=False,
                   with_lse=True, window=None, segment_ids=None):
-    """Returns (out, lse) with ``with_lse`` (training), else (out, None) —
-    inference skips the lse buffer's HBM writes entirely."""
+    """Returns (out, lse ``[B, H, Lq]``) with ``with_lse`` (training),
+    else (out, None) — inference skips the lse buffer's HBM writes
+    entirely."""
     b, lq, h, d = q.shape
     lk = k.shape[1]
     block_q, block_k = _require_fit(block_q, lq), _require_fit(block_k, lk)
+    local = functools.partial(
+        _flash_fwd_local, causal=causal, scale=scale, block_q=block_q,
+        block_k=block_k, interpret=interpret, with_lse=with_lse,
+        window=window)
+    seg = [] if segment_ids is None else [segment_ids]
+    res = _per_shard(
+        local,
+        ["bqhd", "bkhd", "bkhd"] + ["bq" for _ in seg],
+        ["bqhd", "bhq"] if with_lse else ["bqhd"],
+        q, k, v, *seg)
+    return res if with_lse else (res[0], None)
+
+
+def _flash_fwd_local(q, k, v, segment_ids=None, *, causal, scale, block_q,
+                     block_k, interpret, with_lse, window):
+    """The forward launch on ONE device's operands (the whole arrays, or
+    its batch/head shard under :func:`_per_shard`): ``(out,)`` or
+    ``(out, lse)``."""
+    b, lq, h, d = q.shape
+    lk = k.shape[1]
+    segmented = segment_ids is not None
     qf = q.transpose(0, 2, 1, 3).reshape(b * h, lq, d)
     kf = k.transpose(0, 2, 1, 3).reshape(b * h, lk, d)
     vf = v.transpose(0, 2, 1, 3).reshape(b * h, lk, d)
     kernel = functools.partial(_flash_kernel, causal=causal, scale=scale,
                                with_lse=with_lse, window=window,
-                               segmented=segment_ids is not None)
+                               segmented=segmented)
 
-    o_spec = pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0),
-                          memory_space=pltpu.VMEM)
+    q_at = ((1, block_q, d), lambda bh, i, j: (bh, i, 0))
+    o_spec = pl.BlockSpec(*q_at, memory_space=pltpu.VMEM)
     o_shape = jax.ShapeDtypeStruct((b * h, lq, d), q.dtype)
-    lse_spec = pl.BlockSpec((1, block_q, 128), lambda bh, i, j: (bh, i, 0),
+    lse_spec = pl.BlockSpec((1, block_q, _LANES),
+                            lambda bh, i, j: (bh, i, 0),
                             memory_space=pltpu.VMEM)
-    lse_shape = jax.ShapeDtypeStruct((b * h, lq, 128), jnp.float32)
+    lse_shape = jax.ShapeDtypeStruct((b * h, lq, _LANES), jnp.float32)
     out_bytes = o_shape.size * q.dtype.itemsize + (
         lse_shape.size * 4 if with_lse else 0)
 
@@ -379,30 +486,18 @@ def _flash_pallas(q, k, v, causal, scale, block_q, block_k, interpret=False,
         inner, kv_map = _banded_kv(window, block_q, block_k, n_kb)
     else:
         inner, kv_map = n_kb, (lambda bh, i, j: (bh, j, 0))
+    kv_at = ((1, block_k, d), kv_map)
 
     in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, d), kv_map,
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, d), kv_map,
-                     memory_space=pltpu.VMEM),
+        pl.BlockSpec(*q_at, memory_space=pltpu.VMEM),
+        pl.BlockSpec(*kv_at, memory_space=pltpu.VMEM),
+        pl.BlockSpec(*kv_at, memory_space=pltpu.VMEM),
     ]
     args = [qf, kf, vf]
-    if segment_ids is not None:
-        # [B, S] -> [B*H, S] (b-major repeat matches the qf flattening);
-        # the kv-side map reuses kv_map's block index, so the banded
-        # walk stays in lockstep with the K/V tiles.
-        segf = jnp.repeat(segment_ids.astype(jnp.int32), h, axis=0)
-        in_specs += [
-            pl.BlockSpec((1, block_q),
-                         lambda bh, i, j: (bh, i),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k),
-                         lambda bh, i, j: kv_map(bh, i, j)[:2],
-                         memory_space=pltpu.VMEM),
-        ]
-        args += [segf, segf]
+    if segmented:
+        seg_specs, seg_args = _segment_operands(segment_ids, h, q_at, kv_at)
+        in_specs += seg_specs
+        args += seg_args
 
     def call(): return pl.pallas_call(
         kernel,
@@ -411,9 +506,9 @@ def _flash_pallas(q, k, v, causal, scale, block_q, block_k, interpret=False,
         out_specs=(o_spec, lse_spec) if with_lse else o_spec,
         out_shape=(o_shape, lse_shape) if with_lse else o_shape,
         scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),  # m (lane-broadcast)
-            pltpu.VMEM((block_q, 128), jnp.float32),  # l
-            pltpu.VMEM((block_q, d), jnp.float32),    # acc
+            pltpu.VMEM((block_q, _LANES), jnp.float32),  # m (lane-broadcast)
+            pltpu.VMEM((block_q, _LANES), jnp.float32),  # l
+            pltpu.VMEM((block_q, d), jnp.float32),       # acc
         ],
         cost_estimate=pl.CostEstimate(
             flops=4 * b * h * lq * lk * d,
@@ -432,7 +527,7 @@ def _flash_pallas(q, k, v, causal, scale, block_q, block_k, interpret=False,
         res = call()
     out, lse = res if with_lse else (res, None)
     out = out.reshape(b, h, lq, d).transpose(0, 2, 1, 3)
-    return out, (lse[:, :, 0] if with_lse else None)
+    return (out, lse[:, :, 0].reshape(b, h, lq)) if with_lse else (out,)
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -480,8 +575,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             s = jnp.where(_keep_mask(s.shape, row0, col0, window),
                           s, NEG_INF)
         if segmented:
-            s = jnp.where(qseg_ref[0][:, None] == kseg_ref[0][None, :],
-                          s, NEG_INF)
+            s = jnp.where(_same_segment(qseg_ref, kseg_ref), s, NEG_INF)
         p = jnp.exp(s - lse_ref[0][:, :1])
         dp = jax.lax.dot_general(do, vj, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -543,8 +637,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             s = jnp.where(_keep_mask(s.shape, row0, col0, window),
                           s, NEG_INF)
         if segmented:
-            s = jnp.where(qseg_ref[0][:, None] == kseg_ref[0][None, :],
-                          s, NEG_INF)
+            s = jnp.where(_same_segment(qseg_ref, kseg_ref), s, NEG_INF)
         p = jnp.exp(s - lse_ref[0][:, :1])  # [block_q, block_k]
         dv_scr[:] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
@@ -564,29 +657,40 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_pallas_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k,
                       interpret=False, window=None, segment_ids=None):
-    """Pallas dQ/dK/dV from the saved (out, lse) residuals."""
+    """Pallas dQ/dK/dV from the saved (out, lse ``[B, H, Lq]``)
+    residuals."""
+    block_q = _require_fit(block_q, q.shape[1])
+    block_k = _require_fit(block_k, k.shape[1])
+    local = functools.partial(
+        _flash_bwd_local, causal=causal, scale=scale, block_q=block_q,
+        block_k=block_k, interpret=interpret, window=window)
+    seg = [] if segment_ids is None else [segment_ids]
+    return _per_shard(
+        local,
+        ["bqhd", "bkhd", "bkhd", "bqhd", "bhq", "bqhd"] + ["bq" for _ in seg],
+        ["bqhd", "bkhd", "bkhd"],
+        q, k, v, out, lse, g, *seg)
+
+
+def _flash_bwd_local(q, k, v, out, lse, g, segment_ids=None, *, causal,
+                     scale, block_q, block_k, interpret, window):
+    """The two backward launches on ONE device's operands (see
+    :func:`_flash_fwd_local`): ``(dq, dk, dv)``."""
     b, lq, h, d = q.shape
     lk = k.shape[1]
-    block_q, block_k = _require_fit(block_q, lq), _require_fit(block_k, lk)
+    segmented = segment_ids is not None
     flat = lambda a, L: a.transpose(0, 2, 1, 3).reshape(b * h, L, d)
     qf, kf, vf = flat(q, lq), flat(k, lk), flat(v, lk)
     dof, of = flat(g, lq), flat(out, lq)
     delta = jnp.sum(dof.astype(jnp.float32) * of.astype(jnp.float32), axis=-1)
     # Lane-broadcast row vectors (TPU tiling; see _flash_kernel note).
-    lane = lambda a: jnp.broadcast_to(a[:, :, None], (*a.shape, 128))
-    lse_l, delta_l = lane(lse), lane(delta)
-    segmented = segment_ids is not None
-    segf = (jnp.repeat(segment_ids.astype(jnp.int32), h, axis=0)
-            if segmented else None)
-    # Rank-2 seg specs ride the SAME block index as their rank-3
-    # q/k twins ([:2] drops the trailing 0), so banded walks stay in
-    # lockstep.
-    seg_of = lambda at: ((1, at[0][1]), lambda bh, i, j: at[1](bh, i, j)[:2])
+    lane = lambda a: jnp.broadcast_to(a[:, :, None], (*a.shape, _LANES))
+    lse_l, delta_l = lane(lse.reshape(b * h, lq)), lane(delta)
 
     vspec = lambda f: pl.BlockSpec(*f, memory_space=pltpu.VMEM)
     q_at = ((1, block_q, d), lambda bh, i, j: (bh, i, 0))
     kv_at_inner = ((1, block_k, d), lambda bh, i, j: (bh, j, 0))
-    row_at = ((1, block_q, 128), lambda bh, i, j: (bh, i, 0))
+    row_at = ((1, block_q, _LANES), lambda bh, i, j: (bh, i, 0))
 
     n_kb = lk // block_k
     if window is not None:
@@ -600,8 +704,10 @@ def _flash_pallas_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k,
                     vspec(q_at), vspec(row_at), vspec(row_at)]
         args = [qf, kf, vf, dof, lse_l, delta_l]
         if segmented:
-            in_specs += [vspec(seg_of(q_at)), vspec(seg_of(kv_at_banded))]
-            args += [segf, segf]
+            seg_specs, seg_args = _segment_operands(segment_ids, h, q_at,
+                                                    kv_at_banded)
+            in_specs += seg_specs
+            args += seg_args
         return pl.pallas_call(
             functools.partial(_flash_bwd_dq_kernel, causal=causal,
                               scale=scale, window=window,
@@ -620,13 +726,13 @@ def _flash_pallas_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k,
 
     kv_at = ((1, block_k, d), lambda bh, i, j: (bh, i, 0))
     q_at_inner = ((1, block_q, d), lambda bh, i, j: (bh, j, 0))
-    row_at_inner = ((1, block_q, 128), lambda bh, i, j: (bh, j, 0))
+    row_at_inner = ((1, block_q, _LANES), lambda bh, i, j: (bh, j, 0))
 
     n_qb = lq // block_q
     if window is not None:
         dkv_inner, dkv_q_map = _banded_q(window, block_q, block_k, n_qb)
         q_in = ((1, block_q, d), dkv_q_map)
-        row_in = ((1, block_q, 128), dkv_q_map)
+        row_in = ((1, block_q, _LANES), dkv_q_map)
     else:
         dkv_inner, q_in, row_in = n_qb, q_at_inner, row_at_inner
 
@@ -636,8 +742,10 @@ def _flash_pallas_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k,
                     vspec(row_in)]
         args = [qf, kf, vf, dof, lse_l, delta_l]
         if segmented:
-            in_specs += [vspec(seg_of(q_in)), vspec(seg_of(kv_at))]
-            args += [segf, segf]
+            seg_specs, seg_args = _segment_operands(segment_ids, h, q_in,
+                                                    kv_at)
+            in_specs += seg_specs
+            args += seg_args
         return pl.pallas_call(
             functools.partial(_flash_bwd_dkv_kernel, causal=causal,
                               scale=scale, window=window,
@@ -707,7 +815,8 @@ def _require_fit(requested: int, length: int) -> int:
     return b
 
 
-# Measured optimum of the hardware sweep (docs/perf_transformer.md).
+# Optimum of the (block_q, block_k) sweep, measured 2026-07-31 on one
+# v5e, not re-measured since.
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
 
@@ -726,20 +835,27 @@ def _pallas_blocks(lq, lk, d, block_q, block_k, gate_small_bk=False,
     if bq is None or bk is None:
         return None
     # Defaulted callers only (``gate_small_bk``): tiny fitted KV tiles
-    # usually lose to the XLA blockwise fallback end-to-end (sweep,
-    # docs/perf_transformer.md: at block_k=128 the kernel is slower
-    # than the fallback for every block_q except 1024, which edges it
-    # out by ~4%), so keep bk=128 only when bq fitted to >=1024.  An
-    # EXPLICIT small block_k is always honored — the sweep itself must
-    # be able to time the kernel at any point of its grid.
+    # usually lose to the XLA blockwise path end-to-end (sweep measured
+    # 2026-07-31 on one v5e, not re-measured since: at block_k=128 the
+    # kernel is slower than blockwise for every block_q except 1024,
+    # which edges it out by ~4%), so keep bk=128 only when bq fitted to
+    # >=1024.  An EXPLICIT small block_k is always honored — the sweep
+    # itself must be able to time the kernel at any point of its grid.
     if gate_small_bk and bk < 256 and bk != lk and bq < 1024:
         return None
     return bq, bk
 
 
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
 def _use_pallas(q, k, block_q, block_k, gate_small_bk=False,
                 strict_q=False, strict_k=False) -> bool:
-    if not _HAVE_PALLAS or jax.default_backend() != "tpu":
+    """Kernel or blockwise: decided from the backend and the shapes
+    alone, before any kernel is built.  Not a rescue — once this says
+    kernel, a kernel that fails to lower or compile raises."""
+    if not _on_tpu():
         return False
     return _pallas_blocks(q.shape[1], k.shape[1], q.shape[-1],
                           block_q, block_k, gate_small_bk,
@@ -784,10 +900,9 @@ def flash_attention(q, k, v, causal: bool = False, scale: float | None = None,
 
     ``block_q``/``block_k`` default (None) to the measured optimum of
     the (block_q, block_k) hardware sweep on the long-context benchmark
-    config (seq 4096, d1024 L8, TPU v5e —
-    `scripts/sweep_attention_blocks.py`, results in
-    docs/perf_transformer.md): (1024, 1024) beat the untuned (256, 512)
-    by 35% on the full train step.  Defaulted blocks are fitted per
+    config (seq 4096, d1024 L8, `scripts/sweep_attention_blocks.py`;
+    measured 2026-07-31 on one v5e, not re-measured since): (1024,
+    1024) beat the untuned (256, 512) by 35% on the full train step.  Defaulted blocks are fitted per
     call (``_fit_block``): shorter sequences clamp to one block, and
     lengths the default doesn't divide (e.g. 1536) drop to their
     largest lane-aligned divisor instead of leaving the Pallas path —

@@ -58,10 +58,10 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from distkeras_tpu import obs
-from distkeras_tpu.parallel.compat import shard_map
 from distkeras_tpu.parallel.exchange import (adasum_combine, int8_decode,
                                               int8_encode)
 

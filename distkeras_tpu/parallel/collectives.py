@@ -36,8 +36,8 @@ Two spellings of each collective:
   still pending, GSPMD emits a reduce-scatter instead (the same
   mechanism that gives ``fsdp_plan`` its gradient reduce-scatters).
 * :func:`reduce_scatter` / :func:`all_gather` — the explicit
-  shard_map primitives (via ``parallel/compat.py``), for manual-SPMD
-  callers and for testing the collective math in isolation.
+  shard_map primitives, for manual-SPMD callers and for testing the
+  collective math in isolation.
   ``all_gather`` is also the hot path's parameter-update gather.
 
 :func:`zero1_optimizer` wraps any *elementwise* optax transform (the
@@ -66,10 +66,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distkeras_tpu import obs
-from distkeras_tpu.parallel.compat import shard_map
 
 # ~4 MB buckets: big enough to amortize collective launch latency,
 # small enough that several buckets pipeline inside one exchange.

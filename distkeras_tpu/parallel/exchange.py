@@ -55,13 +55,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distkeras_tpu import obs
 from distkeras_tpu.parallel.collectives import (DEFAULT_BUCKET_MB,
                                                  Zero1Layout, all_gather,
                                                  zero1_shard_shapes)
-from distkeras_tpu.parallel.compat import shard_map
 
 _MERGE_RULES = ("mean", "adasum")
 _CODECS = (None, "int8", "topk")

@@ -103,17 +103,11 @@ def initialize_multihost(coordinator_address: str | None = None,
 def enable_cpu_collectives() -> None:
     """Select the gloo backend for cross-process CPU collectives.
 
-    The pinned jax (0.4.37) ships multiprocess CPU support but does not
-    enable it by default — without this, any cross-process psum on the
-    CPU backend dies with "Multiprocess computations aren't implemented
-    on the CPU backend".  Must run BEFORE ``jax.distributed.initialize``.
-    Guarded: on accelerator backends the option is irrelevant, and a
-    future jax that renames or removes it must not break multihost
-    init on real hardware."""
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # noqa: BLE001 — option gone/renamed: proceed
-        pass
+    Without it any cross-process psum on the CPU backend dies with
+    "Multiprocess computations aren't implemented on the CPU backend".
+    Must run BEFORE ``jax.distributed.initialize``; accelerator
+    backends ignore the option."""
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 
 def mesh_axis_size(mesh: Mesh, axis: str) -> int:

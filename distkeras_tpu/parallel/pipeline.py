@@ -23,7 +23,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from distkeras_tpu.parallel.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

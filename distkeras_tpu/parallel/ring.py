@@ -22,8 +22,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from distkeras_tpu.parallel.compat import shard_map
 
 from distkeras_tpu.ops.attention import (
     attention_chunk,

@@ -35,8 +35,7 @@ from typing import Any, Callable, Sequence
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from distkeras_tpu.parallel.compat import keystr
+from jax.tree_util import keystr
 
 
 class UnmatchedLeafError(ValueError):

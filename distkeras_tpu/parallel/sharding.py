@@ -152,9 +152,7 @@ class ShardingPlan:
         covers Keras variable paths and functional-model dicts.
         """
         def leaf(path, x):
-            from distkeras_tpu.parallel.compat import keystr
-
-            name = keystr(path, simple=True, separator="/")
+            name = jax.tree_util.keystr(path, simple=True, separator="/")
             shape = tuple(x.shape) if hasattr(x, "shape") else None
             return NamedSharding(mesh, self.spec_for(name, shape=shape,
                                                      mesh=mesh))

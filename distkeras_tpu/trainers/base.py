@@ -496,7 +496,7 @@ class SingleTrainer(Trainer):
     staging transfer, only ~4 bytes/sample/epoch cross the
     host->device link.  The right mode whenever the dataset fits in
     HBM (CIFAR-scale and far beyond) — the host link is the input
-    pipeline's narrow point, especially on remote-attached devices.
+    pipeline's narrow point.
     Identical math and data order to the streaming path.
     """
 

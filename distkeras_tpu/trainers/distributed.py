@@ -26,7 +26,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from distkeras_tpu.parallel.compat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from distkeras_tpu.data.dataset import Dataset
@@ -326,7 +326,6 @@ class DistributedTrainer(Trainer):
         axis, sharded), for :func:`exchange_optimizer` to merge.  The
         loss is pmean'd for reporting.  The LM analogue is
         ``LMTrainer._stacked_local_value_and_grad``."""
-        from distkeras_tpu.parallel.compat import shard_map
         mesh = self.mesh
 
         def value_and_grad(loss, has_aux=True):
@@ -574,11 +573,11 @@ class ADAG(DistributedTrainer):
         mesh; each round ships only a [window, global_batch] int32
         index block, sharded over the ``data`` axis, and every replica
         gathers its own rows on device — the distributed form of
-        SingleTrainer's ``device_data`` (docs/perf_input_pipeline.md:
-        the streaming path is capped by the host link, 320k vs ~10k
-        samples/s on this relay).  Training math is EXACTLY the
-        streaming path's (same accum step fed the same rows in the same
-        order — exactness-tested).
+        SingleTrainer's ``device_data`` (the streaming path is capped
+        by the host-to-device link; not measured on a directly attached
+        chip).  Training math is EXACTLY the streaming path's (same
+        accum step fed the same rows in the same order —
+        exactness-tested).
 
         Multi-process meshes take :meth:`_fit_device_data_multihost`:
         per-host shard-local staging (each host's rows live only on its

@@ -35,8 +35,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-from distkeras_tpu.parallel.compat import shard_map
 
 from distkeras_tpu.data.dataset import Dataset
 from distkeras_tpu.models.adapter import TrainState

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from distkeras_tpu.parallel.compat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from distkeras_tpu import obs
@@ -54,14 +54,13 @@ _STAGING_SANITY_BYTES = 8 << 30
 
 
 def _device_bytes_limit():
-    """Per-device memory budget in bytes, or None when the backend
-    does not report one (CPU test meshes).  Module-level so tests can
-    monkeypatch a tiny budget to exercise the staging guard."""
-    try:
-        stats = jax.local_devices()[0].memory_stats()
-    except Exception:
-        return None
-    return (stats or {}).get("bytes_limit")
+    """Per-device memory budget in bytes, or None on a backend that
+    reports none (``memory_stats()`` is None on CPU).  A TPU reports
+    one, and a failing ``memory_stats()`` there raises.  Module-level
+    so tests can monkeypatch a tiny budget to exercise the staging
+    guard."""
+    stats = jax.local_devices()[0].memory_stats()
+    return None if stats is None else stats["bytes_limit"]
 
 
 def _with_ema(opt, decay: float):
@@ -231,9 +230,9 @@ class LMTrainer(CheckpointingBase):
     cheap relative to activations), sharded over the ``data`` axis in
     consumption-stream layout; each step then ships only a tiny
     replicated index block and gathers its batch on device
-    (_stage_stream).  This is the distributed/flagship form of the
-    input-pipeline win measured in docs/perf_input_pipeline.md (the
-    host link caps streaming); composes with fsdp/TP/ring/pipeline
+    (_stage_stream).  This is the distributed/flagship form of
+    SingleTrainer's device-resident input plane (the host link caps
+    streaming); composes with fsdp/TP/ring/pipeline
     meshes and grad_accum/segments because the gather feeds the
     unchanged train step inside the same jitted program.  Data order
     is bit-for-bit the streaming path's (parity-tested).
@@ -327,10 +326,9 @@ class LMTrainer(CheckpointingBase):
             # (decaying a normalization gain toward 0 fights the
             # parameterization, not overfitting).
             def decay_mask(params):
-                from distkeras_tpu.parallel.compat import keystr
-
                 def leaf(path, _):
-                    name = keystr(path, simple=True, separator="/")
+                    name = jax.tree_util.keystr(path, simple=True,
+                                                separator="/")
                     return not name.endswith("_scale")
                 return jax.tree_util.tree_map_with_path(leaf, params)
 

@@ -21,7 +21,11 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def setup_devices():
-    """Honor DKT_EXAMPLE_DEVICES before jax initializes; return devices."""
+    """Place the compile cache and honor DKT_EXAMPLE_DEVICES before jax
+    initializes; return devices."""
+    from distkeras_tpu.utils.misc import configure_compile_cache
+
+    configure_compile_cache()
     n = os.environ.get("DKT_EXAMPLE_DEVICES")
     if n:
         os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")

@@ -1,10 +1,11 @@
 """Flagship-MFU ablation: where do the missing percent go?
 
-docs/perf_transformer.md attributes the long-config residual (~21% of
-step time) to unfused elementwise/optimizer/CE-head bandwidth without
-per-component numbers.  This script measures each candidate in
-isolation on the current accelerator so the next optimization lands on
-evidence, not attribution folklore:
+The long-config train step's residual (~21% of step time; measured
+2026-07-31 on one v5e, not re-measured since) was attributed to
+unfused elementwise/optimizer/CE-head bandwidth without per-component
+numbers.  This script measures each candidate in isolation on the
+current accelerator so the next optimization lands on evidence, not
+attribution folklore:
 
 - ``optimizer``: adamw update alone on the flagship param tree (m/v
   read-modify-write is pure HBM traffic; its share of the step bounds
@@ -20,8 +21,7 @@ evidence, not attribution folklore:
   replaced by a mean over hidden states — the head's true share of the
   step, measured rather than modeled.
 
-Prints one JSON line per measurement.  Results land in
-docs/perf_transformer.md's ablation table.
+Prints one JSON line per measurement.
 
 Usage: python scripts/ablate_flagship.py [name ...]
 """
@@ -231,10 +231,7 @@ def main(names):
     print(f"# backend={jax.default_backend()} device={jax.devices()[0]}",
           file=sys.stderr)
     for name in names or ABLATIONS:
-        try:
-            print(json.dumps(ABLATIONS[name]()))
-        except Exception as e:
-            print(json.dumps({"metric": name, "error": repr(e)[:200]}))
+        print(json.dumps(ABLATIONS[name]()))
 
 
 if __name__ == "__main__":

@@ -98,7 +98,7 @@ def test_pallas_kernel_interpret(rng, causal):
     if causal:
         mask = np.tril(np.ones((16, 16), bool))
         logits = np.where(mask[None, None], logits, -1e30)
-    ref_lse = np.log(np.exp(logits).sum(-1)).reshape(1, 16)
+    ref_lse = np.log(np.exp(logits).sum(-1)).reshape(1, 1, 16)  # [B, H, Lq]
     np.testing.assert_allclose(lse, ref_lse, atol=1e-4, rtol=1e-4)
 
 
@@ -328,3 +328,110 @@ def test_explicit_small_block_k_honored_and_unfittable_raises(rng):
     assert _pallas_blocks(768, 768, 128, 512, 512) == (384, 384)
     assert _pallas_blocks(1024, 768, 128, 512, 512,
                           strict_q=True, strict_k=False) == (512, 384)
+
+
+# ------------------------------------------------ TPU lowering, no chip
+#
+# The Mosaic lowering runs under JAX_PLATFORMS=cpu, and it is where the
+# TPU compiler's shape rules are enforced (the (8, 128) block rule, the
+# refusal to partition a kernel automatically) — so a kernel the chip
+# would refuse fails here, at kernel-legal shapes (D 128, blocks 128).
+
+def _tpu_lower(fn, *avals):
+    return jax.jit(fn).trace(*avals).lower(lowering_platforms=("tpu",))
+
+
+def _kernel_avals(b=2, l=512, h=2, d=128, sharding=None, seg_sharding=None):
+    x = jax.ShapeDtypeStruct((b, l, h, d), jnp.bfloat16, sharding=sharding)
+    seg = jax.ShapeDtypeStruct((b, l), jnp.int32, sharding=seg_sharding)
+    return x, seg
+
+
+@pytest.mark.parametrize("with_lse", [False, True])
+@pytest.mark.parametrize("segmented", [False, True])
+@pytest.mark.parametrize("window", [None, 256])
+def test_forward_kernel_lowers_for_tpu(window, segmented, with_lse):
+    x, seg = _kernel_avals()
+
+    def fwd(q, k, v, s):
+        return _flash_pallas(q, k, v, True, 0.1, 128, 128,
+                             with_lse=with_lse, window=window,
+                             segment_ids=s if segmented else None)
+    text = _tpu_lower(fwd, x, x, x, seg).as_text()
+    assert text.count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("segmented", [False, True])
+@pytest.mark.parametrize("window", [None, 256])
+def test_backward_kernels_lower_for_tpu(window, segmented):
+    from distkeras_tpu.ops.attention import _flash_pallas_bwd
+
+    x, seg = _kernel_avals()
+    lse = jax.ShapeDtypeStruct((2, 2, 512), jnp.float32)
+
+    def bwd(q, k, v, out, lse, g, s):
+        return _flash_pallas_bwd(q, k, v, out, lse, g, True, 0.1, 128, 128,
+                                 window=window,
+                                 segment_ids=s if segmented else None)
+    text = _tpu_lower(bwd, x, x, x, x, lse, x, seg).as_text()
+    assert text.count("tpu_custom_call") == 2   # dq, dkv
+
+
+@pytest.mark.parametrize("spec", [("data", None, None, None),
+                                  (None, None, "model", None),
+                                  ("data", None, "model", None)])
+def test_flash_attention_lowers_for_tpu_in_sharded_jit(devices, monkeypatch,
+                                                       spec):
+    """flash_attention inside a multi-device jit, batch- and
+    head-sharded operands, forward and backward, segments included:
+    the kernels must sit in a shard_map (read off the operands' mesh),
+    or the Mosaic lowering raises "cannot be automatically
+    partitioned"."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from distkeras_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    mesh = make_mesh(MeshSpec(data=4, model=2), devices=devices)
+    x, seg = _kernel_avals(
+        b=4, sharding=NamedSharding(mesh, P(*spec)),
+        seg_sharding=NamedSharding(mesh, P(spec[0], None)))
+
+    def loss(q, k, v, s):
+        return flash_attention(q, k, v, True, window=256,
+                               segment_ids=s).astype(jnp.float32).sum()
+    text = _tpu_lower(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                      x, x, x, seg).as_text()
+    assert text.count("tpu_custom_call") == 3
+
+
+def test_per_shard_matches_unsharded(devices, rng):
+    """_per_shard's layout logic, with a jnp function in the kernel's
+    place: batch splits over data, heads over model, an axis that does
+    not divide its dimension leaves it whole, and the result keeps the
+    operands' placement (nothing gathered)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from distkeras_tpu.ops.attention import _per_shard
+
+    mesh = make_mesh(MeshSpec(data=4, model=2), devices=devices)
+    seg = np.repeat(np.arange(4, dtype=np.int32), 8)[None]
+
+    def local(q, k, v, s):
+        return (naive_attention(q, k, v, causal=True, segment_ids=s),)
+
+    def run(q, k, v, s):
+        return _per_shard(local, ["bqhd", "bkhd", "bkhd", "bq"], ["bqhd"],
+                          q, k, v, s)[0]
+
+    for b, want in ((4, P("data", None, "model", None)),
+                    (2, P(None, None, "model", None))):   # 2 % 4 != 0
+        q, k, v = qkv(rng, b=b, d=4)
+        s = np.broadcast_to(seg, (b, 32))
+        ref = naive_attention(q, k, v, causal=True, segment_ids=s)
+        put = lambda a, spec: jax.device_put(a, NamedSharding(mesh, spec))
+        out = jax.jit(run)(*(put(a, P("data", None, "model", None))
+                             if b == 4 else put(a, P(None, None, "model"))
+                             for a in (q, k, v)), s)
+        np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-5)
+        assert out.sharding.is_equivalent_to(NamedSharding(mesh, want), 4)

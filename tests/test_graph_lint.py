@@ -533,7 +533,7 @@ def _ir(fn, *args, donate=(), **jit_kw):
 
 
 def test_dtype_f64_positive_and_negative():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         pos = _ir(lambda x: jnp.asarray(x, jnp.float64) * 2.0,
                   jax.ShapeDtypeStruct((4,), jnp.float32))
     assert "dtype-f64" in rules_of(pos)

@@ -134,11 +134,14 @@ def test_pallas_interpret_segments_fwd_bwd(rng):
     the banded (windowed) grid."""
     from distkeras_tpu.ops.attention import _flash_pallas, _flash_pallas_bwd
 
-    b, s, h, d = 1, 256, 1, 128
+    # Two rows with DIFFERENT packings and two heads: the segment tiles
+    # are indexed by batch row while the grid walks batch*head.
+    b, s, h, d = 2, 256, 2, 128
     q = jnp.asarray(rng.normal(size=(b, s, h, d)).astype(np.float32))
     k = jnp.asarray(rng.normal(size=(b, s, h, d)).astype(np.float32))
     v = jnp.asarray(rng.normal(size=(b, s, h, d)).astype(np.float32))
-    seg = _segs(b, s, splits=(100, 180))
+    seg = jnp.concatenate([_segs(1, s, splits=(100, 180)),
+                           _segs(1, s, splits=(40, 130, 200))])
     for window in (None, 96):
         ref = naive_attention(q, k, v, causal=True, window=window,
                               segment_ids=seg)
