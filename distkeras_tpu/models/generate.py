@@ -223,18 +223,19 @@ def _decode_step(params, cache, tokens, pos, cfg: TransformerConfig,
         anc, w_beams = beam_anc
         anc_oh = jax.nn.one_hot(anc, w_beams, dtype=jnp.float32)
     kv_q = "k_scale" in cache                   # int8 KV cache
-    x = embed_rows(params["tok_emb"], tokens, dtype)  # [B, D]
-    if pad_lens is None:
-        pos_ids = jnp.full((b,), pos)
-    else:
-        pos_ids = jnp.maximum(pos - pad_lens, 0)
-    rope_ang = None
-    if cfg.rope:
-        # [B, half] per-row angles; broadcast over heads.
-        rope_ang = rope_angles(pos_ids, cfg.head_dim,
-                               cfg.rope_theta)[:, None, :]
-    else:
-        x = x + params["pos_emb"][pos_ids].astype(dtype)
+    with jax.named_scope("embed"):
+        x = embed_rows(params["tok_emb"], tokens, dtype)  # [B, D]
+        if pad_lens is None:
+            pos_ids = jnp.full((b,), pos)
+        else:
+            pos_ids = jnp.maximum(pos - pad_lens, 0)
+        rope_ang = None
+        if cfg.rope:
+            # [B, half] per-row angles; broadcast over heads.
+            rope_ang = rope_angles(pos_ids, cfg.head_dim,
+                                   cfg.rope_theta)[:, None, :]
+        else:
+            x = x + params["pos_emb"][pos_ids].astype(dtype)
 
     ck_all, cv_all = cache["k"], cache["v"]     # [L, B, S, kv, hd]
     if kv_q:
@@ -244,114 +245,123 @@ def _decode_step(params, cache, tokens, pos, cfg: TransformerConfig,
     for i in range(cfg.n_layers):
         lp = jax.tree.map(lambda a: a[i], params["layers"])
         h = _rms_norm(x, lp["ln1_scale"])
-        # deq: int8 weights dequantize here (fused into the matmul
-        # read); plain trees pass through untouched.
-        q = jnp.einsum("bd,dhk->bhk", h, deq(lp["attn"]["wq"]))
-        # Cache dtype: the einsum promotes bf16 activations x f32 weights
-        # to f32; the cache stays in the compute dtype.
-        k = jnp.einsum("bd,dhk->bhk", h, deq(lp["attn"]["wk"]))
-        v = jnp.einsum("bd,dhk->bhk", h, deq(lp["attn"]["wv"]))
-        if rope_ang is not None:
-            # Keys cache post-rotation (each key's rotation depends only
-            # on its own position), matching the training forward.
-            q, k = rope_rotate(q, rope_ang), rope_rotate(k, rope_ang)
-        if kv_q:  # post-rotation, like the bf16 cache
-            k, k_s = quantize_kv(k)               # scale [B, C]
-            v, v_s = quantize_kv(v)
+        with jax.named_scope("attn_proj"):
+            # deq: int8 weights dequantize here (fused into the matmul
+            # read); plain trees pass through untouched.
+            q = jnp.einsum("bd,dhk->bhk", h, deq(lp["attn"]["wq"]))
+            # Cache dtype: the einsum promotes bf16 activations x f32
+            # weights to f32; the cache stays in the compute dtype.
+            k = jnp.einsum("bd,dhk->bhk", h, deq(lp["attn"]["wk"]))
+            v = jnp.einsum("bd,dhk->bhk", h, deq(lp["attn"]["wv"]))
+            if rope_ang is not None:
+                # Keys cache post-rotation (each key's rotation depends
+                # only on its own position), matching the training
+                # forward.
+                q, k = rope_rotate(q, rope_ang), rope_rotate(k, rope_ang)
+            if kv_q:  # post-rotation, like the bf16 cache
+                k, k_s = quantize_kv(k)               # scale [B, C]
+                v, v_s = quantize_kv(v)
         # Windowed configs write the ring-buffer slot pos % C (identical
         # to pos while pos < C): with window <= C the cache then
         # supports generation beyond max_len (rolling decode) — the
         # int8 scales ride the same slot arithmetic.
         slot = jnp.asarray(pos % cfg.max_len if cfg.attention_window
                            else pos, jnp.int32)
-        ck_all = _layer_slab_update(ck_all, i, k[:, None], slot)
-        cv_all = _layer_slab_update(cv_all, i, v[:, None], slot)
-        ck, cv = ck_all[i], cv_all[i]
-        if kv_q:
-            cks_all = _layer_slab_update(cks_all, i, k_s[:, None], slot)
-            cvs_all = _layer_slab_update(cvs_all, i, v_s[:, None], slot)
-            cks, cvs = cks_all[i], cvs_all[i]
-
-        # GQA: grouped einsums read only the kv-head cache — never
-        # materialize an expanded per-query-head copy (that repeat
-        # would forfeit the cache-bandwidth saving that is GQA's point).
-        groups = cfg.n_heads // cfg.kv_heads
-        qg = q.astype(jnp.float32).reshape(
-            b, cfg.kv_heads, groups, cfg.head_dim)
-        span = jnp.arange(cfg.max_len)
-        if cfg.attention_window is not None:
-            # Ring-buffer band: slot s holds global position
-            # g = pos - ((pos - s) mod C).  Keep iff the position is
-            # real (g >= 0 — this also excludes every future slot while
-            # pos < C, so prefilled prompts stay causal) and inside the
-            # window (delta < W).  For pos < C this reduces exactly to
-            # span in (pos - W, pos]; for pos >= C it implements the
-            # rolling window.  Distances are pad-invariant, so the
-            # ragged pad mask below composes unchanged.
-            delta = jnp.mod(pos - span, cfg.max_len)
-            row_mask = (delta < cfg.attention_window) & (pos - delta >= 0)
-        else:
-            row_mask = span <= pos
-        if beam_anc is not None:
-            # Windowed beam ancestry: the ancestor map is SLOT-indexed
-            # (identical to positions until the ring wraps; under
-            # rolling decode the beam body retires stale entries as
-            # slots are rewritten) and only the band mask differs from
-            # the full-cache path.
-            bt = b // w_beams
-            mask_b = jnp.broadcast_to(row_mask[None, None, :],
-                                      (bt, w_beams, cfg.max_len))
-            attn = _ancestry_attend(qg, ck, cv, anc_oh, mask_b, cfg,
-                                    w_beams,
-                                    kv_scales=(cks, cvs) if kv_q
-                                    else None)
-        else:
-            logits = jnp.einsum("bcgk,bsck->bcgs", qg,
-                                ck.astype(jnp.float32))
+        with jax.named_scope("kv_slab"):
+            ck_all = _layer_slab_update(ck_all, i, k[:, None], slot)
+            cv_all = _layer_slab_update(cv_all, i, v[:, None], slot)
+            ck, cv = ck_all[i], cv_all[i]
             if kv_q:
-                logits = logits * sc_b(cks)
-            logits = logits / jnp.sqrt(jnp.float32(cfg.head_dim))
-            mask = row_mask[None, None, None, :]
-            if pad_lens is not None:  # left-pad slots never attend
-                mask = mask & (span[None, :] >= pad_lens[:, None]
-                               )[:, None, None, :]
-            logits = jnp.where(mask, logits, -1e30)
-            probs = jax.nn.softmax(logits, axis=-1)
-            attn = jnp.einsum("bcgs,bsck->bcgk",
-                              probs * sc_b(cvs) if kv_q else probs,
-                              cv.astype(jnp.float32)).reshape(
-                b, cfg.n_heads, cfg.head_dim)
-        x = x + jnp.einsum("bhk,hkd->bd", attn.astype(dtype),
-                           deq(lp["attn"]["wo"]))
+                cks_all = _layer_slab_update(cks_all, i, k_s[:, None],
+                                             slot)
+                cvs_all = _layer_slab_update(cvs_all, i, v_s[:, None],
+                                             slot)
+                cks, cvs = cks_all[i], cvs_all[i]
+
+        with jax.named_scope("attn"):
+            # GQA: grouped einsums read only the kv-head cache — never
+            # materialize an expanded per-query-head copy (that repeat
+            # would forfeit the cache-bandwidth saving that is GQA's point).
+            groups = cfg.n_heads // cfg.kv_heads
+            qg = q.astype(jnp.float32).reshape(
+                b, cfg.kv_heads, groups, cfg.head_dim)
+            span = jnp.arange(cfg.max_len)
+            if cfg.attention_window is not None:
+                # Ring-buffer band: slot s holds global position
+                # g = pos - ((pos - s) mod C).  Keep iff the position is
+                # real (g >= 0 — this also excludes every future slot while
+                # pos < C, so prefilled prompts stay causal) and inside the
+                # window (delta < W).  For pos < C this reduces exactly to
+                # span in (pos - W, pos]; for pos >= C it implements the
+                # rolling window.  Distances are pad-invariant, so the
+                # ragged pad mask below composes unchanged.
+                delta = jnp.mod(pos - span, cfg.max_len)
+                row_mask = (delta < cfg.attention_window) & (pos - delta >= 0)
+            else:
+                row_mask = span <= pos
+            if beam_anc is not None:
+                # Windowed beam ancestry: the ancestor map is SLOT-indexed
+                # (identical to positions until the ring wraps; under
+                # rolling decode the beam body retires stale entries as
+                # slots are rewritten) and only the band mask differs from
+                # the full-cache path.
+                bt = b // w_beams
+                mask_b = jnp.broadcast_to(row_mask[None, None, :],
+                                          (bt, w_beams, cfg.max_len))
+                attn = _ancestry_attend(qg, ck, cv, anc_oh, mask_b, cfg,
+                                        w_beams,
+                                        kv_scales=(cks, cvs) if kv_q
+                                        else None)
+            else:
+                logits = jnp.einsum("bcgk,bsck->bcgs", qg,
+                                    ck.astype(jnp.float32))
+                if kv_q:
+                    logits = logits * sc_b(cks)
+                logits = logits / jnp.sqrt(jnp.float32(cfg.head_dim))
+                mask = row_mask[None, None, None, :]
+                if pad_lens is not None:  # left-pad slots never attend
+                    mask = mask & (span[None, :] >= pad_lens[:, None]
+                                   )[:, None, None, :]
+                logits = jnp.where(mask, logits, -1e30)
+                probs = jax.nn.softmax(logits, axis=-1)
+                attn = jnp.einsum("bcgs,bsck->bcgk",
+                                  probs * sc_b(cvs) if kv_q else probs,
+                                  cv.astype(jnp.float32)).reshape(
+                    b, cfg.n_heads, cfg.head_dim)
+        with jax.named_scope("attn_proj"):
+            x = x + jnp.einsum("bhk,hkd->bd", attn.astype(dtype),
+                               deq(lp["attn"]["wo"]))
 
         h = _rms_norm(x, lp["ln2_scale"])
-        if cfg.num_experts:
-            # Decode-time MoE: dense top-k without capacity (batch is
-            # small; correctness over dispatch efficiency).  Same
-            # gate rule as training/prefill via _moe_gates.
-            router = jnp.einsum("bd,de->be", h.astype(jnp.float32),
-                                lp["moe"]["wg"])
-            probs = jax.nn.softmax(router, axis=-1)
-            gates, expert = _moe_gates(probs, cfg)   # [B, k]
-            w1 = lp["moe"]["w1"][expert]  # [B, k, D, F]
-            w2 = lp["moe"]["w2"][expert]  # [B, k, F, D]
-            hk = jax.nn.gelu(jnp.einsum("bd,bkdf->bkf", h,
-                                        w1.astype(dtype)))
-            yk = jnp.einsum("bkf,bkfd->bkd", hk, w2.astype(dtype))
-            y = jnp.einsum("bkd,bk->bd", yk, gates.astype(dtype))
-        else:
-            y = jnp.einsum(
-                "bf,fd->bd",
-                jax.nn.gelu(jnp.einsum("bd,df->bf", h,
-                                       deq(lp["ffn"]["w1"]))),
-                deq(lp["ffn"]["w2"]))
-        x = x + y
+        with jax.named_scope("mlp"):
+            if cfg.num_experts:
+                # Decode-time MoE: dense top-k without capacity (batch is
+                # small; correctness over dispatch efficiency).  Same
+                # gate rule as training/prefill via _moe_gates.
+                router = jnp.einsum("bd,de->be", h.astype(jnp.float32),
+                                    lp["moe"]["wg"])
+                probs = jax.nn.softmax(router, axis=-1)
+                gates, expert = _moe_gates(probs, cfg)   # [B, k]
+                w1 = lp["moe"]["w1"][expert]  # [B, k, D, F]
+                w2 = lp["moe"]["w2"][expert]  # [B, k, F, D]
+                hk = jax.nn.gelu(jnp.einsum("bd,bkdf->bkf", h,
+                                            w1.astype(dtype)))
+                yk = jnp.einsum("bkf,bkfd->bkd", hk, w2.astype(dtype))
+                y = jnp.einsum("bkd,bk->bd", yk, gates.astype(dtype))
+            else:
+                y = jnp.einsum(
+                    "bf,fd->bd",
+                    jax.nn.gelu(jnp.einsum("bd,df->bf", h,
+                                           deq(lp["ffn"]["w1"]))),
+                    deq(lp["ffn"]["w2"]))
+            x = x + y
 
     x = _rms_norm(x, params["ln_f_scale"])
     # Vocab head: int8 trees contract the raw q table and scale the
     # result (int8 stays the HBM operand by construction — see
     # quant.unembed_logits), instead of dequantizing [V, d] per step.
-    out = unembed_logits(x, params["tok_emb"], dtype)
+    with jax.named_scope("head"):
+        out = unembed_logits(x, params["tok_emb"], dtype)
     cache = {"k": ck_all, "v": cv_all}
     if kv_q:
         cache["k_scale"], cache["v_scale"] = cks_all, cvs_all
@@ -467,14 +477,15 @@ def _decode_chunk(params, cache, tokens, pos0, cfg: TransformerConfig,
     """
     dtype = jnp.dtype(cfg.dtype)
     b, t_len = tokens.shape
-    x = embed_rows(params["tok_emb"], tokens, dtype)        # [B, T, D]
-    pos_ids = pos0[:, None] + jnp.arange(t_len)[None, :]    # [B, T]
-    rope_ang = None
-    if cfg.rope:
-        rope_ang = rope_angles(pos_ids, cfg.head_dim,
-                               cfg.rope_theta)[:, :, None, :]
-    else:
-        x = x + params["pos_emb"][pos_ids].astype(dtype)
+    with jax.named_scope("embed"):
+        x = embed_rows(params["tok_emb"], tokens, dtype)      # [B, T, D]
+        pos_ids = pos0[:, None] + jnp.arange(t_len)[None, :]  # [B, T]
+        rope_ang = None
+        if cfg.rope:
+            rope_ang = rope_angles(pos_ids, cfg.head_dim,
+                                   cfg.rope_theta)[:, :, None, :]
+        else:
+            x = x + params["pos_emb"][pos_ids].astype(dtype)
 
     kv_q = "k_scale" in cache                   # int8 KV cache
     win = cfg.attention_window is not None
@@ -526,106 +537,113 @@ def _decode_chunk(params, cache, tokens, pos0, cfg: TransformerConfig,
     for i in range(cfg.n_layers):
         lp = jax.tree.map(lambda a: a[i], params["layers"])
         h = _rms_norm(x, lp["ln1_scale"])
-        q = jnp.einsum("btd,dhk->bthk", h, deq(lp["attn"]["wq"]))
-        k = jnp.einsum("btd,dhk->bthk", h, deq(lp["attn"]["wk"]))
-        v = jnp.einsum("btd,dhk->bthk", h, deq(lp["attn"]["wv"]))
-        if rope_ang is not None:
-            q, k = rope_rotate(q, rope_ang), rope_rotate(k, rope_ang)
-        if kv_q:  # post-rotation, like the bf16 cache
-            k, k_s = quantize_kv(k)
-            v, v_s = quantize_kv(v)
-        if uniform_pos:
-            ck_all = _layer_slab_update(ck_all, i, k, wr_pos[0])
-            cv_all = _layer_slab_update(cv_all, i, v, wr_pos[0])
-            ck, cv = ck_all[i], cv_all[i]
-            if kv_q:
-                cks_all = _layer_slab_update(cks_all, i, k_s, wr_pos[0])
-                cvs_all = _layer_slab_update(cvs_all, i, v_s, wr_pos[0])
-                cks, cvs = cks_all[i], cvs_all[i]
-        else:
-            if win and t_len > 1:
-                # A multi-token ring chunk at divergent row positions
-                # can wrap mid-chunk: modular per-element scatter.
-                upd = lambda c, r: _rows_update_ring(c, r, pos0,
-                                                     cfg.max_len)
+        with jax.named_scope("attn_proj"):
+            q = jnp.einsum("btd,dhk->bthk", h, deq(lp["attn"]["wq"]))
+            k = jnp.einsum("btd,dhk->bthk", h, deq(lp["attn"]["wk"]))
+            v = jnp.einsum("btd,dhk->bthk", h, deq(lp["attn"]["wv"]))
+            if rope_ang is not None:
+                q, k = rope_rotate(q, rope_ang), rope_rotate(k, rope_ang)
+            if kv_q:  # post-rotation, like the bf16 cache
+                k, k_s = quantize_kv(k)
+                v, v_s = quantize_kv(v)
+        with jax.named_scope("kv_slab"):
+            if uniform_pos:
+                ck_all = _layer_slab_update(ck_all, i, k, wr_pos[0])
+                cv_all = _layer_slab_update(cv_all, i, v, wr_pos[0])
+                ck, cv = ck_all[i], cv_all[i]
+                if kv_q:
+                    cks_all = _layer_slab_update(cks_all, i, k_s, wr_pos[0])
+                    cvs_all = _layer_slab_update(cvs_all, i, v_s, wr_pos[0])
+                    cks, cvs = cks_all[i], cvs_all[i]
             else:
-                upd = lambda c, r: _rows_update(c, r, wr_pos)
-            ck = upd(ck_all[i], k)
-            cv = upd(cv_all[i], v)
-            new_k.append(ck)
-            new_v.append(cv)
-            if kv_q:
-                cks = upd(cks_all[i], k_s)
-                cvs = upd(cvs_all[i], v_s)
-                new_ks.append(cks)
-                new_vs.append(cvs)
+                if win and t_len > 1:
+                    # A multi-token ring chunk at divergent row positions
+                    # can wrap mid-chunk: modular per-element scatter.
+                    upd = lambda c, r: _rows_update_ring(c, r, pos0,
+                                                         cfg.max_len)
+                else:
+                    upd = lambda c, r: _rows_update(c, r, wr_pos)
+                ck = upd(ck_all[i], k)
+                cv = upd(cv_all[i], v)
+                new_k.append(ck)
+                new_v.append(cv)
+                if kv_q:
+                    cks = upd(cks_all[i], k_s)
+                    cvs = upd(cvs_all[i], v_s)
+                    new_ks.append(cks)
+                    new_vs.append(cvs)
 
-        groups = cfg.n_heads // cfg.kv_heads
-        qg = q.astype(jnp.float32).reshape(
-            b, t_len, cfg.kv_heads, groups, cfg.head_dim)
-        if beam_anc is not None:
-            # Ancestry attention (shared body: _ancestry_attend) — the
-            # cache is read once, W x the (tiny) decode attention
-            # FLOPs, and the one-hot selects each position's true
-            # ancestor lane.
-            bt = b // w_beams
-            mask_b = mask[:, 0, 0, 0, :].reshape(bt, w_beams,
-                                                 cfg.max_len)
-            attn = _ancestry_attend(
-                qg[:, 0], ck, cv, anc_oh, mask_b, cfg, w_beams,
-                kv_scales=(cks, cvs) if kv_q else None,
-            )[:, None]  # restore T = 1
-        else:
-            logits = jnp.einsum("btcgk,bsck->btcgs", qg,
-                                ck.astype(jnp.float32))
-            if kv_q:
-                logits = logits * sc_b(cks)
-            logits = logits / jnp.sqrt(jnp.float32(cfg.head_dim))
-            logits = jnp.where(mask, logits, -1e30)
-            probs = jax.nn.softmax(logits, axis=-1)
-            attn = jnp.einsum("btcgs,bsck->btcgk",
-                              probs * sc_b(cvs) if kv_q else probs,
-                              cv.astype(jnp.float32)).reshape(
-                b, t_len, cfg.n_heads, cfg.head_dim)
-        x = x + jnp.einsum("bthk,hkd->btd", attn.astype(dtype),
-                           deq(lp["attn"]["wo"]))
+        with jax.named_scope("attn"):
+            groups = cfg.n_heads // cfg.kv_heads
+            qg = q.astype(jnp.float32).reshape(
+                b, t_len, cfg.kv_heads, groups, cfg.head_dim)
+            if beam_anc is not None:
+                # Ancestry attention (shared body: _ancestry_attend) — the
+                # cache is read once, W x the (tiny) decode attention
+                # FLOPs, and the one-hot selects each position's true
+                # ancestor lane.
+                bt = b // w_beams
+                mask_b = mask[:, 0, 0, 0, :].reshape(bt, w_beams,
+                                                     cfg.max_len)
+                attn = _ancestry_attend(
+                    qg[:, 0], ck, cv, anc_oh, mask_b, cfg, w_beams,
+                    kv_scales=(cks, cvs) if kv_q else None,
+                )[:, None]  # restore T = 1
+            else:
+                logits = jnp.einsum("btcgk,bsck->btcgs", qg,
+                                    ck.astype(jnp.float32))
+                if kv_q:
+                    logits = logits * sc_b(cks)
+                logits = logits / jnp.sqrt(jnp.float32(cfg.head_dim))
+                logits = jnp.where(mask, logits, -1e30)
+                probs = jax.nn.softmax(logits, axis=-1)
+                attn = jnp.einsum("btcgs,bsck->btcgk",
+                                  probs * sc_b(cvs) if kv_q else probs,
+                                  cv.astype(jnp.float32)).reshape(
+                    b, t_len, cfg.n_heads, cfg.head_dim)
+        with jax.named_scope("attn_proj"):
+            x = x + jnp.einsum("bthk,hkd->btd", attn.astype(dtype),
+                               deq(lp["attn"]["wo"]))
 
         h = _rms_norm(x, lp["ln2_scale"])
-        if cfg.num_experts and t_len > 1:
-            # Multi-token chunks take the batched dense-routing block
-            # (all experts on all tokens, one-hot combine): peak memory
-            # is [B, T, E, F] ACTIVATIONS, where the per-token weight
-            # gather below would materialize B*T*k copies of the [D, F]
-            # expert mats — GBs per layer at warm-chunk T.  Same math
-            # (_moe_gates shared), same decode-parity semantics.
-            y = _moe_dense_block(lp["moe"], h, cfg)
-        elif cfg.num_experts:
-            # T = 1 (the decode step): gather the k selected experts'
-            # slabs per row — fewer HBM bytes than all E at small
-            # batch, which is what the bandwidth-bound loop wants.
-            router = jnp.einsum("btd,de->bte", h.astype(jnp.float32),
-                                lp["moe"]["wg"])
-            gates, expert = _moe_gates(jax.nn.softmax(router, -1), cfg)
-            w1 = lp["moe"]["w1"][expert]          # [B, T, k, D, F]
-            w2 = lp["moe"]["w2"][expert]
-            hk = jax.nn.gelu(jnp.einsum("btd,btkdf->btkf", h,
-                                        w1.astype(dtype)))
-            yk = jnp.einsum("btkf,btkfd->btkd", hk, w2.astype(dtype))
-            y = jnp.einsum("btkd,btk->btd", yk, gates.astype(dtype))
-        else:
-            y = jnp.einsum(
-                "btf,fd->btd",
-                jax.nn.gelu(jnp.einsum("btd,df->btf", h,
-                                       deq(lp["ffn"]["w1"]))),
-                deq(lp["ffn"]["w2"]))
-        x = x + y
+        with jax.named_scope("mlp"):
+            if cfg.num_experts and t_len > 1:
+                # Multi-token chunks take the batched dense-routing block
+                # (all experts on all tokens, one-hot combine): peak memory
+                # is [B, T, E, F] ACTIVATIONS, where the per-token weight
+                # gather below would materialize B*T*k copies of the [D, F]
+                # expert mats — GBs per layer at warm-chunk T.  Same math
+                # (_moe_gates shared), same decode-parity semantics.
+                y = _moe_dense_block(lp["moe"], h, cfg)
+            elif cfg.num_experts:
+                # T = 1 (the decode step): gather the k selected experts'
+                # slabs per row — fewer HBM bytes than all E at small
+                # batch, which is what the bandwidth-bound loop wants.
+                router = jnp.einsum("btd,de->bte", h.astype(jnp.float32),
+                                    lp["moe"]["wg"])
+                gates, expert = _moe_gates(jax.nn.softmax(router, -1), cfg)
+                w1 = lp["moe"]["w1"][expert]          # [B, T, k, D, F]
+                w2 = lp["moe"]["w2"][expert]
+                hk = jax.nn.gelu(jnp.einsum("btd,btkdf->btkf", h,
+                                            w1.astype(dtype)))
+                yk = jnp.einsum("btkf,btkfd->btkd", hk, w2.astype(dtype))
+                y = jnp.einsum("btkd,btk->btd", yk, gates.astype(dtype))
+            else:
+                y = jnp.einsum(
+                    "btf,fd->btd",
+                    jax.nn.gelu(jnp.einsum("btd,df->btf", h,
+                                           deq(lp["ffn"]["w1"]))),
+                    deq(lp["ffn"]["w2"]))
+            x = x + y
 
     x = _rms_norm(x, params["ln_f_scale"])
-    out = unembed_logits(x, params["tok_emb"], dtype)
+    with jax.named_scope("head"):
+        out = unembed_logits(x, params["tok_emb"], dtype)
     if not uniform_pos:
-        ck_all, cv_all = jnp.stack(new_k), jnp.stack(new_v)
-        if kv_q:
-            cks_all, cvs_all = jnp.stack(new_ks), jnp.stack(new_vs)
+        with jax.named_scope("kv_slab"):
+            ck_all, cv_all = jnp.stack(new_k), jnp.stack(new_v)
+            if kv_q:
+                cks_all, cvs_all = jnp.stack(new_ks), jnp.stack(new_vs)
     cache = {"k": ck_all, "v": cv_all}
     if kv_q:
         cache["k_scale"], cache["v_scale"] = cks_all, cvs_all

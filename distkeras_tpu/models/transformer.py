@@ -333,9 +333,28 @@ def _dropout(x, rate: float, key):
     return jnp.where(mask, x / keep, 0).astype(x.dtype)
 
 
+# Sublayer scopes.  Every operation of a forward, decode or training
+# program sits in one ``jax.named_scope`` of this small vocabulary
+# (here and in models/generate.py), so that a device profile names the
+# work by what the MODEL calls it and not by what the compiler fused it
+# into; backward and rematerialised operations inherit the path.  A
+# scope is metadata only: it changes no compiled program.
+#   embed      token/position embedding, rotary angles
+#   norm       every RMSNorm
+#   attn_proj  the q/k/v/out products and the rotary rotation
+#   attn       scores, softmax, values (the flash kernels sit here)
+#   kv_slab    cutting a layer's K/V out of the cache slab, and putting
+#              it back (decode and chunked prefill)
+#   mlp        the feed-forward (or MoE) block
+#   head       unembedding and cross-entropy
+SCOPES = ("embed", "norm", "attn_proj", "attn", "kv_slab", "mlp", "head")
+
+
 def _rms_norm(x, scale, eps=1e-6):
-    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale
+    with jax.named_scope("norm"):
+        var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1,
+                       keepdims=True)
+        return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale
 
 
 def rope_angles(positions, head_dim: int, theta: float):
@@ -358,17 +377,20 @@ def rope_rotate(x, ang):
 
 def _attention_block(lp, x, attention_fn, rope_ang=None, kv_groups=1,
                      return_kv=False):
-    q = jnp.einsum("bsd,dhk->bshk", x, lp["wq"])
-    k = jnp.einsum("bsd,dhk->bshk", x, lp["wk"])
-    v = jnp.einsum("bsd,dhk->bshk", x, lp["wv"])
-    if rope_ang is not None:
-        q, k = rope_rotate(q, rope_ang), rope_rotate(k, rope_ang)
+    with jax.named_scope("attn_proj"):
+        q = jnp.einsum("bsd,dhk->bshk", x, lp["wq"])
+        k = jnp.einsum("bsd,dhk->bshk", x, lp["wk"])
+        v = jnp.einsum("bsd,dhk->bshk", x, lp["wv"])
+        if rope_ang is not None:
+            q, k = rope_rotate(q, rope_ang), rope_rotate(k, rope_ang)
     kv = (k, v)  # post-rope, pre-GQA-expansion: the decode cache layout
-    if kv_groups > 1:  # GQA: expand shared K/V heads for the kernel
-        k = jnp.repeat(k, kv_groups, axis=2)
-        v = jnp.repeat(v, kv_groups, axis=2)
-    out = attention_fn(q, k, v)
-    out = jnp.einsum("bshk,hkd->bsd", out, lp["wo"])
+    with jax.named_scope("attn"):
+        if kv_groups > 1:  # GQA: expand shared K/V heads for the kernel
+            k = jnp.repeat(k, kv_groups, axis=2)
+            v = jnp.repeat(v, kv_groups, axis=2)
+        out = attention_fn(q, k, v)
+    with jax.named_scope("attn_proj"):
+        out = jnp.einsum("bshk,hkd->bsd", out, lp["wo"])
     return (out, kv) if return_kv else out
 
 
@@ -490,24 +512,31 @@ def block_apply(layer_params, x, cfg: TransformerConfig,
     kv = None
     if return_kv:
         a, kv = a
-    if drop_key is not None:
-        a = _dropout(a, cfg.dropout, jax.random.fold_in(drop_key, 0))
-    x = x + a
+    # The residual sums (and the dropout before them) go to the
+    # sublayer whose output they take in: no operation of a block is
+    # left outside the vocabulary.
+    with jax.named_scope("attn_proj"):
+        if drop_key is not None:
+            a = _dropout(a, cfg.dropout, jax.random.fold_in(drop_key, 0))
+        x = x + a
     h = _rms_norm(x, layer_params["ln2_scale"])
-    if cfg.num_experts and moe_dense_routing:
-        y = _moe_dense_block(layer_params["moe"], h, cfg)
-        aux = jnp.zeros((), jnp.float32)
-    elif cfg.num_experts:
-        y, aux = _moe_block(layer_params["moe"], h, cfg)
-    else:
-        y = jnp.einsum(
-            "bsf,fd->bsd",
-            jax.nn.gelu(jnp.einsum("bsd,df->bsf", h, layer_params["ffn"]["w1"])),
-            layer_params["ffn"]["w2"])
-        aux = jnp.zeros((), jnp.float32)
-    if drop_key is not None:
-        y = _dropout(y, cfg.dropout, jax.random.fold_in(drop_key, 1))
-    out = x + y
+    with jax.named_scope("mlp"):
+        if cfg.num_experts and moe_dense_routing:
+            y = _moe_dense_block(layer_params["moe"], h, cfg)
+            aux = jnp.zeros((), jnp.float32)
+        elif cfg.num_experts:
+            y, aux = _moe_block(layer_params["moe"], h, cfg)
+        else:
+            y = jnp.einsum(
+                "bsf,fd->bsd",
+                jax.nn.gelu(jnp.einsum("bsd,df->bsf", h,
+                                       layer_params["ffn"]["w1"])),
+                layer_params["ffn"]["w2"])
+            aux = jnp.zeros((), jnp.float32)
+    with jax.named_scope("mlp"):
+        if drop_key is not None:
+            y = _dropout(y, cfg.dropout, jax.random.fold_in(drop_key, 1))
+        out = x + y
     return (out, aux, kv) if return_kv else (out, aux)
 
 
@@ -540,13 +569,14 @@ def apply_hidden(params, tokens, cfg: TransformerConfig,
     dtype = jnp.dtype(cfg.dtype)
     b, s = tokens.shape
     _check_len(s, cfg)
-    x = params["tok_emb"][tokens].astype(dtype)
-    rope_ang = None
-    if cfg.rope:
-        rope_ang = rope_angles(jnp.arange(s), cfg.head_dim,
-                               cfg.rope_theta)[None, :, None, :]
-    else:
-        x = x + params["pos_emb"][:s][None].astype(dtype)
+    with jax.named_scope("embed"):
+        x = params["tok_emb"][tokens].astype(dtype)
+        rope_ang = None
+        if cfg.rope:
+            rope_ang = rope_angles(jnp.arange(s), cfg.head_dim,
+                                   cfg.rope_theta)[None, :, None, :]
+        else:
+            x = x + params["pos_emb"][:s][None].astype(dtype)
     dropping = cfg.dropout > 0 and dropout_rng is not None
     if dropping:
         # fold_in index n_layers: disjoint from the per-layer keys 0..L-1.
@@ -578,9 +608,10 @@ def _unembed(hidden, params, cfg: TransformerConfig):
     materialized logits' invariant has one site to stay in sync with.
     """
     dtype = jnp.dtype(cfg.dtype)
-    logits = jnp.einsum("bsd,vd->bsv", hidden,
-                        params["tok_emb"].astype(dtype))
-    return logits.astype(jnp.float32)
+    with jax.named_scope("head"):
+        logits = jnp.einsum("bsd,vd->bsv", hidden,
+                            params["tok_emb"].astype(dtype))
+        return logits.astype(jnp.float32)
 
 
 def apply(params, tokens, cfg: TransformerConfig,
@@ -603,6 +634,7 @@ def apply(params, tokens, cfg: TransformerConfig,
     return _unembed(x, params, cfg), aux_total
 
 
+@jax.named_scope("head")
 def chunked_softmax_xent(hidden, emb, targets, n_chunks: int):
     """Mean softmax cross-entropy without materializing full logits.
     Returns ``(mean_nll, mean_lse_sq)`` — the second term is the z-loss
@@ -725,9 +757,10 @@ def apply_pipelined(params, tokens, cfg: TransformerConfig, mesh,
     dtype = jnp.dtype(cfg.dtype)
     b, s = tokens.shape
     _check_len(s, cfg)
-    x = params["tok_emb"][tokens].astype(dtype)
-    if not cfg.rope:
-        x = x + params["pos_emb"][:s][None].astype(dtype)
+    with jax.named_scope("embed"):
+        x = params["tok_emb"][tokens].astype(dtype)
+        if not cfg.rope:
+            x = x + params["pos_emb"][:s][None].astype(dtype)
 
     stage_params = jax.tree.map(
         lambda a: a.reshape(n_stages, per_stage, *a.shape[1:]),
@@ -827,6 +860,7 @@ def _forward_nll(params, tokens, cfg: TransformerConfig,
         targets = jnp.where(valid, targets, -1)
     zc = cfg.z_loss_coef
 
+    @jax.named_scope("head")
     def full_head(logits, aux):
         # z-loss rides in aux (training-only, like the MoE penalty —
         # lm_nll drops aux, so eval perplexity stays pure).

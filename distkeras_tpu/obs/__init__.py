@@ -34,7 +34,9 @@ telemetry stream must be read off one sink, not two interleaved ones).
 
 from __future__ import annotations
 
+import atexit
 import contextlib
+import sys
 
 from distkeras_tpu.obs.metrics import (Counter, Gauge, Histogram,
                                         MetricsRegistry,
@@ -53,7 +55,11 @@ class ObsSession:
 
     On close the registry snapshot is appended to the trace as its
     final ``metrics`` record, so the JSONL file alone is enough for
-    ``scripts/obs_report.py`` (latency percentiles included).
+    ``scripts/obs_report.py`` (latency percentiles included).  Trace
+    records are buffered in memory and written at close, at
+    ``sess.trace.flush()`` and past a bound of size or age
+    (:class:`EventTrace`); in a process that has imported jax every
+    span is also a ``jax.profiler.TraceAnnotation`` of the same name.
 
     **Live telemetry plane** (round 11): ``serve_port=`` starts a
     :class:`~distkeras_tpu.obs.live.TelemetryServer` on the session's
@@ -76,6 +82,14 @@ class ObsSession:
         self.registry = MetricsRegistry()
         self.trace = (EventTrace(trace_path, run_id=run_id)
                       if trace_path else None)
+        if self.trace is not None and "jax" in sys.modules:
+            # One clock: every span is also a TraceAnnotation of the
+            # same name, so a jax.profiler trace taken meanwhile holds
+            # the spans on its host plane, beside the device's
+            # operations.  A process that never imported jax (a
+            # router) has no profiler to annotate for.
+            self.trace.annotation = \
+                sys.modules["jax"].profiler.TraceAnnotation
         self.run_id = self.trace.run_id if self.trace else run_id
         self.slo = None
         self.server = None
@@ -131,6 +145,12 @@ def disable() -> None:
     sess, _ACTIVE = _ACTIVE, None
     if sess is not None:
         sess.close()
+
+
+# Trace records are buffered in memory: a process that enabled a
+# session and simply returns from main must still leave its last
+# buffer in the file.
+atexit.register(disable)
 
 
 @contextlib.contextmanager

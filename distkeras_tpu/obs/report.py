@@ -11,7 +11,10 @@ A trace JSONL (obs/trace.py) reconstructs into:
   p50/p95/p99 (obs/metrics.percentile_from_buckets);
 * **counters/gauges** — the remaining metrics series;
 * **event timeline** — point events in time order (chaos faults,
-  supervisor attempts, admission rejects...).
+  supervisor attempts, admission rejects...);
+* **serving rounds** — the ``serving.round`` spans' counts, and the
+  host's time between one decode dispatch's end and the next
+  dispatch, split by the span it was spent in.
 
 ``compare`` diffs two reports for regression triage: per-phase total /
 mean deltas, histogram percentile deltas, counter deltas — the dynamic
@@ -26,6 +29,7 @@ section.
 
 from __future__ import annotations
 
+import bisect
 import statistics
 
 from distkeras_tpu.obs.metrics import percentile_from_buckets
@@ -101,11 +105,86 @@ def build_report(records: list[dict]) -> dict:
     return {"meta": {k: meta.get(k) for k in
                      ("run", "host", "pid", "time_unix")},
             "wall_s": wall, "phases": phases, "latency": hists,
-            "scalars": scalars, "timeline": timeline}
+            "scalars": scalars, "timeline": timeline,
+            "rounds": serving_rounds(records)}
 
 
 def load_report(path: str) -> dict:
     return build_report(read_trace(path))
+
+
+# ------------------------------------------------------ serving rounds
+
+# A span that hands the device a program; between the end of a round's
+# ``serving.step`` (tokens on the host) and the start of the next of
+# these the device has nothing queued.
+_DISPATCH = ("serving.admit", "serving.admit_chunk", "serving.step")
+
+
+def _self_time(sp, lo, hi, children, out) -> float:
+    """Add to ``out`` the self time of ``sp`` and of the spans under it
+    within ``[lo, hi]`` (a span's overlap less its children's), keyed by
+    the span's name without ``serving.``; returns ``sp``'s overlap."""
+    ov = min(hi, sp["t0"] + sp["dur"]) - max(lo, sp["t0"])
+    if ov <= 0:
+        return 0.0
+    own = ov - sum(_self_time(c, lo, hi, children, out)
+                   for c in children.get(sp["id"], ()))
+    key = sp["name"].split(".", 1)[1]
+    out[key] = out.get(key, 0.0) + own
+    return ov
+
+
+def serving_rounds(records: list[dict]) -> dict | None:
+    """The ``serving.round`` spans of one thread's trace, reduced: how
+    many (and how many idle), the means of their counts over the rounds
+    that dispatched a decode step, and the **step gap** — from the end
+    of a round's ``serving.step`` to the start of the next dispatching
+    span — as a median with its mean split by whose self time it was:
+    ``emit_loop``, ``reap``, ``pump``, ``round`` (inside a round but in
+    none of its children) and ``caller`` (in no span: between two
+    ``step()`` calls).  None when the trace holds no round."""
+    spans = sorted((r for r in records if r.get("kind") == "span"
+                    and r["name"].startswith("serving.")),
+                   key=lambda r: r["t0"])
+    rounds = [sp for sp in spans if sp["name"] == "serving.round"]
+    if not rounds:
+        return None
+    live = [r for r in rounds if not r["fields"].get("idle")]
+    out = {"rounds": len(rounds), "idle": len(rounds) - len(live),
+           "mean": {k: statistics.fmean(r["fields"][k] for r in live)
+                    for k in ("lanes_busy", "lanes_admitting", "kv_live",
+                              "chunks", "tokens")} if live else {},
+           "chunks_max": max((r["fields"]["chunks"] for r in live),
+                             default=0)}
+    # One thread's spans nest, so the top-level ones are disjoint and
+    # in order: a gap is walked from the one that covers its start.
+    ids = {sp["id"] for sp in spans}
+    children: dict = {}
+    for sp in spans:
+        children.setdefault(sp["parent"], []).append(sp)
+    top = [sp for sp in spans if sp["parent"] not in ids]
+    top_starts = [sp["t0"] for sp in top]
+    dispatch = [sp for sp in spans if sp["name"] in _DISPATCH]
+    gaps, split = [], {}
+    for sp, nxt in zip(dispatch, dispatch[1:]):
+        lo, hi = sp["t0"] + sp["dur"], nxt["t0"]
+        if sp["name"] != "serving.step" or hi <= lo:
+            continue
+        covered = 0.0
+        i = max(0, bisect.bisect_right(top_starts, lo) - 1)
+        while i < len(top) and top[i]["t0"] < hi:
+            covered += _self_time(top[i], lo, hi, children, split)
+            i += 1
+        split["caller"] = split.get("caller", 0.0) + (hi - lo) - covered
+        gaps.append(hi - lo)
+    if gaps:
+        out["gap"] = {"n": len(gaps), "p50_s": statistics.median(gaps),
+                      "mean_s": statistics.fmean(gaps),
+                      "split_mean_s": {k: v / len(gaps)
+                                       for k, v in sorted(split.items())
+                                       if v > 0}}
+    return out
 
 
 # --------------------------------------------------- request waterfall
@@ -425,6 +504,23 @@ def render_report(rep: dict, max_events: int = 60) -> str:
         out.append("\n== counters / gauges ==")
         for name, v in sorted(rep["scalars"].items()):
             out.append(f"{name:<52}{v:>12g}")
+    rounds = rep.get("rounds")
+    if rounds:
+        out.append("\n== serving rounds ==")
+        out.append(f"  {rounds['rounds']} rounds, {rounds['idle']} idle; "
+                   f"most admission programs before one decode step: "
+                   f"{rounds['chunks_max']}")
+        if rounds["mean"]:
+            out.append("  mean per decoding round: " + "  ".join(
+                f"{k}={v:.4g}" for k, v in rounds["mean"].items()))
+        gap = rounds.get("gap")
+        if gap:
+            out.append(
+                f"  step gap (decode step's end -> next dispatch): "
+                f"n={gap['n']} p50={_fmt_s(gap['p50_s'])} "
+                f"mean={_fmt_s(gap['mean_s'])} = " + " + ".join(
+                    f"{k} {_fmt_s(v)}"
+                    for k, v in gap["split_mean_s"].items()))
     if rep["timeline"]:
         out.append("\n== event timeline ==")
         shown = rep["timeline"][:max_events]

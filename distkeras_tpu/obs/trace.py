@@ -10,7 +10,12 @@ reconstructed — and *diffed* — offline (scripts/obs_report.py).
 Record kinds:
 
 ``meta``   — first line: run id, host/pid, unix wall time anchor (maps
-             monotonic ``t`` to wall clock), platform.
+             monotonic ``t`` to wall clock), and the clock anchor
+             ``perf_counter_ns`` / ``time_ns`` read back to back: the
+             pair that places every ``t`` / ``t0`` of this file on the
+             wall clock to the nanosecond — and so beside a
+             ``jax.profiler`` trace, whose host plane also carries
+             every span (see ``annotation`` below).
 ``event``  — a point in time: ``{"kind": "event", "name", "t",
              "fields": {...}}`` (chaos faults, supervisor attempts,
              admission rejects).
@@ -20,17 +25,26 @@ Record kinds:
              is the enclosing span's id (None at top level), so the
              tree reconstructs without begin/end pairing.
 ``metrics``— a full registry snapshot (the obs session appends one on
-             close), so a trace file is self-contained for reports.
+             close), so a trace file is self-contained for reports; it
+             repeats the clock anchor, so the two clocks' drift over
+             the run is the difference of the two pairs.
 
-Thread safety: one lock around the file write; span stacks are
-thread-local.  Writes are ``json.dumps`` + one ``write`` per record —
-cheap enough for per-round/per-request cadence (the hot *inner* loops
-record through the metrics registry, not the trace).
+**Records stay in memory.**  Recording a span or an event appends one
+dict to a list; nothing is serialised on the recording thread until
+:meth:`EventTrace.flush`, :meth:`EventTrace.close`, or the buffer
+passing ``FLUSH_RECORDS`` records or ``FLUSH_AGE_S`` seconds (checked
+when a record arrives — no thread).  So the file, and with it
+``/trace/tail``, lags the program by at most that much while records
+keep arriving, and a crash loses at most one buffer; what reached the
+file is whole lines, a parseable prefix.
+
+Thread safety: one lock around the buffer and the file; span stacks
+are thread-local.
 """
 
 from __future__ import annotations
 
-import contextlib
+import itertools
 import json
 import os
 import threading
@@ -54,29 +68,72 @@ def _host_index() -> int:
     return 0
 
 
+def _clock_anchor() -> dict:
+    """The monotonic clock of every ``t`` / ``t0`` and the wall clock,
+    read back to back (sub-microsecond apart)."""
+    return {"perf_counter_ns": time.perf_counter_ns(),
+            "time_ns": time.time_ns()}
+
+
 class Span:
-    """Handle yielded by :meth:`EventTrace.span` — carries the ids and
-    accepts late fields (``span.fields["x"] = ...`` before exit)."""
+    """The context manager :meth:`EventTrace.span` returns, and the
+    handle its ``with`` yields — carries the ids and accepts late
+    fields (``span.fields["x"] = ...`` before exit)."""
 
-    __slots__ = ("name", "id", "parent", "depth", "t0", "fields")
+    __slots__ = ("name", "id", "parent", "depth", "t0", "fields",
+                 "_trace", "_ann")
 
-    def __init__(self, name, id, parent, depth, t0, fields):
+    def __init__(self, trace, name, fields):
+        self._trace = trace
         self.name = name
-        self.id = id
-        self.parent = parent
-        self.depth = depth
-        self.t0 = t0
         self.fields = fields
+
+    def __enter__(self):
+        tr = self._trace
+        st = tr._stack()
+        self.parent = st[-1].id if st else None
+        self.depth = len(st)
+        self.id = next(tr._ids)
+        st.append(self)
+        ann = tr.annotation
+        self._ann = ann(self.name) if ann is not None else None
+        if self._ann is not None:
+            self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self._trace._stack().pop()
+        self._trace._write({"kind": "span", "name": self.name,
+                            "t0": self.t0, "dur": t1 - self.t0,
+                            "id": self.id, "parent": self.parent,
+                            "depth": self.depth, "fields": self.fields},
+                           t1)
 
 
 class EventTrace:
     """JSONL trace writer (see module docstring for the record model).
 
     ``path``: output file (parent dirs created).  ``run_id`` defaults
-    to a fresh ``uuid4`` hex prefix.  Close (or use as a context
-    manager) to flush; the file is line-buffered in between so a
-    crashed run still leaves a parseable prefix.
+    to a fresh ``uuid4`` hex prefix.  Records are buffered in memory
+    and written by :meth:`flush`, by :meth:`close` (or leaving the
+    ``with``), and whenever a record arrives to a buffer of
+    ``FLUSH_RECORDS`` records or one older than ``FLUSH_AGE_S``: a
+    crash loses at most that one buffer, and the file always holds
+    whole lines.
+
+    ``annotation``: ``name -> context manager`` entered around every
+    span, or None.  The obs session sets it to
+    ``jax.profiler.TraceAnnotation``, which puts each span on the
+    profiler's host plane under the same name — this module itself
+    never imports jax.
     """
+
+    FLUSH_RECORDS = 4096
+    FLUSH_AGE_S = 1.0
 
     def __init__(self, path: str, run_id: str | None = None):
         self.path = os.path.abspath(path)
@@ -88,32 +145,50 @@ class EventTrace:
         # Reusing a path across runs must not blend two runs' records
         # — their monotonic clocks have different epochs, so a merged
         # file would report meaningless relative times.
-        self._f = open(self.path, "w", buffering=1, encoding="utf-8")
-        # Leaf lock: guards the file handle only (one write per
-        # record); span stacks are thread-local, not locked.
+        self._f = open(self.path, "w", encoding="utf-8")
+        # Leaf lock: guards the buffer and the file handle; span
+        # stacks are thread-local, not locked.
         self._lock = TracedLock("obs.trace")
         self._tls = threading.local()
-        self._next_id = 0
+        self._ids = itertools.count(1)   # next() is atomic in CPython
+        self._buf: list[dict] = []
+        self._buf_t0 = 0.0               # arrival of the oldest record
+        self.annotation = None
         self.host = _host_index()
         self.pid = os.getpid()
+        now = time.perf_counter()
         self._write({"kind": "meta", "run": self.run_id,
                      "host": self.host, "pid": self.pid,
-                     "t": time.perf_counter(),
-                     "time_unix": time.time()})
+                     "t": now, "time_unix": time.time(),
+                     **_clock_anchor()}, now)
+        self.flush()    # the file names its run from the first instant
 
     # ------------------------------------------------------------ write
 
-    def _write(self, rec: dict) -> None:
-        line = json.dumps(rec, default=str)
+    def _write(self, rec: dict, now: float) -> None:
+        """Buffer one record (``now``: the ``perf_counter`` reading the
+        caller already took); past the size or age bound, write the
+        buffer out first."""
         with self._lock:
-            if self._f.closed:
-                return
-            self._f.write(line + "\n")
+            buf = self._buf
+            if not buf:
+                self._buf_t0 = now
+            buf.append(rec)
+            if (len(buf) >= self.FLUSH_RECORDS
+                    or now - self._buf_t0 >= self.FLUSH_AGE_S):
+                self._flush_locked()
 
-    def _alloc_id(self) -> int:
+    def _flush_locked(self) -> None:
+        buf, self._buf = self._buf, []
+        if buf and not self._f.closed:
+            self._f.write("".join(
+                json.dumps(rec, default=str) + "\n" for rec in buf))
+            self._f.flush()
+
+    def flush(self) -> None:
+        """Serialise and write what is buffered (and flush the file)."""
         with self._lock:
-            self._next_id += 1
-            return self._next_id
+            self._flush_locked()
 
     def _stack(self) -> list:
         st = getattr(self._tls, "stack", None)
@@ -126,38 +201,27 @@ class EventTrace:
     def event(self, name: str, **fields) -> None:
         """Record a point event now."""
         st = self._stack()
-        self._write({"kind": "event", "name": name,
-                     "t": time.perf_counter(),
+        now = time.perf_counter()
+        self._write({"kind": "event", "name": name, "t": now,
                      "span": st[-1].id if st else None,
-                     "fields": fields})
+                     "fields": fields}, now)
 
-    @contextlib.contextmanager
-    def span(self, name: str, **fields):
-        """Record a closed interval around the block; nests per
-        thread.  The record is written at exit (one line per span)."""
-        st = self._stack()
-        parent = st[-1].id if st else None
-        sp = Span(name=name, id=self._alloc_id(), parent=parent,
-                  depth=len(st), t0=time.perf_counter(), fields=fields)
-        st.append(sp)
-        try:
-            yield sp
-        finally:
-            st.pop()
-            self._write({"kind": "span", "name": name, "t0": sp.t0,
-                         "dur": time.perf_counter() - sp.t0,
-                         "id": sp.id, "parent": sp.parent,
-                         "depth": sp.depth, "fields": sp.fields})
+    def span(self, name: str, **fields) -> Span:
+        """Record a closed interval around the ``with`` block; nests
+        per thread.  The record is made at exit (one line per span)."""
+        return Span(self, name, fields)
 
     def metrics(self, snapshot: dict) -> None:
-        """Append a full metrics-registry snapshot record."""
-        self._write({"kind": "metrics", "t": time.perf_counter(),
-                     "data": snapshot})
+        """Append a full metrics-registry snapshot record (with the
+        clock anchor read again: the drift since ``meta``)."""
+        now = time.perf_counter()
+        self._write({"kind": "metrics", "t": now, **_clock_anchor(),
+                     "data": snapshot}, now)
 
     def close(self) -> None:
         with self._lock:
             if not self._f.closed:
-                self._f.flush()
+                self._flush_locked()
                 self._f.close()
 
     def __enter__(self):

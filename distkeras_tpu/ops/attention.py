@@ -501,6 +501,7 @@ def _flash_fwd_local(q, k, v, segment_ids=None, *, causal, scale, block_q,
 
     def call(): return pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=(b * h, lq // block_q, inner),
         in_specs=in_specs,
         out_specs=(o_spec, lse_spec) if with_lse else o_spec,
@@ -712,6 +713,7 @@ def _flash_bwd_local(q, k, v, out, lse, g, segment_ids=None, *, causal,
             functools.partial(_flash_bwd_dq_kernel, causal=causal,
                               scale=scale, window=window,
                               segmented=segmented),
+            name="flash_bwd_dq",
             grid=(b * h, lq // block_q, dq_inner),
             in_specs=in_specs,
             out_specs=vspec(q_at),
@@ -750,6 +752,7 @@ def _flash_bwd_local(q, k, v, out, lse, g, segment_ids=None, *, causal,
             functools.partial(_flash_bwd_dkv_kernel, causal=causal,
                               scale=scale, window=window,
                               n_qb_total=n_qb, segmented=segmented),
+            name="flash_bwd_dkv",
             grid=(b * h, lk // block_k, dkv_inner),
             in_specs=in_specs,
             out_specs=(vspec(kv_at), vspec(kv_at)),

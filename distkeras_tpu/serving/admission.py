@@ -359,46 +359,47 @@ class _AdmissionMixin:
 
     def _pump_locked(self) -> list[int]:
         admitted = []
-        while self._pending:
-            pend = self._pending[0]
-            if (pend.deadline is not None
-                    and pend.deadline <= self._clock()):
+        with obs.span("serving.pump"):
+            while self._pending:
+                pend = self._pending[0]
+                if (pend.deadline is not None
+                        and pend.deadline <= self._clock()):
+                    self._pending.popleft()
+                    self._finish(pend.request_id, pend.prompt, "timeout",
+                                 pend.prompt.size, born=pend.born)
+                    continue
+                if not self.free_lanes():
+                    break
                 self._pending.popleft()
-                self._finish(pend.request_id, pend.prompt, "timeout",
-                             pend.prompt.size, born=pend.born)
-                continue
-            if not self.free_lanes():
-                break
-            self._pending.popleft()
-            try:
-                ok = self._admit_pending(pend)
-            except Exception as e:  # noqa: BLE001 — deferred validation
-                # Engine-specific validation that enqueue() could not
-                # run up front (e.g. the key-iff-sampling rule, or a
-                # pooled prefix evicted while queued) fails at
-                # admission: the request must still reach a terminal
-                # structured result, not crash the decode loop.
-                self._finish(pend.request_id, pend.prompt, "error",
-                             pend.prompt.size, error=str(e),
-                             born=pend.born)
-                continue
-            if ok:
-                admitted.append(pend.request_id)
-            elif self._decline_reason == "kv_blocks":
-                # Allocator exhausted (paged engine): the request
-                # stays at the queue HEAD — blocks free as running
-                # lanes drain, and FIFO order must hold.
-                self._pending.appendleft(pend)
-                break
-            else:
-                # Free lane + declined admission == the deadline
-                # expired between pump's check and submit's re-check.
-                self._finish(pend.request_id, pend.prompt, "timeout",
-                             pend.prompt.size, born=pend.born)
-        # Unconditionally: expired-head drops shrink the queue without
-        # admitting anything, and the gauge must not report phantom
-        # backlog (no-op when telemetry is disabled).
-        obs.gauge("serving.queue_depth", len(self._pending))
+                try:
+                    ok = self._admit_pending(pend)
+                except Exception as e:  # noqa: BLE001 — deferred validation
+                    # Engine-specific validation that enqueue() could not
+                    # run up front (e.g. the key-iff-sampling rule, or a
+                    # pooled prefix evicted while queued) fails at
+                    # admission: the request must still reach a terminal
+                    # structured result, not crash the decode loop.
+                    self._finish(pend.request_id, pend.prompt, "error",
+                                 pend.prompt.size, error=str(e),
+                                 born=pend.born)
+                    continue
+                if ok:
+                    admitted.append(pend.request_id)
+                elif self._decline_reason == "kv_blocks":
+                    # Allocator exhausted (paged engine): the request
+                    # stays at the queue HEAD — blocks free as running
+                    # lanes drain, and FIFO order must hold.
+                    self._pending.appendleft(pend)
+                    break
+                else:
+                    # Free lane + declined admission == the deadline
+                    # expired between pump's check and submit's re-check.
+                    self._finish(pend.request_id, pend.prompt, "timeout",
+                                 pend.prompt.size, born=pend.born)
+            # Unconditionally: expired-head drops shrink the queue without
+            # admitting anything, and the gauge must not report phantom
+            # backlog (no-op when telemetry is disabled).
+            obs.gauge("serving.queue_depth", len(self._pending))
         return admitted
 
     def _reap(self) -> None:
@@ -406,23 +407,24 @@ class _AdmissionMixin:
         evict deadline-expired running lanes (structured timeout with
         the partial transcript).  Evicted/collected lanes free
         immediately — the next pump()/submit() reuses them."""
-        now = None
-        for lane, st in enumerate(self._lane_state):
-            if st is None:
-                continue
-            if st.done:
-                if st.managed:
-                    self._finish(st.request_id, st.tokens, "ok",
-                                 st.prompt_len, born=st.born)
-                    self._vacate(lane)
-                continue
-            if st.deadline is not None:
-                if now is None:
-                    now = self._clock()
-                if st.deadline <= now:
-                    self._finish(st.request_id, st.tokens, "timeout",
-                                 st.prompt_len, born=st.born)
-                    self._vacate(lane)
+        with obs.span("serving.reap"):
+            now = None
+            for lane, st in enumerate(self._lane_state):
+                if st is None:
+                    continue
+                if st.done:
+                    if st.managed:
+                        self._finish(st.request_id, st.tokens, "ok",
+                                     st.prompt_len, born=st.born)
+                        self._vacate(lane)
+                    continue
+                if st.deadline is not None:
+                    if now is None:
+                        now = self._clock()
+                    if st.deadline <= now:
+                        self._finish(st.request_id, st.tokens, "timeout",
+                                     st.prompt_len, born=st.born)
+                        self._vacate(lane)
 
     # ------------------------------------------------------- results
 

@@ -86,9 +86,14 @@ def _make_lane_admit(model_params, model_cfg, prefix_lane=None,
     def _admit(params, cache, rows, lane, off, *pool):
         if constrain is not None:
             cache = constrain(cache)
-        lane_cache = jax.tree.map(
-            lambda a: jax.lax.dynamic_slice_in_dim(a, lane, 1, axis=1),
-            cache)
+        # "kv_slab": the lane cut out of the slab here and put back
+        # below is the same copying the model's scope of that name
+        # holds (models/transformer.py SCOPES).
+        with jax.named_scope("kv_slab"):
+            lane_cache = jax.tree.map(
+                lambda a: jax.lax.dynamic_slice_in_dim(a, lane, 1,
+                                                       axis=1),
+                cache)
         if seed:
             if pooled:
                 slab, slot = pool
@@ -117,9 +122,10 @@ def _make_lane_admit(model_params, model_cfg, prefix_lane=None,
             params, lane_cache, rows,
             jnp.reshape(off, (1,)).astype(jnp.int32), model_cfg,
             uniform_pos=True)
-        out = jax.tree.map(
-            lambda a, u: jax.lax.dynamic_update_slice_in_dim(
-                a, u, lane, axis=1), cache, lane_cache)
+        with jax.named_scope("kv_slab"):
+            out = jax.tree.map(
+                lambda a, u: jax.lax.dynamic_update_slice_in_dim(
+                    a, u, lane, axis=1), cache, lane_cache)
         return constrain(out) if constrain is not None else out
 
     if take_params:
@@ -182,6 +188,12 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
     # unconditionally).
     _hot_swap = False
     param_version = 0
+
+    # Admission programs dispatched since the last decode dispatch —
+    # first chunks (``_submit_locked``) and continuation chunks
+    # (``_run_pending_chunk``), whoever asked for them; ``step()``
+    # reports it as the round's ``chunks`` and zeroes it there.
+    _admit_programs = 0
 
     def _pargs(self) -> tuple:
         """The params-argument prefix of every compiled-program call:
@@ -432,39 +444,71 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
         ``serving.tpot_s`` (inter-token gap per emitted token), plus
         one ``serving.emit`` trace event per emitting lane carrying
         its ``request_id`` — the decode leg of the request waterfall
-        (``scripts/obs_report.py --request``).  Lanes still ADMITTING
+        (``scripts/obs_report.py --request``).  The whole loop is one
+        ``serving.emit_loop`` span.  Lanes still ADMITTING
         (pending prefill chunks) are parked: their decode rows are
         burnt compute, never emission."""
         out = {}
-        active = obs.active() is not None
-        now = self._clock() if active else None
-        for lane, st in enumerate(self._lane_state):
-            if st is None or st.done or st.chunks is not None:
-                continue
-            emitted = []
-            for tok in lane_tokens(lane):
-                st.tokens.append(int(tok))
-                emitted.append(int(tok))
-                budget = len(st.tokens) - st.prompt_len >= st.max_new
-                if budget or (st.eos is not None and tok == st.eos):
-                    st.done = True
-                    break
-            out[lane] = emitted
-            if active and emitted:
-                first = (len(st.tokens) - st.prompt_len
-                         == len(emitted))
-                if first and st.born is not None:
-                    obs.observe("serving.ttft_s", now - st.born)
-                elif st.last_emit is not None:
-                    obs.observe("serving.tpot_s",
-                                (now - st.last_emit) / len(emitted))
-                st.last_emit = now
-                obs.event("serving.emit", request_id=st.request_id,
-                          lane=lane, n=len(emitted), first=first)
-        if active:
-            obs.count("serving.tokens",
-                      sum(len(v) for v in out.values()))
+        with obs.span("serving.emit_loop"):
+            active = obs.active() is not None
+            now = self._clock() if active else None
+            for lane, st in enumerate(self._lane_state):
+                if st is None or st.done or st.chunks is not None:
+                    continue
+                emitted = []
+                for tok in lane_tokens(lane):
+                    st.tokens.append(int(tok))
+                    emitted.append(int(tok))
+                    budget = len(st.tokens) - st.prompt_len >= st.max_new
+                    if budget or (st.eos is not None and tok == st.eos):
+                        st.done = True
+                        break
+                out[lane] = emitted
+                if active and emitted:
+                    first = (len(st.tokens) - st.prompt_len
+                             == len(emitted))
+                    if first and st.born is not None:
+                        obs.observe("serving.ttft_s", now - st.born)
+                    elif st.last_emit is not None:
+                        obs.observe("serving.tpot_s",
+                                    (now - st.last_emit) / len(emitted))
+                    st.last_emit = now
+                    obs.event("serving.emit", request_id=st.request_id,
+                              lane=lane, n=len(emitted), first=first)
+            if active:
+                obs.count("serving.tokens",
+                          sum(len(v) for v in out.values()))
         return out
+
+    def _close_round(self, rnd, out, chunks: int, idle: bool) -> None:
+        """The counts of one ``step()``, taken where the round ends
+        (session active only): ONE pass over the lane table, set as
+        the ``serving.lanes_busy`` gauge and written into the closing
+        ``serving.round`` span ``rnd`` (None without a trace file).
+
+        ``kv_live`` is positions written and live: a decoding lane
+        holds its prefix and its transcript but for the last token
+        (the next step's input), an admitting lane what lies before
+        its next chunk.  ``chunks`` is the admission programs
+        dispatched since the previous decode dispatch."""
+        busy = admitting = kv_live = 0
+        for st in self._lane_state:
+            if st is None or st.done:
+                continue
+            busy += 1
+            if st.chunks is not None:
+                admitting += 1
+                kv_live += st.chunks[0][0]
+            else:
+                kv_live += st.off + len(st.tokens) - 1
+        obs.gauge("serving.lanes_busy", busy)
+        if rnd is not None:
+            rnd.fields.update(
+                lanes_busy=busy, lanes_admitting=admitting,
+                kv_live=kv_live, chunks=chunks,
+                tokens=sum(len(v) for v in out.values()))
+            if idle:
+                rnd.fields["idle"] = True
 
     # --------------------------------------------- chunked admission
 
@@ -486,6 +530,7 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
                       remaining=len(st.chunks),
                       request_id=st.request_id):
             self._exec_chunk(lane, start, rows)
+        self._admit_programs += 1
         if not st.chunks:
             self._admitting.popleft()
             st.chunks = None

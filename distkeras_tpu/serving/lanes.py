@@ -1065,6 +1065,7 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
                               chunks=len(plan), lane=lane,
                               request_id=rid):
                     self._exec_admit(lane, start0, rows, slot)
+                self._admit_programs += 1
                 if len(plan) > 1:
                     chunks = [(s, self._chunk_rows(prompt, off, s, w))
                               for s, w in plan[1:]]
@@ -1164,8 +1165,17 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
 
         Chunked prefill runs here too: at most ONE pending admission
         chunk executes per call (FIFO across parked lanes) before the
-        decode dispatch, so a long prompt admitting never inserts more
-        than one chunk's compute between any two decode rounds.
+        decode dispatch.  A request admitted since the last decode
+        dispatch (by ``enqueue``, by the caller's ``pump()`` or by this
+        call's) has run its FIRST chunk too, so two chunks can stand
+        between two decode rounds: the round's ``chunks`` field counts
+        them.
+
+        With a telemetry session active the call is one
+        ``serving.round`` span — children ``serving.pump``,
+        ``serving.admit`` / ``serving.admit_chunk``, ``serving.step``,
+        ``serving.emit_loop``, ``serving.reap`` — closed with the
+        round's counts (:meth:`_close_round`; docs/observability.md).
 
         Runs under the engine lock end to end: a concurrent
         ``enqueue`` can trigger a tier resize (scale-up), and the
@@ -1180,7 +1190,7 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
                 f"step({n}) is not in step_windows={self._step_windows}"
                 " — declare it at construction (a lazy compile here "
                 "would break the no-recompile contract across tiers)")
-        with self._admission_lock:
+        with self._admission_lock, obs.span("serving.round") as rnd:
             self.pump()
             # Tier hysteresis BEFORE the idle early-out: an idle
             # elastic engine must still step its lane count back down.
@@ -1192,20 +1202,23 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
             # decode window.  Reap first: a parked (admitting) lane
             # whose deadline expired must still be evicted promptly,
             # not only once decode resumes.
-            if all(s is None or s.done or s.chunks is not None
-                   for s in self._lane_state):
-                self._reap()
-                return {}
-            chaos.probe("serving.step")
-            if obs.active() is not None:  # running() is O(lanes)
-                obs.gauge("serving.lanes_busy", len(self.running()))
-            with obs.span("serving.step", n=n):
-                toks = self._dispatch_step(n)
-            out = self._emit(lambda lane: toks[lane].tolist())
+            idle = all(s is None or s.done or s.chunks is not None
+                       for s in self._lane_state)
+            chunks = self._admit_programs
+            if idle:
+                out = {}
+            else:
+                chaos.probe("serving.step")
+                self._admit_programs = 0
+                with obs.span("serving.step", n=n):
+                    toks = self._dispatch_step(n)
+                out = self._emit(lambda lane: toks[lane].tolist())
             # Deadline granularity is one step window: tokens emitted
             # in the window that straddles the deadline are kept in
             # the partial result.
             self._reap()
+            if obs.active() is not None:
+                self._close_round(rnd, out, chunks, idle)
             return out
 
     def _dispatch_step(self, n: int):

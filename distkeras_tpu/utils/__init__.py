@@ -3,7 +3,7 @@ from distkeras_tpu.utils.serialization import (
     deserialize_keras_model,
 )
 from distkeras_tpu.utils.misc import to_dense_vector, uniform_weights
-from distkeras_tpu.utils.profiling import StepTimer, annotate, trace
+from distkeras_tpu.utils.profiling import StepTimer, trace
 
 __all__ = [
     "serialize_keras_model",
@@ -11,6 +11,5 @@ __all__ = [
     "to_dense_vector",
     "uniform_weights",
     "StepTimer",
-    "annotate",
     "trace",
 ]

@@ -10,8 +10,10 @@ whatever the Spark UI shows.  Here:
 * :class:`StepTimer` — cheap per-step wall-clock stats with correct
   device synchronization at the measurement boundaries only (never
   inside the loop, which would stall the TPU pipeline).
-* :func:`annotate` — named region that shows up on the profile
-  timeline (``jax.profiler.TraceAnnotation``).
+
+Named regions on the profile timeline are ``obs.span``: with a
+telemetry session active every span is also a
+``jax.profiler.TraceAnnotation`` of the same name (docs/observability.md).
 """
 
 from __future__ import annotations
@@ -37,11 +39,6 @@ def trace(logdir: str):
         yield
     finally:
         jax.profiler.stop_trace()
-
-
-def annotate(name: str):
-    """Named region on the profiler timeline (usable as ctx or decorator)."""
-    return jax.profiler.TraceAnnotation(name)
 
 
 class StepTimer:

@@ -113,6 +113,40 @@ def test_span_nesting_jsonl_roundtrip(tmp_path):
     assert read_trace(path) == recs
 
 
+def test_records_stay_in_memory_until_flush_close_or_the_bound(
+        tmp_path, monkeypatch):
+    """Recording serialises nothing: the file grows at flush(), at
+    close() and when a record arrives to a buffer past its bound — a
+    crash loses at most that buffer, and what is in the file is whole
+    lines."""
+    kinds = lambda p: [r["kind"] for r in read_trace(p)]
+    path = str(tmp_path / "b.jsonl")
+    tr = EventTrace(path)
+    assert kinds(path) == ["meta"]            # the run is named at once
+    with tr.span("s"):
+        tr.event("e")
+    assert kinds(path) == ["meta"]
+    tr.flush()
+    assert kinds(path) == ["meta", "event", "span"]
+    tr.event("late")
+    tr.close()
+    assert kinds(path)[-1] == "event"
+    tr.event("after close")                   # dropped, not an error
+    tr.flush()
+    assert len(kinds(path)) == 4
+
+    monkeypatch.setattr(EventTrace, "FLUSH_RECORDS", 3)
+    path = str(tmp_path / "c.jsonl")
+    with EventTrace(path) as tr:
+        tr.event("a")
+        tr.event("b")
+        assert kinds(path) == ["meta"]
+        tr.event("c")                         # the third record
+        assert len(kinds(path)) == 4
+        with open(path) as f:
+            assert f.read().endswith("\n")
+
+
 def test_session_singleton_and_final_metrics_record(tmp_path):
     path = str(tmp_path / "s.jsonl")
     with obs.session(trace_path=path) as sess:
@@ -142,6 +176,35 @@ def test_noop_mode_writes_nothing(tmp_path):
     assert set(os.listdir(tmp_path)) == before
     # The disabled span is one shared null context: no allocation.
     assert obs.span("x") is obs.span("y")
+
+
+def test_disabled_serving_round_is_free(tmp_path, monkeypatch):
+    """A serving round with telemetry off: every span of the round is
+    the one shared null context, the O(lanes) pass that fills the
+    round's counts never runs, and no file appears."""
+    from distkeras_tpu.serving.engine import _LaneEngine
+
+    assert obs.active() is None
+    null = obs.span("serving.round")
+    assert all(obs.span(n) is null for n in (
+        "serving.pump", "serving.step", "serving.emit_loop",
+        "serving.reap", "serving.admit_chunk"))
+
+    def never(*a, **k):
+        raise AssertionError("round counts taken with telemetry off")
+
+    monkeypatch.setattr(_LaneEngine, "_close_round", never)
+    monkeypatch.chdir(tmp_path)
+    params = tfm.init_params(jax.random.key(0), CFG)
+    eng = dk.ContinuousBatcher(params, CFG, lanes=2, max_queue=2,
+                               prompt_buckets=(8,))
+    rid = eng.enqueue(np.arange(5), 3)
+    assert eng.step() and eng.step() and eng.step()
+    assert eng.take(rid).ok and eng.step() == {}
+    # The counter behind the round's ``chunks`` still follows the
+    # dispatches: a session enabled later starts from a true count.
+    assert eng._admit_programs == 0
+    assert os.listdir(tmp_path) == []
 
 
 def test_no_host_callbacks_in_jit_with_obs_enabled(tmp_path):

@@ -28,7 +28,7 @@ from distkeras_tpu.obs.metrics import (MetricsRegistry, prom_name,
                                        windowed_percentiles)
 from distkeras_tpu.obs.report import render_waterfall, request_waterfall
 from distkeras_tpu.obs.slo import SloEngine, SloRule
-from distkeras_tpu.obs.trace import read_trace, tail_trace
+from distkeras_tpu.obs.trace import EventTrace, read_trace, tail_trace
 from distkeras_tpu.resilience.health import write_beat
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -328,11 +328,13 @@ def test_server_endpoints_and_trace_tail(tmp_path):
             fams["x_hits"]["samples"]
         snap = json.loads(_get(url + "/snapshot.json"))
         assert snap["x.hits"]["series"][0]["value"] == 2
-        # /trace/tail?n= — last N records, newest last.
-        lines = _get(url + "/trace/tail?n=3").splitlines()
-        recs = [json.loads(l) for l in lines]
-        assert len(recs) == 3
-        assert [r["fields"]["i"] for r in recs] == [5, 6, 7]
+        # /trace/tail?n= — last N records, newest last.  Records sit
+        # in memory until a flush: only ``meta`` has reached the file.
+        tail = lambda: [json.loads(l) for l in _get(
+            url + "/trace/tail?n=3").splitlines()]
+        assert [r["kind"] for r in tail()] == ["meta"]
+        sess.trace.flush()
+        assert [r["fields"]["i"] for r in tail()] == [5, 6, 7]
         # Unknown endpoint -> 404.
         with pytest.raises(urllib.error.HTTPError) as ei:
             _get(url + "/nope")
@@ -340,6 +342,32 @@ def test_server_endpoints_and_trace_tail(tmp_path):
     # Session close stops the server.
     with pytest.raises(Exception):
         _get(url + "/metrics", timeout=2)
+
+
+def test_trace_tail_lags_by_at_most_the_buffer_bound(tmp_path,
+                                                     monkeypatch):
+    """No flush is asked for: the tail catches up when a record
+    arrives to a buffer older than FLUSH_AGE_S, or FLUSH_RECORDS
+    long."""
+    monkeypatch.setattr(EventTrace, "FLUSH_AGE_S", 0.05)
+    path = str(tmp_path / "t.jsonl")
+    with obs.session(trace_path=path, serve_port=0) as sess:
+        tail = lambda: [r["fields"]["i"] for r in map(json.loads, _get(
+            sess.server.url + "/trace/tail?n=100").splitlines())
+            if r["kind"] == "event"]
+        obs.event("marker", i=0)
+        obs.event("marker", i=1)
+        assert tail() == []                    # inside the bound
+        time.sleep(0.06)
+        obs.event("marker", i=2)               # the buffer is now old
+        assert tail() == [0, 1, 2]
+        monkeypatch.setattr(EventTrace, "FLUSH_AGE_S", 3600.0)
+        monkeypatch.setattr(EventTrace, "FLUSH_RECORDS", 4)
+        for i in range(3, 6):
+            obs.event("marker", i=i)
+        assert tail() == [0, 1, 2]
+        obs.event("marker", i=6)               # the fourth record
+        assert tail() == [0, 1, 2, 3, 4, 5, 6]
 
 
 def test_healthz_flips_with_heartbeat_freshness(tmp_path):

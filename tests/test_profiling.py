@@ -6,7 +6,9 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from distkeras_tpu.utils.profiling import StepTimer, annotate, trace
+from distkeras_tpu import obs
+from distkeras_tpu.obs import read_trace
+from distkeras_tpu.utils.profiling import StepTimer, trace
 
 
 def test_step_timer_rounds():
@@ -106,10 +108,42 @@ def test_trainer_populates_phase_counters():
 
 
 def test_trace_writes_profile(tmp_path):
+    """A profile holds the program's spans on its host plane: with a
+    session active ``obs.span`` is also a TraceAnnotation of the same
+    name, and the clock anchor of the trace's ``meta`` record places
+    the span's ``t0`` on the profiler's clock."""
+    from jax.profiler import ProfileData
+
     logdir = str(tmp_path / "prof")
-    with trace(logdir):
-        with annotate("matmul_region"):
-            y = jax.jit(lambda a: a @ a)(jnp.ones((64, 64)))
-            jax.block_until_ready(y)
+    path = str(tmp_path / "obs.jsonl")
+    step = jax.jit(lambda a: a @ a)
+    jax.block_until_ready(step(jnp.ones((64, 64))))
+    with obs.session(trace_path=path), trace(logdir):
+        for i in range(3):
+            with obs.span("matmul_region", i=i):
+                jax.block_until_ready(step(jnp.ones((64, 64))))
     files = glob.glob(logdir + "/**/*", recursive=True)
     assert any("trace" in f or "xplane" in f for f in files), files
+
+    recs = read_trace(path)
+    meta, closing = recs[0], recs[-1]
+    spans = [r for r in recs if r["kind"] == "span"]
+    assert [s["fields"]["i"] for s in spans] == [0, 1, 2]
+    # Both records carry the two clocks read back to back; the drift
+    # between them over this short run is far under a millisecond.
+    assert closing["kind"] == "metrics"
+    to_wall = meta["time_ns"] - meta["perf_counter_ns"]
+    assert abs(closing["time_ns"] - closing["perf_counter_ns"]
+               - to_wall) < 1_000_000
+
+    (pb,) = glob.glob(logdir + "/**/*.xplane.pb", recursive=True)
+    planes = {p.name: p for p in ProfileData.from_file(pb).planes}
+    # Event times count from the profile's start, a wall-clock stat of
+    # the "Task Environment" plane.
+    start = dict(planes["Task Environment"].stats)["profile_start_time"]
+    found = sorted(int(e.start_ns) for line in planes["/host:CPU"].lines
+                   for e in line.events if e.name == "matmul_region")
+    assert len(found) == 3
+    for sp, at in zip(spans, found):
+        anchored = int(sp["t0"] * 1e9) + to_wall - start
+        assert abs(anchored - at) < 1_000_000, (anchored, at)

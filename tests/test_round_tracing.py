@@ -1,0 +1,258 @@
+"""The names the program gives its own work (docs/observability.md,
+"A serving round" and "Names on the device timeline"): the span tree
+and the counts of one ``ContinuousBatcher.step()``, the three Pallas
+kernels' names, the sublayer scopes in the operation metadata of the
+lowered programs, and the program names the benchmark's readers match.
+"""
+
+import json
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+import distkeras_tpu as dk
+from distkeras_tpu import obs
+from distkeras_tpu.models import transformer as tfm
+from distkeras_tpu.obs import read_trace
+from distkeras_tpu.parallel.mesh import MeshSpec, make_mesh
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CFG = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
+                            n_layers=2, d_ff=64, max_len=64)
+ROUND_CHILDREN = ("serving.pump", "serving.admit_chunk", "serving.step",
+                  "serving.emit_loop", "serving.reap")
+
+
+@pytest.fixture(scope="module")
+def rounds(tmp_path_factory):
+    """A scripted run: B (5 tokens) decodes while A (21 tokens, three
+    chunks of 8) is admitted and prefilled between B's decode steps.
+    Returns the ``serving.round`` spans in order and every span."""
+    path = str(tmp_path_factory.mktemp("rounds") / "t.jsonl")
+    params = tfm.init_params(jax.random.key(0), CFG)
+    with obs.session(trace_path=path):
+        eng = dk.ContinuousBatcher(params, CFG, lanes=2, max_queue=4,
+                                   prefill_chunk=8, prompt_buckets=(8,))
+        eng.step()                          # 0: nothing to do
+        eng.enqueue(np.arange(5), 8)        # B: one admission program
+        eng.step()                          # 1: B's first token
+        eng.enqueue(np.arange(21), 4)       # A: first chunk [0, 8)
+        eng.step()                          # 2: A's chunk [8, 16), B decodes
+        eng.step()                          # 3: A's chunk [12, 20), both decode
+        eng.step()                          # 4
+    spans = [r for r in read_trace(path) if r["kind"] == "span"]
+    return [s for s in spans if s["name"] == "serving.round"], spans
+
+
+@pytest.mark.parametrize("child", ROUND_CHILDREN)
+def test_round_is_parent_of(rounds, child):
+    """Round 2 runs every boundary: pump, a continuation chunk, the
+    decode dispatch, the emit loop, the reap — each a child of the
+    round, inside its interval."""
+    rnds, spans = rounds
+    rnd = rnds[2]
+    (sp,) = [s for s in spans
+             if s["name"] == child and s["parent"] == rnd["id"]]
+    assert sp["depth"] == rnd["depth"] + 1
+    assert rnd["t0"] <= sp["t0"]
+    assert sp["t0"] + sp["dur"] <= rnd["t0"] + rnd["dur"] + 1e-9
+
+
+def test_idle_round_is_marked_and_dispatches_nothing(rounds):
+    rnds, spans = rounds
+    assert [bool(r["fields"].get("idle")) for r in rnds] == [
+        True, False, False, False, False]
+    idle = rnds[0]
+    names = {s["name"] for s in spans if s["parent"] == idle["id"]}
+    assert names == {"serving.pump", "serving.reap"}
+    assert idle["fields"]["tokens"] == idle["fields"]["kv_live"] == 0
+    # The admission spans keep their fields (the prefill reader sums
+    # ``bucket``) and carry the request's id.
+    admits = [s for s in spans if s["name"] in ("serving.admit",
+                                                "serving.admit_chunk")]
+    assert [s["fields"]["bucket"] for s in admits] == [8, 8, 8, 8]
+    assert all("request_id" in s["fields"] for s in admits)
+
+
+@pytest.mark.parametrize("i,want", [
+    # B holds its 5 prompt tokens (the 6th token of its transcript is
+    # the next step's input, not yet in the cache).
+    (1, {"lanes_busy": 1, "lanes_admitting": 0, "kv_live": 5,
+         "chunks": 1, "tokens": 1}),
+    # The round PERF.md describes: A's first chunk ran at admission and
+    # a continuation chunk in this step(), so TWO admission programs
+    # stand between two decode dispatches.  A is mid-prefill: positions
+    # [0, 12) lie before its next chunk.
+    (2, {"lanes_busy": 2, "lanes_admitting": 1, "kv_live": 6 + 12,
+         "chunks": 2, "tokens": 1}),
+    # A's last chunk landed and it joined this decode: its 21 prompt
+    # tokens are in, its first token is the next input.
+    (3, {"lanes_busy": 2, "lanes_admitting": 0, "kv_live": 7 + 21,
+         "chunks": 1, "tokens": 2}),
+    (4, {"lanes_busy": 2, "lanes_admitting": 0, "kv_live": 8 + 22,
+         "chunks": 0, "tokens": 2}),
+])
+def test_round_counts_equal_a_hand_count(rounds, i, want):
+    rnds, _ = rounds
+    got = {k: rnds[i]["fields"][k] for k in want}
+    assert got == want
+
+
+# ------------------------------------------ names on the device timeline
+
+
+def _pattern(metric):
+    with open(os.path.join(REPO, "benchmarks", "layer_metrics",
+                           metric + ".json")) as f:
+        return json.load(f)["args"]["pattern"]
+
+
+@pytest.fixture(scope="module")
+def engine_programs():
+    """The decode-step and the admission program of a hot-swap engine
+    (the benchmark's), lowered: ``{"decode_step" | "admit": text}``."""
+    params = tfm.init_params(jax.random.key(0), CFG)
+    eng = dk.ContinuousBatcher(params, CFG, lanes=2, hot_swap=True,
+                               prefill_chunk=8, prompt_buckets=(8,))
+    out = {}
+    for spec in eng.traced_for_analysis():
+        key = "decode_step" if spec.name.endswith("decode_step") else "admit"
+        out[key] = spec.fn.lower(*spec.args).as_text(debug_info=True)
+    return out
+
+
+@pytest.mark.parametrize("program,metric", [
+    ("decode_step", "decode_step_ms"), ("admit", "prefill_ms_per_ktok")])
+def test_program_names_match_the_benchmark_readers(engine_programs,
+                                                   program, metric):
+    """An "XLA Modules" event is named after the jitted function
+    (``jit_step_n_p(...)``, ``jit__admit(...)``); the accepted readers
+    find the programs by these names, so they are pinned here and not
+    renamed."""
+    (module,) = re.findall(r"module @(\S+)", engine_programs[program])
+    assert module.startswith("jit_")
+    assert re.search(_pattern(metric), module), (module, _pattern(metric))
+
+
+def _scopes_in(text):
+    """The vocabulary words that stand as a scope in some operation's
+    ``loc("jit(f)/jvp(embed)/gather")`` of a lowered program."""
+    locs = " ".join(set(re.findall(r'loc\("([^"]+)"', text)))
+    return {sc for sc in tfm.SCOPES
+            if re.search(rf"(?<![\w.]){sc}(?![\w.])", locs)}
+
+
+@pytest.mark.parametrize("program,absent", [
+    ("decode_step", set()),
+    # An admission discards the chunk's logits: the head is traced and
+    # then pruned as dead code, so the program never computes it.
+    ("admit", {"head"})])
+def test_serving_programs_hold_every_scope(engine_programs, program,
+                                           absent):
+    assert _scopes_in(engine_programs[program]) \
+        == set(tfm.SCOPES) - absent
+
+
+TRAIN_CFG = tfm.TransformerConfig(
+    vocab_size=256, d_model=256, n_heads=2, n_layers=2, d_ff=512,
+    max_len=256, rope=True, remat=True, ce_chunks=2, attention_window=128)
+
+
+@pytest.fixture(scope="module", params=["one_device", "shard_map"])
+def train_step_text(request, devices):
+    """``make_train_step`` at kernel-legal widths, lowered for the TPU
+    from here (tests/test_attention.py, "TPU lowering, no chip"): on one
+    device, and with the rows sharded over ``data=4``, where the kernels
+    sit in ``_per_shard``'s shard_map."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from distkeras_tpu.ops import attention
+
+    sharding = None
+    if request.param == "shard_map":
+        mesh = make_mesh(MeshSpec(data=4), devices=devices[:4])
+        sharding = NamedSharding(mesh, P("data"))
+    opt = optax.sgd(1e-2)
+    params = jax.eval_shape(
+        lambda: tfm.init_params(jax.random.key(0), TRAIN_CFG))
+    state = (params, jax.eval_shape(opt.init, params))
+    rows = jax.ShapeDtypeStruct((4, 257), jnp.int32, sharding=sharding)
+    mp = pytest.MonkeyPatch()
+    mp.setattr(attention, "_on_tpu", lambda: True)
+    try:
+        lowered = jax.jit(tfm.make_train_step(TRAIN_CFG, opt)).trace(
+            state, rows, None, rows).lower(lowering_platforms=("tpu",))
+    finally:
+        mp.undo()
+    text = lowered.as_text(debug_info=True)
+    assert ("shard_map" in text) == (request.param == "shard_map")
+    return text
+
+
+@pytest.mark.parametrize("kernel,calls", [
+    ("flash_fwd", 2), ("flash_bwd_dq", 1), ("flash_bwd_dkv", 1)])
+def test_kernel_names_stand_in_the_lowered_train_step(train_step_text,
+                                                      kernel, calls):
+    """Each Pallas call's location ends ``<kernel>/pallas_call``: the
+    compiled instruction, and with it the profile's event, is named
+    after the component before ``pallas_call`` (``flash_fwd.3``), not
+    after the transform that wrapped the call.  Per layer: the forward
+    twice (once rematerialised), dq once, dkv once."""
+    assert train_step_text.count(f'kernel_name = "{kernel}"') \
+        == TRAIN_CFG.n_layers * calls
+    assert re.search(rf'{kernel}/pallas_call"', train_step_text)
+
+
+def test_train_step_holds_every_scope_but_the_kv_slab(train_step_text):
+    assert _scopes_in(train_step_text) == set(tfm.SCOPES) - {"kv_slab"}
+
+
+# ----------------------------------------------------- the run report
+
+
+def _span(name, t0, dur, id, parent=None, **fields):
+    return {"kind": "span", "name": name, "t0": t0, "dur": dur, "id": id,
+            "parent": parent, "depth": 0 if parent is None else 1,
+            "fields": fields}
+
+
+def test_report_splits_the_step_gap_by_span():
+    """Two rounds by hand.  The gap runs from the end of round 1's
+    ``serving.step`` (t=5) to the start of round 2's first dispatching
+    span, an ``admit_chunk`` at t=14: 9 = emit_loop 2 + reap 1 + caller
+    3 (of which the caller's own pump 1) + round 2's pump 1 + 2 inside
+    the rounds but in no child."""
+    from distkeras_tpu.obs.report import (build_report, render_report,
+                                          serving_rounds)
+
+    counts = dict(lanes_busy=2, lanes_admitting=1, kv_live=10, tokens=1)
+    recs = [
+        _span("serving.round", 0, 9, 1, chunks=1, **counts),
+        _span("serving.pump", 0, 1, 2, 1),
+        _span("serving.step", 1, 4, 3, 1, n=1),
+        _span("serving.emit_loop", 5.5, 2, 4, 1),
+        _span("serving.reap", 7.5, 1, 5, 1),
+        _span("serving.pump", 10, 1, 6),              # the caller's own
+        _span("serving.round", 12, 8, 7, chunks=2, **counts),
+        _span("serving.pump", 12.5, 1, 8, 7),
+        _span("serving.admit_chunk", 14, 1, 9, 7, bucket=8),
+        _span("serving.step", 15, 4, 10, 7, n=1),
+        _span("serving.round", 21, 1, 11, chunks=0, idle=True,
+              **dict(counts, tokens=0)),
+    ]
+    out = serving_rounds(recs)
+    assert (out["rounds"], out["idle"], out["chunks_max"]) == (3, 1, 2)
+    assert out["mean"]["chunks"] == 1.5 and out["mean"]["kv_live"] == 10
+    gap = out["gap"]
+    assert gap["n"] == 1 and gap["p50_s"] == pytest.approx(9)
+    assert gap["split_mean_s"] == pytest.approx(
+        {"emit_loop": 2, "reap": 1, "pump": 2, "round": 2, "caller": 2})
+    assert sum(gap["split_mean_s"].values()) == pytest.approx(9)
+    assert serving_rounds([r for r in recs
+                           if r["name"] != "serving.round"]) is None
+    assert "step gap" in render_report(build_report(recs))
