@@ -6,6 +6,8 @@ every chip JAX finds (one process, no children):
 
 - kernels: ``flash_attention`` against ``naive_attention`` in float32,
   forward and gradient, causal / windowed / segmented / both;
+  ``flash_prefix_attention`` (chunked prefill) against masked float32
+  attention over the whole cache, at the serving cell's widths;
 - paper: ``ADAG`` on the CIFAR CNN -> ``ModelPredictor`` ->
   ``AccuracyEvaluator``;
 - lm: ``LMTrainer`` on the d1024 L8 long-context model at seq 4096,
@@ -53,6 +55,10 @@ SERVE_LANES = 4                           # < requests: mid-flight admission
 SERVE_PAD = 1024                          # teacher-forced reference length
 KERNEL_SHAPE = (2, 4096, 8, 128)          # B, L, H, D
 KERNEL_WINDOW = 1024
+# Chunked prefill at the serving cell's widths: query heads, K/V heads,
+# head, cache slots; (bucket, offset) pairs, the offsets on no block edge.
+PREFIX_SHAPE = (16, 1, 128, 8192)
+PREFIX_CHUNKS = ((64, 4099), (512, 2283), (512, 8192 - 512))
 CIFAR_ROWS_PER_CHIP = 4096
 
 # A generated token must score within this of the best logit of the
@@ -168,6 +174,48 @@ def leg_kernels(mesh=None):
             f"largest entry (out, dq, dk, dv) = {errs}")
     return {"rel_err(out,dq,dk,dv)": report,
             "sharded": None if mesh is None else dict(mesh.shape)}
+
+
+def leg_kernel_prefix():
+    """flash_prefix_attention == float32 attention over every cache
+    slot under the position mask, for chunks of 64 and 512 queries at
+    offsets no block edge meets and at the cache's end; the slots past
+    a chunk hold garbage, which must not reach the result."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distkeras_tpu.ops.attention import (flash_prefix_attention,
+                                             naive_attention)
+
+    h, kv, d, s_len = PREFIX_SHAPE
+    rng = np.random.default_rng(0)
+    k, v = (jnp.asarray(rng.normal(size=(1, s_len, kv, d)), jnp.bfloat16)
+            for _ in range(2))
+
+    def oracle(q, k, v, off):
+        # Every cache slot, K/V heads repeated per query head, float32.
+        wide = lambda a: jnp.repeat(a.astype(jnp.float32), h // kv, axis=2)
+        return naive_attention(q.astype(jnp.float32), wide(k), wide(v),
+                               causal=True, q_offset=off)
+
+    kernel = jax.jit(flash_prefix_attention)
+    report = {}
+    for t, off in PREFIX_CHUNKS:
+        q = jnp.asarray(rng.normal(size=(1, t, h, d)), jnp.bfloat16)
+        off_ = jnp.int32(off)
+        assert "tpu_custom_call" in kernel.lower(q, k, v, off_).as_text()
+        junk_k, junk_v = k.at[:, off + t:].set(3e4), v.at[:, off + t:].set(-3e4)
+        got = np.asarray(kernel(q, junk_k, junk_v, off_), np.float32)
+        with jax.default_matmul_precision("float32"):  # a true f32 oracle
+            want = np.asarray(jax.jit(oracle)(q, k, v, off_))
+        assert np.isfinite(got).all(), f"{t}@{off}: non-finite kernel output"
+        err = float(np.abs(got - want).max() / np.abs(want).max())
+        report[f"{t}@{off}"] = round(err, 5)
+        assert err < KERNEL_TOL, (
+            f"{t} queries at {off}: kernel vs float32 oracle, max error "
+            f"relative to the largest entry = {err}")
+    return {"rel_err": report}
 
 
 # -------------------------------------------------------------------- paper
@@ -545,7 +593,8 @@ def main():
     serving = functools.cache(Serving)  # built by the first leg that serves
     # lm first: its spread check reads peaks that never reset.
     legs = [("lm", leg_lm), ("lm_packed", leg_lm_packed),
-            ("kernels", leg_kernels), ("paper", leg_paper),
+            ("kernels", leg_kernels), ("kernel_prefix", leg_kernel_prefix),
+            ("paper", leg_paper),
             ("serve_continuous",
              lambda: leg_serve_continuous(serving(), meter)),
             ("serve_paged_router",

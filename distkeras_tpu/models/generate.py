@@ -41,7 +41,12 @@ from distkeras_tpu.models.quant import (
     quantize_kv,
     unembed_logits,
 )
-from distkeras_tpu.ops.attention import flash_attention
+from distkeras_tpu.ops.attention import (
+    flash_attention,
+    flash_prefix_attention,
+    is_partitioned,
+    use_flash_prefix,
+)
 
 
 def init_cache(cfg: TransformerConfig, batch: int, dtype=None,
@@ -416,6 +421,28 @@ def _layer_slab_update(cache_all, i, rows, pos):
         cache_all, rows.astype(cache_all.dtype)[None], starts)
 
 
+def chunk_attends_prefix(cfg: TransformerConfig, t_len: int, cache,
+                         uniform_pos: bool = True, beam: bool = False,
+                         sharded: bool = False) -> bool:
+    """Whether :func:`_decode_chunk` compiles the BOUNDED attention
+    (``ops.attention.flash_prefix_attention``: the chunk's queries read
+    cache slots ``[0, pos0 + T)`` only) for a ``t_len``-token chunk
+    against ``cache``, or its dense body over all ``max_len`` slots.
+
+    One static predicate on what the call already holds: a multi-token
+    chunk at one position for every row, no beam ancestry, no ring, no
+    int8 scales; a TPU backend, one device, shapes the kernel tiles.
+    T = 1 (every decode step), per-row positions (speculative verify),
+    windowed, int8 and CPU calls keep the dense body, which is also the
+    kernel's oracle in tests.  The serving engines ask the same
+    question for their admission spans' ``attended`` field."""
+    return (uniform_pos and t_len > 1 and not beam
+            and cfg.attention_window is None and "k_scale" not in cache
+            and use_flash_prefix(t_len, cfg.max_len, cfg.head_dim,
+                                 cfg.n_heads // cfg.kv_heads,
+                                 cache["k"].dtype, sharded=sharded))
+
+
 def _decode_chunk(params, cache, tokens, pos0, cfg: TransformerConfig,
                   uniform_pos: bool = False, beam_anc=None):
     """Process T new tokens per row against the cache in ONE pass:
@@ -489,6 +516,9 @@ def _decode_chunk(params, cache, tokens, pos0, cfg: TransformerConfig,
 
     kv_q = "k_scale" in cache                   # int8 KV cache
     win = cfg.attention_window is not None
+    bounded = chunk_attends_prefix(
+        cfg, t_len, cache, uniform_pos=uniform_pos,
+        beam=beam_anc is not None, sharded=is_partitioned(x))
     if (win and t_len > 1 and not uniform_pos
             and t_len + cfg.attention_window > cfg.max_len):
         # A per-row ring chunk may WRAP, and then two invariants need
@@ -577,7 +607,13 @@ def _decode_chunk(params, cache, tokens, pos0, cfg: TransformerConfig,
             groups = cfg.n_heads // cfg.kv_heads
             qg = q.astype(jnp.float32).reshape(
                 b, t_len, cfg.kv_heads, groups, cfg.head_dim)
-            if beam_anc is not None:
+            if bounded:
+                # The chunk is in the cache already (kv_slab above), so
+                # one mask covers prefix and chunk; slots past
+                # pos0 + T are never read.
+                attn = flash_prefix_attention(q.astype(ck.dtype), ck, cv,
+                                              pos0[0])
+            elif beam_anc is not None:
                 # Ancestry attention (shared body: _ancestry_attend) — the
                 # cache is read once, W x the (tiny) decode attention
                 # FLOPs, and the one-hot selects each position's true

@@ -44,8 +44,10 @@ def _pct(durs: list, q: float) -> float:
     return s[int(idx)]
 
 
-def build_report(records: list[dict]) -> dict:
-    """Trace records -> plain-dict report (JSON-able)."""
+def build_report(records: list[dict], max_len: int | None = None) -> dict:
+    """Trace records -> plain-dict report (JSON-able).  ``max_len``:
+    the serving engine's cache slots a lane, for the attended share
+    (:func:`serving_rounds`)."""
     meta = next((r for r in records if r.get("kind") == "meta"), {})
     spans: dict[str, list] = {}
     events = []
@@ -106,11 +108,11 @@ def build_report(records: list[dict]) -> dict:
                      ("run", "host", "pid", "time_unix")},
             "wall_s": wall, "phases": phases, "latency": hists,
             "scalars": scalars, "timeline": timeline,
-            "rounds": serving_rounds(records)}
+            "rounds": serving_rounds(records, max_len)}
 
 
-def load_report(path: str) -> dict:
-    return build_report(read_trace(path))
+def load_report(path: str, max_len: int | None = None) -> dict:
+    return build_report(read_trace(path), max_len)
 
 
 # ------------------------------------------------------ serving rounds
@@ -135,7 +137,11 @@ def _self_time(sp, lo, hi, children, out) -> float:
     return ov
 
 
-def serving_rounds(records: list[dict]) -> dict | None:
+_ADMIT = ("serving.admit", "serving.admit_chunk")
+
+
+def serving_rounds(records: list[dict],
+                   max_len: int | None = None) -> dict | None:
     """The ``serving.round`` spans of one thread's trace, reduced: how
     many (and how many idle), the means of their counts over the rounds
     that dispatched a decode step, and the **step gap** — from the end
@@ -143,7 +149,12 @@ def serving_rounds(records: list[dict]) -> dict | None:
     span — as a median with its mean split by whose self time it was:
     ``emit_loop``, ``reap``, ``pump``, ``round`` (inside a round but in
     none of its children) and ``caller`` (in no span: between two
-    ``step()`` calls).  None when the trace holds no round."""
+    ``step()`` calls).  ``attended``: the cache positions the admission
+    programs' attention read (the field of that name on the admission
+    spans) — how many programs, their mean, and with ``max_len`` (the
+    engine's slots a lane, which no record carries) the share of the
+    slab a program still reads: 1.0 on the dense path.  None when the
+    trace holds no round."""
     spans = sorted((r for r in records if r.get("kind") == "span"
                     and r["name"].startswith("serving.")),
                    key=lambda r: r["t0"])
@@ -157,6 +168,13 @@ def serving_rounds(records: list[dict]) -> dict | None:
                               "chunks", "tokens")} if live else {},
            "chunks_max": max((r["fields"]["chunks"] for r in live),
                              default=0)}
+    attended = [sp["fields"]["attended"] for sp in spans
+                if sp["name"] in _ADMIT and "attended" in sp["fields"]]
+    if attended:
+        out["attended"] = {"programs": len(attended),
+                           "mean": statistics.fmean(attended)}
+        if max_len:
+            out["attended"]["share"] = out["attended"]["mean"] / max_len
     # One thread's spans nest, so the top-level ones are disjoint and
     # in order: a gap is walked from the one that covers its start.
     ids = {sp["id"] for sp in spans}
@@ -513,6 +531,13 @@ def render_report(rep: dict, max_events: int = 60) -> str:
         if rounds["mean"]:
             out.append("  mean per decoding round: " + "  ".join(
                 f"{k}={v:.4g}" for k, v in rounds["mean"].items()))
+        att = rounds.get("attended")
+        if att:
+            share = (f" = {att['share']:.1%} of the slab's slots a lane"
+                     if "share" in att else " (share: pass --max-len)")
+            out.append(f"  attended: {att['programs']} admission programs "
+                       f"read {att['mean']:.6g} cache positions each"
+                       + share)
         gap = rounds.get("gap")
         if gap:
             out.append(

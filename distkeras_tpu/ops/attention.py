@@ -22,8 +22,15 @@ one set of semantics so tests can pin them against each other:
   blockwise implementation under ``jax.vjp``.  O(L) residuals either
   way.  Inside a multi-device ``jit`` the kernels run per batch/head
   shard (:func:`_per_shard`).
+- :func:`flash_prefix_attention` — Pallas TPU forward kernel
+  (``flash_prefix_fwd``) for a chunk of queries at a TRACED offset
+  against a KV cache it reads only as far as the chunk's last position:
+  chunked prefill's attention (models/generate.py::_decode_chunk takes
+  it where :func:`use_flash_prefix` says so, and keeps its dense body
+  everywhere else).  Inference only; grouped and multi-query heads
+  read their K/V head through the block index, no ``repeat``.
 
-All take ``q: [B, Lq, H, D]``, ``k/v: [B, Lkv, H, D]`` and return
+All but the last take ``q: [B, Lq, H, D]``, ``k/v: [B, Lkv, H, D]`` and return
 ``[B, Lq, H, D]``.  ``q_offset``/``kv_offset`` give the global positions
 of the local chunks so causal masking works when sequences are sharded
 (ring attention).
@@ -341,6 +348,17 @@ def _same_segment(qseg_ref, kseg_ref):
 _SHARD_AXIS = {"b": "data", "h": "model"}
 
 
+def _auto_axes(x):
+    """``(mesh, axes)``: the mesh ``x`` is computed under — recorded on
+    its type, else the ambient ``jax.set_mesh`` — and those of its axes
+    the compiler still partitions over (not made manual by a caller's
+    ``shard_map``)."""
+    mesh = jax.typeof(x).sharding.mesh
+    if mesh.empty:
+        mesh = jax.sharding.get_abstract_mesh()
+    return mesh, [a for a in mesh.axis_names if a not in mesh.manual_axes]
+
+
 def _per_shard(fn, in_dims, out_dims, *arrays):
     """``fn(*arrays)`` with every device running it on its own
     batch/head shard.
@@ -362,10 +380,7 @@ def _per_shard(fn, in_dims, out_dims, *arrays):
     ``in_dims``/``out_dims`` name each array's dimensions, one letter
     each; ``fn`` returns a tuple.
     """
-    mesh = jax.typeof(arrays[0]).sharding.mesh
-    if mesh.empty:
-        mesh = jax.sharding.get_abstract_mesh()
-    auto = [a for a in mesh.axis_names if a not in mesh.manual_axes]
+    mesh, auto = _auto_axes(arrays[0])
     if math.prod(mesh.shape[a] for a in auto) == 1:
         return fn(*arrays)
     size = dict(zip(in_dims[0], arrays[0].shape))
@@ -964,3 +979,203 @@ def _flash_bwd(causal, scale, block_q, block_k, window, res, g):
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
+
+
+# ------------------------------------------------- prefix (chunked prefill)
+
+
+def _flash_prefix_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
+                         acc_scr, *, scale: float):
+    """One (batch, kv-head, q-block, kv-block) cell of chunk-against-cache
+    attention: the ``groups`` query heads that share this K/V head, a
+    ``block_q`` span of the chunk each, folded into the rows of ONE
+    ``[groups * block_q, block_k]`` logits tile, so a K/V block is
+    fetched once for all of them.
+
+    Query row ``t`` of the chunk sits at global position ``off + t``
+    (``off_ref``: scalar prefetch, traced) and keeps key slot ``s`` iff
+    ``s <= off + t`` — the chunk's own K/V are already in the cache, so
+    in-chunk causality is the same comparison.  K/V blocks are aligned
+    to the cache; only the mask reads ``off``.  Blocks past the q
+    block's last position are predicated away here and never fetched
+    (:func:`flash_prefix_attention` clamps their block index).  Block 0 is
+    live for every row (slot 0 is kept), so the running max is finite
+    from the first update on and a later all-masked row adds exp(-1e30
+    - m) = 0.
+
+    Operands reach the MXU in their own dtype (bf16 from a bf16 model
+    and cache) with float32 accumulation; max, sum and accumulator are
+    float32.  The logits are scaled AFTER the product, in float32, as
+    the dense body does.
+    """
+    groups, block_q, d = q_ref.shape
+    block_k = k_ref.shape[0]
+    j = pl.program_id(3)
+    row0 = off_ref[0] + pl.program_id(2) * block_q
+    col0 = j * block_k
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(col0 <= row0 + block_q - 1)
+    def _update():
+        logits = jax.lax.dot_general(
+            q_ref[...].reshape(groups * block_q, d), k_ref[...],
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        rows = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 0)
+        cols = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+        logits = jnp.where(row0 + rows % block_q >= col0 + cols,
+                           logits, NEG_INF)
+        m = m_scr[:, :1]
+        m_new = jnp.maximum(m, logits.max(axis=-1, keepdims=True))
+        corr = jnp.exp(m - m_new)
+        p = jnp.exp(logits - m_new)
+        l_new = l_scr[:, :1] * corr + p.sum(axis=-1, keepdims=True)
+        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[...], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _finish():
+        o_ref[...] = (acc_scr[:] / l_scr[:, :1]).reshape(
+            o_ref.shape).astype(o_ref.dtype)
+
+
+# Rows of the logits tile (query heads of a group x block_q) and its
+# columns (block_k); the tile and its exponentials are float32 in VMEM,
+# 8 MB each at these sizes.  Fastest of ten (block_q, block_k) points at
+# 16 heads on one K/V head, 512 queries, 8192 slots (TPU v5e, PR 26):
+# 0.13 ms at offset 2283 and 0.28 ms at 7680, against 0.22 and 0.55 at
+# (1024, 512).
+PREFIX_TILE_ROWS = 2048
+PREFIX_BLOCK_K = 1024
+# The kernel's VMEM allowance: float32 operands at these tiles need
+# 17.8 MB, over the compiler's 16 MB default; a v5e core has 128 MB.
+PREFIX_VMEM_BYTES = 64 * 1024 * 1024
+
+
+def prefix_blocks(t_len: int, s_len: int, head_dim: int, groups: int,
+                  dtype) -> tuple[int, int] | None:
+    """Tile rule of :func:`flash_prefix_attention` (backend-independent):
+    the ``(block_q, block_k)`` it launches with for a ``t_len``-wide
+    chunk against ``s_len`` cache slots, or None where no legal tiling
+    exists.  ``block_q`` tiles the chunk in whole sublane tiles of
+    ``dtype`` (the q block's leading dims merge into rows), at most
+    ``PREFIX_TILE_ROWS / groups`` of them; ``block_k`` tiles the cache
+    in lane multiples."""
+    sub = _SUBLANES * 4 // jnp.dtype(dtype).itemsize
+    if head_dim % _LANES or t_len % sub or s_len % _LANES:
+        return None
+    cap = max(sub, PREFIX_TILE_ROWS // groups)
+    bq = max(c for c in range(sub, min(cap, t_len) + 1, sub)
+             if t_len % c == 0)
+    bk = max(c for c in range(_LANES, min(PREFIX_BLOCK_K, s_len) + 1, _LANES)
+             if s_len % c == 0)
+    return bq, bk
+
+
+def is_partitioned(x) -> bool:
+    """Whether ``x`` is computed under a mesh the compiler still
+    partitions over more than one device (see :func:`_auto_axes`)."""
+    mesh, auto = _auto_axes(x)
+    return math.prod(mesh.shape[a] for a in auto) > 1
+
+
+def use_flash_prefix(t_len: int, s_len: int, head_dim: int, groups: int,
+                     dtype, sharded: bool = False) -> bool:
+    """Kernel or the caller's dense body: decided from the backend, the
+    placement and the shapes alone, before any kernel is built (as
+    :func:`_use_pallas`).  ``sharded`` (:func:`is_partitioned`) says
+    no: a Mosaic kernel cannot be partitioned automatically, and the
+    head-sharded engines keep the dense body until this launch learns
+    to sit in a ``shard_map``."""
+    return (_on_tpu() and not sharded
+            and prefix_blocks(t_len, s_len, head_dim, groups,
+                              dtype) is not None)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("block_q", "block_k", "interpret"))
+def flash_prefix_attention(q, k, v, off, block_q: int | None = None,
+                           block_k: int | None = None,
+                           interpret: bool = False):
+    """Attention of a chunk's queries against the live prefix of a KV
+    cache: ``q [B, T, H, D]`` at global positions ``off .. off + T - 1``
+    (``off``: int32 scalar, may be traced, need not be block-aligned),
+    ``k``/``v [B, S, KV, D]`` the cache with the chunk's own K/V already
+    written at ``[off, off + T)``.  Key slot ``s`` is kept iff ``s <=
+    off + t``; slots past ``off + T - 1`` are neither fetched nor
+    computed, so bytes and operations follow ``off + T``, not ``S``.
+    Returns ``[B, T, H, D]`` in ``q``'s dtype.  Forward only.
+    ``block_q``/``block_k`` default to :func:`prefix_blocks`' choice
+    (shapes it refuses need them given: the interpreter's tests).
+
+    Jitted, so that a program calling it once a layer traces and
+    lowers the kernel ONCE: unwrapped, 24 layers cost an admission
+    program 1.4 s more of Python before the compile cache is even
+    asked, 13 s over an engine's ten programs (chip, PR 26)."""
+    b, t_len, h, d = q.shape
+    s_len, kv = k.shape[1], k.shape[2]
+    groups = h // kv
+    if block_q is None or block_k is None:
+        fit = prefix_blocks(t_len, s_len, d, groups, q.dtype)
+        if fit is None:
+            raise ValueError(
+                f"no kernel tiling for a chunk of {t_len} x head {d} "
+                f"against {s_len} cache slots (see prefix_blocks)")
+        block_q, block_k = block_q or fit[0], block_k or fit[1]
+    if t_len % block_q or s_len % block_k:
+        raise ValueError(
+            f"blocks ({block_q}, {block_k}) do not tile a chunk of "
+            f"{t_len} against {s_len} cache slots")
+    rows = groups * block_q
+    # Head-major queries [B, KV, G, T, D]: a q block's leading dims
+    # merge into the rows of one tile.  The cache keeps its layout; a
+    # K/V head is a lane-aligned column block of [B, S, KV * D].
+    qg = q.reshape(b, t_len, kv, groups, d).transpose(0, 2, 3, 1, 4)
+    kf, vf = (a.reshape(b, s_len, kv * d) for a in (k, v))
+
+    def kv_map(bi, c, i, j, off_ref):
+        last = (off_ref[0] + (i + 1) * block_q - 1) // block_k
+        return bi, jnp.minimum(j, last), c
+
+    q_spec = pl.BlockSpec((None, None, groups, block_q, d),
+                          lambda bi, c, i, j, off_ref: (bi, c, 0, i, 0))
+    kv_spec = pl.BlockSpec((None, block_k, d), kv_map)
+    live = b * h * t_len * s_len // 2     # an estimate: off is traced
+
+    def call(): return pl.pallas_call(
+        functools.partial(_flash_prefix_kernel, scale=_scale_for(q, None)),
+        name="flash_prefix_fwd",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, kv, t_len // block_q, s_len // block_k),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((rows, _LANES), jnp.float32),   # m
+                pltpu.VMEM((rows, _LANES), jnp.float32),   # l
+                pltpu.VMEM((rows, d), jnp.float32),        # acc
+            ]),
+        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=PREFIX_VMEM_BYTES),
+        cost_estimate=pl.CostEstimate(
+            flops=4 * live * d, transcendentals=live,
+            bytes_accessed=2 * qg.nbytes + (kf.nbytes + vf.nbytes) // 2),
+    )(jnp.reshape(off, (1,)).astype(jnp.int32), qg, kf, vf)
+
+    if interpret:
+        with pltpu.force_tpu_interpret_mode():
+            out = call()
+    else:
+        out = call()
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, t_len, h, d)

@@ -23,7 +23,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from distkeras_tpu import obs
-from distkeras_tpu.models.generate import _decode_chunk
+from distkeras_tpu.models.generate import (_decode_chunk,
+                                           chunk_attends_prefix)
 from distkeras_tpu.serving.admission import _AdmissionMixin
 from distkeras_tpu.serving.elastic import _ElasticMixin
 
@@ -194,6 +195,19 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
     # (``_run_pending_chunk``), whoever asked for them; ``step()``
     # reports it as the round's ``chunks`` and zeroes it there.
     _admit_programs = 0
+
+    def _attended(self, cache, start: int, width: int) -> int:
+        """Cache positions the attention of an admission program reads
+        — the ``attended`` field of ``serving.admit`` and
+        ``serving.admit_chunk``: ``start + width`` where the program
+        of that width was compiled with the bounded path
+        (``chunk_attends_prefix``, the question ``_decode_chunk``
+        itself asks), ``max_len`` where it keeps the dense body.  Host
+        integers known at dispatch; no device read."""
+        sharded = self.mesh is not None and self.mesh.size > 1
+        if chunk_attends_prefix(self.cfg, width, cache, sharded=sharded):
+            return start + width
+        return self.cfg.max_len
 
     def _pargs(self) -> tuple:
         """The params-argument prefix of every compiled-program call:
@@ -528,7 +542,9 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
         start, rows = st.chunks.pop(0)
         with obs.span("serving.admit_chunk", bucket=rows.shape[1],
                       remaining=len(st.chunks),
-                      request_id=st.request_id):
+                      request_id=st.request_id,
+                      attended=self._attended(self.cache, start,
+                                              rows.shape[1])):
             self._exec_chunk(lane, start, rows)
         self._admit_programs += 1
         if not st.chunks:

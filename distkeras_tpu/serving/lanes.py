@@ -1063,7 +1063,9 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
                 rows = self._chunk_rows(prompt, off, start0, width0)
                 with obs.span("serving.admit", bucket=width0,
                               chunks=len(plan), lane=lane,
-                              request_id=rid):
+                              request_id=rid,
+                              attended=self._attended(
+                                  self.cache, start0, width0)):
                     self._exec_admit(lane, start0, rows, slot)
                 self._admit_programs += 1
                 if len(plan) > 1:

@@ -568,7 +568,9 @@ class SpeculativeBatcher(_LaneEngine):
                 rows[0, :warm] = prompt[:-1]
                 rows_j = jnp.asarray(rows)
                 with obs.span("serving.admit", bucket=width, lane=lane,
-                              request_id=rid):
+                              request_id=rid,
+                              attended=self._attended(
+                                  self.tcache, off, width)):
                     if slot is not None:
                         t_slab, d_slab = self._prefix_pool.slab
                         self.tcache = self._admit_t(

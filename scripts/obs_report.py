@@ -14,6 +14,7 @@ Usage::
     python scripts/obs_report.py run.jsonl --json   # the report dict
     python scripts/obs_report.py --merge host0.jsonl host1.jsonl ...
     python scripts/obs_report.py serve.jsonl --request 3
+    python scripts/obs_report.py serve.jsonl --max-len 8192
     python scripts/obs_report.py router.jsonl replica*.jsonl --request 7
 
 ``--compare BASE`` prints a regression diff of NEW (the positional
@@ -28,6 +29,12 @@ SEVERAL traces (round 13) the records are wall-clock aligned first
 and the waterfall follows a fleet-wide router id across processes:
 the routing decision, any re-route hop, and each replica's engine
 stages render as one story.
+
+``--max-len N`` (the serving engine's cache slots a lane, which no
+trace record carries) turns "serving rounds"' attended positions into
+the share of the slab an admission program's attention still reads:
+1.0 on the dense path, about the live prefix's share on the bounded
+one.
 
 ``--merge`` takes SEVERAL per-host traces (a multi-host run writes one
 file per host per attempt) and renders ONE cross-host event timeline,
@@ -88,6 +95,9 @@ def main(argv):
                     help="render one serving request's waterfall "
                          "(submit/admit/chunks/emits/finish) instead "
                          "of the full report")
+    ap.add_argument("--max-len", type=int, metavar="N", default=None,
+                    help="the engine's cache slots a lane: prints the "
+                         "admission programs' attended share")
     ap.add_argument("--json", action="store_true",
                     help="emit the report as JSON instead of text "
                          "(with --merge: one timeline entry per line)")
@@ -121,9 +131,9 @@ def main(argv):
         else:
             print(report.render_waterfall(wf))
         return 0 if wf.get("found") else 1
-    rep = report.load_report(args.trace[0])
+    rep = report.load_report(args.trace[0], args.max_len)
     if args.compare:
-        base = report.load_report(args.compare)
+        base = report.load_report(args.compare, args.max_len)
         if args.json:
             print(json.dumps({"base": base, "new": rep}, indent=1,
                              default=str))

@@ -14,7 +14,9 @@ import pytest
 from distkeras_tpu.ops.attention import (
     blockwise_attention,
     flash_attention,
+    flash_prefix_attention,
     naive_attention,
+    prefix_blocks,
     _flash_pallas,
 )
 from distkeras_tpu.parallel.mesh import MeshSpec, make_mesh
@@ -330,6 +332,113 @@ def test_explicit_small_block_k_honored_and_unfittable_raises(rng):
                           strict_q=True, strict_k=False) == (512, 384)
 
 
+# ------------------------------------------- prefix (chunked prefill)
+
+
+def _dense_chunk_attention(q, k, v, off):
+    """``models/generate.py::_decode_chunk``'s dense body for a uniform
+    chunk at ``off``: float32 scores over every cache slot, the
+    position mask, softmax, values — the kernel's oracle."""
+    b, t, h, d = q.shape
+    s, kv = k.shape[1], k.shape[2]
+    qg = q.astype(jnp.float32).reshape(b, t, kv, h // kv, d)
+    logits = jnp.einsum("btcgk,bsck->btcgs", qg, k.astype(jnp.float32))
+    logits = logits / jnp.sqrt(jnp.float32(d))
+    pos_ids = off + jnp.arange(t)
+    mask = (jnp.arange(s)[None, :] <= pos_ids[:, None]
+            )[None, :, None, None, :]
+    probs = jax.nn.softmax(jnp.where(mask, logits, -1e30), axis=-1)
+    return jnp.einsum("btcgs,bsck->btcgk", probs,
+                      v.astype(jnp.float32)).reshape(b, t, h, d)
+
+
+PREFIX_S = 64          # max_len of the stand-in cache
+
+
+def _prefix_case(rng, t, h, kv, off, b=1, d=16):
+    """A chunk ``[off, off + t)`` written into a cache whose slots past
+    the chunk hold garbage (large, finite): what a reused lane holds."""
+    q = rng.normal(size=(b, t, h, d))
+    k = rng.normal(size=(b, PREFIX_S, kv, d))
+    v = rng.normal(size=(b, PREFIX_S, kv, d))
+    k[:, off + t:] = 3e4
+    v[:, off + t:] = -3e4
+    return tuple(jnp.asarray(a, jnp.bfloat16) for a in (q, k, v))
+
+
+@pytest.mark.parametrize("heads", [(4, 1), (4, 2)],
+                         ids=["multi_query", "grouped"])
+@pytest.mark.parametrize("t,bq", [(8, 8), (32, 16)],
+                         ids=["narrow_bucket", "wide_bucket"])
+@pytest.mark.parametrize("where", ["start", "unaligned", "end"])
+def test_flash_prefix_matches_dense_body(rng, where, t, bq, heads):
+    """The blocked kernel (interpreter) against ``_decode_chunk``'s
+    dense body: at offset 0, at an offset no block boundary meets, and
+    with the chunk ending at the cache's last slot; two chunk widths
+    (stand-ins for the 64 and 512 buckets; the wide one spans two q
+    blocks); multi-query and grouped heads; garbage past the chunk."""
+    h, kv = heads
+    off = {"start": 0, "unaligned": 13, "end": PREFIX_S - t}[where]
+    q, k, v = _prefix_case(rng, t, h, kv, off, b=2 if kv == 2 else 1)
+    out = flash_prefix_attention(q, k, v, jnp.int32(off), block_q=bq,
+                                 block_k=16, interpret=True)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    ref = _dense_chunk_attention(q, k, v, off)
+    np.testing.assert_allclose(out.astype(jnp.float32), ref, atol=2e-2,
+                               rtol=2e-2)
+
+
+def test_flash_prefix_garbage_and_padded_tail_change_nothing(rng):
+    """Bit for bit: slots past ``off + T`` are never read, whatever
+    they hold, and a bucket-padded tail (the last rows of the chunk are
+    padding: other queries, other K/V in their slots) leaves the real
+    rows' results alone — each row sees only slots up to its own."""
+    t, real, off = 16, 11, 21
+    q, k, v = _prefix_case(rng, t, 4, 1, off)
+    run = lambda q, k, v: flash_prefix_attention(
+        q, k, v, jnp.int32(off), block_q=8, block_k=16, interpret=True)
+    out = run(q, k, v)
+    np.testing.assert_array_equal(
+        out, run(q, k.at[:, off + t:].set(-7e3), v.at[:, off + t:].set(9e3)))
+    pad = slice(off + real, off + t)
+    padded = run(q.at[:, real:].set(1.5), k.at[:, pad].set(0.25),
+                 v.at[:, pad].set(-2.0))
+    np.testing.assert_array_equal(out[:, :real], padded[:, :real])
+
+
+def test_flash_prefix_traced_offset_is_one_program(rng):
+    """``off`` is a traced scalar: one compiled program serves every
+    chunk offset (an admission bucket compiles once)."""
+    q, k, v = _prefix_case(rng, 8, 4, 1, 0)
+    fn = jax.jit(lambda q, k, v, off: flash_prefix_attention(
+        q, k, v, off, block_q=8, block_k=16, interpret=True))
+    for off in (0, 5, 40):
+        np.testing.assert_allclose(
+            fn(q, k, v, jnp.int32(off)).astype(jnp.float32),
+            _dense_chunk_attention(q, k.at[:, off + 8:].set(0),
+                                   v.at[:, off + 8:].set(0), off),
+            atol=2e-2, rtol=2e-2)
+    assert fn._cache_size() == 1
+
+
+def test_prefix_blocks_tile_rule():
+    """The serving cell's five admission widths (buckets 64-512 and the
+    cap-wide 8192) tile against its 8192 slots; a head that is no lane
+    multiple, a chunk that is no whole sublane tile and a cache that is
+    no lane multiple do not."""
+    bf16 = jnp.bfloat16
+    for t, bq in ((64, 64), (128, 128), (256, 128), (512, 128),
+                  (8192, 128)):
+        assert prefix_blocks(t, 8192, 128, 16, bf16) == (bq, 1024)
+    assert prefix_blocks(512, 4096, 128, 12, bf16) == (128, 1024)  # 24 / 2
+    assert prefix_blocks(512, 1536, 128, 1, bf16) == (512, 768)
+    assert prefix_blocks(24, 384, 128, 1, jnp.float32) == (24, 384)
+    assert prefix_blocks(24, 384, 128, 1, bf16) is None           # 24 % 16
+    assert prefix_blocks(8, 8192, 128, 16, bf16) is None
+    assert prefix_blocks(512, 8192, 64, 16, bf16) is None
+    assert prefix_blocks(512, 1000, 128, 16, bf16) is None
+
+
 # ------------------------------------------------ TPU lowering, no chip
 #
 # The Mosaic lowering runs under JAX_PLATFORMS=cpu, and it is where the
@@ -435,3 +544,19 @@ def test_per_shard_matches_unsharded(devices, rng):
                              for a in (q, k, v)), s)
         np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-5)
         assert out.sharding.is_equivalent_to(NamedSharding(mesh, want), 4)
+
+
+@pytest.mark.parametrize("t,h,kv,s", [(64, 16, 1, 8192), (512, 16, 1, 8192),
+                                      (8192, 16, 1, 8192),
+                                      (512, 24, 2, 4096)])
+def test_prefix_kernel_lowers_for_tpu(t, h, kv, s):
+    """The chunked-prefill kernel at the serving cell's widths (16
+    query heads on one K/V head of 128, 8192 slots; the narrowest, the
+    chunk-wide and the cap-wide bucket) and at grouped heads, with the
+    blocks its tile rule picks and a traced offset."""
+    x = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    text = _tpu_lower(flash_prefix_attention, x(1, t, h, 128),
+                      x(1, s, kv, 128), x(1, s, kv, 128),
+                      jax.ShapeDtypeStruct((), jnp.int32)).as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "flash_prefix_fwd" in text

@@ -1258,3 +1258,70 @@ def test_generate_top_p_one_equals_no_filter(rng):
     with pytest.raises(ValueError, match="min_p"):
         generate(params, prompt, CFG, 6, temperature=0.9, min_p=-0.1,
                  key=k)
+
+
+# --------------------------------- chunked prefill's bounded attention
+
+# Kernel-legal widths (a head of 128, 128 slots, float32: chunks in
+# whole tiles of 8) at the smallest size that has them.
+GATE_CFG = tfm.TransformerConfig(vocab_size=64, d_model=256, n_heads=2,
+                                 n_kv_heads=1, n_layers=1, d_ff=64,
+                                 max_len=128)
+
+
+def _gate_call(case, rng, cfg=GATE_CFG):
+    """``(params, cache, tokens, pos0, cfg, kwargs)`` of one
+    ``_decode_chunk`` call of the named shape."""
+    import dataclasses
+
+    rows, t, kw = 2, 8, {"uniform_pos": True}
+    pos0 = jnp.full((rows,), 13, jnp.int32)
+    kv_int8 = False
+    if case == "one_token":
+        t = 1
+    elif case == "per_row":
+        kw, pos0 = {}, jnp.asarray([13, 40], jnp.int32)
+    elif case == "windowed":
+        cfg = dataclasses.replace(cfg, attention_window=32)
+    elif case == "int8":
+        kv_int8 = True
+    elif case == "beam":
+        t = 1
+        kw["beam_anc"] = (jnp.zeros((1, rows, cfg.max_len), jnp.int32),
+                          rows)
+    params = tfm.init_params(jax.random.key(0), cfg)
+    toks = jnp.asarray(rng.integers(0, 64, (rows, t)), jnp.int32)
+    return (params, init_cache(cfg, rows, kv_int8=kv_int8), toks, pos0,
+            cfg, kw)
+
+
+@pytest.mark.parametrize("case", ["uniform_chunk", "one_token", "per_row",
+                                  "windowed", "int8", "beam"])
+def test_decode_chunk_gate(rng, monkeypatch, case):
+    """On a TPU backend a uniform multi-token chunk — an admission, a
+    prefix warm-up — attends through the blocked kernel and computes
+    what the dense body computes; a decode step (T = 1), per-row
+    positions, a ring, an int8 cache and beam ancestry keep the dense
+    body, as every call does on another backend."""
+    from distkeras_tpu.models import generate as gen
+    from distkeras_tpu.ops import attention
+
+    calls = []
+
+    def kernel(q, k, v, off):
+        calls.append(q.shape)
+        return attention.flash_prefix_attention(q, k, v, off,
+                                                interpret=True)
+    monkeypatch.setattr(gen, "flash_prefix_attention", kernel)
+    params, cache, toks, pos0, cfg, kw = _gate_call(case, rng)
+    dense_logits, dense_cache = gen._decode_chunk(params, cache, toks, pos0,
+                                                  cfg, **kw)
+    assert calls == []                      # this backend is no TPU
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    logits, new_cache = gen._decode_chunk(params, cache, toks, pos0, cfg,
+                                          **kw)
+    assert calls == ([(2, 8, 2, 128)] if case == "uniform_chunk" else [])
+    np.testing.assert_allclose(logits, dense_logits, atol=1e-4, rtol=1e-4)
+    for leaf, want in zip(jax.tree.leaves(new_cache),
+                          jax.tree.leaves(dense_cache)):
+        np.testing.assert_array_equal(leaf, want)

@@ -103,6 +103,57 @@ def test_round_counts_equal_a_hand_count(rounds, i, want):
     assert got == want
 
 
+def _admits(spans):
+    return [s for s in spans if s["name"] in ("serving.admit",
+                                              "serving.admit_chunk")]
+
+
+def test_attended_is_max_len_on_the_dense_path(rounds):
+    """No kernel on this backend: every admission program's attention
+    reads all of the lane's slots, and says so."""
+    _, spans = rounds
+    assert [s["fields"]["attended"] for s in _admits(spans)] == [
+        CFG.max_len] * 4
+
+
+def test_attended_is_the_chunks_end_on_the_bounded_path(tmp_path,
+                                                        monkeypatch):
+    """Where the admission programs hold the bounded kernel (a TPU
+    backend, kernel-legal widths; here the interpreter stands in),
+    ``attended`` is ``start + bucket``: A's 20 warm tokens are chunks
+    [0, 8), [8, 16) and the backed-up tail [12, 20).  Never above
+    ``max_len``; ``bucket`` and the other fields stay."""
+    from distkeras_tpu.models import generate as gen
+    from distkeras_tpu.ops import attention
+
+    cfg = tfm.TransformerConfig(vocab_size=64, d_model=256, n_heads=2,
+                                n_kv_heads=1, n_layers=1, d_ff=64,
+                                max_len=128)
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(
+        gen, "flash_prefix_attention",
+        lambda q, k, v, off: attention.flash_prefix_attention(
+            q, k, v, off, interpret=True))
+    path = str(tmp_path / "t.jsonl")
+    with obs.session(trace_path=path):
+        eng = dk.ContinuousBatcher(
+            tfm.init_params(jax.random.key(0), cfg), cfg, lanes=2,
+            max_queue=4, prefill_chunk=8, prompt_buckets=(8,))
+        eng.enqueue(np.arange(21), 2)
+        for _ in range(3):
+            eng.step()
+    admits = _admits([r for r in read_trace(path) if r["kind"] == "span"])
+    assert [s["name"] for s in admits] == [
+        "serving.admit", "serving.admit_chunk", "serving.admit_chunk"]
+    assert [s["fields"]["attended"] for s in admits] == [8, 16, 20]
+    assert [s["fields"]["bucket"] for s in admits] == [8, 8, 8]
+    assert all(s["fields"]["attended"] <= cfg.max_len for s in admits)
+    assert set(admits[0]["fields"]) == {"bucket", "chunks", "lane",
+                                        "request_id", "attended"}
+    assert set(admits[1]["fields"]) == {"bucket", "remaining",
+                                        "request_id", "attended"}
+
+
 # ------------------------------------------ names on the device timeline
 
 
@@ -240,7 +291,7 @@ def test_report_splits_the_step_gap_by_span():
         _span("serving.pump", 10, 1, 6),              # the caller's own
         _span("serving.round", 12, 8, 7, chunks=2, **counts),
         _span("serving.pump", 12.5, 1, 8, 7),
-        _span("serving.admit_chunk", 14, 1, 9, 7, bucket=8),
+        _span("serving.admit_chunk", 14, 1, 9, 7, bucket=8, attended=24),
         _span("serving.step", 15, 4, 10, 7, n=1),
         _span("serving.round", 21, 1, 11, chunks=0, idle=True,
               **dict(counts, tokens=0)),
@@ -256,3 +307,8 @@ def test_report_splits_the_step_gap_by_span():
     assert serving_rounds([r for r in recs
                            if r["name"] != "serving.round"]) is None
     assert "step gap" in render_report(build_report(recs))
+    # The attended share needs the engine's slots a lane.
+    assert out["attended"] == {"programs": 1, "mean": 24}
+    assert serving_rounds(recs, max_len=64)["attended"]["share"] == 0.375
+    assert "= 37.5% of the slab" in render_report(build_report(recs, 64))
+    assert "pass --max-len" in render_report(build_report(recs))
