@@ -49,16 +49,29 @@ LM_BATCH_PER_CHIP = 8
 LM_STEPS = 6
 SERVE_CFG = dict(vocab_size=32768, d_model=1024, n_heads=8, n_layers=8,
                  d_ff=4096, max_len=1025, dtype="bfloat16", rope=True)
+# The looped leg: the extended block (gated SiLU feed-forward, output
+# norms, untied head) applied three times with the same weights, at a
+# toy depth; 1024 slots so that its admissions take the prefix kernel.
+SERVE_LOOPED_CFG = dict(vocab_size=32768, d_model=1024, n_heads=8,
+                        n_layers=4, d_ff=2816, max_len=1024,
+                        dtype="bfloat16", rope=True, rope_theta=1e6,
+                        ffn_gated=True, tie_head=False, post_norms=True,
+                        fused_qkv=True, n_passes=3)
 SERVE_PROMPT_LENS = (20, 100, 300, 500)   # buckets 32 / 128 / 512
 SERVE_NEW = 32
 SERVE_LANES = 4                           # < requests: mid-flight admission
 SERVE_PAD = 1024                          # teacher-forced reference length
 KERNEL_SHAPE = (2, 4096, 8, 128)          # B, L, H, D
 KERNEL_WINDOW = 1024
-# Chunked prefill at the serving cell's widths: query heads, K/V heads,
-# head, cache slots; (bucket, offset) pairs, the offsets on no block edge.
-PREFIX_SHAPE = (16, 1, 128, 8192)
-PREFIX_CHUNKS = ((64, 4099), (512, 2283), (512, 8192 - 512))
+# Chunked prefill at the serving cells' widths: query heads, K/V heads,
+# head, cache slots; (bucket, offset) pairs, the offsets on no block
+# edge.  ``sc1b``: 16 query heads on one K/V head, 8192 slots;
+# ``ouro``: 16 K/V heads (groups of 1), 512 slots.
+PREFIX_SHAPES = {
+    "sc1b": ((16, 1, 128, 8192),
+             ((64, 4099), (512, 2283), (512, 8192 - 512))),
+    "ouro": ((16, 16, 128, 512), ((64, 37), (128, 131), (256, 256))),
+}
 CIFAR_ROWS_PER_CHIP = 4096
 
 # A generated token must score within this of the best logit of the
@@ -178,9 +191,10 @@ def leg_kernels(mesh=None):
 
 def leg_kernel_prefix():
     """flash_prefix_attention == float32 attention over every cache
-    slot under the position mask, for chunks of 64 and 512 queries at
-    offsets no block edge meets and at the cache's end; the slots past
-    a chunk hold garbage, which must not reach the result."""
+    slot under the position mask, at each serving cell's widths
+    (PREFIX_SHAPES), for chunks at offsets no block edge meets and at
+    the cache's end; the slots past a chunk hold garbage, which must
+    not reach the result."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -188,33 +202,36 @@ def leg_kernel_prefix():
     from distkeras_tpu.ops.attention import (flash_prefix_attention,
                                              naive_attention)
 
-    h, kv, d, s_len = PREFIX_SHAPE
     rng = np.random.default_rng(0)
-    k, v = (jnp.asarray(rng.normal(size=(1, s_len, kv, d)), jnp.bfloat16)
-            for _ in range(2))
-
-    def oracle(q, k, v, off):
-        # Every cache slot, K/V heads repeated per query head, float32.
-        wide = lambda a: jnp.repeat(a.astype(jnp.float32), h // kv, axis=2)
-        return naive_attention(q.astype(jnp.float32), wide(k), wide(v),
-                               causal=True, q_offset=off)
-
     kernel = jax.jit(flash_prefix_attention)
     report = {}
-    for t, off in PREFIX_CHUNKS:
-        q = jnp.asarray(rng.normal(size=(1, t, h, d)), jnp.bfloat16)
-        off_ = jnp.int32(off)
-        assert "tpu_custom_call" in kernel.lower(q, k, v, off_).as_text()
-        junk_k, junk_v = k.at[:, off + t:].set(3e4), v.at[:, off + t:].set(-3e4)
-        got = np.asarray(kernel(q, junk_k, junk_v, off_), np.float32)
-        with jax.default_matmul_precision("float32"):  # a true f32 oracle
-            want = np.asarray(jax.jit(oracle)(q, k, v, off_))
-        assert np.isfinite(got).all(), f"{t}@{off}: non-finite kernel output"
-        err = float(np.abs(got - want).max() / np.abs(want).max())
-        report[f"{t}@{off}"] = round(err, 5)
-        assert err < KERNEL_TOL, (
-            f"{t} queries at {off}: kernel vs float32 oracle, max error "
-            f"relative to the largest entry = {err}")
+    for name, ((h, kv, d, s_len), chunks) in PREFIX_SHAPES.items():
+        k, v = (jnp.asarray(rng.normal(size=(1, s_len, kv, d)),
+                            jnp.bfloat16) for _ in range(2))
+
+        def oracle(q, k, v, off, groups=h // kv):
+            # Every cache slot, K/V heads repeated per query head, f32.
+            wide = lambda a: jnp.repeat(a.astype(jnp.float32), groups,
+                                        axis=2)
+            return naive_attention(q.astype(jnp.float32), wide(k), wide(v),
+                                   causal=True, q_offset=off)
+
+        for t, off in chunks:
+            q = jnp.asarray(rng.normal(size=(1, t, h, d)), jnp.bfloat16)
+            off_ = jnp.int32(off)
+            assert "tpu_custom_call" in kernel.lower(q, k, v, off_).as_text()
+            junk_k = k.at[:, off + t:].set(3e4)
+            junk_v = v.at[:, off + t:].set(-3e4)
+            got = np.asarray(kernel(q, junk_k, junk_v, off_), np.float32)
+            with jax.default_matmul_precision("float32"):  # a true f32 oracle
+                want = np.asarray(jax.jit(oracle)(q, k, v, off_))
+            assert np.isfinite(got).all(), (
+                f"{name} {t}@{off}: non-finite kernel output")
+            err = float(np.abs(got - want).max() / np.abs(want).max())
+            report[f"{name}:{t}@{off}"] = round(err, 5)
+            assert err < KERNEL_TOL, (
+                f"{name}: {t} queries at {off}: kernel vs float32 oracle, "
+                f"max error relative to the largest entry = {err}")
     return {"rel_err": report}
 
 
@@ -383,7 +400,7 @@ class Serving:
     """The serving model, its requests and its references, built once
     and shared by the engine legs."""
 
-    def __init__(self):
+    def __init__(self, cfg_kwargs=None):
         import jax
         import jax.numpy as jnp
         import numpy as np
@@ -391,7 +408,7 @@ class Serving:
         from distkeras_tpu.models import transformer as tfm
         from distkeras_tpu.models.generate import generate
 
-        self.cfg = cfg = tfm.TransformerConfig(**SERVE_CFG)
+        self.cfg = cfg = tfm.TransformerConfig(**(cfg_kwargs or SERVE_CFG))
         self.params = tfm.init_params(jax.random.key(0), cfg)
         rng = np.random.default_rng(0)
         # Two requests per prompt length, interleaved so that lengths
@@ -530,6 +547,22 @@ def leg_serve_continuous(serving, meter):
     return report
 
 
+def leg_serve_looped(serving, meter):
+    """A looped stack (SERVE_LOOPED_CFG) through the engine the looped
+    benchmark cell uses — hot-swap, chunked prefill, every program
+    warmed on the live slab — against solo ``generate`` and the full
+    forward, gated on logit distance like the other engine legs."""
+    from distkeras_tpu.serving import ContinuousBatcher
+
+    engine, report = _leg_engine(serving, meter, lambda: ContinuousBatcher(
+        serving.params, serving.cfg, lanes=SERVE_LANES, hot_swap=True,
+        prefill_chunk=256, prompt_buckets=(32, 128, 256),
+        max_queue=len(serving.prompts)))
+    report["kv_planes"] = int(engine.cache["k"].shape[0])
+    assert report["kv_planes"] == serving.cfg.n_passes * serving.cfg.n_layers
+    return report
+
+
 def leg_serve_paged_router(serving, meter):
     from distkeras_tpu.serving import PagedBatcher
 
@@ -591,6 +624,7 @@ def main():
     print("data_plane=" + ("native" if native.available() else "numpy"))
     meter = Meter()
     serving = functools.cache(Serving)  # built by the first leg that serves
+    looped = functools.cache(lambda: Serving(SERVE_LOOPED_CFG))
     # lm first: its spread check reads peaks that never reset.
     legs = [("lm", leg_lm), ("lm_packed", leg_lm_packed),
             ("kernels", leg_kernels), ("kernel_prefix", leg_kernel_prefix),
@@ -598,7 +632,8 @@ def main():
             ("serve_continuous",
              lambda: leg_serve_continuous(serving(), meter)),
             ("serve_paged_router",
-             lambda: leg_serve_paged_router(serving(), meter))]
+             lambda: leg_serve_paged_router(serving(), meter)),
+            ("serve_looped", lambda: leg_serve_looped(looped(), meter))]
     if n >= 4:
         legs += [("kernels_sharded", lambda: leg_kernels(
                      dk.make_mesh(dk.MeshSpec(data=n // 2, model=2)))),

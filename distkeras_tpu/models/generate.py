@@ -31,8 +31,13 @@ from distkeras_tpu.models.transformer import (
     _rms_norm,
     _unembed,
     block_apply,
+    ffn_apply,
+    final_norm,
+    head_table,
+    reject_extended,
     rope_angles,
     rope_rotate,
+    split_qkv,
 )
 from distkeras_tpu.models.quant import (
     deq,
@@ -51,7 +56,10 @@ from distkeras_tpu.ops.attention import (
 
 def init_cache(cfg: TransformerConfig, batch: int, dtype=None,
                kv_int8: bool = False):
-    """Per-layer KV buffers [L, B, max_len, kv_heads, head_dim].
+    """Per-layer KV buffers [L, B, max_len, kv_heads, head_dim] — one
+    plane per (pass, layer) where the stack is looped: the leading axis
+    is ``cfg.kv_planes`` = n_passes * n_layers, plane ``r * L + l``
+    holding pass ``r`` of layer ``l``.
 
     Under GQA (cfg.n_kv_heads < n_heads) the cache carries only the
     shared K/V heads — the n_heads/kv_heads memory and HBM-bandwidth
@@ -64,8 +72,10 @@ def init_cache(cfg: TransformerConfig, batch: int, dtype=None,
     roofline.  The presence of the scale leaves is what switches the
     decode attention onto the dequantizing einsums.
     """
+    if kv_int8:
+        reject_extended(cfg, "the int8 KV cache (kv_int8)")
     dtype = jnp.int8 if kv_int8 else (dtype or jnp.dtype(cfg.dtype))
-    shape = (cfg.n_layers, batch, cfg.max_len, cfg.kv_heads, cfg.head_dim)
+    shape = (cfg.kv_planes, batch, cfg.max_len, cfg.kv_heads, cfg.head_dim)
     cache = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
     if kv_int8:
         cache["k_scale"] = jnp.zeros(shape[:-1], jnp.float32)
@@ -103,6 +113,14 @@ def prefill(params, prompt, cfg: TransformerConfig,
         raise ValueError(
             f"prompt length {p_len} exceeds max_len={cfg.max_len} "
             "(the KV cache size)")
+    if cfg.n_passes > 1:
+        # A looped stack has ONE cached body, whose pass loop is one
+        # traced body: the prompt is a chunk at position 0 against an
+        # empty cache.
+        logits, cache = _decode_chunk(
+            params, init_cache(cfg, b, kv_int8=kv_int8), prompt,
+            jnp.zeros((b,), jnp.int32), cfg, uniform_pos=True)
+        return cache, (logits[:, -1] if last_logits else None)
     x = params["tok_emb"][prompt].astype(dtype)
     rope_ang = None
     if cfg.rope:
@@ -196,6 +214,9 @@ def _ancestry_attend(qg, ck, cv, anc_oh, mask_b, cfg: TransformerConfig,
         b, cfg.n_heads, cfg.head_dim)
 
 
+_BASE_ONLY = "a windowed (rolling) or ragged-prompt (prompt_lengths) decode"
+
+
 def _decode_step(params, cache, tokens, pos, cfg: TransformerConfig,
                  pad_lens=None, beam_anc=None):
     """One position: tokens [B] at position ``pos`` -> (logits [B, V], cache).
@@ -216,6 +237,8 @@ def _decode_step(params, cache, tokens, pos, cfg: TransformerConfig,
     """
     dtype = jnp.dtype(cfg.dtype)
     b = tokens.shape[0]
+    if cfg.attention_window is not None or pad_lens is not None:
+        reject_extended(cfg, _BASE_ONLY)
     if cfg.attention_window is None and pad_lens is None:
         out, cache = _decode_chunk(params, cache, tokens[:, None],
                                    jnp.full((b,), pos, jnp.int32), cfg,
@@ -443,8 +466,194 @@ def chunk_attends_prefix(cfg: TransformerConfig, t_len: int, cache,
                                  cache["k"].dtype, sharded=sharded))
 
 
+def base_body_only(cfg: TransformerConfig, params, cache,
+                   beam: bool = False) -> str | None:
+    """What keeps a :func:`_decode_chunk` call on the base body, by
+    name — or None: the call takes :func:`_chunk_in_place`.  One static
+    question on what the call already holds (the serving engines ask
+    it of their live state to choose the admission program): a ring, a
+    MoE feed-forward, beam ancestry, int8 scales in the cache, int8
+    weights, a cache the compiler partitions."""
+    if cfg.attention_window is not None:
+        return "a windowed (rolling) decode"
+    if cfg.num_experts:
+        return "a MoE feed-forward (num_experts > 0)"
+    if beam:
+        return "beam search"
+    if "k_scale" in cache:
+        return "the int8 KV cache (kv_int8)"
+    if is_quantized(params):
+        return "int8-quantized weights (models/quant)"
+    if is_partitioned(cache["k"]):
+        return "a partitioned cache (a tensor-parallel serving_plan)"
+    return None
+
+
+def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
+                    uniform_pos: bool = False, lane=None):
+    """:func:`_decode_chunk`'s body for every call that
+    :func:`base_body_only` does not hold back: ``tokens [B, T]`` at
+    positions ``pos0[b] + (0..T-1)`` -> ``(logits [B, T, V] f32, cache)``
+    — of the LAST pass where the stack is looped (``cfg.n_passes``
+    applications of the same weights).  It implements the extended
+    block (gated feed-forward, sandwich norms, untied head, either
+    layout of the attention projections).
+
+    One traced layer body: ``lax.scan`` over the layers inside
+    ``lax.scan`` over the passes (192 layer applications of a 48-layer
+    stack looped four times are one body, not 192 copies), the final
+    norm after every pass.  Pass ``r`` of layer ``l`` reads and writes
+    plane ``r * L + l`` of the cache only.
+
+    **The slab is never copied.**  Inside the loops it is read-only: a
+    layer attends its plane's slots STRICTLY BEFORE the chunk (mask
+    ``slot < pos0[b]``) together with the chunk's own K/V, which it
+    holds in registers, under one softmax — the same sum the base body
+    takes over a cache it has already written into.  The chunk's K/V
+    of every plane leave the loops as scan outputs and are written
+    once, at the end, in place (the caller donates the cache): one
+    ``dynamic_update_slice`` where every row is at one position, one
+    per row (a window over all planes) where rows differ — the decode
+    step, and speculative verification.  K/V are rounded to the
+    cache's dtype BEFORE the chunk attends them, so a token attends
+    itself exactly as later tokens will read it.
+
+    ``lane`` (traced int32; requires B == 1, ``uniform_pos``): the
+    chunk is admitted into lane ``lane`` of a slab of many lanes
+    without cutting the lane out — each plane's ``[1, S]`` row is
+    sliced for reading, the chunk is written at ``[:, lane, pos0]``.
+
+    ``uniform_pos`` multi-token chunks on a TPU attend through
+    ``flash_prefix_attention`` (:func:`chunk_attends_prefix`): the
+    kernel wants the chunk inside the cache, so the plane's row (not
+    the slab) gets it first."""
+    dtype = jnp.dtype(cfg.dtype)
+    b, t_len = tokens.shape
+    n_layers, s_len = cfg.n_layers, cfg.max_len
+    groups = cfg.n_heads // cfg.kv_heads
+    if lane is not None and (b != 1 or not uniform_pos):
+        raise ValueError("lane= admits ONE row at one position")
+    ck_all, cv_all = cache["k"], cache["v"]      # [R*L, B|lanes, S, kv, hd]
+    with jax.named_scope("embed"):
+        x = params["tok_emb"][tokens].astype(dtype)           # [B, T, D]
+        pos_ids = pos0[:, None] + jnp.arange(t_len)[None, :]  # [B, T]
+        rope_ang = None
+        if cfg.rope:
+            rope_ang = rope_angles(pos_ids, cfg.head_dim,
+                                   cfg.rope_theta)[:, :, None, :]
+        else:
+            x = x + params["pos_emb"][pos_ids].astype(dtype)
+    bounded = chunk_attends_prefix(cfg, t_len, cache,
+                                   uniform_pos=uniform_pos,
+                                   sharded=is_partitioned(x))
+    # The dense body's two masks: cache slots before the chunk, and
+    # the chunk's own causal triangle ([B, T, kv, g, S | T]).
+    before = (jnp.arange(s_len)[None, :] < pos0[:, None]
+              )[:, None, None, None, :]
+    causal = jnp.tril(jnp.ones((t_len, t_len), bool))[None, :, None, None, :]
+    scale = 1.0 / jnp.sqrt(jnp.float32(cfg.head_dim))
+
+    def layer(x, lp, plane):
+        h = _rms_norm(x, lp["ln1_scale"])
+        with jax.named_scope("attn_proj"):
+            if cfg.fused_qkv:
+                q, k, v = split_qkv(
+                    jnp.einsum("btd,dk->btk", h, lp["attn"]["wqkv"]), cfg)
+            else:
+                q, k, v = (jnp.einsum("btd,dhk->bthk", h, lp["attn"][w])
+                           for w in ("wq", "wk", "wv"))
+            if rope_ang is not None:
+                q, k = rope_rotate(q, rope_ang), rope_rotate(k, rope_ang)
+            k, v = k.astype(ck_all.dtype), v.astype(cv_all.dtype)
+        with jax.named_scope("kv_slab"):
+            # This plane's rows, for reading: [B, S, kv, hd].
+            if lane is None:
+                ck, cv = (jax.lax.dynamic_index_in_dim(
+                    a, plane, 0, keepdims=False) for a in (ck_all, cv_all))
+            else:
+                ck, cv = (jax.lax.dynamic_slice(
+                    a, (plane, lane, 0, 0, 0), (1, 1) + a.shape[2:])[0]
+                    for a in (ck_all, cv_all))
+            if bounded:
+                at = (jnp.int32(0), pos0[0], jnp.int32(0), jnp.int32(0))
+                ck = jax.lax.dynamic_update_slice(ck, k, at)
+                cv = jax.lax.dynamic_update_slice(cv, v, at)
+        with jax.named_scope("attn"):
+            if bounded:
+                attn = flash_prefix_attention(q.astype(ck.dtype), ck, cv,
+                                              pos0[0])
+            else:
+                qg = q.astype(jnp.float32).reshape(
+                    b, t_len, cfg.kv_heads, groups, cfg.head_dim)
+                old = jnp.einsum("btcgk,bsck->btcgs", qg,
+                                 ck.astype(jnp.float32)) * scale
+                new = jnp.einsum("btcgk,buck->btcgu", qg,
+                                 k.astype(jnp.float32)) * scale
+                probs = jax.nn.softmax(jnp.concatenate(
+                    [jnp.where(before, old, -1e30),
+                     jnp.where(causal, new, -1e30)], axis=-1), axis=-1)
+                attn = (jnp.einsum("btcgs,bsck->btcgk", probs[..., :s_len],
+                                   cv.astype(jnp.float32))
+                        + jnp.einsum("btcgu,buck->btcgk",
+                                     probs[..., s_len:],
+                                     v.astype(jnp.float32))).reshape(
+                    b, t_len, cfg.n_heads, cfg.head_dim)
+        with jax.named_scope("attn_proj"):
+            attn = attn.astype(dtype)
+            if cfg.fused_qkv:
+                a = jnp.einsum("btk,kd->btd", attn.reshape(b, t_len, -1),
+                               lp["attn"]["wo"])
+            else:
+                a = jnp.einsum("bthk,hkd->btd", attn, lp["attn"]["wo"])
+        if cfg.post_norms:
+            a = _rms_norm(a, lp["ln1_post_scale"])
+        # (The stream keeps the compute dtype whatever the weights'
+        # is: a scan's carry cannot widen on the way.)
+        with jax.named_scope("attn_proj"):
+            x = x + a.astype(dtype)
+        h = _rms_norm(x, lp["ln2_scale"])
+        with jax.named_scope("mlp"):
+            y = ffn_apply(lp["ffn"], h, cfg)
+        if cfg.post_norms:
+            y = _rms_norm(y, lp["ln2_post_scale"])
+        with jax.named_scope("mlp"):
+            x = x + y.astype(dtype)
+        return x, (k, v)
+
+    def one_pass(x, r):
+        x, kv = jax.lax.scan(
+            lambda x, lw: layer(x, lw[0], r * n_layers + lw[1]), x,
+            (params["layers"], jnp.arange(n_layers)))
+        return final_norm(x, params, cfg).astype(dtype), kv
+
+    x, (new_k, new_v) = jax.lax.scan(one_pass, x, jnp.arange(cfg.n_passes))
+    with jax.named_scope("head"):
+        out = jnp.einsum("btd,vd->btv", x,
+                         head_table(params, cfg).astype(dtype))
+    with jax.named_scope("kv_slab"):
+        # [R, L, B, T, kv, hd] -> planes first, then ONE write in place.
+        new_k, new_v = (a.reshape((cfg.kv_planes,) + a.shape[2:])
+                        for a in (new_k, new_v))
+        zero = jnp.int32(0)
+        if uniform_pos:
+            at = (zero, zero if lane is None else lane, pos0[0], zero, zero)
+            ck_all = jax.lax.dynamic_update_slice(ck_all, new_k, at)
+            cv_all = jax.lax.dynamic_update_slice(cv_all, new_v, at)
+        else:
+            # One window over all planes per row, unrolled: a
+            # ``fori_loop`` over the rows with both slabs as carry
+            # trips a RET_CHECK in the TPU compiler (AOT, PR 27).
+            for i in range(b):
+                at = (zero, jnp.int32(i), pos0[i], zero, zero)
+                ck_all = jax.lax.dynamic_update_slice(
+                    ck_all, new_k[:, i:i + 1], at)
+                cv_all = jax.lax.dynamic_update_slice(
+                    cv_all, new_v[:, i:i + 1], at)
+    return out.astype(jnp.float32), {"k": ck_all, "v": cv_all}
+
+
 def _decode_chunk(params, cache, tokens, pos0, cfg: TransformerConfig,
-                  uniform_pos: bool = False, beam_anc=None):
+                  uniform_pos: bool = False, beam_anc=None, lane=None):
     """Process T new tokens per row against the cache in ONE pass:
     ``tokens [B, T]`` at global positions ``pos0[b] + (0..T-1)`` ->
     ``(logits [B, T, V] f32, cache)``.
@@ -455,6 +664,13 @@ def _decode_chunk(params, cache, tokens, pos0, cfg: TransformerConfig,
     K/V land in the cache at per-row offsets, so rows at different
     positions (speculative decoding's per-row accept divergence) share
     one compiled program.
+
+    Two bodies.  Every PLAIN call — no ring, MoE, beam ancestry, int8
+    cache or weights, partitioned cache (:func:`base_body_only`) —
+    takes :func:`_chunk_in_place`: one traced layer under ``lax.scan``,
+    the slab written in place, the extended block and the pass loop of
+    a looped stack.  The rest of this docstring is the BASE block's
+    body below, which keeps those features and nothing else.
 
     Windowed (``attention_window``) configs run in three shapes:
     (a) the per-row path with T == 1 — each row writes its ring slot
@@ -502,6 +718,18 @@ def _decode_chunk(params, cache, tokens, pos0, cfg: TransformerConfig,
     whole attention read (measured 2026-07-31 on one v5e, not
     re-measured since).
     """
+    why = base_body_only(cfg, params, cache, beam=beam_anc is not None)
+    if why is None:
+        # Every plain call: one traced layer under scans, the slab
+        # written in place.  What follows is the BASE block's body for
+        # what that one does not express (the docstring above).
+        return _chunk_in_place(params, cache, tokens, pos0, cfg,
+                               uniform_pos=uniform_pos, lane=lane)
+    reject_extended(cfg, why)
+    if lane is not None:
+        raise ValueError(f"lane= (in-place admission into one lane of a "
+                         f"slab) does not compose with {why}: cut the "
+                         "lane out of the slab")
     dtype = jnp.dtype(cfg.dtype)
     b, t_len = tokens.shape
     with jax.named_scope("embed"):
@@ -866,6 +1094,7 @@ def _resolve_prompt_cache(prompt_cache, cfg, b, p, max_new_tokens,
     quantization/batch constraints and returns ``(cache, cached_len)``
     with a batch-1 prefix fanned out to ``b`` rows."""
     pc_cache, cached_len = prompt_cache
+    reject_extended(cfg, "prompt_cache (a prefilled shared prefix)")
     if cfg.attention_window is not None:
         raise ValueError("prompt_cache requires a full-cache config "
                          "(no attention_window)")
@@ -1175,6 +1404,7 @@ def beam_search(params, prompt, cfg: TransformerConfig,
       pre-round-3 construction; exact same hypotheses, more HBM
       traffic per step at moderate beam widths).
     """
+    reject_extended(cfg, "beam_search")
     params = _device_tree(params)
     b, p = prompt.shape
     w = beam_width

@@ -51,7 +51,10 @@ from distkeras_tpu.models.transformer import TransformerConfig
 def _validate(params, draft_params, cfg, draft_cfg, p, max_new_tokens,
               n_draft, temperature, key, eos_token=None):
     from distkeras_tpu.models.generate import _check_eos
+    from distkeras_tpu.models.transformer import reject_extended
 
+    for c in (cfg, draft_cfg):
+        reject_extended(c, "speculative_generate (speculative decoding)")
     _check_eos(eos_token, cfg)
     if draft_cfg.vocab_size != cfg.vocab_size:
         raise ValueError(

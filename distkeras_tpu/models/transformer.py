@@ -114,6 +114,52 @@ class TransformerConfig:
     # (eval perplexity stays a pure model-quality number).  Typical:
     # 1e-4.  Works on every head path, including chunked CE.
     z_loss_coef: float = 0.0
+    # --- the EXTENDED block (any of the five below off its default) ---
+    # Gated feed-forward (SwiGLU): (silu(h·w1) * (h·w3))·w2, three
+    # matrices (w1 the gate, w3 the up projection, w2 down) instead of
+    # gelu(h·w1)·w2.
+    ffn_gated: bool = False
+    # Untied output head: logits = hidden · head^T with its own
+    # ``head [V, D]`` leaf instead of ``tok_emb``.
+    tie_head: bool = True
+    # A norm on each sublayer's OUTPUT as well as its input (four norms
+    # a layer): x = x + rms(attn(rms(x))); x = x + rms(ffn(rms(x))).
+    post_norms: bool = False
+    # The attention projections as MATRICES: q, k and v side by side in
+    # one ``attn/wqkv [L, D, (heads + 2 kv_heads) * head_dim]`` (one
+    # product a layer) and ``attn/wo [L, heads * head_dim, D]``, in
+    # place of wq / wk / wv ``[L, D, heads, head_dim]`` and wo ``[L,
+    # heads, head_dim, D]``.  On the TPU a [D, heads, head_dim] leaf is
+    # tiled over (heads, head_dim) and a decode step re-lays it out
+    # before every product: 3.6 ms of a 46.7 ms step and 1.2 GB of
+    # temporaries at 48 layers of 16 heads x 128 (chip, PR 27).  A
+    # layout, not a model: both give the same function of the same
+    # numbers.  The sharding rules, LoRA and int8 weights know the
+    # split layout only.
+    fused_qkv: bool = False
+    # Looped stack: the SAME ``n_layers`` layers' weights are applied
+    # ``n_passes`` times, the final norm after every pass (the next
+    # pass starts from it), an exit gate D -> 1 beside the head.  The
+    # KV cache holds one plane per (pass, layer): n_passes * n_layers.
+    # Served, every pass runs and the last pass's logits are the
+    # model's (exit threshold 1); :func:`apply_passes` returns every
+    # pass's logits and the exit distribution.
+    n_passes: int = 1
+
+    @property
+    def extended(self) -> bool:
+        """Whether this config uses a switch above.  ``block_apply``
+        (so ``apply`` and ``lm_loss``) and the cached decode body every
+        plain call takes (``generate._chunk_in_place``) implement them;
+        every other path — :func:`reject_extended` — knows the base
+        block only and says so."""
+        return self.n_passes != 1 or any(
+            getattr(self, k) != base for k, base in _SWITCHES.items())
+
+    @property
+    def kv_planes(self) -> int:
+        """(k, v) planes of the decode cache: one per pass and layer."""
+        return self.n_passes * self.n_layers
 
     @property
     def head_dim(self) -> int:
@@ -127,6 +173,32 @@ class TransformerConfig:
             raise ValueError(
                 f"n_kv_heads={kv} must divide n_heads={self.n_heads}")
         return kv
+
+
+# The extended block's switches and their defaults (the base block).
+_SWITCHES = {"ffn_gated": False, "tie_head": True, "post_norms": False,
+             "fused_qkv": False}
+
+
+def reject_extended(cfg: "TransformerConfig", path: str,
+                    allow: tuple = ()) -> None:
+    """THE validation site for what the extended block does not run:
+    every path that was not made to work with it (and tested) calls
+    this with its own name and raises for an extended configuration.
+    ``allow`` names the switches the path does take — what shares
+    ``block_apply`` lacks only the pass loop, and a looped stack is
+    never allowed (training the last pass alone would be a different
+    objective under the model's name)."""
+    on = [k for k, base in _SWITCHES.items()
+          if getattr(cfg, k) != base and k not in allow]
+    if cfg.n_passes > 1 or on:
+        what = (f"a looped stack (n_passes={cfg.n_passes})"
+                if cfg.n_passes > 1 else
+                f"the extended block ({' / '.join(on)})")
+        raise ValueError(
+            f"{path} does not support {what}: it runs through "
+            "apply / apply_passes, generate and ContinuousBatcher "
+            "(monolithic lanes, chunked prefill) only")
 
 
 _REMAT_POLICIES = {
@@ -203,6 +275,10 @@ def init_params(rng, cfg: TransformerConfig):
         raise ValueError(
             f"moe_top_k={cfg.moe_top_k} must be in [1, num_experts="
             f"{cfg.num_experts}]")
+    if cfg.n_passes < 1:
+        raise ValueError(f"n_passes must be >= 1, got {cfg.n_passes}")
+    if cfg.num_experts:
+        reject_extended(cfg, "a MoE feed-forward (num_experts > 0)")
     _validate_remat_policy(cfg)
     keys = jax.random.split(rng, 12)
     d, f, h, hd = cfg.d_model, cfg.d_ff, cfg.n_heads, cfg.head_dim
@@ -222,6 +298,12 @@ def init_params(rng, cfg: TransformerConfig):
             "wo": stack(keys[3], (h, hd, d), d),
         },
     }
+    if cfg.fused_qkv:  # the same draws, laid out as matrices
+        a = layers["attn"]
+        layers["attn"] = {
+            "wqkv": jnp.concatenate([a[w].reshape(L, d, -1)
+                                     for w in ("wq", "wk", "wv")], axis=-1),
+            "wo": a["wo"].reshape(L, -1, d)}
     if cfg.num_experts:
         layers["moe"] = {
             "wg": stack(keys[4], (d, cfg.num_experts), d),
@@ -233,6 +315,12 @@ def init_params(rng, cfg: TransformerConfig):
             "w1": stack(keys[7], (d, f), d),
             "w2": stack(keys[8], (f, d), f),
         }
+        if cfg.ffn_gated:
+            layers["ffn"]["w3"] = stack(jax.random.fold_in(keys[7], 1),
+                                        (d, f), d)
+    if cfg.post_norms:
+        layers["ln1_post_scale"] = jnp.ones((L, d))
+        layers["ln2_post_scale"] = jnp.ones((L, d))
     params = {
         # Tied embedding/unembedding: std 1/sqrt(d) keeps initial logits
         # O(1) so the initial LM loss sits at ~ln(vocab).
@@ -247,6 +335,13 @@ def init_params(rng, cfg: TransformerConfig):
                 f"(d_model={d}, n_heads={h})")
     else:
         params["pos_emb"] = _dense_init(keys[10], (cfg.max_len, d), 1.0) * 0.02
+    if not cfg.tie_head:
+        params["head"] = _dense_init(keys[11], (cfg.vocab_size, d), d)
+    if cfg.n_passes > 1:
+        # Exit gate D -> 1, evaluated after every pass's final norm.
+        params["exit_w"] = _dense_init(jax.random.fold_in(keys[11], 1),
+                                       (d,), d)
+        params["exit_b"] = jnp.zeros(())
     return params
 
 
@@ -346,12 +441,15 @@ def _dropout(x, rate: float, key):
 #   kv_slab    cutting a layer's K/V out of the cache slab, and putting
 #              it back (decode and chunked prefill)
 #   mlp        the feed-forward (or MoE) block
-#   head       unembedding and cross-entropy
-SCOPES = ("embed", "norm", "attn_proj", "attn", "kv_slab", "mlp", "head")
+#   head       unembedding (tied or not) and cross-entropy
+#   loop_exit  what sits between two passes of a looped stack: the
+#              final norm after every pass and the exit gate
+SCOPES = ("embed", "norm", "attn_proj", "attn", "kv_slab", "mlp", "head",
+          "loop_exit")
 
 
-def _rms_norm(x, scale, eps=1e-6):
-    with jax.named_scope("norm"):
+def _rms_norm(x, scale, eps=1e-6, scope="norm"):
+    with jax.named_scope(scope):
         var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1,
                        keepdims=True)
         return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale
@@ -489,6 +587,35 @@ def _moe_dense_block(lp, x, cfg: TransformerConfig):
                       ).astype(dtype)
 
 
+def final_norm(x, params, cfg: TransformerConfig):
+    """The stack's one final norm; in a looped stack it runs after
+    every pass and belongs to what sits between two passes."""
+    return _rms_norm(x, params["ln_f_scale"],
+                     scope="loop_exit" if cfg.n_passes > 1 else "norm")
+
+
+def split_qkv(qkv, cfg: TransformerConfig):
+    """``[..., (heads + 2 kv_heads) * head_dim]`` — the fused
+    projection's output, or the ``wqkv`` matrix — cut into q, k and v
+    with the heads split out: ``[..., heads | kv_heads, head_dim]``."""
+    hd = cfg.head_dim
+    cut = (cfg.n_heads * hd, (cfg.n_heads + cfg.kv_heads) * hd)
+    return tuple(a.reshape(a.shape[:-1] + (-1, hd))
+                 for a in jnp.split(qkv, cut, axis=-1))
+
+
+def ffn_apply(ffn, h, cfg: TransformerConfig):
+    """The dense feed-forward over ``h [..., D]``: gelu(h·w1)·w2, or
+    gated (silu(h·w1) * (h·w3))·w2 — ONE definition for
+    :func:`block_apply` and ``generate._chunk_in_place``."""
+    y = jnp.einsum("...d,df->...f", h, ffn["w1"])
+    if cfg.ffn_gated:
+        y = jax.nn.silu(y) * jnp.einsum("...d,df->...f", h, ffn["w3"])
+    else:
+        y = jax.nn.gelu(y)
+    return jnp.einsum("...f,fd->...d", y, ffn["w2"])
+
+
 def block_apply(layer_params, x, cfg: TransformerConfig,
                 attention_fn: Callable, rope_ang=None, drop_key=None,
                 return_kv=False, moe_dense_routing=False):
@@ -506,12 +633,19 @@ def block_apply(layer_params, x, cfg: TransformerConfig,
     jax.checkpoint.  ``drop_key`` non-None enables residual dropout.
     """
     h = _rms_norm(x, layer_params["ln1_scale"])
-    a = _attention_block(layer_params["attn"], h, attention_fn, rope_ang,
+    attn_w = layer_params["attn"]
+    if cfg.fused_qkv:  # matrices (init_params): heads split out here
+        wq, wk, wv = split_qkv(attn_w["wqkv"], cfg)
+        attn_w = {"wq": wq, "wk": wk, "wv": wv,
+                  "wo": attn_w["wo"].reshape(-1, cfg.head_dim, cfg.d_model)}
+    a = _attention_block(attn_w, h, attention_fn, rope_ang,
                          kv_groups=cfg.n_heads // cfg.kv_heads,
                          return_kv=return_kv)
     kv = None
     if return_kv:
         a, kv = a
+    if cfg.post_norms:
+        a = _rms_norm(a, layer_params["ln1_post_scale"])
     # The residual sums (and the dropout before them) go to the
     # sublayer whose output they take in: no operation of a block is
     # left outside the vocabulary.
@@ -527,12 +661,10 @@ def block_apply(layer_params, x, cfg: TransformerConfig,
         elif cfg.num_experts:
             y, aux = _moe_block(layer_params["moe"], h, cfg)
         else:
-            y = jnp.einsum(
-                "bsf,fd->bsd",
-                jax.nn.gelu(jnp.einsum("bsd,df->bsf", h,
-                                       layer_params["ffn"]["w1"])),
-                layer_params["ffn"]["w2"])
+            y = ffn_apply(layer_params["ffn"], h, cfg)
             aux = jnp.zeros((), jnp.float32)
+    if cfg.post_norms:
+        y = _rms_norm(y, layer_params["ln2_post_scale"])
     with jax.named_scope("mlp"):
         if drop_key is not None:
             y = _dropout(y, cfg.dropout, jax.random.fold_in(drop_key, 1))
@@ -540,31 +672,12 @@ def block_apply(layer_params, x, cfg: TransformerConfig,
     return (out, aux, kv) if return_kv else (out, aux)
 
 
-def apply_hidden(params, tokens, cfg: TransformerConfig,
-                 attention_fn: Callable | None = None, dropout_rng=None,
-                 moe_dense_routing: bool = False, segment_ids=None):
-    """Trunk forward: tokens [B, S] int32 -> final-norm hidden [B, S, D].
-
-    Everything in :func:`apply` except the unembedding matmul; the
-    chunked cross-entropy path consumes the hidden states directly so
-    the full-vocab logits never materialize.  Returns (hidden, aux).
-
-    ``moe_dense_routing=True`` scores MoE configs with the capacity-FREE
-    dense routing that :func:`~distkeras_tpu.models.generate.generate`
-    and ``prefill`` use — the *inference semantics* (aux comes back 0).
-    Evaluating a trained MoE this way agrees exactly with the KV-cached
-    decode at ANY capacity factor; the default (training capacity
-    dispatch) diverges for every token the router would capacity-drop.
-    No-op for dense configs.
-
-    ``segment_ids [B, S]`` int32 (packed sequences, data/packing.py):
-    attention is masked to within-segment pairs; 0 marks padding.
-    With ``rope=True`` the packed forward is EXACT vs running each
-    document alone — rotary scores depend only on within-document
-    relative distance, which a uniform position shift preserves.  With
-    a learned position table, packed documents see shifted rows
-    (standard packing behavior; prefer rope for packed training).
-    """
+def _trunk(params, tokens, cfg: TransformerConfig,
+           attention_fn: Callable | None = None, dropout_rng=None,
+           moe_dense_routing: bool = False, segment_ids=None):
+    """The trunk of :func:`apply_hidden`: ``(hiddens, aux)`` with one
+    final-norm hidden ``[B, S, D]`` per pass of the stack (a list of
+    ``cfg.n_passes``; one for every unlooped config)."""
     attention_fn = _resolve_attention_fn(cfg, attention_fn, segment_ids)
     dtype = jnp.dtype(cfg.dtype)
     b, s = tokens.shape
@@ -590,18 +703,62 @@ def apply_hidden(params, tokens, cfg: TransformerConfig,
     # Python loop (not scan): attention_fn may close over shard_map /
     # pallas calls whose tracing under scan complicates sharding; layer
     # counts at this framework's scale compile fine unrolled.
-    for i in range(cfg.n_layers):
-        lp = jax.tree.map(lambda a: a[i], params["layers"])
-        drop_key = (jax.random.fold_in(dropout_rng, i) if dropping
-                    else None)
-        x, aux = block(lp, x, cfg, attention_fn, rope_ang, drop_key)
-        aux_total = aux_total + aux
+    hiddens = []
+    for r in range(cfg.n_passes):
+        for i in range(cfg.n_layers):
+            lp = jax.tree.map(lambda a: a[i], params["layers"])
+            # Pass 0 keeps the keys 0..L-1 it always had (L is the
+            # embedding's); later passes continue past them.
+            drop_key = (jax.random.fold_in(
+                dropout_rng, r * (cfg.n_layers + 1) + i) if dropping
+                else None)
+            x, aux = block(lp, x, cfg, attention_fn, rope_ang, drop_key)
+            aux_total = aux_total + aux
+        # The ONE final norm, after every pass: the next pass starts
+        # from the normed stream.
+        x = final_norm(x, params, cfg)
+        hiddens.append(x)
+    return hiddens, aux_total
 
-    return _rms_norm(x, params["ln_f_scale"]), aux_total
+
+def apply_hidden(params, tokens, cfg: TransformerConfig,
+                 attention_fn: Callable | None = None, dropout_rng=None,
+                 moe_dense_routing: bool = False, segment_ids=None):
+    """Trunk forward: tokens [B, S] int32 -> final-norm hidden [B, S, D]
+    (of the LAST pass where the stack is looped: what is served).
+
+    Everything in :func:`apply` except the unembedding matmul; the
+    chunked cross-entropy path consumes the hidden states directly so
+    the full-vocab logits never materialize.  Returns (hidden, aux).
+
+    ``moe_dense_routing=True`` scores MoE configs with the capacity-FREE
+    dense routing that :func:`~distkeras_tpu.models.generate.generate`
+    and ``prefill`` use — the *inference semantics* (aux comes back 0).
+    Evaluating a trained MoE this way agrees exactly with the KV-cached
+    decode at ANY capacity factor; the default (training capacity
+    dispatch) diverges for every token the router would capacity-drop.
+    No-op for dense configs.
+
+    ``segment_ids [B, S]`` int32 (packed sequences, data/packing.py):
+    attention is masked to within-segment pairs; 0 marks padding.
+    With ``rope=True`` the packed forward is EXACT vs running each
+    document alone — rotary scores depend only on within-document
+    relative distance, which a uniform position shift preserves.  With
+    a learned position table, packed documents see shifted rows
+    (standard packing behavior; prefer rope for packed training).
+    """
+    hiddens, aux = _trunk(params, tokens, cfg, attention_fn, dropout_rng,
+                          moe_dense_routing, segment_ids)
+    return hiddens[-1], aux
+
+
+def head_table(params, cfg: TransformerConfig):
+    """The output head's ``[V, D]`` table: ``tok_emb`` when tied."""
+    return params["tok_emb"] if cfg.tie_head else params["head"]
 
 
 def _unembed(hidden, params, cfg: TransformerConfig):
-    """Tied unembedding head: hidden [B, S, D] -> f32 logits [B, S, V].
+    """Unembedding head: hidden [B, S, D] -> f32 logits [B, S, V].
 
     The single definition of the head — apply, apply_pipelined and the
     materialized loss branch all call it, so the 'chunked CE matches
@@ -610,7 +767,7 @@ def _unembed(hidden, params, cfg: TransformerConfig):
     dtype = jnp.dtype(cfg.dtype)
     with jax.named_scope("head"):
         logits = jnp.einsum("bsd,vd->bsv", hidden,
-                            params["tok_emb"].astype(dtype))
+                            head_table(params, cfg).astype(dtype))
         return logits.astype(jnp.float32)
 
 
@@ -632,6 +789,36 @@ def apply(params, tokens, cfg: TransformerConfig,
                                 dropout_rng, moe_dense_routing,
                                 segment_ids)
     return _unembed(x, params, cfg), aux_total
+
+
+def apply_passes(params, tokens, cfg: TransformerConfig,
+                 attention_fn: Callable | None = None):
+    """Every pass of a looped stack: ``(logits [R, B, S, V] f32,
+    exit_probs [R, B, S] f32)``.
+
+    After pass ``r`` the exit gate reads the normed stream:
+    ``lambda_r = sigmoid(x_r · exit_w + exit_b)``.  The probability of
+    leaving at pass ``r`` is ``p_r = lambda_r * prod_{j<r}(1 -
+    lambda_j)`` and the last pass takes what is left, ``p_{R-1} =
+    prod_{j<R-1}(1 - lambda_j)``, so the R values sum to 1.  Served at
+    exit threshold 1, the model's logits are ``logits[-1]`` — what
+    :func:`apply` returns and the engines compute; an adaptive exit (a
+    pass count that differs by token) is not implemented."""
+    if cfg.n_passes < 2:
+        raise ValueError(
+            f"apply_passes needs a looped stack (n_passes >= 2, got "
+            f"{cfg.n_passes}): an unlooped config has no exit gate")
+    hiddens, _ = _trunk(params, tokens, cfg, attention_fn)
+    logits = jnp.stack([_unembed(h, params, cfg) for h in hiddens])
+    with jax.named_scope("loop_exit"):
+        lam = jax.nn.sigmoid(jnp.stack([
+            jnp.einsum("bsd,d->bs", h.astype(jnp.float32),
+                       params["exit_w"].astype(jnp.float32))
+            for h in hiddens]) + params["exit_b"])
+        stay = jnp.cumprod(1.0 - lam[:-1], axis=0)       # prod_{j<=r}
+        before = jnp.concatenate([jnp.ones_like(lam[:1]), stay])
+        probs = jnp.concatenate([lam[:-1] * before[:-1], before[-1:]])
+    return logits, probs
 
 
 @jax.named_scope("head")
@@ -723,6 +910,7 @@ def apply_pipelined(params, tokens, cfg: TransformerConfig, mesh,
 
     from distkeras_tpu.parallel.pipeline import make_pipeline
 
+    reject_extended(cfg, "apply_pipelined")
     segmented = segment_ids is not None
     if segmented and attention_fn is not None:
         raise ValueError(
@@ -845,6 +1033,8 @@ def _forward_nll(params, tokens, cfg: TransformerConfig,
     """
     if apply_fn is not None and hidden_fn is not None:
         raise ValueError("pass apply_fn or hidden_fn, not both")
+    reject_extended(cfg, "lm_loss / lm_nll (the training and evaluation "
+                    "loss)", allow=tuple(_SWITCHES))
     targets = tokens[:, 1:]
     valid = None
     seg_in = None
@@ -897,7 +1087,7 @@ def _forward_nll(params, tokens, cfg: TransformerConfig,
                                               seg_in)
     hidden, aux = call_custom(hidden_fn, params, tokens[:, :-1])
     if cfg.ce_chunks > 1:
-        nll, z_mean = chunked_softmax_xent(hidden, params["tok_emb"],
+        nll, z_mean = chunked_softmax_xent(hidden, head_table(params, cfg),
                                            targets, cfg.ce_chunks)
         if zc > 0:
             aux = aux + zc * z_mean

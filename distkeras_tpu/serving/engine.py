@@ -45,6 +45,11 @@ class _Lane:
     # chunks; non-None means the lane is still ADMITTING — parked out
     # of the emission loop until the last chunk lands.
     chunks: list | None = None
+    # Cache positions the admission programs run so far have written
+    # prompt tokens up to (exclusive): what a later chunk re-writes
+    # below it (a backed-up tail) is not new (``positions`` of the
+    # admission spans).
+    filled: int = 0
     # Shared-prefix bookkeeping: the request's prefix length (0 =
     # none) and its PrefixPool id (refcount released at vacation).
     off: int = 0
@@ -56,7 +61,8 @@ class _Lane:
 
 def _make_lane_admit(model_params, model_cfg, prefix_lane=None,
                      pooled: bool = False, seed: bool = True,
-                     constrain=None, take_params: bool = False):
+                     constrain=None, take_params: bool = False,
+                     in_place: bool = False):
     """ONE-lane admission program factory shared by both engines:
     prefill ``rows`` (bucket-padded) into a single lane's cache slice
     at traced start position ``off``, seeded from the engine's static
@@ -75,6 +81,13 @@ def _make_lane_admit(model_params, model_cfg, prefix_lane=None,
     the KV-slab constraint so GSPMD pins the cache layout inside the
     compiled program instead of inferring it per call).
 
+    ``in_place=True`` (an engine with no prefix to seed and a state
+    ``generate.base_body_only`` does not hold back): the chunk is
+    written into lane ``lane`` of the slab IN PLACE — no lane cut out,
+    none put back, nothing seeded (a previous occupant's slots are
+    masked until overwritten) — so ``seed`` changes nothing and the
+    engine uses ONE program for first and continuation chunks.
+
     ``take_params=True`` builds the hot-swap spelling (round 20): the
     program takes the param tree as its FIRST argument instead of
     closing over it, so a live weight push is a plain argument change
@@ -85,6 +98,11 @@ def _make_lane_admit(model_params, model_cfg, prefix_lane=None,
     survive the swap for rollback.
     """
     def _admit(params, cache, rows, lane, off, *pool):
+        if in_place:
+            return _decode_chunk(
+                params, cache, rows,
+                jnp.reshape(off, (1,)).astype(jnp.int32), model_cfg,
+                uniform_pos=True, lane=lane)[1]
         if constrain is not None:
             cache = constrain(cache)
         # "kv_slab": the lane cut out of the slab here and put back
@@ -520,7 +538,8 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
             rnd.fields.update(
                 lanes_busy=busy, lanes_admitting=admitting,
                 kv_live=kv_live, chunks=chunks,
-                tokens=sum(len(v) for v in out.values()))
+                tokens=sum(len(v) for v in out.values()),
+                passes=self.cfg.n_passes)
             if idle:
                 rnd.fields["idle"] = True
 
@@ -540,8 +559,10 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
         lane = self._admitting[0]
         st = self._lane_state[lane]
         start, rows = st.chunks.pop(0)
+        end = min(start + rows.shape[1], st.off + st.prompt_len - 1)
+        new, st.filled = max(end - st.filled, 0), max(end, st.filled)
         with obs.span("serving.admit_chunk", bucket=rows.shape[1],
-                      remaining=len(st.chunks),
+                      positions=new, remaining=len(st.chunks),
                       request_id=st.request_id,
                       attended=self._attended(self.cache, start,
                                               rows.shape[1])):

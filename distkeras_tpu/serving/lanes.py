@@ -34,13 +34,15 @@ from distkeras_tpu.models.generate import (
     _decode_chunk,
     _device_tree,
     _resolve_prompt_cache,
+    base_body_only,
     init_cache,
     min_p_mask,
     rolling_eligible,
     top_k_mask,
     top_p_mask,
 )
-from distkeras_tpu.models.transformer import TransformerConfig
+from distkeras_tpu.models.transformer import (TransformerConfig,
+                                               reject_extended)
 from distkeras_tpu.serving.elastic import _ElasticLanesMixin
 from distkeras_tpu.serving.engine import (_Lane, _LaneEngine,
                                           _make_lane_admit,
@@ -159,6 +161,19 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
     ``prompt_cache``/rolling configs (the composition table lives in
     docs/serving_guide.md "Pod-sharded serving").
 
+    **The slab is written in place.**  The decode step writes one
+    window over all planes a row (``generate._chunk_in_place``); an
+    engine with no prefix to seed admits its chunk into the lane in
+    place too (first and continuation chunks are one program); and
+    the construction-time warm-up of an untiered engine runs on the
+    LIVE slab (:meth:`_warm_live`) — a slab can be half the chip, so
+    no second one is made.  **Extended configs** (``cfg.extended``:
+    the gated / sandwich-norm / untied block, ``fused_qkv``, a looped
+    stack of ``n_passes`` with one KV plane per pass and layer)
+    compose with ``hot_swap``, ``prefill_chunk`` and sampling;
+    everything else (``plan=``, ``prompt_cache=``, ``prefix_pool=``,
+    ``kv_int8=``, ``lane_tiers=``, a window) rejects them by name.
+
     **Live weight push** (round 20, ``hot_swap=True``): every decode
     and admission program takes the param tree as an explicit jit
     argument (never donated), so :meth:`swap_params` can replace the
@@ -208,6 +223,22 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
         # compiles ONCE with sharding constraints so GSPMD inserts the
         # per-token collectives — emitted tokens stay bit-exact vs the
         # solo engine (tests/test_serving_sharded.py).
+        if cfg.extended:
+            # The extended block serves through monolithic lanes,
+            # chunked prefill and per-row decode; what was not made to
+            # work with it says so by name (reject_extended).
+            for on, path in (
+                    (plan is not None, "plan= (a tensor-parallel "
+                     "serving_plan)"),
+                    (cfg.attention_window is not None, "rolling lanes "
+                     "(attention_window)"),
+                    (prompt_cache is not None, "prompt_cache="),
+                    (prefix_pool is not None, "prefix_pool="),
+                    (bool(kv_int8), "kv_int8= (the int8 KV cache)"),
+                    (lane_tiers is not None, "lane_tiers= (elastic "
+                     "resizing gathers the slab)")):
+                if on:
+                    reject_extended(cfg, f"ContinuousBatcher with {path}")
         if (plan is None) != (mesh is None):
             raise ValueError(
                 "pass plan= and mesh= together: the plan's rules only "
@@ -448,12 +479,21 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
                     f"({cfg.max_len - self._off})")
         self.prefill_chunk = prefill_chunk
         # Buckets clamp to the cache slots past the shared prefix and
-        # always include the largest legal width (and the chunk width,
-        # so chunked admission's full chunks have an exact program).
+        # include the chunk width (chunked admission's full chunks have
+        # an exact program) or, unchunked, the largest legal width.
+        # Chunked, no wider program is built: a longer prompt goes in
+        # chunks — unless a shared prefix offsets the lanes, where the
+        # cap-wide bucket is what still fits when a narrower one's
+        # padding would overflow the cache.
         cap = cfg.max_len - self._off
-        self._buckets = tuple(sorted(
-            {min(int(w), cap) for w in prompt_buckets} | {cap}
-            | ({prefill_chunk} if prefill_chunk else set())))
+        widths = {min(int(w), cap) for w in prompt_buckets}
+        if prefill_chunk is None or self._off:
+            widths.add(cap)
+        if prefill_chunk is not None:
+            widths = {w for w in widths
+                      if w <= prefill_chunk or (self._off and w == cap)}
+            widths.add(prefill_chunk)
+        self._buckets = tuple(sorted(widths))
         self._lane_state: list[_Lane | None] = [None] * lanes
         self._next_id = 0
         # Admission control (resilience subsystem): ``max_queue`` bounds
@@ -502,6 +542,7 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
         self.top_k = top_k
         self.exact_top_k = exact_top_k
         self._init_device_state(lanes)
+        self._kv_layout_event()
         self._one_step = self._build_one_step()
         self._steps = {}
         self._build_admission_programs()
@@ -522,7 +563,7 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
             # unconditionally — every one of their programs is built
             # here or nowhere.
             with obs.span("serving.compile_warm", lanes=lanes):
-                self._warm_tier(lanes)
+                self._warm_live()
 
     # ----------------------------------------- device-state factories
     #
@@ -549,6 +590,44 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
     def _init_device_state(self, lanes: int) -> None:
         self.cache = self._fresh_cache(lanes)
         self._init_lane_rows(lanes)
+
+    def _kv_layout_event(self) -> None:
+        """One ``serving.kv_layout`` event at construction: what a
+        reader needs to turn ``serving.round``'s ``kv_live`` slots
+        into bytes without knowing the model."""
+        cfg = self.cfg
+        # [planes, lanes, max_len, ...] (the paged store: [planes,
+        # blocks, block, ...]): slots are rows x positions.
+        slots = int(np.prod(self.cache["k"].shape[1:3]))
+        slab = sum(int(a.nbytes) for a in jax.tree.leaves(self.cache))
+        obs.event("serving.kv_layout", passes=cfg.n_passes,
+                  layers=cfg.n_layers, planes=cfg.kv_planes,
+                  bytes_per_slot=slab // slots, slots=slots,
+                  slab_bytes=slab)
+
+    def _warm_live(self) -> None:
+        """Compile an untiered engine's programs by running each ONCE
+        on the LIVE state — no dummy slab beside the live one (a slab
+        can be half the chip; a second does not fit).  Every program
+        writes the cache in place and hands it back; what the warm-up
+        leaves in lane 0 and at slot 0 is stale K/V like a previous
+        occupant's, masked until overwritten or seeded over.  The row
+        state is made anew afterwards.  (Tiered engines warm every
+        tier on dummies of its size: ``_compile_tiers``.)"""
+        pool = self._prefix_pool
+        for n in self._step_windows:
+            self._dispatch_step(n)
+        for width in self._buckets:
+            rows = np.zeros((1, width), np.int32)
+            self._exec_admit(0, self._off, rows, None)
+            if self._admit_cont not in (None, self._admit):
+                self._exec_chunk(0, self._off, rows)
+        if pool is not None:
+            self._exec_reseed(0, 0)
+        elif self._prefix_lane is not None:
+            self._exec_reseed(0, None)
+        self._warm_host_writes(self.lanes)
+        self._init_lane_rows(self.lanes)
 
     def _place_rows(self, cur, pos, keys, temps, tps, mps):
         """Commit per-lane row state REPLICATED over the serving mesh
@@ -745,19 +824,27 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
         # length and chunk offset shares it.
         pooled = self._prefix_pool is not None
         constrain = self._kv_constraint
+        # Nothing to seed and nothing that needs the base body: the
+        # chunk is written into the slab in place, and a first chunk
+        # and a continuation are ONE program.
+        in_place = (self._prefix_lane is None and not pooled
+                    and base_body_only(self.cfg, self.params,
+                                       self.cache) is None)
         self._admit = _make_lane_admit(self.params, self.cfg,
                                        prefix_lane=self._prefix_lane,
                                        pooled=pooled,
                                        constrain=constrain,
-                                       take_params=self._hot_swap)
+                                       take_params=self._hot_swap,
+                                       in_place=in_place)
         # Chunked prefill: the continuation program lands chunk k > 0
         # on the lane's existing cache (no reseed — that would erase
         # the earlier chunks).
-        self._admit_cont = (_make_lane_admit(self.params, self.cfg,
-                                             seed=False,
-                                             constrain=constrain,
-                                             take_params=self._hot_swap)
-                            if self.prefill_chunk is not None else None)
+        self._admit_cont = (
+            None if self.prefill_chunk is None
+            else self._admit if in_place
+            else _make_lane_admit(self.params, self.cfg, seed=False,
+                                  constrain=constrain,
+                                  take_params=self._hot_swap))
         self._reseed = (_make_lane_reseed(prefix_lane=self._prefix_lane,
                                           constrain=constrain)
                         if self._prefix_lane is not None else None)
@@ -1057,11 +1144,13 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
                 if prefix_id is not None:
                     self._prefix_pool.release(prefix_id)
                 return None
-            chunks = None
+            chunks, filled = None, off
             if plan:
                 start0, width0 = plan[0]
                 rows = self._chunk_rows(prompt, off, start0, width0)
+                filled = min(start0 + width0, off + warm)
                 with obs.span("serving.admit", bucket=width0,
+                              positions=filled - start0,
                               chunks=len(plan), lane=lane,
                               request_id=rid,
                               attended=self._attended(
@@ -1096,7 +1185,7 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
                 max_new=max_new_tokens, key=key, tokens=list(prompt),
                 eos=self.eos_token if eos_token is None else eos_token,
                 deadline=dl, born=self._clock(), chunks=chunks,
-                off=off, prefix_id=prefix_id)
+                filled=filled, off=off, prefix_id=prefix_id)
             if not self._admitting_internal:
                 self.last_request_id = rid
         except Exception:

@@ -74,7 +74,8 @@ import numpy as np
 from distkeras_tpu import obs
 from distkeras_tpu.models.generate import _decode_chunk, init_cache, prefill
 from distkeras_tpu.models.quant import is_quantized
-from distkeras_tpu.models.transformer import TransformerConfig
+from distkeras_tpu.models.transformer import (TransformerConfig,
+                                               reject_extended)
 from distkeras_tpu.serving.engine import _Lane
 from distkeras_tpu.serving.lanes import ContinuousBatcher
 from distkeras_tpu.serving.prefix import PinnedStems
@@ -308,6 +309,7 @@ class PagedBatcher(ContinuousBatcher):
                  scale_down_after: int = 8, step_windows=(1,),
                  prefill_chunk: int | None = None, plan=None,
                  mesh=None):
+        reject_extended(cfg, "PagedBatcher (the paged KV store)")
         if cfg.attention_window is not None:
             raise ValueError(
                 "paged KV needs a full-cache config (no "
@@ -614,6 +616,11 @@ class PagedBatcher(ContinuousBatcher):
         self._fork_rows_key = jax.jit(fork_rows_key)
 
     # ------------------------------------------------------- warm-up
+
+    def _warm_live(self) -> None:
+        # The paged programs take page tables and their warmers make
+        # their own (small) block slabs: untiered, the one tier.
+        self._warm_tier(self.lanes)
 
     def _warm_steps(self, tier: int) -> None:
         for n in self._step_windows:

@@ -45,7 +45,8 @@ import jax
 import jax.numpy as jnp
 
 from distkeras_tpu.models.generate import init_cache
-from distkeras_tpu.models.transformer import TransformerConfig
+from distkeras_tpu.models.transformer import (TransformerConfig,
+                                               reject_extended)
 from distkeras_tpu.utils.locks import TracedRLock
 
 
@@ -85,6 +86,9 @@ class PrefixPool:
                  mesh=None, kv_axis: str | None = "model"):
         if slots < 1:
             raise ValueError(f"slots must be >= 1, got {slots}")
+        for c in (cfg, draft_cfg):
+            if c is not None:
+                reject_extended(c, "PrefixPool (pooled prefilled prefixes)")
         if mesh is not None and draft_cfg is not None:
             raise ValueError(
                 "sharded pools serve pod-sharded ContinuousBatchers; "

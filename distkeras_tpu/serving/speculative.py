@@ -19,7 +19,8 @@ from distkeras_tpu.resilience import chaos
 from distkeras_tpu.models.generate import (_decode_chunk, _device_tree,
                                            init_cache, rolling_eligible)
 from distkeras_tpu.models.speculative import speculative_accept
-from distkeras_tpu.models.transformer import TransformerConfig
+from distkeras_tpu.models.transformer import (TransformerConfig,
+                                               reject_extended)
 from distkeras_tpu.serving.engine import (_Lane, _LaneEngine,
                                           _make_lane_admit,
                                           _make_lane_reseed)
@@ -115,6 +116,8 @@ class SpeculativeBatcher(_LaneEngine):
         # max_len.  Mixed full/windowed model
         # pairs stay rejected: their caches disagree on what a
         # position IS past the smaller ring.
+        for c in (cfg, draft_cfg):
+            reject_extended(c, "SpeculativeBatcher (speculative decoding)")
         self._rolling = False
         if (cfg.attention_window is None) != (draft_cfg.attention_window
                                               is None):

@@ -305,6 +305,9 @@ class LMTrainer(CheckpointingBase):
                  checkpoint_backend: str = "auto",
                  ema_decay: float | None = None):
         self.cfg = cfg
+        # (fused_qkv: the sharding rules name wq / wk / wv / wo.)
+        tfm.reject_extended(cfg, "LMTrainer", allow=(
+            "ffn_gated", "tie_head", "post_norms"))
         from distkeras_tpu.trainers.base import normalize_zero_args
 
         zero, zero1, zero_bucket_mb = normalize_zero_args(

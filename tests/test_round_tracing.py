@@ -76,6 +76,11 @@ def test_idle_round_is_marked_and_dispatches_nothing(rounds):
     admits = [s for s in spans if s["name"] in ("serving.admit",
                                                 "serving.admit_chunk")]
     assert [s["fields"]["bucket"] for s in admits] == [8, 8, 8, 8]
+    # ``positions``: what each program writes that is neither padding
+    # (B: 4 warm tokens in a bucket of 8) nor written before (A's
+    # backed-up tail [12, 20) adds four); a request's sum to its
+    # prompt length less one.
+    assert [s["fields"]["positions"] for s in admits] == [4, 8, 8, 4]
     assert all("request_id" in s["fields"] for s in admits)
 
 
@@ -148,9 +153,9 @@ def test_attended_is_the_chunks_end_on_the_bounded_path(tmp_path,
     assert [s["fields"]["attended"] for s in admits] == [8, 16, 20]
     assert [s["fields"]["bucket"] for s in admits] == [8, 8, 8]
     assert all(s["fields"]["attended"] <= cfg.max_len for s in admits)
-    assert set(admits[0]["fields"]) == {"bucket", "chunks", "lane",
-                                        "request_id", "attended"}
-    assert set(admits[1]["fields"]) == {"bucket", "remaining",
+    assert set(admits[0]["fields"]) == {"bucket", "positions", "chunks",
+                                        "lane", "request_id", "attended"}
+    assert set(admits[1]["fields"]) == {"bucket", "positions", "remaining",
                                         "request_id", "attended"}
 
 
@@ -199,14 +204,57 @@ def _scopes_in(text):
 
 
 @pytest.mark.parametrize("program,absent", [
-    ("decode_step", set()),
+    # ``loop_exit`` is what sits between two passes: an unlooped stack
+    # has no such operation.
+    ("decode_step", {"loop_exit"}),
     # An admission discards the chunk's logits: the head is traced and
     # then pruned as dead code, so the program never computes it.
-    ("admit", {"head"})])
+    ("admit", {"head", "loop_exit"})])
 def test_serving_programs_hold_every_scope(engine_programs, program,
                                            absent):
     assert _scopes_in(engine_programs[program]) \
         == set(tfm.SCOPES) - absent
+
+
+LOOPED = tfm.TransformerConfig(
+    vocab_size=64, d_model=32, n_heads=2, n_layers=2, d_ff=64, max_len=64,
+    rope=True, ffn_gated=True, tie_head=False, post_norms=True,
+    fused_qkv=True, n_passes=3)
+
+
+@pytest.fixture(scope="module")
+def looped_programs():
+    """The same two programs of a LOOPED hot-swap engine."""
+    params = tfm.init_params(jax.random.key(0), LOOPED)
+    eng = dk.ContinuousBatcher(params, LOOPED, lanes=2, hot_swap=True,
+                               prefill_chunk=8, prompt_buckets=(8,))
+    out = {}
+    for spec in eng.traced_for_analysis():
+        key = "decode_step" if spec.name.endswith("decode_step") else "admit"
+        out[key] = spec.fn.lower(*spec.args).as_text(debug_info=True)
+    return out
+
+
+@pytest.mark.parametrize("program,metric,absent", [
+    ("decode_step", "decode_step_ms", set()),
+    ("admit", "prefill_ms_per_ktok", {"head"})])
+def test_looped_programs_keep_their_names_and_hold_every_scope(
+        looped_programs, program, metric, absent):
+    """The looped step is read by the readers that read the unlooped
+    one (program names unchanged), holds the whole vocabulary —
+    ``loop_exit`` for the per-pass final norm included — and no model
+    operation of it stands outside a scope: the output norms are
+    ``norm``, the gated product ``mlp``, the untied head ``head``."""
+    text = looped_programs[program]
+    (module,) = re.findall(r"module @(\S+)", text)
+    assert re.search(_pattern(metric), module), module
+    assert _scopes_in(text) == set(tfm.SCOPES) - absent
+    heavy = [loc for loc in set(re.findall(r'loc\("([^"]+)"', text))
+             if re.search(r"/(dot_general|exp|logistic|rsqrt)$", loc)]
+    assert heavy
+    for loc in heavy:
+        assert any(re.search(rf"(?<![\w.]){sc}(?![\w.])", loc)
+                   for sc in tfm.SCOPES), loc
 
 
 TRAIN_CFG = tfm.TransformerConfig(
@@ -260,7 +308,9 @@ def test_kernel_names_stand_in_the_lowered_train_step(train_step_text,
 
 
 def test_train_step_holds_every_scope_but_the_kv_slab(train_step_text):
-    assert _scopes_in(train_step_text) == set(tfm.SCOPES) - {"kv_slab"}
+    # (nor ``loop_exit``: no looped stack trains)
+    assert _scopes_in(train_step_text) \
+        == set(tfm.SCOPES) - {"kv_slab", "loop_exit"}
 
 
 # ----------------------------------------------------- the run report
