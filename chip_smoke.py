@@ -72,6 +72,15 @@ PREFIX_SHAPES = {
              ((64, 4099), (512, 2283), (512, 8192 - 512))),
     "ouro": ((16, 16, 128, 512), ((64, 37), (128, 131), (256, 256))),
 }
+# The decode step's attention at the serving cells' slab shapes, cut in
+# planes to what fits beside nothing else: slab [planes, lanes, slots,
+# K/V heads, head], query heads, and the share of a lane's slots that
+# the cell's traced runs found live.
+DECODE_SHAPES = {
+    "sc1b": ((4, 32, 8192, 1, 128), 16, 0.54),
+    "ouro": ((16, 11, 512, 16, 128), 16, 0.43),
+}
+DECODE_CALLS = 96                         # timed calls a dispatch
 CIFAR_ROWS_PER_CHIP = 4096
 
 # A generated token must score within this of the best logit of the
@@ -233,6 +242,103 @@ def leg_kernel_prefix():
                 f"{name}: {t} queries at {off}: kernel vs float32 oracle, "
                 f"max error relative to the largest entry = {err}")
     return {"rel_err": report}
+
+
+def leg_kernel_decode():
+    """flash_decode_attention == float32 attention over every slot
+    before each lane's position, on the whole slab at each serving
+    cell's shape (DECODE_SHAPES): lanes filled as the cells fill them,
+    one at 0, one parked at the last slot, garbage in every dead slot;
+    one token a lane and a per-row chunk of four.  Then the kernel's
+    time against the dense body's read of the plane (XLA's fusions
+    over ALL slots), per call of a dependent chain over the planes,
+    and the kernel's rate over the blocks it reads."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distkeras_tpu.ops.attention import (DECODE_TAIL_PARTS, decode_block,
+                                             flash_decode_attention)
+
+    def dense(q, k_all, v_all, plane, pos0):
+        # _chunk_in_place's dense read: every slot, masked.
+        b, t, h, d = q.shape
+        s_len, kv = k_all.shape[2], k_all.shape[3]
+        ck, cv = (jax.lax.dynamic_index_in_dim(a, plane, 0, keepdims=False)
+                  .astype(jnp.float32) for a in (k_all, v_all))
+        qg = q.astype(jnp.float32).reshape(b, t, kv, h // kv, d)
+        logits = jnp.einsum("btcgk,bsck->btcgs", qg, ck) / np.sqrt(d)
+        before = (jnp.arange(s_len)[None, :] < pos0[:, None]
+                  )[:, None, None, None, :]
+        probs = jax.nn.softmax(jnp.where(before, logits, -1e30), axis=-1)
+        return jnp.einsum("btcgs,bsck->btcgk", probs, cv).reshape(q.shape)
+
+    def per_call_us(fn, q, k_all, v_all, pos0):
+        @jax.jit
+        def chain(q, k_all, v_all, pos0):
+            def body(i, acc):
+                return fn(q + (acc * 1e-3).astype(q.dtype), k_all, v_all,
+                          i % k_all.shape[0], pos0).astype(jnp.float32)
+            return jax.lax.fori_loop(0, DECODE_CALLS, body,
+                                     jnp.zeros(q.shape, jnp.float32))
+        chain(q, k_all, v_all, pos0).block_until_ready()
+        best = float("inf")
+        for _ in range(3):
+            t0 = time.perf_counter()
+            chain(q, k_all, v_all, pos0).block_until_ready()
+            best = min(best, time.perf_counter() - t0)
+        return best / DECODE_CALLS * 1e6
+
+    rng = np.random.default_rng(0)
+    kernel = lambda *a: flash_decode_attention(*a)[0]
+    report = {}
+    for name, (slab, h, fill) in DECODE_SHAPES.items():
+        planes, b, s_len, kv, d = slab
+        make = jax.jit(lambda key: jax.random.normal(key, slab, jnp.bfloat16))
+        k_all, v_all = make(jax.random.key(1)), make(jax.random.key(2))
+        pos = rng.uniform(0.15, 0.93, b)
+        pos = np.clip((pos * fill / pos.mean() * s_len).astype(int), 1,
+                      s_len - 1)
+        pos[0], pos[-1] = 0, s_len - 1
+        pos0 = jnp.asarray(pos, jnp.int32)
+        dead = jnp.asarray(np.arange(s_len)[None, :] >= pos[:, None]
+                           )[:, :, None, None]
+        junk_k = k_all.at[1].set(jnp.where(dead, 3e4, k_all[1]))
+        junk_v = v_all.at[1].set(jnp.where(dead, -3e4, v_all[1]))
+        errs = {}
+        for t in (1, 4):
+            q = jnp.asarray(rng.normal(size=(b, t, h, d)), jnp.bfloat16)
+            run = jax.jit(flash_decode_attention)
+            assert "tpu_custom_call" in run.lower(
+                q, junk_k, junk_v, jnp.int32(1), pos0).as_text()
+            got, lse = (np.asarray(a) for a in run(q, junk_k, junk_v,
+                                                   jnp.int32(1), pos0))
+            with jax.default_matmul_precision("float32"):  # a true oracle
+                want = np.asarray(jax.jit(dense)(q, k_all[1:2], v_all[1:2],
+                                                 jnp.int32(0), pos0))
+            assert np.isfinite(got).all() and not got[0].any(), (
+                f"{name} T={t}: the lane at 0 must read nothing")
+            assert (lse[0] < -1e29).all() and np.isfinite(lse[1:]).all()
+            errs[t] = float(np.abs(got[1:] - want[1:]).max()
+                            / np.abs(want).max())
+            assert errs[t] < KERNEL_TOL, (
+                f"{name}: {t} token(s) a lane: kernel vs float32 oracle, "
+                f"max error relative to the largest entry = {errs[t]}")
+        q = jnp.asarray(rng.normal(size=(b, 1, h, d)), jnp.bfloat16)
+        block = decode_block(1, s_len, d, h, kv, k_all.dtype)
+        unit = block // DECODE_TAIL_PARTS       # what a read rounds up to
+        live = int((-(-pos // unit) * unit).sum()) * kv * d * 2 * 2
+        kernel_us = per_call_us(kernel, q, k_all, v_all, pos0)
+        dense_us = per_call_us(dense, q, k_all, v_all, pos0)
+        report[name] = {
+            "rel_err": {f"T={t}": round(e, 5) for t, e in errs.items()},
+            "block": block, "fill": round(float(pos.mean()) / s_len, 3),
+            "kernel_us": round(kernel_us, 1), "dense_us": round(dense_us, 1),
+            "kernel_gb_s_live": round(live / kernel_us / 1e3, 1),
+            "dense_gb_s_all": round(b * s_len * kv * d * 4 / dense_us / 1e3,
+                                    1)}
+        del k_all, v_all, junk_k, junk_v
+    return report
 
 
 # -------------------------------------------------------------------- paper
@@ -628,6 +734,7 @@ def main():
     # lm first: its spread check reads peaks that never reset.
     legs = [("lm", leg_lm), ("lm_packed", leg_lm_packed),
             ("kernels", leg_kernels), ("kernel_prefix", leg_kernel_prefix),
+            ("kernel_decode", leg_kernel_decode),
             ("paper", leg_paper),
             ("serve_continuous",
              lambda: leg_serve_continuous(serving(), meter)),

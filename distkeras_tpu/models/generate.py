@@ -47,9 +47,13 @@ from distkeras_tpu.models.quant import (
     unembed_logits,
 )
 from distkeras_tpu.ops.attention import (
+    DECODE_TAIL_PARTS,
+    decode_block,
     flash_attention,
+    flash_decode_attention,
     flash_prefix_attention,
     is_partitioned,
+    use_flash_decode,
     use_flash_prefix,
 )
 
@@ -455,15 +459,72 @@ def chunk_attends_prefix(cfg: TransformerConfig, t_len: int, cache,
     One static predicate on what the call already holds: a multi-token
     chunk at one position for every row, no beam ancestry, no ring, no
     int8 scales; a TPU backend, one device, shapes the kernel tiles.
-    T = 1 (every decode step), per-row positions (speculative verify),
-    windowed, int8 and CPU calls keep the dense body, which is also the
-    kernel's oracle in tests.  The serving engines ask the same
-    question for their admission spans' ``attended`` field."""
+    T = 1 (every decode step) and per-row positions (speculative
+    verify) are :func:`decode_attends_prefix`'s; windowed, int8 and CPU
+    calls keep the dense body, which is also the kernels' oracle in
+    tests.  The serving engines ask the same question for their
+    admission spans' ``attended`` field."""
     return (uniform_pos and t_len > 1 and not beam
             and cfg.attention_window is None and "k_scale" not in cache
             and use_flash_prefix(t_len, cfg.max_len, cfg.head_dim,
                                  cfg.n_heads // cfg.kv_heads,
                                  cache["k"].dtype, sharded=sharded))
+
+
+def decode_attends_prefix(cfg: TransformerConfig, t_len: int, cache,
+                          uniform_pos: bool = False,
+                          sharded: bool = False) -> bool:
+    """Whether :func:`_chunk_in_place` compiles the PER-LANE bounded
+    attention (``ops.attention.flash_decode_attention``: lane ``b``
+    reads the blocks holding slots ``< pos0[b]`` of its row, straight
+    from the slab) or its dense body over all ``max_len`` slots of
+    every lane.
+
+    :func:`chunk_attends_prefix`'s sibling, for the calls that one
+    leaves: T = 1 (every decode step) and per-row chunks (speculative
+    verification).  The same kind of question: no ring, no int8 scales;
+    a TPU backend, one device, shapes the kernel tiles (one K/V head or
+    whole sublane tiles of them, at most ``DECODE_MAX_ROWS`` query
+    rows).  The serving engines ask it for ``serving.step``'s
+    ``attended``."""
+    return (not (uniform_pos and t_len > 1)
+            and cfg.attention_window is None and "k_scale" not in cache
+            and use_flash_decode(t_len, cfg.max_len, cfg.head_dim,
+                                 cfg.n_heads, cfg.kv_heads,
+                                 cache["k"].dtype, sharded=sharded))
+
+
+def decode_read_unit(cfg: TransformerConfig, t_len: int, cache) -> int:
+    """Cache slots the smallest copy of the per-lane bounded attention
+    brings in (a part of a lane's last block): a lane at position ``p``
+    reads ``p`` rounded up to it."""
+    return decode_block(t_len, cfg.max_len, cfg.head_dim, cfg.n_heads,
+                        cfg.kv_heads, cache["k"].dtype) // DECODE_TAIL_PARTS
+
+
+def _attend_before_and_chunk(q, k, v, ck_all, cv_all, plane, pos0):
+    """Attention of ``q [B, T, H, hd]`` over plane ``plane``'s slots
+    strictly before ``pos0[b]`` (the kernel, straight from the slab)
+    and over the chunk's own ``k``/``v [B, T, kv, hd]`` under their
+    causal triangle, as ONE softmax: the two terms are merged by their
+    log-sum-exps, ``[B, T, H]`` of elementwise work.  Float32."""
+    b, t_len, h, d = q.shape
+    kv = k.shape[2]
+    old, lse = flash_decode_attention(q, ck_all, cv_all, plane, pos0)
+    old = old.reshape(b, t_len, kv, h // kv, d)
+    lse = lse.reshape(b, t_len, kv, h // kv)
+    qg = q.astype(jnp.float32).reshape(old.shape)
+    new = jnp.einsum("btcgk,buck->btcgu", qg,
+                     k.astype(jnp.float32)) / jnp.sqrt(jnp.float32(d))
+    causal = jnp.tril(jnp.ones((t_len, t_len), bool))[None, :, None, None, :]
+    new = jnp.where(causal, new, -1e30)
+    m = jnp.maximum(lse, new.max(axis=-1))
+    p_new = jnp.exp(new - m[..., None])
+    w_old = jnp.exp(lse - m)
+    out = (w_old[..., None] * old
+           + jnp.einsum("btcgu,buck->btcgk", p_new, v.astype(jnp.float32))
+           ) / (w_old + p_new.sum(axis=-1))[..., None]
+    return out.reshape(b, t_len, h, d)
 
 
 def base_body_only(cfg: TransformerConfig, params, cache,
@@ -526,7 +587,13 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
     ``uniform_pos`` multi-token chunks on a TPU attend through
     ``flash_prefix_attention`` (:func:`chunk_attends_prefix`): the
     kernel wants the chunk inside the cache, so the plane's row (not
-    the slab) gets it first."""
+    the slab) gets it first.  Every other call without ``lane`` — one
+    token a row, rows at their own positions — attends on a TPU through
+    ``flash_decode_attention`` (:func:`decode_attends_prefix`): the
+    kernel is handed the slab and the plane's index, reads of row ``b``
+    only the blocks before ``pos0[b]``, and its result is merged with
+    the chunk's own term (:func:`_attend_before_and_chunk`) — the dense
+    body's sum, without its read of the dead slots."""
     dtype = jnp.dtype(cfg.dtype)
     b, t_len = tokens.shape
     n_layers, s_len = cfg.n_layers, cfg.max_len
@@ -543,9 +610,11 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
                                    cfg.rope_theta)[:, :, None, :]
         else:
             x = x + params["pos_emb"][pos_ids].astype(dtype)
+    sharded = is_partitioned(x)
     bounded = chunk_attends_prefix(cfg, t_len, cache,
-                                   uniform_pos=uniform_pos,
-                                   sharded=is_partitioned(x))
+                                   uniform_pos=uniform_pos, sharded=sharded)
+    per_lane = lane is None and decode_attends_prefix(
+        cfg, t_len, cache, uniform_pos=uniform_pos, sharded=sharded)
     # The dense body's two masks: cache slots before the chunk, and
     # the chunk's own causal triangle ([B, T, kv, g, S | T]).
     before = (jnp.arange(s_len)[None, :] < pos0[:, None]
@@ -566,20 +635,24 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
                 q, k = rope_rotate(q, rope_ang), rope_rotate(k, rope_ang)
             k, v = k.astype(ck_all.dtype), v.astype(cv_all.dtype)
         with jax.named_scope("kv_slab"):
-            # This plane's rows, for reading: [B, S, kv, hd].
-            if lane is None:
-                ck, cv = (jax.lax.dynamic_index_in_dim(
-                    a, plane, 0, keepdims=False) for a in (ck_all, cv_all))
-            else:
+            # This plane's rows, for reading: [B, S, kv, hd] (the
+            # per-lane kernel takes the slab and the plane's index).
+            if lane is not None:
                 ck, cv = (jax.lax.dynamic_slice(
                     a, (plane, lane, 0, 0, 0), (1, 1) + a.shape[2:])[0]
                     for a in (ck_all, cv_all))
+            elif not per_lane:
+                ck, cv = (jax.lax.dynamic_index_in_dim(
+                    a, plane, 0, keepdims=False) for a in (ck_all, cv_all))
             if bounded:
                 at = (jnp.int32(0), pos0[0], jnp.int32(0), jnp.int32(0))
                 ck = jax.lax.dynamic_update_slice(ck, k, at)
                 cv = jax.lax.dynamic_update_slice(cv, v, at)
         with jax.named_scope("attn"):
-            if bounded:
+            if per_lane:
+                attn = _attend_before_and_chunk(q, k, v, ck_all, cv_all,
+                                                plane, pos0)
+            elif bounded:
                 attn = flash_prefix_attention(q.astype(ck.dtype), ck, cv,
                                               pos0[0])
             else:

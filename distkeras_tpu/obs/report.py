@@ -153,8 +153,13 @@ def serving_rounds(records: list[dict],
     programs' attention read (the field of that name on the admission
     spans) — how many programs, their mean, and with ``max_len`` (the
     engine's slots a lane, which no record carries) the share of the
-    slab a program still reads: 1.0 on the dense path.  None when the
-    trace holds no round."""
+    slab a program still reads: 1.0 on the dense path.
+    ``attended_step``: the same for the decode dispatches
+    (``serving.step``'s field) — how many, the mean slots one step of
+    the decode program read, and ``live_share``, the rounds' ``kv_live``
+    over it: the share of what the step read that was live (the lanes'
+    fill on the dense path, near 1 on the per-lane bounded one).  None
+    when the trace holds no round."""
     spans = sorted((r for r in records if r.get("kind") == "span"
                     and r["name"].startswith("serving.")),
                    key=lambda r: r["t0"])
@@ -175,6 +180,17 @@ def serving_rounds(records: list[dict],
                            "mean": statistics.fmean(attended)}
         if max_len:
             out["attended"]["share"] = out["attended"]["mean"] / max_len
+    by_id = {r["id"]: r["fields"] for r in live}
+    steps = [(sp["fields"], by_id[sp["parent"]]) for sp in spans
+             if sp["name"] == "serving.step" and "attended" in sp["fields"]
+             and sp["parent"] in by_id]
+    if steps:
+        read = sum(f["attended"] for f, _ in steps)
+        windows = sum(f.get("n", 1) for f, _ in steps)
+        out["attended_step"] = {
+            "steps": windows, "mean": read / windows,
+            "live_share": sum(rnd["kv_live"] * f.get("n", 1)
+                              for f, rnd in steps) / read}
     # One thread's spans nest, so the top-level ones are disjoint and
     # in order: a gap is walked from the one that covers its start.
     ids = {sp["id"] for sp in spans}
@@ -538,6 +554,11 @@ def render_report(rep: dict, max_events: int = 60) -> str:
             out.append(f"  attended: {att['programs']} admission programs "
                        f"read {att['mean']:.6g} cache positions each"
                        + share)
+        att = rounds.get("attended_step")
+        if att:
+            out.append(f"  attended: {att['steps']} decode steps read "
+                       f"{att['mean']:.6g} cache slots each, "
+                       f"{att['live_share']:.1%} of them live")
         gap = rounds.get("gap")
         if gap:
             out.append(

@@ -29,8 +29,14 @@ one set of semantics so tests can pin them against each other:
   it where :func:`use_flash_prefix` says so, and keeps its dense body
   everywhere else).  Inference only; grouped and multi-query heads
   read their K/V head through the block index, no ``repeat``.
+- :func:`flash_decode_attention` — Pallas TPU forward kernel
+  (``flash_decode_fwd``) for the decode step: each lane's few query
+  rows against the live prefix of ITS row of one plane of the KV slab,
+  which it is handed whole with the plane's index and the lanes'
+  positions (models/generate.py::_chunk_in_place takes it where
+  :func:`use_flash_decode` says so).  Bytes follow the lanes' lengths.
 
-All but the last take ``q: [B, Lq, H, D]``, ``k/v: [B, Lkv, H, D]`` and return
+All but the last two take ``q: [B, Lq, H, D]``, ``k/v: [B, Lkv, H, D]`` and return
 ``[B, Lq, H, D]``.  ``q_offset``/``kv_offset`` give the global positions
 of the local chunks so causal masking works when sequences are sharded
 (ring attention).
@@ -1060,6 +1066,11 @@ PREFIX_BLOCK_K = 1024
 PREFIX_VMEM_BYTES = 64 * 1024 * 1024
 
 
+def _sublane_rows(dtype) -> int:
+    """Rows of one sublane tile of ``dtype`` (8 of 32 bits, 16 of 16)."""
+    return _SUBLANES * 4 // jnp.dtype(dtype).itemsize
+
+
 def prefix_blocks(t_len: int, s_len: int, head_dim: int, groups: int,
                   dtype) -> tuple[int, int] | None:
     """Tile rule of :func:`flash_prefix_attention` (backend-independent):
@@ -1069,7 +1080,7 @@ def prefix_blocks(t_len: int, s_len: int, head_dim: int, groups: int,
     ``dtype`` (the q block's leading dims merge into rows), at most
     ``PREFIX_TILE_ROWS / groups`` of them; ``block_k`` tiles the cache
     in lane multiples."""
-    sub = _SUBLANES * 4 // jnp.dtype(dtype).itemsize
+    sub = _sublane_rows(dtype)
     if head_dim % _LANES or t_len % sub or s_len % _LANES:
         return None
     cap = max(sub, PREFIX_TILE_ROWS // groups)
@@ -1179,3 +1190,264 @@ def flash_prefix_attention(q, k, v, off, block_q: int | None = None,
     else:
         out = call()
     return out.transpose(0, 3, 1, 2, 4).reshape(b, t_len, h, d)
+
+
+# ------------------------------------------- decode (per-lane live prefix)
+
+
+def _flash_decode_kernel(plane_ref, pos_ref, q_ref, *refs, scale: float,
+                         kv: int, block_k: int, parts: int):
+    """One lane of the decode step's attention over the cache: the
+    lane's ``rows`` queries (``T`` tokens x all query heads) against the
+    slots STRICTLY BEFORE ``pos_ref[lane]`` of plane ``plane_ref[0]``.
+
+    The slab stays in HBM (``k_hbm``/``v_hbm``: ``[P, B, S * KV, D]``,
+    slot ``s`` of K/V head ``c`` at row ``s * KV + c``).  The lane walks
+    its ``ceil(pos / block_k)`` live blocks in one grid step, each a
+    contiguous ``[block_k * KV, D]`` copy into one of two VMEM buffers,
+    the next block's copy in flight while this one is computed; its
+    last block starts block 0 of the next lane that has any (lanes run
+    in order: the grid is ``arbitrary``), so no lane begins with an
+    empty pipe.  Dead blocks cost nothing: they are neither fetched
+    nor stepped over.  A lane's LAST block is brought in by the
+    ``1 / parts`` of a block, as many as hold a live slot; what the
+    buffer keeps beyond them is an earlier block's (finite) or the
+    zeros it started with, under the position mask either way.
+
+    Every query head multiplies the whole block: a row's logits for the
+    other K/V heads' rows are masked (``bias_ref``: 0 where the row's
+    K/V head is the column's, -1e30 elsewhere; absent for one K/V
+    head), so their probabilities are exact zeros in the second
+    product.  The MXU's time goes by the block's bytes, not by the
+    tile's few rows, so the surplus products are free and the slab
+    needs no other layout than its own.
+
+    Arithmetic as :func:`_flash_prefix_kernel`'s: operands in the
+    cache's dtype, float32 accumulation, max, sum and accumulator,
+    logits scaled after the product.  Outputs the normalised result
+    and the log-sum-exp of the logits (float32); a lane with nothing
+    before it (``pos == 0``) yields zeros and ``NEG_INF``: weight 0
+    when merged with the chunk's own term."""
+    if kv > 1:
+        bias_ref, refs = refs[0], refs[1:]
+    (k_hbm, v_hbm, o_ref, lse_ref, kbuf, vbuf, sems, slot_ref,
+     m_scr, l_scr, acc_scr) = refs
+    lane, lanes = pl.program_id(0), pl.num_programs(0)
+    plane, pos = plane_ref[0], pos_ref[lane]
+    rows_k = block_k * kv
+    rows_p = rows_k // parts
+    n = (pos + block_k - 1) // block_k
+
+    def transfer(which, ln, i, slot, go):
+        """``go`` (start or wait) the copies that bring block ``i`` of
+        lane ``ln``'s K (0) or V (1) into ``slot``: the block whole, or
+        the parts of a last block that hold a slot before ``pos``."""
+        hbm, buf = ((k_hbm, kbuf), (v_hbm, vbuf))[which]
+
+        def rows(r0, count):
+            go(pltpu.make_async_copy(
+                hbm.at[plane, ln, pl.ds(i * rows_k + r0, count)],
+                buf.at[slot, pl.ds(r0, count)], sems.at[which, slot]))
+
+        live = jnp.minimum(
+            (pos_ref[ln] * kv - i * rows_k + rows_p - 1) // rows_p, parts)
+        pl.when(live == parts)(lambda: rows(0, rows_k))
+        for j in range(parts - 1):
+            pl.when(jnp.logical_and(j < live, live < parts))(
+                functools.partial(rows, j * rows_p, rows_p))
+
+    def start(ln, i, slot):
+        for which in (0, 1):
+            transfer(which, ln, i, slot, lambda c: c.start())
+
+    def start_next_lane(slot):
+        nxt = jax.lax.while_loop(
+            lambda c: jnp.logical_and(
+                c < lanes, pos_ref[jnp.minimum(c, lanes - 1)] == 0),
+            lambda c: c + 1, lane + 1)
+        pl.when(nxt < lanes)(lambda: start(nxt, 0, slot))
+
+    @pl.when(lane == 0)
+    def _first():
+        slot_ref[0] = 0
+        vbuf[...] = jnp.zeros_like(vbuf)
+        pl.when(n > 0)(lambda: start(0, 0, 0))
+        pl.when(n == 0)(lambda: start_next_lane(0))
+
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    slot0 = slot_ref[0]
+
+    def block(i, carry):
+        slot = (slot0 + i) % 2
+        pl.when(i + 1 < n)(lambda: start(lane, i + 1, 1 - slot))
+        pl.when(i + 1 == n)(lambda: start_next_lane(1 - slot))
+        transfer(0, lane, i, slot, lambda c: c.wait())
+        logits = jax.lax.dot_general(
+            q_ref[...], kbuf[slot], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        if kv > 1:
+            logits = logits + bias_ref[...]
+        cols = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+        logits = jnp.where(i * rows_k + cols < pos * kv, logits, NEG_INF)
+        m = m_scr[:, :1]
+        m_new = jnp.maximum(m, logits.max(axis=-1, keepdims=True))
+        corr = jnp.exp(m - m_new)
+        p = jnp.exp(logits - m_new)
+        l_new = l_scr[:, :1] * corr + p.sum(axis=-1, keepdims=True)
+        transfer(1, lane, i, slot, lambda c: c.wait())
+        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+            p.astype(vbuf.dtype), vbuf[slot], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+        return carry
+
+    jax.lax.fori_loop(0, n, block, 0)
+    slot_ref[0] = (slot0 + n) % 2
+    some = l_scr[...] > 0
+    l_safe = jnp.where(some, l_scr[...], 1.0)
+    o_ref[...] = acc_scr[...] / l_safe[:, :1]
+    lse_ref[...] = jnp.where(some, m_scr[...] + jnp.log(l_safe), NEG_INF)
+
+
+# A copy brings in ``block_k`` slots of every K/V head: the most that
+# keeps it at DECODE_BLOCK_BYTES; a lane's LAST block comes by the
+# 1 / DECODE_TAIL_PARTS of a block.  A block costs ~0.4 us besides its
+# bytes, a copy under ~128 KiB more than it saves, and a lane reads
+# half a copy past its position on average (TPU v5e, PR 28, lanes 54 %
+# / 43 % full, us a layer with the merge; whole blocks | quartered
+# tail): one K/V head of 128 over 8192 slots, blocks of 2048 slots
+# (512 KiB) 130 | 116, of 1024 127 | 130, of 512 174 | -; 16 K/V heads
+# over 512 slots, blocks of 128 slots (512 KiB) 43 | 39, of 64 42 | 44,
+# of 256 53 | -.
+DECODE_BLOCK_BYTES = 512 * 1024
+DECODE_TAIL_PARTS = 4
+# Rows of the logits tile (T x query heads): past a lane tile of rows
+# the surplus products of :func:`_flash_decode_kernel` stop being free.
+DECODE_MAX_ROWS = 128
+
+
+@functools.lru_cache(maxsize=None)
+def decode_block(t_len: int, s_len: int, head_dim: int, heads: int,
+                 kv: int, dtype) -> int | None:
+    """Tile rule of :func:`flash_decode_attention` (backend-independent):
+    the ``block_k`` (cache slots a copy) it launches with, or None where
+    the kernel has no legal tiling — a head that is no lane multiple;
+    K/V heads that neither are one nor fill whole sublane tiles of
+    ``dtype`` (the slab's ``[S, KV, D]`` planes are then no ``[S * KV,
+    D]`` matrix without a copy); more than ``DECODE_MAX_ROWS`` query
+    rows; a cache that no block of whole lane tiles of ``[S * KV]``
+    rows divides.  What a lane reads is its position rounded up to
+    ``block_k // DECODE_TAIL_PARTS``."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sub = _sublane_rows(dtype)
+    if (head_dim % _LANES or (kv > 1 and kv % sub)
+            or t_len * heads > DECODE_MAX_ROWS):
+        return None
+    # A block's rows [block_k * KV] are whole lane tiles (the logits'
+    # columns), each of its parts whole sublane tiles.
+    rows = math.lcm(_LANES, DECODE_TAIL_PARTS * sub)
+    step = rows // math.gcd(rows, kv)
+    cap = max(step, DECODE_BLOCK_BYTES // (kv * head_dim * itemsize))
+    return max((c for c in range(step, min(cap, s_len) + 1, step)
+                if s_len % c == 0), default=None)
+
+
+def use_flash_decode(t_len: int, s_len: int, head_dim: int, heads: int,
+                     kv: int, dtype, sharded: bool = False) -> bool:
+    """Kernel or the caller's dense body, as :func:`use_flash_prefix`:
+    the backend, the placement and the shapes decide."""
+    return (_on_tpu() and not sharded
+            and decode_block(t_len, s_len, head_dim, heads, kv,
+                             dtype) is not None)
+
+
+@functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
+def flash_decode_attention(q, k_all, v_all, plane, pos0,
+                           block_k: int | None = None,
+                           interpret: bool = False):
+    """Attention of each lane's queries against the live prefix of ITS
+    row of one plane of a KV slab: ``q [B, T, H, D]``, ``k_all``/``v_all
+    [P, B, S, KV, D]`` (the whole slab: nothing is cut out of it),
+    ``plane`` (int32 scalar) and ``pos0 [B]`` (int32), both may be
+    traced.  Lane ``b`` attends slots ``s < pos0[b]`` — STRICTLY before
+    its chunk, whose own K/V the caller holds and merges — and neither
+    fetches nor computes blocks past them, so bytes follow the lanes'
+    lengths, not ``S``.
+
+    Returns ``(out [B, T, H, D], lse [B, T, H])``, float32: the
+    attention normalised over the attended slots and the log-sum-exp of
+    their scaled logits; ``pos0[b] == 0`` gives zeros and ``NEG_INF``.
+    Forward only.  ``block_k`` defaults to :func:`decode_block`'s
+    choice (the interpreter's tests give shapes it refuses).  Jitted for
+    the reason :func:`flash_prefix_attention` is."""
+    b, t_len, h, d = q.shape
+    planes, _, s_len, kv, _ = k_all.shape
+    if block_k is None:
+        block_k = decode_block(t_len, s_len, d, h, kv, k_all.dtype)
+        if block_k is None:
+            raise ValueError(
+                f"no kernel tiling for {t_len} x {h} query rows of head "
+                f"{d} against {s_len} slots of {kv} K/V heads (see "
+                "decode_block)")
+    if s_len % block_k:
+        raise ValueError(f"block {block_k} does not tile {s_len} slots")
+    sub = _sublane_rows(k_all.dtype)
+    rows = -(-t_len * h // sub) * sub
+    rows_k = block_k * kv
+    q2 = jnp.pad(q.astype(k_all.dtype).reshape(b, t_len * h, d),
+                 ((0, 0), (0, rows - t_len * h), (0, 0)))
+    kf, vf = (a.reshape(planes, b, s_len * kv, d) for a in (k_all, v_all))
+    row_spec = lambda width: pl.BlockSpec(
+        (None, rows, width), lambda ln, plane_ref, pos_ref: (ln, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    operands, in_specs = [q2], [row_spec(d)]
+    if kv > 1:
+        # Row r is query head r % H, of K/V head (r % H) // groups;
+        # column j of a block is K/V head j % KV.
+        head = jnp.arange(rows)[:, None] % h // (h // kv)
+        bias = jnp.where(head == jnp.arange(rows_k)[None, :] % kv,
+                         0.0, NEG_INF).astype(jnp.float32)
+        operands.append(bias)
+        in_specs.append(pl.BlockSpec(
+            (rows, rows_k), lambda ln, plane_ref, pos_ref: (0, 0)))
+    live = b * s_len // 2 * kv            # an estimate: pos0 is traced
+
+    def call(): return pl.pallas_call(
+        functools.partial(_flash_decode_kernel, scale=_scale_for(q, None),
+                          kv=kv, block_k=block_k, parts=DECODE_TAIL_PARTS),
+        name="flash_decode_fwd",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b,),
+            in_specs=in_specs + [hbm, hbm],
+            out_specs=[row_spec(d), row_spec(_LANES)],
+            scratch_shapes=[
+                pltpu.VMEM((2, rows_k, d), k_all.dtype),
+                pltpu.VMEM((2, rows_k, d), v_all.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),                # live buffer
+                pltpu.VMEM((rows, _LANES), jnp.float32),    # m
+                pltpu.VMEM((rows, _LANES), jnp.float32),    # l
+                pltpu.VMEM((rows, d), jnp.float32),         # acc
+            ]),
+        out_shape=[jax.ShapeDtypeStruct((b, rows, d), jnp.float32),
+                   jax.ShapeDtypeStruct((b, rows, _LANES), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=PREFIX_VMEM_BYTES),
+        cost_estimate=pl.CostEstimate(
+            flops=4 * rows * live * d, transcendentals=rows * live,
+            bytes_accessed=2 * live * d * k_all.dtype.itemsize),
+    )(jnp.reshape(plane, (1,)).astype(jnp.int32), pos0.astype(jnp.int32),
+      *operands, kf, vf)
+
+    if interpret:
+        with pltpu.force_tpu_interpret_mode():
+            out, lse = call()
+    else:
+        out, lse = call()
+    return (out[:, :t_len * h].reshape(b, t_len, h, d),
+            lse[:, :t_len * h, 0].reshape(b, t_len, h))

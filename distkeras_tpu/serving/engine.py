@@ -24,7 +24,9 @@ import numpy as np
 
 from distkeras_tpu import obs
 from distkeras_tpu.models.generate import (_decode_chunk,
-                                           chunk_attends_prefix)
+                                           chunk_attends_prefix,
+                                           decode_attends_prefix,
+                                           decode_read_unit)
 from distkeras_tpu.serving.admission import _AdmissionMixin
 from distkeras_tpu.serving.elastic import _ElasticMixin
 
@@ -226,6 +228,33 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
         if chunk_attends_prefix(self.cfg, width, cache, sharded=sharded):
             return start + width
         return self.cfg.max_len
+
+    def _step_attended(self, n: int) -> int:
+        """Cache slots the decode program's attention reads in a round
+        of ``n`` steps — ``serving.step``'s ``attended``, the decode
+        step's share of the question :meth:`_attended` answers for an
+        admission.  On the per-lane bounded path
+        (``decode_attends_prefix``) a decoding lane reads its position
+        rounded up to the kernel's smallest copy; a free, done or admitting
+        lane is counted as parked at ``max_len - 1``, its whole row (a
+        done lane gets there a step at a time: an upper bound).  On
+        the dense path every lane reads ``max_len`` slots.  Host
+        integers from the lane table; no device read."""
+        cap = self.cfg.max_len
+        sharded = self.mesh is not None and self.mesh.size > 1
+        if not decode_attends_prefix(self.cfg, 1, self.cache,
+                                     sharded=sharded):
+            return n * len(self._lane_state) * cap
+        unit = decode_read_unit(self.cfg, 1, self.cache)
+        total = 0
+        for st in self._lane_state:
+            if st is None or st.done or st.chunks is not None:
+                total += n * cap
+                continue
+            pos = st.off + len(st.tokens) - 1
+            total += sum(min(-(-(pos + j) // unit) * unit, cap)
+                         for j in range(n))
+        return total
 
     def _pargs(self) -> tuple:
         """The params-argument prefix of every compiled-program call:

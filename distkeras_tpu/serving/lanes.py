@@ -1301,7 +1301,8 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
             else:
                 chaos.probe("serving.step")
                 self._admit_programs = 0
-                with obs.span("serving.step", n=n):
+                with obs.span("serving.step", n=n,
+                              attended=self._step_attended(n)):
                     toks = self._dispatch_step(n)
                 out = self._emit(lambda lane: toks[lane].tolist())
             # Deadline granularity is one step window: tokens emitted

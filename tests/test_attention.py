@@ -12,8 +12,11 @@ import numpy as np
 import pytest
 
 from distkeras_tpu.ops.attention import (
+    NEG_INF,
     blockwise_attention,
+    decode_block,
     flash_attention,
+    flash_decode_attention,
     flash_prefix_attention,
     naive_attention,
     prefix_blocks,
@@ -439,6 +442,132 @@ def test_prefix_blocks_tile_rule():
     assert prefix_blocks(512, 1000, 128, 16, bf16) is None
 
 
+# ------------------------------------- decode (per-lane live prefix)
+
+
+DECODE_S, DECODE_BK, DECODE_PLANE = 64, 16, 1
+# A lane with nothing before it, with one slot, at a block's edge, on
+# either side of one (a last block of one, three and four quarters),
+# and at the cache's last slot.
+DECODE_POS = (0, 1, 16, 17, 43, 63)
+
+
+def _dense_before(q, k_all, v_all, plane, pos0):
+    """``_chunk_in_place``'s dense read of a plane, cut to the slots
+    before the chunk: float32 scores over EVERY slot of every lane
+    under the mask ``s < pos0[b]``, softmax, values, and the
+    log-sum-exp the kernel hands to the merge — its oracle."""
+    b, t, h, d = q.shape
+    s, kv = k_all.shape[2], k_all.shape[3]
+    ck, cv = (a[plane].astype(jnp.float32) for a in (k_all, v_all))
+    qg = q.astype(jnp.float32).reshape(b, t, kv, h // kv, d)
+    logits = jnp.einsum("btcgk,bsck->btcgs", qg, ck)
+    logits = logits / jnp.sqrt(jnp.float32(d))
+    before = (jnp.arange(s)[None, :] < pos0[:, None]
+              )[:, None, None, None, :]
+    logits = jnp.where(before, logits, -1e30)
+    out = jnp.einsum("btcgs,bsck->btcgk", jax.nn.softmax(logits, axis=-1),
+                     cv)
+    return (out.reshape(b, t, h, d),
+            jax.nn.logsumexp(logits, axis=-1).reshape(b, t, h))
+
+
+def _decode_case(rng, t, h, kv, pos, planes=3, d=16, junk=True):
+    """A slab of ``planes`` planes; with ``junk``, every slot at or
+    past a lane's position, in every plane, and EVERY slot of every
+    plane but ``DECODE_PLANE`` holds garbage (large, finite)."""
+    b = len(pos)
+    q = rng.normal(size=(b, t, h, d))
+    k = rng.normal(size=(planes, b, DECODE_S, kv, d))
+    v = rng.normal(size=(planes, b, DECODE_S, kv, d))
+    if junk:
+        dead = np.arange(DECODE_S)[None, :] >= np.asarray(pos)[:, None]
+        k[:, dead], v[:, dead] = 3e4, -3e4
+        others = np.arange(planes) != DECODE_PLANE
+        k[others], v[others] = -2e4, 2e4
+    return tuple(jnp.asarray(a, jnp.bfloat16) for a in (q, k, v))
+
+
+@pytest.mark.parametrize("heads", [(16, 1), (16, 16), (8, 2)],
+                         ids=["groups16", "groups1", "grouped"])
+@pytest.mark.parametrize("t", [1, 4], ids=["token", "chunk4"])
+def test_flash_decode_matches_dense_body(rng, t, heads):
+    """The per-lane bounded kernel (interpreter) against the dense
+    body's read of the plane, lanes at 0, 1, a block edge, past one and
+    ``S - 1`` in ONE call: the serving cells' two head layouts (16
+    query heads on one K/V head; 16 K/V heads, groups of 1) and a
+    grouped one; one token and a per-row chunk of four.  The lane at 0
+    returns zeros and a log-sum-exp that weighs nothing."""
+    h, kv = heads
+    q, k, v = _decode_case(rng, t, h, kv, DECODE_POS)
+    pos0 = jnp.asarray(DECODE_POS, jnp.int32)
+    out, lse = flash_decode_attention(q, k, v, jnp.int32(DECODE_PLANE), pos0,
+                                      block_k=DECODE_BK, interpret=True)
+    assert out.shape == q.shape and lse.shape == q.shape[:3]
+    assert out.dtype == lse.dtype == jnp.float32
+    ref, ref_lse = _dense_before(q, k, v, DECODE_PLANE, pos0)
+    np.testing.assert_allclose(out[1:], ref[1:], atol=2e-2, rtol=2e-2)
+    np.testing.assert_allclose(lse[1:], ref_lse[1:], atol=2e-2, rtol=2e-2)
+    np.testing.assert_array_equal(out[0], 0.0)
+    np.testing.assert_array_equal(lse[0], np.float32(NEG_INF))
+
+
+@pytest.mark.parametrize("heads", [(16, 1), (16, 16)],
+                         ids=["groups16", "groups1"])
+def test_flash_decode_garbage_changes_nothing(rng, heads):
+    """Bit for bit: what the slots at and past a lane's position hold,
+    in any plane, and what every other plane holds, never reaches the
+    result — the plane index is honoured and dead blocks are not
+    read."""
+    h, kv = heads
+    q, k, v = _decode_case(rng, 1, h, kv, DECODE_POS)
+    _, k0, v0 = _decode_case(rng, 1, h, kv, DECODE_POS, junk=False)
+    pos0 = jnp.asarray(DECODE_POS, jnp.int32)
+    dead = (jnp.arange(DECODE_S)[None, :] >= pos0[:, None]
+            )[:, :, None, None]
+    clean = lambda a, a0: a0.at[DECODE_PLANE].set(
+        jnp.where(dead, 0, a[DECODE_PLANE]))
+    run = lambda k, v: flash_decode_attention(
+        q, k, v, jnp.int32(DECODE_PLANE), pos0, block_k=DECODE_BK,
+        interpret=True)
+    for got, want in zip(run(k, v), run(clean(k, k0), clean(v, v0))):
+        np.testing.assert_array_equal(got, want)
+
+
+def test_flash_decode_traced_plane_and_positions_are_one_program(rng):
+    """``plane`` and ``pos0`` are traced: one compiled program serves
+    every layer of the scan and every state of the lanes."""
+    pos = (5, 40, 16)
+    q, k, v = _decode_case(rng, 1, 4, 1, pos, junk=False)
+    fn = jax.jit(lambda q, k, v, plane, pos0: flash_decode_attention(
+        q, k, v, plane, pos0, block_k=DECODE_BK, interpret=True))
+    for plane, shift in ((0, 0), (2, 7), (1, 23)):
+        pos0 = jnp.asarray(pos, jnp.int32) + shift
+        out, lse = fn(q, k, v, jnp.int32(plane), pos0)
+        ref, ref_lse = _dense_before(q, k, v, plane, pos0)
+        np.testing.assert_allclose(out, ref, atol=2e-2, rtol=2e-2)
+        np.testing.assert_allclose(lse, ref_lse, atol=2e-2, rtol=2e-2)
+    assert fn._cache_size() == 1
+
+
+def test_decode_block_tile_rule():
+    """Both serving cells' decode steps tile (one K/V head of 128 over
+    8192 slots; 16 K/V heads over 512), as does a verification chunk of
+    five; K/V heads that fill no sublane tile, a head that is no lane
+    multiple, too many query rows and a cache no lane multiple tiles do
+    not."""
+    bf16 = jnp.bfloat16
+    assert decode_block(1, 8192, 128, 16, 1, bf16) == 2048
+    assert decode_block(1, 512, 128, 16, 16, bf16) == 128
+    assert decode_block(5, 8192, 128, 16, 1, bf16) == 2048
+    assert decode_block(1, 1536, 128, 8, 8, jnp.float32) == 128
+    assert decode_block(1, 24, 128, 16, 16, bf16) == 24      # 384 rows
+    assert decode_block(1, 512, 128, 16, 8, bf16) is None     # 8 % 16
+    assert decode_block(1, 512, 64, 16, 1, bf16) is None
+    assert decode_block(9, 8192, 128, 16, 1, bf16) is None    # 144 rows
+    assert decode_block(1, 1000, 128, 16, 1, bf16) is None
+
+
 # ------------------------------------------------ TPU lowering, no chip
 #
 # The Mosaic lowering runs under JAX_PLATFORMS=cpu, and it is where the
@@ -560,3 +689,23 @@ def test_prefix_kernel_lowers_for_tpu(t, h, kv, s):
                       jax.ShapeDtypeStruct((), jnp.int32)).as_text()
     assert text.count("tpu_custom_call") == 1
     assert "flash_prefix_fwd" in text
+
+
+@pytest.mark.parametrize("slab,h,t", [((24, 32, 8192, 1, 128), 16, 1),
+                                      ((192, 11, 512, 16, 128), 16, 1),
+                                      ((24, 32, 8192, 1, 128), 16, 5)],
+                         ids=["sc1b", "ouro", "sc1b_verify5"])
+def test_decode_kernel_lowers_for_tpu(slab, h, t):
+    """The per-lane bounded decode kernel at both serving cells' slab
+    shapes (and a verification chunk), taking the WHOLE slab, a traced
+    plane and traced per-lane positions, with the block its tile rule
+    picks."""
+    x = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    text = _tpu_lower(flash_decode_attention, x(slab[1], t, h, 128),
+                      x(*slab), x(*slab),
+                      jax.ShapeDtypeStruct((), jnp.int32),
+                      jax.ShapeDtypeStruct((slab[1],), jnp.int32)).as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "flash_decode_fwd" in text
+    # Nothing is cut out of the slab in front of the call.
+    assert "dynamic_slice" not in text and "dynamic-slice" not in text

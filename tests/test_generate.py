@@ -1299,20 +1299,23 @@ def _gate_call(case, rng, cfg=GATE_CFG):
                                   "windowed", "int8", "beam"])
 def test_decode_chunk_gate(rng, monkeypatch, case):
     """On a TPU backend a uniform multi-token chunk — an admission, a
-    prefix warm-up — attends through the blocked kernel and computes
-    what the dense body computes; a decode step (T = 1), per-row
-    positions, a ring, an int8 cache and beam ancestry keep the dense
-    body, as every call does on another backend."""
+    prefix warm-up — attends through the blocked prefix kernel, a
+    decode step (T = 1) and a chunk at per-row positions through the
+    per-lane bounded kernel over the slab, and both compute what the
+    dense body computes; a ring, an int8 cache and beam ancestry keep
+    the dense body, as every call does on another backend."""
     from distkeras_tpu.models import generate as gen
     from distkeras_tpu.ops import attention
 
     calls = []
 
-    def kernel(q, k, v, off):
-        calls.append(q.shape)
-        return attention.flash_prefix_attention(q, k, v, off,
-                                                interpret=True)
-    monkeypatch.setattr(gen, "flash_prefix_attention", kernel)
+    def kernel(name):
+        def run(q, *rest):
+            calls.append((name, q.shape))
+            return getattr(attention, name)(q, *rest, interpret=True)
+        return run
+    for name in ("flash_prefix_attention", "flash_decode_attention"):
+        monkeypatch.setattr(gen, name, kernel(name))
     params, cache, toks, pos0, cfg, kw = _gate_call(case, rng)
     dense_logits, dense_cache = gen._decode_chunk(params, cache, toks, pos0,
                                                   cfg, **kw)
@@ -1320,8 +1323,71 @@ def test_decode_chunk_gate(rng, monkeypatch, case):
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     logits, new_cache = gen._decode_chunk(params, cache, toks, pos0, cfg,
                                           **kw)
-    assert calls == ([(2, 8, 2, 128)] if case == "uniform_chunk" else [])
+    assert calls == {
+        "uniform_chunk": [("flash_prefix_attention", (2, 8, 2, 128))],
+        "one_token": [("flash_decode_attention", (2, 1, 2, 128))],
+        "per_row": [("flash_decode_attention", (2, 8, 2, 128))],
+    }.get(case, [])
     np.testing.assert_allclose(logits, dense_logits, atol=1e-4, rtol=1e-4)
     for leaf, want in zip(jax.tree.leaves(new_cache),
                           jax.tree.leaves(dense_cache)):
         np.testing.assert_array_equal(leaf, want)
+
+
+# ------------------------------- the decode step's per-lane bounded read
+
+# The two serving cells' head layouts at the smallest kernel-legal
+# size, float32: query heads on ONE K/V head (the tile rule's block is
+# the lane, read by quarters of 256 slots), and 8 K/V heads with groups
+# of 1 in a looped, extended stack — 2 passes x 2 layers, so four
+# planes, each holding other keys (two blocks of 128 slots a lane,
+# quarters of 32).
+LANE_CFGS = {
+    "multi_query": tfm.TransformerConfig(
+        vocab_size=64, d_model=256, n_heads=2, n_kv_heads=1, n_layers=2,
+        d_ff=64, max_len=1024),
+    "groups_of_1_looped": tfm.TransformerConfig(
+        vocab_size=64, d_model=1024, n_heads=8, n_kv_heads=8, n_layers=2,
+        d_ff=64, max_len=256, rope=True, ffn_gated=True, tie_head=False,
+        post_norms=True, fused_qkv=True, n_passes=2),
+}
+
+
+@pytest.mark.parametrize("t", [1, 4], ids=["token", "chunk4"])
+@pytest.mark.parametrize("name", sorted(LANE_CFGS))
+def test_chunk_in_place_per_lane_kernel_is_the_dense_body(rng, monkeypatch,
+                                                          name, t):
+    """``_chunk_in_place`` through the per-lane bounded kernel (the
+    interpreter, the tile rule's own block) against its dense body, on
+    a slab whose every slot holds something: rows at 0, 1, a copy's
+    edge, past the next and the last position a chunk fits, one token and a
+    per-row chunk of four.  Same logits; the slab written alike — and
+    read nowhere at or past a row's position, in no other plane: the
+    dense body masks those, the kernel must not see them."""
+    from distkeras_tpu.models import generate as gen
+    from distkeras_tpu.ops import attention
+
+    cfg = LANE_CFGS[name]
+    unit = gen.decode_read_unit(cfg, t, {"k": jnp.zeros((), jnp.float32)})
+    assert unit and cfg.max_len // unit >= 4
+    pos0 = jnp.asarray([0, 1, unit, 2 * unit + 3, cfg.max_len - t],
+                       jnp.int32)
+    params = tfm.init_params(jax.random.key(0), cfg)
+    cache = {k: jnp.asarray(rng.normal(size=v.shape), v.dtype)
+             for k, v in init_cache(cfg, len(pos0)).items()}
+    toks = jnp.asarray(rng.integers(0, 64, (len(pos0), t)), jnp.int32)
+    want, want_cache = gen._chunk_in_place(params, cache, toks, pos0, cfg)
+
+    calls = []
+
+    def kernel(q, *rest):
+        calls.append(q.shape)
+        return attention.flash_decode_attention(q, *rest, interpret=True)
+    monkeypatch.setattr(gen, "flash_decode_attention", kernel)
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    got, got_cache = gen._chunk_in_place(params, cache, toks, pos0, cfg)
+    assert calls == [(len(pos0), t, cfg.n_heads, cfg.head_dim)]  # one trace
+    np.testing.assert_allclose(got, want, atol=2e-4, rtol=2e-4)
+    for leaf, ref in zip(jax.tree.leaves(got_cache),
+                         jax.tree.leaves(want_cache)):
+        np.testing.assert_allclose(leaf, ref, atol=2e-5, rtol=2e-5)

@@ -121,6 +121,21 @@ def test_attended_is_max_len_on_the_dense_path(rounds):
         CFG.max_len] * 4
 
 
+def _interpreted_kernels(monkeypatch):
+    """A TPU backend's choice of attention (the admission and the
+    decode kernel wherever their shape rules allow), run through the
+    Pallas interpreter."""
+    from distkeras_tpu.models import generate as gen
+    from distkeras_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    for name in ("flash_prefix_attention", "flash_decode_attention"):
+        kernel = getattr(attention, name)
+        monkeypatch.setattr(gen, name, lambda *a, kernel=kernel: kernel(
+            *a, interpret=True))
+    return gen
+
+
 def test_attended_is_the_chunks_end_on_the_bounded_path(tmp_path,
                                                         monkeypatch):
     """Where the admission programs hold the bounded kernel (a TPU
@@ -128,17 +143,10 @@ def test_attended_is_the_chunks_end_on_the_bounded_path(tmp_path,
     ``attended`` is ``start + bucket``: A's 20 warm tokens are chunks
     [0, 8), [8, 16) and the backed-up tail [12, 20).  Never above
     ``max_len``; ``bucket`` and the other fields stay."""
-    from distkeras_tpu.models import generate as gen
-    from distkeras_tpu.ops import attention
-
     cfg = tfm.TransformerConfig(vocab_size=64, d_model=256, n_heads=2,
                                 n_kv_heads=1, n_layers=1, d_ff=64,
                                 max_len=128)
-    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
-    monkeypatch.setattr(
-        gen, "flash_prefix_attention",
-        lambda q, k, v, off: attention.flash_prefix_attention(
-            q, k, v, off, interpret=True))
+    _interpreted_kernels(monkeypatch)
     path = str(tmp_path / "t.jsonl")
     with obs.session(trace_path=path):
         eng = dk.ContinuousBatcher(
@@ -157,6 +165,58 @@ def test_attended_is_the_chunks_end_on_the_bounded_path(tmp_path,
                                         "lane", "request_id", "attended"}
     assert set(admits[1]["fields"]) == {"bucket", "positions", "remaining",
                                         "request_id", "attended"}
+
+
+def _steps(spans):
+    return [s for s in spans if s["name"] == "serving.step"]
+
+
+def test_step_attended_is_every_slot_on_the_dense_path(rounds):
+    """No kernel on this backend: every decode dispatch reads all
+    ``max_len`` slots of every lane, busy or not, and says so beside
+    ``n``."""
+    _, spans = rounds
+    steps = _steps(spans)
+    assert len(steps) == 4                       # the idle round has none
+    assert [s["fields"] for s in steps] == [
+        {"n": 1, "attended": 2 * CFG.max_len}] * 4
+
+
+def test_step_attended_follows_the_lanes_on_the_bounded_path(tmp_path,
+                                                             monkeypatch):
+    """Where the decode program holds the per-lane bounded kernel (a
+    TPU backend, kernel-legal shapes; here the interpreter stands in),
+    ``attended`` is each decoding lane's position rounded up to the
+    kernel's smallest copy — 256 of the 1024 slots here — plus the whole row of
+    every lane that is free or still admitting; a window of ``n`` steps
+    reads ``n`` times.  Its tokens are the dense path's."""
+    cfg = tfm.TransformerConfig(vocab_size=64, d_model=256, n_heads=2,
+                                n_kv_heads=1, n_layers=1, d_ff=64,
+                                max_len=1024)
+    params = tfm.init_params(jax.random.key(0), cfg)
+
+    def run(path):
+        with obs.session(trace_path=path):
+            eng = dk.ContinuousBatcher(params, cfg, lanes=3, max_queue=4,
+                                       prefill_chunk=8, prompt_buckets=(8,))
+            eng.enqueue(np.arange(5), 8)     # B: decoding from position 4
+            out = [eng.step()]
+            eng.enqueue(np.arange(21), 4)    # A: admitting for two rounds
+            out += [eng.step(), eng.step(2)]
+        return out, _steps([r for r in read_trace(path)
+                            if r["kind"] == "span"])
+
+    dense_out, dense = run(str(tmp_path / "dense.jsonl"))
+    assert [s["fields"]["attended"] for s in dense] == [3072, 3072, 6144]
+    gen = _interpreted_kernels(monkeypatch)
+    assert gen.decode_read_unit(cfg, 1, {"k": jnp.zeros(())}) == 256
+    out, steps = run(str(tmp_path / "bounded.jsonl"))
+    # B alone; B and the admitting A; B and A over a window of two.
+    assert [s["fields"] for s in steps] == [
+        {"n": 1, "attended": 256 + 2 * 1024},
+        {"n": 1, "attended": 256 + 2 * 1024},
+        {"n": 2, "attended": 2 * (256 + 256 + 1024)}]
+    assert out == dense_out
 
 
 # ------------------------------------------ names on the device timeline
@@ -362,3 +422,33 @@ def test_report_splits_the_step_gap_by_span():
     assert serving_rounds(recs, max_len=64)["attended"]["share"] == 0.375
     assert "= 37.5% of the slab" in render_report(build_report(recs, 64))
     assert "pass --max-len" in render_report(build_report(recs))
+
+
+@pytest.mark.parametrize("attended,share", [((128, 256), "15.6%"),
+                                            ((20, 40), "100.0%")],
+                         ids=["dense", "bounded"])
+def test_report_sets_the_decode_steps_attended_against_kv_live(attended,
+                                                               share):
+    """``serving.step``'s ``attended`` beside the admissions': slots a
+    decode step read (a window of ``n`` steps reads ``n`` times) and
+    the share of them the rounds' ``kv_live`` says were live."""
+    from distkeras_tpu.obs.report import (build_report, render_report,
+                                          serving_rounds)
+
+    counts = dict(lanes_busy=2, lanes_admitting=0, chunks=0, tokens=2)
+    recs = [
+        _span("serving.round", 0, 5, 1, kv_live=20, **counts),
+        _span("serving.step", 1, 3, 2, 1, n=1, attended=attended[0]),
+        _span("serving.round", 6, 5, 3, kv_live=20, **counts),
+        _span("serving.step", 7, 3, 4, 3, n=2, attended=attended[1]),
+    ]
+    att = serving_rounds(recs)["attended_step"]
+    assert att["steps"] == 3 and att["mean"] == sum(attended) / 3
+    assert att["live_share"] == pytest.approx(60 / sum(attended))
+    assert (f"3 decode steps read {sum(attended) / 3:.6g} cache slots "
+            f"each, {share} of them live") in render_report(
+                build_report(recs))
+    # A trace from before the field existed reports none.
+    for r in recs:
+        r["fields"].pop("attended", None)
+    assert "attended_step" not in serving_rounds(recs)
