@@ -71,8 +71,8 @@ def test_blockwise_grads_match_naive(rng, causal):
     def loss_blk(q, k, v):
         return blockwise_attention(q, k, v, causal=causal, block_k=8).sum()
 
-    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    g_blk = jax.grad(loss_blk, argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
+    g_blk = jax.jit(jax.grad(loss_blk, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(g_ref, g_blk):
         np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5)
 
@@ -85,8 +85,10 @@ def test_flash_fallback_and_vjp(rng, causal):
     out = flash_attention(q, k, v, causal)
     np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-5)
 
-    g = jax.grad(lambda q: flash_attention(q, k, v, causal).sum())(q)
-    g_ref = jax.grad(lambda q: naive_attention(q, k, v, causal=causal).sum())(q)
+    g = jax.jit(jax.grad(
+        lambda q: flash_attention(q, k, v, causal).sum()))(q)
+    g_ref = jax.jit(jax.grad(
+        lambda q: naive_attention(q, k, v, causal=causal).sum()))(q)
     np.testing.assert_allclose(g, g_ref, atol=1e-5, rtol=1e-5)
 
 
@@ -233,8 +235,8 @@ def test_flash_attention_window_grads_fallback(rng):
 
     np.testing.assert_allclose(float(f_flash(q, k, v)),
                                float(f_naive(q, k, v)), rtol=1e-5)
-    g1 = jax.grad(f_flash, argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(f_naive, argnums=(0, 1, 2))(q, k, v)
+    g1 = jax.jit(jax.grad(f_flash, argnums=(0, 1, 2)))(q, k, v)
+    g2 = jax.jit(jax.grad(f_naive, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
 
@@ -338,6 +340,7 @@ def test_explicit_small_block_k_honored_and_unfittable_raises(rng):
 # ------------------------------------------- prefix (chunked prefill)
 
 
+@jax.jit
 def _dense_chunk_attention(q, k, v, off):
     """``models/generate.py::_decode_chunk``'s dense body for a uniform
     chunk at ``off``: float32 scores over every cache slot, the
@@ -452,6 +455,7 @@ DECODE_S, DECODE_BK, DECODE_PLANE = 64, 16, 1
 DECODE_POS = (0, 1, 16, 17, 43, 63)
 
 
+@jax.jit
 def _dense_before(q, k_all, v_all, plane, pos0):
     """``_chunk_in_place``'s dense read of a plane, cut to the slots
     before the chunk: float32 scores over EVERY slot of every lane

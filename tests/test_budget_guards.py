@@ -17,6 +17,7 @@ from distkeras_tpu.analysis import ir_lint, shard_lint
 from distkeras_tpu.analysis.targets import (ZERO1_PARITY_PAIRS,
                                              ZERO_PARITY_TARGETS,
                                              default_targets)
+from helpers import toy_params
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -124,16 +125,14 @@ def test_placement_census_cross_checks_live_memory_footprint(linted):
     engine.memory_footprint() reads off LIVE addressable shards — the
     same accounting the ~n×-per-device-bytes serving claim is asserted
     from (tests/test_serving_sharded.py), now with a static witness."""
-    import jax
 
     import distkeras_tpu as dk
     from distkeras_tpu.analysis.targets import _lm_cfg
-    from distkeras_tpu.models import transformer as tfm
     from distkeras_tpu.parallel.mesh import MeshSpec, make_mesh
     from distkeras_tpu.parallel.sharding import serving_plan
 
     cfg = _lm_cfg()
-    params = tfm.init_params(jax.random.key(0), cfg)
+    params = toy_params(cfg)
     mesh = make_mesh(MeshSpec(data=4, model=2))
     eng = dk.ContinuousBatcher(params, cfg, lanes=2, prompt_buckets=(8,),
                                plan=serving_plan(), mesh=mesh)

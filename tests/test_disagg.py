@@ -27,9 +27,8 @@ from distkeras_tpu.serving import (EngineEndpoint, HttpReplica,
 from distkeras_tpu.serving.disagg import (BlockShipment,
                                           decode_shipment,
                                           encode_shipment)
+from helpers import serve_cfg, toy_params
 
-CFG_KW = dict(vocab_size=64, d_model=32, n_heads=2, n_layers=2,
-              d_ff=64, max_len=32, rope=True)
 BLOCK = 8
 
 
@@ -37,10 +36,9 @@ BLOCK = 8
 def engine_params():
     import jax
 
-    from distkeras_tpu.models import transformer as tfm
 
-    cfg = tfm.TransformerConfig(**CFG_KW)
-    return tfm.init_params(jax.random.key(0), cfg), cfg
+    cfg = serve_cfg()
+    return toy_params(cfg), cfg
 
 
 def _paged(params, cfg, **kw):
@@ -128,7 +126,7 @@ def test_codec_rejects_malformed():
 
 def test_export_import_refcounts_and_admission_hit(engine_params,
                                                    rng):
-    from distkeras_tpu.models.generate import generate
+    from helpers import generate
 
     params, cfg = engine_params
     src, dst = _paged(params, cfg), _paged(params, cfg)
@@ -210,7 +208,7 @@ def _fleet(params, cfg, prefill_cls=InProcessReplica, **kw):
 
 def test_disagg_parity_greedy_and_role_exclusivity(engine_params,
                                                    rng):
-    from distkeras_tpu.models.generate import generate
+    from helpers import generate
 
     params, cfg = engine_params
     router, pre, dec = _fleet(params, cfg, prefill_cls=_NoDecode)
@@ -235,7 +233,7 @@ def test_disagg_parity_greedy_and_role_exclusivity(engine_params,
 def test_disagg_parity_seeded_sampling(engine_params, rng):
     import jax
 
-    from distkeras_tpu.models.generate import generate
+    from helpers import generate
 
     params, cfg = engine_params
     kw = dict(temperature=0.7, top_k=16)
@@ -251,7 +249,7 @@ def test_disagg_parity_seeded_sampling(engine_params, rng):
 
 
 def test_disagg_parity_chunked_prefill(engine_params, rng):
-    from distkeras_tpu.models.generate import generate
+    from helpers import generate
 
     params, cfg = engine_params
     router, _pre, _dec = _fleet(params, cfg, prefill_chunk=8)
@@ -284,7 +282,7 @@ def test_disagg_parity_kv_int8(engine_params, rng):
 
 
 def test_warm_stems_skip_the_transfer(engine_params, rng):
-    from distkeras_tpu.models.generate import generate
+    from helpers import generate
 
     params, cfg = engine_params
     router, _pre, _dec = _fleet(params, cfg)
@@ -305,7 +303,7 @@ def test_warm_stems_skip_the_transfer(engine_params, rng):
 
 def test_prefill_failure_falls_back_never_errors(engine_params, rng,
                                                  monkeypatch):
-    from distkeras_tpu.models.generate import generate
+    from helpers import generate
 
     params, cfg = engine_params
     router, pre, _dec = _fleet(params, cfg)
@@ -328,7 +326,7 @@ def test_prefill_failure_falls_back_never_errors(engine_params, rng,
 
 
 def test_stream_first_token_before_terminal(engine_params, rng):
-    from distkeras_tpu.models.generate import generate
+    from helpers import generate
 
     params, cfg = engine_params
     eng = _paged(params, cfg)
@@ -347,7 +345,7 @@ def test_stream_first_token_before_terminal(engine_params, rng):
 
 
 def test_stream_across_the_disagg_hop(engine_params, rng):
-    from distkeras_tpu.models.generate import generate
+    from helpers import generate
 
     params, cfg = engine_params
     router, _pre, _dec = _fleet(params, cfg)

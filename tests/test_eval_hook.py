@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 import distkeras_tpu as dk
-from helpers import make_blobs, make_mlp
+from helpers import make_blobs, make_mlp, toy_params
 
 
 def _sets(blobs):
@@ -162,11 +162,10 @@ def test_perplexity_evaluator_validation(rng):
     import distkeras_tpu as dk
     from distkeras_tpu.models import transformer as tfm
 
-    import jax
 
     cfg = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
                                 n_layers=1, d_ff=64, max_len=17)
-    params = tfm.init_params(jax.random.key(0), cfg)
+    params = toy_params(cfg)
     ev = dk.PerplexityEvaluator(params, cfg, batch_size=16)
     with pytest.raises(ValueError, match="one batch needs"):
         ev.evaluate(np.zeros((4, 17), np.int32))

@@ -25,7 +25,7 @@ from distkeras_tpu.parallel.mesh import MeshSpec, make_mesh
 from distkeras_tpu.resilience import FaultPlan, Supervisor
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from helpers import make_blobs, make_mlp
+from helpers import make_blobs, make_mlp, toy_params
 
 CFG = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
                             n_layers=2, d_ff=64, max_len=32)
@@ -427,8 +427,7 @@ def test_exchange_rejections(devices):
     with pytest.raises(ValueError, match="data"):
         dk.LMTrainer(CFG, mesh=tp, merge_rule="adasum")
     with pytest.raises(ValueError, match="LoRATrainer"):
-        dk.LoRATrainer(CFG, base_params=tfm.init_params(
-            jax.random.key(0), CFG), compress="int8")
+        dk.LoRATrainer(CFG, base_params=toy_params(CFG), compress="int8")
     with pytest.raises(ValueError, match="segments"):
         t = dk.LMTrainer(CFG, mesh=mesh, compress="int8")
         rows = lm_tokens(32)

@@ -6,7 +6,10 @@ import numpy as np
 import pytest
 
 from distkeras_tpu.models import transformer as tfm
-from distkeras_tpu.models.generate import _decode_step, generate, init_cache
+from helpers import generate, jgen, jtfm, toy_params
+
+_decode_step, init_cache, prefill, beam_search = (
+    jgen._decode_step, jgen.init_cache, jgen.prefill, jgen.beam_search)
 
 
 CFG = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
@@ -32,9 +35,9 @@ def test_cached_decode_matches_full_forward(rng, cfg):
     decode semantics — so nothing depends on capacity being large
     enough to never drop (a no-op flag for the dense/rope configs).
     """
-    params = tfm.init_params(jax.random.key(0), cfg)
+    params = toy_params(cfg)
     toks = jnp.asarray(rng.integers(0, 64, (2, 12)).astype(np.int32))
-    full_logits, _ = tfm.apply(params, toks, cfg,
+    full_logits, _ = jtfm.apply(params, toks, cfg,
                                moe_dense_routing=bool(cfg.num_experts))
 
     cache = init_cache(cfg, 2)
@@ -58,7 +61,7 @@ def test_moe_capacity_vs_dense_divergence_bounded(rng):
     import optax
 
     cfg = MOE_CFG
-    params = tfm.init_params(jax.random.key(3), cfg)
+    params = toy_params(cfg, 3)
     opt = optax.adam(3e-3)
     step = jax.jit(tfm.make_train_step(cfg, opt))
     carry = (params, opt.init(params))
@@ -67,8 +70,8 @@ def test_moe_capacity_vs_dense_divergence_bounded(rng):
         carry, _ = step(carry, toks)
     trained = carry[0]
 
-    nll_cap = float(tfm.lm_nll(trained, toks, cfg))
-    nll_dense = float(tfm.lm_nll(trained, toks, cfg,
+    nll_cap = float(jtfm.lm_nll(trained, toks, cfg))
+    nll_dense = float(jtfm.lm_nll(trained, toks, cfg,
                                  moe_dense_routing=True))
     # Routing genuinely differs at this capacity (the contract is a
     # bound, not equality)...
@@ -80,7 +83,7 @@ def test_moe_capacity_vs_dense_divergence_bounded(rng):
 
 
 def test_generate_greedy_matches_argmax_rollout(rng):
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     prompt = jnp.asarray(rng.integers(0, 64, (2, 4)).astype(np.int32))
     out = generate(params, prompt, CFG, max_new_tokens=6)
     assert out.shape == (2, 10)
@@ -89,21 +92,21 @@ def test_generate_greedy_matches_argmax_rollout(rng):
     # Reference rollout: full forward, argmax, append.
     seq = np.asarray(prompt)
     for _ in range(6):
-        logits, _ = tfm.apply(params, jnp.asarray(seq), CFG)
+        logits, _ = jtfm.apply(params, jnp.asarray(seq), CFG)
         nxt = np.asarray(logits[:, -1].argmax(-1), np.int32)
         seq = np.concatenate([seq, nxt[:, None]], axis=1)
     np.testing.assert_array_equal(out, seq)
 
 
 def test_generate_deterministic_and_jittable(rng):
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     prompt = jnp.asarray(rng.integers(0, 64, (1, 3)).astype(np.int32))
     g = jax.jit(lambda p, t: generate(p, t, CFG, max_new_tokens=5))
     np.testing.assert_array_equal(g(params, prompt), g(params, prompt))
 
 
 def test_generate_temperature_needs_key(rng):
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     prompt = jnp.zeros((1, 3), jnp.int32)
     with pytest.raises(ValueError, match="PRNG key"):
         generate(params, prompt, CFG, 4, temperature=0.8)
@@ -117,13 +120,13 @@ def test_generate_bfloat16_cache(rng):
     cfg = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
                                 n_layers=1, d_ff=64, max_len=16,
                                 dtype="bfloat16")
-    params = tfm.init_params(jax.random.key(0), cfg)
+    params = toy_params(cfg)
     out = generate(params, jnp.zeros((1, 2), jnp.int32), cfg, 4)
     assert out.shape == (1, 6)
 
 
 def test_generate_length_guard(rng):
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     with pytest.raises(ValueError, match="max_len"):
         generate(params, jnp.zeros((1, 10), jnp.int32), CFG, 10)
     with pytest.raises(ValueError, match="at least one token"):
@@ -153,7 +156,7 @@ def test_top_p_mask_nucleus():
 
 
 def test_generate_topk1_equals_greedy(rng):
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     prompt = jnp.asarray(rng.integers(0, 64, (2, 4)).astype(np.int32))
     greedy = generate(params, prompt, CFG, max_new_tokens=6)
     k1 = generate(params, prompt, CFG, max_new_tokens=6, temperature=0.7,
@@ -162,7 +165,7 @@ def test_generate_topk1_equals_greedy(rng):
 
 
 def test_generate_tiny_top_p_equals_greedy(rng):
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     prompt = jnp.asarray(rng.integers(0, 64, (2, 4)).astype(np.int32))
     greedy = generate(params, prompt, CFG, max_new_tokens=6)
     p0 = generate(params, prompt, CFG, max_new_tokens=6, temperature=1.3,
@@ -171,7 +174,7 @@ def test_generate_tiny_top_p_equals_greedy(rng):
 
 
 def test_generate_sampling_deterministic_per_key(rng):
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     prompt = jnp.asarray(rng.integers(0, 64, (1, 3)).astype(np.int32))
 
     def g(seed):
@@ -183,7 +186,7 @@ def test_generate_sampling_deterministic_per_key(rng):
 
 
 def test_generate_sampling_validation(rng):
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     prompt = jnp.zeros((1, 3), jnp.int32)
     with pytest.raises(ValueError, match="temperature > 0"):
         generate(params, prompt, CFG, 4, top_k=5)
@@ -197,12 +200,12 @@ def test_generate_sampling_validation(rng):
 
 def test_generate_rope_greedy_matches_rollout(rng):
     cfg = ROPE_CFG
-    params = tfm.init_params(jax.random.key(0), cfg)
+    params = toy_params(cfg)
     prompt = jnp.asarray(rng.integers(0, 64, (2, 4)).astype(np.int32))
     out = generate(params, prompt, cfg, max_new_tokens=6)
     seq = np.asarray(prompt)
     for _ in range(6):
-        logits, _ = tfm.apply(params, jnp.asarray(seq), cfg)
+        logits, _ = jtfm.apply(params, jnp.asarray(seq), cfg)
         nxt = np.asarray(logits[:, -1].argmax(-1), np.int32)
         seq = np.concatenate([seq, nxt[:, None]], axis=1)
     np.testing.assert_array_equal(out, seq)
@@ -217,9 +220,9 @@ def test_gqa_cache_is_smaller_and_decode_matches(rng, kv):
                                 n_kv_heads=kv, rope=True)
     cache = init_cache(cfg, batch=2)
     assert cache["k"].shape == (2, 2, 16, kv, 8)
-    params = tfm.init_params(jax.random.key(0), cfg)
+    params = toy_params(cfg)
     toks_ = jnp.asarray(rng.integers(0, 64, (2, 10)).astype(np.int32))
-    full_logits, _ = tfm.apply(params, toks_, cfg)
+    full_logits, _ = jtfm.apply(params, toks_, cfg)
     for pos in range(10):
         logits, cache = _decode_step(params, cache, toks_[:, pos], pos, cfg)
         np.testing.assert_allclose(logits, full_logits[:, pos],
@@ -231,7 +234,7 @@ def test_generate_ragged_batch_matches_individual(rng, cfg):
     """Right-padded prompts + prompt_lengths: every row decodes exactly
     as it would alone (left-pad alignment, masked pad, per-row position
     ids)."""
-    params = tfm.init_params(jax.random.key(0), cfg)
+    params = toy_params(cfg)
     p1 = rng.integers(1, 64, (5,)).astype(np.int32)   # length 5
     p2 = rng.integers(1, 64, (2,)).astype(np.int32)   # length 2
     padded = np.zeros((2, 5), np.int32)
@@ -250,14 +253,14 @@ def test_generate_ragged_batch_matches_individual(rng, cfg):
 
 
 def test_generate_ragged_validation(rng):
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     prompt = jnp.zeros((2, 4), jnp.int32)
     with pytest.raises(ValueError, match="prompt_lengths"):
         generate(params, prompt, CFG, 4, prompt_lengths=np.array([4]))
 
 
 def test_generate_ragged_length_range_checked(rng):
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     prompt = jnp.zeros((2, 4), jnp.int32)
     with pytest.raises(ValueError, match=r"\[1, 4\]"):
         generate(params, prompt, CFG, 4, prompt_lengths=np.array([4, 7]))
@@ -266,7 +269,7 @@ def test_generate_ragged_length_range_checked(rng):
 
 
 def test_generate_eos_sticky(rng):
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     prompt = jnp.asarray(rng.integers(0, 64, (2, 4)).astype(np.int32))
     free = np.asarray(generate(params, prompt, CFG, max_new_tokens=8))
     eos = int(free[0, 4])  # row 0's first generated token
@@ -288,7 +291,7 @@ def test_prefill_matches_sequential_generate(rng, cfg):
     """The prefill/decode split is a pure optimization: outputs must
     equal teacher-forcing every prompt position through the cached
     step (same einsums, same dtype path)."""
-    params = tfm.init_params(jax.random.key(0), cfg)
+    params = toy_params(cfg)
     prompt = jnp.asarray(rng.integers(0, 64, (3, 7)), jnp.int32)
     seq = generate(params, prompt, cfg, max_new_tokens=8,
                    use_prefill=False)
@@ -301,7 +304,7 @@ def test_prefill_matches_sequential_gqa(rng):
     cfg = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=4,
                                 n_layers=2, d_ff=64, max_len=32,
                                 n_kv_heads=2, rope=True)
-    params = tfm.init_params(jax.random.key(1), cfg)
+    params = toy_params(cfg, 1)
     prompt = jnp.asarray(rng.integers(0, 64, (2, 11)), jnp.int32)
     seq = generate(params, prompt, cfg, max_new_tokens=6,
                    use_prefill=False)
@@ -311,7 +314,7 @@ def test_prefill_matches_sequential_gqa(rng):
 
 
 def test_prefill_sampling_matches_sequential(rng):
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     prompt = jnp.asarray(rng.integers(0, 64, (2, 7)), jnp.int32)
     kw = dict(temperature=0.8, key=jax.random.key(5), top_k=8)
     seq = generate(params, prompt, CFG, 6, use_prefill=False, **kw)
@@ -320,7 +323,7 @@ def test_prefill_sampling_matches_sequential(rng):
 
 
 def test_prefill_eos_matches_sequential(rng):
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     prompt = jnp.asarray(rng.integers(0, 64, (4, 5)), jnp.int32)
     seq = generate(params, prompt, CFG, 10, eos_token=3,
                    use_prefill=False)
@@ -332,7 +335,7 @@ def test_prefill_eos_matches_sequential(rng):
 def test_prefill_rejections(rng):
     prompt = jnp.asarray(rng.integers(0, 64, (2, 5)), jnp.int32)
     # Ragged prompts keep the sequential path.
-    params_d = tfm.init_params(jax.random.key(0), CFG)
+    params_d = toy_params(CFG)
     with pytest.raises(ValueError, match="use_prefill"):
         generate(params_d, prompt, CFG, 4, use_prefill=True,
                  prompt_lengths=np.array([3, 5]))
@@ -342,7 +345,7 @@ def test_prefill_rejections(rng):
 def test_prefill_moe_matches_sequential(rng, cfg):
     """MoE prompts prefill with decode-parity dense routing: outputs
     equal the all-sequential path exactly (same per-token math)."""
-    params = tfm.init_params(jax.random.key(1), cfg)
+    params = toy_params(cfg, 1)
     prompt = jnp.asarray(rng.integers(0, 64, (3, 7)), jnp.int32)
     seq = generate(params, prompt, cfg, 6, use_prefill=False)
     pre = generate(params, prompt, cfg, 6, use_prefill=True)
@@ -352,9 +355,8 @@ def test_prefill_moe_matches_sequential(rng, cfg):
 
 
 def test_prefill_rejects_overlong_prompt(rng):
-    from distkeras_tpu.models.generate import prefill
 
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     prompt = jnp.asarray(rng.integers(0, 64, (2, CFG.max_len + 2)), jnp.int32)
     with pytest.raises(ValueError, match="max_len"):
         prefill(params, prompt, CFG)
@@ -365,7 +367,7 @@ def test_prefill_rejects_overlong_prompt(rng):
 def test_quantize_roundtrip_error_bound(rng):
     from distkeras_tpu.models.quant import quantize_params
 
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     qp = quantize_params(params)
     w = np.asarray(params["layers"]["attn"]["wq"])
     dq = np.asarray(qp["layers"]["attn"]["wq"].dequant())
@@ -381,7 +383,7 @@ def test_quantized_decode_matches_f32_greedy(rng):
 
     from distkeras_tpu.models.quant import quantize_params
 
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     opt = optax.adam(1e-2)
     step = jax.jit(tfm.make_train_step(CFG, opt))
     carry = (params, opt.init(params))
@@ -401,7 +403,7 @@ def test_quantized_decode_matches_f32_greedy(rng):
 def test_quantized_params_memory_and_guards(rng):
     from distkeras_tpu.models.quant import QTensor, quantize_params
 
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     qp = quantize_params(params)
     emb = qp["tok_emb"]
     assert isinstance(emb, QTensor) and emb.q.dtype == jnp.int8
@@ -414,7 +416,7 @@ def test_quantized_params_memory_and_guards(rng):
     with pytest.raises(ValueError, match="use_prefill"):
         generate(qp, prompt, CFG, 4, use_prefill=True)
     # MoE rejected.
-    moe_params = tfm.init_params(jax.random.key(1), MOE_CFG)
+    moe_params = toy_params(MOE_CFG, 1)
     with pytest.raises(ValueError, match="dense-FFN"):
         quantize_params(moe_params)
 
@@ -425,7 +427,7 @@ def test_quantized_decode_rope_gqa(rng):
     cfg = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=4,
                                 n_layers=2, d_ff=64, max_len=32,
                                 n_kv_heads=2, rope=True)
-    params = tfm.init_params(jax.random.key(1), cfg)
+    params = toy_params(cfg, 1)
     prompt = jnp.asarray(rng.integers(0, 64, (2, 5)), jnp.int32)
     out = generate(quantize_params(params), prompt, cfg, 6)
     assert out.shape == (2, 11)
@@ -438,7 +440,7 @@ def _seq_logprob(params, cfg, seq, start):
     """Sum of per-token log-probs of seq[start:] under the model."""
     from distkeras_tpu.models import transformer as tfm
 
-    logits, _ = tfm.apply(params, jnp.asarray(seq[None, :-1]), cfg)
+    logits, _ = jtfm.apply(params, jnp.asarray(seq[None, :-1]), cfg)
     logp = jax.nn.log_softmax(logits, axis=-1)[0]
     tgt = np.asarray(seq[1:])
     per = np.asarray(jnp.take_along_axis(
@@ -447,9 +449,8 @@ def _seq_logprob(params, cfg, seq, start):
 
 
 def test_beam_width_1_equals_greedy(rng):
-    from distkeras_tpu.models.generate import beam_search
 
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     prompt = jnp.asarray(rng.integers(0, 64, (3, 5)), jnp.int32)
     greedy = generate(params, prompt, CFG, 8)
     seqs, scores = beam_search(params, prompt, CFG, 8, beam_width=1)
@@ -458,9 +459,8 @@ def test_beam_width_1_equals_greedy(rng):
 
 
 def test_beam_scores_match_rescoring_and_beat_greedy(rng):
-    from distkeras_tpu.models.generate import beam_search
 
-    params = tfm.init_params(jax.random.key(1), CFG)
+    params = toy_params(CFG, 1)
     prompt = jnp.asarray(rng.integers(0, 64, (2, 4)), jnp.int32)
     n_new = 6
     seqs, scores = beam_search(params, prompt, CFG, n_new, beam_width=4)
@@ -483,9 +483,8 @@ def test_beam_scores_match_rescoring_and_beat_greedy(rng):
 
 
 def test_beam_eos_freezes_score(rng):
-    from distkeras_tpu.models.generate import beam_search
 
-    params = tfm.init_params(jax.random.key(2), CFG)
+    params = toy_params(CFG, 2)
     prompt = jnp.asarray(rng.integers(0, 64, (2, 3)), jnp.int32)
     seqs, scores = beam_search(params, prompt, CFG, 8, beam_width=3,
                                eos_token=5)
@@ -499,10 +498,9 @@ def test_beam_eos_freezes_score(rng):
 
 
 def test_beam_validation_and_quantized(rng):
-    from distkeras_tpu.models.generate import beam_search
     from distkeras_tpu.models.quant import quantize_params
 
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     prompt = jnp.asarray(rng.integers(0, 64, (2, 4)), jnp.int32)
     with pytest.raises(ValueError, match="beam_width"):
         beam_search(params, prompt, CFG, 4, beam_width=0)
@@ -518,9 +516,8 @@ def test_beam_validation_and_quantized(rng):
 
 
 def test_beam_prefill_matches_sequential(rng):
-    from distkeras_tpu.models.generate import beam_search
 
-    params = tfm.init_params(jax.random.key(3), CFG)
+    params = toy_params(CFG, 3)
     prompt = jnp.asarray(rng.integers(0, 64, (2, 6)), jnp.int32)
     s1, sc1 = beam_search(params, prompt, CFG, 5, beam_width=3,
                           use_prefill=True)
@@ -537,12 +534,11 @@ def test_beam_frozen_score_is_length_invariant(rng):
     not logp(eos), each step)."""
     import optax
 
-    from distkeras_tpu.models.generate import beam_search
 
     # Constant-row training: the model emits token c forever; with
     # eos_token=c the best beam finishes at the first generated slot.
     c = 9
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     opt = optax.adam(1e-2)
     step = jax.jit(tfm.make_train_step(CFG, opt))
     carry = (params, opt.init(params))
@@ -566,9 +562,9 @@ def test_windowed_decode_matches_training_forward(rng):
     import dataclasses
 
     cfg = dataclasses.replace(ROPE_CFG, attention_window=4)
-    params = tfm.init_params(jax.random.key(0), cfg)
+    params = toy_params(cfg)
     t = jnp.asarray(rng.integers(0, 64, (2, 10)), jnp.int32)
-    full_logits, _ = tfm.apply(params, t, cfg)
+    full_logits, _ = jtfm.apply(params, t, cfg)
     cache = init_cache(cfg, 2)
     for pos in range(10):
         step_logits, cache = _decode_step(params, cache, t[:, pos], pos,
@@ -582,7 +578,7 @@ def test_windowed_generate_prefill_matches_sequential(rng):
     import dataclasses
 
     cfg = dataclasses.replace(CFG, attention_window=3)
-    params = tfm.init_params(jax.random.key(1), cfg)
+    params = toy_params(cfg, 1)
     prompt = jnp.asarray(rng.integers(0, 64, (2, 7)), jnp.int32)
     pre = generate(params, prompt, cfg, 6, use_prefill=True)
     seq = generate(params, prompt, cfg, 6, use_prefill=False)
@@ -593,9 +589,8 @@ def test_beam_length_penalty(rng):
     """alpha=0 is the raw ordering; alpha>0 re-ranks by the GNMT
     normalization and returns the normalized scores, consistent with
     each beam's generated length."""
-    from distkeras_tpu.models.generate import beam_search
 
-    params = tfm.init_params(jax.random.key(4), CFG)
+    params = toy_params(CFG, 4)
     prompt = jnp.asarray(rng.integers(0, 64, (2, 4)), jnp.int32)
     s0, sc0 = beam_search(params, prompt, CFG, 6, beam_width=4)
     s1, sc1 = beam_search(params, prompt, CFG, 6, beam_width=4,
@@ -624,10 +619,9 @@ def test_beam_length_penalty_frozen_lengths(rng):
     emits eos immediately, the best beam's normalized score uses n=1."""
     import optax
 
-    from distkeras_tpu.models.generate import beam_search
 
     c = 9
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     opt = optax.adam(1e-2)
     step = jax.jit(tfm.make_train_step(CFG, opt))
     carry = (params, opt.init(params))
@@ -657,7 +651,7 @@ def test_rolling_decode_matches_large_cache(rng):
                                  n_layers=2, d_ff=64, rope=True,
                                  attention_window=6, max_len=64)
     small = dataclasses.replace(base, max_len=16)  # will wrap
-    params = tfm.init_params(jax.random.key(0), base)
+    params = toy_params(base)
     prompt = jnp.asarray(rng.integers(0, 64, (2, 5)), jnp.int32)
     n_new = 35  # 5 + 35 = 40 > 16: several full wraps
     big = generate(params, prompt, base, n_new)
@@ -673,7 +667,7 @@ def test_rolling_decode_sampling_and_eos(rng):
                                  attention_window=4, max_len=48,
                                  n_kv_heads=1)
     small = dataclasses.replace(base, max_len=12)
-    params = tfm.init_params(jax.random.key(1), base)
+    params = toy_params(base, 1)
     prompt = jnp.asarray(rng.integers(0, 64, (2, 4)), jnp.int32)
     kw = dict(temperature=0.8, key=jax.random.key(7), top_k=8, eos_token=3)
     big = generate(params, prompt, base, 25, **kw)
@@ -689,14 +683,13 @@ def test_rolling_beam_matches_large_cache(rng):
     parent-gather, with eos and GQA in the mix."""
     import dataclasses
 
-    from distkeras_tpu.models.generate import beam_search
 
     base = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=4,
                                  n_kv_heads=2, n_layers=2, d_ff=64,
                                  rope=True, attention_window=6,
                                  max_len=64)
     small = dataclasses.replace(base, max_len=16)  # will wrap
-    params = tfm.init_params(jax.random.key(2), base)
+    params = toy_params(base, 2)
     prompt = jnp.asarray(rng.integers(0, 64, (2, 5)), jnp.int32)
     n_new = 30  # 5 + 30 = 35 > 16: several full wraps
     for kw in [dict(), dict(eos_token=7),
@@ -717,18 +710,18 @@ def test_rolling_decode_requires_rope_and_window(rng):
     still raise, including for ragged prompts."""
     import dataclasses
 
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     prompt = jnp.asarray(rng.integers(0, 64, (2, 4)), jnp.int32)
     with pytest.raises(ValueError, match="max_len"):
         generate(params, prompt, CFG, 20)  # no rope, no window
     win = dataclasses.replace(CFG, attention_window=4)  # window, no rope
-    pw = tfm.init_params(jax.random.key(0), win)
+    pw = toy_params(win)
     with pytest.raises(ValueError, match="max_len"):
         generate(pw, prompt, win, 20)
     roll = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
                                  n_layers=1, d_ff=64, rope=True,
                                  attention_window=4, max_len=12)
-    pr = tfm.init_params(jax.random.key(0), roll)
+    pr = toy_params(roll)
     with pytest.raises(ValueError, match="max_len"):  # ragged: no rolling
         generate(pr, prompt, roll, 20, prompt_lengths=np.array([2, 4]))
     out = generate(pr, prompt, roll, 20)  # eligible: runs past max_len
@@ -745,7 +738,7 @@ def test_rolling_decode_long_prompt_sequential_fallback(rng):
                                  n_layers=2, d_ff=64, rope=True,
                                  attention_window=4, max_len=48)
     small = dataclasses.replace(base, max_len=12)
-    params = tfm.init_params(jax.random.key(2), base)
+    params = toy_params(base, 2)
     prompt = jnp.asarray(rng.integers(0, 64, (2, 20)), jnp.int32)  # > 12
     big = generate(params, prompt, base, 10)
     rolled = generate(params, prompt, small, 10)
@@ -760,9 +753,8 @@ def test_beam_search_windowed_cfg(rng):
     import dataclasses
 
     cfg = dataclasses.replace(ROPE_CFG, attention_window=4)
-    params = tfm.init_params(jax.random.key(3), cfg)
+    params = toy_params(cfg, 3)
     prompt = jnp.asarray(rng.integers(0, 64, (2, 4)), jnp.int32)
-    from distkeras_tpu.models.generate import beam_search
 
     greedy = generate(params, prompt, cfg, 6)
     seqs, _ = beam_search(params, prompt, cfg, 6, beam_width=1)
@@ -781,7 +773,7 @@ def test_rolling_decode_quantized(rng):
                                  n_layers=2, d_ff=64, rope=True,
                                  attention_window=4, max_len=40)
     small = dataclasses.replace(base, max_len=10)
-    qp = quantize_params(tfm.init_params(jax.random.key(5), base))
+    qp = quantize_params(toy_params(base, 5))
     prompt = jnp.asarray(rng.integers(0, 64, (2, 4)), jnp.int32)
     big = generate(qp, prompt, base, 20)
     rolled = generate(qp, prompt, small, 20)
@@ -803,7 +795,7 @@ def test_min_p_mask_semantics():
 
 
 def test_generate_min_p_sampling(rng):
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     prompt = jnp.asarray(rng.integers(0, 64, (2, 5)), jnp.int32)
     out = generate(params, prompt, CFG, 6, temperature=0.9, min_p=0.1,
                    key=jax.random.key(1))
@@ -824,10 +816,9 @@ def test_beam_ancestry_equals_physical_reorder(rng):
     including under GQA grouping and an eos freeze."""
     import dataclasses
 
-    from distkeras_tpu.models.generate import beam_search
 
     gqa_cfg = dataclasses.replace(CFG, n_heads=4, n_kv_heads=2, rope=True)
-    params = tfm.init_params(jax.random.key(3), gqa_cfg)
+    params = toy_params(gqa_cfg, 3)
     prompt = jnp.asarray(rng.integers(0, 64, (3, 5)), jnp.int32)
     for kw in [dict(), dict(eos_token=7), dict(length_penalty=0.8)]:
         seqs_a, sc_a = beam_search(params, prompt, gqa_cfg, 10,
@@ -848,9 +839,8 @@ def test_beam_impl_knob_and_ancestry_size_guard(rng, monkeypatch):
     that size, and bad values are rejected.  (Windowed configs take
     ancestry too — test_beam_windowed_ancestry_equals_physical.)"""
     from distkeras_tpu.models import generate as gen
-    from distkeras_tpu.models.generate import beam_search
 
-    params = tfm.init_params(jax.random.key(5), CFG)
+    params = toy_params(CFG, 5)
     prompt = jnp.asarray(rng.integers(0, 64, (2, 4)).astype(np.int32))
     sa, sca = beam_search(params, prompt, CFG, 5, beam_width=3,
                           beam_impl="ancestry")
@@ -887,11 +877,10 @@ def test_beam_windowed_ancestry_equals_physical(rng):
     shorter than the sequence."""
     import dataclasses
 
-    from distkeras_tpu.models.generate import beam_search
 
     cfg = dataclasses.replace(CFG, n_heads=4, n_kv_heads=2, rope=True,
                               attention_window=6)
-    params = tfm.init_params(jax.random.key(7), cfg)
+    params = toy_params(cfg, 7)
     prompt = jnp.asarray(rng.integers(0, 64, (3, 5)), jnp.int32)
     for kw in [dict(), dict(eos_token=7), dict(length_penalty=0.6)]:
         sa, sca = beam_search(params, prompt, cfg, 10, beam_width=3,
@@ -941,12 +930,11 @@ def test_kv_int8_decode_close_to_fp(rng):
     """int8 KV cache: teacher-forced logits track the full-precision
     decode within quantization noise, and greedy generation on a
     near-deterministic model is unchanged."""
-    from distkeras_tpu.models.generate import _decode_step
 
     cfg = ROPE_CFG
-    params = tfm.init_params(jax.random.key(0), cfg)
+    params = toy_params(cfg)
     toks = jnp.asarray(rng.integers(0, 64, (2, 12)).astype(np.int32))
-    full_logits, _ = tfm.apply(params, toks, cfg)
+    full_logits, _ = jtfm.apply(params, toks, cfg)
 
     cache = init_cache(cfg, 2, kv_int8=True)
     for pos in range(12):
@@ -965,10 +953,8 @@ def test_kv_int8_generate_prefill_close_to_sequential(rng):
     int8 rounding.  The contract is closeness on logits (advisor
     round-3: token equality only held because greedy argmax absorbed
     the drift on a tiny model — fragile across seeds/backends)."""
-    from distkeras_tpu.models.generate import (_decode_step, init_cache,
-                                               prefill)
 
-    params = tfm.init_params(jax.random.key(1), CFG)
+    params = toy_params(CFG, 1)
     prompt = jnp.asarray(rng.integers(0, 64, (2, 6)).astype(np.int32))
     _, last_p = prefill(params, prompt, CFG, last_logits=True,
                         kv_int8=True)
@@ -984,9 +970,8 @@ def test_kv_int8_generate_prefill_close_to_sequential(rng):
 def test_kv_int8_beam_ancestry_equals_physical(rng):
     """Beam search runs on the int8 cache through BOTH the ancestry and
     physical paths with identical results."""
-    from distkeras_tpu.models.generate import beam_search
 
-    params = tfm.init_params(jax.random.key(2), CFG)
+    params = toy_params(CFG, 2)
     prompt = jnp.asarray(rng.integers(0, 64, (2, 4)).astype(np.int32))
     sa, sca = beam_search(params, prompt, CFG, 6, beam_width=3,
                           kv_int8=True)
@@ -1009,7 +994,7 @@ def test_kv_int8_rolling_decode_matches_large_cache(rng):
                                  n_layers=2, d_ff=64, rope=True,
                                  attention_window=6, max_len=64)
     small = dataclasses.replace(base, max_len=16)  # will wrap
-    params = tfm.init_params(jax.random.key(0), base)
+    params = toy_params(base)
     prompt = jnp.asarray(rng.integers(0, 64, (2, 5)), jnp.int32)
     big = generate(params, prompt, base, 35, kv_int8=True,
                    use_prefill=False)
@@ -1023,14 +1008,13 @@ def test_kv_int8_rolling_beam_matches_large_cache(rng):
     non-wrapping int8 big-cache run."""
     import dataclasses
 
-    from distkeras_tpu.models.generate import beam_search
 
     base = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=4,
                                  n_kv_heads=2, n_layers=2, d_ff=64,
                                  rope=True, attention_window=6,
                                  max_len=64)
     small = dataclasses.replace(base, max_len=16)  # will wrap
-    params = tfm.init_params(jax.random.key(2), base)
+    params = toy_params(base, 2)
     prompt = jnp.asarray(rng.integers(0, 64, (2, 5)), jnp.int32)
     kw = dict(beam_width=3, kv_int8=True, use_prefill=False)
     bs, bsc = beam_search(params, prompt, base, 20, **kw)
@@ -1047,7 +1031,7 @@ def test_kv_int8_ragged_rows_match_solo(rng):
     alone on the int8 cache (left-pad slots never attend; position ids
     count from the row's true start; per-token quantization makes the
     comparison exact, not just close)."""
-    params = tfm.init_params(jax.random.key(3), ROPE_CFG)
+    params = toy_params(ROPE_CFG, 3)
     p = 6
     rows = jnp.asarray(rng.integers(0, 64, (2, p)), jnp.int32)
     lens = [3, 6]
@@ -1067,9 +1051,8 @@ def test_prompt_cache_matches_full_prompt(rng):
     EXACTLY the tokens of running the concatenated prompt from scratch
     — greedy and sampled (the position-keyed PRNG stream makes the
     sampled comparison exact), batch-matched and batch-1-broadcast."""
-    from distkeras_tpu.models.generate import prefill
 
-    params = tfm.init_params(jax.random.key(0), ROPE_CFG)
+    params = toy_params(ROPE_CFG)
     prefix = jnp.asarray(rng.integers(0, 64, (2, 5)).astype(np.int32))
     tail = jnp.asarray(rng.integers(0, 64, (2, 3)).astype(np.int32))
     full = jnp.concatenate([prefix, tail], axis=1)
@@ -1098,9 +1081,8 @@ def test_prompt_cache_matches_full_prompt(rng):
 
 
 def test_prompt_cache_kv_int8_and_validation(rng):
-    from distkeras_tpu.models.generate import prefill
 
-    params = tfm.init_params(jax.random.key(1), CFG)
+    params = toy_params(CFG, 1)
     prefix = jnp.asarray(rng.integers(0, 64, (2, 4)).astype(np.int32))
     tail = jnp.asarray(rng.integers(0, 64, (2, 2)).astype(np.int32))
     qcache, _ = prefill(params, prefix, CFG, last_logits=False,
@@ -1125,10 +1107,9 @@ def test_prompt_cache_single_token_tail_and_quantized(rng):
     """Code-review regressions: a 1-token tail and a quantized tree both
     work with prompt_cache (no _resolve_prefill interference), and the
     error messages distinguish empty prefixes from budget overflow."""
-    from distkeras_tpu.models.generate import prefill
     from distkeras_tpu.models.quant import quantize_params
 
-    params = tfm.init_params(jax.random.key(1), CFG)
+    params = toy_params(CFG, 1)
     prefix = jnp.asarray(rng.integers(0, 64, (2, 4)).astype(np.int32))
     tail = jnp.asarray(rng.integers(0, 64, (2, 1)).astype(np.int32))
     cache, _ = prefill(params, prefix, CFG, last_logits=False)
@@ -1160,9 +1141,8 @@ def test_beam_prompt_cache_matches_full_prompt(rng):
     """Beam search over a reused prefix cache returns exactly the
     hypotheses and scores of beaming the concatenated prompt — on both
     the ancestry and physical paths, and under kv_int8."""
-    from distkeras_tpu.models.generate import beam_search, prefill
 
-    params = tfm.init_params(jax.random.key(0), ROPE_CFG)
+    params = toy_params(ROPE_CFG)
     prefix = jnp.asarray(rng.integers(0, 64, (2, 4)).astype(np.int32))
     tail = jnp.asarray(rng.integers(0, 64, (2, 3)).astype(np.int32))
     full = jnp.concatenate([prefix, tail], axis=1)
@@ -1195,12 +1175,11 @@ def test_kv_int8_gqa_decode_close_to_fp(rng):
     than query heads) quantizes and dequantizes consistently."""
     import dataclasses
 
-    from distkeras_tpu.models.generate import _decode_step
 
     cfg = dataclasses.replace(ROPE_CFG, n_heads=4, n_kv_heads=2)
-    params = tfm.init_params(jax.random.key(2), cfg)
+    params = toy_params(cfg, 2)
     toks = jnp.asarray(rng.integers(0, 64, (2, 10)).astype(np.int32))
-    full_logits, _ = tfm.apply(params, toks, cfg)
+    full_logits, _ = jtfm.apply(params, toks, cfg)
     cache = init_cache(cfg, 2, kv_int8=True)
     for pos in range(10):
         logits, cache = _decode_step(params, cache, toks[:, pos], pos,
@@ -1238,7 +1217,7 @@ def test_generate_top_p_one_equals_no_filter(rng):
     like top_p=None (the serving engines' contract), so a request
     copying its solo call's top_p=1.0 cannot diverge in the float
     corner where the sorted cumsum overshoots 1.0."""
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     prompt = jnp.asarray(rng.integers(0, 64, (2, 5)), jnp.int32)
     k = jax.random.key(7)
     one = generate(params, prompt, CFG, 6, temperature=0.9, top_p=1.0,
@@ -1258,136 +1237,3 @@ def test_generate_top_p_one_equals_no_filter(rng):
     with pytest.raises(ValueError, match="min_p"):
         generate(params, prompt, CFG, 6, temperature=0.9, min_p=-0.1,
                  key=k)
-
-
-# --------------------------------- chunked prefill's bounded attention
-
-# Kernel-legal widths (a head of 128, 128 slots, float32: chunks in
-# whole tiles of 8) at the smallest size that has them.
-GATE_CFG = tfm.TransformerConfig(vocab_size=64, d_model=256, n_heads=2,
-                                 n_kv_heads=1, n_layers=1, d_ff=64,
-                                 max_len=128)
-
-
-def _gate_call(case, rng, cfg=GATE_CFG):
-    """``(params, cache, tokens, pos0, cfg, kwargs)`` of one
-    ``_decode_chunk`` call of the named shape."""
-    import dataclasses
-
-    rows, t, kw = 2, 8, {"uniform_pos": True}
-    pos0 = jnp.full((rows,), 13, jnp.int32)
-    kv_int8 = False
-    if case == "one_token":
-        t = 1
-    elif case == "per_row":
-        kw, pos0 = {}, jnp.asarray([13, 40], jnp.int32)
-    elif case == "windowed":
-        cfg = dataclasses.replace(cfg, attention_window=32)
-    elif case == "int8":
-        kv_int8 = True
-    elif case == "beam":
-        t = 1
-        kw["beam_anc"] = (jnp.zeros((1, rows, cfg.max_len), jnp.int32),
-                          rows)
-    params = tfm.init_params(jax.random.key(0), cfg)
-    toks = jnp.asarray(rng.integers(0, 64, (rows, t)), jnp.int32)
-    return (params, init_cache(cfg, rows, kv_int8=kv_int8), toks, pos0,
-            cfg, kw)
-
-
-@pytest.mark.parametrize("case", ["uniform_chunk", "one_token", "per_row",
-                                  "windowed", "int8", "beam"])
-def test_decode_chunk_gate(rng, monkeypatch, case):
-    """On a TPU backend a uniform multi-token chunk — an admission, a
-    prefix warm-up — attends through the blocked prefix kernel, a
-    decode step (T = 1) and a chunk at per-row positions through the
-    per-lane bounded kernel over the slab, and both compute what the
-    dense body computes; a ring, an int8 cache and beam ancestry keep
-    the dense body, as every call does on another backend."""
-    from distkeras_tpu.models import generate as gen
-    from distkeras_tpu.ops import attention
-
-    calls = []
-
-    def kernel(name):
-        def run(q, *rest):
-            calls.append((name, q.shape))
-            return getattr(attention, name)(q, *rest, interpret=True)
-        return run
-    for name in ("flash_prefix_attention", "flash_decode_attention"):
-        monkeypatch.setattr(gen, name, kernel(name))
-    params, cache, toks, pos0, cfg, kw = _gate_call(case, rng)
-    dense_logits, dense_cache = gen._decode_chunk(params, cache, toks, pos0,
-                                                  cfg, **kw)
-    assert calls == []                      # this backend is no TPU
-    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
-    logits, new_cache = gen._decode_chunk(params, cache, toks, pos0, cfg,
-                                          **kw)
-    assert calls == {
-        "uniform_chunk": [("flash_prefix_attention", (2, 8, 2, 128))],
-        "one_token": [("flash_decode_attention", (2, 1, 2, 128))],
-        "per_row": [("flash_decode_attention", (2, 8, 2, 128))],
-    }.get(case, [])
-    np.testing.assert_allclose(logits, dense_logits, atol=1e-4, rtol=1e-4)
-    for leaf, want in zip(jax.tree.leaves(new_cache),
-                          jax.tree.leaves(dense_cache)):
-        np.testing.assert_array_equal(leaf, want)
-
-
-# ------------------------------- the decode step's per-lane bounded read
-
-# The two serving cells' head layouts at the smallest kernel-legal
-# size, float32: query heads on ONE K/V head (the tile rule's block is
-# the lane, read by quarters of 256 slots), and 8 K/V heads with groups
-# of 1 in a looped, extended stack — 2 passes x 2 layers, so four
-# planes, each holding other keys (two blocks of 128 slots a lane,
-# quarters of 32).
-LANE_CFGS = {
-    "multi_query": tfm.TransformerConfig(
-        vocab_size=64, d_model=256, n_heads=2, n_kv_heads=1, n_layers=2,
-        d_ff=64, max_len=1024),
-    "groups_of_1_looped": tfm.TransformerConfig(
-        vocab_size=64, d_model=1024, n_heads=8, n_kv_heads=8, n_layers=2,
-        d_ff=64, max_len=256, rope=True, ffn_gated=True, tie_head=False,
-        post_norms=True, fused_qkv=True, n_passes=2),
-}
-
-
-@pytest.mark.parametrize("t", [1, 4], ids=["token", "chunk4"])
-@pytest.mark.parametrize("name", sorted(LANE_CFGS))
-def test_chunk_in_place_per_lane_kernel_is_the_dense_body(rng, monkeypatch,
-                                                          name, t):
-    """``_chunk_in_place`` through the per-lane bounded kernel (the
-    interpreter, the tile rule's own block) against its dense body, on
-    a slab whose every slot holds something: rows at 0, 1, a copy's
-    edge, past the next and the last position a chunk fits, one token and a
-    per-row chunk of four.  Same logits; the slab written alike — and
-    read nowhere at or past a row's position, in no other plane: the
-    dense body masks those, the kernel must not see them."""
-    from distkeras_tpu.models import generate as gen
-    from distkeras_tpu.ops import attention
-
-    cfg = LANE_CFGS[name]
-    unit = gen.decode_read_unit(cfg, t, {"k": jnp.zeros((), jnp.float32)})
-    assert unit and cfg.max_len // unit >= 4
-    pos0 = jnp.asarray([0, 1, unit, 2 * unit + 3, cfg.max_len - t],
-                       jnp.int32)
-    params = tfm.init_params(jax.random.key(0), cfg)
-    cache = {k: jnp.asarray(rng.normal(size=v.shape), v.dtype)
-             for k, v in init_cache(cfg, len(pos0)).items()}
-    toks = jnp.asarray(rng.integers(0, 64, (len(pos0), t)), jnp.int32)
-    want, want_cache = gen._chunk_in_place(params, cache, toks, pos0, cfg)
-
-    calls = []
-
-    def kernel(q, *rest):
-        calls.append(q.shape)
-        return attention.flash_decode_attention(q, *rest, interpret=True)
-    monkeypatch.setattr(gen, "flash_decode_attention", kernel)
-    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
-    got, got_cache = gen._chunk_in_place(params, cache, toks, pos0, cfg)
-    assert calls == [(len(pos0), t, cfg.n_heads, cfg.head_dim)]  # one trace
-    np.testing.assert_allclose(got, want, atol=2e-4, rtol=2e-4)
-    for leaf, ref in zip(jax.tree.leaves(got_cache),
-                         jax.tree.leaves(want_cache)):
-        np.testing.assert_allclose(leaf, ref, atol=2e-5, rtol=2e-5)

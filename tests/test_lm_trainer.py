@@ -8,6 +8,7 @@ import pytest
 import distkeras_tpu as dk
 from distkeras_tpu.models import transformer as tfm
 from distkeras_tpu.parallel.mesh import MeshSpec, make_mesh
+from helpers import toy_params
 
 
 CFG = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
@@ -425,7 +426,7 @@ def test_ema_resume_matches_straight_run(tmp_path, devices, rng):
 
 
 def test_lora_trainer_rejects_ema(devices):
-    base = tfm.init_params(jax.random.key(0), CFG)
+    base = toy_params(CFG)
     with pytest.raises(ValueError, match="ema_decay is not supported"):
         dk.LoRATrainer(CFG, base, lora_rank=2, ema_decay=0.9)
 

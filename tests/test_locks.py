@@ -26,6 +26,7 @@ from distkeras_tpu import obs
 from distkeras_tpu.utils import locks
 from distkeras_tpu.utils.locks import (LockOrderViolation, TracedLock,
                                         TracedRLock, assert_unlocked)
+from helpers import serve_cfg, toy_params
 
 
 @pytest.fixture(autouse=True)
@@ -400,11 +401,8 @@ def _stress(eng, *, submitters: int, per_thread: int, url,
 
 
 def _stress_cfg():
-    from distkeras_tpu.models import transformer as tfm
 
-    return tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
-                                 n_layers=2, d_ff=64, max_len=32,
-                                 rope=True)
+    return serve_cfg()
 
 
 def test_concurrency_stress_bounded():
@@ -413,13 +411,11 @@ def test_concurrency_stress_bounded():
     under the sanitizer.  Every request reaches a terminal structured
     result, no thread dies, no violation is recorded (conftest's gate
     re-asserts that)."""
-    import jax
 
-    from distkeras_tpu.models import transformer as tfm
     from distkeras_tpu.serving import ContinuousBatcher
 
     cfg = _stress_cfg()
-    params = tfm.init_params(jax.random.key(0), cfg)
+    params = toy_params(cfg)
     eng = ContinuousBatcher(params, cfg, lanes=2, max_queue=4,
                             prompt_buckets=(8,))
     rule = obs.SloRule("serving.request_s", percentile=0.99,
@@ -442,13 +438,11 @@ def test_concurrency_stress_elastic_resize():
     scraper, ticker, and shutdown race on.  The resize compacts the
     lane table under the admission lock; the sanitizer watches every
     acquisition."""
-    import jax
 
-    from distkeras_tpu.models import transformer as tfm
     from distkeras_tpu.serving import ContinuousBatcher
 
     cfg = _stress_cfg()
-    params = tfm.init_params(jax.random.key(0), cfg)
+    params = toy_params(cfg)
     eng = ContinuousBatcher(params, cfg, lane_tiers=(1, 2), max_queue=2,
                             scale_up_after=1, scale_down_after=2,
                             prompt_buckets=(8,))

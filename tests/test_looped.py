@@ -33,6 +33,7 @@ from distkeras_tpu.models import generate as gen
 from distkeras_tpu.models import speculative as spec
 from distkeras_tpu.models import transformer as tfm
 from distkeras_tpu.obs import read_trace
+from helpers import generate, jgen, jtfm, toy_params
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TC = dict(vocab_size=128, d_model=64, n_heads=4, n_layers=2, d_ff=96,
@@ -55,7 +56,7 @@ def ref():
 def params():
     """Seeded weights with every norm scale and the gate's bias moved
     off their initial 1 and 0, so that a misplaced norm shows."""
-    p = tfm.init_params(jax.random.key(0), CFG)
+    p = toy_params(CFG)
     leaves, treedef = jax.tree.flatten(p)
     keys = jax.random.split(jax.random.key(1), len(leaves))
     return jax.tree.unflatten(treedef, [
@@ -73,14 +74,14 @@ def _tokens(n, seed=0):
 
 def test_every_pass_logits_and_exit_distribution(ref, params):
     toks = _tokens(23)
-    logits, probs = tfm.apply_passes(params, jnp.asarray(toks)[None], CFG)
+    logits, probs = jtfm.apply_passes(params, jnp.asarray(toks)[None], CFG)
     want_l, want_p = ref.all_passes(params, TC, toks)
     assert logits.shape == (3, 1, 23, 128) and probs.shape == (3, 1, 23)
     np.testing.assert_allclose(logits[:, 0], want_l, atol=TOL)
     np.testing.assert_allclose(probs[:, 0], want_p, atol=1e-5)
     np.testing.assert_allclose(probs.sum(0), 1.0, atol=1e-6)
     assert 0.02 < float(probs[0].mean()) < 0.98     # the gate does gate
-    served, _ = tfm.apply(params, jnp.asarray(toks)[None], CFG)
+    served, _ = jtfm.apply(params, jnp.asarray(toks)[None], CFG)
     np.testing.assert_array_equal(served, logits[-1])
 
 
@@ -99,13 +100,13 @@ def test_prefill_then_decode_logits(ref, params, use_prefill):
     seqs = np.stack([_tokens(15, 1), _tokens(15, 2)])
     want = np.stack([_ref_logits(ref, params, s) for s in seqs])
     if use_prefill:
-        cache, last = gen.prefill(params, jnp.asarray(seqs[:, :9]), CFG)
+        cache, last = jgen.prefill(params, jnp.asarray(seqs[:, :9]), CFG)
         np.testing.assert_allclose(last, want[:, 8], atol=TOL)
         start = 9
     else:
-        cache, start = gen.init_cache(CFG, 2), 0
+        cache, start = jgen.init_cache(CFG, 2), 0
     for pos in range(start, 15):
-        lg, cache = gen._decode_step(params, cache,
+        lg, cache = jgen._decode_step(params, cache,
                                      jnp.asarray(seqs[:, pos]), pos, CFG)
         np.testing.assert_allclose(lg, want[:, pos], atol=TOL)
 
@@ -119,10 +120,10 @@ def test_lane_admission_in_chunks_and_per_row_decode_logits(ref, params):
     on garbage.  Every logit the cache served against the reference."""
     a, b = _tokens(26, 3), _tokens(11, 4)
     want_a, want_b = (_ref_logits(ref, params, s) for s in (a, b))
-    cache = gen.init_cache(CFG, 3)
+    cache = jgen.init_cache(CFG, 3)
 
     def admit(cache, toks, lane, start):
-        lg, cache = gen._decode_chunk(
+        lg, cache = jgen._decode_chunk(
             params, cache, jnp.asarray(toks)[None],
             jnp.full((1,), start, jnp.int32), CFG, uniform_pos=True,
             lane=jnp.int32(lane))
@@ -136,7 +137,7 @@ def test_lane_admission_in_chunks_and_per_row_decode_logits(ref, params):
     for i in range(6):
         cur = jnp.asarray([b[5 + i], 7, a[20 + i]], jnp.int32)
         pos = jnp.asarray([5 + i, 0, 20 + i], jnp.int32)
-        lg, cache = gen._decode_chunk(params, cache, cur[:, None], pos, CFG)
+        lg, cache = jgen._decode_chunk(params, cache, cur[:, None], pos, CFG)
         np.testing.assert_allclose(lg[0, 0], want_b[5 + i], atol=TOL)
         np.testing.assert_allclose(lg[2, 0], want_a[20 + i], atol=TOL)
 
@@ -163,7 +164,7 @@ def served(params, tmp_path_factory):
 
 def test_engine_equals_solo_generate(served, params):
     for prompt, got in served[0]:
-        solo = gen.generate(params, jnp.asarray(prompt)[None], CFG, 10)
+        solo = generate(params, jnp.asarray(prompt)[None], CFG, 10)
         np.testing.assert_array_equal(got, solo[0, len(prompt):])
 
 
@@ -196,9 +197,9 @@ def test_float32_weights_under_bfloat16_activations_decode():
     """The scan's carry keeps the compute dtype when the weights are
     wider (the trainers' float32 master weights served as they are)."""
     cfg = dataclasses.replace(CFG, dtype="bfloat16")
-    p = tfm.init_params(jax.random.key(0), cfg)
-    out = gen.generate(p, jnp.asarray(_tokens(9))[None], cfg, 5)
-    lg, _ = tfm.apply(p, out[:, :-1], cfg)
+    p = toy_params(cfg)
+    out = generate(p, jnp.asarray(_tokens(9))[None], cfg, 5)
+    lg, _ = jtfm.apply(p, out[:, :-1], cfg)
     gap = lg[0, 8:].max(-1) - jnp.take_along_axis(
         lg[0, 8:], out[0, 9:, None], -1)[:, 0]
     assert out.shape == (1, 14) and float(gap.max()) < 0.25
@@ -213,7 +214,7 @@ def test_weights_for_l_layers_and_a_cache_of_r_times_l_planes(params):
     want = 2 * per_layer + 2 * v * d + d + d + 1   # + final norm, gate w, b
     assert sum(a.size for a in jax.tree.leaves(params)) == want
     assert all(a.shape[0] == 2 for a in jax.tree.leaves(params["layers"]))
-    cache = gen.init_cache(CFG, 5)
+    cache = jgen.init_cache(CFG, 5)
     assert CFG.kv_planes == 6
     assert cache["k"].shape == cache["v"].shape == (6, 5, 64, 4, 16)
 
@@ -228,7 +229,7 @@ def test_a_wrong_reference_fails_the_tolerance(ref, params, fault):
     hundreds of tolerances, so neither mistake in the program could
     pass (a)-(b)."""
     toks = _tokens(23)
-    served, _ = tfm.apply(params, jnp.asarray(toks)[None], CFG)
+    served, _ = jtfm.apply(params, jnp.asarray(toks)[None], CFG)
     wrong = ref.logits_at(params, ref.forward(params, TC, toks, fault=fault),
                           np.arange(23))
     assert np.abs(np.asarray(served[0]) - wrong).max() > 100 * TOL
@@ -240,16 +241,16 @@ def test_a_wrong_reference_fails_the_tolerance(ref, params, fault):
 OLD = {
     "learned": (dict(vocab_size=64, d_model=32, n_heads=2, n_layers=2,
                      d_ff=64, max_len=64),
-                ["0x1.7c480a0000000p+0", "-0x1.2f46c60000000p-2",
-                 "-0x1.f7820c0000000p-1", "-0x1.5d2ff20000000p+0",
-                 "0x1.8a85d00000000p-4", "0x1.8c5f180000000p-1"],
+                ["0x1.7c48060000000p+0", "-0x1.2f46be0000000p-2",
+                 "-0x1.f782100000000p-1", "-0x1.5d2ff00000000p+0",
+                 "0x1.8a85a20000000p-4", "0x1.8c5f1e0000000p-1"],
                 [[37, 54, 43, 43, 43, 43], [32, 32, 46, 32, 0, 0]],
                 [5, 5, 5, 5, 5]),
     "rope_gqa": (dict(vocab_size=64, d_model=32, n_heads=4, n_kv_heads=2,
                       n_layers=2, d_ff=64, max_len=64, rope=True),
-                 ["-0x1.0b7ee00000000p+0", "0x1.c4bb6c0000000p+0",
-                  "-0x1.0801600000000p-1", "-0x1.461a800000000p-2",
-                  "-0x1.20d28c0000000p+0", "0x1.a0d0fe0000000p+0"],
+                 ["-0x1.0b7ed80000000p+0", "0x1.c4bb680000000p+0",
+                  "-0x1.0801740000000p-1", "-0x1.461a840000000p-2",
+                  "-0x1.20d28a0000000p+0", "0x1.a0d1000000000p+0"],
                  [[30, 24, 50, 1, 1, 1], [23, 6, 6, 6, 6, 5]],
                  [34, 38, 38, 5, 32]),
 }
@@ -261,12 +262,17 @@ def test_switches_off_is_the_parent_bit_for_bit(name, monkeypatch):
     gives what commit 42e8483 (the parent of the PR that added the
     switches) gave on these toy configs, logits to the last bit; solo
     ``generate`` and the engine, which now decode through the in-place
-    body, give that commit's tokens."""
+    body, give that commit's tokens.  (Recorded from that commit under
+    conftest's XLA flags, PR 29: without
+    ``--xla_backend_optimization_level=0`` it and this tree both end
+    0x1.7c480a..., with it both 0x1.7c4806...; the tokens are the same.)"""
     kwargs, logits_hex, tokens, engine_tokens = OLD[name]
     cfg = tfm.TransformerConfig(**kwargs, n_passes=1, ffn_gated=False,
                                 tie_head=True, post_norms=False,
                                 fused_qkv=False)
     assert cfg == tfm.TransformerConfig(**kwargs) and not cfg.extended
+    # Eager throughout, not toy_params and jtfm: the recorded bits are
+    # those of a primitive at a time (a fused program rounds otherwise).
     p = tfm.init_params(jax.random.key(0), cfg)
     prompt = (jnp.arange(14).reshape(2, 7) * 5) % 64
     lg, _ = tfm.apply(p, prompt, cfg)
@@ -302,15 +308,15 @@ REJECTED = {
     "speculative_generate": lambda p: spec.speculative_generate(
         p, p, _prompt(), CFG, CFG, 4),
     "beam_search": lambda p: gen.beam_search(p, _prompt(), CFG, 4),
-    "kv_int8": lambda p: gen.generate(p, _prompt(), CFG, 4, kv_int8=True),
+    "kv_int8": lambda p: generate(p, _prompt(), CFG, 4, kv_int8=True),
     "ContinuousBatcher with kv_int8=": _engine(kv_int8=True),
     "rolling lanes": lambda p: dk.ContinuousBatcher(p, WINDOWED, lanes=2),
-    "windowed (rolling)": lambda p: gen.generate(p, _prompt(), WINDOWED, 4),
-    "ragged-prompt": lambda p: gen.generate(
+    "windowed (rolling)": lambda p: generate(p, _prompt(), WINDOWED, 4),
+    "ragged-prompt": lambda p: generate(
         p, _prompt(), CFG, 4, prompt_lengths=np.asarray([4])),
-    "prompt_cache": lambda p: gen.generate(
+    "prompt_cache": lambda p: generate(
         p, _prompt(), CFG, 4,
-        prompt_cache=(gen.init_cache(CFG, 1), 6)),
+        prompt_cache=(jgen.init_cache(CFG, 1), 6)),
     "ContinuousBatcher with prompt_cache=": _engine(
         prompt_cache=(None, 4)),
     "ContinuousBatcher with prefix_pool=": _engine(prefix_pool=object()),
@@ -347,18 +353,18 @@ def test_multi_token_chunks_at_per_row_positions(ref, params):
     over all planes."""
     a, b = _tokens(20, 5), _tokens(20, 6)
     want = [_ref_logits(ref, params, s) for s in (a, b)]
-    cache = gen.init_cache(CFG, 2)
+    cache = jgen.init_cache(CFG, 2)
     for row, (seq, n) in enumerate(((a, 9), (b, 4))):
-        _, cache = gen._decode_chunk(
+        _, cache = jgen._decode_chunk(
             params, cache, jnp.asarray(seq[:n])[None],
             jnp.zeros((1,), jnp.int32), CFG, uniform_pos=True,
             lane=jnp.int32(row))
-    lg, cache = gen._decode_chunk(
+    lg, cache = jgen._decode_chunk(
         params, cache, jnp.asarray(np.stack([a[9:12], b[4:7]])),
         jnp.asarray([9, 4], jnp.int32), CFG)
     np.testing.assert_allclose(lg[0], want[0][9:12], atol=TOL)
     np.testing.assert_allclose(lg[1], want[1][4:7], atol=TOL)
-    lg, _ = gen._decode_chunk(
+    lg, _ = jgen._decode_chunk(
         params, cache, jnp.asarray([[a[12]], [b[7]]]),
         jnp.asarray([12, 7], jnp.int32), CFG)
     np.testing.assert_allclose(lg[0, 0], want[0][12], atol=TOL)
@@ -384,16 +390,16 @@ def test_in_place_body_equals_the_full_forward(name):
     rotary positions: logits through the cache (a chunk, then single
     tokens) are ``apply``'s."""
     cfg = tfm.TransformerConfig(**LAYOUTS[name])
-    p = tfm.init_params(jax.random.key(2), cfg)
+    p = toy_params(cfg, 2)
     assert ("wqkv" in p["layers"]["attn"]) == cfg.fused_qkv
     toks = jnp.asarray(_tokens(12, 8) % 64)[None]
-    want, _ = tfm.apply(p, toks, cfg)
-    lg, cache = gen._decode_chunk(p, gen.init_cache(cfg, 1), toks[:, :7],
+    want, _ = jtfm.apply(p, toks, cfg)
+    lg, cache = jgen._decode_chunk(p, jgen.init_cache(cfg, 1), toks[:, :7],
                                   jnp.zeros((1,), jnp.int32), cfg,
                                   uniform_pos=True)
     np.testing.assert_allclose(lg[0], want[0, :7], atol=TOL)
     for pos in range(7, 12):
-        lg, cache = gen._decode_step(p, cache, toks[:, pos], pos, cfg)
+        lg, cache = jgen._decode_step(p, cache, toks[:, pos], pos, cfg)
         np.testing.assert_allclose(lg[0], want[0, pos], atol=TOL)
 
 
@@ -403,15 +409,15 @@ def test_fused_layout_is_the_split_layout_rearranged():
               n_layers=2, d_ff=64, max_len=32, rope=True)
     split, fused = (tfm.TransformerConfig(**kw, fused_qkv=f)
                     for f in (False, True))
-    ps, pf = (tfm.init_params(jax.random.key(3), c) for c in (split, fused))
+    ps, pf = (toy_params(c, 3) for c in (split, fused))
     a = ps["layers"]["attn"]
     np.testing.assert_array_equal(
         pf["layers"]["attn"]["wqkv"],
         jnp.concatenate([a[w].reshape(2, 32, -1)
                          for w in ("wq", "wk", "wv")], -1))
     toks = jnp.asarray(_tokens(10, 9) % 64)[None]
-    np.testing.assert_allclose(tfm.apply(pf, toks, fused)[0],
-                               tfm.apply(ps, toks, split)[0], atol=1e-5)
+    np.testing.assert_allclose(jtfm.apply(pf, toks, fused)[0],
+                               jtfm.apply(ps, toks, split)[0], atol=1e-5)
 
 
 @pytest.mark.parametrize("fsdp", [False, True])
@@ -429,7 +435,7 @@ def test_extended_block_trains_through_lm_trainer(devices, fsdp):
     t = dk.LMTrainer(cfg, learning_rate=1e-2, batch_size=16, num_epoch=8,
                      mesh=mesh, fsdp=fsdp)
     data = np.random.default_rng(0).integers(0, 64, (64, 17)).astype(np.int32)
-    p0 = tfm.init_params(jax.random.key(0), cfg)
+    p0 = toy_params(cfg)
     p = t.train(dk.Dataset({"tokens": data}))
     assert t.history[-1] < t.history[0] * 0.9, t.history[::8]
     assert set(p) == set(p0) and p["head"].shape == (64, 32)
@@ -446,10 +452,11 @@ def test_gated_unlooped_block_trains_through_lm_loss():
     ``block_apply`` with the trunk: its loss is the cross-entropy of
     its own logits and has gradients for the new leaves."""
     cfg = dataclasses.replace(CFG, n_passes=1)
-    p = tfm.init_params(jax.random.key(0), cfg)
+    p = toy_params(cfg)
     toks = jnp.asarray(_tokens(17))[None]
-    loss, grads = jax.value_and_grad(tfm.lm_loss)(p, toks, cfg)
-    logits, _ = tfm.apply(p, toks[:, :-1], cfg)
+    loss, grads = jax.jit(jax.value_and_grad(tfm.lm_loss),
+                          static_argnums=2)(p, toks, cfg)
+    logits, _ = jtfm.apply(p, toks[:, :-1], cfg)
     logp = jax.nn.log_softmax(logits, -1)
     want = -jnp.take_along_axis(logp, toks[:, 1:, None], -1).mean()
     np.testing.assert_allclose(loss, want, rtol=1e-6)

@@ -16,6 +16,7 @@ from distkeras_tpu.models.lora import (
     lora_mask,
     lora_merge,
 )
+from helpers import toy_params
 
 
 CFG = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
@@ -29,7 +30,7 @@ def _rows(rng, n=64):
 def test_zero_init_merge_is_identity(rng):
     """B = 0 at init: the merged tree equals the base exactly, so step
     0 of a finetune reproduces the pretrained model."""
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     lcfg = LoRAConfig(rank=4, targets=("wq", "wk", "wv", "wo",
                                        "w1", "w2"))
     adapters = lora_init(jax.random.key(1), CFG, lcfg)
@@ -39,7 +40,7 @@ def test_zero_init_merge_is_identity(rng):
 
 
 def test_merge_matches_manual_delta(rng):
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     lcfg = LoRAConfig(rank=3, alpha=6.0, targets=("wq",))
     adapters = lora_init(jax.random.key(1), CFG, lcfg)
     a = np.asarray(rng.normal(size=adapters["attn"]["wq"]["a"].shape),
@@ -73,7 +74,7 @@ def test_validation():
 
 
 def test_finetune_trains_adapters_and_freezes_base(rng):
-    base = tfm.init_params(jax.random.key(0), CFG)
+    base = toy_params(CFG)
     base_copy = jax.tree.map(lambda x: np.asarray(x).copy(), base)
     rows = _rows(rng)
     tr = dk.LoRATrainer(CFG, base, lora_rank=4, learning_rate=5e-2,
@@ -103,7 +104,7 @@ def test_finetune_trains_adapters_and_freezes_base(rng):
 def test_optimizer_state_excludes_base(rng):
     """The LoRA memory win: masked optimizer moments exist for the
     adapter leaves only (no [V, D] / [L, D, F] moment buffers)."""
-    base = tfm.init_params(jax.random.key(0), CFG)
+    base = toy_params(CFG)
     tr = dk.LoRATrainer(CFG, base, lora_rank=4, learning_rate=1e-2,
                         batch_size=16)
     packed = tr.init_params()
@@ -120,10 +121,10 @@ def test_optimizer_state_excludes_base(rng):
 
 def test_merged_model_serves(rng):
     """The finetuned artifact drops into generate + quantize + save."""
-    from distkeras_tpu.models.generate import generate
+    from helpers import generate
     from distkeras_tpu.models.quant import quantize_params
 
-    base = tfm.init_params(jax.random.key(0), CFG)
+    base = toy_params(CFG)
     rows = _rows(rng, 32)
     tr = dk.LoRATrainer(CFG, base, lora_rank=2, learning_rate=1e-2,
                         batch_size=16, num_epoch=1)
@@ -140,7 +141,7 @@ def test_lora_composes_with_tp_mesh_and_segments(devices, rng):
     from distkeras_tpu.parallel.mesh import MeshSpec, make_mesh
 
     cfg = dataclasses.replace(CFG, rope=True)
-    base = tfm.init_params(jax.random.key(0), cfg)
+    base = toy_params(cfg)
     docs = [rng.integers(1, 64, (int(n),)).tolist()
             for n in rng.integers(5, 30, 40)]
     rows, segs = dk.pack_documents(docs, seq_len=16)
@@ -156,7 +157,7 @@ def test_lora_composes_with_tp_mesh_and_segments(devices, rng):
 
 
 def test_lora_checkpoint_resume_matches_straight(tmp_path, rng):
-    base = tfm.init_params(jax.random.key(0), CFG)
+    base = toy_params(CFG)
     rows = _rows(rng)
     common = dict(lora_rank=4, learning_rate=1e-2, batch_size=16)
     d = str(tmp_path / "ck")
@@ -176,14 +177,14 @@ def test_lora_checkpoint_resume_matches_straight(tmp_path, rng):
 def test_lora_mask_shape():
     lcfg = LoRAConfig(rank=2)
     adapters = lora_init(jax.random.key(0), CFG, lcfg)
-    base = tfm.init_params(jax.random.key(0), CFG)
+    base = toy_params(CFG)
     mask = lora_mask((adapters, base))
     assert all(jax.tree.leaves(mask[0]))
     assert not any(jax.tree.leaves(mask[1]))
 
 
 def test_train_rejects_params_argument(rng):
-    base = tfm.init_params(jax.random.key(0), CFG)
+    base = toy_params(CFG)
     tr = dk.LoRATrainer(CFG, base, batch_size=16)
     with pytest.raises(ValueError, match="base_params"):
         tr.train(_rows(rng), params=base)
@@ -195,11 +196,11 @@ def test_lora_merged_serves_speculatively(rng):
     """The full adapt-and-deploy composition: LoRA-finetuned merged
     tree serves via speculative decoding with its own int8 copy as the
     draft, matching generate's greedy rollout exactly."""
-    from distkeras_tpu.models.generate import generate
+    from helpers import generate
     from distkeras_tpu.models.quant import quantize_params
     from distkeras_tpu.models.speculative import speculative_generate
 
-    base = tfm.init_params(jax.random.key(0), CFG)
+    base = toy_params(CFG)
     rows = _rows(rng)
     tr = dk.LoRATrainer(CFG, base, lora_rank=4, learning_rate=3e-2,
                         batch_size=16, num_epoch=2)
@@ -218,7 +219,7 @@ def test_lora_grad_accum_matches_large_batch(rng):
     gradients of the adapters equals one large-batch step (the
     masked-optimizer path composes with make_train_step's accum loop).
     """
-    base = tfm.init_params(jax.random.key(0), CFG)
+    base = toy_params(CFG)
     rows = _rows(rng, 32)
     big = dk.LoRATrainer(CFG, base, lora_rank=4, learning_rate=1e-2,
                          batch_size=32, num_epoch=1)

@@ -14,15 +14,14 @@ import pytest
 
 import distkeras_tpu as dk
 from distkeras_tpu import obs
-from distkeras_tpu.models import transformer as tfm
 from distkeras_tpu.obs.metrics import (MetricsRegistry,
                                         percentile_from_buckets)
 from distkeras_tpu.obs.report import (build_report, load_report,
                                        render_compare, render_report)
 from distkeras_tpu.obs.trace import EventTrace, read_trace
+from helpers import serve_cfg, spec_draft_cfg, toy_params
 
-CFG = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
-                            n_layers=2, d_ff=64, max_len=16)
+CFG = serve_cfg(max_len=16, rope=False)
 
 
 def tokens(n=32, s=16, seed=0):
@@ -195,7 +194,7 @@ def test_disabled_serving_round_is_free(tmp_path, monkeypatch):
 
     monkeypatch.setattr(_LaneEngine, "_close_round", never)
     monkeypatch.chdir(tmp_path)
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     eng = dk.ContinuousBatcher(params, CFG, lanes=2, max_queue=2,
                                prompt_buckets=(8,))
     rid = eng.enqueue(np.arange(5), 3)
@@ -275,9 +274,8 @@ def test_trainer_end_to_end_trace(tmp_path):
 
 
 def test_serving_end_to_end_trace_and_compare(tmp_path):
-    cfg = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
-                                n_layers=2, d_ff=64, max_len=32)
-    params = tfm.init_params(jax.random.key(0), cfg)
+    cfg = serve_cfg(rope=False)
+    params = toy_params(cfg)
     rng = np.random.default_rng(0)
 
     def serve(path, n_requests):
@@ -307,9 +305,8 @@ def test_serving_end_to_end_trace_and_compare(tmp_path):
 
 
 def test_serving_rejects_and_deadline_metrics(tmp_path):
-    cfg = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
-                                n_layers=2, d_ff=64, max_len=32)
-    params = tfm.init_params(jax.random.key(0), cfg)
+    cfg = serve_cfg(rope=False)
+    params = toy_params(cfg)
     rng = np.random.default_rng(0)
     clock = [0.0]
     with obs.session() as sess:
@@ -369,13 +366,11 @@ def test_chaos_and_supervisor_events_in_trace(tmp_path):
 
 
 def test_speculative_accept_rate_counters():
-    cfg = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
-                                n_layers=2, d_ff=64, max_len=32)
-    draft = tfm.TransformerConfig(vocab_size=64, d_model=16, n_heads=2,
-                                  n_layers=1, d_ff=32, max_len=32)
+    cfg = serve_cfg(rope=False)
+    draft = spec_draft_cfg(rope=False)
     eng = dk.SpeculativeBatcher(
-        tfm.init_params(jax.random.key(0), cfg),
-        tfm.init_params(jax.random.key(1), draft),
+        toy_params(cfg),
+        toy_params(draft, 1),
         cfg, draft, lanes=2, n_draft=2)
     prompt = np.random.default_rng(0).integers(0, 64, (4,)).astype(
         np.int32)
@@ -417,8 +412,7 @@ def test_zero1_bucket_geometry_recorded():
     # Exchange bytes == parameter bytes (the pad-free parity layout).
     pbytes = sum(np.prod(v.shape) * v.dtype.itemsize
                  for v in jax.tree.leaves(
-                     jax.eval_shape(lambda: tfm.init_params(
-                         jax.random.key(0), CFG))))
+                     jax.eval_shape(lambda: toy_params(CFG))))
     assert snap["zero1.exchange_bytes"] == pbytes
 
 

@@ -21,7 +21,6 @@ import pytest
 
 import distkeras_tpu as dk
 from distkeras_tpu import obs
-from distkeras_tpu.models import transformer as tfm
 from distkeras_tpu.obs.live import (HeartbeatHealth, TelemetryServer,
                                     merge_expositions)
 from distkeras_tpu.obs.metrics import (MetricsRegistry, prom_name,
@@ -30,11 +29,11 @@ from distkeras_tpu.obs.report import render_waterfall, request_waterfall
 from distkeras_tpu.obs.slo import SloEngine, SloRule
 from distkeras_tpu.obs.trace import EventTrace, read_trace, tail_trace
 from distkeras_tpu.resilience.health import write_beat
+from helpers import serve_cfg, spec_draft_cfg, toy_params
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-CFG = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
-                            n_layers=2, d_ff=64, max_len=32, rope=True)
+CFG = serve_cfg()
 
 
 def _get(url, timeout=10):
@@ -503,13 +502,12 @@ def test_live_plane_end_to_end_engine_healthz_slo_waterfall(tmp_path):
     (slo.breach event + subscriber callback), and
     `obs_report.py --request` renders the request's
     submit -> admit -> chunks -> decode waterfall from the trace."""
-    import jax
 
     path = str(tmp_path / "serve.jsonl")
     hb = str(tmp_path / "hb")
     clk = [0.0]
     hclk = [1000.0]
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     rng = np.random.default_rng(0)
     health = HeartbeatHealth(hb, host=0, window=2.0,
                              clock=lambda: hclk[0])
@@ -603,17 +601,14 @@ def test_live_plane_end_to_end_engine_healthz_slo_waterfall(tmp_path):
 def test_request_waterfall_speculative_and_unknown_id(tmp_path):
     """Per-request propagation covers the speculative engine too, and
     an unknown id reports found=False."""
-    import jax
 
-    cfg = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
-                                n_layers=2, d_ff=64, max_len=32)
-    draft = tfm.TransformerConfig(vocab_size=64, d_model=16, n_heads=2,
-                                  n_layers=1, d_ff=32, max_len=32)
+    cfg = serve_cfg(rope=False)
+    draft = spec_draft_cfg(rope=False)
     path = str(tmp_path / "spec.jsonl")
     with obs.session(trace_path=path):
         eng = dk.SpeculativeBatcher(
-            tfm.init_params(jax.random.key(0), cfg),
-            tfm.init_params(jax.random.key(1), draft),
+            toy_params(cfg),
+            toy_params(draft, 1),
             cfg, draft, lanes=2, n_draft=2, max_queue=2)
         rid = eng.enqueue(np.arange(4, dtype=np.int32), 6)
         while eng.poll(rid) is None:

@@ -16,6 +16,7 @@ from distkeras_tpu.ops.attention import (
     flash_attention,
     naive_attention,
 )
+from helpers import jtfm, toy_params
 
 
 # ---------------------------------------------------------------- packing
@@ -170,16 +171,16 @@ CFG = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
 def test_packed_forward_equals_separate_docs(rng):
     """rope + segments: the packed logits for each document equal the
     document run alone (relative positions survive the shift)."""
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     d1 = rng.integers(1, 64, (1, 11)).astype(np.int32)
     d2 = rng.integers(1, 64, (1, 13)).astype(np.int32)
     row = np.concatenate([d1, d2], axis=1)
     seg = np.concatenate([np.full((1, 11), 1), np.full((1, 13), 2)],
                          axis=1).astype(np.int32)
-    packed, _ = tfm.apply(params, jnp.asarray(row), CFG,
+    packed, _ = jtfm.apply(params, jnp.asarray(row), CFG,
                           segment_ids=jnp.asarray(seg))
     for doc, lo, hi in ((d1, 0, 11), (d2, 11, 24)):
-        alone, _ = tfm.apply(params, jnp.asarray(doc), CFG)
+        alone, _ = jtfm.apply(params, jnp.asarray(doc), CFG)
         np.testing.assert_allclose(np.asarray(packed[:, lo:hi]),
                                    np.asarray(alone), atol=2e-4, rtol=2e-4)
 
@@ -187,39 +188,39 @@ def test_packed_forward_equals_separate_docs(rng):
 def test_packed_loss_equals_weighted_separate_losses(rng):
     """Masked packed NLL == target-count-weighted mean of per-document
     NLLs (boundary and pad targets excluded)."""
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     d1 = rng.integers(1, 64, (1, 11)).astype(np.int32)
     d2 = rng.integers(1, 64, (1, 9)).astype(np.int32)
     row = np.zeros((1, 25), np.int32)
     row[:, :11], row[:, 11:20] = d1, d2
     seg = np.zeros((1, 25), np.int32)
     seg[:, :11], seg[:, 11:20] = 1, 2
-    packed = float(tfm.lm_nll(params, jnp.asarray(row), CFG,
+    packed = float(jtfm.lm_nll(params, jnp.asarray(row), CFG,
                               segment_ids=jnp.asarray(seg)))
-    nll1 = float(tfm.lm_nll(params, jnp.asarray(d1), CFG))
-    nll2 = float(tfm.lm_nll(params, jnp.asarray(d2), CFG))
+    nll1 = float(jtfm.lm_nll(params, jnp.asarray(d1), CFG))
+    nll2 = float(jtfm.lm_nll(params, jnp.asarray(d2), CFG))
     want = (10 * nll1 + 8 * nll2) / 18
     np.testing.assert_allclose(packed, want, rtol=1e-5)
 
 
 def test_packed_loss_chunked_ce_matches_full(rng):
     cfg = dataclasses.replace(CFG, ce_chunks=4)
-    params = tfm.init_params(jax.random.key(1), CFG)
+    params = toy_params(CFG, 1)
     row = rng.integers(1, 64, (2, 25)).astype(np.int32)
     seg = np.asarray(_segs(2, 25, splits=(9, 17)))
-    full = float(tfm.lm_nll(params, jnp.asarray(row), CFG,
+    full = float(jtfm.lm_nll(params, jnp.asarray(row), CFG,
                             segment_ids=jnp.asarray(seg)))
-    chunked = float(tfm.lm_nll(params, jnp.asarray(row), cfg,
+    chunked = float(jtfm.lm_nll(params, jnp.asarray(row), cfg,
                                segment_ids=jnp.asarray(seg)))
     np.testing.assert_allclose(chunked, full, rtol=1e-5)
 
 
 def test_segments_with_custom_attention_fn_rejected(rng):
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     row = rng.integers(1, 64, (1, 8)).astype(np.int32)
     seg = np.ones((1, 8), np.int32)
     with pytest.raises(ValueError, match="custom attention_fn"):
-        tfm.apply(params, jnp.asarray(row), CFG,
+        jtfm.apply(params, jnp.asarray(row), CFG,
                   attention_fn=lambda q, k, v: q,
                   segment_ids=jnp.asarray(seg))
 
@@ -256,9 +257,9 @@ def test_packed_eval_weighted_by_valid_counts(rng):
                       eval_tokens=rows, eval_segments=segs)
     got = tr.eval_history[-1][1]["loss"]
 
-    n1 = float(tfm.lm_nll(params, jnp.asarray(rows[:8]), cfg,
+    n1 = float(jtfm.lm_nll(params, jnp.asarray(rows[:8]), cfg,
                           segment_ids=jnp.asarray(segs[:8])))
-    n2 = float(tfm.lm_nll(params, jnp.asarray(rows[8:]), cfg,
+    n2 = float(jtfm.lm_nll(params, jnp.asarray(rows[8:]), cfg,
                           segment_ids=jnp.asarray(segs[8:])))
     w1, w2 = 8 * 16, 8 * 4  # valid targets per chunk
     np.testing.assert_allclose(got, (w1 * n1 + w2 * n2) / (w1 + w2),
@@ -296,13 +297,13 @@ def test_packed_forward_ring_mesh_matches_default(devices, rng):
 
     mesh = make_mesh(MeshSpec(data=2, seq=4), devices=devices)
     cfg = dataclasses.replace(CFG, max_len=33)
-    params = tfm.init_params(jax.random.key(2), cfg)
+    params = toy_params(cfg, 2)
     row = rng.integers(1, 64, (2, 32)).astype(np.int32)
     seg = np.asarray(_segs(2, 32, splits=(11, 21)))
-    ref, _ = tfm.apply(params, jnp.asarray(row), cfg,
+    ref, _ = jtfm.apply(params, jnp.asarray(row), cfg,
                        segment_ids=jnp.asarray(seg))
     ring = make_ring_attention(mesh, causal=True)
-    out, _ = tfm.apply(params, jnp.asarray(row), cfg, attention_fn=ring,
+    out, _ = jtfm.apply(params, jnp.asarray(row), cfg, attention_fn=ring,
                        segment_ids=jnp.asarray(seg))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-4, rtol=2e-4)
@@ -335,10 +336,10 @@ def test_packed_forward_pipeline_matches_default(devices, rng, with_seq):
             else MeshSpec(data=4, pipeline=2))
     mesh = make_mesh(spec, devices=devices)
     cfg = dataclasses.replace(CFG, max_len=33)
-    params = tfm.init_params(jax.random.key(3), cfg)
+    params = toy_params(cfg, 3)
     rows = rng.integers(1, 64, (4, 32)).astype(np.int32)
     seg = np.asarray(_segs(4, 32, splits=(9, 23)))
-    ref, _ = tfm.apply(params, jnp.asarray(rows), cfg,
+    ref, _ = jtfm.apply(params, jnp.asarray(rows), cfg,
                        segment_ids=jnp.asarray(seg))
     out, _ = jax.jit(lambda p, t, s: tfm.apply_pipelined(
         p, t, cfg, mesh, microbatches=2,
@@ -371,18 +372,18 @@ def test_remat_composes_with_segments(rng):
     attention_fn slot — loss and grads must match the no-remat run."""
     cfg = dataclasses.replace(CFG, max_len=33, remat=True)
     plain = dataclasses.replace(cfg, remat=False)
-    params = tfm.init_params(jax.random.key(4), cfg)
+    params = toy_params(cfg, 4)
     rows = jnp.asarray(rng.integers(1, 64, (2, 20)), jnp.int32)
     seg = jnp.asarray(np.asarray(_segs(2, 20, splits=(7, 13))))
-    ref = float(tfm.lm_nll(params, rows, plain, segment_ids=seg))
-    out = float(jax.jit(lambda p, t, s: tfm.lm_nll(p, t, cfg,
+    ref = float(jtfm.lm_nll(params, rows, plain, segment_ids=seg))
+    out = float(jax.jit(lambda p, t, s: jtfm.lm_nll(p, t, cfg,
                                                    segment_ids=s))(
         params, rows, seg))
     np.testing.assert_allclose(out, ref, rtol=1e-5)
-    g = jax.jit(jax.grad(lambda p: tfm.lm_nll(p, rows, cfg,
-                                              segment_ids=seg)))(params)
-    gr = jax.grad(lambda p: tfm.lm_nll(p, rows, plain,
-                                       segment_ids=seg))(params)
+    g = jax.jit(jax.grad(lambda p: jtfm.lm_nll(p, rows, cfg,
+                                               segment_ids=seg)))(params)
+    gr = jax.jit(jax.grad(lambda p: jtfm.lm_nll(p, rows, plain,
+                                                segment_ids=seg)))(params)
     for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(gr)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=2e-4, rtol=2e-4)

@@ -10,6 +10,7 @@ import pytest
 from distkeras_tpu.models import transformer as tfm
 from distkeras_tpu.parallel.mesh import MeshSpec, make_mesh
 from distkeras_tpu.parallel.pipeline import make_pipeline
+from helpers import toy_params
 
 
 def test_pipeline_matches_sequential(devices, rng):
@@ -68,7 +69,7 @@ def test_pipelined_transformer_matches_single(devices, rng):
     cfg = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
                                 n_layers=4, d_ff=64, max_len=32)
     mesh = make_mesh(MeshSpec(data=2, pipeline=4), devices=devices)
-    params = tfm.init_params(jax.random.key(0), cfg)
+    params = toy_params(cfg)
     t = jnp.asarray(rng.integers(0, 64, (8, 16)).astype(np.int32))
     ref, _ = tfm.apply(params, t, cfg)
     out, _ = jax.jit(
@@ -81,7 +82,7 @@ def test_pipelined_transformer_trains(devices, rng):
     cfg = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
                                 n_layers=2, d_ff=64, max_len=32)
     mesh = make_mesh(MeshSpec(data=2, pipeline=2), devices=devices[:4])
-    params = tfm.init_params(jax.random.key(0), cfg)
+    params = toy_params(cfg)
     opt = optax.adam(1e-2)
     step = jax.jit(tfm.make_train_step(
         cfg, opt, apply_fn=lambda p, t: tfm.apply_pipelined(
@@ -102,7 +103,7 @@ def test_pipelined_moe_aux_flows_into_loss(devices, rng):
                                 n_layers=2, d_ff=64, max_len=32,
                                 num_experts=2, capacity_factor=2.0)
     mesh = make_mesh(MeshSpec(data=2, pipeline=2, expert=2), devices=devices)
-    params = tfm.init_params(jax.random.key(0), cfg)
+    params = toy_params(cfg)
     t = jnp.asarray(rng.integers(0, 64, (8, 17)).astype(np.int32))
 
     apply_fn = lambda p, tk: tfm.apply_pipelined(p, tk, cfg, mesh,
@@ -129,7 +130,7 @@ def test_pipelined_ring_attention_matches_single(devices, rng):
     cfg = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
                                 n_layers=2, d_ff=64, max_len=32)
     mesh = make_mesh(MeshSpec(data=2, pipeline=2, seq=2), devices=devices)
-    params = tfm.init_params(jax.random.key(1), cfg)
+    params = toy_params(cfg, 1)
     t = jnp.asarray(rng.integers(0, 64, (8, 17)).astype(np.int32))
     apply_fn = lambda p, tk: tfm.apply_pipelined(
         p, tk, cfg, mesh, microbatches=2, seq_axis="seq")
@@ -154,7 +155,7 @@ def test_pipelined_moe_with_seq_axis_aux_consistent(devices, rng):
                                 num_experts=2, capacity_factor=4.0)
     mesh = make_mesh(MeshSpec(data=1, pipeline=2, seq=2, expert=2),
                      devices=devices)
-    params = tfm.init_params(jax.random.key(0), cfg)
+    params = toy_params(cfg)
     t = jnp.asarray(rng.integers(0, 64, (8, 17)).astype(np.int32))
     apply_fn = lambda p, tk: tfm.apply_pipelined(
         p, tk, cfg, mesh, microbatches=2, seq_axis="seq")

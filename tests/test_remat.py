@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from distkeras_tpu.models import transformer as tfm
+from helpers import jtfm, toy_params
 
 
 def test_transformer_remat_matches_plain(rng):
@@ -12,11 +13,13 @@ def test_transformer_remat_matches_plain(rng):
                 d_ff=64, max_len=32)
     cfg = tfm.TransformerConfig(**base)
     cfg_r = tfm.TransformerConfig(**base, remat=True)
-    params = tfm.init_params(jax.random.key(0), cfg)
+    params = toy_params(cfg)
     toks = jnp.asarray(rng.integers(0, 64, (4, 17)).astype(np.int32))
 
-    l1, g1 = jax.value_and_grad(tfm.lm_loss)(params, toks, cfg)
-    l2, g2 = jax.value_and_grad(tfm.lm_loss)(params, toks, cfg_r)
+    l1, g1 = jax.jit(jax.value_and_grad(tfm.lm_loss),
+                        static_argnums=2)(params, toks, cfg)
+    l2, g2 = jax.jit(jax.value_and_grad(tfm.lm_loss),
+                        static_argnums=2)(params, toks, cfg_r)
     np.testing.assert_allclose(l1, l2, atol=1e-6, rtol=1e-6)
     for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):
         np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5)
@@ -31,7 +34,7 @@ def test_transformer_pipelined_remat(devices, rng):
     cfg = tfm.TransformerConfig(**base)
     cfg_r = tfm.TransformerConfig(**base, remat=True)
     mesh = make_mesh(MeshSpec(data=2, pipeline=2), devices=devices[:4])
-    params = tfm.init_params(jax.random.key(0), cfg)
+    params = toy_params(cfg)
     toks = jnp.asarray(rng.integers(0, 64, (4, 16)).astype(np.int32))
     ref, _ = jax.jit(lambda p, t: tfm.apply_pipelined(p, t, cfg, mesh, 2))(
         params, toks)
@@ -49,14 +52,16 @@ def test_remat_policy_matches_plain_remat(rng):
 
     base = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
                                  n_layers=2, d_ff=64, max_len=32)
-    params = tfm.init_params(jax.random.key(0), base)
+    params = toy_params(base)
     t = jnp.asarray(rng.integers(0, 64, (4, 16)), jnp.int32)
-    ref_l, ref_g = jax.value_and_grad(tfm.lm_loss)(params, t, base)
+    ref_l, ref_g = jax.jit(jax.value_and_grad(tfm.lm_loss),
+                        static_argnums=2)(params, t, base)
     for kw in ({"remat": True},
                {"remat": True, "remat_policy": "dots"},
                {"remat": True, "remat_policy": "dots_no_batch"}):
         cfg = dataclasses.replace(base, **kw)
-        l, g = jax.value_and_grad(tfm.lm_loss)(params, t, cfg)
+        l, g = jax.jit(jax.value_and_grad(tfm.lm_loss),
+                        static_argnums=2)(params, t, cfg)
         np.testing.assert_allclose(float(l), float(ref_l), rtol=1e-6,
                                    err_msg=str(kw))
         jax.tree.map(lambda a, b: np.testing.assert_allclose(
@@ -92,8 +97,8 @@ def test_remat_policy_inert_when_remat_disabled_post_init(rng):
     train_cfg = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
                                       n_layers=1, d_ff=64, max_len=16,
                                       remat=True, remat_policy="dots")
-    params = tfm.init_params(jax.random.key(0), train_cfg)
+    params = toy_params(train_cfg)
     eval_cfg = dataclasses.replace(train_cfg, remat=False)
     t = jnp.asarray(rng.integers(0, 64, (2, 8)), jnp.int32)
-    logits, _ = tfm.apply(params, t, eval_cfg)  # must not raise
+    logits, _ = jtfm.apply(params, t, eval_cfg)  # must not raise
     assert logits.shape == (2, 8, 64)

@@ -16,8 +16,7 @@ import pytest
 
 import distkeras_tpu as dk
 from distkeras_tpu.checkpoint import CheckpointManager
-from distkeras_tpu.models import transformer as tfm
-from distkeras_tpu.models.generate import generate
+from helpers import generate, serve_cfg, spec_draft_cfg, toy_params
 from distkeras_tpu.resilience import (EngineClosed, FaultInjected,
                                       FaultPlan, Preempted, QueueFull,
                                       Supervisor, chaos)
@@ -25,10 +24,8 @@ from distkeras_tpu.serving import ContinuousBatcher, SpeculativeBatcher
 
 from conftest import make_blobs, make_mlp
 
-CFG = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
-                            n_layers=2, d_ff=64, max_len=32)
-DRAFT = tfm.TransformerConfig(vocab_size=64, d_model=16, n_heads=2,
-                              n_layers=1, d_ff=32, max_len=32)
+CFG = serve_cfg(rope=False)
+DRAFT = spec_draft_cfg(rope=False)
 
 COMMON = dict(loss="sparse_categorical_crossentropy",
               worker_optimizer="sgd", learning_rate=0.05,
@@ -37,7 +34,7 @@ COMMON = dict(loss="sparse_categorical_crossentropy",
 
 @pytest.fixture(scope="module")
 def params():
-    return tfm.init_params(jax.random.key(0), CFG)
+    return toy_params(CFG)
 
 
 @pytest.fixture()
@@ -288,8 +285,7 @@ def test_supervisor_wraps_lm_trainer(tmp_path):
     kw = dict(optimizer="sgd", learning_rate=0.05, batch_size=8,
               num_epoch=1, seed=3)
 
-    cfg = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
-                                n_layers=2, d_ff=64, max_len=16)
+    cfg = serve_cfg(max_len=16, rope=False)
     straight = dk.LMTrainer(cfg, **kw)
     ref_params = straight.train(rows)
 
@@ -461,8 +457,8 @@ def test_draft_fault_falls_back_and_completes_greedy_parity(rng):
     """Acceptance: a faulting draft model must not kill requests — the
     engine degrades to the plain decode path mid-flight and greedy
     outputs still match solo generate exactly."""
-    tp = tfm.init_params(jax.random.key(0), CFG)
-    dp = tfm.init_params(jax.random.key(9), DRAFT)
+    tp = toy_params(CFG)
+    dp = toy_params(DRAFT, 9)
     pa = rng.integers(0, 64, (5,)).astype(np.int32)
     pb = rng.integers(0, 64, (3,)).astype(np.int32)
     eng = SpeculativeBatcher(tp, dp, CFG, DRAFT, lanes=2, n_draft=3)
@@ -646,8 +642,8 @@ def test_elastic_resize_preserves_inflight_requests(params, rng):
 
 
 def test_speculative_deadline_and_queue(rng, fake_clock):
-    tp = tfm.init_params(jax.random.key(0), CFG)
-    dp = tfm.init_params(jax.random.key(9), DRAFT)
+    tp = toy_params(CFG)
+    dp = toy_params(DRAFT, 9)
     eng = SpeculativeBatcher(tp, dp, CFG, DRAFT, lanes=1, n_draft=2,
                              max_queue=1, clock=fake_clock)
     p = rng.integers(0, 64, (3,)).astype(np.int32)

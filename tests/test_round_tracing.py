@@ -20,6 +20,7 @@ from distkeras_tpu import obs
 from distkeras_tpu.models import transformer as tfm
 from distkeras_tpu.obs import read_trace
 from distkeras_tpu.parallel.mesh import MeshSpec, make_mesh
+from helpers import toy_params
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CFG = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
@@ -34,7 +35,7 @@ def rounds(tmp_path_factory):
     chunks of 8) is admitted and prefilled between B's decode steps.
     Returns the ``serving.round`` spans in order and every span."""
     path = str(tmp_path_factory.mktemp("rounds") / "t.jsonl")
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     with obs.session(trace_path=path):
         eng = dk.ContinuousBatcher(params, CFG, lanes=2, max_queue=4,
                                    prefill_chunk=8, prompt_buckets=(8,))
@@ -150,7 +151,7 @@ def test_attended_is_the_chunks_end_on_the_bounded_path(tmp_path,
     path = str(tmp_path / "t.jsonl")
     with obs.session(trace_path=path):
         eng = dk.ContinuousBatcher(
-            tfm.init_params(jax.random.key(0), cfg), cfg, lanes=2,
+            toy_params(cfg), cfg, lanes=2,
             max_queue=4, prefill_chunk=8, prompt_buckets=(8,))
         eng.enqueue(np.arange(21), 2)
         for _ in range(3):
@@ -193,7 +194,7 @@ def test_step_attended_follows_the_lanes_on_the_bounded_path(tmp_path,
     cfg = tfm.TransformerConfig(vocab_size=64, d_model=256, n_heads=2,
                                 n_kv_heads=1, n_layers=1, d_ff=64,
                                 max_len=1024)
-    params = tfm.init_params(jax.random.key(0), cfg)
+    params = toy_params(cfg)
 
     def run(path):
         with obs.session(trace_path=path):
@@ -232,7 +233,7 @@ def _pattern(metric):
 def engine_programs():
     """The decode-step and the admission program of a hot-swap engine
     (the benchmark's), lowered: ``{"decode_step" | "admit": text}``."""
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     eng = dk.ContinuousBatcher(params, CFG, lanes=2, hot_swap=True,
                                prefill_chunk=8, prompt_buckets=(8,))
     out = {}
@@ -285,7 +286,7 @@ LOOPED = tfm.TransformerConfig(
 @pytest.fixture(scope="module")
 def looped_programs():
     """The same two programs of a LOOPED hot-swap engine."""
-    params = tfm.init_params(jax.random.key(0), LOOPED)
+    params = toy_params(LOOPED)
     eng = dk.ContinuousBatcher(params, LOOPED, lanes=2, hot_swap=True,
                                prefill_chunk=8, prompt_buckets=(8,))
     out = {}
@@ -338,7 +339,7 @@ def train_step_text(request, devices):
         sharding = NamedSharding(mesh, P("data"))
     opt = optax.sgd(1e-2)
     params = jax.eval_shape(
-        lambda: tfm.init_params(jax.random.key(0), TRAIN_CFG))
+        lambda: toy_params(TRAIN_CFG))
     state = (params, jax.eval_shape(opt.init, params))
     rows = jax.ShapeDtypeStruct((4, 257), jnp.int32, sharding=sharding)
     mp = pytest.MonkeyPatch()

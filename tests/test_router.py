@@ -26,6 +26,7 @@ from distkeras_tpu.serving import (EngineClosed, EngineEndpoint,
                                    HttpReplica, InProcessReplica,
                                    PagedBatcher, QueueFull, Router)
 from distkeras_tpu.serving.residency import stem_hexes
+from helpers import serve_cfg, toy_params
 
 
 # ------------------------------------------------------- fake replicas
@@ -437,19 +438,15 @@ def test_expired_on_arrival_never_routes(rng):
 
 # ------------------------------------------- integration: real engines
 
-CFG_KW = dict(vocab_size=64, d_model=32, n_heads=2, n_layers=2,
-              d_ff=64, max_len=32, rope=True)
 BLOCK = 8
 
 
 @pytest.fixture(scope="module")
 def engine_params():
-    import jax
 
-    from distkeras_tpu.models import transformer as tfm
 
-    cfg = tfm.TransformerConfig(**CFG_KW)
-    return tfm.init_params(jax.random.key(0), cfg), cfg
+    cfg = serve_cfg()
+    return toy_params(cfg), cfg
 
 
 def _paged(params, cfg, **kw):
@@ -460,7 +457,7 @@ def _paged(params, cfg, **kw):
 
 
 def test_two_engine_affinity_and_parity(engine_params, rng):
-    from distkeras_tpu.models.generate import generate
+    from helpers import generate
 
     params, cfg = engine_params
     engines = [_paged(params, cfg) for _ in range(2)]
@@ -482,7 +479,7 @@ def test_two_engine_affinity_and_parity(engine_params, rng):
 
 def test_drain_midstream_keeps_parity_and_waterfall(engine_params,
                                                     rng, tmp_path):
-    from distkeras_tpu.models.generate import generate
+    from helpers import generate
 
     params, cfg = engine_params
     trace = str(tmp_path / "router.jsonl")

@@ -4,6 +4,7 @@ import numpy as np
 
 from distkeras_tpu import serialize_keras_model, deserialize_keras_model
 from distkeras_tpu.utils.misc import to_dense_vector, uniform_weights
+from helpers import toy_params
 
 
 def test_round_trip(mlp):
@@ -46,7 +47,7 @@ def test_save_load_lm_round_trip(tmp_path, rng):
                                 n_layers=2, d_ff=64, max_len=24,
                                 rope=True, n_kv_heads=1, remat=True,
                                 remat_policy="dots", ce_chunks=2)
-    params = tfm.init_params(jax.random.key(0), cfg)
+    params = toy_params(cfg)
     path = str(tmp_path / "lm.npz")
     dk.save_lm(path, params, cfg)
     loaded, cfg2 = dk.load_lm(path)
@@ -71,7 +72,7 @@ def test_save_lm_rejects_quantized(tmp_path):
 
     cfg = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
                                 n_layers=1, d_ff=64, max_len=16)
-    qp = quantize_params(tfm.init_params(jax.random.key(0), cfg))
+    qp = quantize_params(toy_params(cfg))
     with pytest.raises(ValueError, match="full-precision"):
         dk.save_lm(str(tmp_path / "q.npz"), qp, cfg)
 
@@ -87,7 +88,7 @@ def test_load_lm_decodes_eagerly_without_jit(tmp_path, rng):
 
     cfg = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
                                 n_layers=1, d_ff=64, max_len=24)
-    params = tfm.init_params(jax.random.key(1), cfg)
+    params = toy_params(cfg, 1)
     path = str(tmp_path / "lm.npz")
     dk.save_lm(path, params, cfg)
     loaded, cfg2 = dk.load_lm(path)

@@ -5,18 +5,16 @@ import jax
 import numpy as np
 import pytest
 
-from distkeras_tpu.models import transformer as tfm
-from distkeras_tpu.models.generate import generate
+from helpers import generate, jgen, serve_cfg, spec_draft_cfg, toy_params
 from distkeras_tpu.serving import ContinuousBatcher
 
 
-CFG = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
-                            n_layers=2, d_ff=64, max_len=32, rope=True)
+CFG = serve_cfg()
 
 
 @pytest.fixture(scope="module")
 def params():
-    return tfm.init_params(jax.random.key(0), CFG)
+    return toy_params(CFG)
 
 
 def run_to_done(eng, lane):
@@ -118,10 +116,8 @@ def test_engine_shared_prefix_matches_generate_prompt_cache(params, rng):
     """An engine built over a shared prefilled prefix emits exactly
     what generate(prompt_cache=...) emits per request — including for a
     lane's SECOND occupant (the admission reseed from the prefix)."""
-    from distkeras_tpu.models.generate import prefill
-
     prefix = rng.integers(0, 64, (6,)).astype(np.int32)
-    cache, _ = prefill(params, prefix[None], CFG, last_logits=False)
+    cache, _ = jgen.prefill(params, prefix[None], CFG, last_logits=False)
     eng = ContinuousBatcher(params, CFG, lanes=1,
                             prompt_cache=(cache, 6))
     for tail_len in (3, 1):    # second pass reuses lane 0; tail_len 1
@@ -231,9 +227,7 @@ def test_lane_pos_clamped_and_idle_engine_skips_device(params, rng):
                                   solo(params, pc, 6))
 
 
-ROLL_CFG = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
-                                 n_layers=2, d_ff=64, max_len=12,
-                                 rope=True, attention_window=5)
+ROLL_CFG = serve_cfg(max_len=12, attention_window=5)
 
 
 def test_rolling_engine_matches_rolling_generate(params, rng):
@@ -241,7 +235,7 @@ def test_rolling_engine_matches_rolling_generate(params, rng):
     each request decodes past max_len on the ring cache and must match
     its solo rolling generate() run exactly — staggered admission,
     lane reuse, and lanes mid-wrap while a fresh lane is admitted."""
-    rparams = tfm.init_params(jax.random.key(3), ROLL_CFG)
+    rparams = toy_params(ROLL_CFG, 3)
 
     def rsolo(prompt, n, **kw):
         return np.asarray(generate(rparams, np.asarray(prompt)[None],
@@ -272,7 +266,7 @@ def test_rolling_engine_sampled_and_validation(params, rng):
     fit the admission buckets."""
     import dataclasses
 
-    rparams = tfm.init_params(jax.random.key(4), ROLL_CFG)
+    rparams = toy_params(ROLL_CFG, 4)
     eng = ContinuousBatcher(rparams, ROLL_CFG, lanes=2,
                             temperature=0.8, top_k=8)
     p = rng.integers(0, 64, (4,)).astype(np.int32)
@@ -285,7 +279,7 @@ def test_rolling_engine_sampled_and_validation(params, rng):
 
     norope = dataclasses.replace(ROLL_CFG, rope=False)
     with pytest.raises(ValueError, match="rolling lanes"):
-        ContinuousBatcher(tfm.init_params(jax.random.key(0), norope),
+        ContinuousBatcher(toy_params(norope),
                           norope, lanes=1)
     # Prompt must fit the ring (admission chunk cannot wrap).
     with pytest.raises(ValueError, match="admission"):
@@ -328,9 +322,7 @@ def test_kv_int8_engine_matches_sequential_generate(params, rng):
     # Windowed engines take kv_int8 too since round 5 — positive
     # coverage in test_kv_int8_rolling_engine_matches_rolling_generate.
     # Prefix quantization must match the engine cache.
-    from distkeras_tpu.models.generate import prefill
-
-    fp_cache, _ = prefill(params, pa[None], CFG, last_logits=False)
+    fp_cache, _ = jgen.prefill(params, pa[None], CFG, last_logits=False)
     with pytest.raises(ValueError, match="quantization must match"):
         ContinuousBatcher(params, CFG, lanes=1, kv_int8=True,
                           prompt_cache=(fp_cache, 6))
@@ -340,10 +332,8 @@ def test_kv_int8_engine_shared_prefix(params, rng):
     """A kv_int8 engine over a kv_int8-prefilled shared prefix matches
     generate(prompt_cache=..., kv_int8=True) per request, including
     the lane-reuse reseed."""
-    from distkeras_tpu.models.generate import prefill
-
     prefix = rng.integers(0, 64, (6,)).astype(np.int32)
-    cache, _ = prefill(params, prefix[None], CFG, last_logits=False,
+    cache, _ = jgen.prefill(params, prefix[None], CFG, last_logits=False,
                        kv_int8=True)
     eng = ContinuousBatcher(params, CFG, lanes=1, kv_int8=True,
                             prompt_cache=(cache, 6))
@@ -363,7 +353,7 @@ def test_kv_int8_rolling_engine_matches_rolling_generate(rng):
     the int8 ring cache and matches its solo sequential
     generate(kv_int8=True, use_prefill=False) run EXACTLY — admission
     chunk and decode loop both attend the already-quantized cache."""
-    rparams = tfm.init_params(jax.random.key(5), ROLL_CFG)
+    rparams = toy_params(ROLL_CFG, 5)
     eng = ContinuousBatcher(rparams, ROLL_CFG, lanes=2, kv_int8=True)
     assert eng.kv_int8 and "k_scale" in eng.cache
 
@@ -514,7 +504,7 @@ def test_per_request_sampling_on_rolling_lanes(rng):
     """per_request_sampling composes with rolling ring lanes: a greedy
     and a sampled request decode past max_len side by side, each
     matching its solo rolling generate() run."""
-    rparams = tfm.init_params(jax.random.key(6), ROLL_CFG)
+    rparams = toy_params(ROLL_CFG, 6)
     eng = ContinuousBatcher(rparams, ROLL_CFG, lanes=2,
                             per_request_sampling=True)
     pa = rng.integers(0, 64, (4,)).astype(np.int32)
@@ -541,10 +531,8 @@ def test_speculative_batcher_matches_solo(params, rng):
     from distkeras_tpu.models.speculative import speculative_generate
     from distkeras_tpu.serving import SpeculativeBatcher
 
-    draft_cfg = tfm.TransformerConfig(vocab_size=64, d_model=16,
-                                      n_heads=2, n_layers=1, d_ff=32,
-                                      max_len=32, rope=True)
-    draft = tfm.init_params(jax.random.key(9), draft_cfg)
+    draft_cfg = spec_draft_cfg()
+    draft = toy_params(draft_cfg, 9)
     eng = SpeculativeBatcher(params, draft, CFG, draft_cfg, lanes=2,
                              n_draft=3)
     pa = rng.integers(0, 64, (5,)).astype(np.int32)
@@ -578,10 +566,8 @@ def test_speculative_batcher_validation(params, rng):
 
     from distkeras_tpu.serving import SpeculativeBatcher
 
-    draft_cfg = tfm.TransformerConfig(vocab_size=64, d_model=16,
-                                      n_heads=2, n_layers=1, d_ff=32,
-                                      max_len=32, rope=True)
-    draft = tfm.init_params(jax.random.key(9), draft_cfg)
+    draft_cfg = spec_draft_cfg()
+    draft = toy_params(draft_cfg, 9)
     p = rng.integers(0, 64, (4,)).astype(np.int32)
     with pytest.raises(ValueError, match="full-cache"):
         SpeculativeBatcher(params, draft,
@@ -607,10 +593,8 @@ def test_speculative_batcher_sampled_matches_solo(params, rng):
     from distkeras_tpu.models.speculative import speculative_generate
     from distkeras_tpu.serving import SpeculativeBatcher
 
-    draft_cfg = tfm.TransformerConfig(vocab_size=64, d_model=16,
-                                      n_heads=2, n_layers=1, d_ff=32,
-                                      max_len=32, rope=True)
-    draft = tfm.init_params(jax.random.key(9), draft_cfg)
+    draft_cfg = spec_draft_cfg()
+    draft = toy_params(draft_cfg, 9)
     eng = SpeculativeBatcher(params, draft, CFG, draft_cfg, lanes=2,
                              n_draft=3, temperature=0.8)
     pa = rng.integers(0, 64, (5,)).astype(np.int32)
@@ -647,17 +631,15 @@ def test_speculative_impossible_config_rejected_eagerly(params):
 
     from distkeras_tpu.serving import SpeculativeBatcher
 
-    draft_cfg = tfm.TransformerConfig(vocab_size=64, d_model=16,
-                                      n_heads=2, n_layers=1, d_ff=32,
-                                      max_len=4, rope=True)
-    draft = tfm.init_params(jax.random.key(9), draft_cfg)
+    draft_cfg = spec_draft_cfg(max_len=4)
+    draft = toy_params(draft_cfg, 9)
     # min(max_len) = 4 <= n_draft + 1 = 5: no request can ever fit.
     with pytest.raises(ValueError, match=r"n_draft=4.*max_len"):
         SpeculativeBatcher(params, draft, CFG, draft_cfg, n_draft=4)
     # The boundary case (cap == 1) constructs and admits a 1-token
     # prompt with one new token.
     ok_draft_cfg = dataclasses.replace(draft_cfg, max_len=6)
-    ok_draft = tfm.init_params(jax.random.key(9), ok_draft_cfg)
+    ok_draft = toy_params(ok_draft_cfg, 9)
     eng = SpeculativeBatcher(params, ok_draft, CFG, ok_draft_cfg,
                              n_draft=4, lanes=1)
     assert eng.submit(np.asarray([3], np.int32), 1) == 0

@@ -16,18 +16,16 @@ import numpy as np
 import pytest
 
 from distkeras_tpu import obs
-from distkeras_tpu.models import transformer as tfm
-from distkeras_tpu.models.generate import generate, prefill
 from distkeras_tpu.serving import (ContinuousBatcher, PrefixPool,
                                    SpeculativeBatcher)
+from helpers import generate, jgen, serve_cfg, spec_draft_cfg, toy_params
 
-CFG = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
-                            n_layers=2, d_ff=64, max_len=64, rope=True)
+CFG = serve_cfg(max_len=64)
 
 
 @pytest.fixture(scope="module")
 def params():
-    return tfm.init_params(jax.random.key(0), CFG)
+    return toy_params(CFG)
 
 
 def run_to_done(eng, lane):
@@ -106,10 +104,8 @@ def test_chunked_prefill_1k_prompt_bounded_gap(rng):
     decoding lane emits exactly one token per step() through the whole
     8-chunk admission, and the long request's output still matches its
     solo run."""
-    big = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
-                                n_layers=2, d_ff=64, max_len=1056,
-                                rope=True)
-    bparams = tfm.init_params(jax.random.key(2), big)
+    big = serve_cfg(max_len=1056)
+    bparams = toy_params(big, 2)
     eng = ContinuousBatcher(bparams, big, lanes=2, prefill_chunk=128,
                             prompt_buckets=(8, 128))
     ps = rng.integers(0, 64, (4,)).astype(np.int32)
@@ -150,11 +146,8 @@ def test_chunked_prefill_sampled_and_tail_overlap(params, rng, chunk):
 
 def test_chunked_prefill_validation(params):
     with pytest.raises(ValueError, match="full-cache"):
-        roll = tfm.TransformerConfig(vocab_size=64, d_model=32,
-                                     n_heads=2, n_layers=2, d_ff=64,
-                                     max_len=12, rope=True,
-                                     attention_window=5)
-        ContinuousBatcher(tfm.init_params(jax.random.key(1), roll),
+        roll = serve_cfg(max_len=12, attention_window=5)
+        ContinuousBatcher(toy_params(roll, 1),
                           roll, lanes=1, prefill_chunk=4)
     with pytest.raises(ValueError, match="prefill_chunk"):
         ContinuousBatcher(params, CFG, lanes=1, prefill_chunk=0)
@@ -192,7 +185,7 @@ def test_prefix_pool_refcount_lru_and_errors(params, rng):
     segs = {}
     for name, n in (("a", 4), ("b", 6), ("c", 5)):
         pref = rng.integers(0, 64, (n,)).astype(np.int32)
-        cache, _ = prefill(params, pref[None], CFG, last_logits=False)
+        cache, _ = jgen.prefill(params, pref[None], CFG, last_logits=False)
         segs[name] = (pref, cache)
     ida = pool.put(segs["a"][1], 4)
     idb = pool.put(segs["b"][1], 6)
@@ -214,7 +207,7 @@ def test_prefix_pool_refcount_lru_and_errors(params, rng):
     assert ida not in pool and idc in pool
     # Validation: segment shape/quantization must match the pool spec.
     with pytest.raises(ValueError, match="spec"):
-        qcache, _ = prefill(params, segs["a"][0][None], CFG,
+        qcache, _ = jgen.prefill(params, segs["a"][0][None], CFG,
                             last_logits=False, kv_int8=True)
         pool.put(qcache, 4)
     with pytest.raises(ValueError, match="length"):
@@ -233,8 +226,8 @@ def test_prefix_pool_engine_parity_and_zero_prefix_work(params, rng,
     pool = PrefixPool(CFG, slots=2)
     pref_a = rng.integers(0, 64, (20,)).astype(np.int32)
     pref_b = rng.integers(0, 64, (6,)).astype(np.int32)
-    ca, _ = prefill(params, pref_a[None], CFG, last_logits=False)
-    cb, _ = prefill(params, pref_b[None], CFG, last_logits=False)
+    ca, _ = jgen.prefill(params, pref_a[None], CFG, last_logits=False)
+    cb, _ = jgen.prefill(params, pref_b[None], CFG, last_logits=False)
     ida, idb = pool.put(ca, 20), pool.put(cb, 6)
     eng = ContinuousBatcher(params, CFG, lanes=2, prefix_pool=pool,
                             prompt_buckets=(8,))
@@ -277,7 +270,7 @@ def test_prefix_pool_sampled_kv_int8_and_lane_reuse(params, rng):
     reuse and the 1-token-prompt reseed path."""
     pool = PrefixPool(CFG, slots=2, kv_int8=True)
     pref = rng.integers(0, 64, (6,)).astype(np.int32)
-    cache, _ = prefill(params, pref[None], CFG, last_logits=False,
+    cache, _ = jgen.prefill(params, pref[None], CFG, last_logits=False,
                        kv_int8=True)
     pid = pool.put(cache, 6)
     with pytest.warns(RuntimeWarning, match="kv_int8"):
@@ -315,7 +308,7 @@ def test_prefix_pin_taken_first_and_released_on_decline(params, rng):
     or failed submit releases the pin it took."""
     pool = PrefixPool(CFG, slots=1)
     pref = rng.integers(0, 64, (6,)).astype(np.int32)
-    cache, _ = prefill(params, pref[None], CFG, last_logits=False)
+    cache, _ = jgen.prefill(params, pref[None], CFG, last_logits=False)
     pid = pool.put(cache, 6)
     eng = ContinuousBatcher(params, CFG, lanes=1, prefix_pool=pool,
                             prompt_buckets=(8,))
@@ -343,7 +336,7 @@ def test_prefix_pool_chunked_compose(params, rng):
     generate(prompt_cache=...)."""
     pool = PrefixPool(CFG, slots=1)
     pref = rng.integers(0, 64, (6,)).astype(np.int32)
-    cache, _ = prefill(params, pref[None], CFG, last_logits=False)
+    cache, _ = jgen.prefill(params, pref[None], CFG, last_logits=False)
     pid = pool.put(cache, 6)
     eng = ContinuousBatcher(params, CFG, lanes=1, prefix_pool=pool,
                             prefill_chunk=8, prompt_buckets=(8,))
@@ -364,13 +357,11 @@ def test_speculative_prefix_pool_greedy_parity(params, rng):
     greedy parity vs generate(prompt_cache=...) — including the
     1-token-prompt reseed (which needs the recorded last_token) — and
     refcounts release at drain."""
-    draft_cfg = tfm.TransformerConfig(vocab_size=64, d_model=16,
-                                      n_heads=2, n_layers=1, d_ff=32,
-                                      max_len=64, rope=True)
-    draft = tfm.init_params(jax.random.key(9), draft_cfg)
+    draft_cfg = spec_draft_cfg(max_len=64)
+    draft = toy_params(draft_cfg, 9)
     pref = rng.integers(0, 64, (10,)).astype(np.int32)
-    tca, _ = prefill(params, pref[None], CFG, last_logits=False)
-    dca, _ = prefill(draft, pref[None], draft_cfg, last_logits=False)
+    tca, _ = jgen.prefill(params, pref[None], CFG, last_logits=False)
+    dca, _ = jgen.prefill(draft, pref[None], draft_cfg, last_logits=False)
     pool = PrefixPool(CFG, slots=2, draft_cfg=draft_cfg)
     pid = pool.put((tca, dca), 10, last_token=int(pref[-1]))
     pid_bare = pool.put((tca, dca), 10)      # no last_token recorded
@@ -403,10 +394,8 @@ def test_speculative_prefix_pool_greedy_parity(params, rng):
 
 
 def test_speculative_pool_validation(params, rng):
-    draft_cfg = tfm.TransformerConfig(vocab_size=64, d_model=16,
-                                      n_heads=2, n_layers=1, d_ff=32,
-                                      max_len=64, rope=True)
-    draft = tfm.init_params(jax.random.key(9), draft_cfg)
+    draft_cfg = spec_draft_cfg(max_len=64)
+    draft = toy_params(draft_cfg, 9)
     with pytest.raises(ValueError, match="speculative pool"):
         SpeculativeBatcher(params, draft, CFG, draft_cfg,
                            prefix_pool=PrefixPool(CFG, slots=1))
@@ -414,9 +403,7 @@ def test_speculative_pool_validation(params, rng):
         ContinuousBatcher(params, CFG, prefix_pool=PrefixPool(
             CFG, slots=1, draft_cfg=draft_cfg))
     with pytest.raises(ValueError, match="full-cache"):
-        PrefixPool(tfm.TransformerConfig(
-            vocab_size=64, d_model=32, n_heads=2, n_layers=2, d_ff=64,
-            max_len=12, rope=True, attention_window=5), slots=1)
+        PrefixPool(serve_cfg(max_len=12, attention_window=5), slots=1)
 
 
 # ------------------------------------------------------ kv_int8 advice
@@ -455,7 +442,7 @@ def test_elastic_chunked_pool_enqueue(params, rng):
     step-up and finishes with exact parity."""
     pool = PrefixPool(CFG, slots=1)
     pref = rng.integers(0, 64, (6,)).astype(np.int32)
-    cache, _ = prefill(params, pref[None], CFG, last_logits=False)
+    cache, _ = jgen.prefill(params, pref[None], CFG, last_logits=False)
     pid = pool.put(cache, 6)
     eng = ContinuousBatcher(params, CFG, lane_tiers=(1, 2),
                             max_queue=1, scale_up_after=1,

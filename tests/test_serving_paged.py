@@ -18,23 +18,20 @@ import numpy as np
 import pytest
 
 from distkeras_tpu import obs
-from distkeras_tpu.models import transformer as tfm
-from distkeras_tpu.models.generate import (_decode_chunk, generate,
-                                           init_cache, prefill)
 from distkeras_tpu.serving import (BlockAllocator, ContinuousBatcher,
                                    PagedBatcher, QueueFull)
 from distkeras_tpu.serving.paged import (KV_INT8_PREFILL_LOGIT_TOL,
                                          TRASH_BLOCK)
+from helpers import generate, jgen, serve_cfg, toy_params
 
-CFG = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
-                            n_layers=2, d_ff=64, max_len=32, rope=True)
+CFG = serve_cfg()
 BLOCK = 8
 MB = CFG.max_len // BLOCK
 
 
 @pytest.fixture(scope="module")
 def params():
-    return tfm.init_params(jax.random.key(0), CFG)
+    return toy_params(CFG)
 
 
 def paged(params, lanes=2, n_blocks=None, **kw):
@@ -602,16 +599,16 @@ def test_kv_int8_prefill_admission_tolerance(params, rng):
     prompt = rng.integers(0, 64, (1, 17)).astype(np.int32)
     warm = jnp.asarray(prompt[:, :-1])
     w = warm.shape[1]
-    cache_d = init_cache(CFG, 1, kv_int8=True)
-    _, cache_d = _decode_chunk(params, cache_d, warm,
+    cache_d = jgen.init_cache(CFG, 1, kv_int8=True)
+    _, cache_d = jgen._decode_chunk(params, cache_d, warm,
                                jnp.zeros((1,), jnp.int32), CFG,
                                uniform_pos=True)
-    cache_p, _ = prefill(params, warm, CFG, last_logits=False,
+    cache_p, _ = jgen.prefill(params, warm, CFG, last_logits=False,
                          kv_int8=True)
     pos = jnp.full((1,), w, jnp.int32)
     last = jnp.asarray(prompt[:, -1:])
-    lg_d, _ = _decode_chunk(params, cache_d, last, pos, CFG)
-    lg_p, _ = _decode_chunk(params, cache_p, last, pos, CFG)
+    lg_d, _ = jgen._decode_chunk(params, cache_d, last, pos, CFG)
+    lg_p, _ = jgen._decode_chunk(params, cache_p, last, pos, CFG)
     diff = float(jnp.max(jnp.abs(lg_d - lg_p)))
     assert 0.0 < diff < KV_INT8_PREFILL_LOGIT_TOL, diff
 
@@ -653,9 +650,7 @@ def test_kv_int8_prefill_validation(params):
 def test_paged_constructor_validation(params):
     with pytest.raises(ValueError, match="divide max_len"):
         PagedBatcher(params, CFG, block=5)
-    win = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
-                                n_layers=2, d_ff=64, max_len=32,
-                                rope=True, attention_window=16)
+    win = serve_cfg(attention_window=16)
     with pytest.raises(ValueError, match="full-cache"):
         PagedBatcher(params, win, block=8)
     with pytest.raises(ValueError, match="block must be >= 1"):

@@ -15,8 +15,6 @@ import jax
 import numpy as np
 import pytest
 
-from distkeras_tpu.models import transformer as tfm
-from distkeras_tpu.models.generate import generate, prefill
 from distkeras_tpu.parallel.mesh import MeshSpec, make_mesh
 from distkeras_tpu.parallel.rules import kv_slab_specs, serving_kv_axis
 from distkeras_tpu.parallel.sharding import fsdp_plan, serving_plan
@@ -24,15 +22,15 @@ from distkeras_tpu.serving import (ContinuousBatcher, InProcessReplica,
                                    PagedBatcher, PrefixPool, Router,
                                    SpeculativeBatcher)
 from jax.sharding import PartitionSpec as P
+from helpers import generate, jgen, serve_cfg, spec_draft_cfg, toy_params
 
-CFG = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
-                            n_layers=2, d_ff=64, max_len=32, rope=True)
+CFG = serve_cfg()
 BLOCK = 8
 
 
 @pytest.fixture(scope="module")
 def params():
-    return tfm.init_params(jax.random.key(0), CFG)
+    return toy_params(CFG)
 
 
 @pytest.fixture(scope="module")
@@ -136,7 +134,7 @@ def test_sharded_prefix_pool_bit_exact(params, tp2, rng):
     mesh, plan = tp2
     pool = PrefixPool(CFG, slots=2, mesh=mesh, kv_axis="model")
     pref = rng.integers(0, 64, (1, 6)).astype(np.int32)
-    cache, _ = prefill(params, pref, CFG, last_logits=False)
+    cache, _ = jgen.prefill(params, pref, CFG, last_logits=False)
     pid = pool.put(cache, 6)
     tail = rng.integers(0, 64, (4,)).astype(np.int32)
     ref = np.asarray(generate(params, tail[None], CFG, 4,
@@ -284,9 +282,7 @@ def test_sharded_elastic_paged_rows_only_resize(params, tp2, rng):
 
 # ------------------------------------ speculative x plan (round 17)
 
-SPEC_DRAFT = tfm.TransformerConfig(vocab_size=64, d_model=16,
-                                   n_heads=2, n_layers=1, d_ff=32,
-                                   max_len=32, rope=True)
+SPEC_DRAFT = spec_draft_cfg()
 
 
 def test_sharded_speculative_greedy_parity(params, tp2, rng):
@@ -294,7 +290,7 @@ def test_sharded_speculative_greedy_parity(params, tp2, rng):
     draft replicated — greedy output stays bit-exact vs the solo
     pinned contract (greedy speculative IS greedy generate)."""
     mesh, plan = tp2
-    draft = tfm.init_params(jax.random.key(8), SPEC_DRAFT)
+    draft = toy_params(SPEC_DRAFT, 8)
     eng = SpeculativeBatcher(params, draft, CFG, SPEC_DRAFT, lanes=2,
                              n_draft=3, prompt_buckets=(8,),
                              plan=plan, mesh=mesh)
@@ -310,7 +306,7 @@ def test_sharded_speculative_greedy_parity(params, tp2, rng):
 
 def test_sharded_speculative_rejections(params, tp2):
     mesh, plan = tp2
-    draft = tfm.init_params(jax.random.key(8), SPEC_DRAFT)
+    draft = toy_params(SPEC_DRAFT, 8)
     with pytest.raises(ValueError, match="plan= and mesh= together"):
         SpeculativeBatcher(params, draft, CFG, SPEC_DRAFT, plan=plan)
     pool = PrefixPool(CFG, slots=1, draft_cfg=SPEC_DRAFT)
@@ -341,12 +337,10 @@ def test_rejection_matrix(params, tp2, devices):
     with pytest.raises(ValueError, match="prompt_cache"):
         ContinuousBatcher(params, CFG, plan=plan, mesh=mesh,
                           prompt_cache=(jax.tree.map(
-                              lambda a: a, prefill(
+                              lambda a: a, jgen.prefill(
                                   params, np.zeros((1, 4), np.int32),
                                   CFG, last_logits=False)[0]), 4))
-    wcfg = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
-                                 n_layers=2, d_ff=64, max_len=32,
-                                 rope=True, attention_window=16)
+    wcfg = serve_cfg(attention_window=16)
     with pytest.raises(ValueError, match="full-cache"):
         ContinuousBatcher(params, wcfg, plan=plan, mesh=mesh)
     # Pool placement must match the engine's.
@@ -375,7 +369,7 @@ def test_equal_mesh_from_separate_make_mesh_accepted(params, devices,
     assert mesh_a == mesh_b
     pool = PrefixPool(CFG, slots=1, mesh=mesh_a, kv_axis="model")
     pref = rng.integers(0, 64, (1, 6)).astype(np.int32)
-    cache, _ = prefill(params, pref, CFG, last_logits=False)
+    cache, _ = jgen.prefill(params, pref, CFG, last_logits=False)
     pid = pool.put(cache, 6)
     eng = ContinuousBatcher(params, CFG, lanes=2, prefix_pool=pool,
                             prompt_buckets=(8,), plan=serving_plan(),

@@ -25,6 +25,7 @@ from distkeras_tpu.analysis import shard_lint as sl
 from distkeras_tpu.analysis.ir_lint import TraceSpec, trace_target
 from distkeras_tpu.parallel import rules as pr
 from distkeras_tpu.parallel.mesh import MeshSpec, make_mesh
+from helpers import toy_params
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -403,12 +404,11 @@ def test_repo_plan_matrix_catches_injected_regressions():
     """A stranded (dead) rule and a newly-shadowing rule in the
     serving plan are both caught by the same lint the matrix runs."""
     from distkeras_tpu.analysis.targets import _lm_cfg
-    from distkeras_tpu.models import transformer as tfm
     from distkeras_tpu.parallel.sharding import serving_plan
 
     cfg = _lm_cfg()
     tree = jax.eval_shape(
-        lambda: tfm.init_params(jax.random.key(0), cfg))
+        lambda: toy_params(cfg))
     axes = {"data": 4, "model": 2}
     # Injected typo: the extra rule places nothing.
     fs = sl.lint_plan(serving_plan(extra_rules=[("atn/wq$", P())]),

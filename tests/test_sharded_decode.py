@@ -19,6 +19,7 @@ from distkeras_tpu.models.generate import beam_search, generate
 from distkeras_tpu.parallel.mesh import MeshSpec, make_mesh
 from distkeras_tpu.parallel.sharding import ShardingPlan
 from jax.sharding import NamedSharding, PartitionSpec as P
+from helpers import toy_params
 
 
 CFG = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
@@ -64,7 +65,7 @@ def _sharded_beam(params, prompt, mesh, psh, **kw):
 
 
 def test_generate_greedy_tp_sharded_matches_single(devices, rng):
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     prompt = _prompt(rng)
     ref = np.asarray(generate(params, prompt, CFG, 10))
     mesh, psh = _tp_layout(devices, params)
@@ -76,7 +77,7 @@ def test_generate_sampled_tp_sharded_matches_single(devices, rng):
     # Sampling draws through the position-keyed fold_in stream; the
     # sharded run must reproduce the same tokens (categorical over
     # near-identical logits with the identical key).
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     prompt = _prompt(rng)
     key = jax.random.key(7)
     kw = dict(temperature=0.8, key=key, top_k=20)
@@ -87,7 +88,7 @@ def test_generate_sampled_tp_sharded_matches_single(devices, rng):
 
 
 def test_generate_greedy_fsdp_scattered_matches_single(devices, rng):
-    params = tfm.init_params(jax.random.key(1), CFG)
+    params = toy_params(CFG, 1)
     prompt = _prompt(rng)
     ref = np.asarray(generate(params, prompt, CFG, 10))
     mesh, psh = _fsdp_layout(devices, params)
@@ -96,7 +97,7 @@ def test_generate_greedy_fsdp_scattered_matches_single(devices, rng):
 
 
 def test_beam_search_tp_sharded_matches_single(devices, rng):
-    params = tfm.init_params(jax.random.key(2), CFG)
+    params = toy_params(CFG, 2)
     prompt = _prompt(rng, b=4)
     ref_seqs, ref_scores = beam_search(params, prompt, CFG, 8, beam_width=4)
     mesh, psh = _tp_layout(devices, params)
@@ -107,7 +108,7 @@ def test_beam_search_tp_sharded_matches_single(devices, rng):
 
 
 def test_beam_search_fsdp_scattered_matches_single(devices, rng):
-    params = tfm.init_params(jax.random.key(3), CFG)
+    params = toy_params(CFG, 3)
     prompt = _prompt(rng, b=8)  # data=8 mesh: batch divisible by 8
     ref_seqs, ref_scores = beam_search(params, prompt, CFG, 8, beam_width=4,
                                        eos_token=3)
@@ -126,8 +127,8 @@ def test_speculative_tp_sharded_matches_single(devices, rng):
 
     d_cfg = tfm.TransformerConfig(vocab_size=64, d_model=16, n_heads=2,
                                   n_layers=1, d_ff=32, max_len=32)
-    params = tfm.init_params(jax.random.key(4), CFG)
-    draft = tfm.init_params(jax.random.key(5), d_cfg)
+    params = toy_params(CFG, 4)
+    draft = toy_params(d_cfg, 5)
     prompt = _prompt(rng, b=4, p=4)
     ref, _ = speculative_generate(params, draft, prompt, CFG, d_cfg, 9,
                                   n_draft=3)
@@ -150,7 +151,7 @@ def test_prompt_cache_decode_under_tp(devices, rng):
     exactly the single-device concatenated-prompt tokens."""
     from distkeras_tpu.models.generate import prefill
 
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     prefix = _prompt(rng, b=8, p=4)
     tail = _prompt(rng, b=8, p=3)
     full = jnp.concatenate([prefix, tail], axis=1)
@@ -175,7 +176,7 @@ def test_continuous_batcher_under_tp(devices, rng):
     matches its solo single-device generate run."""
     from distkeras_tpu.serving import ContinuousBatcher
 
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     prompts = [_prompt(rng, b=1, p=4)[0], _prompt(rng, b=1, p=7)[0]]
     refs = [np.asarray(generate(params, p[None], CFG, 6))[0]
             for p in prompts]
@@ -195,7 +196,7 @@ def test_beam_prompt_cache_under_tp(devices, rng):
     the single-device concatenated-prompt beam run."""
     from distkeras_tpu.models.generate import prefill
 
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     prefix = _prompt(rng, b=4, p=4)
     tail = _prompt(rng, b=4, p=3)
     full = jnp.concatenate([prefix, tail], axis=1)

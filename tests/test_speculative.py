@@ -9,13 +9,13 @@ import numpy as np
 import pytest
 
 from distkeras_tpu.models import transformer as tfm
-from distkeras_tpu.models.generate import (
-    _decode_chunk,
-    _decode_step,
-    generate,
-    init_cache,
-)
-from distkeras_tpu.models.speculative import speculative_generate
+from distkeras_tpu.models import speculative
+from helpers import (generate, jgen, jitted, jtfm, no_compile_cache,
+                     toy_params)
+
+_decode_chunk, _decode_step, init_cache = (
+    jgen._decode_chunk, jgen._decode_step, jgen.init_cache)
+speculative_generate = jitted(speculative.speculative_generate)
 
 
 # max_len carries the n_draft slack past prompt + new (validated).
@@ -26,8 +26,8 @@ DRAFT = tfm.TransformerConfig(vocab_size=64, d_model=16, n_heads=2,
 
 
 def _models(cfg=CFG, draft=DRAFT):
-    return (tfm.init_params(jax.random.key(0), cfg),
-            tfm.init_params(jax.random.key(9), draft))
+    return (toy_params(cfg),
+            toy_params(draft, 9))
 
 
 def test_decode_chunk_matches_decode_step(rng):
@@ -91,8 +91,8 @@ def test_greedy_rope_gqa_matches_generate(rng):
                                 n_kv_heads=2, n_layers=2, d_ff=64,
                                 max_len=32, rope=True)
     draft_cfg = dataclasses.replace(cfg, n_layers=1)
-    params = tfm.init_params(jax.random.key(1), cfg)
-    draft = tfm.init_params(jax.random.key(8), draft_cfg)
+    params = toy_params(cfg, 1)
+    draft = toy_params(draft_cfg, 8)
     prompt = jnp.asarray(rng.integers(1, 64, (3, 4)), jnp.int32)
     ref = np.asarray(generate(params, prompt, cfg, 9))
     out, _ = speculative_generate(params, draft, prompt, cfg, draft_cfg,
@@ -105,7 +105,7 @@ def test_greedy_moe_matches_generate(rng):
                                 n_layers=1, d_ff=64, max_len=32,
                                 num_experts=4, moe_top_k=2,
                                 capacity_factor=1.25)
-    params = tfm.init_params(jax.random.key(2), cfg)
+    params = toy_params(cfg, 2)
     _, draft = _models()
     prompt = jnp.asarray(rng.integers(1, 64, (2, 4)), jnp.int32)
     ref = np.asarray(generate(params, prompt, cfg, 8))
@@ -139,7 +139,7 @@ def test_nonuniform_acceptance_rows_finish_cleanly(rng):
     from distkeras_tpu.models.quant import quantize_params
 
     cfg = dataclasses.replace(CFG, max_len=40)
-    params = tfm.init_params(jax.random.key(6), cfg)
+    params = toy_params(cfg, 6)
     draft = quantize_params(params)
     prompt = jnp.asarray(rng.integers(1, 64, (8, 4)), jnp.int32)
     ref = np.asarray(generate(params, prompt, cfg, 20))
@@ -173,18 +173,21 @@ def test_sampled_matches_target_distribution(rng):
     cfg = tfm.TransformerConfig(vocab_size=vocab, d_model=16, n_heads=2,
                                 n_layers=1, d_ff=32, max_len=8)
     dcfg = dataclasses.replace(cfg, d_model=8, d_ff=16)
-    params = tfm.init_params(jax.random.key(3), cfg)
-    draft = tfm.init_params(jax.random.key(4), dcfg)
+    params = toy_params(cfg, 3)
+    draft = toy_params(dcfg, 4)
     temp = 0.9
     b = 4096
     prompt = jnp.full((b, 1), 7, jnp.int32)
-    out, _ = speculative_generate(params, draft, prompt, cfg, dcfg, 1,
-                                  n_draft=2, temperature=temp,
-                                  key=jax.random.key(11))
+    # XLA:CPU takes minutes over the loop at 4096 rows, and the compile
+    # cache dies (SIGSEGV) serializing what it made.
+    with no_compile_cache():
+        out, _ = speculative_generate(params, draft, prompt, cfg, dcfg, 1,
+                                      n_draft=2, temperature=temp,
+                                      key=jax.random.key(11))
     samples = np.asarray(out[:, 1])
     emp = np.bincount(samples, minlength=vocab) / b
 
-    logits, _ = tfm.apply(params, prompt[:1], cfg)
+    logits, _ = jtfm.apply(params, prompt[:1], cfg)
     target = np.asarray(jax.nn.softmax(logits[0, 0] / temp))
     tv = 0.5 * np.abs(emp - target).sum()
     assert tv < 0.05, (tv, emp, target)
@@ -286,7 +289,7 @@ def test_speculative_kv_int8_greedy_matches_generate_kv_int8(rng):
     same tokens as plain kv_int8 generate: quantization is per-token
     deterministic, so the verify-chunk cache and the slab-update cache
     hold identical int8 values."""
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     prompt = jnp.asarray(rng.integers(0, 64, (3, 4)).astype(np.int32))
     ref = np.asarray(generate(params, prompt, CFG, 8, kv_int8=True))
     out, stats = speculative_generate(params, params, prompt, CFG, CFG,

@@ -16,6 +16,7 @@ from distkeras_tpu.parallel.mesh import MeshSpec, make_mesh
 from distkeras_tpu.parallel.ring import make_ring_attention
 from distkeras_tpu.parallel.sharding import ShardingPlan
 from jax.sharding import NamedSharding, PartitionSpec as P
+from helpers import jtfm, toy_params
 
 
 CFG = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
@@ -27,10 +28,10 @@ def toks(rng, b=4, s=16, vocab=64):
 
 
 def test_forward_shape_and_determinism(rng):
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     t = toks(rng)
-    out1, aux1 = tfm.apply(params, t, CFG)
-    out2, _ = tfm.apply(params, t, CFG)
+    out1, aux1 = jtfm.apply(params, t, CFG)
+    out2, _ = jtfm.apply(params, t, CFG)
     assert out1.shape == (4, 16, 64)
     assert float(aux1) == 0.0  # dense model: no aux loss
     np.testing.assert_array_equal(out1, out2)
@@ -40,7 +41,7 @@ def test_train_step_learns_copy_task(rng):
     # Predict-previous-token: a transformer with causal attention can
     # solve this exactly; loss must fall fast.
     cfg = CFG
-    params = tfm.init_params(jax.random.key(0), cfg)
+    params = toy_params(cfg)
     opt = optax.adam(1e-2)
     step = jax.jit(tfm.make_train_step(cfg, opt))
     carry = (params, opt.init(params))
@@ -58,34 +59,34 @@ def _sharded_apply(params, t, cfg, mesh, rules, attention_fn=None):
     params_sh = jax.device_put(params, psh)
     tsh = NamedSharding(mesh, P("data", None))
     fn = jax.jit(
-        lambda p, t: tfm.apply(p, t, cfg, attention_fn)[0],
+        lambda p, t: jtfm.apply(p, t, cfg, attention_fn)[0],
         in_shardings=(psh, tsh))
     return fn(params_sh, jnp.asarray(t))
 
 
 def test_tensor_parallel_matches_single(devices, rng):
     mesh = make_mesh(MeshSpec(data=4, model=2), devices=devices)
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     t = toks(rng)
-    ref, _ = tfm.apply(params, jnp.asarray(t), CFG)
+    ref, _ = jtfm.apply(params, jnp.asarray(t), CFG)
     out = _sharded_apply(params, t, CFG, mesh, tfm.tp_rules())
     np.testing.assert_allclose(out, ref, atol=2e-4, rtol=2e-4)
 
 
 def test_sequence_parallel_ring_matches_single(devices, rng):
     mesh = make_mesh(MeshSpec(data=2, seq=4), devices=devices)
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     t = toks(rng)
-    ref, _ = tfm.apply(params, jnp.asarray(t), CFG)
+    ref, _ = jtfm.apply(params, jnp.asarray(t), CFG)
     ring = make_ring_attention(mesh, causal=True)
     out = _sharded_apply(params, t, CFG, mesh, [], attention_fn=ring)
     np.testing.assert_allclose(out, ref, atol=2e-4, rtol=2e-4)
 
 
 def test_seq_len_over_max_len_raises(rng):
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     with pytest.raises(ValueError, match="max_len"):
-        tfm.apply(params, jnp.zeros((2, CFG.max_len + 4), jnp.int32), CFG)
+        jtfm.apply(params, jnp.zeros((2, CFG.max_len + 4), jnp.int32), CFG)
 
 
 MOE_CFG = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
@@ -96,7 +97,7 @@ MOE_CFG = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
 def test_moe_dispatch_matches_per_token_reference(rng):
     """Dense-dispatch einsum == a literal per-token expert loop (no drops
     at capacity_factor=4)."""
-    params = tfm.init_params(jax.random.key(1), MOE_CFG)
+    params = toy_params(MOE_CFG, 1)
     lp = jax.tree.map(lambda a: a[0], params["layers"])["moe"]
     x = jnp.asarray(rng.normal(size=(2, 8, 32)).astype(np.float32))
     out, aux = tfm._moe_block(lp, x, MOE_CFG)
@@ -120,7 +121,7 @@ def test_moe_capacity_drops_tokens(rng):
     cfg = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
                                 n_layers=1, d_ff=64, max_len=32,
                                 num_experts=4, capacity_factor=0.25)
-    params = tfm.init_params(jax.random.key(1), cfg)
+    params = toy_params(cfg, 1)
     lp = jax.tree.map(lambda a: a[0], params["layers"])["moe"]
     x = jnp.asarray(rng.normal(size=(2, 8, 32)).astype(np.float32))
     out, _ = tfm._moe_block(lp, x, cfg)
@@ -132,16 +133,16 @@ def test_moe_capacity_drops_tokens(rng):
 
 def test_expert_parallel_matches_single(devices, rng):
     mesh = make_mesh(MeshSpec(data=2, expert=4), devices=devices)
-    params = tfm.init_params(jax.random.key(1), MOE_CFG)
+    params = toy_params(MOE_CFG, 1)
     t = toks(rng)
-    ref, _ = tfm.apply(params, jnp.asarray(t), MOE_CFG)
+    ref, _ = jtfm.apply(params, jnp.asarray(t), MOE_CFG)
     out = _sharded_apply(params, t, MOE_CFG, mesh, tfm.tp_rules())
     np.testing.assert_allclose(out, ref, atol=2e-4, rtol=2e-4)
 
 
 def test_moe_train_step_learns(rng):
     opt = optax.adam(1e-2)
-    params = tfm.init_params(jax.random.key(0), MOE_CFG)
+    params = toy_params(MOE_CFG)
     step = jax.jit(tfm.make_train_step(MOE_CFG, opt))
     carry = (params, opt.init(params))
     t = jnp.asarray(toks(rng, b=16, s=16))
@@ -163,7 +164,7 @@ MOE2_CFG = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
 def test_moe_top2_dispatch_matches_per_token_reference(rng):
     """Top-2 capacity dispatch == a literal per-token two-expert loop
     with renormalized gates (no drops at capacity_factor=4)."""
-    params = tfm.init_params(jax.random.key(1), MOE2_CFG)
+    params = toy_params(MOE2_CFG, 1)
     lp = jax.tree.map(lambda a: a[0], params["layers"])["moe"]
     x = jnp.asarray(rng.normal(size=(2, 8, 32)).astype(np.float32))
     out, aux = tfm._moe_block(lp, x, MOE2_CFG)
@@ -189,10 +190,10 @@ def test_moe_top2_capacity_equals_dense_routing_when_nothing_drops(rng):
     """At generous capacity the capacity path and the decode-parity
     dense path compute the same function (the top-2 analogue of the
     cached-decode parity contract)."""
-    params = tfm.init_params(jax.random.key(2), MOE2_CFG)
+    params = toy_params(MOE2_CFG, 2)
     t = jnp.asarray(toks(rng))
-    cap_logits, _ = tfm.apply(params, t, MOE2_CFG)
-    dense_logits, _ = tfm.apply(params, t, MOE2_CFG,
+    cap_logits, _ = jtfm.apply(params, t, MOE2_CFG)
+    dense_logits, _ = jtfm.apply(params, t, MOE2_CFG,
                                 moe_dense_routing=True)
     np.testing.assert_allclose(np.asarray(cap_logits),
                                np.asarray(dense_logits),
@@ -207,7 +208,7 @@ def test_moe_top2_second_choices_yield_capacity(rng):
 
     cfg = dataclasses.replace(MOE2_CFG, capacity_factor=0.125)
     # cap = int(0.125 * 2 * 16 / 4) = 1 slot per expert.
-    params = tfm.init_params(jax.random.key(1), cfg)
+    params = toy_params(cfg, 1)
     lp = jax.tree.map(lambda a: a[0], params["layers"])["moe"]
     x = jnp.asarray(rng.normal(size=(2, 8, 32)).astype(np.float32))
     out, _ = tfm._moe_block(lp, x, cfg)
@@ -230,9 +231,9 @@ def test_moe_top2_second_choices_yield_capacity(rng):
 
 def test_moe_top2_expert_parallel_matches_single(devices, rng):
     mesh = make_mesh(MeshSpec(data=2, expert=4), devices=devices)
-    params = tfm.init_params(jax.random.key(1), MOE2_CFG)
+    params = toy_params(MOE2_CFG, 1)
     t = toks(rng)
-    ref, _ = tfm.apply(params, jnp.asarray(t), MOE2_CFG)
+    ref, _ = jtfm.apply(params, jnp.asarray(t), MOE2_CFG)
     out = _sharded_apply(params, t, MOE2_CFG, mesh, tfm.tp_rules())
     np.testing.assert_allclose(out, ref, atol=2e-4, rtol=2e-4)
 
@@ -245,7 +246,7 @@ def test_moe_top_k_range_validated():
     for bad in (0, 5):
         cfg = dataclasses.replace(MOE_CFG, moe_top_k=bad)
         with pytest.raises(ValueError, match="moe_top_k"):
-            tfm.init_params(jax.random.key(0), cfg)
+            toy_params(cfg)
 
 
 ROPE_CFG = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
@@ -253,7 +254,7 @@ ROPE_CFG = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
 
 
 def test_rope_params_have_no_pos_table():
-    params = tfm.init_params(jax.random.key(0), ROPE_CFG)
+    params = toy_params(ROPE_CFG)
     assert "pos_emb" not in params
     with pytest.raises(ValueError, match="even head_dim"):
         tfm.init_params(jax.random.key(0), tfm.TransformerConfig(
@@ -262,9 +263,9 @@ def test_rope_params_have_no_pos_table():
 
 
 def test_rope_forward_and_learning(rng):
-    params = tfm.init_params(jax.random.key(0), ROPE_CFG)
+    params = toy_params(ROPE_CFG)
     t = toks(rng)
-    out, _ = tfm.apply(params, jnp.asarray(t), ROPE_CFG)
+    out, _ = jtfm.apply(params, jnp.asarray(t), ROPE_CFG)
     assert out.shape == (4, 16, 64) and np.isfinite(np.asarray(out)).all()
 
     opt = optax.adam(1e-2)
@@ -289,7 +290,7 @@ def test_rope_relative_position_invariance(rng):
     not do; instead verify the cheap exact form: rotating *all*
     positions by a constant offset leaves attention scores unchanged.
     """
-    params = tfm.init_params(jax.random.key(0), ROPE_CFG)
+    params = toy_params(ROPE_CFG)
     q = jnp.asarray(rng.normal(size=(1, 8, 2, 16)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(1, 8, 2, 16)), jnp.float32)
     base = tfm.rope_angles(jnp.arange(8), 16, 10000.0)[None, :, None, :]
@@ -306,9 +307,9 @@ def test_rope_relative_position_invariance(rng):
 def test_rope_ring_matches_single(devices, rng):
     """SP: ring attention with global-position rotary == single-device."""
     mesh = make_mesh(MeshSpec(data=2, seq=4), devices=devices)
-    params = tfm.init_params(jax.random.key(0), ROPE_CFG)
+    params = toy_params(ROPE_CFG)
     t = toks(rng)
-    ref, _ = tfm.apply(params, jnp.asarray(t), ROPE_CFG)
+    ref, _ = jtfm.apply(params, jnp.asarray(t), ROPE_CFG)
     ring = make_ring_attention(mesh, causal=True)
     out = _sharded_apply(params, t, ROPE_CFG, mesh, [], attention_fn=ring)
     np.testing.assert_allclose(out, ref, atol=2e-4, rtol=2e-4)
@@ -318,9 +319,9 @@ def test_rope_pipelined_matches_single(devices, rng):
     """PP and PP x SP: stage-local rotary offsets must reproduce the
     un-pipelined forward exactly."""
     mesh = make_mesh(MeshSpec(data=2, pipeline=2, seq=2), devices=devices)
-    params = tfm.init_params(jax.random.key(0), ROPE_CFG)
+    params = toy_params(ROPE_CFG)
     t = jnp.asarray(toks(rng, b=4, s=16))
-    ref, _ = tfm.apply(params, t, ROPE_CFG)
+    ref, _ = jtfm.apply(params, t, ROPE_CFG)
     out, _ = jax.jit(lambda p, tk: tfm.apply_pipelined(
         p, tk, ROPE_CFG, mesh, microbatches=2, seq_axis="seq"))(params, t)
     np.testing.assert_allclose(out, ref, atol=2e-4, rtol=2e-4)
@@ -329,9 +330,9 @@ def test_rope_pipelined_matches_single(devices, rng):
 def test_rope_trains_past_max_len(rng):
     """No position table -> training length is unbounded by max_len
     (which only sizes the decode KV cache)."""
-    params = tfm.init_params(jax.random.key(0), ROPE_CFG)
+    params = toy_params(ROPE_CFG)
     long = jnp.asarray(toks(rng, b=2, s=ROPE_CFG.max_len * 2))
-    out, _ = tfm.apply(params, long, ROPE_CFG)
+    out, _ = jtfm.apply(params, long, ROPE_CFG)
     assert out.shape == (2, ROPE_CFG.max_len * 2, 64)
     assert np.isfinite(np.asarray(out)).all()
 
@@ -342,10 +343,10 @@ GQA_CFG = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=4,
 
 
 def test_gqa_shapes_and_learning(rng):
-    params = tfm.init_params(jax.random.key(0), GQA_CFG)
+    params = toy_params(GQA_CFG)
     assert params["layers"]["attn"]["wk"].shape == (2, 32, 2, 8)
     assert params["layers"]["attn"]["wq"].shape == (2, 32, 4, 8)
-    out, _ = tfm.apply(params, jnp.asarray(toks(rng)), GQA_CFG)
+    out, _ = jtfm.apply(params, jnp.asarray(toks(rng)), GQA_CFG)
     assert out.shape == (4, 16, 64) and np.isfinite(np.asarray(out)).all()
 
     opt = optax.adam(1e-2)
@@ -364,11 +365,11 @@ def test_gqa_equals_mha_when_kv_heads_full(rng):
     full = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
                                  n_layers=2, d_ff=64, max_len=32,
                                  n_kv_heads=2)
-    p1 = tfm.init_params(jax.random.key(0), CFG)
-    p2 = tfm.init_params(jax.random.key(0), full)
+    p1 = toy_params(CFG)
+    p2 = toy_params(full)
     t = jnp.asarray(toks(rng))
-    np.testing.assert_array_equal(tfm.apply(p1, t, CFG)[0],
-                                  tfm.apply(p2, t, full)[0])
+    np.testing.assert_array_equal(jtfm.apply(p1, t, CFG)[0],
+                                  jtfm.apply(p2, t, full)[0])
 
 
 def test_gqa_validation():
@@ -380,9 +381,9 @@ def test_gqa_validation():
 
 def test_gqa_ring_matches_single(devices, rng):
     mesh = make_mesh(MeshSpec(data=2, seq=4), devices=devices)
-    params = tfm.init_params(jax.random.key(0), GQA_CFG)
+    params = toy_params(GQA_CFG)
     t = toks(rng)
-    ref, _ = tfm.apply(params, jnp.asarray(t), GQA_CFG)
+    ref, _ = jtfm.apply(params, jnp.asarray(t), GQA_CFG)
     ring = make_ring_attention(mesh, causal=True)
     out = _sharded_apply(params, t, GQA_CFG, mesh, [], attention_fn=ring)
     np.testing.assert_allclose(out, ref, atol=2e-4, rtol=2e-4)
@@ -394,24 +395,24 @@ DROP_CFG = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
 
 
 def test_dropout_deterministic_per_key_and_off_without_rng(rng):
-    params = tfm.init_params(jax.random.key(0), DROP_CFG)
+    params = toy_params(DROP_CFG)
     t = jnp.asarray(toks(rng))
     # No rng -> deterministic inference even with cfg.dropout > 0.
-    a, _ = tfm.apply(params, t, DROP_CFG)
-    b, _ = tfm.apply(params, t, DROP_CFG)
+    a, _ = jtfm.apply(params, t, DROP_CFG)
+    b, _ = jtfm.apply(params, t, DROP_CFG)
     np.testing.assert_array_equal(a, b)
     # Same key -> same masks; different key -> different activations.
     k1, k2 = jax.random.key(1), jax.random.key(2)
-    d1, _ = tfm.apply(params, t, DROP_CFG, dropout_rng=k1)
-    d1b, _ = tfm.apply(params, t, DROP_CFG, dropout_rng=k1)
-    d2, _ = tfm.apply(params, t, DROP_CFG, dropout_rng=k2)
+    d1, _ = jtfm.apply(params, t, DROP_CFG, dropout_rng=k1)
+    d1b, _ = jtfm.apply(params, t, DROP_CFG, dropout_rng=k1)
+    d2, _ = jtfm.apply(params, t, DROP_CFG, dropout_rng=k2)
     np.testing.assert_array_equal(d1, d1b)
     assert not np.array_equal(np.asarray(d1), np.asarray(d2))
     assert not np.array_equal(np.asarray(a), np.asarray(d1))
 
 
 def test_dropout_training_learns(rng):
-    params = tfm.init_params(jax.random.key(0), DROP_CFG)
+    params = toy_params(DROP_CFG)
     opt = optax.adam(1e-2)
     step = jax.jit(tfm.make_train_step(DROP_CFG, opt))
     carry = (params, opt.init(params))
@@ -430,7 +431,7 @@ def test_dropout_validation(rng):
             max_len=32, dropout=1.0))
     # A dropout config whose step is driven without an rng must refuse
     # rather than silently train unregularized.
-    params = tfm.init_params(jax.random.key(0), DROP_CFG)
+    params = toy_params(DROP_CFG)
     opt = optax.adam(1e-2)
     step = tfm.make_train_step(DROP_CFG, opt)
     with pytest.raises(ValueError, match="dropout_rng"):
@@ -445,10 +446,12 @@ def test_chunked_ce_loss_and_grads_match_full(rng):
     import dataclasses
 
     cfg_c = dataclasses.replace(CFG, ce_chunks=4)
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     t = jnp.asarray(toks(rng))
-    l_full, g_full = jax.value_and_grad(tfm.lm_loss)(params, t, CFG)
-    l_chunk, g_chunk = jax.value_and_grad(tfm.lm_loss)(params, t, cfg_c)
+    l_full, g_full = jax.jit(jax.value_and_grad(tfm.lm_loss),
+                              static_argnums=2)(params, t, CFG)
+    l_chunk, g_chunk = jax.jit(jax.value_and_grad(tfm.lm_loss),
+                              static_argnums=2)(params, t, cfg_c)
     np.testing.assert_allclose(float(l_chunk), float(l_full), rtol=1e-6)
     jax.tree.map(lambda a, b: np.testing.assert_allclose(
         a, b, atol=1e-6, rtol=1e-5), g_full, g_chunk)
@@ -460,10 +463,10 @@ def test_chunked_ce_handles_nondivisible_token_count(rng):
     import dataclasses
 
     cfg_c = dataclasses.replace(CFG, ce_chunks=7)  # 4*15=60 tokens, 7∤60
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     t = jnp.asarray(toks(rng))
-    l_full = tfm.lm_loss(params, t, CFG)
-    l_chunk = tfm.lm_loss(params, t, cfg_c)
+    l_full = jtfm.lm_loss(params, t, CFG)
+    l_chunk = jtfm.lm_loss(params, t, cfg_c)
     np.testing.assert_allclose(float(l_chunk), float(l_full), rtol=1e-6)
 
 
@@ -471,11 +474,11 @@ def test_chunked_ce_eval_nll_matches(rng):
     import dataclasses
 
     cfg_c = dataclasses.replace(CFG, ce_chunks=4)
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     t = jnp.asarray(toks(rng))
     np.testing.assert_allclose(
-        float(tfm.lm_nll(params, t, cfg_c)),
-        float(tfm.lm_nll(params, t, CFG)), rtol=1e-6)
+        float(jtfm.lm_nll(params, t, cfg_c)),
+        float(jtfm.lm_nll(params, t, CFG)), rtol=1e-6)
 
 
 def test_chunked_ce_under_tensor_parallel(devices, rng):
@@ -486,14 +489,14 @@ def test_chunked_ce_under_tensor_parallel(devices, rng):
 
     cfg_c = dataclasses.replace(CFG, ce_chunks=4)
     mesh = make_mesh(MeshSpec(data=4, model=2), devices=devices)
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     t = jnp.asarray(toks(rng))
-    ref = float(tfm.lm_loss(params, t, CFG))
+    ref = float(jtfm.lm_loss(params, t, CFG))
     plan = ShardingPlan(rules=tfm.tp_rules())
     psh = plan.tree_shardings(mesh, params)
     params_sh = jax.device_put(params, psh)
     tsh = NamedSharding(mesh, P("data", None))
-    loss = jax.jit(lambda p, x: tfm.lm_loss(p, x, cfg_c),
+    loss = jax.jit(lambda p, x: jtfm.lm_loss(p, x, cfg_c),
                    in_shardings=(psh, tsh))(params_sh, t)
     np.testing.assert_allclose(float(loss), ref, atol=2e-5, rtol=2e-5)
 
@@ -502,7 +505,7 @@ def test_chunked_ce_trains(rng):
     import dataclasses
 
     cfg_c = dataclasses.replace(CFG, ce_chunks=4)
-    params = tfm.init_params(jax.random.key(0), cfg_c)
+    params = toy_params(cfg_c)
     opt = optax.adam(1e-2)
     step = jax.jit(tfm.make_train_step(cfg_c, opt))
     carry = (params, opt.init(params))
@@ -521,13 +524,13 @@ def test_chunked_ce_pipelined_matches_unpipelined(devices, rng):
 
     cfg = dataclasses.replace(CFG, n_layers=2, ce_chunks=4)
     mesh = make_mesh(MeshSpec(data=2, pipeline=2), devices=devices[:4])
-    params = tfm.init_params(jax.random.key(0), cfg)
+    params = toy_params(cfg)
     t = jnp.asarray(toks(rng, b=4, s=16))
-    ref = float(tfm.lm_loss(params, t, dataclasses.replace(cfg, ce_chunks=0)))
+    ref = float(jtfm.lm_loss(params, t, dataclasses.replace(cfg, ce_chunks=0)))
     hidden_fn = lambda p, x: tfm.apply_pipelined(
         p, x, cfg, mesh, microbatches=2, return_hidden=True)
     with mesh:
-        loss = jax.jit(lambda p, x: tfm.lm_loss(p, x, cfg,
+        loss = jax.jit(lambda p, x: jtfm.lm_loss(p, x, cfg,
                                                 hidden_fn=hidden_fn))(params, t)
     np.testing.assert_allclose(float(loss), ref, atol=2e-5, rtol=2e-5)
 
@@ -550,15 +553,15 @@ def test_chunked_ce_pipelined_trains_via_lm_trainer(devices, rng):
 
 
 def test_lm_loss_rejects_both_forward_hooks(rng):
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     t = jnp.asarray(toks(rng))
     dummy = lambda p, x: (None, None)
     with pytest.raises(ValueError, match="not both"):
-        tfm.lm_loss(params, t, CFG, apply_fn=dummy, hidden_fn=dummy)
+        jtfm.lm_loss(params, t, CFG, apply_fn=dummy, hidden_fn=dummy)
     # Same guard on the eval entry point: silently preferring apply_fn
     # would materialize the logits the caller asked ce_chunks to avoid.
     with pytest.raises(ValueError, match="not both"):
-        tfm.lm_nll(params, t, CFG, apply_fn=dummy, hidden_fn=dummy)
+        jtfm.lm_nll(params, t, CFG, apply_fn=dummy, hidden_fn=dummy)
 
 
 # -------------------------------------------------------------------- z-loss
@@ -570,11 +573,13 @@ def test_z_loss_chunked_matches_full(rng):
 
     z = dataclasses.replace(CFG, z_loss_coef=1e-3)
     zc = dataclasses.replace(CFG, z_loss_coef=1e-3, ce_chunks=4)
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     t = jnp.asarray(toks(rng))
-    base = float(tfm.lm_loss(params, t, CFG))
-    l_full, g_full = jax.value_and_grad(tfm.lm_loss)(params, t, z)
-    l_chunk, g_chunk = jax.value_and_grad(tfm.lm_loss)(params, t, zc)
+    base = float(jtfm.lm_loss(params, t, CFG))
+    l_full, g_full = jax.jit(jax.value_and_grad(tfm.lm_loss),
+                              static_argnums=2)(params, t, z)
+    l_chunk, g_chunk = jax.jit(jax.value_and_grad(tfm.lm_loss),
+                              static_argnums=2)(params, t, zc)
     assert float(l_full) > base
     np.testing.assert_allclose(float(l_chunk), float(l_full), rtol=1e-6)
     jax.tree.map(lambda a, b: np.testing.assert_allclose(
@@ -585,10 +590,10 @@ def test_z_loss_excluded_from_eval_nll(rng):
     import dataclasses
 
     z = dataclasses.replace(CFG, z_loss_coef=1e-2)
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     t = jnp.asarray(toks(rng))
-    np.testing.assert_allclose(float(tfm.lm_nll(params, t, z)),
-                               float(tfm.lm_nll(params, t, CFG)),
+    np.testing.assert_allclose(float(jtfm.lm_nll(params, t, z)),
+                               float(jtfm.lm_nll(params, t, CFG)),
                                rtol=1e-7)
 
 
@@ -598,14 +603,14 @@ def test_z_loss_trains_and_shrinks_normalizer(rng):
     import dataclasses
 
     def train(cfg):
-        params = tfm.init_params(jax.random.key(0), cfg)
+        params = toy_params(cfg)
         opt = optax.adam(1e-2)
         step = jax.jit(tfm.make_train_step(cfg, opt))
         carry = (params, opt.init(params))
         t = jnp.asarray(toks(rng_local, b=16, s=16))
         for _ in range(40):
             carry, loss = step(carry, t)
-        logits, _ = tfm.apply(carry[0], t[:, :-1], cfg)
+        logits, _ = jtfm.apply(carry[0], t[:, :-1], cfg)
         lse = jax.scipy.special.logsumexp(logits, axis=-1)
         return float(loss), float(jnp.square(lse).mean())
 
@@ -628,18 +633,18 @@ def test_attention_window_matches_manual_mask(rng):
 
     w = 5
     cfg_w = dataclasses.replace(CFG, attention_window=w)
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     t = jnp.asarray(toks(rng))
-    ref, _ = tfm.apply(params, t, CFG,
+    ref, _ = jtfm.apply(params, t, CFG,
                        attention_fn=lambda q, k, v: naive_attention(
                            q, k, v, causal=True, window=w))
-    out, _ = tfm.apply(params, t, cfg_w)
+    out, _ = jtfm.apply(params, t, cfg_w)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=1e-4, rtol=1e-4)
     # window >= seq degenerates to full causal
     cfg_big = dataclasses.replace(CFG, attention_window=64)
-    full, _ = tfm.apply(params, t, CFG)
-    big, _ = tfm.apply(params, t, cfg_big)
+    full, _ = jtfm.apply(params, t, CFG)
+    big, _ = jtfm.apply(params, t, cfg_big)
     np.testing.assert_allclose(np.asarray(big), np.asarray(full),
                                atol=1e-5, rtol=1e-5)
 
@@ -648,7 +653,7 @@ def test_attention_window_trains(rng):
     import dataclasses
 
     cfg = dataclasses.replace(CFG, attention_window=4)
-    params = tfm.init_params(jax.random.key(0), cfg)
+    params = toy_params(cfg)
     opt = optax.adam(1e-2)
     step = jax.jit(tfm.make_train_step(cfg, opt))
     carry = (params, opt.init(params))
@@ -669,9 +674,9 @@ def test_attention_window_ring_matches_single(rng, devices):
     w = 5
     cfg = dataclasses.replace(CFG, attention_window=w)
     mesh = make_mesh(MeshSpec(data=2, seq=4), devices=devices)
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     t = toks(rng)
-    ref, _ = tfm.apply(params, jnp.asarray(t), cfg)
+    ref, _ = jtfm.apply(params, jnp.asarray(t), cfg)
     ring = make_ring_attention(mesh, causal=True, window=w)
     assert ring.handles_window
     out = _sharded_apply(params, t, cfg, mesh, [], attention_fn=ring)
@@ -703,9 +708,9 @@ def test_attention_window_rejects_custom_attention_fn(rng):
     from distkeras_tpu.ops.attention import naive_attention
 
     cfg = dataclasses.replace(CFG, attention_window=4)
-    params = tfm.init_params(jax.random.key(0), cfg)
+    params = toy_params(cfg)
     with pytest.raises(ValueError, match="attention_fn"):
-        tfm.apply(params, jnp.asarray(toks(rng)), cfg,
+        jtfm.apply(params, jnp.asarray(toks(rng)), cfg,
                   attention_fn=lambda q, k, v: naive_attention(
                       q, k, v, causal=True))
 
@@ -717,14 +722,14 @@ def test_attention_window_rejects_mismatched_ring(rng, devices):
 
     cfg = dataclasses.replace(CFG, attention_window=4)
     mesh = make_mesh(MeshSpec(data=2, seq=4), devices=devices)
-    params = tfm.init_params(jax.random.key(0), CFG)
+    params = toy_params(CFG)
     ring8 = make_ring_attention(mesh, causal=True, window=8)
     with pytest.raises(ValueError, match="mismatch"):
-        tfm.apply(params, jnp.asarray(toks(rng)), cfg, attention_fn=ring8)
+        jtfm.apply(params, jnp.asarray(toks(rng)), cfg, attention_fn=ring8)
     # The unchecked direction: a windowed fn with a window-less cfg is
     # equally a silent train/decode divergence and must be refused.
     with pytest.raises(ValueError, match="mismatch"):
-        tfm.apply(params, jnp.asarray(toks(rng)), CFG, attention_fn=ring8)
+        jtfm.apply(params, jnp.asarray(toks(rng)), CFG, attention_fn=ring8)
 
 
 def test_attention_window_composes_with_moe(rng):
@@ -733,7 +738,7 @@ def test_attention_window_composes_with_moe(rng):
     import dataclasses
 
     cfg = dataclasses.replace(MOE_CFG, attention_window=4)
-    params = tfm.init_params(jax.random.key(0), cfg)
+    params = toy_params(cfg)
     opt = optax.adam(1e-2)
     step = jax.jit(tfm.make_train_step(cfg, opt))
     carry = (params, opt.init(params))
@@ -753,9 +758,9 @@ def test_attention_window_pipelined_ring_matches_single(devices, rng):
 
     cfg = dataclasses.replace(ROPE_CFG, attention_window=5)
     mesh = make_mesh(MeshSpec(data=2, pipeline=2, seq=2), devices=devices)
-    params = tfm.init_params(jax.random.key(0), cfg)
+    params = toy_params(cfg)
     t = jnp.asarray(toks(rng, b=4, s=16))
-    ref, _ = tfm.apply(params, t, cfg)
+    ref, _ = jtfm.apply(params, t, cfg)
     out, _ = jax.jit(lambda p, tk: tfm.apply_pipelined(
         p, tk, cfg, mesh, microbatches=2, seq_axis="seq"))(params, t)
     np.testing.assert_allclose(out, ref, atol=2e-4, rtol=2e-4)

@@ -20,8 +20,7 @@ import jax
 import numpy as np
 import pytest
 
-from distkeras_tpu.models import transformer as tfm
-from distkeras_tpu.models.generate import generate
+from helpers import generate, serve_cfg, toy_params
 from distkeras_tpu.resilience import chaos
 from distkeras_tpu.serving import (CanaryController, ContinuousBatcher,
                                    InProcessReplica, Router,
@@ -30,23 +29,22 @@ from distkeras_tpu.serving import (CanaryController, ContinuousBatcher,
 from distkeras_tpu.utils import locks
 
 
-CFG = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
-                            n_layers=2, d_ff=64, max_len=32, rope=True)
+CFG = serve_cfg()
 
 
 @pytest.fixture(scope="module")
 def params():
-    return tfm.init_params(jax.random.key(0), CFG)
+    return toy_params(CFG)
 
 
 @pytest.fixture(scope="module")
 def params_v1():
-    return tfm.init_params(jax.random.key(1), CFG)
+    return toy_params(CFG, 1)
 
 
 @pytest.fixture(scope="module")
 def template():
-    return jax.eval_shape(lambda: tfm.init_params(jax.random.key(0), CFG))
+    return jax.eval_shape(lambda: toy_params(CFG))
 
 
 def np_tree(tree):

@@ -18,6 +18,7 @@ from distkeras_tpu.parallel import collectives as cl
 from distkeras_tpu.parallel.mesh import MeshSpec, make_mesh
 from distkeras_tpu.resilience import FaultPlan, Supervisor
 from jax.sharding import NamedSharding, PartitionSpec as P
+from helpers import toy_params
 
 
 CFG = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
@@ -329,8 +330,7 @@ def test_zero1_rejections(devices, blobs):
     with pytest.raises(ValueError, match="data axis only"):
         dk.LMTrainer(CFG, mesh=mesh, zero1=True)
     with pytest.raises(ValueError, match="zero1"):
-        dk.LoRATrainer(CFG, base_params=tfm.init_params(
-            jax.random.key(0), CFG), zero1=True)
+        dk.LoRATrainer(CFG, base_params=toy_params(CFG), zero1=True)
     with pytest.raises(ValueError, match="zero1_bucket_mb"):
         dk.ADAG(make_mlp(), zero1_bucket_mb=8.0)
     with pytest.raises(ValueError, match="zero1_bucket_mb"):

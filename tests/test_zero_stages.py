@@ -22,6 +22,7 @@ from distkeras_tpu.parallel import rules as pr
 from distkeras_tpu.parallel.mesh import MeshSpec, make_mesh
 from distkeras_tpu.resilience import FaultPlan, Supervisor
 from jax.sharding import NamedSharding, PartitionSpec as P
+from helpers import toy_params
 
 
 CFG = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
@@ -401,7 +402,7 @@ def test_lm_codec_rules_wire_geometry():
     from distkeras_tpu.parallel import exchange as ex
 
     params = jax.eval_shape(
-        lambda: tfm.init_params(jax.random.key(0), CFG))
+        lambda: toy_params(CFG))
     n = 8
     rules_cfg = ex.ExchangeConfig(compress=(("emb", "topk"),
                                             (".*", "int8")))
@@ -438,8 +439,7 @@ def test_zero_flag_wiring_and_rejections(devices, blobs):
     with pytest.raises(ValueError, match="zero"):
         dk.AEASGD(make_mlp(), zero=2)
     with pytest.raises(ValueError, match="zero"):
-        dk.LoRATrainer(CFG, base_params=tfm.init_params(
-            jax.random.key(0), CFG), zero=3)
+        dk.LoRATrainer(CFG, base_params=toy_params(CFG), zero=3)
     with pytest.raises(ValueError, match="zero_bucket_mb"):
         dk.ADAG(make_mlp(), zero_bucket_mb=8.0)
     with pytest.raises(ValueError, match="only one of zero_bucket_mb"):
