@@ -12,9 +12,11 @@ A trace JSONL (obs/trace.py) reconstructs into:
 * **counters/gauges** — the remaining metrics series;
 * **event timeline** — point events in time order (chaos faults,
   supervisor attempts, admission rejects...);
-* **serving rounds** — the ``serving.round`` spans' counts, and the
-  host's time between one decode dispatch's end and the next
-  dispatch, split by the span it was spent in.
+* **serving rounds** — the ``serving.round`` spans' counts, the share
+  of decoding rounds dispatched while the round before was still
+  unread with the median wait for its tokens, and the host's time
+  between one decode dispatch's end and the next dispatch, split by
+  the span it was spent in.
 
 ``compare`` diffs two reports for regression triage: per-phase total /
 mean deltas, histogram percentile deltas, counter deltas — the dynamic
@@ -117,9 +119,11 @@ def load_report(path: str, max_len: int | None = None) -> dict:
 
 # ------------------------------------------------------ serving rounds
 
-# A span that hands the device a program; between the end of a round's
-# ``serving.step`` (tokens on the host) and the start of the next of
-# these the device has nothing queued.
+# A span that hands the device a program.  From the end of a round's
+# ``serving.step`` to the start of the next of these is the host's time
+# between two dispatches: the device runs that round's programs
+# meanwhile where the round is ``overlapped``, and has nothing queued
+# where ``serving.step`` still ended at the read (an older trace).
 _DISPATCH = ("serving.admit", "serving.admit_chunk", "serving.step")
 
 
@@ -149,7 +153,12 @@ def serving_rounds(records: list[dict],
     span — as a median with its mean split by whose self time it was:
     ``emit_loop``, ``reap``, ``pump``, ``round`` (inside a round but in
     none of its children) and ``caller`` (in no span: between two
-    ``step()`` calls).  ``attended``: the cache positions the admission
+    ``step()`` calls).  ``overlap`` (traces with ``serving.collect``):
+    how many decoding rounds dispatched a step, the share of them
+    ``overlapped`` (dispatched while the round before was unread), and
+    the median ``wait_ms`` of the reads — near the device's round time
+    the device sets the pace, near zero the host does.
+    ``attended``: the cache positions the admission
     programs' attention read (the field of that name on the admission
     spans) — how many programs, their mean, and with ``max_len`` (the
     engine's slots a lane, which no record carries) the share of the
@@ -173,6 +182,18 @@ def serving_rounds(records: list[dict],
                               "chunks", "tokens")} if live else {},
            "chunks_max": max((r["fields"]["chunks"] for r in live),
                              default=0)}
+    waits = [sp["fields"]["wait_ms"] for sp in spans
+             if sp["name"] == "serving.collect"]
+    if waits:
+        stepped = {sp["parent"] for sp in spans
+                   if sp["name"] == "serving.step"}
+        dispatched = [r for r in live if r["id"] in stepped]
+        out["overlap"] = {
+            "dispatched": len(dispatched),
+            "share": (sum(bool(r["fields"].get("overlapped"))
+                          for r in dispatched) / len(dispatched)
+                      if dispatched else 0.0),
+            "wait_p50_ms": statistics.median(waits)}
     attended = [sp["fields"]["attended"] for sp in spans
                 if sp["name"] in _ADMIT and "attended" in sp["fields"]]
     if attended:
@@ -559,6 +580,13 @@ def render_report(rep: dict, max_events: int = 60) -> str:
             out.append(f"  attended: {att['steps']} decode steps read "
                        f"{att['mean']:.6g} cache slots each, "
                        f"{att['live_share']:.1%} of them live")
+        ov = rounds.get("overlap")
+        if ov:
+            out.append(
+                f"  overlap: {ov['share']:.1%} of {ov['dispatched']} "
+                f"decode dispatches went out with the round before "
+                f"unread; wait for a round's tokens p50="
+                f"{ov['wait_p50_ms']:.3g}ms")
         gap = rounds.get("gap")
         if gap:
             out.append(

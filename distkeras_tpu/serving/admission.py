@@ -70,6 +70,9 @@ class _AdmissionMixin:
         # Chunked-prefill scheduler state: lanes with pending admission
         # chunks, FIFO (see engine._run_pending_chunk).
         self._admitting = collections.deque()
+        # ``(_Lane, tokens)`` a ``_flush_round`` read outside a
+        # ``step()`` result; the next ``step()`` returns them.
+        self._flushed = []
         # Elastic-tier bookkeeping (ContinuousBatcher(lane_tiers=...);
         # inert defaults for every other engine).
         self.lane_tiers = None
@@ -535,6 +538,12 @@ class _AdmissionMixin:
                 # THIS step's reap get their pump on the next
                 # iteration, which must run.)
                 break
+        # A round may still be in flight (``max_steps`` tripped, or
+        # its lanes ended a round earlier): its tokens belong to the
+        # transcripts, and a request they finish is "ok", not
+        # "cancelled".
+        if self._flush_round():
+            self._reap()
         for pend in self._pending:
             self._finish(pend.request_id, pend.prompt, "cancelled",
                          pend.prompt.size, born=pend.born)

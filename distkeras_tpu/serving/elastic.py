@@ -80,6 +80,9 @@ class _ElasticMixin:
         contract as lane reuse).  Strictly host-plus-precompiled work:
         no compile, ever (pinned by ``scripts/check_compile_counts.py``'s
         elastic session)."""
+        # The round in flight names lanes by the numbers they have
+        # now: read it before they change.
+        self._flush_round()
         old = self.lanes
         keep = [i for i, s in enumerate(self._lane_state)
                 if s is not None]

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import time
 
 import jax
 import jax.numpy as jnp
@@ -59,6 +60,11 @@ class _Lane:
     # Engine-clock time of the lane's previous emission (TTFT/TPOT
     # telemetry; None until the first token lands).
     last_emit: float | None = None
+    # Decode steps DISPATCHED for this request, read or not: with one
+    # round in flight the transcript lags the device by that round, so
+    # what asks where the lane stands on the device before a dispatch
+    # (its budget, its written slots) asks this and not ``tokens``.
+    launched: int = 0
 
 
 def _make_lane_admit(model_params, model_cfg, prefix_lane=None,
@@ -216,6 +222,12 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
     # reports it as the round's ``chunks`` and zeroes it there.
     _admit_programs = 0
 
+    # The decode round dispatched and not yet read: ``(tokens on the
+    # device, n, [(lane, _Lane), ...] decoding at the dispatch)``, or
+    # None.  ``ContinuousBatcher.step()`` reads it in the call AFTER
+    # the one that dispatched it.
+    _inflight = None
+
     def _attended(self, cache, start: int, width: int) -> int:
         """Cache positions the attention of an admission program reads
         — the ``attended`` field of ``serving.admit`` and
@@ -239,7 +251,8 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
         lane is counted as parked at ``max_len - 1``, its whole row (a
         done lane gets there a step at a time: an upper bound).  On
         the dense path every lane reads ``max_len`` slots.  Host
-        integers from the lane table; no device read."""
+        integers from the lane table (the steps dispatched so far, so
+        an unread round counts); no device read."""
         cap = self.cfg.max_len
         sharded = self.mesh is not None and self.mesh.size > 1
         if not decode_attends_prefix(self.cfg, 1, self.cache,
@@ -251,7 +264,7 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
             if st is None or st.done or st.chunks is not None:
                 total += n * cap
                 continue
-            pos = st.off + len(st.tokens) - 1
+            pos = st.off + st.prompt_len - 1 + st.launched
             total += sum(min(-(-(pos + j) // unit) * unit, cap)
                          for j in range(n))
         return total
@@ -493,11 +506,15 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
                 f"max_new_tokens must be >= 1, got {max_new_tokens}")
         return prompt
 
-    def _emit(self, lane_tokens):
+    def _emit(self, lane_tokens, lanes=None):
         """Feed each live lane's new tokens (``lane_tokens(lane)``)
         through the transcript/budget/eos bookkeeping; returns the
-        ``{lane: [emitted...]}`` step result.  The ONE site that
-        counts emitted tokens (``serving.tokens``) — every step path
+        ``{lane: [emitted...]}`` step result.  ``lanes``: the
+        ``(lane, _Lane)`` pairs the tokens were decoded FOR (a round
+        read after its dispatch names them; default: the lane table as
+        it stands) — a lane that has since been vacated, or vacated and
+        given to another request, gets nothing of the row.  The ONE
+        site that counts emitted tokens (``serving.tokens``) — every step path
         funnels through here, so the throughput metric is
         structurally complete, and so are the per-request latency
         signals it derives: ``serving.ttft_s`` (born -> first token,
@@ -513,8 +530,11 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
         with obs.span("serving.emit_loop"):
             active = obs.active() is not None
             now = self._clock() if active else None
-            for lane, st in enumerate(self._lane_state):
-                if st is None or st.done or st.chunks is not None:
+            if lanes is None:
+                lanes = enumerate(self._lane_state)
+            for lane, st in lanes:
+                if (st is None or st is not self._lane_state[lane]
+                        or st.done or st.chunks is not None):
                     continue
                 emitted = []
                 for tok in lane_tokens(lane):
@@ -541,17 +561,54 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
                           sum(len(v) for v in out.values()))
         return out
 
-    def _close_round(self, rnd, out, chunks: int, idle: bool) -> None:
+    def _read_tokens(self, toks) -> np.ndarray:
+        """The second of a decode round's two hooks (the first is
+        ``_dispatch_step``): the round's ``[lanes, n]`` tokens on the
+        host; blocks until the device has finished the round."""
+        return np.asarray(toks)
+
+    def _collect(self, pending) -> dict:
+        """Read a dispatched round and emit it: ``pending`` is the
+        record ``step()`` kept at the dispatch.  The read is one
+        ``serving.collect`` span whose ``wait_ms`` is the time blocked
+        in it — near the device's round time where the device sets the
+        pace, near zero where the host does."""
+        dev, n, lanes = pending
+        with obs.span("serving.collect", n=n) as sp:
+            t0 = time.perf_counter()
+            toks = self._read_tokens(dev)
+            if sp is not None:
+                sp.fields["wait_ms"] = (time.perf_counter() - t0) * 1e3
+        return self._emit(lambda lane: toks[lane].tolist(), lanes)
+
+    def _flush_round(self) -> bool:
+        """Read the round in flight NOW, where something is about to
+        move lanes under it (a resize renumbers them) or to stop
+        calling ``step()`` (shutdown): its tokens join the transcripts
+        here and ride out with the next ``step()``'s result, found
+        again by ``_Lane`` identity.  Returns whether there was one."""
+        pending, self._inflight = self._inflight, None
+        if pending is None:
+            return False
+        out = self._collect(pending)
+        self._flushed += [(self._lane_state[lane], toks)
+                          for lane, toks in out.items()]
+        return True
+
+    def _close_round(self, rnd, out, chunks: int, idle: bool,
+                     overlapped: bool = False) -> None:
         """The counts of one ``step()``, taken where the round ends
         (session active only): ONE pass over the lane table, set as
         the ``serving.lanes_busy`` gauge and written into the closing
         ``serving.round`` span ``rnd`` (None without a trace file).
 
-        ``kv_live`` is positions written and live: a decoding lane
-        holds its prefix and its transcript but for the last token
-        (the next step's input), an admitting lane what lies before
-        its next chunk.  ``chunks`` is the admission programs
-        dispatched since the previous decode dispatch."""
+        ``kv_live`` is positions written and live once the steps
+        dispatched so far have run: a decoding lane holds its prefix,
+        its prompt but for the last token and one slot for every step
+        dispatched for it, up to its budget (the round in flight
+        counts: the count is the dispatch's, not the transcript's), an
+        admitting lane what lies before its next chunk.  ``chunks`` is the admission
+        programs dispatched since the previous decode dispatch."""
         busy = admitting = kv_live = 0
         for st in self._lane_state:
             if st is None or st.done:
@@ -561,7 +618,8 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
                 admitting += 1
                 kv_live += st.chunks[0][0]
             else:
-                kv_live += st.off + len(st.tokens) - 1
+                kv_live += (st.off + st.prompt_len - 1
+                            + min(st.launched, st.max_new))
         obs.gauge("serving.lanes_busy", busy)
         if rnd is not None:
             rnd.fields.update(
@@ -571,6 +629,8 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
                 passes=self.cfg.n_passes)
             if idle:
                 rnd.fields["idle"] = True
+            if overlapped:
+                rnd.fields["overlapped"] = True
 
     # --------------------------------------------- chunked admission
 

@@ -1242,12 +1242,35 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
                 fn=self._admit, args=admit_args, donate_argnums=(d,)),
         ]
 
+    # An engine whose next dispatch needs nothing of the last round's
+    # tokens keeps one round in flight.  The paged engine's does (page
+    # tables grow from the transcript) and reads each round at once.
+    _overlap = True
+
     def step(self, n: int = 1):
-        """Advance every lane ``n`` tokens in ONE device round-trip;
+        """Advance every lane ``n`` tokens in ONE device round;
         returns ``{lane: [tokens...]}`` for lanes that emitted.
 
-        ``n > 1`` amortizes the per-dispatch host latency (its floor has
-        not been re-measured on a directly attached chip) at the cost
+        **One decode round stays in flight.**  A call dispatches its
+        round and then reads the round the call BEFORE dispatched, so
+        while the host blocks in that read, walks its tokens, returns
+        to its caller and comes back, the device already holds the next
+        programs: nothing the next dispatch needs is on the host (the
+        current tokens, positions and cache are device arrays, a
+        budget is a host count of steps dispatched).  For a caller: a
+        token is returned by the call AFTER the one that dispatched it
+        — a request's first token comes one round later, a finished
+        lane is freed one round later — and a call with nothing left to
+        dispatch returns the round still unread.  Every returned token
+        exists on the host; ``running()``, ``free_lanes()``, ``take``,
+        ``drain``, ``partial`` and ``results`` speak of what has been
+        RETURNED.  A lane that ended (eos, budget, deadline) while a
+        further round of it was in flight has that round's row
+        dropped, as ``n > 1`` drops a window's surplus.  (The paged
+        engine, whose dispatch grows page tables from the transcript,
+        reads each round at once: ``_overlap``.)
+
+        ``n > 1`` amortizes the per-dispatch host latency at the cost
         of admission granularity: new requests wait for the window to
         finish, and a lane that hits its eos/budget mid-window keeps
         decoding privately — the surplus tokens are discarded here,
@@ -1264,14 +1287,16 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
 
         With a telemetry session active the call is one
         ``serving.round`` span — children ``serving.pump``,
-        ``serving.admit`` / ``serving.admit_chunk``, ``serving.step``,
-        ``serving.emit_loop``, ``serving.reap`` — closed with the
-        round's counts (:meth:`_close_round`; docs/observability.md).
+        ``serving.admit`` / ``serving.admit_chunk``, ``serving.step``
+        (the dispatch), ``serving.collect`` (the read of the round
+        before), ``serving.emit_loop``, ``serving.reap`` — closed with
+        the round's counts (:meth:`_close_round`;
+        docs/observability.md).
 
         Runs under the engine lock end to end: a concurrent
         ``enqueue`` can trigger a tier resize (scale-up), and the
         device state this step captures must not be swapped and
-        compacted under it mid-round-trip.
+        compacted under it mid-dispatch.
         """
         if n < 1:
             raise ValueError(f"n must be >= 1, got {n}")
@@ -1287,43 +1312,70 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
             # elastic engine must still step its lane count back down.
             self._maybe_scale_down()
             self._run_pending_chunk()
-            # Idle engine (every lane empty, finished-but-undrained,
-            # or still admitting): nothing can emit, so skip the
-            # device round-trip entirely instead of burning a full
-            # decode window.  Reap first: a parked (admitting) lane
-            # whose deadline expired must still be evicted promptly,
-            # not only once decode resumes.
-            idle = all(s is None or s.done or s.chunks is not None
-                       for s in self._lane_state)
+            # Lanes with a token still to decode: not empty, finished,
+            # admitting, or with their whole budget already dispatched.
+            lanes = [(i, s) for i, s in enumerate(self._lane_state)
+                     if s is not None and not s.done and s.chunks is None
+                     and s.launched < s.max_new]
+            # ``_inflight`` is rebound only after the dispatch: one that
+            # raises keeps the unread round for the next call.
+            unread, launched = self._inflight, None
             chunks = self._admit_programs
-            if idle:
-                out = {}
-            else:
+            if lanes:
                 chaos.probe("serving.step")
                 self._admit_programs = 0
                 with obs.span("serving.step", n=n,
                               attended=self._step_attended(n)):
                     toks = self._dispatch_step(n)
-                out = self._emit(lambda lane: toks[lane].tolist())
+                    toks.copy_to_host_async()
+                for _, s in lanes:
+                    s.launched += n
+                launched = (toks, n, lanes)
+                if not self._overlap:
+                    unread, launched = launched, None
+            self._inflight = launched
+            # Nothing to dispatch and nothing read (every lane empty,
+            # finished-but-undrained, or still admitting): nothing can
+            # emit.  Reap all the same: a parked (admitting) lane whose
+            # deadline expired must still be evicted promptly, not only
+            # once decode resumes.
+            idle = not (lanes or unread or self._flushed)
+            out = {} if unread is None else self._collect(unread)
+            if self._flushed:
+                self._return_flushed(out)
             # Deadline granularity is one step window: tokens emitted
             # in the window that straddles the deadline are kept in
             # the partial result.
             self._reap()
             if obs.active() is not None:
-                self._close_round(rnd, out, chunks, idle)
+                self._close_round(rnd, out, chunks, idle,
+                                  overlapped=launched is not None
+                                  and unread is not None)
             return out
 
+    def _return_flushed(self, out: dict) -> None:
+        """Add to ``out`` the tokens a :meth:`_flush_round` read between
+        two ``step()`` results, under the lanes their requests hold NOW
+        (a resize may have moved them).  A flush leaves no round
+        unread, so the call that returns them has read none itself."""
+        at = {id(s): i for i, s in enumerate(self._lane_state)}
+        out.update((at[id(st)], toks) for st, toks in self._flushed
+                   if id(st) in at)
+        self._flushed = []
+
     def _dispatch_step(self, n: int):
-        """ONE device round-trip of the ``n``-token decode window over
-        the engine's storage; returns the emitted-token matrix
-        ``[lanes, n]`` (host numpy).  The paged engine overrides this
-        to grow page tables first and thread them through its step."""
+        """DISPATCH the ``n``-token decode window over the engine's
+        storage — the first of a round's two hooks; returns the
+        emitted-token matrix ``[lanes, n]`` still on the device
+        (``_read_tokens`` is the read).  The paged engine overrides
+        this to grow page tables first and thread them through its
+        step."""
         if n not in self._steps:
             self._steps[n] = self._make_step(n)
         self.cache, self.cur, self.pos, toks = self._steps[n](
             *self._pargs(), self.cache, self.cur, self.pos, self.keys,
             self.temps, self.tps, self.mps)
-        return np.asarray(toks)
+        return toks
 
 
 __all__ = ["ContinuousBatcher", "KV_INT8_LANE_ADVISORY"]

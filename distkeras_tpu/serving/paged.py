@@ -793,6 +793,11 @@ class PagedBatcher(ContinuousBatcher):
 
     # -------------------------------------------------- decode growth
 
+    # The page tables grow from the transcript's length and a lane the
+    # allocator cannot grow is evicted: the next dispatch DOES need the
+    # last round's tokens, so each round is read at once.
+    _overlap = False
+
     def _dispatch_step(self, n: int):
         self._ensure_growth(n)
         if n not in self._steps:
@@ -800,7 +805,7 @@ class PagedBatcher(ContinuousBatcher):
         self.cache, self.cur, self.pos, toks = self._steps[n](
             self.cache, self.tables, self.cur, self.pos, self.keys,
             self.temps, self.tps, self.mps)
-        return np.asarray(toks)
+        return toks
 
     def _ensure_growth(self, n: int) -> None:
         """Allocate the blocks this window's writes need, per live
@@ -962,7 +967,8 @@ class PagedBatcher(ContinuousBatcher):
                 max_new=st.max_new,
                 key=key if key is not None else st.key,
                 tokens=st.tokens[:-1] + [token], eos=st.eos,
-                deadline=st.deadline, born=self._clock(), off=st.off)
+                deadline=st.deadline, born=self._clock(), off=st.off,
+                launched=st.launched)
             self.last_request_id = rid
             obs.count("serving.cow_forks")
             obs.event("serving.fork", src=lane, dst=dst,

@@ -198,6 +198,9 @@ def test_disabled_serving_round_is_free(tmp_path, monkeypatch):
     eng = dk.ContinuousBatcher(params, CFG, lanes=2, max_queue=2,
                                prompt_buckets=(8,))
     rid = eng.enqueue(np.arange(5), 3)
+    # A token comes back from the call after its dispatch: the first
+    # call returns none, the fourth has nothing left to dispatch.
+    assert eng.step() == {}
     assert eng.step() and eng.step() and eng.step()
     assert eng.take(rid).ok and eng.step() == {}
     # The counter behind the round's ``chunks`` still follows the

@@ -26,14 +26,16 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CFG = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
                             n_layers=2, d_ff=64, max_len=64)
 ROUND_CHILDREN = ("serving.pump", "serving.admit_chunk", "serving.step",
-                  "serving.emit_loop", "serving.reap")
+                  "serving.collect", "serving.emit_loop", "serving.reap")
 
 
 @pytest.fixture(scope="module")
 def rounds(tmp_path_factory):
     """A scripted run: B (5 tokens) decodes while A (21 tokens, three
     chunks of 8) is admitted and prefilled between B's decode steps.
-    Returns the ``serving.round`` spans in order and every span."""
+    A call dispatches its decode round and reads the one before, so a
+    round's ``tokens`` are the previous dispatch's.  Returns the
+    ``serving.round`` spans in order and every span."""
     path = str(tmp_path_factory.mktemp("rounds") / "t.jsonl")
     params = toy_params(CFG)
     with obs.session(trace_path=path):
@@ -41,11 +43,12 @@ def rounds(tmp_path_factory):
                                    prefill_chunk=8, prompt_buckets=(8,))
         eng.step()                          # 0: nothing to do
         eng.enqueue(np.arange(5), 8)        # B: one admission program
-        eng.step()                          # 1: B's first token
+        eng.step()                          # 1: B's first step goes out
         eng.enqueue(np.arange(21), 4)       # A: first chunk [0, 8)
-        eng.step()                          # 2: A's chunk [8, 16), B decodes
+        eng.step()                          # 2: A's chunk [8, 16), B decodes;
+        #                                        B's first token comes back
         eng.step()                          # 3: A's chunk [12, 20), both decode
-        eng.step()                          # 4
+        eng.step()                          # 4: both tokens of round 3
     spans = [r for r in read_trace(path) if r["kind"] == "span"]
     return [s for s in spans if s["name"] == "serving.round"], spans
 
@@ -53,8 +56,8 @@ def rounds(tmp_path_factory):
 @pytest.mark.parametrize("child", ROUND_CHILDREN)
 def test_round_is_parent_of(rounds, child):
     """Round 2 runs every boundary: pump, a continuation chunk, the
-    decode dispatch, the emit loop, the reap — each a child of the
-    round, inside its interval."""
+    decode dispatch, the read of the round before, the emit loop, the
+    reap — each a child of the round, inside its interval."""
     rnds, spans = rounds
     rnd = rnds[2]
     (sp,) = [s for s in spans
@@ -86,20 +89,22 @@ def test_idle_round_is_marked_and_dispatches_nothing(rounds):
 
 
 @pytest.mark.parametrize("i,want", [
-    # B holds its 5 prompt tokens (the 6th token of its transcript is
-    # the next step's input, not yet in the cache).
+    # B holds its 5 prompt tokens once the step dispatched here has
+    # run (the token that step decodes is the next step's input, not
+    # yet in the cache); the step is unread, so nothing is emitted.
     (1, {"lanes_busy": 1, "lanes_admitting": 0, "kv_live": 5,
-         "chunks": 1, "tokens": 1}),
+         "chunks": 1, "tokens": 0}),
     # The round PERF.md describes: A's first chunk ran at admission and
     # a continuation chunk in this step(), so TWO admission programs
     # stand between two decode dispatches.  A is mid-prefill: positions
-    # [0, 12) lie before its next chunk.
+    # [0, 12) lie before its next chunk.  The token is round 1's.
     (2, {"lanes_busy": 2, "lanes_admitting": 1, "kv_live": 6 + 12,
          "chunks": 2, "tokens": 1}),
     # A's last chunk landed and it joined this decode: its 21 prompt
-    # tokens are in, its first token is the next input.
+    # tokens are in, its first token is the next input — and comes back
+    # a call later: the token emitted here is B's, of round 2.
     (3, {"lanes_busy": 2, "lanes_admitting": 0, "kv_live": 7 + 21,
-         "chunks": 1, "tokens": 2}),
+         "chunks": 1, "tokens": 1}),
     (4, {"lanes_busy": 2, "lanes_admitting": 0, "kv_live": 8 + 22,
          "chunks": 0, "tokens": 2}),
 ])
@@ -453,3 +458,35 @@ def test_report_sets_the_decode_steps_attended_against_kv_live(attended,
     for r in recs:
         r["fields"].pop("attended", None)
     assert "attended_step" not in serving_rounds(recs)
+
+
+def test_report_prints_the_overlapped_share_and_the_median_wait():
+    """Three decoding rounds by hand: the first dispatches with nothing
+    unread, the next two with the round before in flight, a fourth only
+    reads.  Two of three dispatches overlapped; the waits' median."""
+    from distkeras_tpu.obs.report import (build_report, render_report,
+                                          serving_rounds)
+
+    counts = dict(lanes_busy=1, lanes_admitting=0, kv_live=5, chunks=0)
+    recs = [
+        _span("serving.round", 0, 2, 1, tokens=0, **counts),
+        _span("serving.step", 0.5, 1, 2, 1, n=1),
+        _span("serving.round", 3, 8, 3, tokens=1, overlapped=True, **counts),
+        _span("serving.step", 3.5, 1, 4, 3, n=1),
+        _span("serving.collect", 5, 5, 5, 3, n=1, wait_ms=5.0),
+        _span("serving.round", 12, 8, 6, tokens=1, overlapped=True,
+              **counts),
+        _span("serving.step", 12.5, 1, 7, 6, n=1),
+        _span("serving.collect", 14, 5, 8, 6, n=1, wait_ms=7.0),
+        _span("serving.round", 21, 2, 9, tokens=1, **counts),
+        _span("serving.collect", 21.5, 1, 10, 9, n=1, wait_ms=0.25),
+    ]
+    ov = serving_rounds(recs)["overlap"]
+    assert ov == {"dispatched": 3, "share": pytest.approx(2 / 3),
+                  "wait_p50_ms": 5.0}
+    assert ("overlap: 66.7% of 3 decode dispatches went out with the "
+            "round before unread; wait for a round's tokens p50=5ms"
+            ) in render_report(build_report(recs))
+    # A trace from before ``serving.collect`` reports none.
+    old = [r for r in recs if r["name"] != "serving.collect"]
+    assert "overlap" not in serving_rounds(old)
