@@ -34,6 +34,8 @@ from distkeras_tpu.models.transformer import (
     ffn_apply,
     final_norm,
     head_table,
+    layer_rotates,
+    moe_ffn,
     reject_extended,
     rope_angles,
     rope_rotate,
@@ -58,6 +60,14 @@ from distkeras_tpu.ops.attention import (
 )
 
 
+# Slots a ring plane is allocated past its ``sliding_window``: one is
+# needed (the parking slot); sixteen keep the slots a whole number of
+# bf16 sublane tiles — with 129 slots the TPU's default layout put the
+# slots outside the K/V heads and every decode step copied both ring
+# slabs into the order it reads them in (1.3 ms of 16.3; chip, PR 31).
+RING_PARK = 16
+
+
 def init_cache(cfg: TransformerConfig, batch: int, dtype=None,
                kv_int8: bool = False):
     """Per-layer KV buffers [L, B, max_len, kv_heads, head_dim] — one
@@ -78,6 +88,22 @@ def init_cache(cfg: TransformerConfig, batch: int, dtype=None,
     """
     if kv_int8:
         reject_extended(cfg, "the int8 KV cache (kv_int8)")
+    if cfg.typed:
+        # Two kinds of plane side by side, both K/V-HEAD-MAJOR (a head's
+        # slots are one [S, head_dim] matrix: what the bounded kernels
+        # tile whatever the number of K/V heads): ``k``/``v`` one plane
+        # of max_len slots a full layer, ``k_win``/``v_win`` one ring a
+        # window layer, of ``sliding_window`` slots (position p at
+        # slot p % sliding_window) and RING_PARK slots after them, the
+        # first of which is where a parked lane's decode step writes.
+        dtype = dtype or jnp.dtype(cfg.dtype)
+        full = (cfg.kv_planes, batch, cfg.kv_heads, cfg.max_len,
+                cfg.head_dim)
+        ring = (cfg.kv_ring_planes, batch, cfg.kv_heads,
+                cfg.sliding_window + RING_PARK, cfg.head_dim)
+        return {"k": jnp.zeros(full, dtype), "v": jnp.zeros(full, dtype),
+                "k_win": jnp.zeros(ring, dtype),
+                "v_win": jnp.zeros(ring, dtype)}
     dtype = jnp.int8 if kv_int8 else (dtype or jnp.dtype(cfg.dtype))
     shape = (cfg.kv_planes, batch, cfg.max_len, cfg.kv_heads, cfg.head_dim)
     cache = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
@@ -117,10 +143,10 @@ def prefill(params, prompt, cfg: TransformerConfig,
         raise ValueError(
             f"prompt length {p_len} exceeds max_len={cfg.max_len} "
             "(the KV cache size)")
-    if cfg.n_passes > 1:
+    if cfg.n_passes > 1 or cfg.typed:
         # A looped stack has ONE cached body, whose pass loop is one
-        # traced body: the prompt is a chunk at position 0 against an
-        # empty cache.
+        # traced body (a typed stack: whose layer knows its kinds):
+        # the prompt is a chunk at position 0 against an empty cache.
         logits, cache = _decode_chunk(
             params, init_cache(cfg, b, kv_int8=kv_int8), prompt,
             jnp.zeros((b,), jnp.int32), cfg, uniform_pos=True)
@@ -466,6 +492,7 @@ def chunk_attends_prefix(cfg: TransformerConfig, t_len: int, cache,
     admission spans' ``attended`` field."""
     return (uniform_pos and t_len > 1 and not beam
             and cfg.attention_window is None and "k_scale" not in cache
+            and bool(cfg.kv_planes)
             and use_flash_prefix(t_len, cfg.max_len, cfg.head_dim,
                                  cfg.n_heads // cfg.kv_heads,
                                  cache["k"].dtype, sharded=sharded))
@@ -489,30 +516,57 @@ def decode_attends_prefix(cfg: TransformerConfig, t_len: int, cache,
     ``attended``."""
     return (not (uniform_pos and t_len > 1)
             and cfg.attention_window is None and "k_scale" not in cache
+            and bool(cfg.kv_planes)
             and use_flash_decode(t_len, cfg.max_len, cfg.head_dim,
-                                 cfg.n_heads, cfg.kv_heads,
+                                 *_decode_heads(cfg),
                                  cache["k"].dtype, sharded=sharded))
+
+
+def _decode_heads(cfg: TransformerConfig) -> tuple[int, int]:
+    """``(query heads, K/V heads)`` of one row of the per-lane kernel:
+    the model's, or — a typed stack's K/V-head-major planes — one K/V
+    head a row with the query heads that share it (the kernel then
+    sees lanes x K/V heads rows of one head each, which it tiles
+    whatever their number)."""
+    if cfg.typed:
+        return cfg.n_heads // cfg.kv_heads, 1
+    return cfg.n_heads, cfg.kv_heads
 
 
 def decode_read_unit(cfg: TransformerConfig, t_len: int, cache) -> int:
     """Cache slots the smallest copy of the per-lane bounded attention
     brings in (a part of a lane's last block): a lane at position ``p``
     reads ``p`` rounded up to it."""
-    return decode_block(t_len, cfg.max_len, cfg.head_dim, cfg.n_heads,
-                        cfg.kv_heads, cache["k"].dtype) // DECODE_TAIL_PARTS
+    return decode_block(t_len, cfg.max_len, cfg.head_dim,
+                        *_decode_heads(cfg),
+                        cache["k"].dtype) // DECODE_TAIL_PARTS
 
 
-def _attend_before_and_chunk(q, k, v, ck_all, cv_all, plane, pos0):
+def _attend_before_and_chunk(q, k, v, ck_all, cv_all, plane, pos0,
+                             head_major: bool = False):
     """Attention of ``q [B, T, H, hd]`` over plane ``plane``'s slots
     strictly before ``pos0[b]`` (the kernel, straight from the slab)
     and over the chunk's own ``k``/``v [B, T, kv, hd]`` under their
     causal triangle, as ONE softmax: the two terms are merged by their
-    log-sum-exps, ``[B, T, H]`` of elementwise work.  Float32."""
+    log-sum-exps, ``[B, T, H]`` of elementwise work.  Float32.
+    ``head_major``: the slab is ``[P, B, kv, S, hd]`` (a typed stack's)
+    and the kernel is handed its free view ``[P, B * kv, S, 1, hd]``:
+    a row a (lane, K/V head), of the query heads that share the head."""
     b, t_len, h, d = q.shape
     kv = k.shape[2]
-    old, lse = flash_decode_attention(q, ck_all, cv_all, plane, pos0)
-    old = old.reshape(b, t_len, kv, h // kv, d)
-    lse = lse.reshape(b, t_len, kv, h // kv)
+    if head_major:
+        planes, _, _, s_len, _ = ck_all.shape
+        rows = lambda a: a.reshape(planes, b * kv, s_len, 1, d)
+        old, lse = flash_decode_attention(
+            q.reshape(b, t_len, kv, h // kv, d).transpose(0, 2, 1, 3, 4)
+            .reshape(b * kv, t_len, h // kv, d),
+            rows(ck_all), rows(cv_all), plane, jnp.repeat(pos0, kv))
+        old = old.reshape(b, kv, t_len, h // kv, d).transpose(0, 2, 1, 3, 4)
+        lse = lse.reshape(b, kv, t_len, h // kv).transpose(0, 2, 1, 3)
+    else:
+        old, lse = flash_decode_attention(q, ck_all, cv_all, plane, pos0)
+        old = old.reshape(b, t_len, kv, h // kv, d)
+        lse = lse.reshape(b, t_len, kv, h // kv)
     qg = q.astype(jnp.float32).reshape(old.shape)
     new = jnp.einsum("btcgk,buck->btcgu", qg,
                      k.astype(jnp.float32)) / jnp.sqrt(jnp.float32(d))
@@ -537,8 +591,8 @@ def base_body_only(cfg: TransformerConfig, params, cache,
     weights, a cache the compiler partitions."""
     if cfg.attention_window is not None:
         return "a windowed (rolling) decode"
-    if cfg.num_experts:
-        return "a MoE feed-forward (num_experts > 0)"
+    if cfg.num_experts and not cfg.typed:
+        return "a MoE feed-forward (num_experts > 0) without ffn_types"
     if beam:
         return "beam search"
     if "k_scale" in cache:
@@ -551,7 +605,8 @@ def base_body_only(cfg: TransformerConfig, params, cache,
 
 
 def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
-                    uniform_pos: bool = False, lane=None):
+                    uniform_pos: bool = False, lane=None, n_real=None,
+                    with_routes: bool = False):
     """:func:`_decode_chunk`'s body for every call that
     :func:`base_body_only` does not hold back: ``tokens [B, T]`` at
     positions ``pos0[b] + (0..T-1)`` -> ``(logits [B, T, V] f32, cache)``
@@ -593,14 +648,46 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
     kernel is handed the slab and the plane's index, reads of row ``b``
     only the blocks before ``pos0[b]``, and its result is merged with
     the chunk's own term (:func:`_attend_before_and_chunk`) — the dense
-    body's sum, without its read of the dead slots."""
+    body's sum, without its read of the dead slots.
+
+    **A typed stack** (``cfg.typed``: a layer is an (attention kind,
+    feed-forward kind) pair) runs the same layer body, one scan a run
+    of consecutive layers of one kind over that kind's group of the
+    parameter tree; its cache is ``init_cache``'s four K/V-head-major
+    leaves.  A FULL layer reads and writes its ``max_len``-slot plane
+    as above (the kernels handed the head-major planes).  A WINDOW
+    layer's plane is a ring: slot ``s`` holds the latest position
+    ``p = s (mod sliding_window)`` before the chunk, so the layer attends
+    the ring under the mask ``0 <= p`` and ``i - p < sliding_window``
+    — plain ``jax.numpy`` in slot order, the positions worked out from
+    ``pos0`` — together with the chunk's own K/V under their band,
+    one softmax; the chunk's last ``sliding_window`` REAL positions are
+    written modulo the ring at the end.  ``n_real`` (traced int32;
+    uniform chunks): how many of the chunk's tokens are real — an
+    admission's padding is not written into a ring, where it would
+    overwrite live positions (in a full plane it lands past the
+    frontier, masked until overwritten).  A row at position
+    ``max_len - 1`` is PARKED (free, done or admitting: no live row
+    ever stands there, since a request's last token is never fed
+    back): its decode step writes the ring's parking slot.  A sparse
+    layer is ``transformer.moe_ffn``; ``with_routes`` appends, to the
+    result, ``[sparse layers, B, T, k]``: each assignment's index among
+    the held experts (their number: an absent expert's)."""
     dtype = jnp.dtype(cfg.dtype)
     b, t_len = tokens.shape
     n_layers, s_len = cfg.n_layers, cfg.max_len
     groups = cfg.n_heads // cfg.kv_heads
     if lane is not None and (b != 1 or not uniform_pos):
         raise ValueError("lane= admits ONE row at one position")
-    ck_all, cv_all = cache["k"], cache["v"]      # [R*L, B|lanes, S, kv, hd]
+    typed = cfg.typed
+    if typed and t_len > 1 and not uniform_pos:
+        raise ValueError(
+            "a typed stack takes multi-token chunks at one position for "
+            "every row only (per-row chunks: speculative verification)")
+    # [R*L, B|lanes, S, kv, hd]; a typed stack's: [full layers, B|lanes,
+    # kv, S, hd] and the rings [window layers, B|lanes, kv, ring + park, hd].
+    ck_all, cv_all = cache["k"], cache["v"]
+    eps, pre_norm = cfg.norm_eps, cfg.post_norms != "only"
     with jax.named_scope("embed"):
         x = params["tok_emb"][tokens].astype(dtype)           # [B, T, D]
         pos_ids = pos0[:, None] + jnp.arange(t_len)[None, :]  # [B, T]
@@ -621,9 +708,113 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
               )[:, None, None, None, :]
     causal = jnp.tril(jnp.ones((t_len, t_len), bool))[None, :, None, None, :]
     scale = 1.0 / jnp.sqrt(jnp.float32(cfg.head_dim))
+    if typed:
+        rk_all, rv_all = cache["k_win"], cache["v_win"]
+        window = cfg.sliding_window
+        # Ring slot s holds position p_s, the latest one = s (mod window)
+        # before the chunk (negative: never written by this occupant);
+        # query i sees it iff 0 <= p_s and i - p_s < window.
+        # The plane is attended whole — a cut of its first ``window``
+        # slots would be a copy a layer — with the slots past them
+        # (parking) masked like one never written.
+        last = pos0[:, None] - 1
+        slot = jnp.arange(rk_all.shape[3])[None, :]
+        p_s = jnp.where(slot < window,
+                        last - jnp.mod(last - slot, window), -1)
+        in_ring = ((p_s[:, None, :] >= 0)
+                   & (pos_ids[:, :, None] - p_s[:, None, :] < window)
+                   )[:, :, None, None, :]       # [B, T, 1, 1, window + park]
+        t_ids = jnp.arange(t_len)
+        band = causal & (t_ids[:, None] - t_ids[None, :] < window
+                         )[None, :, None, None, :]
 
-    def layer(x, lp, plane):
-        h = _rms_norm(x, lp["ln1_scale"])
+    def plane_rows(a_all, plane):
+        """Plane ``plane``'s rows, for reading: of every row, or of
+        lane ``lane`` alone."""
+        if lane is not None:
+            return jax.lax.dynamic_slice(
+                a_all, (plane, lane) + (0,) * (a_all.ndim - 2),
+                (1, 1) + a_all.shape[2:])[0]
+        return jax.lax.dynamic_index_in_dim(a_all, plane, 0, keepdims=False)
+
+    def attend_plain(q, k, v, plane):
+        """An untyped stack's attention over its token-major plane
+        ``[B, S, kv, hd]`` and the chunk's own K/V."""
+        with jax.named_scope("kv_slab"):
+            # This plane's rows, for reading (the per-lane kernel takes
+            # the slab and the plane's index).
+            if lane is not None or not per_lane:
+                ck, cv = (plane_rows(a, plane) for a in (ck_all, cv_all))
+            if bounded:
+                at = (jnp.int32(0), pos0[0], jnp.int32(0), jnp.int32(0))
+                ck = jax.lax.dynamic_update_slice(ck, k, at)
+                cv = jax.lax.dynamic_update_slice(cv, v, at)
+        with jax.named_scope("attn"):
+            if per_lane:
+                return _attend_before_and_chunk(q, k, v, ck_all, cv_all,
+                                                plane, pos0)
+            if bounded:
+                return flash_prefix_attention(q.astype(ck.dtype), ck, cv,
+                                              pos0[0])
+            qg = q.astype(jnp.float32).reshape(
+                b, t_len, cfg.kv_heads, groups, cfg.head_dim)
+            old = jnp.einsum("btcgk,bsck->btcgs", qg,
+                             ck.astype(jnp.float32)) * scale
+            new = jnp.einsum("btcgk,buck->btcgu", qg,
+                             k.astype(jnp.float32)) * scale
+            probs = jax.nn.softmax(jnp.concatenate(
+                [jnp.where(before, old, -1e30),
+                 jnp.where(causal, new, -1e30)], axis=-1), axis=-1)
+            return (jnp.einsum("btcgs,bsck->btcgk", probs[..., :s_len],
+                               cv.astype(jnp.float32))
+                    + jnp.einsum("btcgu,buck->btcgk", probs[..., s_len:],
+                                 v.astype(jnp.float32))).reshape(
+                b, t_len, cfg.n_heads, cfg.head_dim)
+
+    def attend_typed(q, k, v, plane, kind):
+        """A typed stack's attention: the plane before the chunk and
+        the chunk's own K/V, one softmax (see the docstring)."""
+        if kind == "window":
+            with jax.named_scope("kv_slab"):
+                ck, cv = (plane_rows(a, plane) for a in (rk_all, rv_all))
+            keep_old, keep_new = in_ring, band
+        else:
+            keep_old, keep_new = before, causal
+            if per_lane:
+                return _attend_before_and_chunk(
+                    q, k, v, ck_all, cv_all, plane, pos0, head_major=True)
+            with jax.named_scope("kv_slab"):
+                ck, cv = (plane_rows(a, plane) for a in (ck_all, cv_all))
+                if bounded:
+                    at = (jnp.int32(0), jnp.int32(0), pos0[0], jnp.int32(0))
+                    ck = jax.lax.dynamic_update_slice(
+                        ck, k.transpose(0, 2, 1, 3), at)
+                    cv = jax.lax.dynamic_update_slice(
+                        cv, v.transpose(0, 2, 1, 3), at)
+            if bounded:
+                return flash_prefix_attention(q.astype(ck.dtype), ck, cv,
+                                              pos0[0], head_major=True)
+        # Operands in the cache's dtype, float32 accumulation and
+        # softmax, the logits scaled after the product: the kernels'
+        # arithmetic (a ring is read whole every step: no float32 copy).
+        f32 = dict(preferred_element_type=jnp.float32)
+        qg = q.astype(ck.dtype).reshape(
+            b, t_len, cfg.kv_heads, groups, cfg.head_dim)
+        old = jnp.einsum("btcgk,bcsk->btcgs", qg, ck, **f32) * scale
+        new = jnp.einsum("btcgk,buck->btcgu", qg, k, **f32) * scale
+        n_old = old.shape[-1]
+        probs = jax.nn.softmax(jnp.concatenate(
+            [jnp.where(keep_old, old, -1e30),
+             jnp.where(keep_new, new, -1e30)], axis=-1), axis=-1
+        ).astype(cv.dtype)
+        return (jnp.einsum("btcgs,bcsk->btcgk", probs[..., :n_old], cv,
+                           **f32)
+                + jnp.einsum("btcgu,buck->btcgk", probs[..., n_old:], v,
+                             **f32)).reshape(
+            b, t_len, cfg.n_heads, cfg.head_dim)
+
+    def layer(x, lp, plane, kind=None, experts=None):
+        h = _rms_norm(x, lp["ln1_scale"], eps) if pre_norm else x
         with jax.named_scope("attn_proj"):
             if cfg.fused_qkv:
                 q, k, v = split_qkv(
@@ -631,46 +822,17 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
             else:
                 q, k, v = (jnp.einsum("btd,dhk->bthk", h, lp["attn"][w])
                            for w in ("wq", "wk", "wv"))
-            if rope_ang is not None:
+            if cfg.qk_norm:
+                q = _rms_norm(q, lp["attn"]["q_scale"], eps)
+                k = _rms_norm(k, lp["attn"]["k_scale"], eps)
+            if rope_ang is not None and layer_rotates(cfg, kind):
                 q, k = rope_rotate(q, rope_ang), rope_rotate(k, rope_ang)
             k, v = k.astype(ck_all.dtype), v.astype(cv_all.dtype)
-        with jax.named_scope("kv_slab"):
-            # This plane's rows, for reading: [B, S, kv, hd] (the
-            # per-lane kernel takes the slab and the plane's index).
-            if lane is not None:
-                ck, cv = (jax.lax.dynamic_slice(
-                    a, (plane, lane, 0, 0, 0), (1, 1) + a.shape[2:])[0]
-                    for a in (ck_all, cv_all))
-            elif not per_lane:
-                ck, cv = (jax.lax.dynamic_index_in_dim(
-                    a, plane, 0, keepdims=False) for a in (ck_all, cv_all))
-            if bounded:
-                at = (jnp.int32(0), pos0[0], jnp.int32(0), jnp.int32(0))
-                ck = jax.lax.dynamic_update_slice(ck, k, at)
-                cv = jax.lax.dynamic_update_slice(cv, v, at)
-        with jax.named_scope("attn"):
-            if per_lane:
-                attn = _attend_before_and_chunk(q, k, v, ck_all, cv_all,
-                                                plane, pos0)
-            elif bounded:
-                attn = flash_prefix_attention(q.astype(ck.dtype), ck, cv,
-                                              pos0[0])
-            else:
-                qg = q.astype(jnp.float32).reshape(
-                    b, t_len, cfg.kv_heads, groups, cfg.head_dim)
-                old = jnp.einsum("btcgk,bsck->btcgs", qg,
-                                 ck.astype(jnp.float32)) * scale
-                new = jnp.einsum("btcgk,buck->btcgu", qg,
-                                 k.astype(jnp.float32)) * scale
-                probs = jax.nn.softmax(jnp.concatenate(
-                    [jnp.where(before, old, -1e30),
-                     jnp.where(causal, new, -1e30)], axis=-1), axis=-1)
-                attn = (jnp.einsum("btcgs,bsck->btcgk", probs[..., :s_len],
-                                   cv.astype(jnp.float32))
-                        + jnp.einsum("btcgu,buck->btcgk",
-                                     probs[..., s_len:],
-                                     v.astype(jnp.float32))).reshape(
-                    b, t_len, cfg.n_heads, cfg.head_dim)
+        if kind is None:
+            attn = attend_plain(q, k, v, plane)
+        else:
+            with jax.named_scope("attn"):
+                attn = attend_typed(q, k, v, plane, kind[0])
         with jax.named_scope("attn_proj"):
             attn = attn.astype(dtype)
             if cfg.fused_qkv:
@@ -679,19 +841,24 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
             else:
                 a = jnp.einsum("bthk,hkd->btd", attn, lp["attn"]["wo"])
         if cfg.post_norms:
-            a = _rms_norm(a, lp["ln1_post_scale"])
+            a = _rms_norm(a, lp["ln1_post_scale"], eps)
         # (The stream keeps the compute dtype whatever the weights'
         # is: a scan's carry cannot widen on the way.)
         with jax.named_scope("attn_proj"):
             x = x + a.astype(dtype)
-        h = _rms_norm(x, lp["ln2_scale"])
+        h = _rms_norm(x, lp["ln2_scale"], eps) if pre_norm else x
+        routes = None
         with jax.named_scope("mlp"):
-            y = ffn_apply(lp["ffn"], h, cfg)
+            if kind is not None and kind[1] == "sparse":
+                y, routes = moe_ffn(lp, h, cfg, with_routes=True,
+                                    stacked=experts)
+            else:
+                y = ffn_apply(lp["ffn"], h, cfg)
         if cfg.post_norms:
-            y = _rms_norm(y, lp["ln2_post_scale"])
+            y = _rms_norm(y, lp["ln2_post_scale"], eps)
         with jax.named_scope("mlp"):
             x = x + y.astype(dtype)
-        return x, (k, v)
+        return x, ((k, v) if routes is None else (k, v, routes))
 
     def one_pass(x, r):
         x, kv = jax.lax.scan(
@@ -699,6 +866,10 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
             (params["layers"], jnp.arange(n_layers)))
         return final_norm(x, params, cfg).astype(dtype), kv
 
+    if typed:
+        return _typed_tail(*_typed_runs(params, x, cfg, layer), params,
+                           cache, pos0, cfg, uniform_pos, lane, n_real,
+                           with_routes)
     x, (new_k, new_v) = jax.lax.scan(one_pass, x, jnp.arange(cfg.n_passes))
     with jax.named_scope("head"):
         out = jnp.einsum("btd,vd->btv", x,
@@ -725,8 +896,102 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
     return out.astype(jnp.float32), {"k": ck_all, "v": cv_all}
 
 
+def _typed_runs(params, x, cfg: TransformerConfig, layer):
+    """A typed stack's layers over the stream ``x``: one ``lax.scan`` a
+    run of consecutive layers of one kind, over that kind's slice of
+    its group's stacked leaves (the whole group where the kind makes
+    one run).  Returns ``(x, new)``: ``new[kind]`` the runs' scan
+    outputs of the attention kinds ``"full"`` and ``"window"`` — ``(k,
+    v)`` — and ``new["routes"]`` the sparse runs' routes, each a list
+    of leaves stacked by layer."""
+    new = {"full": [], "window": [], "routes": []}
+    planes = {"full": 0, "window": 0}
+    for group, first, count in cfg.layer_runs:
+        kind = tuple(group.split("."))
+        leaves, heavy = params["layers"][group], None
+        if kind[1] == "sparse":
+            # The experts' weights stay out of the scanned leaves: a
+            # grouped product takes the group's whole stack and the
+            # layer's index (``transformer.moe_held_experts``).
+            moe = dict(leaves["moe"])
+            heavy = (moe.pop("w13"), moe.pop("w2"))
+            leaves = {**leaves, "moe": moe}
+        if (first, count) != (0, cfg.layer_kinds.count(kind)):
+            leaves = jax.tree.map(lambda a: a[first:first + count], leaves)
+        p0 = planes[kind[0]]
+        planes[kind[0]] += count
+        x, outs = jax.lax.scan(
+            lambda x, lw: layer(x, lw[0], p0 + lw[1], kind,
+                                heavy and heavy + (first + lw[1],)),
+            x, (leaves, jnp.arange(count)))
+        new[kind[0]].append(outs[:2])
+        if len(outs) == 3:
+            new["routes"].append(outs[2])
+    return x, new
+
+
+def _typed_tail(x, new, params, cache, pos0, cfg: TransformerConfig,
+                uniform_pos, lane, n_real, with_routes):
+    """What follows a typed stack's layers in :func:`_chunk_in_place`:
+    the final norm and the head, then the chunk's K/V written in place
+    — into the full planes at their positions, into the rings modulo
+    ``sliding_window`` (see that docstring for ``n_real`` and parking)."""
+    dtype = jnp.dtype(cfg.dtype)
+    b, t_len = x.shape[:2]
+    ring = cfg.sliding_window
+    x = final_norm(x, params, cfg).astype(dtype)
+    with jax.named_scope("head"):
+        out = jnp.einsum("btd,vd->btv", x,
+                         head_table(params, cfg).astype(dtype))
+    cache = dict(cache)
+    zero = jnp.int32(0)
+    row0 = zero if lane is None else lane
+    with jax.named_scope("kv_slab"):
+        for kind, names in (("full", ("k", "v")),
+                            ("window", ("k_win", "v_win"))):
+            if not new[kind]:
+                continue
+            # Where a row's chunk starts in its plane: its position, or
+            # in a ring its slot (a parked row's: the parking slot).
+            start = pos0 if kind == "full" else jnp.where(
+                pos0 >= cfg.max_len - 1, ring, jnp.mod(pos0, ring))
+            for name, i in zip(names, (0, 1)):
+                # [layers of the kind, B, T, kv, hd] -> head-major rows.
+                a = jnp.concatenate([run[i] for run in new[kind]], axis=0
+                                    ).transpose(0, 1, 3, 2, 4)
+                slab = cache[name]
+                if kind == "window" and uniform_pos:
+                    # Slot s takes the latest REAL position of the chunk
+                    # that is = s (mod ring), or keeps what it holds.
+                    end = pos0[0] + (t_len if n_real is None else n_real)
+                    cand = (end - 1) - jnp.mod(
+                        end - 1 - jnp.arange(ring), ring)
+                    take = (cand >= pos0[0])[None, None, None, :, None]
+                    at = (zero, row0, zero, zero, zero)
+                    held = jax.lax.dynamic_slice(
+                        slab, at, a.shape[:3] + (ring,) + a.shape[4:])
+                    picked = jnp.take(
+                        a, jnp.clip(cand - pos0[0], 0, t_len - 1), axis=3)
+                    slab = jax.lax.dynamic_update_slice(
+                        slab, jnp.where(take, picked, held), at)
+                elif uniform_pos:
+                    slab = jax.lax.dynamic_update_slice(
+                        slab, a, (zero, row0, zero, start[0], zero))
+                else:  # one window over all planes per row, unrolled
+                    for r in range(b):
+                        slab = jax.lax.dynamic_update_slice(
+                            slab, a[:, r:r + 1],
+                            (zero, jnp.int32(r), zero, start[r], zero))
+                cache[name] = slab
+    if with_routes:
+        return (out.astype(jnp.float32), cache,
+                jnp.concatenate(new["routes"], axis=0))
+    return out.astype(jnp.float32), cache
+
+
 def _decode_chunk(params, cache, tokens, pos0, cfg: TransformerConfig,
-                  uniform_pos: bool = False, beam_anc=None, lane=None):
+                  uniform_pos: bool = False, beam_anc=None, lane=None,
+                  n_real=None, with_routes: bool = False):
     """Process T new tokens per row against the cache in ONE pass:
     ``tokens [B, T]`` at global positions ``pos0[b] + (0..T-1)`` ->
     ``(logits [B, T, V] f32, cache)``.
@@ -797,7 +1062,8 @@ def _decode_chunk(params, cache, tokens, pos0, cfg: TransformerConfig,
         # written in place.  What follows is the BASE block's body for
         # what that one does not express (the docstring above).
         return _chunk_in_place(params, cache, tokens, pos0, cfg,
-                               uniform_pos=uniform_pos, lane=lane)
+                               uniform_pos=uniform_pos, lane=lane,
+                               n_real=n_real, with_routes=with_routes)
     reject_extended(cfg, why)
     if lane is not None:
         raise ValueError(f"lane= (in-place admission into one lane of a "

@@ -32,9 +32,11 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from distkeras_tpu.ops.attention import flash_attention
+from distkeras_tpu.ops.grouped import TILE_M as GROUP_TILE, grouped_matmul
 
 
 @dataclasses.dataclass(frozen=True)
@@ -124,7 +126,9 @@ class TransformerConfig:
     tie_head: bool = True
     # A norm on each sublayer's OUTPUT as well as its input (four norms
     # a layer): x = x + rms(attn(rms(x))); x = x + rms(ffn(rms(x))).
-    post_norms: bool = False
+    # The third placement, "only": on the output and NOT on the input
+    # (two norms a layer): x = x + rms(attn(x)); x = x + rms(ffn(x)).
+    post_norms: bool | str = False
     # The attention projections as MATRICES: q, k and v side by side in
     # one ``attn/wqkv [L, D, (heads + 2 kv_heads) * head_dim]`` (one
     # product a layer) and ``attn/wo [L, heads * head_dim, D]``, in
@@ -145,6 +149,96 @@ class TransformerConfig:
     # model's (exit threshold 1); :func:`apply_passes` returns every
     # pass's logits and the exit distribution.
     n_passes: int = 1
+    # --- the TYPED stack: a layer is a pair (attention kind,
+    # feed-forward kind) read from two per-layer lists, and the keys
+    # below say what the kinds mean.  ``apply`` and the serving path
+    # (``generate``, ``ContinuousBatcher`` with ``hot_swap`` and
+    # ``prefill_chunk``) run it; every other path rejects these keys by
+    # name (:func:`reject_extended`).  The parameter tree groups the
+    # layers by kind (``layers/<attention>.<ffn>/...``, each group
+    # stacked on its own leading axis), and a run of consecutive layers
+    # of one kind is one scan.  A stack whose lists name full attention
+    # and the dense feed-forward everywhere is today's stack: the lists
+    # are dropped (``__post_init__``) and it compiles today's programs.
+    # ``layer_types``: "window" | "full" per layer; a window layer
+    # attends its last ``sliding_window`` positions (self included) —
+    # unlike ``attention_window``, which windows the WHOLE stack and
+    # makes the serving lanes roll.  Served, a window layer's K/V plane
+    # is a ring of ``sliding_window`` slots beside the full layers'
+    # ``max_len``-slot planes.
+    layer_types: tuple | None = None
+    sliding_window: int | None = None
+    # ``ffn_types``: "dense" | "sparse" per layer.  A sparse layer is
+    # the ROUTED feed-forward (:func:`moe_ffn`): a router over all
+    # ``num_experts``, ``moe_top_k`` experts a token, no capacity and
+    # no dropped token, computed for the experts HELD here —
+    # ``moe_held`` (their ids; None = all) — each of width ``moe_d_ff``
+    # and the dense form, plus ``moe_shared`` shared experts that every
+    # token takes.  Its router: scores sigmoid(x·wg), the choice by
+    # score + a selection bias, weights = the chosen scores over their
+    # sum, times ``moe_route_scale``.
+    ffn_types: tuple | None = None
+    moe_held: tuple | None = None
+    moe_d_ff: int | None = None
+    moe_shared: int = 0
+    moe_route_scale: float = 1.0
+    # Kinds of layer that rotate q and k (None: every layer, where
+    # ``rope``): a model may rotate in its window layers only.
+    rope_layer_types: tuple | None = None
+    # The width of a head where it is not d_model / n_heads (read it
+    # as ``head_dim``); RMSNorm over each head of q and k before the
+    # rotation (``attn/q_scale``, ``attn/k_scale`` [head_dim]); the
+    # epsilon of every RMSNorm.
+    d_head: int | None = None
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
+
+    def __post_init__(self):
+        # Lists arrive from JSON as lists; a config is a static jit
+        # argument and has to hash.
+        put = lambda k, v: object.__setattr__(self, k, v)
+        for k in ("layer_types", "ffn_types", "moe_held",
+                  "rope_layer_types"):
+            v = getattr(self, k)
+            if v is not None:
+                put(k, tuple(v))
+        if self.layer_types is None and self.ffn_types is None:
+            return
+        # One list given: the other names today's kind everywhere.
+        if self.layer_types is None:
+            put("layer_types", ("full",) * self.n_layers)
+        if self.ffn_types is None:
+            put("ffn_types", ("dense",) * self.n_layers)
+        if (set(self.layer_types) == {"full"}
+                and set(self.ffn_types) == {"dense"}):
+            put("layer_types", None)
+            put("ffn_types", None)
+
+    @property
+    def typed(self) -> bool:
+        """Whether the layers differ in kind (the typed stack)."""
+        return self.layer_types is not None
+
+    @property
+    def layer_kinds(self) -> tuple:
+        """``(attention kind, feed-forward kind)`` of every layer."""
+        return tuple(zip(self.layer_types, self.ffn_types))
+
+    @property
+    def layer_runs(self) -> tuple:
+        """The typed stack as runs of consecutive layers of one kind:
+        ``(group, first index within the group, layers)`` — one scan
+        each over a slice of the group's stacked leaves."""
+        runs, seen = [], {}
+        for kind in self.layer_kinds:
+            g = ".".join(kind)
+            at = seen.get(g, 0)
+            seen[g] = at + 1
+            if runs and runs[-1][0] == g:
+                runs[-1][2] += 1
+            else:
+                runs.append([g, at, 1])
+        return tuple(tuple(r) for r in runs)
 
     @property
     def extended(self) -> bool:
@@ -154,17 +248,39 @@ class TransformerConfig:
         every other path — :func:`reject_extended` — knows the base
         block only and says so."""
         return self.n_passes != 1 or any(
-            getattr(self, k) != base for k, base in _SWITCHES.items())
+            getattr(self, k) != base
+            for k, base in {**_SWITCHES, **_TYPED_KEYS}.items())
 
     @property
     def kv_planes(self) -> int:
-        """(k, v) planes of the decode cache: one per pass and layer."""
+        """(k, v) planes of ``max_len`` slots in the decode cache: one
+        per pass and layer; in a typed stack one per full layer."""
+        if self.typed:
+            return self.layer_types.count("full")
         return self.n_passes * self.n_layers
 
     @property
+    def kv_ring_planes(self) -> int:
+        """Ring planes of the decode cache: one per window layer of a
+        typed stack."""
+        return self.layer_types.count("window") if self.typed else 0
+
+    @property
     def head_dim(self) -> int:
+        if self.d_head is not None:
+            return self.d_head
         assert self.d_model % self.n_heads == 0
         return self.d_model // self.n_heads
+
+    @property
+    def expert_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    @property
+    def experts_held(self) -> tuple:
+        """Ids of the routed experts whose weights are here."""
+        return (tuple(range(self.num_experts)) if self.moe_held is None
+                else self.moe_held)
 
     @property
     def kv_heads(self) -> int:
@@ -178,6 +294,13 @@ class TransformerConfig:
 # The extended block's switches and their defaults (the base block).
 _SWITCHES = {"ffn_gated": False, "tie_head": True, "post_norms": False,
              "fused_qkv": False}
+# The typed stack's keys and theirs: a path that takes the switches
+# above (the training loss) still rejects these by name.
+_TYPED_KEYS = {"layer_types": None, "ffn_types": None,
+               "sliding_window": None, "moe_held": None,
+               "moe_d_ff": None, "moe_shared": 0, "moe_route_scale": 1.0,
+               "rope_layer_types": None, "d_head": None, "qk_norm": False,
+               "norm_eps": 1e-6}
 
 
 def reject_extended(cfg: "TransformerConfig", path: str,
@@ -191,6 +314,7 @@ def reject_extended(cfg: "TransformerConfig", path: str,
     objective under the model's name)."""
     on = [k for k, base in _SWITCHES.items()
           if getattr(cfg, k) != base and k not in allow]
+    on += [k for k, base in _TYPED_KEYS.items() if getattr(cfg, k) != base]
     if cfg.n_passes > 1 or on:
         what = (f"a looped stack (n_passes={cfg.n_passes})"
                 if cfg.n_passes > 1 else
@@ -277,50 +401,102 @@ def init_params(rng, cfg: TransformerConfig):
             f"{cfg.num_experts}]")
     if cfg.n_passes < 1:
         raise ValueError(f"n_passes must be >= 1, got {cfg.n_passes}")
-    if cfg.num_experts:
-        reject_extended(cfg, "a MoE feed-forward (num_experts > 0)")
+    if cfg.post_norms not in (False, True, "only"):
+        raise ValueError(
+            f"post_norms must be False, True or 'only', got "
+            f"{cfg.post_norms!r}")
+    if cfg.typed:
+        _validate_typed(cfg)
+    elif cfg.num_experts:
+        reject_extended(cfg, "a MoE feed-forward (num_experts > 0) "
+                        "without ffn_types (the capacity dispatch)")
     _validate_remat_policy(cfg)
     keys = jax.random.split(rng, 12)
     d, f, h, hd = cfg.d_model, cfg.d_ff, cfg.n_heads, cfg.head_dim
     kv = cfg.kv_heads
-    L = cfg.n_layers
 
-    def stack(key, shape, fan_in):
-        return _dense_init(key, (L, *shape), fan_in)
+    def group(gkey, L, sparse=None):
+        """``L`` layers of one kind, stacked on a leading axis; the
+        whole stack where the layers do not differ (``sparse`` None:
+        the old capacity-dispatch experts where ``num_experts``)."""
+        gk = keys if gkey is None else jax.random.split(gkey, 12)
 
-    layers = {
-        "ln1_scale": jnp.ones((L, d)),
-        "ln2_scale": jnp.ones((L, d)),
-        "attn": {
-            "wq": stack(keys[0], (d, h, hd), d),
-            "wk": stack(keys[1], (d, kv, hd), d),
-            "wv": stack(keys[2], (d, kv, hd), d),
-            "wo": stack(keys[3], (h, hd, d), d),
-        },
-    }
-    if cfg.fused_qkv:  # the same draws, laid out as matrices
-        a = layers["attn"]
-        layers["attn"] = {
-            "wqkv": jnp.concatenate([a[w].reshape(L, d, -1)
-                                     for w in ("wq", "wk", "wv")], axis=-1),
-            "wo": a["wo"].reshape(L, -1, d)}
-    if cfg.num_experts:
-        layers["moe"] = {
-            "wg": stack(keys[4], (d, cfg.num_experts), d),
-            "w1": stack(keys[5], (cfg.num_experts, d, f), d),
-            "w2": stack(keys[6], (cfg.num_experts, f, d), f),
-        }
+        def stack(key, shape, fan_in):
+            return _dense_init(key, (L, *shape), fan_in)
+
+        layers = {"attn": {
+            "wq": stack(gk[0], (d, h, hd), d),
+            "wk": stack(gk[1], (d, kv, hd), d),
+            "wv": stack(gk[2], (d, kv, hd), d),
+            "wo": stack(gk[3], (h, hd, d), h * hd),
+        }}
+        if cfg.post_norms != "only":
+            layers["ln1_scale"] = jnp.ones((L, d))
+            layers["ln2_scale"] = jnp.ones((L, d))
+        if cfg.fused_qkv:  # the same draws, laid out as matrices
+            a = layers["attn"]
+            layers["attn"] = {
+                "wqkv": jnp.concatenate(
+                    [a[w].reshape(L, d, -1) for w in ("wq", "wk", "wv")],
+                    axis=-1),
+                "wo": a["wo"].reshape(L, -1, d)}
+        if cfg.qk_norm:
+            layers["attn"]["q_scale"] = jnp.ones((L, hd))
+            layers["attn"]["k_scale"] = jnp.ones((L, hd))
+        if sparse:
+            ef, held = cfg.expert_ff, len(cfg.experts_held)
+            # Every expert a draw of its own, by its ID: a share holds
+            # the numbers the whole model has for its experts.
+            def experts(key, shape, fan_in):
+                return jax.vmap(
+                    lambda e: _dense_init(jax.random.fold_in(key, e),
+                                          (L, *shape), fan_in),
+                    out_axes=1)(jnp.asarray(cfg.experts_held))
+            layers["moe"] = {
+                "wg": stack(gk[4], (d, cfg.num_experts), d),
+                # Gate and up side by side: one grouped product.
+                "w13": experts(gk[5], (d, 2 * ef), d),
+                "w2": experts(gk[6], (ef, d), ef),
+            }
+            assert layers["moe"]["w13"].shape[1] == held
+            # The selection bias: drawn, so that leaving it out of a
+            # computation shows.
+            layers["moe"]["bias"] = 0.02 * jax.random.normal(
+                gk[9], (L, cfg.num_experts), jnp.float32)
+            if cfg.moe_shared:
+                sf = cfg.moe_shared * ef
+                layers["shared"] = {
+                    "w1": stack(gk[7], (d, sf), d),
+                    "w3": stack(jax.random.fold_in(gk[7], 1), (d, sf), d),
+                    "w2": stack(gk[8], (sf, d), sf)}
+        elif sparse is None and cfg.num_experts:
+            layers["moe"] = {
+                "wg": stack(gk[4], (d, cfg.num_experts), d),
+                "w1": stack(gk[5], (cfg.num_experts, d, f), d),
+                "w2": stack(gk[6], (cfg.num_experts, f, d), f),
+            }
+        else:
+            layers["ffn"] = {
+                "w1": stack(gk[7], (d, f), d),
+                "w2": stack(gk[8], (f, d), f),
+            }
+            if cfg.ffn_gated:
+                layers["ffn"]["w3"] = stack(jax.random.fold_in(gk[7], 1),
+                                            (d, f), d)
+        if cfg.post_norms:
+            layers["ln1_post_scale"] = jnp.ones((L, d))
+            layers["ln2_post_scale"] = jnp.ones((L, d))
+        return layers
+
+    if cfg.typed:
+        kinds = cfg.layer_kinds
+        layers = {
+            ".".join(kind): group(
+                jax.random.fold_in(keys[0], i), kinds.count(kind),
+                sparse=kind[1] == "sparse")
+            for i, kind in enumerate(dict.fromkeys(kinds))}
     else:
-        layers["ffn"] = {
-            "w1": stack(keys[7], (d, f), d),
-            "w2": stack(keys[8], (f, d), f),
-        }
-        if cfg.ffn_gated:
-            layers["ffn"]["w3"] = stack(jax.random.fold_in(keys[7], 1),
-                                        (d, f), d)
-    if cfg.post_norms:
-        layers["ln1_post_scale"] = jnp.ones((L, d))
-        layers["ln2_post_scale"] = jnp.ones((L, d))
+        layers = group(None, cfg.n_layers)
     params = {
         # Tied embedding/unembedding: std 1/sqrt(d) keeps initial logits
         # O(1) so the initial LM loss sits at ~ln(vocab).
@@ -343,6 +519,62 @@ def init_params(rng, cfg: TransformerConfig):
                                        (d,), d)
         params["exit_b"] = jnp.zeros(())
     return params
+
+
+def _validate_typed(cfg: "TransformerConfig") -> None:
+    """What a typed stack's keys have to say together."""
+    n = cfg.n_layers
+    for name, kinds in (("layer_types", ("window", "full")),
+                        ("ffn_types", ("dense", "sparse"))):
+        got = getattr(cfg, name)
+        if len(got) != n or set(got) - set(kinds):
+            raise ValueError(
+                f"{name} must name one of {kinds} for each of the "
+                f"{n} layers, got {got}")
+    if cfg.n_passes != 1 or cfg.attention_window is not None:
+        raise ValueError(
+            "a typed stack (layer_types / ffn_types) is neither looped "
+            "(n_passes) nor windowed as a whole (attention_window: its "
+            "window layers take sliding_window)")
+    if "window" in cfg.layer_types:
+        w = cfg.sliding_window
+        if w is None or w < 1 or cfg.max_len % w:
+            raise ValueError(
+                f"window layers need a sliding_window >= 1 that divides "
+                f"max_len={cfg.max_len} (a window layer's cache plane is "
+                f"a ring of that many slots), got {w}")
+    if "sparse" in cfg.ffn_types:
+        held = cfg.experts_held
+        if not (1 <= cfg.moe_top_k <= cfg.num_experts) or not held or (
+                len(set(held)) != len(held)
+                or not all(0 <= e < cfg.num_experts for e in held)):
+            raise ValueError(
+                f"sparse layers need 1 <= moe_top_k <= num_experts and "
+                f"moe_held distinct ids below num_experts, got top_k="
+                f"{cfg.moe_top_k}, num_experts={cfg.num_experts}, "
+                f"moe_held={cfg.moe_held}")
+        if not cfg.ffn_gated:
+            raise ValueError(
+                "sparse layers hold gated experts: ffn_gated=True")
+    if cfg.rope_layer_types is not None and not cfg.rope:
+        raise ValueError("rope_layer_types needs rope=True")
+
+
+def layer_rotates(cfg: "TransformerConfig", kind) -> bool:
+    """Whether a layer of ``kind`` (None: an untyped stack's) rotates
+    its q and k."""
+    return cfg.rope and (kind is None or cfg.rope_layer_types is None
+                         or kind[0] in cfg.rope_layer_types)
+
+
+def layer_at(layers, cfg: "TransformerConfig", i: int):
+    """``(layer i's parameters, its kind)`` out of the stacked tree;
+    the kind is None where the layers do not differ."""
+    if not cfg.typed:
+        return jax.tree.map(lambda a: a[i], layers), None
+    kind = cfg.layer_kinds[i]
+    j = cfg.layer_kinds[:i].count(kind)
+    return jax.tree.map(lambda a: a[j], layers[".".join(kind)]), kind
 
 
 def tp_rules():
@@ -378,6 +610,16 @@ def _resolve_attention_fn(cfg: "TransformerConfig", attention_fn,
     BOTH directions — a band applied on one side only would silently
     diverge training from the KV-cached decode, which follows cfg.
     """
+    if cfg.typed:
+        if attention_fn is not None or segment_ids is not None:
+            raise ValueError(
+                "a typed stack (layer_types / ffn_types) runs its own "
+                "attention by layer kind: no attention_fn, no "
+                "segment_ids")
+        by_window = lambda window: lambda q, k, v: flash_attention(
+            q, k, v, True, window=window)
+        return {"window": by_window(cfg.sliding_window),
+                "full": by_window(None)}
     if attention_fn is None:
         return lambda q, k, v: flash_attention(
             q, k, v, True, window=cfg.attention_window,
@@ -440,12 +682,16 @@ def _dropout(x, rate: float, key):
 #   attn       scores, softmax, values (the flash kernels sit here)
 #   kv_slab    cutting a layer's K/V out of the cache slab, and putting
 #              it back (decode and chunked prefill)
-#   mlp        the feed-forward (or MoE) block
+#   mlp        the feed-forward (or MoE) block; inside it, in a sparse
+#              layer of a typed stack: moe_route (scores, choice,
+#              weights), moe_experts (the held experts' grouped
+#              products, sorted in and gathered back), moe_shared
 #   head       unembedding (tied or not) and cross-entropy
 #   loop_exit  what sits between two passes of a looped stack: the
 #              final norm after every pass and the exit gate
 SCOPES = ("embed", "norm", "attn_proj", "attn", "kv_slab", "mlp", "head",
           "loop_exit")
+MOE_SCOPES = ("moe_route", "moe_experts", "moe_shared")
 
 
 def _rms_norm(x, scale, eps=1e-6, scope="norm"):
@@ -474,11 +720,14 @@ def rope_rotate(x, ang):
 
 
 def _attention_block(lp, x, attention_fn, rope_ang=None, kv_groups=1,
-                     return_kv=False):
+                     return_kv=False, eps=1e-6):
     with jax.named_scope("attn_proj"):
         q = jnp.einsum("bsd,dhk->bshk", x, lp["wq"])
         k = jnp.einsum("bsd,dhk->bshk", x, lp["wk"])
         v = jnp.einsum("bsd,dhk->bshk", x, lp["wv"])
+        if "q_scale" in lp:  # qk_norm: over each head, before rotating
+            q = _rms_norm(q, lp["q_scale"], eps)
+            k = _rms_norm(k, lp["k_scale"], eps)
         if rope_ang is not None:
             q, k = rope_rotate(q, rope_ang), rope_rotate(k, rope_ang)
     kv = (k, v)  # post-rope, pre-GQA-expansion: the decode cache layout
@@ -587,10 +836,135 @@ def _moe_dense_block(lp, x, cfg: TransformerConfig):
                       ).astype(dtype)
 
 
+def moe_route(moe, h, cfg: TransformerConfig):
+    """The router of a sparse layer over ``h [N, D]``: ``(expert [N, k]
+    ids among all ``num_experts``, weight [N, k] float32)``.  Float32
+    throughout: a choice is a comparison of near-equal scores."""
+    with jax.named_scope("moe_route"):
+        score = jax.nn.sigmoid(jnp.einsum(
+            "nd,de->ne", h.astype(jnp.float32),
+            moe["wg"].astype(jnp.float32)))
+        # The bias chooses; the weights are of the raw scores.
+        _, expert = jax.lax.top_k(score + moe["bias"].astype(jnp.float32),
+                                  cfg.moe_top_k)
+        picked = jnp.take_along_axis(score, expert, axis=-1)
+        return expert, (cfg.moe_route_scale * picked
+                        / picked.sum(axis=-1, keepdims=True))
+
+
+def moe_held_experts(moe, h, expert, gates, cfg: TransformerConfig,
+                     layer=None):
+    """The HELD experts' part of a sparse layer for ``h [N, D]`` routed
+    to ``expert [N, k]`` with ``gates [N, k]``: ``(out [N, D] float32,
+    local [N, k])`` — ``local`` is an assignment's index among the held
+    experts, or their number where its expert is not here (what absent
+    experts would add is left out).
+
+    One path for a prefill chunk and a decode step: the N·k
+    assignments are sorted by held expert (those of absent experts
+    last) into rows grouped by expert, one grouped product a
+    projection over the held experts' stacked weights
+    (``ops.grouped.grouped_matmul``: on the TPU a kernel that walks
+    only the row tiles the groups fill), and each token gathers its k
+    rows back and sums them under its gates.  No capacity: the row
+    count is the assignments', so no token is dropped whatever the
+    routing.
+
+    ``layer`` (traced int32): ``moe["w13"]`` and ``moe["w2"]`` are a
+    GROUP's stacked leaves ``[layers, held, ...]`` and this is layer
+    ``layer`` of it — the products then run over all ``layers * held``
+    groups with every other layer's empty.  A layer's experts cut out
+    of the stack inside a scan would be copied before a grouped
+    product could read them (1.2 GB a layer at the benchmark's sizes:
+    AOT, PR 31); the whole stack is handed over as it lies."""
+    with jax.named_scope("moe_experts"):
+        n, k = expert.shape
+        held = cfg.experts_held
+        e = len(held)
+        table = np.full((cfg.num_experts,), e, np.int32)
+        table[list(held)] = np.arange(e, dtype=np.int32)
+        local = jnp.asarray(table)[expert]                    # [N, k]
+        key = local.reshape(-1)
+        order = jnp.argsort(key)
+        sizes = jnp.sum(key[:, None] == jnp.arange(e)[None, :], axis=0,
+                        dtype=jnp.int32)
+        # Every group starts on a row tile of the kernel, so a group of
+        # up to a tile of rows is ONE visit of its expert's weights
+        # wherever it lies: packed end to end, a group that straddles a
+        # tile boundary is read twice, and how many do follows the
+        # routing (a 512-token chunk's ~512 held rows made 19 or 20
+        # visits by the seed's draw: serve_tok_s 1.8 % apart in two
+        # clusters; chip, PR 31).  The row count stays a static bound:
+        # all N*k assignments and a tile of slack a group.
+        # A group WITHOUT rows keeps one tile (of zero rows): every held
+        # expert's weights are read in every product, so the product's
+        # time follows the shapes and not the routing.  Skipped, an
+        # expert no token reached made a step faster by its 75 MB, and
+        # how many are reached is the router's skew: under seeded random
+        # weights one layer's products took 0.71 of another's in one
+        # run, and the same requests ran 0.9 to 5.7 % faster by the
+        # seed (6,979-7,282 tokens/s over 12 seeds; 6,865-6,934 with
+        # the tile kept; chip, PR 31) — where a share of a deployment
+        # sees its experts reached by every chip's tokens.
+        tile = GROUP_TILE
+        padded = jnp.maximum(-(-sizes // tile), 1) * tile
+        first = jnp.cumsum(sizes) - sizes          # in the sorted order
+        base = jnp.cumsum(padded) - padded         # in the padded rows
+        n_rows = -(-n * k // tile) * tile + e * tile
+        row = jnp.arange(n_rows)
+        group = jnp.minimum(jnp.searchsorted(base + padded, row,
+                                             side="right"), e - 1)
+        within = row - base[group]
+        live = within < sizes[group]
+        src = order[jnp.clip(first[group] + within, 0, n * k - 1)] // k
+        rows = jnp.where(live[:, None], h[src], 0)
+        w13, w2 = moe["w13"], moe["w2"]
+        if layer is not None:
+            padded = jax.lax.dynamic_update_slice(
+                jnp.zeros((w13.shape[0] * e,), jnp.int32), padded,
+                (layer * e,))
+            w13, w2 = (a.reshape((-1,) + a.shape[2:]) for a in (w13, w2))
+        up = grouped_matmul(rows, w13.astype(h.dtype), padded)
+        f = up.shape[-1] // 2
+        y = grouped_matmul(jax.nn.silu(up[:, :f]) * up[:, f:],
+                           w2.astype(h.dtype), padded)
+        # Each assignment's row back (an absent expert's: any row,
+        # selected away), summed over a token's k under its gates.
+        slot = jnp.minimum(key, e - 1)
+        at = base[slot] + jnp.argsort(order) - first[slot]
+        y = jnp.where((key < e)[:, None], y[at].astype(jnp.float32), 0.0)
+        out = (y * gates.reshape(-1)[:, None]).reshape(n, k, -1).sum(axis=1)
+        return out, local
+
+
+def moe_ffn(lp, h, cfg: TransformerConfig, with_routes: bool = False,
+            stacked=None):
+    """The routed feed-forward of a typed stack's sparse layer over
+    ``h [..., D]``: the held experts' part for the tokens routed to
+    them plus the shared expert, in ``h``'s dtype.  ``with_routes``:
+    also ``local [..., k]`` of :func:`moe_held_experts` (the serving
+    engine's counters).  ``stacked = (w13, w2, layer)``: the experts'
+    weights as their group's whole stack and this layer's index in it
+    (``lp["moe"]`` then holds the router alone)."""
+    flat = h.reshape(-1, h.shape[-1])
+    expert, gates = moe_route(lp["moe"], flat, cfg)
+    moe, layer = lp["moe"], None
+    if stacked is not None:
+        moe, layer = {"w13": stacked[0], "w2": stacked[1]}, stacked[2]
+    out, local = moe_held_experts(moe, flat, expert, gates, cfg, layer)
+    if "shared" in lp:
+        with jax.named_scope("moe_shared"):
+            out = out + ffn_apply(lp["shared"], flat, cfg)
+    out = out.astype(h.dtype).reshape(h.shape)
+    if with_routes:
+        return out, local.reshape(h.shape[:-1] + local.shape[-1:])
+    return out
+
+
 def final_norm(x, params, cfg: TransformerConfig):
     """The stack's one final norm; in a looped stack it runs after
     every pass and belongs to what sits between two passes."""
-    return _rms_norm(x, params["ln_f_scale"],
+    return _rms_norm(x, params["ln_f_scale"], cfg.norm_eps,
                      scope="loop_exit" if cfg.n_passes > 1 else "norm")
 
 
@@ -618,9 +992,12 @@ def ffn_apply(ffn, h, cfg: TransformerConfig):
 
 def block_apply(layer_params, x, cfg: TransformerConfig,
                 attention_fn: Callable, rope_ang=None, drop_key=None,
-                return_kv=False, moe_dense_routing=False):
-    """One transformer block (pre-norm).  Returns (x, aux_loss), or
-    (x, aux_loss, (k, v)) with ``return_kv`` (post-rope, kv-heads-only —
+                return_kv=False, moe_dense_routing=False, kind=None):
+    """One transformer block (pre-norm).  ``kind``: a typed stack's
+    ``(attention kind, feed-forward kind)`` of this layer — the caller
+    hands the attention function and the rotation that go with it
+    (:func:`_trunk`); a sparse layer is :func:`moe_ffn`.  Returns
+    (x, aux_loss), or (x, aux_loss, (k, v)) with ``return_kv`` (post-rope, kv-heads-only —
     the decode-cache layout; generate.prefill consumes it so there is
     exactly ONE definition of the block body to keep in sync).
     ``moe_dense_routing`` swaps the MoE FFN for the capacity-free
@@ -632,20 +1009,21 @@ def block_apply(layer_params, x, cfg: TransformerConfig,
     callable closing over traced values would leak tracers through
     jax.checkpoint.  ``drop_key`` non-None enables residual dropout.
     """
-    h = _rms_norm(x, layer_params["ln1_scale"])
+    eps, pre = cfg.norm_eps, cfg.post_norms != "only"
+    h = _rms_norm(x, layer_params["ln1_scale"], eps) if pre else x
     attn_w = layer_params["attn"]
     if cfg.fused_qkv:  # matrices (init_params): heads split out here
         wq, wk, wv = split_qkv(attn_w["wqkv"], cfg)
-        attn_w = {"wq": wq, "wk": wk, "wv": wv,
+        attn_w = {**attn_w, "wq": wq, "wk": wk, "wv": wv,
                   "wo": attn_w["wo"].reshape(-1, cfg.head_dim, cfg.d_model)}
     a = _attention_block(attn_w, h, attention_fn, rope_ang,
                          kv_groups=cfg.n_heads // cfg.kv_heads,
-                         return_kv=return_kv)
+                         return_kv=return_kv, eps=eps)
     kv = None
     if return_kv:
         a, kv = a
     if cfg.post_norms:
-        a = _rms_norm(a, layer_params["ln1_post_scale"])
+        a = _rms_norm(a, layer_params["ln1_post_scale"], eps)
     # The residual sums (and the dropout before them) go to the
     # sublayer whose output they take in: no operation of a block is
     # left outside the vocabulary.
@@ -653,18 +1031,19 @@ def block_apply(layer_params, x, cfg: TransformerConfig,
         if drop_key is not None:
             a = _dropout(a, cfg.dropout, jax.random.fold_in(drop_key, 0))
         x = x + a
-    h = _rms_norm(x, layer_params["ln2_scale"])
+    h = _rms_norm(x, layer_params["ln2_scale"], eps) if pre else x
     with jax.named_scope("mlp"):
-        if cfg.num_experts and moe_dense_routing:
-            y = _moe_dense_block(layer_params["moe"], h, cfg)
-            aux = jnp.zeros((), jnp.float32)
-        elif cfg.num_experts:
-            y, aux = _moe_block(layer_params["moe"], h, cfg)
-        else:
+        aux = jnp.zeros((), jnp.float32)
+        if kind is not None and kind[1] == "sparse":
+            y = moe_ffn(layer_params, h, cfg)
+        elif kind is not None or not cfg.num_experts:
             y = ffn_apply(layer_params["ffn"], h, cfg)
-            aux = jnp.zeros((), jnp.float32)
+        elif moe_dense_routing:
+            y = _moe_dense_block(layer_params["moe"], h, cfg)
+        else:
+            y, aux = _moe_block(layer_params["moe"], h, cfg)
     if cfg.post_norms:
-        y = _rms_norm(y, layer_params["ln2_post_scale"])
+        y = _rms_norm(y, layer_params["ln2_post_scale"], eps)
     with jax.named_scope("mlp"):
         if drop_key is not None:
             y = _dropout(y, cfg.dropout, jax.random.fold_in(drop_key, 1))
@@ -706,13 +1085,20 @@ def _trunk(params, tokens, cfg: TransformerConfig,
     hiddens = []
     for r in range(cfg.n_passes):
         for i in range(cfg.n_layers):
-            lp = jax.tree.map(lambda a: a[i], params["layers"])
+            lp, kind = layer_at(params["layers"], cfg, i)
             # Pass 0 keeps the keys 0..L-1 it always had (L is the
             # embedding's); later passes continue past them.
             drop_key = (jax.random.fold_in(
                 dropout_rng, r * (cfg.n_layers + 1) + i) if dropping
                 else None)
-            x, aux = block(lp, x, cfg, attention_fn, rope_ang, drop_key)
+            if kind is None:
+                x, aux = block(lp, x, cfg, attention_fn, rope_ang,
+                               drop_key)
+            else:  # a typed stack: attention and rotation by kind
+                x, aux = block_apply(
+                    lp, x, cfg, attention_fn[kind[0]],
+                    rope_ang if layer_rotates(cfg, kind) else None,
+                    drop_key, kind=kind)
             aux_total = aux_total + aux
         # The ONE final norm, after every pass: the next pass starts
         # from the normed stream.

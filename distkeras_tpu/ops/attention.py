@@ -1111,11 +1111,12 @@ def use_flash_prefix(t_len: int, s_len: int, head_dim: int, groups: int,
                               dtype) is not None)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("block_q", "block_k", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_q", "block_k",
+                                             "interpret", "head_major"))
 def flash_prefix_attention(q, k, v, off, block_q: int | None = None,
                            block_k: int | None = None,
-                           interpret: bool = False):
+                           interpret: bool = False,
+                           head_major: bool = False):
     """Attention of a chunk's queries against the live prefix of a KV
     cache: ``q [B, T, H, D]`` at global positions ``off .. off + T - 1``
     (``off``: int32 scalar, may be traced, need not be block-aligned),
@@ -1124,6 +1125,9 @@ def flash_prefix_attention(q, k, v, off, block_q: int | None = None,
     off + t``; slots past ``off + T - 1`` are neither fetched nor
     computed, so bytes and operations follow ``off + T``, not ``S``.
     Returns ``[B, T, H, D]`` in ``q``'s dtype.  Forward only.
+    ``head_major``: the cache is ``[B, KV, S, D]`` (a K/V head's slots
+    one matrix: a typed stack's planes) and a K/V block is a row block
+    of its head's.
     ``block_q``/``block_k`` default to :func:`prefix_blocks`' choice
     (shapes it refuses need them given: the interpreter's tests).
 
@@ -1132,7 +1136,7 @@ def flash_prefix_attention(q, k, v, off, block_q: int | None = None,
     program 1.4 s more of Python before the compile cache is even
     asked, 13 s over an engine's ten programs (chip, PR 26)."""
     b, t_len, h, d = q.shape
-    s_len, kv = k.shape[1], k.shape[2]
+    kv, s_len = k.shape[1:3] if head_major else k.shape[2:0:-1]
     groups = h // kv
     if block_q is None or block_k is None:
         fit = prefix_blocks(t_len, s_len, d, groups, q.dtype)
@@ -1150,15 +1154,20 @@ def flash_prefix_attention(q, k, v, off, block_q: int | None = None,
     # merge into the rows of one tile.  The cache keeps its layout; a
     # K/V head is a lane-aligned column block of [B, S, KV * D].
     qg = q.reshape(b, t_len, kv, groups, d).transpose(0, 2, 3, 1, 4)
-    kf, vf = (a.reshape(b, s_len, kv * d) for a in (k, v))
+    kf, vf = (k, v) if head_major else (
+        a.reshape(b, s_len, kv * d) for a in (k, v))
 
     def kv_map(bi, c, i, j, off_ref):
         last = (off_ref[0] + (i + 1) * block_q - 1) // block_k
+        if head_major:
+            return bi, c, jnp.minimum(j, last), 0
         return bi, jnp.minimum(j, last), c
 
     q_spec = pl.BlockSpec((None, None, groups, block_q, d),
                           lambda bi, c, i, j, off_ref: (bi, c, 0, i, 0))
-    kv_spec = pl.BlockSpec((None, block_k, d), kv_map)
+    kv_spec = pl.BlockSpec(
+        (None, None, block_k, d) if head_major else (None, block_k, d),
+        kv_map)
     live = b * h * t_len * s_len // 2     # an estimate: off is traced
 
     def call(): return pl.pallas_call(

@@ -107,10 +107,13 @@ def _make_lane_admit(model_params, model_cfg, prefix_lane=None,
     """
     def _admit(params, cache, rows, lane, off, *pool):
         if in_place:
+            # ``pool`` is then ``(n_real,)`` or empty: how many of the
+            # rows are real, where the program writes ring planes.
             return _decode_chunk(
                 params, cache, rows,
                 jnp.reshape(off, (1,)).astype(jnp.int32), model_cfg,
-                uniform_pos=True, lane=lane)[1]
+                uniform_pos=True, lane=lane,
+                n_real=pool[0] if pool else None)[1]
         if constrain is not None:
             cache = constrain(cache)
         # "kv_slab": the lane cut out of the slab here and put back
@@ -561,11 +564,33 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
                           sum(len(v) for v in out.values()))
         return out
 
+    # The routes of the round last read (a typed stack with sparse
+    # layers: ``[n, sparse layers, lanes, k]``, an assignment's index
+    # among the held experts), and the round's counts made of them.
+    _routes = None
+    _moe_round = None
+
     def _read_tokens(self, toks) -> np.ndarray:
         """The second of a decode round's two hooks (the first is
         ``_dispatch_step``): the round's ``[lanes, n]`` tokens on the
-        host; blocks until the device has finished the round."""
+        host; blocks until the device has finished the round.  A
+        routed step's routes come in the same read."""
+        if isinstance(toks, tuple):
+            toks, routes = toks
+            self._routes = np.asarray(routes)
         return np.asarray(toks)
+
+    def _count_routes(self, lanes) -> None:
+        """``serving.round``'s ``moe_*`` counts of the round just read,
+        over the lanes that were decoding at its dispatch: assignments
+        routed, those that fell on held experts, the most one held
+        expert got in one layer of one step."""
+        routes, self._routes = self._routes, None
+        held = len(self.cfg.experts_held)
+        mine = routes[:, :, [lane for lane, _ in lanes]]
+        per_expert = (mine[..., None] == np.arange(held)).sum(axis=(2, 3))
+        self._moe_round = (int(mine.size), int(per_expert.sum()),
+                           int(per_expert.max(initial=0)))
 
     def _collect(self, pending) -> dict:
         """Read a dispatched round and emit it: ``pending`` is the
@@ -579,6 +604,8 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
             toks = self._read_tokens(dev)
             if sp is not None:
                 sp.fields["wait_ms"] = (time.perf_counter() - t0) * 1e3
+        if self._routes is not None and obs.active() is not None:
+            self._count_routes(lanes)
         return self._emit(lambda lane: toks[lane].tolist(), lanes)
 
     def _flush_round(self) -> bool:
@@ -609,24 +636,36 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
         counts: the count is the dispatch's, not the transcript's), an
         admitting lane what lies before its next chunk.  ``chunks`` is the admission
         programs dispatched since the previous decode dispatch."""
-        busy = admitting = kv_live = 0
+        busy = admitting = kv_live = kv_live_window = 0
+        window = self.cfg.sliding_window or 0
         for st in self._lane_state:
             if st is None or st.done:
                 continue
             busy += 1
             if st.chunks is not None:
                 admitting += 1
-                kv_live += st.chunks[0][0]
+                live = st.chunks[0][0]
             else:
-                kv_live += (st.off + st.prompt_len - 1
-                            + min(st.launched, st.max_new))
+                live = (st.off + st.prompt_len - 1
+                        + min(st.launched, st.max_new))
+            kv_live += live
+            kv_live_window += min(live, window)
         obs.gauge("serving.lanes_busy", busy)
+        moe, self._moe_round = self._moe_round, None
         if rnd is not None:
             rnd.fields.update(
                 lanes_busy=busy, lanes_admitting=admitting,
                 kv_live=kv_live, chunks=chunks,
                 tokens=sum(len(v) for v in out.values()),
                 passes=self.cfg.n_passes)
+            if self.cfg.kv_ring_planes:
+                # Ring planes: what of a lane's positions a window
+                # layer still holds (sum of min(position, window)).
+                rnd.fields["kv_live_window"] = kv_live_window
+            if moe is not None:
+                # Of the round READ here (like ``tokens``).
+                (rnd.fields["moe_assigned"], rnd.fields["moe_held"],
+                 rnd.fields["moe_max"]) = moe
             if idle:
                 rnd.fields["idle"] = True
             if overlapped:
@@ -655,12 +694,19 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
                       request_id=st.request_id,
                       attended=self._attended(self.cache, start,
                                               rows.shape[1])):
-            self._exec_chunk(lane, start, rows)
+            self._exec_chunk(lane, start, rows, **self._real(end - start))
         self._admit_programs += 1
         if not st.chunks:
             self._admitting.popleft()
             st.chunks = None
             self._finish_admission(lane, st)
+
+    def _real(self, n: int) -> dict:
+        """``n_real=`` of an admission dispatch — how many of the
+        chunk's tokens are real, which a program that writes ring
+        planes takes as one argument more — or nothing: every other
+        engine's programs keep their signature."""
+        return {"n_real": n} if self.cfg.kv_ring_planes else {}
 
     def _exec_chunk(self, lane, start, rows):  # pragma: no cover
         raise NotImplementedError(

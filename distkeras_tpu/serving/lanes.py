@@ -596,10 +596,30 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
         reader needs to turn ``serving.round``'s ``kv_live`` slots
         into bytes without knowing the model."""
         cfg = self.cfg
+        slab = sum(int(a.nbytes) for a in jax.tree.leaves(self.cache))
+        if cfg.typed:
+            # Two kinds of plane in one slab: a lane's slot costs the
+            # full planes' bytes at every position and the rings' at
+            # its last ``window`` positions only (``kv_live`` and
+            # ``kv_live_window`` of ``serving.round`` count the two).
+            # ``bytes_per_slot`` keeps its meaning: the slab over the
+            # lanes' max_len positions.  (Keywords spelled out: the
+            # contract lint reads an event's labels off its call.)
+            slots = self.lanes * cfg.max_len
+            per = 2 * cfg.kv_heads * cfg.head_dim * self.cache["k"].itemsize
+            obs.event("serving.kv_layout", passes=cfg.n_passes,
+                      layers=cfg.n_layers, planes=cfg.kv_planes,
+                      bytes_per_slot=slab // slots, slots=slots,
+                      slab_bytes=slab, planes_full=cfg.kv_planes,
+                      planes_window=cfg.kv_ring_planes,
+                      window=cfg.sliding_window,
+                      ring_slots=cfg.sliding_window,
+                      bytes_per_slot_full=per * cfg.kv_planes,
+                      bytes_per_slot_window=per * cfg.kv_ring_planes)
+            return
         # [planes, lanes, max_len, ...] (the paged store: [planes,
         # blocks, block, ...]): slots are rows x positions.
         slots = int(np.prod(self.cache["k"].shape[1:3]))
-        slab = sum(int(a.nbytes) for a in jax.tree.leaves(self.cache))
         obs.event("serving.kv_layout", passes=cfg.n_passes,
                   layers=cfg.n_layers, planes=cfg.kv_planes,
                   bytes_per_slot=slab // slots, slots=slots,
@@ -619,7 +639,7 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
             self._dispatch_step(n)
         for width in self._buckets:
             rows = np.zeros((1, width), np.int32)
-            self._exec_admit(0, self._off, rows, None)
+            self._exec_admit(0, self._off, rows, None, **self._real(width))
             if self._admit_cont not in (None, self._admit):
                 self._exec_chunk(0, self._off, rows)
         if pool is not None:
@@ -694,9 +714,20 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
             return jax.random.categorical(
                 jax.random.fold_in(k, q), row)
 
+        # A typed stack with sparse layers: the step also yields each
+        # lane's routes ([sparse layers, lanes, k]: the index among the
+        # held experts of every assignment), the round's moe_* counts.
+        routed = cfg.typed and "sparse" in cfg.ffn_types
+
         def one_step_p(params, cache, cur, pos, keys, temps, tps, mps):
-            logits, cache = _decode_chunk(
-                params, cache, cur[:, None], pos, cfg)
+            routes = None
+            if routed:
+                logits, cache, routes = _decode_chunk(
+                    params, cache, cur[:, None], pos, cfg, with_routes=True)
+                routes = routes[:, :, 0]
+            else:
+                logits, cache = _decode_chunk(
+                    params, cache, cur[:, None], pos, cfg)
             logits = logits[:, 0]                      # [lanes, V]
             if per_request_sampling:
                 # Vectorized per-lane params: greedy lanes (t <= 0)
@@ -754,7 +785,7 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
             # stops the clock entirely.
             nxt_pos = (pos + 1 if self._rolling
                        else jnp.minimum(pos + 1, cfg.max_len - 1))
-            return cache, nxt.astype(jnp.int32), nxt_pos
+            return cache, nxt.astype(jnp.int32), nxt_pos, routes
 
         if self._hot_swap:
             # Hot-swap engines thread the params through as the first
@@ -782,15 +813,16 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
 
                 def body(carry, _):
                     cache, cur, pos = carry
-                    cache, cur, pos = one_step(params, cache, cur,
-                                               pos, keys, temps, tps,
-                                               mps)
-                    return (cache, cur, pos), cur
-                (cache, cur, pos), toks = jax.lax.scan(
+                    cache, cur, pos, routes = one_step(
+                        params, cache, cur, pos, keys, temps, tps, mps)
+                    return (cache, cur, pos), (cur, routes)
+                (cache, cur, pos), (toks, routes) = jax.lax.scan(
                     body, (cache, cur, pos), None, length=n)
                 if constrain is not None:
                     cache = constrain(cache)
-                return cache, cur, pos, toks.T    # [lanes, n]
+                toks = toks.T                     # [lanes, n]
+                return cache, cur, pos, (toks if routes is None
+                                         else (toks, routes))
             # Donate the cache (now argument 1); params are NOT
             # donated — version N must survive the swap for rollback.
             return jax.jit(step_n_p, donate_argnums=1)
@@ -806,14 +838,16 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
 
             def body(carry, _):
                 cache, cur, pos = carry
-                cache, cur, pos = one_step(cache, cur, pos, keys,
-                                           temps, tps, mps)
-                return (cache, cur, pos), cur
-            (cache, cur, pos), toks = jax.lax.scan(
+                cache, cur, pos, routes = one_step(cache, cur, pos, keys,
+                                                   temps, tps, mps)
+                return (cache, cur, pos), (cur, routes)
+            (cache, cur, pos), (toks, routes) = jax.lax.scan(
                 body, (cache, cur, pos), None, length=n)
             if constrain is not None:
                 cache = constrain(cache)
-            return cache, cur, pos, toks.T        # [lanes, n]
+            toks = toks.T                         # [lanes, n]
+            return cache, cur, pos, (toks if routes is None
+                                     else (toks, routes))
         return jax.jit(step_n, donate_argnums=0)
 
     def _build_admission_programs(self) -> None:
@@ -916,7 +950,13 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
             raise ValueError(
                 f"chunked admission grid overflows the cache (chunk at "
                 f"{plan[-1][0]} + {w_chunk} > {self.cfg.max_len})")
-        if rem:
+        if rem and self.cfg.kv_ring_planes:
+            # Ring planes: the tail stays on the grid and its padding
+            # goes unwritten (``n_real``) — a backed-up tail would ask a
+            # ring for positions the chunk before has rolled out of it.
+            plan.append((lo + m * w_chunk,
+                         self._bucket_for(rem, lo + m * w_chunk)))
+        elif rem:
             # The chunk width is always a bucket (the constructor adds
             # it), so the smallest bucket >= rem is <= w_chunk < span:
             # the backed-up start always lands inside the grid, never
@@ -939,9 +979,10 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
         whatever _admission_plan staged (no-op for monolithic lanes;
         the paged engine frees the staged blocks)."""
 
-    def _exec_admit(self, lane, start, rows, slot) -> None:
+    def _exec_admit(self, lane, start, rows, slot, n_real=None) -> None:
         """Execute the FIRST admission chunk (the one that seeds the
         lane) — ``slot`` is the pinned prefix-pool slot or None."""
+        real = () if n_real is None else (jnp.int32(n_real),)
         if slot is not None:
             self.cache = self._admit(
                 self.cache, jnp.asarray(rows), jnp.int32(lane),
@@ -957,7 +998,8 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
         else:
             self.cache = self._admit(*self._pargs(), self.cache,
                                      jnp.asarray(rows),
-                                     jnp.int32(lane), jnp.int32(start))
+                                     jnp.int32(lane), jnp.int32(start),
+                                     *real)
 
     def _exec_reseed(self, lane, slot) -> None:
         """No admission chunk ran (1-token prompt) but the lane still
@@ -989,10 +1031,12 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
         rows[0, :hi - lo] = prompt[lo:hi]
         return rows
 
-    def _exec_chunk(self, lane, start, rows):
+    def _exec_chunk(self, lane, start, rows, n_real=None):
+        real = () if n_real is None else (jnp.int32(n_real),)
         self.cache = self._admit_cont(*self._pargs(), self.cache,
                                       jnp.asarray(rows),
-                                      jnp.int32(lane), jnp.int32(start))
+                                      jnp.int32(lane), jnp.int32(start),
+                                      *real)
 
     def _finish_admission(self, lane, st):
         """Last chunk landed: un-park the lane — set its decode
@@ -1155,7 +1199,8 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
                               request_id=rid,
                               attended=self._attended(
                                   self.cache, start0, width0)):
-                    self._exec_admit(lane, start0, rows, slot)
+                    self._exec_admit(lane, start0, rows, slot,
+                                     **self._real(filled - start0))
                 self._admit_programs += 1
                 if len(plan) > 1:
                     chunks = [(s, self._chunk_rows(prompt, off, s, w))
@@ -1225,7 +1270,8 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
         pargs = self._pargs()  # hot-swap engines take params first
         d = len(pargs)
         admit_args = pargs + (self.cache, rows, jnp.int32(0),
-                              jnp.int32(self._off))
+                              jnp.int32(self._off)) + tuple(
+            jnp.int32(n) for n in self._real(rows.shape[1]).values())
         if self._prefix_pool is not None:
             admit_args += (self._prefix_pool.slab, jnp.int32(0))
         return [
@@ -1327,7 +1373,8 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
                 with obs.span("serving.step", n=n,
                               attended=self._step_attended(n)):
                     toks = self._dispatch_step(n)
-                    toks.copy_to_host_async()
+                    for a in jax.tree.leaves(toks):
+                        a.copy_to_host_async()
                 for _, s in lanes:
                     s.launched += n
                 launched = (toks, n, lanes)

@@ -477,8 +477,8 @@ class PagedBatcher(ContinuousBatcher):
 
             def body(carry, _):
                 view, cur, pos = carry
-                view, cur, pos = one_step(view, cur, pos, keys, temps,
-                                          tps, mps)
+                view, cur, pos, _ = one_step(view, cur, pos, keys, temps,
+                                             tps, mps)
                 return (view, cur, pos), cur
 
             (view, cur2, pos2), toks = jax.lax.scan(

@@ -6,6 +6,8 @@ materialized-logits oracle (SURVEY.md §4 test strategy: numerics vs a
 hand-rolled reference).
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -713,3 +715,37 @@ def test_decode_kernel_lowers_for_tpu(slab, h, t):
     assert "flash_decode_fwd" in text
     # Nothing is cut out of the slab in front of the call.
     assert "dynamic_slice" not in text and "dynamic-slice" not in text
+
+
+def test_head_major_kernels_and_the_grouped_product_lower_for_tpu(
+        monkeypatch):
+    """A typed stack's planes are K/V-head-major: the prefix kernel
+    takes them as they lie and the decode kernel their free view of one
+    K/V head a row, at 8 K/V heads of 128 under 64 query heads (no
+    token-major tiling exists: ``decode_block`` says so); the held
+    experts' grouped product at the benchmark's widths, a group's whole
+    stack of 3 layers x 16 experts handed over."""
+    from distkeras_tpu.ops import attention, grouped
+
+    x = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    assert decode_block(1, 8192, 128, 64, 8, jnp.bfloat16) is None
+    assert decode_block(1, 8192, 128, 8, 1, jnp.bfloat16) == 2048
+    text = _tpu_lower(
+        functools.partial(flash_prefix_attention, head_major=True),
+        x(1, 512, 64, 128), x(1, 8, 8192, 128), x(1, 8, 8192, 128),
+        i32()).as_text()
+    assert text.count("tpu_custom_call") == 1 and "flash_prefix_fwd" in text
+    text = _tpu_lower(flash_decode_attention, x(16 * 8, 1, 8, 128),
+                      x(1, 16 * 8, 8192, 1, 128), x(1, 16 * 8, 8192, 1, 128),
+                      i32(), i32(16 * 8)).as_text()
+    assert text.count("tpu_custom_call") == 1 and "flash_decode_fwd" in text
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(grouped, "_on_tpu", lambda: True)
+    assert grouped.grouped_tiles(6144, 4096, jnp.bfloat16) == (128, 2048,
+                                                                1024)
+    text = _tpu_lower(grouped.grouped_matmul, x(1520, 6144),
+                      x(48, 6144, 4096), i32(48)).as_text()
+    # The name the trace gives the kernel's calls (``mosaic:gmm``, read
+    # by benchmarks/readers/moe_gmm_roofline.py) is that library's.
+    assert text.count("tpu_custom_call") >= 1 and "gmm" in text
