@@ -49,6 +49,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
@@ -213,9 +214,9 @@ def blockwise_attention(q, k, v, causal: bool = False,
 # ------------------------------------------------------------- Pallas kernel
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, *refs, causal: bool, scale: float,
-                  with_lse: bool, window: int | None = None,
-                  segmented: bool = False):
+def _flash_kernel(*refs, causal: bool, scale: float, with_lse: bool,
+                  window: int | None = None, segmented: bool = False,
+                  tiles: tuple | None = None, heads: int = 1):
     """Flash-attention forward for one (batch*head, q-block, kv-block) cell.
 
     KV streams through the grid's innermost dimension so VMEM holds only
@@ -235,7 +236,17 @@ def _flash_kernel(q_ref, k_ref, v_ref, *refs, causal: bool, scale: float,
     ``segmented``: two extra int32 inputs (q/k segment-id tiles, laid
     out by :func:`_segment_operands`) gate the logits to within-segment
     pairs — packed-document masking.
+
+    ``tiles`` (:func:`_tile_bounds`; a segmented launch): two leading
+    scalar-prefetch refs give, per (row, q tile), the first and last k
+    tile that holds an attended pair.  The block is then walked as
+    ``tiles = (tile_q, tile_k)`` sub-tiles and one outside its q
+    tile's range is not multiplied.  ``heads``: grid index -> row.
     """
+    bounds = None
+    if tiles is not None:
+        bounds, refs = refs[:2], refs[2:]
+    q_ref, k_ref, v_ref, *refs = refs
     if segmented:
         qseg_ref, kseg_ref, *refs = refs
     if with_lse:
@@ -246,6 +257,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, *refs, causal: bool, scale: float,
     n_kb = pl.num_programs(2)
     block_q = q_ref.shape[1]
     block_k = k_ref.shape[1]
+    tile_q, tile_k = tiles or (block_q, block_k)
 
     @pl.when(j == 0)
     def _init():
@@ -265,31 +277,37 @@ def _flash_kernel(q_ref, k_ref, v_ref, *refs, causal: bool, scale: float,
     else:
         col0, live = _banded_cols(row0, j, n_kb, block_q, block_k, window)
 
-    @pl.when(live)
-    def _update():
-        qi = jax.lax.convert_element_type(q_ref[0], jnp.float32) * scale
-        kj = jax.lax.convert_element_type(k_ref[0], jnp.float32)
-        vj = jax.lax.convert_element_type(v_ref[0], jnp.float32)
+    def _update(r, u):
+        rows, cols = _tile_at(r, tile_q), _tile_at(u, tile_k)
+        qi = jax.lax.convert_element_type(q_ref[0, rows], jnp.float32) * scale
+        kj = jax.lax.convert_element_type(k_ref[0, cols], jnp.float32)
+        vj = jax.lax.convert_element_type(v_ref[0, cols], jnp.float32)
         logits = jax.lax.dot_general(
             qi, kj, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)  # [block_q, block_k]
+            preferred_element_type=jnp.float32)  # [tile_q, tile_k]
         if causal:
-            logits = jnp.where(_keep_mask(logits.shape, row0, col0, window),
-                               logits, NEG_INF)
+            logits = jnp.where(
+                _keep_mask(logits.shape, _plus(row0, r * tile_q),
+                           _plus(col0, u * tile_k), window),
+                logits, NEG_INF)
         if segmented:
-            logits = jnp.where(_same_segment(qseg_ref, kseg_ref),
+            logits = jnp.where(_same_segment(qseg_ref, kseg_ref, rows, u),
                                logits, NEG_INF)
-        m = m_scr[:, :1]
-        l = l_scr[:, :1]
+        m = m_scr[rows, :1]
+        l = l_scr[rows, :1]
         m_new = jnp.maximum(m, logits.max(axis=-1, keepdims=True))
         corr = jnp.exp(m - m_new)
         p = jnp.exp(logits - m_new)
         l_new = l * corr + p.sum(axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
+        acc_scr[rows] = acc_scr[rows] * corr + jax.lax.dot_general(
             p, vj, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        m_scr[rows] = jnp.broadcast_to(m_new, (tile_q, m_scr.shape[1]))
+        l_scr[rows] = jnp.broadcast_to(l_new, (tile_q, l_scr.shape[1]))
+
+    _walk_tiles(_update, live, bounds, heads,
+                resident=(row0, block_q, tile_q),
+                streamed=(col0, block_k, tile_k))
 
     @pl.when(j == n_kb - 1)
     def _finish():
@@ -311,40 +329,190 @@ def _flash_kernel(q_ref, k_ref, v_ref, *refs, causal: bool, scale: float,
 _SUBLANES, _LANES = 8, 128
 
 
-def _segment_operands(segment_ids, h: int, q_at, k_at):
+def _segment_operands(segment_ids, h: int, q_at, k_at, tiles=None):
     """Segment ids as kernel operands: ``(in_specs, args)``.
 
     The q side is lane-broadcast ``[B, S, 128]`` (the kernel reads a
-    ``[block_q, 1]`` column), the k side sublane-broadcast ``[B, 8, S]``
-    (read as a ``[1, block_k]`` row), so both blocks respect the TPU
-    tile and the comparison broadcasts with no in-kernel transpose.
-    Heads share their batch row: the index maps divide the flattened
-    batch*head grid index by ``h`` instead of repeating the ids per
-    head.  ``q_at``/``k_at`` are the ``(block, index_map)`` pairs of the
+    ``[tile_q, 1]`` column), the k side one sublane-broadcast
+    ``[8, tile_k]`` slab a k tile, ``[B, S / tile_k * 8, tile_k]``
+    (read as a ``[1, tile_k]`` row; a tile of the block is a row slice,
+    never a lane slice), so both blocks respect the TPU tile and the
+    comparison broadcasts with no in-kernel transpose.  Heads share
+    their batch row: the index maps divide the flattened batch*head
+    grid index by ``h`` instead of repeating the ids per head.
+    ``q_at``/``k_at`` are the ``(block, index_map)`` pairs of the
     rank-3 q/k tiles; the segment tiles ride the SAME sequence block
-    index, so banded walks stay in lockstep.
+    index, so banded walks stay in lockstep.  ``tiles``: the launch's
+    ``(tile_q, tile_k)`` (None: a block is one tile).
     """
     seg = segment_ids.astype(jnp.int32)
     b, s = seg.shape
     (_, block_q, _), q_map = q_at
     (_, block_k, _), k_map = k_at
+    tile_k = tiles[1] if tiles else block_k
     specs = [
         pl.BlockSpec((1, block_q, _LANES),
-                     lambda bh, i, j: (bh // h, q_map(bh, i, j)[1], 0),
+                     lambda bh, *g: (bh // h, q_map(bh, *g)[1], 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, _SUBLANES, block_k),
-                     lambda bh, i, j: (bh // h, 0, k_map(bh, i, j)[1]),
+        pl.BlockSpec((1, block_k // tile_k * _SUBLANES, tile_k),
+                     lambda bh, *g: (bh // h, k_map(bh, *g)[1], 0),
                      memory_space=pltpu.VMEM),
     ]
+    by_tile = jnp.broadcast_to(seg.reshape(b, s // tile_k, 1, tile_k),
+                               (b, s // tile_k, _SUBLANES, tile_k))
     return specs, [jnp.broadcast_to(seg[:, :, None], (b, s, _LANES)),
-                   jnp.broadcast_to(seg[:, None, :], (b, _SUBLANES, s))]
+                   by_tile.reshape(b, -1, tile_k)]
 
 
-def _same_segment(qseg_ref, kseg_ref):
-    """[block_q, block_k] within-segment mask from the tiles of
+def _same_segment(qseg_ref, kseg_ref, rows, u):
+    """[tile_q, tile_k] within-segment mask of q rows ``rows`` and k
+    tile ``u`` of the block, from the tiles of
     :func:`_segment_operands` — the ONE definition all three kernels
     share."""
-    return qseg_ref[0][:, :1] == kseg_ref[0][:1, :]
+    return qseg_ref[0, rows][:, :1] == kseg_ref[0, _tile_at(u, _SUBLANES)][:1]
+
+
+# ------------------------------------------ tiles no document spans
+#
+# A packed row's documents make most of the causal band dead: a tile
+# whose q rows and k columns share no segment id holds no attended
+# pair.  A segmented launch finds those tiles from the ids (plain
+# jax.numpy, inside the jitted step) and hands each kernel, as scalar
+# prefetch, the first and last live tile of the streamed side for every
+# tile of the resident side.  The kernels' predicate and the streamed
+# operands' index maps read the same two arrays.
+
+
+def _tile_band(n_q: int, n_k: int, tile_q: int, tile_k: int, causal: bool,
+               window: int | None):
+    """``[n_q, n_k]`` bool (numpy): tiles that hold a pair the causal,
+    windowed mask keeps."""
+    row0 = np.arange(n_q)[:, None] * tile_q
+    col0 = np.arange(n_k)[None, :] * tile_k
+    band = np.ones((n_q, n_k), bool)
+    if causal:
+        band &= col0 <= row0 + tile_q - 1
+    if window is not None:
+        band &= col0 + tile_k - 1 >= row0 - (window - 1)
+    return band
+
+
+def _live_tiles(seg, tile_q: int, tile_k: int, causal: bool,
+                window: int | None):
+    """``[B, S/tile_q, S/tile_k]`` bool: tiles inside the band whose q
+    rows' and k columns' segment-id ranges overlap.  Safe for any ids
+    (no equal pair exists where the ranges do not meet), exact for ids
+    that do not decrease along a row before its padding
+    (``pack_documents``).  numpy in, numpy out."""
+    b, s = seg.shape
+    # Padding (id 0) trails a packed row: ranked last, the row's ids do
+    # not decrease and the test stays exact in its last tiles too.
+    seg = seg + (seg == 0) * jnp.iinfo(jnp.int32).max
+    qt = seg.reshape(b, s // tile_q, tile_q)
+    kt = seg.reshape(b, s // tile_k, tile_k)
+    overlap = ((qt.min(-1)[:, :, None] <= kt.max(-1)[:, None, :])
+               & (kt.min(-1)[:, None, :] <= qt.max(-1)[:, :, None]))
+    return overlap & _tile_band(s // tile_q, s // tile_k, tile_q, tile_k,
+                                causal, window)
+
+
+def _first_last(live, axis: int, xp=jnp):
+    """First and last True along ``axis`` (every resident tile has its
+    diagonal tile live; were one empty it would read 0 .. n-1)."""
+    n = live.shape[axis]
+    return (xp.argmax(live, axis=axis),
+            n - 1 - xp.argmax(xp.flip(live, axis), axis=axis))
+
+
+def _tile_bounds(segment_ids, tile_q: int, tile_k: int, causal: bool,
+                 window: int | None, resident: str):
+    """The kernels' scalar-prefetch operands: int32 ``(lo, hi)``, each
+    ``[B * tiles a row]`` — per (row, tile of the ``resident`` side:
+    ``"q"`` forward and dQ, ``"k"`` dK/dV) the first and last live tile
+    of the streamed side.  A tile between them is computed."""
+    live = _live_tiles(segment_ids.astype(jnp.int32), tile_q, tile_k,
+                       causal, window)
+    lo, hi = _first_last(live, 2 if resident == "q" else 1)
+    return (lo.astype(jnp.int32).reshape(-1),
+            hi.astype(jnp.int32).reshape(-1))
+
+
+def live_tile_share(segments, block_q: int, block_k: int,
+                    window: int | None = None) -> float:
+    """Tiles the segmented kernels compute / tiles of the causal
+    (windowed) band, at ``block_q x block_k`` tiles over packed rows
+    ``segments [rows, S]``: the kernels' predicate, in numpy on the
+    host (``train.attn_live_tile_share``)."""
+    seg = np.asarray(segments)
+    live = _live_tiles(seg, block_q, block_k, True, window)
+    lo, hi = _first_last(live, 2, xp=np)
+    at = np.arange(live.shape[2])
+    computed = (lo[..., None] <= at) & (at <= hi[..., None])
+    band = _tile_band(*live.shape[1:], block_q, block_k, True, window)
+    return float(computed.sum() / (len(seg) * band.sum()))
+
+
+def _skip_dead(index_map, segment_ids, tiles, blocks, causal: bool,
+               window: int | None, resident: str, heads: int):
+    """``(index_map, bounds)`` of a launch's streamed operands.  With
+    ``tiles``: the bounds of :func:`_tile_bounds`, and the map under
+    scalar prefetch — a step whose block holds no live tile maps to the
+    nearest block that does, so the pipeline sees an unchanged index
+    and issues no copy (as the banded maps' clamped duplicates already
+    do).  Without: the map as it is, and None."""
+    if tiles is None:
+        return index_map, None
+    side = 0 if resident == "q" else 1
+    per_block = blocks[side] // tiles[side]         # resident tiles a block
+    per_row = segment_ids.shape[1] // tiles[side]
+    streamed = blocks[1 - side] // tiles[1 - side]  # streamed tiles a block
+
+    def bounded(bh, i, j, lo_ref, hi_ref):
+        b_, block, z = index_map(bh, i, j)
+        at = (bh // heads) * per_row + i * per_block
+        lo, hi = lo_ref[at], hi_ref[at]
+        for r in range(1, per_block):
+            lo = jnp.minimum(lo, lo_ref[at + r])
+            hi = jnp.maximum(hi, hi_ref[at + r])
+        return b_, jnp.clip(block, lo // streamed, hi // streamed), z
+
+    return bounded, _tile_bounds(segment_ids, *tiles, causal, window,
+                                 resident)
+
+
+def _walk_tiles(update, live, bounds, heads: int, resident, streamed,
+                resident_is_q: bool = True):
+    """Run ``update(r, u)`` (q tile ``r``, k tile ``u`` of the block) for
+    a live block.  ``bounds`` None: the block is one tile.  Else
+    ``(lo_ref, hi_ref)``: every tile that lies inside its resident
+    tile's live range — ONE body in a loop over the block's tiles, so a
+    kernel's program does not grow with them.  ``resident`` /
+    ``streamed``: ``(first position, block, tile)`` of either side."""
+    if bounds is None:
+        pl.when(live)(functools.partial(update, 0, 0))
+        return
+    lo_ref, hi_ref = bounds
+    (res0, res_block, res_tile), (str0, str_block, str_tile) = \
+        resident, streamed
+    n_res, n_str = res_block // res_tile, str_block // str_tile
+    per_row = pl.num_programs(1) * n_res
+    first = (pl.program_id(0) // heads) * per_row + res0 // res_tile
+
+    def tile(a, c):
+        at = str0 // str_tile + c
+        inside = (lo_ref[first + a] <= at) & (at <= hi_ref[first + a])
+        pl.when(inside)(functools.partial(
+            update, *((a, c) if resident_is_q else (c, a))))
+
+    @pl.when(live)
+    def _block():
+        if n_res * n_str == 1:
+            tile(0, 0)
+        else:
+            jax.lax.fori_loop(
+                0, n_res * n_str,
+                lambda t, carry: (tile(t // n_str, t % n_str), carry)[1],
+                None)
 
 
 # The mesh axes (parallel/mesh.py::AXES) that carry the batch and the
@@ -413,6 +581,20 @@ def _banded_cols(row0, j, n_inner: int, block_q: int, block_k: int,
     return col0, live
 
 
+def _plus(base, offset):
+    """``base + offset`` that traces nothing for a tile at its block's
+    start: the launch without tiles is the program it always was."""
+    return base if isinstance(offset, int) and offset == 0 else base + offset
+
+
+def _tile_at(index, size: int):
+    """Rows of tile ``index``: static for a Python ``index``, a dynamic
+    slice aligned to ``size`` for a traced one (the loop over tiles)."""
+    if isinstance(index, int):
+        return pl.ds(index * size, size)
+    return pl.ds(pl.multiple_of(index * size, size), size)
+
+
 def _keep_mask(shape, row0, col0, window):
     """Causal (optionally banded) keep-mask for a [block_q, block_k]
     logits tile at global offsets (row0, col0) — shared by all three
@@ -465,9 +647,10 @@ def _flash_pallas(q, k, v, causal, scale, block_q, block_k, interpret=False,
     lk = k.shape[1]
     block_q, block_k = _require_fit(block_q, lq), _require_fit(block_k, lk)
     local = functools.partial(
-        _flash_fwd_local, causal=causal, scale=scale, block_q=block_q,
+        _flash_fwd_local if segment_ids is None else _flash_fwd_segmented,
+        causal=causal, scale=scale, block_q=block_q,
         block_k=block_k, interpret=interpret, with_lse=with_lse,
-        window=window)
+        window=window, tiles=_segment_tiles("flash_fwd", block_q, block_k))
     seg = [] if segment_ids is None else [segment_ids]
     res = _per_shard(
         local,
@@ -477,26 +660,52 @@ def _flash_pallas(q, k, v, causal, scale, block_q, block_k, interpret=False,
     return res if with_lse else (res[0], None)
 
 
+def _launch(kernel, name, grid, in_specs, out_specs, out_shape,
+            scratch_shapes, cost_estimate, args, bounds=None):
+    """One ``pallas_call``: today's plain grid, or — ``bounds``, the
+    ``(lo, hi)`` of :func:`_tile_bounds` — the same grid under scalar
+    prefetch, the bounds first among the kernel's and the index maps'
+    arguments."""
+    if bounds is None:
+        return pl.pallas_call(
+            kernel, name=name, grid=grid, in_specs=in_specs,
+            out_specs=out_specs, out_shape=out_shape,
+            scratch_shapes=scratch_shapes,
+            cost_estimate=cost_estimate)(*args)
+    return pl.pallas_call(
+        kernel, name=name,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch_shapes),
+        out_shape=out_shape, cost_estimate=cost_estimate,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=SEGMENT_VMEM_BYTES))(*bounds, *args)
+
+
 def _flash_fwd_local(q, k, v, segment_ids=None, *, causal, scale, block_q,
-                     block_k, interpret, with_lse, window):
+                     block_k, interpret, with_lse, window, tiles=None,
+                     skip_dead=True):
     """The forward launch on ONE device's operands (the whole arrays, or
     its batch/head shard under :func:`_per_shard`): ``(out,)`` or
-    ``(out, lse)``."""
+    ``(out, lse)``.  With ``segment_ids`` the launch skips the tiles no
+    document spans, at ``tiles`` (None: whole blocks); ``skip_dead``
+    False (tests only) masks them instead, as a launch did before
+    the skip."""
     b, lq, h, d = q.shape
     lk = k.shape[1]
     segmented = segment_ids is not None
+    tiles = (tiles or (block_q, block_k)) if segmented and skip_dead else None
     qf = q.transpose(0, 2, 1, 3).reshape(b * h, lq, d)
     kf = k.transpose(0, 2, 1, 3).reshape(b * h, lk, d)
     vf = v.transpose(0, 2, 1, 3).reshape(b * h, lk, d)
     kernel = functools.partial(_flash_kernel, causal=causal, scale=scale,
                                with_lse=with_lse, window=window,
-                               segmented=segmented)
+                               segmented=segmented, tiles=tiles, heads=h)
 
-    q_at = ((1, block_q, d), lambda bh, i, j: (bh, i, 0))
+    q_at = ((1, block_q, d), lambda bh, i, j, *_: (bh, i, 0))
     o_spec = pl.BlockSpec(*q_at, memory_space=pltpu.VMEM)
     o_shape = jax.ShapeDtypeStruct((b * h, lq, d), q.dtype)
-    lse_spec = pl.BlockSpec((1, block_q, _LANES),
-                            lambda bh, i, j: (bh, i, 0),
+    lse_spec = pl.BlockSpec((1, block_q, _LANES), q_at[1],
                             memory_space=pltpu.VMEM)
     lse_shape = jax.ShapeDtypeStruct((b * h, lq, _LANES), jnp.float32)
     out_bytes = o_shape.size * q.dtype.itemsize + (
@@ -507,6 +716,8 @@ def _flash_fwd_local(q, k, v, segment_ids=None, *, causal, scale, block_q,
         inner, kv_map = _banded_kv(window, block_q, block_k, n_kb)
     else:
         inner, kv_map = n_kb, (lambda bh, i, j: (bh, j, 0))
+    kv_map, bounds = _skip_dead(kv_map, segment_ids, tiles,
+                                (block_q, block_k), causal, window, "q", h)
     kv_at = ((1, block_k, d), kv_map)
 
     in_specs = [
@@ -516,28 +727,25 @@ def _flash_fwd_local(q, k, v, segment_ids=None, *, causal, scale, block_q,
     ]
     args = [qf, kf, vf]
     if segmented:
-        seg_specs, seg_args = _segment_operands(segment_ids, h, q_at, kv_at)
+        seg_specs, seg_args = _segment_operands(segment_ids, h, q_at, kv_at,
+                                                tiles)
         in_specs += seg_specs
         args += seg_args
 
-    def call(): return pl.pallas_call(
-        kernel,
-        name="flash_fwd",
-        grid=(b * h, lq // block_q, inner),
-        in_specs=in_specs,
-        out_specs=(o_spec, lse_spec) if with_lse else o_spec,
-        out_shape=(o_shape, lse_shape) if with_lse else o_shape,
-        scratch_shapes=[
+    def call(): return _launch(
+        kernel, "flash_fwd", (b * h, lq // block_q, inner), in_specs,
+        (o_spec, lse_spec) if with_lse else o_spec,
+        (o_shape, lse_shape) if with_lse else o_shape,
+        [
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # m (lane-broadcast)
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # l
             pltpu.VMEM((block_q, d), jnp.float32),       # acc
         ],
-        cost_estimate=pl.CostEstimate(
+        pl.CostEstimate(
             flops=4 * b * h * lq * lk * d,
             bytes_accessed=(qf.nbytes + kf.nbytes + vf.nbytes + out_bytes),
             transcendentals=b * h * lq * lk,
-        ),
-    )(*args)
+        ), args, bounds)
 
     if interpret:
         # The TPU-semantics interpreter: validates the kernel (incl.
@@ -552,10 +760,23 @@ def _flash_fwd_local(q, k, v, segment_ids=None, *, causal, scale, block_q,
     return (out, lse[:, :, 0].reshape(b, h, lq)) if with_lse else (out,)
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         *refs, causal: bool, scale: float,
+# The trainer unrolls its layers, and the segmented kernels (bounds,
+# loop, maps) take twice as long to trace and lower as the plain ones:
+# jitted, a step traces and lowers them once, not once a layer and
+# pass (4 layers, a CPU's seconds: 0.8 the first call and 0.3 after,
+# against 2.1 unjitted and 1.2 without segments; as
+# flash_prefix_attention).  The launch without segments stays as it
+# was, call for call.
+_flash_fwd_segmented = jax.jit(
+    _flash_fwd_local,
+    static_argnames=("causal", "scale", "block_q", "block_k", "interpret",
+                     "with_lse", "window", "tiles", "skip_dead"))
+
+
+def _flash_bwd_dq_kernel(*refs, causal: bool, scale: float,
                          window: int | None = None,
-                         segmented: bool = False):
+                         segmented: bool = False,
+                         tiles: tuple | None = None, heads: int = 1):
     """dQ for one (batch*head, q-block, kv-block) cell.
 
     FA2 backward: probabilities are rebuilt per tile from the saved
@@ -563,8 +784,13 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     softmax normalizer's gradient.  dq accumulates across the inner
     kv-block dimension in VMEM scratch.  Segment masking re-applies to
     the rebuilt logits (masked pairs rebuild p = 0, so their gradient
-    contribution vanishes exactly as in the forward).
+    contribution vanishes exactly as in the forward).  ``tiles``: as
+    :func:`_flash_kernel`.
     """
+    bounds = None
+    if tiles is not None:
+        bounds, refs = refs[:2], refs[2:]
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs = refs
     if segmented:
         qseg_ref, kseg_ref, dq_ref, dq_scr = refs
     else:
@@ -573,6 +799,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     n_kb = pl.num_programs(2)
     block_q = q_ref.shape[1]
     block_k = k_ref.shape[1]
+    tile_q, tile_k = tiles or (block_q, block_k)
     row0 = pl.program_id(1) * block_q
 
     @pl.when(j == 0)
@@ -585,38 +812,51 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     else:
         col0, live = _banded_cols(row0, j, n_kb, block_q, block_k, window)
 
-    @pl.when(live)
-    def _update():
-        qi = jax.lax.convert_element_type(q_ref[0], jnp.float32)
-        kj = jax.lax.convert_element_type(k_ref[0], jnp.float32)
-        vj = jax.lax.convert_element_type(v_ref[0], jnp.float32)
-        do = jax.lax.convert_element_type(do_ref[0], jnp.float32)
+    def _update(r, u):
+        rows, cols = _tile_at(r, tile_q), _tile_at(u, tile_k)
+        qi = jax.lax.convert_element_type(q_ref[0, rows], jnp.float32)
+        kj = jax.lax.convert_element_type(k_ref[0, cols], jnp.float32)
+        vj = jax.lax.convert_element_type(v_ref[0, cols], jnp.float32)
+        do = jax.lax.convert_element_type(do_ref[0, rows], jnp.float32)
         s = jax.lax.dot_general(qi, kj, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if causal:
-            s = jnp.where(_keep_mask(s.shape, row0, col0, window),
+            s = jnp.where(_keep_mask(s.shape, _plus(row0, r * tile_q),
+                                     _plus(col0, u * tile_k), window),
                           s, NEG_INF)
         if segmented:
-            s = jnp.where(_same_segment(qseg_ref, kseg_ref), s, NEG_INF)
-        p = jnp.exp(s - lse_ref[0][:, :1])
+            s = jnp.where(_same_segment(qseg_ref, kseg_ref, rows, u),
+                          s, NEG_INF)
+        p = jnp.exp(s - lse_ref[0, rows][:, :1])
         dp = jax.lax.dot_general(do, vj, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, :1]) * scale
-        dq_scr[:] += jax.lax.dot_general(
+        ds = p * (dp - delta_ref[0, rows][:, :1]) * scale
+        dq_scr[rows] += jax.lax.dot_general(
             ds, kj, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+    _walk_tiles(_update, live, bounds, heads,
+                resident=(row0, block_q, tile_q),
+                streamed=(col0, block_k, tile_k))
 
     @pl.when(j == n_kb - 1)
     def _finish():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          *refs, causal: bool,
+def _flash_bwd_dkv_kernel(*refs, causal: bool,
                           scale: float, window: int | None = None,
-                          n_qb_total: int = 0, segmented: bool = False):
+                          n_qb_total: int = 0, segmented: bool = False,
+                          tiles: tuple | None = None, heads: int = 1):
     """dK/dV for one (batch*head, kv-block, q-block) cell; q streams on
-    the inner grid dimension, accumulating into the kv block's scratch."""
+    the inner grid dimension, accumulating into the kv block's scratch.
+    ``tiles``: as :func:`_flash_kernel`, with the sides swapped — the
+    prefetched bounds are, per (row, k tile), the first and last live
+    q tile."""
+    bounds = None
+    if tiles is not None:
+        bounds, refs = refs[:2], refs[2:]
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs = refs
     if segmented:
         qseg_ref, kseg_ref, dk_ref, dv_ref, dk_scr, dv_scr = refs
     else:
@@ -625,6 +865,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     n_qb = pl.num_programs(2)
     block_k = k_ref.shape[1]
     block_q = q_ref.shape[1]
+    tile_q, tile_k = tiles or (block_q, block_k)
     col0 = pl.program_id(1) * block_k
 
     @pl.when(jq == 0)
@@ -647,29 +888,35 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 & (row0 + block_q - 1 >= col0)
                 & (row0 - (col0 + block_k - 1) < window))
 
-    @pl.when(live)
-    def _update():
-        qi = jax.lax.convert_element_type(q_ref[0], jnp.float32)
-        kj = jax.lax.convert_element_type(k_ref[0], jnp.float32)
-        vj = jax.lax.convert_element_type(v_ref[0], jnp.float32)
-        do = jax.lax.convert_element_type(do_ref[0], jnp.float32)
+    def _update(r, u):
+        rows, cols = _tile_at(r, tile_q), _tile_at(u, tile_k)
+        qi = jax.lax.convert_element_type(q_ref[0, rows], jnp.float32)
+        kj = jax.lax.convert_element_type(k_ref[0, cols], jnp.float32)
+        vj = jax.lax.convert_element_type(v_ref[0, cols], jnp.float32)
+        do = jax.lax.convert_element_type(do_ref[0, rows], jnp.float32)
         s = jax.lax.dot_general(qi, kj, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if causal:
-            s = jnp.where(_keep_mask(s.shape, row0, col0, window),
+            s = jnp.where(_keep_mask(s.shape, _plus(row0, r * tile_q),
+                                     _plus(col0, u * tile_k), window),
                           s, NEG_INF)
         if segmented:
-            s = jnp.where(_same_segment(qseg_ref, kseg_ref), s, NEG_INF)
-        p = jnp.exp(s - lse_ref[0][:, :1])  # [block_q, block_k]
-        dv_scr[:] += jax.lax.dot_general(
+            s = jnp.where(_same_segment(qseg_ref, kseg_ref, rows, u),
+                          s, NEG_INF)
+        p = jnp.exp(s - lse_ref[0, rows][:, :1])  # [tile_q, tile_k]
+        dv_scr[cols] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do, vj, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, :1]) * scale
-        dk_scr[:] += jax.lax.dot_general(
+        ds = p * (dp - delta_ref[0, rows][:, :1]) * scale
+        dk_scr[cols] += jax.lax.dot_general(
             ds, qi, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+    _walk_tiles(_update, live, bounds, heads,
+                resident=(col0, block_k, tile_k),
+                streamed=(row0, block_q, tile_q), resident_is_q=False)
 
     @pl.when(jq == n_qb - 1)
     def _finish():
@@ -684,8 +931,11 @@ def _flash_pallas_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k,
     block_q = _require_fit(block_q, q.shape[1])
     block_k = _require_fit(block_k, k.shape[1])
     local = functools.partial(
-        _flash_bwd_local, causal=causal, scale=scale, block_q=block_q,
-        block_k=block_k, interpret=interpret, window=window)
+        _flash_bwd_local if segment_ids is None else _flash_bwd_segmented,
+        causal=causal, scale=scale, block_q=block_q,
+        block_k=block_k, interpret=interpret, window=window,
+        tiles=_segment_tiles("flash_bwd_dq", block_q, block_k),
+        dkv_tiles=_segment_tiles("flash_bwd_dkv", block_q, block_k))
     seg = [] if segment_ids is None else [segment_ids]
     return _per_shard(
         local,
@@ -695,12 +945,19 @@ def _flash_pallas_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k,
 
 
 def _flash_bwd_local(q, k, v, out, lse, g, segment_ids=None, *, causal,
-                     scale, block_q, block_k, interpret, window):
+                     scale, block_q, block_k, interpret, window, tiles=None,
+                     dkv_tiles=None, skip_dead=True):
     """The two backward launches on ONE device's operands (see
-    :func:`_flash_fwd_local`): ``(dq, dk, dv)``."""
+    :func:`_flash_fwd_local`): ``(dq, dk, dv)``.  ``tiles`` are the dQ
+    launch's, ``dkv_tiles`` the dK/dV launch's (None: ``tiles``)."""
     b, lq, h, d = q.shape
     lk = k.shape[1]
     segmented = segment_ids is not None
+    if segmented and skip_dead:
+        tiles = tiles or (block_q, block_k)
+        dkv_tiles = dkv_tiles or tiles
+    else:
+        tiles = dkv_tiles = None
     flat = lambda a, L: a.transpose(0, 2, 1, 3).reshape(b * h, L, d)
     qf, kf, vf = flat(q, lq), flat(k, lk), flat(v, lk)
     dof, of = flat(g, lq), flat(out, lq)
@@ -710,83 +967,74 @@ def _flash_bwd_local(q, k, v, out, lse, g, segment_ids=None, *, causal,
     lse_l, delta_l = lane(lse.reshape(b * h, lq)), lane(delta)
 
     vspec = lambda f: pl.BlockSpec(*f, memory_space=pltpu.VMEM)
-    q_at = ((1, block_q, d), lambda bh, i, j: (bh, i, 0))
-    kv_at_inner = ((1, block_k, d), lambda bh, i, j: (bh, j, 0))
-    row_at = ((1, block_q, _LANES), lambda bh, i, j: (bh, i, 0))
+    resident = lambda bh, i, j, *_: (bh, i, 0)
+    inner_map = lambda bh, i, j: (bh, j, 0)
+    q_at = ((1, block_q, d), resident)
+    row_at = ((1, block_q, _LANES), resident)
+    cost = pl.CostEstimate(
+        flops=6 * b * h * lq * lk * d,
+        bytes_accessed=(qf.nbytes + kf.nbytes + vf.nbytes
+                        + dof.nbytes + lse_l.nbytes + delta_l.nbytes),
+        transcendentals=b * h * lq * lk)
+    args = [qf, kf, vf, dof, lse_l, delta_l]
+    kernel_kw = dict(causal=causal, scale=scale, window=window,
+                     segmented=segmented, heads=h)
+    blocks = (block_q, block_k)
 
     n_kb = lk // block_k
     if window is not None:
         dq_inner, dq_kv_map = _banded_kv(window, block_q, block_k, n_kb)
-        kv_at_banded = ((1, block_k, d), dq_kv_map)
     else:
-        dq_inner, kv_at_banded = n_kb, kv_at_inner
+        dq_inner, dq_kv_map = n_kb, inner_map
 
     def call_dq():
-        in_specs = [vspec(q_at), vspec(kv_at_banded), vspec(kv_at_banded),
+        kv_map, bounds = _skip_dead(dq_kv_map, segment_ids, tiles, blocks,
+                                    causal, window, "q", h)
+        kv_in = ((1, block_k, d), kv_map)
+        in_specs = [vspec(q_at), vspec(kv_in), vspec(kv_in),
                     vspec(q_at), vspec(row_at), vspec(row_at)]
-        args = [qf, kf, vf, dof, lse_l, delta_l]
+        seg_args = []
         if segmented:
             seg_specs, seg_args = _segment_operands(segment_ids, h, q_at,
-                                                    kv_at_banded)
+                                                    kv_in, tiles)
             in_specs += seg_specs
-            args += seg_args
-        return pl.pallas_call(
-            functools.partial(_flash_bwd_dq_kernel, causal=causal,
-                              scale=scale, window=window,
-                              segmented=segmented),
-            name="flash_bwd_dq",
-            grid=(b * h, lq // block_q, dq_inner),
-            in_specs=in_specs,
-            out_specs=vspec(q_at),
-            out_shape=jax.ShapeDtypeStruct((b * h, lq, d), q.dtype),
-            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-            cost_estimate=pl.CostEstimate(
-                flops=6 * b * h * lq * lk * d,
-                bytes_accessed=(qf.nbytes + kf.nbytes + vf.nbytes
-                                + dof.nbytes + lse_l.nbytes + delta_l.nbytes),
-                transcendentals=b * h * lq * lk),
-        )(*args)
+        return _launch(
+            functools.partial(_flash_bwd_dq_kernel, tiles=tiles, **kernel_kw),
+            "flash_bwd_dq", (b * h, lq // block_q, dq_inner), in_specs,
+            vspec(q_at), jax.ShapeDtypeStruct((b * h, lq, d), q.dtype),
+            [pltpu.VMEM((block_q, d), jnp.float32)], cost,
+            args + seg_args, bounds)
 
-    kv_at = ((1, block_k, d), lambda bh, i, j: (bh, i, 0))
-    q_at_inner = ((1, block_q, d), lambda bh, i, j: (bh, j, 0))
-    row_at_inner = ((1, block_q, _LANES), lambda bh, i, j: (bh, j, 0))
-
+    kv_at = ((1, block_k, d), resident)
     n_qb = lq // block_q
     if window is not None:
         dkv_inner, dkv_q_map = _banded_q(window, block_q, block_k, n_qb)
-        q_in = ((1, block_q, d), dkv_q_map)
-        row_in = ((1, block_q, _LANES), dkv_q_map)
     else:
-        dkv_inner, q_in, row_in = n_qb, q_at_inner, row_at_inner
+        dkv_inner, dkv_q_map = n_qb, inner_map
 
     def call_dkv():
+        q_map, bounds = _skip_dead(dkv_q_map, segment_ids, dkv_tiles, blocks,
+                                   causal, window, "k", h)
+        q_in = ((1, block_q, d), q_map)
+        row_in = ((1, block_q, _LANES), q_map)
         in_specs = [vspec(q_in), vspec(kv_at), vspec(kv_at),
                     vspec(q_in), vspec(row_in),
                     vspec(row_in)]
-        args = [qf, kf, vf, dof, lse_l, delta_l]
+        seg_args = []
         if segmented:
             seg_specs, seg_args = _segment_operands(segment_ids, h, q_in,
-                                                    kv_at)
+                                                    kv_at, dkv_tiles)
             in_specs += seg_specs
-            args += seg_args
-        return pl.pallas_call(
-            functools.partial(_flash_bwd_dkv_kernel, causal=causal,
-                              scale=scale, window=window,
-                              n_qb_total=n_qb, segmented=segmented),
-            name="flash_bwd_dkv",
-            grid=(b * h, lk // block_k, dkv_inner),
-            in_specs=in_specs,
-            out_specs=(vspec(kv_at), vspec(kv_at)),
-            out_shape=(jax.ShapeDtypeStruct((b * h, lk, d), k.dtype),
-                       jax.ShapeDtypeStruct((b * h, lk, d), v.dtype)),
-            scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                            pltpu.VMEM((block_k, d), jnp.float32)],
-            cost_estimate=pl.CostEstimate(
-                flops=6 * b * h * lq * lk * d,
-                bytes_accessed=(qf.nbytes + kf.nbytes + vf.nbytes
-                                + dof.nbytes + lse_l.nbytes + delta_l.nbytes),
-                transcendentals=b * h * lq * lk),
-        )(*args)
+        return _launch(
+            functools.partial(_flash_bwd_dkv_kernel, n_qb_total=n_qb,
+                              tiles=dkv_tiles, **kernel_kw),
+            "flash_bwd_dkv", (b * h, lk // block_k, dkv_inner), in_specs,
+            (vspec(kv_at), vspec(kv_at)),
+            (jax.ShapeDtypeStruct((b * h, lk, d), k.dtype),
+             jax.ShapeDtypeStruct((b * h, lk, d), v.dtype)),
+            [pltpu.VMEM((block_k, d), jnp.float32),
+             pltpu.VMEM((block_k, d), jnp.float32)], cost,
+            args + seg_args, bounds)
 
     if interpret:
         with pltpu.force_tpu_interpret_mode():
@@ -797,6 +1045,12 @@ def _flash_bwd_local(q, k, v, out, lse, g, segment_ids=None, *, causal,
         dk, dv = call_dkv()
     unflat = lambda a, L: a.reshape(b, h, L, d).transpose(0, 2, 1, 3)
     return unflat(dq, lq), unflat(dk, lk), unflat(dv, lk)
+
+
+_flash_bwd_segmented = jax.jit(
+    _flash_bwd_local,
+    static_argnames=("causal", "scale", "block_q", "block_k", "interpret",
+                     "window", "tiles", "dkv_tiles", "skip_dead"))
 
 
 def _fit_block(requested: int, length: int,
@@ -839,10 +1093,53 @@ def _require_fit(requested: int, length: int) -> int:
     return b
 
 
-# Optimum of the (block_q, block_k) sweep, measured 2026-07-31 on one
-# v5e, not re-measured since.
+# The (block_q, block_k) a launch without segments keeps.  On the
+# tiles they compute its three kernels run at 58-82 % of the bf16 peak
+# (TPU v5e, PR 32, PERF.md §6) and smaller blocks only lose (512 x 512:
+# +12 %, 256 x 256: 2.6 x, over packed rows), so a block size has
+# little to find; a sweep WITHOUT segments has not run on this code.
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
+# A launch with ``segment_ids`` walks larger blocks as tiles and skips
+# the tiles no document spans (see _tile_bounds): a tile's logits, not
+# a block's, have to fit VMEM, and a grid step costs ~0.4 us whatever
+# it multiplies.  Fastest of some 40 (block, tile) points over the
+# benchmark's packed rows of 4096 at 24 heads of 128, float32 operands
+# (TPU v5e, PR 32, scripts/sweep_attention_blocks.py; ms a call of 8
+# rows, masking launch -> this): flash_fwd 9.31 -> 7.96, flash_bwd_dq
+# 9.34 -> 6.63, flash_bwd_dkv 15.99 -> 11.58.  The forward pays its
+# online-softmax bookkeeping (running max and sum, the accumulator's
+# rescale) once a tile, so it wants its k tile wide: 512 x 512 reads
+# 10.15, any 256-wide k tile 15-20 ms.
+SEGMENT_BLOCK_Q = 2048
+SEGMENT_BLOCK_K = 2048
+# Blocks of 2048 of float32 operands (the trainer's q, k, v: bf16
+# activations times float32 weights), double-buffered, pass the
+# compiler's 16 MB default; a v5e core has 128 MB.
+SEGMENT_VMEM_BYTES = 64 * 1024 * 1024
+SEGMENT_TILES = {"flash_fwd": (512, 1024), "flash_bwd_dq": (512, 512),
+                 "flash_bwd_dkv": (512, 512)}
+
+
+def _segment_tiles(kernel: str, block_q: int, block_k: int):
+    """The segmented launch's ``(tile_q, tile_k)`` for ``kernel`` inside
+    fitted blocks: the tuned tile where it divides the block into
+    lane-aligned parts, else the block whole."""
+    def fit(tile, block):
+        t = math.gcd(tile, block)
+        return t if t % _LANES == 0 else block
+    tile_q, tile_k = SEGMENT_TILES[kernel]
+    return fit(tile_q, block_q), fit(tile_k, block_k)
+
+
+def segment_tiles_for(seq_len: int) -> dict:
+    """``{kernel: (tile_q, tile_k)}`` at which a defaulted segmented
+    launch over rows of ``seq_len`` skips dead tiles (the granularity
+    :func:`live_tile_share` is asked about)."""
+    bq = _fit_block(SEGMENT_BLOCK_Q, seq_len) or seq_len
+    bk = _fit_block(SEGMENT_BLOCK_K, seq_len) or seq_len
+    return {kernel: _segment_tiles(kernel, bq, bk)
+            for kernel in SEGMENT_TILES}
 
 
 def _pallas_blocks(lq, lk, d, block_q, block_k, gate_small_bk=False,
@@ -859,12 +1156,13 @@ def _pallas_blocks(lq, lk, d, block_q, block_k, gate_small_bk=False,
     if bq is None or bk is None:
         return None
     # Defaulted callers only (``gate_small_bk``): tiny fitted KV tiles
-    # usually lose to the XLA blockwise path end-to-end (sweep measured
-    # 2026-07-31 on one v5e, not re-measured since: at block_k=128 the
-    # kernel is slower than blockwise for every block_q except 1024,
-    # which edges it out by ~4%), so keep bk=128 only when bq fitted to
-    # >=1024.  An EXPLICIT small block_k is always honored — the sweep
-    # itself must be able to time the kernel at any point of its grid.
+    # lose — the kernels get slower with every halving of the block
+    # (256 x 256 blocks: 2.6 x the time of 1024 x 1024 over the same
+    # rows; TPU v5e, PR 32, PERF.md §6), and a 128-wide block was not
+    # measured against the blockwise path on this code — so keep bk=128
+    # only when bq fitted to >=1024.  An EXPLICIT small block_k is
+    # always honored — a sweep must be able to time any point of its
+    # grid.
     if gate_small_bk and bk < 256 and bk != lk and bq < 1024:
         return None
     return bq, bk
@@ -886,16 +1184,18 @@ def _use_pallas(q, k, block_q, block_k, gate_small_bk=False,
                           strict_q=strict_q, strict_k=strict_k) is not None
 
 
-def _resolve_blocks(block_q, block_k):
-    """None -> tuned default; the small-bk gate and divisor refitting
-    apply only to defaulted blocks — explicit blocks are honored
-    exactly or fall back (strict _fit_block).  The ONE definition
-    shared by flash_attention and its custom_vjp fwd/bwd so primal and
-    vjp can never disagree."""
+def _resolve_blocks(block_q, block_k, segmented=False):
+    """None -> tuned default (the segmented launch has its own); the
+    small-bk gate and divisor refitting apply only to defaulted blocks —
+    explicit blocks are honored exactly or fall back (strict
+    _fit_block).  The ONE definition shared by flash_attention and its
+    custom_vjp fwd/bwd so primal and vjp can never disagree."""
     q_explicit, k_explicit = block_q is not None, block_k is not None
     gate = not k_explicit
-    bq = block_q if q_explicit else DEFAULT_BLOCK_Q
-    bk = block_k if k_explicit else DEFAULT_BLOCK_K
+    default_q, default_k = ((SEGMENT_BLOCK_Q, SEGMENT_BLOCK_K) if segmented
+                            else (DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K))
+    bq = block_q if q_explicit else default_q
+    bk = block_k if k_explicit else default_k
     return bq, bk, gate, q_explicit, k_explicit
 
 
@@ -922,11 +1222,15 @@ def flash_attention(q, k, v, causal: bool = False, scale: float | None = None,
     — the packed-document training primitive.  An integer input: its
     cotangent is None.
 
-    ``block_q``/``block_k`` default (None) to the measured optimum of
-    the (block_q, block_k) hardware sweep on the long-context benchmark
-    config (seq 4096, d1024 L8, `scripts/sweep_attention_blocks.py`;
-    measured 2026-07-31 on one v5e, not re-measured since): (1024,
-    1024) beat the untuned (256, 512) by 35% on the full train step.  Defaulted blocks are fitted per
+    ``block_q``/``block_k`` default (None) to ``DEFAULT_BLOCK_*``
+    (1024 x 1024), and with ``segment_ids`` to ``SEGMENT_BLOCK_*``
+    walked as ``SEGMENT_TILES``, the fastest point of
+    `scripts/sweep_attention_blocks.py` over the benchmark's packed
+    rows (seq 4096, 24 heads of 128; TPU v5e, PR 32; see the
+    constants).  With segments a tile no document spans is neither
+    fetched nor multiplied (:func:`_tile_bounds`); explicit blocks are
+    walked as the same tiles where they divide.  Defaulted blocks are
+    fitted per
     call (``_fit_block``): shorter sequences clamp to one block, and
     lengths the default doesn't divide (e.g. 1536) drop to their
     largest lane-aligned divisor instead of leaving the Pallas path —
@@ -940,7 +1244,8 @@ def flash_attention(q, k, v, causal: bool = False, scale: float | None = None,
     """
     _check_window(window, causal)
     s = _scale_for(q, scale)
-    bq, bk, gate, xq, xk = _resolve_blocks(block_q, block_k)
+    bq, bk, gate, xq, xk = _resolve_blocks(block_q, block_k,
+                                           segment_ids is not None)
     if _use_pallas(q, k, bq, bk, gate_small_bk=gate,
                    strict_q=xq, strict_k=xk):
         return _flash_pallas(q, k, v, causal, s, bq, bk,
@@ -955,7 +1260,8 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, window=None,
                segment_ids=None):
     _check_window(window, causal)
     s = _scale_for(q, scale)
-    bq, bk, gate, xq, xk = _resolve_blocks(block_q, block_k)
+    bq, bk, gate, xq, xk = _resolve_blocks(block_q, block_k,
+                                           segment_ids is not None)
     if _use_pallas(q, k, bq, bk, gate_small_bk=gate,
                    strict_q=xq, strict_k=xk):
         out, lse = _flash_pallas(q, k, v, causal, s, bq, bk,
@@ -970,7 +1276,8 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, window=None,
 def _flash_bwd(causal, scale, block_q, block_k, window, res, g):
     q, k, v, out, lse, segment_ids = res
     s = _scale_for(q, scale)
-    bq, bk, _, _, _ = _resolve_blocks(block_q, block_k)
+    bq, bk, _, _, _ = _resolve_blocks(block_q, block_k,
+                                      segment_ids is not None)
     if lse is not None:
         dq, dk, dv = _flash_pallas_bwd(q, k, v, out, lse, g, causal, s,
                                        bq, bk, window=window,

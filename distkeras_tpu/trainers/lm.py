@@ -1394,6 +1394,19 @@ class LMTrainer(CheckpointingBase):
                 self.residual_norm = rn
         self.training_time = time.perf_counter() - t0
         self._record_run_metrics()
+        if obs.active() is not None:
+            # Once a call, from the host's rows: the share of the causal
+            # band's tiles each segmented attention kernel computes.
+            from distkeras_tpu.ops.attention import (live_tile_share,
+                                                     segment_tiles_for)
+
+            inputs = (None if segments is None
+                      else np.asarray(segments[:n_rows])[:, :seq_len])
+            for kernel, tiles in segment_tiles_for(seq_len).items():
+                share = 1.0 if inputs is None else live_tile_share(
+                    inputs, *tiles, self.cfg.attention_window)
+                obs.gauge("train.attn_live_tile_share", share,
+                          trainer=type(self).__name__, kernel=kernel)
         return params
 
 

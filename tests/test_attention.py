@@ -574,6 +574,188 @@ def test_decode_block_tile_rule():
     assert decode_block(1, 1000, 128, 16, 1, bf16) is None
 
 
+# ------------------------------------ segmented launch: dead tiles skipped
+#
+# With segment_ids the three training kernels get, as scalar prefetch,
+# the first and last live tile of the streamed side for every resident
+# tile (_tile_bounds) and neither fetch nor multiply a tile outside
+# them.  A skipped tile contributed exactly nothing before, so at
+# unchanged blocks the results are the masking launch's, bit for bit.
+
+S_SEG = 512
+
+
+def _packed_ids(*rows, s=S_SEG):
+    """``[len(rows), s]`` int32: each row ``(splits, n_pad)`` — ids 1,
+    2, ... changing at ``splits``, the last ``n_pad`` positions 0."""
+    out = np.zeros((len(rows), s), np.int32)
+    for r, (splits, n_pad) in enumerate(rows):
+        edges = [0, *splits, s - n_pad]
+        for n, (a, e) in enumerate(zip(edges[:-1], edges[1:])):
+            out[r, a:e] = n + 1
+    return jnp.asarray(out)
+
+
+# Row 0 is the case's; row 1 another packing, so that bounds are read by
+# row while the grid walks batch * head.
+OTHER_ROW = ((40, 130, 200, 390), 0)
+SEG_CASES = {
+    "window": (((100, 180, 300), 0), 200),
+    "no_window": (((100, 180, 300), 0), None),
+    "padding_tail": (((128, 300), 62), None),
+    "one_block_document": (((128, 256), 0), None),
+    "spanning_document": (((), 0), 300),
+}
+
+
+def _seg_case(rng, name, b=2, h=2, d=128):
+    row, window = SEG_CASES[name]
+    seg = _packed_ids(row, OTHER_ROW)[:b]
+    q, k, v = (jnp.asarray(rng.normal(size=(b, S_SEG, h, d))
+                           .astype(np.float32)) for _ in range(3))
+    return q, k, v, seg, window
+
+
+def _local_launchers(window, block=128, **kw):
+    from distkeras_tpu.ops.attention import (_flash_bwd_local,
+                                             _flash_fwd_local)
+
+    common = dict(causal=True, scale=128 ** -0.5, block_q=block,
+                  block_k=block, interpret=True, window=window, **kw)
+    return (jax.jit(functools.partial(_flash_fwd_local, with_lse=True,
+                                      **common)),
+            jax.jit(functools.partial(_flash_bwd_local, **common)))
+
+
+@pytest.mark.parametrize("case", list(SEG_CASES))
+def test_segmented_forward_skip_is_bit_identical(rng, case):
+    q, k, v, seg, window = _seg_case(rng, case)
+    fwd, _ = _local_launchers(window)
+    masked, _ = _local_launchers(window, skip_dead=False)
+    for got, want in zip(fwd(q, k, v, seg), masked(q, k, v, seg)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("case", list(SEG_CASES))
+def test_segmented_backward_skip_is_bit_identical(rng, case):
+    q, k, v, seg, window = _seg_case(rng, case)
+    fwd, bwd = _local_launchers(window)
+    _, masked = _local_launchers(window, skip_dead=False)
+    out, lse = fwd(q, k, v, seg)
+    for got, want in zip(bwd(q, k, v, out, lse, 2 * out, seg),
+                         masked(q, k, v, out, lse, 2 * out, seg)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _naive_out_and_grads(q, k, v, seg, window):
+    ref = functools.partial(naive_attention, causal=True, window=window,
+                            segment_ids=seg)
+    grads = jax.grad(lambda q, k, v: (ref(q, k, v) ** 2).sum(),
+                     argnums=(0, 1, 2))(q, k, v)
+    return ref(q, k, v), grads
+
+
+def _assert_matches_naive(fwd, bwd, q, k, v, seg, window):
+    """The file's tolerances for the interpreted kernels (segments:
+    tests/test_packing.py)."""
+    ref, grads = _naive_out_and_grads(q, k, v, seg, window)
+    out, lse = fwd(q, k, v, seg)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-3, rtol=2e-3)
+    for got, want in zip(bwd(q, k, v, out, lse, 2 * out, seg), grads):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=5e-3, rtol=5e-3)
+
+
+@pytest.mark.parametrize("tiles", [None, (128, 128)],
+                         ids=["blocks", "tiles"])
+def test_segment_ids_that_decrease_still_match_naive(rng, tiles):
+    """Ids 1, 3, 2, 3: the range test only ever keeps too much (a tile
+    between two live ones is computed and masked), never too little."""
+    q, k, v, _, _ = _seg_case(rng, "no_window", b=1)
+    seg = jnp.asarray(np.repeat([1, 3, 2, 3], S_SEG // 4)[None]
+                      .astype(np.int32))
+    block = 128 if tiles is None else 256
+    fwd, bwd = _local_launchers(None, block=block, tiles=tiles)
+    _assert_matches_naive(fwd, bwd, q, k, v, seg, None)
+
+
+@pytest.mark.parametrize("window", [None, 200])
+@pytest.mark.parametrize("tiles", [(128, 128), (256, 128), (128, 256)],
+                         ids=lambda t: f"{t[0]}x{t[1]}")
+def test_segmented_tiles_match_naive(rng, tiles, window):
+    """Blocks of 256 walked as tiles (the segmented launch's
+    granularity: SEGMENT_TILES inside SEGMENT_BLOCK_* on the chip; a k
+    tile twice the q tile is the forward's), forward and gradients."""
+    q, k, v, seg, _ = _seg_case(rng, "padding_tail")
+    fwd, bwd = _local_launchers(window, block=256, tiles=tiles)
+    _assert_matches_naive(fwd, bwd, q, k, v, seg, window)
+
+
+def _tiles_with_a_pair(seg, tile_q, tile_k, window):
+    """Brute force over the mask: ``[B, S/tile_q, S/tile_k]`` bool,
+    tiles that hold an attended pair."""
+    seg = np.asarray(seg)
+    b, s = seg.shape
+    pos = np.arange(s)
+    keep = pos[:, None] >= pos[None, :]
+    if window is not None:
+        keep &= pos[:, None] - pos[None, :] < window
+    mask = keep[None] & (seg[:, :, None] == seg[:, None, :])
+    return mask.reshape(b, s // tile_q, tile_q, s // tile_k,
+                        tile_k).any(axis=(2, 4))
+
+
+@pytest.mark.parametrize("window", [None, 200])
+@pytest.mark.parametrize("tiles", [(128, 128), (256, 128), (64, 256)],
+                         ids=lambda t: f"{t[0]}x{t[1]}")
+def test_live_tile_share_is_the_masks_count(tiles, window):
+    from distkeras_tpu.ops.attention import live_tile_share
+
+    seg = _packed_ids(((100, 180, 300), 0), ((128, 300), 62), OTHER_ROW,
+                      ((), 0))
+    live = _tiles_with_a_pair(seg, *tiles, window)
+    band = _tiles_with_a_pair(np.ones_like(seg), *tiles, window)
+    assert 0 < live.sum() < band.sum()
+    assert live_tile_share(seg, *tiles, window) \
+        == pytest.approx(live.sum() / band.sum(), abs=1e-12)
+    assert live_tile_share(np.ones_like(seg), *tiles, window) == 1.0
+
+
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd_dq",
+                                    "flash_bwd_dkv"])
+def test_kernels_compute_the_live_tiles_and_no_other(rng, kernel):
+    """Which tiles a kernel really multiplies, found by poison: NaN in
+    the streamed-or-resident operand of one tile column reaches the
+    result rows of exactly the tiles computed against it (0 x NaN is
+    NaN, so a computed but wholly masked tile shows too).  The count
+    is ``live_tile_share``'s."""
+    from distkeras_tpu.ops.attention import live_tile_share
+
+    tile, window = 128, None
+    q, k, v, seg, _ = _seg_case(rng, "padding_tail", b=1, h=1)
+    fwd, bwd = _local_launchers(window, block=256, tiles=(tile, tile))
+    out, lse = fwd(q, k, v, seg)
+    n = S_SEG // tile
+    poison = lambda a, t: a.at[:, t * tile:(t + 1) * tile].set(jnp.nan)
+    by_tile = lambda a: np.isnan(np.asarray(a)).reshape(n, -1).any(axis=1)
+    computed = np.zeros((n, n), bool)          # [q tile, k tile]
+    for t in range(n):
+        if kernel == "flash_fwd":              # V's k tile -> out's q tiles
+            computed[:, t] = by_tile(fwd(q, k, poison(v, t), seg)[0])
+        elif kernel == "flash_bwd_dq":         # K's k tile -> dq's q tiles
+            computed[:, t] = by_tile(
+                bwd(q, poison(k, t), v, out, lse, 2 * out, seg)[0])
+        else:                                  # dO's q tile -> dv's k tiles
+            computed[t, :] = by_tile(
+                bwd(q, k, v, out, lse, poison(2 * out, t), seg)[2])
+    want = _tiles_with_a_pair(seg, tile, tile, window)[0]
+    np.testing.assert_array_equal(computed, want)
+    band = _tiles_with_a_pair(np.ones_like(seg), tile, tile, window)[0]
+    assert computed.sum() / band.sum() == pytest.approx(
+        live_tile_share(seg, tile, tile, window))
+
+
 # ------------------------------------------------ TPU lowering, no chip
 #
 # The Mosaic lowering runs under JAX_PLATFORMS=cpu, and it is where the
@@ -583,6 +765,22 @@ def test_decode_block_tile_rule():
 
 def _tpu_lower(fn, *avals):
     return jax.jit(fn).trace(*avals).lower(lowering_platforms=("tpu",))
+
+
+def _scalar_prefetch(fn, *avals):
+    """Scalar-prefetch operands of every ``pallas_call`` ``fn`` traces
+    to, in order."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append(eqn.params["grid_mapping"].num_index_operands)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*avals).jaxpr)
+    return found
 
 
 def _kernel_avals(b=2, l=512, h=2, d=128, sharding=None, seg_sharding=None):
@@ -603,6 +801,9 @@ def test_forward_kernel_lowers_for_tpu(window, segmented, with_lse):
                              segment_ids=s if segmented else None)
     text = _tpu_lower(fwd, x, x, x, seg).as_text()
     assert text.count("tpu_custom_call") == 1
+    # Segments bring the tile bounds (plain XLA, no kernel of their
+    # own); without them the launch is the plain grid.
+    assert _scalar_prefetch(fwd, x, x, x, seg) == [2 if segmented else 0]
 
 
 @pytest.mark.parametrize("segmented", [False, True])
@@ -619,6 +820,31 @@ def test_backward_kernels_lower_for_tpu(window, segmented):
                                  segment_ids=s if segmented else None)
     text = _tpu_lower(bwd, x, x, x, x, lse, x, seg).as_text()
     assert text.count("tpu_custom_call") == 2   # dq, dkv
+    assert _scalar_prefetch(bwd, x, x, x, x, lse, x, seg) \
+        == [2 if segmented else 0] * 2
+
+
+@pytest.mark.parametrize("window", [None, 2048])
+def test_segmented_default_launch_lowers_for_tpu(monkeypatch, window):
+    """``flash_attention`` with segments and defaulted blocks, forward
+    and backward, at the segmented launch's own blocks and tiles over
+    rows long enough to hold several: three Mosaic calls, each under
+    the tile bounds; without segments the same call takes none."""
+    from distkeras_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    x, seg = _kernel_avals(b=1, l=4096)
+    assert all(tq < 4096 and tk < 4096 for tq, tk in
+               attention.segment_tiles_for(4096).values())
+
+    def loss(q, k, v, s=None):
+        return flash_attention(q, k, v, True, window=window,
+                               segment_ids=s).astype(jnp.float32).sum()
+    grad = jax.value_and_grad(loss, argnums=(0, 1, 2))
+    assert _tpu_lower(grad, x, x, x, seg).as_text().count(
+        "tpu_custom_call") == 3
+    assert _scalar_prefetch(grad, x, x, x, seg) == [2, 2, 2]
+    assert _scalar_prefetch(grad, x, x, x) == [0, 0, 0]
 
 
 @pytest.mark.parametrize("spec", [("data", None, None, None),

@@ -270,10 +270,41 @@ def test_trainer_end_to_end_trace(tmp_path):
     assert snap["train.rounds{trainer=LMTrainer}"] == len(t.history)
     assert snap["train.loss{trainer=LMTrainer}"] == pytest.approx(
         t.history[-1])
+    assert snap["train.attn_live_tile_share"
+                "{kernel=flash_fwd,trainer=LMTrainer}"] == 1.0
     rep = load_report(path)
     assert rep["phases"]["train.step"]["count"] == len(t.history)
     text = render_report(rep)
     assert "train.step" in text and "phase breakdown" in text
+
+
+def test_trainer_reports_the_live_tile_share_of_packed_rows(monkeypatch):
+    """``train.attn_live_tile_share{kernel}``: the kernels' own
+    predicate (``ops.attention.live_tile_share``) over the call's
+    segments, at the tiles each segmented launch skips by — here set
+    for the test over rows of 256 (a toy row is one tile otherwise)."""
+    from distkeras_tpu.ops import attention
+
+    tiles = {"flash_fwd": (128, 256), "flash_bwd_dq": (128, 128),
+             "flash_bwd_dkv": (128, 128)}
+    monkeypatch.setattr(attention, "segment_tiles_for",
+                        lambda seq_len: tiles)
+    cfg = serve_cfg(max_len=256, rope=False)
+    rows = tokens(n=8, s=256)
+    segs = np.ones_like(rows)
+    segs[:, 128:] = 2       # a document boundary on a tile's edge
+    with obs.session() as sess:
+        dk.LMTrainer(cfg, learning_rate=1e-2, batch_size=8).train(
+            rows, segments=segs)
+    snap = sess.registry.compact()
+    share = {k: snap["train.attn_live_tile_share"
+                     f"{{kernel={k},trainer=LMTrainer}}"] for k in tiles}
+    for kernel, tile in tiles.items():
+        assert share[kernel] == attention.live_tile_share(segs[:, :-1], *tile)
+    # 128 x 128: the tile under the diagonal is dead, 2 of 3 computed;
+    # a k tile as wide as the row holds a pair whatever the documents.
+    assert share == {"flash_fwd": 1.0, "flash_bwd_dq": pytest.approx(2 / 3),
+                     "flash_bwd_dkv": pytest.approx(2 / 3)}
 
 
 def test_serving_end_to_end_trace_and_compare(tmp_path):

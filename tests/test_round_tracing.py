@@ -350,13 +350,44 @@ def train_step_text(request, devices):
     mp = pytest.MonkeyPatch()
     mp.setattr(attention, "_on_tpu", lambda: True)
     try:
-        lowered = jax.jit(tfm.make_train_step(TRAIN_CFG, opt)).trace(
-            state, rows, None, rows).lower(lowering_platforms=("tpu",))
+        traced = jax.jit(tfm.make_train_step(TRAIN_CFG, opt)).trace(
+            state, rows, None, rows)
+        lowered = traced.lower(lowering_platforms=("tpu",))
     finally:
         mp.undo()
     text = lowered.as_text(debug_info=True)
     assert ("shard_map" in text) == (request.param == "shard_map")
-    return text
+    return _Lowered(text, _kernel_calls(traced.jaxpr.jaxpr))
+
+
+class _Lowered(str):
+    """The lowered text, and the Pallas calls the program makes by
+    kernel name (``kernel_calls``): every call SITE counts — the
+    segmented launchers are jitted (ops/attention.py), so the text
+    holds a kernel once and calls it once a layer and pass; the
+    compiler inlines the calls, and the compiled program and the
+    profile hold every one."""
+
+    def __new__(cls, text, kernel_calls):
+        self = super().__new__(cls, text)
+        self.kernel_calls = kernel_calls
+        return self
+
+
+def _kernel_calls(jaxpr):
+    import collections
+
+    calls = collections.Counter()
+
+    def walk(j):
+        for eqn in j.eqns:
+            if eqn.primitive.name == "pallas_call":
+                calls[eqn.params["name"]] += 1
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr)
+    return calls
 
 
 @pytest.mark.parametrize("kernel,calls", [
@@ -367,9 +398,13 @@ def test_kernel_names_stand_in_the_lowered_train_step(train_step_text,
     compiled instruction, and with it the profile's event, is named
     after the component before ``pallas_call`` (``flash_fwd.3``), not
     after the transform that wrapped the call.  Per layer: the forward
-    twice (once rematerialised), dq once, dkv once."""
-    assert train_step_text.count(f'kernel_name = "{kernel}"') \
+    twice (once rematerialised), dq once, dkv once — three kernels a
+    layer application, no fourth for the tile bounds."""
+    assert train_step_text.kernel_calls[kernel] \
         == TRAIN_CFG.n_layers * calls
+    assert set(train_step_text.kernel_calls) \
+        == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+    assert f'kernel_name = "{kernel}"' in train_step_text
     assert re.search(rf'{kernel}/pallas_call"', train_step_text)
 
 
