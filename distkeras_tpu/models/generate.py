@@ -48,6 +48,15 @@ from distkeras_tpu.models.quant import (
     quantize_kv,
     unembed_logits,
 )
+from distkeras_tpu.ops.retention import (
+    log_gate,
+    phi_rows,
+    ret_state_step,
+    retention_chunk,
+    retention_step,
+    step_operands,
+    use_ret_kernel,
+)
 from distkeras_tpu.ops.attention import (
     DECODE_TAIL_PARTS,
     decode_block,
@@ -96,14 +105,25 @@ def init_cache(cfg: TransformerConfig, batch: int, dtype=None,
         # window layer, of ``sliding_window`` slots (position p at
         # slot p % sliding_window) and RING_PARK slots after them, the
         # first of which is where a parked lane's decode step writes.
+        # A third kind where the stack has retention layers: ``s``/``z``
+        # one float32 STATE a retention layer (``ops/retention.py``: ``s
+        # [.., rows, head_dim, head_dim]``, ``z [.., rows, head_dim]``),
+        # of the same size at every position: it has no slots.
         dtype = dtype or jnp.dtype(cfg.dtype)
         full = (cfg.kv_planes, batch, cfg.kv_heads, cfg.max_len,
                 cfg.head_dim)
         ring = (cfg.kv_ring_planes, batch, cfg.kv_heads,
-                cfg.sliding_window + RING_PARK, cfg.head_dim)
-        return {"k": jnp.zeros(full, dtype), "v": jnp.zeros(full, dtype),
-                "k_win": jnp.zeros(ring, dtype),
-                "v_win": jnp.zeros(ring, dtype)}
+                (cfg.sliding_window or 0) + RING_PARK, cfg.head_dim)
+        cache = {"k": jnp.zeros(full, dtype), "v": jnp.zeros(full, dtype),
+                 "k_win": jnp.zeros(ring, dtype),
+                 "v_win": jnp.zeros(ring, dtype)}
+        if cfg.state_planes:
+            z = (cfg.state_planes, batch, cfg.kv_heads,
+                 phi_rows(cfg.head_dim), cfg.head_dim)
+            cache["s"] = jnp.zeros(z[:-1] + (cfg.head_dim,) * 2,
+                                   jnp.float32)
+            cache["z"] = jnp.zeros(z, jnp.float32)
+        return cache
     dtype = jnp.int8 if kv_int8 else (dtype or jnp.dtype(cfg.dtype))
     shape = (cfg.kv_planes, batch, cfg.max_len, cfg.kv_heads, cfg.head_dim)
     cache = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
@@ -606,7 +626,7 @@ def base_body_only(cfg: TransformerConfig, params, cache,
 
 def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
                     uniform_pos: bool = False, lane=None, n_real=None,
-                    with_routes: bool = False):
+                    with_routes: bool = False, live=None):
     """:func:`_decode_chunk`'s body for every call that
     :func:`base_body_only` does not hold back: ``tokens [B, T]`` at
     positions ``pos0[b] + (0..T-1)`` -> ``(logits [B, T, V] f32, cache)``
@@ -672,7 +692,18 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
     back): its decode step writes the ring's parking slot.  A sparse
     layer is ``transformer.moe_ffn``; ``with_routes`` appends, to the
     result, ``[sparse layers, B, T, k]``: each assignment's index among
-    the held experts (their number: an absent expert's)."""
+    the held experts (their number: an absent expert's).
+
+    A RETENTION layer's plane is a state (``init_cache``), carried
+    through the layers' scans and updated in place where it lies.  One
+    token a row (``T == 1``, no ``lane``) is the recurrent form: on the
+    TPU the kernel ``ret_state_step``, elsewhere the same arithmetic
+    (``ops/retention.py``); ``live [B]`` names the rows that decode —
+    every other row's state is left as it is, unread.  A chunk is the
+    chunked form through the row's (``lane``'s) state; ``n_real`` keeps
+    an admission's padding out of it.  A row at position 0 starts from
+    a zero state whatever its plane holds: a stale state is not masked
+    by position as stale slots are."""
     dtype = jnp.dtype(cfg.dtype)
     b, t_len = tokens.shape
     n_layers, s_len = cfg.n_layers, cfg.max_len
@@ -708,7 +739,7 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
               )[:, None, None, None, :]
     causal = jnp.tril(jnp.ones((t_len, t_len), bool))[None, :, None, None, :]
     scale = 1.0 / jnp.sqrt(jnp.float32(cfg.head_dim))
-    if typed:
+    if typed and cfg.kv_ring_planes:
         rk_all, rv_all = cache["k_win"], cache["v_win"]
         window = cfg.sliding_window
         # Ring slot s holds position p_s, the latest one = s (mod window)
@@ -813,7 +844,43 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
                              **f32)).reshape(
             b, t_len, cfg.n_heads, cfg.head_dim)
 
-    def layer(x, lp, plane, kind=None, experts=None):
+    def attend_state(h, q, k, v, lp, plane, state):
+        """A retention layer's attention, through its state."""
+        s_all, z_all = state
+        fresh = pos0 == 0
+        with jax.named_scope("ret_gate"):
+            logg = log_gate(h, lp["attn"]["wg"], lp["attn"]["bg"])
+        if lane is None and t_len == 1:
+            with jax.named_scope("ret_state"):
+                ops = step_operands(q[:, 0], k[:, 0], v[:, 0], logg[:, 0],
+                                    fresh)
+                on = (jnp.ones((b,), jnp.int32) if live is None
+                      else live.astype(jnp.int32))
+                step = (ret_state_step if use_ret_kernel(
+                    cfg.head_dim, groups, s_all.dtype, sharded)
+                    else retention_step)
+                y, s_all, z_all = step(ops, s_all, z_all, plane, on)
+            return (y[:, :, :groups].reshape(b, 1, cfg.n_heads,
+                                             cfg.head_dim),
+                    (s_all, z_all))
+        # Chunks: each row through its own state (one row: the lane's).
+        row0 = jnp.int32(0) if lane is None else lane
+        at = (plane, row0) + (jnp.int32(0),) * 4
+        with jax.named_scope("ret_state"):
+            s_in = jax.lax.dynamic_slice(s_all, at, (1, b) + s_all.shape[2:])
+            z_in = jax.lax.dynamic_slice(z_all, at[:-1],
+                                         (1, b) + z_all.shape[2:])
+        real = None if n_real is None else jnp.broadcast_to(n_real, (b,))
+        y, s_new, z_new = jax.vmap(
+            retention_chunk, in_axes=(0, 0, 0, 0, 0, 0,
+                                      None if real is None else 0, 0))(
+            q.astype(dtype), k, v, logg, s_in[0], z_in[0], real, fresh)
+        with jax.named_scope("ret_state"):
+            s_all = jax.lax.dynamic_update_slice(s_all, s_new[None], at)
+            z_all = jax.lax.dynamic_update_slice(z_all, z_new[None], at[:-1])
+        return y, (s_all, z_all)
+
+    def layer(x, lp, plane, kind=None, experts=None, state=None):
         h = _rms_norm(x, lp["ln1_scale"], eps) if pre_norm else x
         with jax.named_scope("attn_proj"):
             if cfg.fused_qkv:
@@ -830,6 +897,9 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
             k, v = k.astype(ck_all.dtype), v.astype(cv_all.dtype)
         if kind is None:
             attn = attend_plain(q, k, v, plane)
+        elif kind[0] == "retention":
+            with jax.named_scope("attn"):
+                attn, state = attend_state(h, q, k, v, lp, plane, state)
         else:
             with jax.named_scope("attn"):
                 attn = attend_typed(q, k, v, plane, kind[0])
@@ -858,7 +928,10 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
             y = _rms_norm(y, lp["ln2_post_scale"], eps)
         with jax.named_scope("mlp"):
             x = x + y.astype(dtype)
-        return x, ((k, v) if routes is None else (k, v, routes))
+        if kind is None:
+            return x, (k, v)
+        outs = () if kind[0] == "retention" else (k, v)
+        return (x, state), outs + (() if routes is None else (routes,))
 
     def one_pass(x, r):
         x, kv = jax.lax.scan(
@@ -867,9 +940,10 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
         return final_norm(x, params, cfg).astype(dtype), kv
 
     if typed:
-        return _typed_tail(*_typed_runs(params, x, cfg, layer), params,
-                           cache, pos0, cfg, uniform_pos, lane, n_real,
-                           with_routes)
+        state = (cache["s"], cache["z"]) if cfg.state_planes else None
+        return _typed_tail(*_typed_runs(params, x, cfg, layer, state),
+                           params, cache, pos0, cfg, uniform_pos, lane,
+                           n_real, with_routes)
     x, (new_k, new_v) = jax.lax.scan(one_pass, x, jnp.arange(cfg.n_passes))
     with jax.named_scope("head"):
         out = jnp.einsum("btd,vd->btv", x,
@@ -896,16 +970,19 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
     return out.astype(jnp.float32), {"k": ck_all, "v": cv_all}
 
 
-def _typed_runs(params, x, cfg: TransformerConfig, layer):
+def _typed_runs(params, x, cfg: TransformerConfig, layer, state=None):
     """A typed stack's layers over the stream ``x``: one ``lax.scan`` a
     run of consecutive layers of one kind, over that kind's slice of
     its group's stacked leaves (the whole group where the kind makes
-    one run).  Returns ``(x, new)``: ``new[kind]`` the runs' scan
-    outputs of the attention kinds ``"full"`` and ``"window"`` — ``(k,
-    v)`` — and ``new["routes"]`` the sparse runs' routes, each a list
-    of leaves stacked by layer."""
+    one run).  ``state``: the retention layers' state slabs ``(s,
+    z)``, carried through every scan beside the stream and updated
+    where they lie (None: no such layer).  Returns ``(x, new)``:
+    ``new[kind]`` the runs' scan outputs of the attention kinds
+    ``"full"`` and ``"window"`` — ``(k, v)`` — ``new["routes"]`` the
+    sparse runs' routes, each a list of leaves stacked by layer, and
+    ``new["state"]`` the slabs after the last layer."""
     new = {"full": [], "window": [], "routes": []}
-    planes = {"full": 0, "window": 0}
+    planes = {"full": 0, "window": 0, "retention": 0}
     for group, first, count in cfg.layer_runs:
         kind = tuple(group.split("."))
         leaves, heavy = params["layers"][group], None
@@ -920,13 +997,15 @@ def _typed_runs(params, x, cfg: TransformerConfig, layer):
             leaves = jax.tree.map(lambda a: a[first:first + count], leaves)
         p0 = planes[kind[0]]
         planes[kind[0]] += count
-        x, outs = jax.lax.scan(
-            lambda x, lw: layer(x, lw[0], p0 + lw[1], kind,
-                                heavy and heavy + (first + lw[1],)),
-            x, (leaves, jnp.arange(count)))
-        new[kind[0]].append(outs[:2])
-        if len(outs) == 3:
-            new["routes"].append(outs[2])
+        (x, state), outs = jax.lax.scan(
+            lambda c, lw: layer(c[0], lw[0], p0 + lw[1], kind,
+                                heavy and heavy + (first + lw[1],), c[1]),
+            (x, state), (leaves, jnp.arange(count)))
+        if kind[0] != "retention":
+            new[kind[0]].append(outs[:2])
+        if kind[1] == "sparse":
+            new["routes"].append(outs[-1])
+    new["state"] = state
     return x, new
 
 
@@ -944,6 +1023,8 @@ def _typed_tail(x, new, params, cache, pos0, cfg: TransformerConfig,
         out = jnp.einsum("btd,vd->btv", x,
                          head_table(params, cfg).astype(dtype))
     cache = dict(cache)
+    if new["state"] is not None:   # written where they lay, layer by layer
+        cache["s"], cache["z"] = new["state"]
     zero = jnp.int32(0)
     row0 = zero if lane is None else lane
     with jax.named_scope("kv_slab"):
@@ -991,7 +1072,7 @@ def _typed_tail(x, new, params, cache, pos0, cfg: TransformerConfig,
 
 def _decode_chunk(params, cache, tokens, pos0, cfg: TransformerConfig,
                   uniform_pos: bool = False, beam_anc=None, lane=None,
-                  n_real=None, with_routes: bool = False):
+                  n_real=None, with_routes: bool = False, live=None):
     """Process T new tokens per row against the cache in ONE pass:
     ``tokens [B, T]`` at global positions ``pos0[b] + (0..T-1)`` ->
     ``(logits [B, T, V] f32, cache)``.
@@ -1063,7 +1144,8 @@ def _decode_chunk(params, cache, tokens, pos0, cfg: TransformerConfig,
         # what that one does not express (the docstring above).
         return _chunk_in_place(params, cache, tokens, pos0, cfg,
                                uniform_pos=uniform_pos, lane=lane,
-                               n_real=n_real, with_routes=with_routes)
+                               n_real=n_real, with_routes=with_routes,
+                               live=live)
     reject_extended(cfg, why)
     if lane is not None:
         raise ValueError(f"lane= (in-place admission into one lane of a "
@@ -1617,8 +1699,12 @@ def generate(params, prompt, cfg: TransformerConfig, max_new_tokens: int,
         # Cache holds K/V for [0, p); the scan starts at the last
         # prompt position (its step recomputes identical K/V in place
         # and yields the logits that sample token p).
-        cache, _ = prefill(params, prompt, cfg, last_logits=False,
-                           kv_int8=kv_int8)
+        # (A state is not a slot: recomputed, the last position would
+        # enter it twice.  A stack with retention layers prefills the
+        # prompt but for its last token.)
+        cache, _ = prefill(params,
+                           prompt[:, :-1] if cfg.state_planes else prompt,
+                           cfg, last_logits=False, kv_int8=kv_int8)
         start = p - 1
     else:
         cache = init_cache(cfg, b, kv_int8=kv_int8)

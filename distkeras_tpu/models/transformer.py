@@ -37,6 +37,7 @@ from jax.sharding import PartitionSpec as P
 
 from distkeras_tpu.ops.attention import flash_attention
 from distkeras_tpu.ops.grouped import TILE_M as GROUP_TILE, grouped_matmul
+from distkeras_tpu.ops.retention import log_gate, retention_attention
 
 
 @dataclasses.dataclass(frozen=True)
@@ -160,12 +161,16 @@ class TransformerConfig:
     # of one kind is one scan.  A stack whose lists name full attention
     # and the dense feed-forward everywhere is today's stack: the lists
     # are dropped (``__post_init__``) and it compiles today's programs.
-    # ``layer_types``: "window" | "full" per layer; a window layer
-    # attends its last ``sliding_window`` positions (self included) —
-    # unlike ``attention_window``, which windows the WHOLE stack and
-    # makes the serving lanes roll.  Served, a window layer's K/V plane
-    # is a ring of ``sliding_window`` slots beside the full layers'
-    # ``max_len``-slot planes.
+    # ``layer_types``: "window" | "full" | "retention" per layer; a
+    # window layer attends its last ``sliding_window`` positions (self
+    # included) — unlike ``attention_window``, which windows the WHOLE
+    # stack and makes the serving lanes roll.  Served, a window layer's
+    # K/V plane is a ring of ``sliding_window`` slots beside the full
+    # layers' ``max_len``-slot planes.  A retention layer is power
+    # retention of degree 2 (``ops/retention.py``): no softmax, weights
+    # ``(q . k / sqrt d)^2`` under a per-K/V-head gate ``attn/wg [D,
+    # kv_heads]``, ``attn/bg``; served, its plane is a float32 STATE of
+    # fixed size, whatever the position.
     layer_types: tuple | None = None
     sliding_window: int | None = None
     # ``ffn_types``: "dense" | "sparse" per layer.  A sparse layer is
@@ -266,6 +271,12 @@ class TransformerConfig:
         return self.layer_types.count("window") if self.typed else 0
 
     @property
+    def state_planes(self) -> int:
+        """State planes of the decode cache: one per retention layer
+        of a typed stack."""
+        return self.layer_types.count("retention") if self.typed else 0
+
+    @property
     def head_dim(self) -> int:
         if self.d_head is not None:
             return self.d_head
@@ -316,6 +327,8 @@ def reject_extended(cfg: "TransformerConfig", path: str,
           if getattr(cfg, k) != base and k not in allow]
     on += [k for k, base in _TYPED_KEYS.items() if getattr(cfg, k) != base]
     if cfg.n_passes > 1 or on:
+        if cfg.state_planes:   # the kind no other path knows, by name
+            on[on.index("layer_types")] = "layer_types: 'retention'"
         what = (f"a looped stack (n_passes={cfg.n_passes})"
                 if cfg.n_passes > 1 else
                 f"the extended block ({' / '.join(on)})")
@@ -415,7 +428,7 @@ def init_params(rng, cfg: TransformerConfig):
     d, f, h, hd = cfg.d_model, cfg.d_ff, cfg.n_heads, cfg.head_dim
     kv = cfg.kv_heads
 
-    def group(gkey, L, sparse=None):
+    def group(gkey, L, sparse=None, retention=False):
         """``L`` layers of one kind, stacked on a leading axis; the
         whole stack where the layers do not differ (``sparse`` None:
         the old capacity-dispatch experts where ``num_experts``)."""
@@ -443,6 +456,16 @@ def init_params(rng, cfg: TransformerConfig):
         if cfg.qk_norm:
             layers["attn"]["q_scale"] = jnp.ones((L, hd))
             layers["attn"]["k_scale"] = jnp.ones((L, hd))
+        if retention:
+            # The gate, one scalar a K/V head.  Its bias is drawn
+            # around 6: g = sigmoid(6 +- 2) keeps a state for tens to
+            # thousands of positions (a trained gate is learnt; at 0 a
+            # state would be gone within a few positions and nothing
+            # of the mechanism would show).
+            layers["attn"]["wg"] = stack(jax.random.fold_in(gk[3], 1),
+                                         (d, kv), d)
+            layers["attn"]["bg"] = 6.0 + jax.random.normal(
+                jax.random.fold_in(gk[3], 2), (L, kv), jnp.float32)
         if sparse:
             ef, held = cfg.expert_ff, len(cfg.experts_held)
             # Every expert a draw of its own, by its ID: a share holds
@@ -493,7 +516,8 @@ def init_params(rng, cfg: TransformerConfig):
         layers = {
             ".".join(kind): group(
                 jax.random.fold_in(keys[0], i), kinds.count(kind),
-                sparse=kind[1] == "sparse")
+                sparse=kind[1] == "sparse",
+                retention=kind[0] == "retention")
             for i, kind in enumerate(dict.fromkeys(kinds))}
     else:
         layers = group(None, cfg.n_layers)
@@ -524,7 +548,7 @@ def init_params(rng, cfg: TransformerConfig):
 def _validate_typed(cfg: "TransformerConfig") -> None:
     """What a typed stack's keys have to say together."""
     n = cfg.n_layers
-    for name, kinds in (("layer_types", ("window", "full")),
+    for name, kinds in (("layer_types", ("window", "full", "retention")),
                         ("ffn_types", ("dense", "sparse"))):
         got = getattr(cfg, name)
         if len(got) != n or set(got) - set(kinds):
@@ -543,6 +567,12 @@ def _validate_typed(cfg: "TransformerConfig") -> None:
                 f"window layers need a sliding_window >= 1 that divides "
                 f"max_len={cfg.max_len} (a window layer's cache plane is "
                 f"a ring of that many slots), got {w}")
+    if "retention" in cfg.layer_types and (
+            cfg.head_dim % 2 or cfg.n_heads // cfg.kv_heads > 5):
+        raise ValueError(
+            "retention layers need an even head_dim and at most 5 query "
+            f"heads a K/V head, got head_dim={cfg.head_dim}, n_heads="
+            f"{cfg.n_heads}, n_kv_heads={cfg.kv_heads}")
     if "sparse" in cfg.ffn_types:
         held = cfg.experts_held
         if not (1 <= cfg.moe_top_k <= cfg.num_experts) or not held or (
@@ -618,8 +648,10 @@ def _resolve_attention_fn(cfg: "TransformerConfig", attention_fn,
                 "segment_ids")
         by_window = lambda window: lambda q, k, v: flash_attention(
             q, k, v, True, window=window)
+        # A retention layer's attention needs the layer's gate, which
+        # no ``fn(q, k, v)`` takes: ``_attention_block`` runs it.
         return {"window": by_window(cfg.sliding_window),
-                "full": by_window(None)}
+                "full": by_window(None), "retention": None}
     if attention_fn is None:
         return lambda q, k, v: flash_attention(
             q, k, v, True, window=cfg.attention_window,
@@ -692,6 +724,11 @@ def _dropout(x, rate: float, key):
 SCOPES = ("embed", "norm", "attn_proj", "attn", "kv_slab", "mlp", "head",
           "loop_exit")
 MOE_SCOPES = ("moe_route", "moe_experts", "moe_shared")
+# Inside ``attn``, in a retention layer: ret_gate (the gate's product
+# and log-sigmoid), ret_state (the state's decay-and-add and, in a
+# decode step, its query: the kernel ``ret_state_step`` sits here),
+# ret_chunk (a chunk's own pairs and its query of the state before it).
+RET_SCOPES = ("ret_gate", "ret_state", "ret_chunk")
 
 
 def _rms_norm(x, scale, eps=1e-6, scope="norm"):
@@ -732,10 +769,15 @@ def _attention_block(lp, x, attention_fn, rope_ang=None, kv_groups=1,
             q, k = rope_rotate(q, rope_ang), rope_rotate(k, rope_ang)
     kv = (k, v)  # post-rope, pre-GQA-expansion: the decode cache layout
     with jax.named_scope("attn"):
-        if kv_groups > 1:  # GQA: expand shared K/V heads for the kernel
-            k = jnp.repeat(k, kv_groups, axis=2)
-            v = jnp.repeat(v, kv_groups, axis=2)
-        out = attention_fn(q, k, v)
+        if "wg" in lp:  # a retention layer: the attention form, plain
+            with jax.named_scope("ret_gate"):
+                logg = log_gate(x, lp["wg"], lp["bg"])
+            out = retention_attention(q, k, v, logg).astype(x.dtype)
+        elif kv_groups > 1:  # GQA: expand shared K/V heads for the kernel
+            out = attention_fn(q, jnp.repeat(k, kv_groups, axis=2),
+                               jnp.repeat(v, kv_groups, axis=2))
+        else:
+            out = attention_fn(q, k, v)
     with jax.named_scope("attn_proj"):
         out = jnp.einsum("bshk,hkd->bsd", out, lp["wo"])
     return (out, kv) if return_kv else out

@@ -239,6 +239,8 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
         (``chunk_attends_prefix``, the question ``_decode_chunk``
         itself asks), ``max_len`` where it keeps the dense body.  Host
         integers known at dispatch; no device read."""
+        if not (self.cfg.kv_planes or self.cfg.kv_ring_planes):
+            return 0        # states only: no cache position is read
         sharded = self.mesh is not None and self.mesh.size > 1
         if chunk_attends_prefix(self.cfg, width, cache, sharded=sharded):
             return start + width
@@ -257,6 +259,8 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
         integers from the lane table (the steps dispatched so far, so
         an unread round counts); no device read."""
         cap = self.cfg.max_len
+        if not (self.cfg.kv_planes or self.cfg.kv_ring_planes):
+            return 0
         sharded = self.mesh is not None and self.mesh.size > 1
         if not decode_attends_prefix(self.cfg, 1, self.cache,
                                      sharded=sharded):
@@ -623,7 +627,7 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
         return True
 
     def _close_round(self, rnd, out, chunks: int, idle: bool,
-                     overlapped: bool = False) -> None:
+                     overlapped: bool = False, state_lanes: int = 0) -> None:
         """The counts of one ``step()``, taken where the round ends
         (session active only): ONE pass over the lane table, set as
         the ``serving.lanes_busy`` gauge and written into the closing
@@ -662,6 +666,10 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
                 # Ring planes: what of a lane's positions a window
                 # layer still holds (sum of min(position, window)).
                 rnd.fields["kv_live_window"] = kv_live_window
+            if self.cfg.state_planes:
+                # Lanes whose state the step DISPATCHED here read and
+                # wrote (its ``live`` mask): the others' was left alone.
+                rnd.fields["state_lanes"] = state_lanes
             if moe is not None:
                 # Of the round READ here (like ``tokens``).
                 (rnd.fields["moe_assigned"], rnd.fields["moe_held"],
@@ -704,9 +712,10 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
     def _real(self, n: int) -> dict:
         """``n_real=`` of an admission dispatch — how many of the
         chunk's tokens are real, which a program that writes ring
-        planes takes as one argument more — or nothing: every other
-        engine's programs keep their signature."""
-        return {"n_real": n} if self.cfg.kv_ring_planes else {}
+        planes or a state takes as one argument more — or nothing: every
+        other engine's programs keep their signature."""
+        cfg = self.cfg
+        return {"n_real": n} if cfg.kv_ring_planes or cfg.state_planes else {}
 
     def _exec_chunk(self, lane, start, rows):  # pragma: no cover
         raise NotImplementedError(

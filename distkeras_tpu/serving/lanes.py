@@ -174,6 +174,19 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
     everything else (``plan=``, ``prompt_cache=``, ``prefix_pool=``,
     ``kv_int8=``, ``lane_tiers=``, a window) rejects them by name.
 
+    **State planes** (a stack with retention layers, ``layer_types``
+    ``"retention"``): such a layer's lane plane is a float32 state of
+    fixed size, not slots — ``max_len`` is positions there, not memory
+    (``serving.kv_layout``'s ``state_bytes_per_lane``;
+    :meth:`memory_footprint` counts it under ``kv_bytes``).  Three
+    things a K/V slab never needed: the decode step takes the lanes'
+    ``live`` mask (:meth:`_live`) and leaves a free, finished or
+    ADMITTING lane's state unread and unwritten; a new occupant starts
+    from zero (a chunk or a step at position 0 clears the state: a
+    stale state is not masked by position); and an admission's padding
+    neither adds to a state nor decays it (``n_real``; a chunked tail
+    stays on the grid, as with ring planes).
+
     **Live weight push** (round 20, ``hot_swap=True``): every decode
     and admission program takes the param tree as an explicit jit
     argument (never donated), so :meth:`swap_params` can replace the
@@ -607,6 +620,12 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
             # contract lint reads an event's labels off its call.)
             slots = self.lanes * cfg.max_len
             per = 2 * cfg.kv_heads * cfg.head_dim * self.cache["k"].itemsize
+            # A third kind where the stack has retention layers: one
+            # float32 state a layer, of a fixed size at every position
+            # (``max_len`` is positions there, not memory); 0 planes
+            # and "" say the stack has none.
+            state = ([self.cache["s"], self.cache["z"]]
+                     if cfg.state_planes else [])
             obs.event("serving.kv_layout", passes=cfg.n_passes,
                       layers=cfg.n_layers, planes=cfg.kv_planes,
                       bytes_per_slot=slab // slots, slots=slots,
@@ -615,7 +634,11 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
                       window=cfg.sliding_window,
                       ring_slots=cfg.sliding_window,
                       bytes_per_slot_full=per * cfg.kv_planes,
-                      bytes_per_slot_window=per * cfg.kv_ring_planes)
+                      bytes_per_slot_window=per * cfg.kv_ring_planes,
+                      planes_state=cfg.state_planes,
+                      state_bytes_per_lane=sum(
+                          int(a.nbytes) for a in state) // self.lanes,
+                      state_dtype=str(state[0].dtype) if state else "")
             return
         # [planes, lanes, max_len, ...] (the paged store: [planes,
         # blocks, block, ...]): slots are rows x positions.
@@ -719,15 +742,17 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
         # held experts of every assignment), the round's moe_* counts.
         routed = cfg.typed and "sparse" in cfg.ffn_types
 
-        def one_step_p(params, cache, cur, pos, keys, temps, tps, mps):
+        def one_step_p(params, cache, cur, pos, keys, temps, tps, mps,
+                       live=None):
             routes = None
             if routed:
                 logits, cache, routes = _decode_chunk(
-                    params, cache, cur[:, None], pos, cfg, with_routes=True)
+                    params, cache, cur[:, None], pos, cfg, with_routes=True,
+                    live=live)
                 routes = routes[:, :, 0]
             else:
                 logits, cache = _decode_chunk(
-                    params, cache, cur[:, None], pos, cfg)
+                    params, cache, cur[:, None], pos, cfg, live=live)
             logits = logits[:, 0]                      # [lanes, V]
             if per_request_sampling:
                 # Vectorized per-lane params: greedy lanes (t <= 0)
@@ -796,9 +821,9 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
             # pre-round-20 one.
             return one_step_p
 
-        def one_step(cache, cur, pos, keys, temps, tps, mps):
+        def one_step(cache, cur, pos, keys, temps, tps, mps, *live):
             return one_step_p(self.params, cache, cur, pos, keys,
-                              temps, tps, mps)
+                              temps, tps, mps, *live)
         return one_step
 
     def _make_step(self, n: int):
@@ -807,14 +832,18 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
 
         if self._hot_swap:
             def step_n_p(params, cache, cur, pos, keys, temps, tps,
-                         mps):
+                         mps, *live):
+                # ``live`` (a stack with state planes only: ``_live``):
+                # the lanes this round decodes; the step leaves every
+                # other lane's state as it is.
                 if constrain is not None:
                     cache = constrain(cache)
 
                 def body(carry, _):
                     cache, cur, pos = carry
                     cache, cur, pos, routes = one_step(
-                        params, cache, cur, pos, keys, temps, tps, mps)
+                        params, cache, cur, pos, keys, temps, tps, mps,
+                        *live)
                     return (cache, cur, pos), (cur, routes)
                 (cache, cur, pos), (toks, routes) = jax.lax.scan(
                     body, (cache, cur, pos), None, length=n)
@@ -827,7 +856,7 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
             # donated — version N must survive the swap for rollback.
             return jax.jit(step_n_p, donate_argnums=1)
 
-        def step_n(cache, cur, pos, keys, temps, tps, mps):
+        def step_n(cache, cur, pos, keys, temps, tps, mps, *live):
             if constrain is not None:
                 # Pod-sharded engines pin the cache layout here: GSPMD
                 # then inserts the per-token collectives (psum per
@@ -839,7 +868,7 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
             def body(carry, _):
                 cache, cur, pos = carry
                 cache, cur, pos, routes = one_step(cache, cur, pos, keys,
-                                                   temps, tps, mps)
+                                                   temps, tps, mps, *live)
                 return (cache, cur, pos), (cur, routes)
             (cache, cur, pos), (toks, routes) = jax.lax.scan(
                 body, (cache, cur, pos), None, length=n)
@@ -950,10 +979,11 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
             raise ValueError(
                 f"chunked admission grid overflows the cache (chunk at "
                 f"{plan[-1][0]} + {w_chunk} > {self.cfg.max_len})")
-        if rem and self.cfg.kv_ring_planes:
+        if rem and (self.cfg.kv_ring_planes or self.cfg.state_planes):
             # Ring planes: the tail stays on the grid and its padding
             # goes unwritten (``n_real``) — a backed-up tail would ask a
-            # ring for positions the chunk before has rolled out of it.
+            # ring for positions the chunk before has rolled out of it
+            # (and would enter a state a second time).
             plan.append((lo + m * w_chunk,
                          self._bucket_for(rem, lo + m * w_chunk)))
         elif rem:
@@ -1280,7 +1310,7 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
                 fn=self._steps[1],
                 args=pargs + (self.cache, self.cur, self.pos,
                               self.keys, self.temps, self.tps,
-                              self.mps),
+                              self.mps) + self._live(),
                 donate_argnums=(d,)),
             TraceSpec(
                 name=f"continuousbatcher_{mode}/admit_b"
@@ -1358,11 +1388,7 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
             # elastic engine must still step its lane count back down.
             self._maybe_scale_down()
             self._run_pending_chunk()
-            # Lanes with a token still to decode: not empty, finished,
-            # admitting, or with their whole budget already dispatched.
-            lanes = [(i, s) for i, s in enumerate(self._lane_state)
-                     if s is not None and not s.done and s.chunks is None
-                     and s.launched < s.max_new]
+            lanes = self._decoding()
             # ``_inflight`` is rebound only after the dispatch: one that
             # raises keeps the unread round for the next call.
             unread, launched = self._inflight, None
@@ -1397,7 +1423,8 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
             if obs.active() is not None:
                 self._close_round(rnd, out, chunks, idle,
                                   overlapped=launched is not None
-                                  and unread is not None)
+                                  and unread is not None,
+                                  state_lanes=len(lanes))
             return out
 
     def _return_flushed(self, out: dict) -> None:
@@ -1421,8 +1448,32 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
             self._steps[n] = self._make_step(n)
         self.cache, self.cur, self.pos, toks = self._steps[n](
             *self._pargs(), self.cache, self.cur, self.pos, self.keys,
-            self.temps, self.tps, self.mps)
+            self.temps, self.tps, self.mps, *self._live())
         return toks
+
+    def _decoding(self) -> list:
+        """``(lane, _Lane)`` of the lanes with a token still to decode:
+        not empty, finished, admitting, or with their whole budget
+        already dispatched."""
+        return [(i, s) for i, s in enumerate(self._lane_state)
+                if s is not None and not s.done and s.chunks is None
+                and s.launched < s.max_new]
+
+    def _live(self) -> tuple:
+        """The decode step's last argument where the cache holds state
+        planes: the mask ``[lanes]`` of the lanes that decode in the
+        round about to be dispatched.  A K/V slot written for a lane
+        that does not decode is a parked write, masked by position; a
+        state has no positions, and a step on an ADMITTING lane would
+        decay and add to the very state its chunks are building — so
+        the step is told, and leaves the others' state unread.
+        Nothing for every other engine: its programs keep their
+        signature."""
+        if not self.cfg.state_planes:
+            return ()
+        mask = np.zeros((len(self._lane_state),), np.int32)
+        mask[[i for i, _ in self._decoding()]] = 1
+        return (jnp.asarray(mask),)
 
 
 __all__ = ["ContinuousBatcher", "KV_INT8_LANE_ADVISORY"]

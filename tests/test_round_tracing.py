@@ -323,6 +323,94 @@ def test_looped_programs_keep_their_names_and_hold_every_scope(
                    for sc in tfm.SCOPES), loc
 
 
+RETENTION = tfm.TransformerConfig(
+    vocab_size=64, d_model=32, n_heads=2, n_layers=2, d_ff=64, max_len=64,
+    rope=True, ffn_gated=True, tie_head=False, fused_qkv=True, qk_norm=True,
+    layer_types=("retention", "retention"))
+
+
+@pytest.fixture(scope="module")
+def retention_run(tmp_path_factory):
+    """A retention stack (a lane's plane is a state, not keys and
+    values) through a two-lane hot-swap engine: the trace's records,
+    and its two programs lowered."""
+    path = str(tmp_path_factory.mktemp("ret") / "t.jsonl")
+    with obs.session(trace_path=path):
+        eng = dk.ContinuousBatcher(toy_params(RETENTION), RETENTION, lanes=2,
+                                   hot_swap=True, prefill_chunk=8,
+                                   prompt_buckets=(8,), max_queue=4)
+        eng.enqueue(np.arange(5, dtype=np.int32), 3)
+        eng.step()
+        eng.enqueue(np.arange(20, dtype=np.int32), 2)   # admitting: 3 chunks
+        while eng.running():
+            eng.step()
+    texts = {("decode_step" if spec.name.endswith("decode_step") else "admit"):
+             spec.fn.lower(*spec.args).as_text(debug_info=True)
+             for spec in eng.traced_for_analysis()}
+    return read_trace(path), texts
+
+
+def test_state_planes_are_named_in_the_layout_and_the_rounds(retention_run,
+                                                            rounds):
+    """``serving.kv_layout`` says what a lane's state costs (at any
+    position); ``serving.round`` how many lanes' state the step it
+    dispatched read and wrote — not the free, finished or admitting
+    ones'."""
+    records, _ = retention_run
+    (layout,) = [r["fields"] for r in records
+                 if r.get("name") == "serving.kv_layout"]
+    per_lane = 2 * 2 * (9 * 16 * 16 + 9 * 16) * 4     # layers, heads, s + z
+    assert (layout["planes_state"], layout["state_bytes_per_lane"],
+            layout["state_dtype"]) == (2, per_lane, "float32")
+    mine = [r["fields"] for r in records
+            if r.get("name") == "serving.round"]
+    assert [r["state_lanes"] for r in mine][:3] == [1, 1, 2]
+    assert mine[1]["lanes_admitting"] == 1 and mine[1]["lanes_busy"] == 2
+    assert max(r["state_lanes"] for r in mine) == 2
+    assert all(r["state_lanes"] == 0 for r in mine if r.get("idle"))
+    # an engine without state planes says nothing of them
+    assert all("state_lanes" not in r["fields"] for r in rounds[0])
+
+
+@pytest.mark.parametrize("program,metric,scopes", [
+    ("decode_step", "decode_step_ms", ("ret_gate", "ret_state")),
+    ("admit", "prefill_ms_per_ktok", ("ret_gate", "ret_state", "ret_chunk"))])
+def test_retention_programs_keep_their_names_and_hold_the_ret_scopes(
+        retention_run, program, metric, scopes):
+    """The programs are read by the readers that read every other
+    engine's (names unchanged); the gate and the state's update stand
+    inside ``attn`` under their own scopes, a chunk's pairs under
+    ``ret_chunk`` (``models/transformer.py::RET_SCOPES``)."""
+    text = retention_run[1][program]
+    (module,) = re.findall(r"module @(\S+)", text)
+    assert re.search(_pattern(metric), module), module
+    locs = " ".join(set(re.findall(r'loc\("([^"]+)"', text)))
+    for scope in scopes:
+        assert scope in tfm.RET_SCOPES
+        assert re.search(rf"(?<![\w.]){scope}(?![\w.])", locs), scope
+    assert re.search(r"attn/ret_gate(?![\w.])", locs)
+    assert re.search(r"attn/ret_state(?![\w.])", locs)
+
+
+def test_the_state_kernel_is_named_ret_state_step():
+    """The decode update's Pallas call is ``ret_state_step``: the
+    compiled instruction, and with it the profile's event (row
+    ``mosaic:ret_state_step`` of ``breakdown.device_ops``), carries the
+    name ``step_ret_state_roofline``'s pattern looks for."""
+    from distkeras_tpu.ops import retention as ret
+
+    sd = jax.ShapeDtypeStruct
+    text = jax.jit(ret.ret_state_step.__wrapped__).trace(
+        sd((2, 8, 8, 128), jnp.float32),
+        sd((2, 2, 8, 65, 128, 128), jnp.float32),
+        sd((2, 2, 8, 65, 128), jnp.float32), sd((), jnp.int32),
+        sd((2,), jnp.int32)).lower(lowering_platforms=("tpu",)).as_text(
+            debug_info=True)
+    assert 'kernel_name = "ret_state_step"' in text
+    assert re.search(r'ret_state_step/pallas_call"', text)
+    assert re.search(_pattern("step_ret_state_roofline"), "ret_state_step.3")
+
+
 TRAIN_CFG = tfm.TransformerConfig(
     vocab_size=256, d_model=256, n_heads=2, n_layers=2, d_ff=512,
     max_len=256, rope=True, remat=True, ce_chunks=2, attention_window=128)
