@@ -51,10 +51,12 @@ from distkeras_tpu.models.quant import (
 from distkeras_tpu.ops.retention import (
     log_gate,
     phi_rows,
+    ret_chunk_fwd,
     ret_state_step,
     retention_chunk,
     retention_step,
     step_operands,
+    use_ret_chunk_kernel,
     use_ret_kernel,
 )
 from distkeras_tpu.ops.attention import (
@@ -700,7 +702,10 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
     TPU the kernel ``ret_state_step``, elsewhere the same arithmetic
     (``ops/retention.py``); ``live [B]`` names the rows that decode —
     every other row's state is left as it is, unread.  A chunk is the
-    chunked form through the row's (``lane``'s) state; ``n_real`` keeps
+    chunked form through the row's (``lane``'s) state — one row at heads
+    of 128 on the TPU: the kernel ``ret_chunk_fwd`` on the state where
+    it lies; else ``retention_chunk`` on a copy cut out and put back
+    (``use_ret_chunk_kernel``) — and ``n_real`` keeps
     an admission's padding out of it.  A row at position 0 starts from
     a zero state whatever its plane holds: a stale state is not masked
     by position as stale slots are."""
@@ -865,6 +870,14 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
                     (s_all, z_all))
         # Chunks: each row through its own state (one row: the lane's).
         row0 = jnp.int32(0) if lane is None else lane
+        if use_ret_chunk_kernel(cfg.head_dim, groups, t_len, b, s_all.dtype,
+                                sharded):
+            # The state read and written where it lies, by the kernel.
+            with jax.named_scope("ret_chunk"):
+                y, s_all, z_all = ret_chunk_fwd(
+                    q[0].astype(dtype), k[0], v[0], logg[0], s_all, z_all,
+                    plane, row0, n_real, fresh[0])
+            return y[None], (s_all, z_all)
         at = (plane, row0) + (jnp.int32(0),) * 4
         with jax.named_scope("ret_state"):
             s_in = jax.lax.dynamic_slice(s_all, at, (1, b) + s_all.shape[2:])

@@ -727,7 +727,8 @@ MOE_SCOPES = ("moe_route", "moe_experts", "moe_shared")
 # Inside ``attn``, in a retention layer: ret_gate (the gate's product
 # and log-sigmoid), ret_state (the state's decay-and-add and, in a
 # decode step, its query: the kernel ``ret_state_step`` sits here),
-# ret_chunk (a chunk's own pairs and its query of the state before it).
+# ret_chunk (a chunk's own pairs and its query of the state before it;
+# on the TPU the kernel ``ret_chunk_fwd``, the new state included).
 RET_SCOPES = ("ret_gate", "ret_state", "ret_chunk")
 
 

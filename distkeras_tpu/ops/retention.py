@@ -12,8 +12,16 @@ with a gate ``log g_t <= 0`` a position:
   ``S_t = g_t S_{t-1} + phi(k_t) v_t^T``, ``z_t = g_t z_{t-1} +
   phi(k_t)``, ``y_t = phi(q_t)^T S_t / (phi(q_t)^T z_t + eps)`` where
   ``phi(q) . phi(k) = (q . k / sqrt d)^2``.
-- **chunked form** (:func:`retention_chunk`; a prefill chunk after a
-  state): the attention form inside the chunk plus the state's term.
+- **chunked form** (a prefill chunk after a state): the attention form
+  inside the chunk plus the state's term.  Two paths, picked by the
+  shapes (:func:`use_ret_chunk_kernel`): :func:`retention_chunk`, plain
+  ``jax.numpy`` on a state cut out of the slabs — everywhere, and the
+  other's oracle — and on the TPU, for one lane, heads of 128 and an
+  engine's chunk lengths, the Pallas kernel ``ret_chunk_fwd``
+  (:func:`ret_chunk_fwd`): one K/V head a grid step, the lane's state
+  aliased in and out where it lies, ``phi`` of the chunk's queries and
+  keys built an offset at a time in VMEM between a lane rotation and
+  the MXU, never in HBM.
 
 **phi and the state's layout.**  ``phi(x)`` holds the ``d (d + 1) / 2``
 products ``x_a x_b``, ``a <= b``, of ``x / d^(1/4)``, the off-diagonal
@@ -397,3 +405,248 @@ def ret_state_step(x, s_all, z_all, plane, live, rows: int = STEP_ROWS,
         with pltpu.force_tpu_interpret_mode():
             return tuple(call())
     return tuple(call())
+
+
+# --------------------------------------------------- the chunk's kernel
+
+# Offsets of phi(q) one product of the chunk kernel takes side by side
+# (its contraction is ``CHUNK_GROUP d`` long), and offsets of phi(k) one
+# pass over the new state writes: chosen on the chip
+# (``scripts/sweep_ret_chunk.py``); both divide the d/2 offsets after
+# the first and the d/2 + 1 offsets.
+CHUNK_GROUP = 4
+STATE_GROUP = 5
+CHUNK_LENGTHS = (64, 128, 256, 512)
+CHUNK_VMEM_BYTES = 64 * 1024 * 1024
+
+
+def _mm(a, b, dims=(((1,), (0,)), ((), ()))):
+    """A product on the MXU with float32 accumulation; float32 operands
+    (the tests' compute dtype) at full precision."""
+    exact = jax.lax.Precision.HIGHEST if a.dtype == jnp.float32 else None
+    return jax.lax.dot_general(a, b, dims, precision=exact,
+                               preferred_element_type=jnp.float32)
+
+
+def _ret_chunk_kernel(sc_ref, q_ref, k_ref, v_ref, vt_ref, col_ref, row_ref,
+                      coef_ref, s_ref, z_ref, y_ref, s_out, z_out, qf_scr,
+                      qb_scr, kf_scr, kl_scr, sb_scr, zc_scr, num_scr, den_scr, *,
+                      groups: int):
+    """One K/V head of :func:`retention_chunk`: the head's state ``s [R,
+    d, d]``, ``z [R, d]`` comes in once and goes out once, and ``phi``
+    of the chunk's queries and keys exists a few offsets at a time, in
+    VMEM, between a lane rotation and the MXU.
+
+    ``sc_ref``: (plane, lane, n_real, fresh).  ``q_ref [C, G d]``,
+    ``k_ref``/``v_ref [C, d]``, ``vt_ref [d, C]`` in the compute dtype,
+    q and k divided by ``d^(1/4)``; ``col_ref [C, 4]`` the gate's
+    cumulative sum, ``grow`` and ``left`` down the sublanes, ``row_ref
+    [1, C]`` the cumulative sum along the lanes (float32).
+
+    *The state's query* (skipped by a ``fresh`` chunk), a query head
+    at a time: row ``o`` of ``phi(q)`` is ``sqrt 2 x roll(x, o)`` in
+    float32 — the ``sqrt 2`` of ``c_o`` folded into one factor once, the
+    rotation run on the queries as they came (in bfloat16 two rows to a
+    32-bit lane: half the vregs through the rotate unit); offset 0 (the
+    squares) stands before the loop, and the second copy of a pair at
+    offset ``d / 2`` meets zeros in the state's copy — rounded to the
+    compute dtype ONCE, and that rounded value goes both to the MXU
+    (``CHUNK_GROUP`` offsets side by side against ``[CHUNK_GROUP d, d]``
+    of the state's copy ``sb_scr [(o, a), v]``, in the compute dtype)
+    and, times ``z[o]``, into the normaliser: numerator and normaliser
+    weigh a position alike, as :func:`retention_chunk`'s do.  *The new
+    state*, ``STATE_GROUP`` offsets a pass: ``v^T [phi_o(k) left]`` and
+    the column sums, float32 but for the product's operands.  *The
+    chunk's own pairs* as :func:`retention_chunk` has them, and the
+    quotient."""
+    c_len, d = k_ref.shape
+    n_off = z_ref.shape[0]
+    half = d // 2
+    dt = q_ref.dtype
+    f32 = jnp.float32
+    n_real, fresh = sc_ref[2], sc_ref[3]
+    cum_i, grow = col_ref[:, 0:1], col_ref[:, 1:2]            # [C, 1]
+    # grow at the chunk's end, along the lanes ([1, d]): zero if fresh
+    g_last = col_ref[c_len - 1:c_len, 1:2] + jnp.zeros((1, d), f32)
+    back = lambda o: (d - o) % d               # roll(x, back(o)): x[a + o]
+
+    for g in range(groups):
+        q_g = q_ref[:, g * d:(g + 1) * d]
+        mine = slice(g * c_len, (g + 1) * c_len)
+        qb_scr[mine, :] = q_g
+        qf_scr[mine, :] = q_g.astype(f32) * f32(2.0 ** 0.5)
+    kf_scr[...] = k_ref[...].astype(f32)
+    kl_scr[...] = kf_scr[...] * col_ref[:, 2:3]
+
+    @pl.when(fresh != 0)
+    def _no_state():
+        num_scr[...] = jnp.zeros_like(num_scr)
+        den_scr[...] = jnp.zeros_like(den_scr)
+
+    @pl.when(fresh == 0)
+    def _query_state():
+        def cast(o, carry):
+            sb_scr[pl.ds(pl.multiple_of(o * d, d), d), :] = (
+                s_ref[o].T.astype(dt))
+            return carry
+
+        jax.lax.fori_loop(0, n_off, cast, 0)
+        last = (n_off - 1) * d
+        sb_scr[last + half:last + d, :] = jnp.zeros((half, d), dt)
+        zc_scr[...] = jnp.where(coef_ref[...] != 0, z_ref[...], 0.0)
+
+        def head(g, carry):
+            mine = pl.ds(pl.multiple_of(g * c_len, c_len), c_len)
+            x0 = qb_scr[mine, :].astype(f32)
+            p0 = (x0 * x0).astype(dt)
+            num_scr[mine, :] = _mm(p0, sb_scr[0:d, :])
+            den_scr[mine, :] = p0.astype(f32) * zc_scr[0:1, :]
+
+            def group(gi, carry):
+                o0 = 1 + gi * CHUNK_GROUP
+                ps, den = [], 0.0
+                for o in (o0 + j for j in range(CHUNK_GROUP)):
+                    other = pltpu.bitcast(pltpu.roll(pltpu.bitcast(
+                        qb_scr[mine, :], jnp.int32), back(o), 1), dt)
+                    ps.append((qf_scr[mine, :] * other.astype(f32)
+                               ).astype(dt))
+                    den = den + ps[-1].astype(f32) * zc_scr[pl.ds(o, 1), :]
+                num_scr[mine, :] += _mm(
+                    jnp.concatenate(ps, axis=1),
+                    sb_scr[pl.ds(pl.multiple_of(o0 * d, d), CHUNK_GROUP * d),
+                           :])
+                den_scr[mine, :] += den
+                return carry
+
+            jax.lax.fori_loop(0, (n_off - 1) // CHUNK_GROUP, group, 0)
+            return carry
+
+        jax.lax.fori_loop(0, groups, head, 0)
+
+    def state(gi, carry):
+        pks = []
+        for o in (gi * STATE_GROUP + j for j in range(STATE_GROUP)):
+            pk = (kl_scr[...] * pltpu.roll(kf_scr[...], back(o), 1)
+                  * coef_ref[pl.ds(o, 1), :])
+            z_out[pl.ds(o, 1), :] = (g_last * z_ref[pl.ds(o, 1), :]
+                                     + jnp.sum(pk, axis=0, keepdims=True))
+            pks.append(pk.astype(dt))
+        new = _mm(vt_ref[...], jnp.concatenate(pks, axis=1))
+        for j in range(STATE_GROUP):
+            o = gi * STATE_GROUP + j
+            s_out[o] = g_last * s_ref[o] + new[:, j * d:(j + 1) * d]
+        return carry
+
+    jax.lax.fori_loop(0, n_off // STATE_GROUP, state, 0)
+
+    row_i = jax.lax.broadcasted_iota(jnp.int32, (c_len, c_len), 0)
+    col_j = jax.lax.broadcasted_iota(jnp.int32, (c_len, c_len), 1)
+    keep = jnp.logical_and(col_j <= row_i, col_j < n_real)
+    decay = jnp.where(
+        keep, jnp.exp(jnp.where(keep, cum_i - row_ref[...], 0.0)), 0.0)
+    for g in range(groups):
+        mine = slice(g * c_len, (g + 1) * c_len)
+        score = _mm(q_ref[:, g * d:(g + 1) * d], k_ref[...],
+                    (((1,), (1,)), ((), ())))
+        a = score * score * decay
+        den = (jnp.sum(a, axis=1, keepdims=True) + grow * jnp.sum(
+            den_scr[mine, :], axis=1, keepdims=True))
+        num = _mm(a.astype(dt), v_ref[...]) + grow * num_scr[mine, :]
+        y_ref[:, g * d:(g + 1) * d] = (num / (den + EPS)).astype(y_ref.dtype)
+
+
+def use_ret_chunk_kernel(d: int, groups: int, c_len: int, rows: int, dtype,
+                         sharded: bool = False) -> bool:
+    """Kernel or :func:`retention_chunk` for a chunk: the backend, the
+    placement and the shapes decide (ONE row, a head of one lane tile,
+    a chunk length the kernel's tiles were built for, a float32
+    state)."""
+    return (_on_tpu() and not sharded and rows == 1 and d == _LANES
+            and groups <= 8 and c_len in CHUNK_LENGTHS
+            and jnp.dtype(dtype) == jnp.float32)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",),
+                   donate_argnames=("s_all", "z_all"))
+def ret_chunk_fwd(q, k, v, logg, s_all, z_all, plane, lane, n_real=None,
+                  fresh=None, interpret: bool = False):
+    """:func:`retention_chunk` through lane ``lane`` of plane ``plane``
+    of the state slabs ``s_all [P, B, KV, R, d, d]``, ``z_all [P, B, KV,
+    R, d]`` as ONE Pallas call, ``ret_chunk_fwd`` -> ``(y [C, H, d] in
+    q's dtype, s_all', z_all')``: grid (K/V heads,), the slabs aliased
+    in and out with the plane and the lane scalar-prefetched into their
+    index maps, so the lane's state is read and written where it lies
+    and no other block is touched.  The gate's cumulative sums, ``grow``
+    and ``left`` are :func:`retention_chunk`'s, worked out here."""
+    c_len, h, d = q.shape
+    kv = k.shape[1]
+    groups = h // kv
+    n_off = phi_rows(d)
+    dt = q.dtype
+    q, k = scale_qk(q, k)
+    n_real = jnp.int32(c_len) if n_real is None else n_real
+    fresh = jnp.bool_(False) if fresh is None else fresh
+    real = jnp.arange(c_len) < n_real
+    cum = jnp.cumsum(jnp.where(real[:, None], logg.astype(jnp.float32), 0.0),
+                     axis=0)                                   # [C, KV]
+    grow = jnp.exp(cum) * jnp.where(fresh, 0.0, 1.0)
+    left = jnp.where(real[:, None], jnp.exp(cum[-1] - cum), 0.0)
+    cols = jnp.stack([cum, grow, left, jnp.zeros_like(cum)],
+                     axis=-1).transpose(1, 0, 2)               # [KV, C, 4]
+    scalars = jnp.stack([jnp.asarray(a, jnp.int32).reshape(())
+                         for a in (plane, lane, n_real, fresh)])
+
+    def state_map(tail):
+        return lambda c, sc: (sc[0], sc[1], c) + (0,) * tail
+
+    head = lambda c, sc: (0, c)
+    own = lambda c, sc: (c, 0, 0)
+    s_spec = pl.BlockSpec((None, None, None, n_off, d, d), state_map(3))
+    z_spec = pl.BlockSpec((None, None, None, n_off, d), state_map(2))
+    q_spec = pl.BlockSpec((c_len, groups * d), head)
+    kv_spec = pl.BlockSpec((c_len, d), head)
+
+    def call(): return pl.pallas_call(
+        functools.partial(_ret_chunk_kernel, groups=groups),
+        name="ret_chunk_fwd",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(kv,),
+            in_specs=[q_spec, kv_spec, kv_spec,
+                      pl.BlockSpec((None, d, c_len), own),
+                      pl.BlockSpec((None, c_len, 4), own),
+                      pl.BlockSpec((None, 1, c_len), own),
+                      pl.BlockSpec((n_off, d), lambda c, sc: (0, 0)),
+                      s_spec, z_spec],
+            out_specs=[q_spec, s_spec, z_spec],
+            scratch_shapes=[pltpu.VMEM((groups * c_len, d), jnp.float32),
+                            pltpu.VMEM((groups * c_len, d), dt),
+                            pltpu.VMEM((c_len, d), jnp.float32),
+                            pltpu.VMEM((c_len, d), jnp.float32),
+                            pltpu.VMEM((n_off * d, d), dt),
+                            pltpu.VMEM((n_off, d), jnp.float32),
+                            pltpu.VMEM((groups * c_len, d), jnp.float32),
+                            pltpu.VMEM((groups * c_len, d), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((c_len, h * d), dt),
+                   jax.ShapeDtypeStruct(s_all.shape, s_all.dtype),
+                   jax.ShapeDtypeStruct(z_all.shape, z_all.dtype)],
+        # Operands are counted with the prefetched scalars.
+        input_output_aliases={8: 1, 9: 2},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=CHUNK_VMEM_BYTES),
+        cost_estimate=pl.CostEstimate(
+            flops=kv * c_len * d * (2 * (groups + 1) * n_off * d
+                                    + 4 * groups * c_len),
+            transcendentals=kv * c_len * c_len,
+            bytes_accessed=8 * kv * n_off * d * (d + 1)),
+    )(scalars, q.reshape(c_len, h * d), k.reshape(c_len, kv * d),
+      v.reshape(c_len, kv * d), v.transpose(1, 2, 0), cols,
+      cum.T[:, None, :], jnp.asarray(_phi_coef(d)), s_all, z_all)
+
+    if interpret:
+        with pltpu.force_tpu_interpret_mode():
+            y, s_all, z_all = call()
+    else:
+        y, s_all, z_all = call()
+    return y.reshape(c_len, h, d), s_all, z_all

@@ -193,6 +193,98 @@ def test_kernel_lowers_for_the_tpu_at_the_published_widths():
     assert "tpu_custom_call" in text and "ret_state_step" in text
 
 
+def _chunk_inputs(n, kv=2, g=5, d=128, seed=0):
+    """``n`` positions at the width the chunk kernel is built for, and
+    the slabs ``[2, 3, kv, ...]`` whose lane 2 of plane 1 holds the
+    state 48 earlier positions leave (so the normaliser is a sum of
+    squares, as an engine's always is); every other block random."""
+    rng = np.random.default_rng(seed)
+    draw = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    gate = lambda m: jax.nn.log_sigmoid(3.0 + draw(m, kv))
+    _, s, z = jax.jit(ret.retention_chunk)(
+        draw(48, kv * g, d), draw(48, kv, d), draw(48, kv, d), gate(48),
+        jnp.zeros((kv, 65, d, d)), jnp.zeros((kv, 65, d)))
+    s_all = draw(2, 3, kv, 65, d, d).at[1, 2].set(s)
+    z_all = draw(2, 3, kv, 65, d).at[1, 2].set(z)
+    return (draw(n, kv * g, d), draw(n, kv, d), draw(n, kv, d), gate(n),
+            s_all, z_all)
+
+
+@pytest.mark.parametrize("case", [
+    "chunk_of_64", "chunk_of_512", "short_of_the_chunk",
+    "fresh_over_garbage", "two_chunks_in_a_row"])
+def test_chunk_kernel_is_the_chunked_form(case):
+    """``ret_chunk_fwd`` in the TPU interpreter, float32, against
+    ``retention_chunk`` on the lane's state cut out of the slabs: the
+    outputs, the new state, and every other (plane, lane) of the slabs
+    bit for bit what it was."""
+    c_len = 512 if case == "chunk_of_512" else 64
+    n_real = 41 if case == "short_of_the_chunk" else c_len
+    fresh = case in ("fresh_over_garbage", "two_chunks_in_a_row")
+    q, k, v, logg, s0, z0 = _chunk_inputs(
+        2 * c_len, kv=1 if c_len == 512 else 2)
+    if case == "fresh_over_garbage":      # an earlier occupant's sums
+        s0, z0 = s0.at[1, 2].multiply(1e6), z0.at[1, 2].multiply(-1e6)
+    one, two = slice(0, c_len), slice(c_len, 2 * c_len)
+    kernel = lambda part, s, z, n, new: ret.ret_chunk_fwd(
+        q[part], k[part], v[part], logg[part], s, z, jnp.int32(1),
+        jnp.int32(2), jnp.int32(n), jnp.bool_(new), interpret=True)
+    y, s1, z1 = kernel(one, s0 + 0, z0 + 0, n_real, fresh)
+    before = ((jnp.zeros_like(s0[1, 2]), jnp.zeros_like(z0[1, 2])) if fresh
+              else (s0[1, 2], z0[1, 2]))
+    want = jax.jit(ret.retention_chunk)(
+        q[one], k[one], v[one], logg[one], *before, jnp.int32(n_real))
+    close = lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-4,
+                                                    atol=1e-5)
+    close(y[:n_real], want[0][:n_real])
+    close(s1[1, 2], want[1])
+    close(z1[1, 2], want[2])
+    if case == "two_chunks_in_a_row":     # = the attention form over both
+        y2, s1, z1 = kernel(two, s1, z1, c_len, False)
+        whole = ret.retention_attention(q[None], k[None], v[None], logg[None])
+        close(jnp.concatenate([y, y2]), whole[0])
+    untouched = np.ones((2, 3), bool)
+    untouched[1, 2] = False
+    for new, old in ((s1, s0), (z1, z0)):
+        np.testing.assert_array_equal(np.asarray(new)[untouched],
+                                      np.asarray(old)[untouched])
+
+
+@pytest.mark.parametrize("where,picks", [
+    ("the_cell", True), ("head_of_64", False), ("odd_length", False),
+    ("on_the_cpu", False)])
+def test_the_shapes_pick_the_chunk_kernel(monkeypatch, where, picks):
+    """An admission chunk of a retention layer lowers to the Mosaic
+    call ``ret_chunk_fwd`` at the cell's shapes (heads of 128, 5 query
+    heads a K/V head, one lane, an engine's bucket) on the TPU, and to
+    ``retention_chunk``'s plain operations at another head size, a
+    length no bucket has, or off the TPU: the shapes decide, no knob."""
+    if where != "on_the_cpu":
+        monkeypatch.setattr(ret, "_on_tpu", lambda: True)
+    head = 64 if where == "head_of_64" else 128
+    t_len = 72 if where == "odd_length" else 64
+    cfg = tfm.TransformerConfig(**{
+        **TC, "d_model": 128, "n_heads": 10, "n_kv_heads": 2, "d_head": head,
+        "n_layers": 1, "max_len": 128, "dtype": "bfloat16",
+        "layer_types": ["retention"], "ffn_types": ["dense"]})
+    assert ret.use_ret_chunk_kernel(head, 5, t_len, 1, jnp.float32) == picks
+    assert not ret.use_ret_chunk_kernel(128, 5, 64, 2, jnp.float32)
+    assert not ret.use_ret_chunk_kernel(128, 5, 64, 1, jnp.float32,
+                                        sharded=True)
+    sd = jax.ShapeDtypeStruct
+    shapes = lambda tree: jax.tree.map(lambda a: sd(a.shape, a.dtype), tree)
+    text = jax.jit(
+        lambda p, c, rows, lane, off, n: gen._decode_chunk(
+            p, c, rows, off[None], cfg, uniform_pos=True, lane=lane,
+            n_real=n)).trace(
+        shapes(jax.eval_shape(lambda: toy_params(cfg))),
+        shapes(jax.eval_shape(lambda: gen.init_cache(cfg, 3))),
+        sd((1, t_len), jnp.int32), sd((), jnp.int32), sd((), jnp.int32),
+        sd((), jnp.int32)).lower(lowering_platforms=("tpu",)).as_text()
+    assert ("ret_chunk_fwd" in text) == picks
+    assert ("tpu_custom_call" in text) == picks
+
+
 # ------------------------------------------------ (b) the full forward
 
 

@@ -5,6 +5,7 @@ kernels' names, the sublayer scopes in the operation metadata of the
 lowered programs, and the program names the benchmark's readers match.
 """
 
+import dataclasses
 import json
 import os
 import re
@@ -409,6 +410,43 @@ def test_the_state_kernel_is_named_ret_state_step():
     assert 'kernel_name = "ret_state_step"' in text
     assert re.search(r'ret_state_step/pallas_call"', text)
     assert re.search(_pattern("step_ret_state_roofline"), "ret_state_step.3")
+
+
+def test_the_chunk_kernel_is_named_ret_chunk_fwd(monkeypatch):
+    """A retention layer's admission chunk at heads of 128 is ONE
+    Pallas call, ``ret_chunk_fwd`` (row ``mosaic:ret_chunk_fwd`` of
+    ``breakdown.device_ops``, 8 an admission of the cell), under
+    ``attn`` / ``ret_chunk`` of an admission program, and the decode
+    kernel's roofline does not take it for its own."""
+    from distkeras_tpu.ops import retention as ret
+
+    from distkeras_tpu.models import generate as gen
+    from distkeras_tpu.serving.engine import _make_lane_admit
+
+    monkeypatch.setattr(ret, "_on_tpu", lambda: True)
+    cfg = dataclasses.replace(RETENTION, d_model=128, n_heads=4,
+                              n_kv_heads=2, d_head=128, n_layers=1,
+                              max_len=128, layer_types=("retention",),
+                              ffn_types=("dense",))
+    sd = jax.ShapeDtypeStruct
+    shapes = lambda make: jax.tree.map(lambda a: sd(a.shape, a.dtype),
+                                       jax.eval_shape(make))
+    # The engine's in-place admission program, traced as the TPU would.
+    admit = _make_lane_admit(None, cfg, take_params=True, in_place=True)
+    text = jax.jit(admit).trace(
+        shapes(lambda: toy_params(cfg)),
+        shapes(lambda: gen.init_cache(cfg, 2)), sd((1, 64), jnp.int32),
+        *(sd((), jnp.int32),) * 3).lower(
+            lowering_platforms=("tpu",)).as_text(debug_info=True)
+    (module,) = re.findall(r"module @(\S+)", text)
+    assert re.search(_pattern("prefill_ms_per_ktok"), module), module
+    assert text.count('kernel_name = "ret_chunk_fwd"') == 1
+    # (the jitted launcher is a function of its own in the module: the
+    # scope stands on its call, the kernel's name on the call inside)
+    assert re.search(r'"attn/ret_chunk/jit\(ret_chunk_fwd\)"', text)
+    assert re.search(r'"ret_chunk_fwd/pallas_call"', text)
+    for name in ("ret_chunk_fwd", "ret_chunk_fwd.7"):
+        assert not re.search(_pattern("step_ret_state_roofline"), name)
 
 
 TRAIN_CFG = tfm.TransformerConfig(
