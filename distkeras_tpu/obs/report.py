@@ -157,7 +157,9 @@ def serving_rounds(records: list[dict],
     how many decoding rounds dispatched a step, the share of them
     ``overlapped`` (dispatched while the round before was unread), and
     the median ``wait_ms`` of the reads — near the device's round time
-    the device sets the pace, near zero the host does.
+    the device sets the pace, near zero the host does — beside the
+    median ``host_ms`` of the decoding rounds, the host's own work in
+    a call.
     ``attended``: the cache positions the admission
     programs' attention read (the field of that name on the admission
     spans) — how many programs, their mean, and with ``max_len`` (the
@@ -194,6 +196,13 @@ def serving_rounds(records: list[dict],
                           for r in dispatched) / len(dispatched)
                       if dispatched else 0.0),
             "wait_p50_ms": statistics.median(waits)}
+        # The other side of the wait (``serving.round``'s ``host_ms``:
+        # the call's time less its reads' waits); absent from a trace
+        # older than the field.
+        hosts = [r["fields"]["host_ms"] for r in live
+                 if "host_ms" in r["fields"]]
+        if hosts:
+            out["overlap"]["host_p50_ms"] = statistics.median(hosts)
     attended = [sp["fields"]["attended"] for sp in spans
                 if sp["name"] in _ADMIT and "attended" in sp["fields"]]
     if attended:
@@ -586,7 +595,10 @@ def render_report(rep: dict, max_events: int = 60) -> str:
                 f"  overlap: {ov['share']:.1%} of {ov['dispatched']} "
                 f"decode dispatches went out with the round before "
                 f"unread; wait for a round's tokens p50="
-                f"{ov['wait_p50_ms']:.3g}ms")
+                f"{ov['wait_p50_ms']:.3g}ms" + (
+                    f", the host's own work in a round p50="
+                    f"{ov['host_p50_ms']:.3g}ms"
+                    if "host_p50_ms" in ov else ""))
         gap = rounds.get("gap")
         if gap:
             out.append(

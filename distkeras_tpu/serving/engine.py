@@ -67,6 +67,16 @@ class _Lane:
     launched: int = 0
 
 
+def _program_name(jitted) -> str:
+    """What the device trace calls a launch of ``jitted``: an "XLA
+    Modules" event is named ``jit_<function>(<id>)`` after the function
+    ``jax.jit`` wrapped.  The dispatching spans' ``program`` field, so
+    that a reader finds a round's device work by what the engine says
+    it launched and not by a name typed in beside it (pinned against
+    the lowered module's name by tests/test_round_tracing.py)."""
+    return "jit_" + jitted.__name__
+
+
 def _make_lane_admit(model_params, model_cfg, prefix_lane=None,
                      pooled: bool = False, seed: bool = True,
                      constrain=None, take_params: bool = False,
@@ -226,10 +236,20 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
     _admit_programs = 0
 
     # The decode round dispatched and not yet read: ``(tokens on the
-    # device, n, [(lane, _Lane), ...] decoding at the dispatch)``, or
-    # None.  ``ContinuousBatcher.step()`` reads it in the call AFTER
-    # the one that dispatched it.
+    # device, [(lane, _Lane), ...] decoding at the dispatch, its
+    # ``seq``)``, or None.  ``ContinuousBatcher.step()`` reads it in
+    # the call AFTER the one that dispatched it.
     _inflight = None
+
+    # Decode rounds dispatched so far: ``serving.step``'s ``seq``, and
+    # ``serving.collect``'s for the round it reads.  Counted with or
+    # without a session.
+    _dispatch_seq = 0
+
+    # ``wait_ms`` of the ``serving.collect`` spans since the round's
+    # span opened (``serving.round``'s ``host_ms`` is its time less
+    # this); kept only while a trace is written.
+    _waited_ms = 0.0
 
     def _attended(self, cache, start: int, width: int) -> int:
         """Cache positions the attention of an admission program reads
@@ -596,18 +616,39 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
         self._moe_round = (int(mine.size), int(per_expert.sum()),
                            int(per_expert.max(initial=0)))
 
+    def _number_dispatch(self, sp, program) -> None:
+        """Count the decode round just launched and, under a trace, say
+        on its ``serving.step`` span ``sp`` which launch it was
+        (``seq``) and of which program (``program``).  Called once the
+        launch went out: a dispatch that raised leaves no hole in the
+        count."""
+        self._dispatch_seq += 1
+        if sp is not None:
+            sp.fields.update(seq=self._dispatch_seq,
+                             program=_program_name(program))
+
+    def _timed_read(self, seq: int, read):
+        """``read()`` — the device-to-host read of dispatch ``seq`` —
+        inside a ``serving.collect`` span that names the dispatch and
+        says how long the read blocked (``wait_ms``)."""
+        with obs.span("serving.collect", seq=seq) as sp:
+            t0 = time.perf_counter()
+            out = read()
+            if sp is not None:
+                wait = (time.perf_counter() - t0) * 1e3
+                sp.fields["wait_ms"] = wait
+                self._waited_ms += wait
+        return out
+
     def _collect(self, pending) -> dict:
         """Read a dispatched round and emit it: ``pending`` is the
         record ``step()`` kept at the dispatch.  The read is one
         ``serving.collect`` span whose ``wait_ms`` is the time blocked
         in it — near the device's round time where the device sets the
-        pace, near zero where the host does."""
-        dev, n, lanes = pending
-        with obs.span("serving.collect", n=n) as sp:
-            t0 = time.perf_counter()
-            toks = self._read_tokens(dev)
-            if sp is not None:
-                sp.fields["wait_ms"] = (time.perf_counter() - t0) * 1e3
+        pace, near zero where the host does — and whose ``seq`` names
+        the dispatch it reads (``serving.step``'s of the same number)."""
+        dev, lanes, seq = pending
+        toks = self._timed_read(seq, lambda: self._read_tokens(dev))
         if self._routes is not None and obs.active() is not None:
             self._count_routes(lanes)
         return self._emit(lambda lane: toks[lane].tolist(), lanes)
@@ -639,7 +680,10 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
         dispatched for it, up to its budget (the round in flight
         counts: the count is the dispatch's, not the transcript's), an
         admitting lane what lies before its next chunk.  ``chunks`` is the admission
-        programs dispatched since the previous decode dispatch."""
+        programs dispatched since the previous decode dispatch.
+        ``host_ms`` is the call's time so far (this is its last act)
+        less what its ``serving.collect`` spans waited for the device:
+        the host's own work in the round."""
         busy = admitting = kv_live = kv_live_window = 0
         window = self.cfg.sliding_window or 0
         for st in self._lane_state:
@@ -660,8 +704,7 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
             rnd.fields.update(
                 lanes_busy=busy, lanes_admitting=admitting,
                 kv_live=kv_live, chunks=chunks,
-                tokens=sum(len(v) for v in out.values()),
-                passes=self.cfg.n_passes)
+                tokens=sum(len(v) for v in out.values()))
             if self.cfg.kv_ring_planes:
                 # Ring planes: what of a lane's positions a window
                 # layer still holds (sum of min(position, window)).
@@ -678,6 +721,8 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
                 rnd.fields["idle"] = True
             if overlapped:
                 rnd.fields["overlapped"] = True
+            rnd.fields["host_ms"] = (
+                (time.perf_counter() - rnd.t0) * 1e3 - self._waited_ms)
 
     # --------------------------------------------- chunked admission
 
@@ -699,10 +744,14 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
         new, st.filled = max(end - st.filled, 0), max(end, st.filled)
         with obs.span("serving.admit_chunk", bucket=rows.shape[1],
                       positions=new, remaining=len(st.chunks),
-                      request_id=st.request_id,
-                      attended=self._attended(self.cache, start,
-                                              rows.shape[1])):
-            self._exec_chunk(lane, start, rows, **self._real(end - start))
+                      request_id=st.request_id) as sp:
+            fn = self._exec_chunk(lane, start, rows,
+                                  **self._real(end - start))
+            if sp is not None:
+                sp.fields.update(
+                    program=_program_name(fn),
+                    attended=self._attended(self.cache, start,
+                                            rows.shape[1]))
         self._admit_programs += 1
         if not st.chunks:
             self._admitting.popleft()
@@ -727,4 +776,4 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
 
 
 __all__ = ["_Lane", "_LaneEngine", "_make_lane_admit",
-           "_make_lane_reseed"]
+           "_make_lane_reseed", "_program_name"]

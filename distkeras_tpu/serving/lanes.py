@@ -45,6 +45,7 @@ from distkeras_tpu.models.transformer import (TransformerConfig,
                                                reject_extended)
 from distkeras_tpu.serving.elastic import _ElasticLanesMixin
 from distkeras_tpu.serving.engine import (_Lane, _LaneEngine,
+                                          _program_name,
                                           _make_lane_admit,
                                           _make_lane_reseed)
 
@@ -1009,9 +1010,11 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
         whatever _admission_plan staged (no-op for monolithic lanes;
         the paged engine frees the staged blocks)."""
 
-    def _exec_admit(self, lane, start, rows, slot, n_real=None) -> None:
+    def _exec_admit(self, lane, start, rows, slot, n_real=None):
         """Execute the FIRST admission chunk (the one that seeds the
-        lane) — ``slot`` is the pinned prefix-pool slot or None."""
+        lane) — ``slot`` is the pinned prefix-pool slot or None.
+        Returns the jitted program it launched (``serving.admit``'s
+        ``program`` is its name)."""
         real = () if n_real is None else (jnp.int32(n_real),)
         if slot is not None:
             self.cache = self._admit(
@@ -1030,6 +1033,7 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
                                      jnp.asarray(rows),
                                      jnp.int32(lane), jnp.int32(start),
                                      *real)
+        return self._admit
 
     def _exec_reseed(self, lane, slot) -> None:
         """No admission chunk ran (1-token prompt) but the lane still
@@ -1067,6 +1071,7 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
                                       jnp.asarray(rows),
                                       jnp.int32(lane), jnp.int32(start),
                                       *real)
+        return self._admit_cont
 
     def _finish_admission(self, lane, st):
         """Last chunk landed: un-park the lane — set its decode
@@ -1226,11 +1231,14 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
                 with obs.span("serving.admit", bucket=width0,
                               positions=filled - start0,
                               chunks=len(plan), lane=lane,
-                              request_id=rid,
-                              attended=self._attended(
-                                  self.cache, start0, width0)):
-                    self._exec_admit(lane, start0, rows, slot,
-                                     **self._real(filled - start0))
+                              request_id=rid) as sp:
+                    fn = self._exec_admit(lane, start0, rows, slot,
+                                          **self._real(filled - start0))
+                    if sp is not None:
+                        sp.fields.update(
+                            program=_program_name(fn),
+                            attended=self._attended(self.cache, start0,
+                                                    width0))
                 self._admit_programs += 1
                 if len(plan) > 1:
                     chunks = [(s, self._chunk_rows(prompt, off, s, w))
@@ -1367,7 +1375,10 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
         (the dispatch), ``serving.collect`` (the read of the round
         before), ``serving.emit_loop``, ``serving.reap`` — closed with
         the round's counts (:meth:`_close_round`;
-        docs/observability.md).
+        docs/observability.md).  A dispatching span names the
+        ``program`` it launched; ``serving.step`` numbers its launch
+        (``seq``, the engine's count of decode dispatches) and
+        ``serving.collect`` says which launch it reads.
 
         Runs under the engine lock end to end: a concurrent
         ``enqueue`` can trigger a tier resize (scale-up), and the
@@ -1383,6 +1394,8 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
                 " — declare it at construction (a lazy compile here "
                 "would break the no-recompile contract across tiers)")
         with self._admission_lock, obs.span("serving.round") as rnd:
+            if rnd is not None:
+                self._waited_ms = 0.0
             self.pump()
             # Tier hysteresis BEFORE the idle early-out: an idle
             # elastic engine must still step its lane count back down.
@@ -1396,14 +1409,16 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
             if lanes:
                 chaos.probe("serving.step")
                 self._admit_programs = 0
-                with obs.span("serving.step", n=n,
-                              attended=self._step_attended(n)):
+                with obs.span("serving.step", n=n) as sp:
+                    if sp is not None:
+                        sp.fields["attended"] = self._step_attended(n)
                     toks = self._dispatch_step(n)
                     for a in jax.tree.leaves(toks):
                         a.copy_to_host_async()
+                    self._number_dispatch(sp, self._steps[n])
                 for _, s in lanes:
                     s.launched += n
-                launched = (toks, n, lanes)
+                launched = (toks, lanes, self._dispatch_seq)
                 if not self._overlap:
                     unread, launched = launched, None
             self._inflight = launched

@@ -755,11 +755,11 @@ class PagedBatcher(ContinuousBatcher):
         self._tables_np[lane, :] = TRASH_BLOCK
         self._push_tables()
 
-    def _exec_admit(self, lane, start, rows, slot) -> None:
+    def _exec_admit(self, lane, start, rows, slot):
         assert slot is None  # no prefix pool on paged engines
-        self._exec_chunk(lane, start, rows)
+        return self._exec_chunk(lane, start, rows)
 
-    def _exec_chunk(self, lane, start, rows) -> None:
+    def _exec_chunk(self, lane, start, rows):
         limit = self._lane_limit[lane]
         row = self._put_host(self._tables_np[lane].copy())
         w = rows.shape[1]
@@ -770,13 +770,16 @@ class PagedBatcher(ContinuousBatcher):
             # continuations and stem-shared tails keep the decode-built
             # path (they must attend PRIOR cache, which prefill
             # cannot).
-            self.cache = self._admit_prefill(
+            fn = self._admit_prefill
+            self.cache = fn(
                 self.cache, row, jnp.asarray(rows), jnp.int32(limit))
         else:
-            self.cache = self._admit(
+            fn = self._admit
+            self.cache = fn(
                 self.cache, row, jnp.asarray(rows), jnp.int32(start),
                 jnp.int32(limit))
         self._register_written(lane, min(start + w, limit))
+        return fn
 
     def _register_written(self, lane, end: int) -> None:
         pend = self._pending_hashes.get(lane)

@@ -23,7 +23,8 @@ from distkeras_tpu.models.transformer import (TransformerConfig,
                                                reject_extended)
 from distkeras_tpu.serving.engine import (_Lane, _LaneEngine,
                                           _make_lane_admit,
-                                          _make_lane_reseed)
+                                          _make_lane_reseed,
+                                          _program_name)
 
 
 class SpeculativeBatcher(_LaneEngine):
@@ -571,9 +572,14 @@ class SpeculativeBatcher(_LaneEngine):
                 rows[0, :warm] = prompt[:-1]
                 rows_j = jnp.asarray(rows)
                 with obs.span("serving.admit", bucket=width, lane=lane,
-                              request_id=rid,
-                              attended=self._attended(
-                                  self.tcache, off, width)):
+                              request_id=rid) as sp:
+                    if sp is not None:
+                        # Target and draft admission are one function
+                        # jitted twice: one name on the device.
+                        sp.fields.update(
+                            program=_program_name(self._admit_t),
+                            attended=self._attended(self.tcache, off,
+                                                    width))
                     if slot is not None:
                         t_slab, d_slab = self._prefix_pool.slab
                         self.tcache = self._admit_t(
@@ -721,6 +727,15 @@ class SpeculativeBatcher(_LaneEngine):
         with self._admission_lock:
             return self._step_locked()
 
+    def _read_round(self, *arrays) -> tuple:
+        """The round's results on the host, read in the call that
+        launched it (the next dispatch needs them): a
+        ``serving.collect`` span INSIDE ``serving.step``, with the
+        ``seq`` just given."""
+        return self._timed_read(
+            self._dispatch_seq,
+            lambda: tuple(np.asarray(a) for a in arrays))
+
     def _step_locked(self):
         self.pump()
         if all(s is None or s.done for s in self._lane_state):
@@ -731,18 +746,19 @@ class SpeculativeBatcher(_LaneEngine):
         if not self._degraded:
             try:
                 chaos.probe("serving.draft")
-                with obs.span("serving.step", speculative=True):
+                with obs.span("serving.step") as sp:
                     (tcache, dcache, prev, cur, pos, iters, win,
                      adv) = self._step(
                         self.tcache, self.dcache, self.prev, self.cur,
                         self.pos, self.keys, self.iters)
+                    self._number_dispatch(sp, self._step)
                     # Force async dispatch errors to surface INSIDE the
                     # try, before the engine state is rebound: a fault
                     # arriving here finds self.* still naming the donated
                     # (now consumed) inputs, and _note_draft_fault reports
                     # the unrecoverable case with a clear error instead of
                     # leaving poisoned state behind.
-                    win, adv = np.asarray(win), np.asarray(adv)
+                    win, adv = self._read_round(win, adv)
             except Exception as e:  # noqa: BLE001 — degrade, not die
                 self._note_draft_fault(e)
             else:
@@ -764,10 +780,11 @@ class SpeculativeBatcher(_LaneEngine):
         # Degraded: plain target decode — requests still complete.
         if self._fallback is None:
             self._fallback = self._make_fallback()
-        with obs.span("serving.step", speculative=False):
+        with obs.span("serving.step") as sp:
             self.tcache, self.cur, self.pos, nxt, adv = self._fallback(
                 self.tcache, self.cur, self.pos, self.keys)
-            nxt, adv = np.asarray(nxt), np.asarray(adv)
+            self._number_dispatch(sp, self._fallback)
+            nxt, adv = self._read_round(nxt, adv)
         out = self._emit(
             lambda lane: [int(nxt[lane])] if adv[lane] else [])
         self._reap()

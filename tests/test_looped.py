@@ -180,8 +180,9 @@ def test_engine_tokens_are_the_references_best(served, ref, params):
 
 
 def test_engine_says_its_kv_layout_and_passes(served):
-    """``serving.kv_layout`` turns ``kv_live`` slots into bytes; every
-    ``serving.round`` says how many passes its step ran."""
+    """``serving.kv_layout`` turns ``kv_live`` slots into bytes and
+    says how many passes a step runs (once: an engine's rounds all run
+    the same, so ``serving.round`` does not repeat it)."""
     (ev,) = [r for r in served[1] if r.get("name") == "serving.kv_layout"]
     f = ev["fields"]
     per_slot = 2 * 6 * 4 * 16 * 4      # k and v, 6 planes, 4 heads of 16, f32
@@ -190,7 +191,7 @@ def test_engine_says_its_kv_layout_and_passes(served):
         "passes": 3, "layers": 2, "planes": 6, "bytes_per_slot": per_slot,
         "slots": 2 * 64, "slab_bytes": per_slot * 2 * 64}
     rounds = [r for r in served[1] if r.get("name") == "serving.round"]
-    assert rounds and all(r["fields"]["passes"] == 3 for r in rounds)
+    assert rounds and all("passes" not in r["fields"] for r in rounds)
 
 
 def test_float32_weights_under_bfloat16_activations_decode():

@@ -169,13 +169,18 @@ def test_attended_is_the_chunks_end_on_the_bounded_path(tmp_path,
     assert [s["fields"]["bucket"] for s in admits] == [8, 8, 8]
     assert all(s["fields"]["attended"] <= cfg.max_len for s in admits)
     assert set(admits[0]["fields"]) == {"bucket", "positions", "chunks",
-                                        "lane", "request_id", "attended"}
+                                        "lane", "request_id", "attended",
+                                        "program"}
     assert set(admits[1]["fields"]) == {"bucket", "positions", "remaining",
-                                        "request_id", "attended"}
+                                        "request_id", "attended", "program"}
 
 
 def _steps(spans):
     return [s for s in spans if s["name"] == "serving.step"]
+
+
+def _n_attended(step):
+    return {k: step["fields"][k] for k in ("n", "attended")}
 
 
 def test_step_attended_is_every_slot_on_the_dense_path(rounds):
@@ -185,8 +190,10 @@ def test_step_attended_is_every_slot_on_the_dense_path(rounds):
     _, spans = rounds
     steps = _steps(spans)
     assert len(steps) == 4                       # the idle round has none
-    assert [s["fields"] for s in steps] == [
+    assert [_n_attended(s) for s in steps] == [
         {"n": 1, "attended": 2 * CFG.max_len}] * 4
+    assert all(set(s["fields"]) == {"n", "attended", "seq", "program"}
+               for s in steps)
 
 
 def test_step_attended_follows_the_lanes_on_the_bounded_path(tmp_path,
@@ -219,11 +226,200 @@ def test_step_attended_follows_the_lanes_on_the_bounded_path(tmp_path,
     assert gen.decode_read_unit(cfg, 1, {"k": jnp.zeros(())}) == 256
     out, steps = run(str(tmp_path / "bounded.jsonl"))
     # B alone; B and the admitting A; B and A over a window of two.
-    assert [s["fields"] for s in steps] == [
+    assert [_n_attended(s) for s in steps] == [
         {"n": 1, "attended": 256 + 2 * 1024},
         {"n": 1, "attended": 256 + 2 * 1024},
         {"n": 2, "attended": 2 * (256 + 256 + 1024)}]
     assert out == dense_out
+
+
+# --------------------------- which program a span launched, which launch it read
+
+
+def _lowered_names(eng):
+    """``{"decode_step" | "admit": the lowered module's name}`` of an
+    engine's two programs: what an "XLA Modules" event of the device
+    trace is called (before its ``(id)``)."""
+    out = {}
+    for spec in eng.traced_for_analysis():
+        key = "decode_step" if spec.name.endswith("decode_step") else "admit"
+        (out[key],) = re.findall(r"module @(\S+)",
+                                 spec.fn.lower(*spec.args).as_text())
+    return out
+
+
+@pytest.fixture(scope="module", params=[False, True],
+                ids=["closure", "hot_swap"])
+def declared(request, tmp_path_factory):
+    """A chunked admission beside a decoding lane, by an engine that
+    closes over its weights and by one that takes them as an argument
+    (other functions, other names): the spans, and the two programs'
+    lowered names."""
+    path = str(tmp_path_factory.mktemp("declared") / "t.jsonl")
+    with obs.session(trace_path=path):
+        eng = dk.ContinuousBatcher(toy_params(CFG), CFG, lanes=2,
+                                   hot_swap=request.param, max_queue=4,
+                                   prefill_chunk=8, prompt_buckets=(8,))
+        eng.enqueue(np.arange(5), 4)
+        eng.step()
+        eng.enqueue(np.arange(21), 2)
+        while eng.running():
+            eng.step()
+    spans = [r for r in read_trace(path) if r["kind"] == "span"]
+    return spans, _lowered_names(eng)
+
+
+@pytest.mark.parametrize("span,program", [
+    ("serving.step", "decode_step"), ("serving.admit", "admit"),
+    ("serving.admit_chunk", "admit")])
+def test_program_is_the_lowered_modules_name(declared, span, program):
+    """A dispatching span's ``program`` is what the device trace will
+    call the launch — taken from the jitted callable, so a renamed or
+    fused program changes it with no reader edited."""
+    spans, lowered = declared
+    said = {s["fields"]["program"] for s in spans if s["name"] == span}
+    assert said == {lowered[program]}
+    assert lowered[program].startswith("jit_")
+
+
+def _scripted(kind, path):
+    """Two requests through an engine of ``kind`` under a session until
+    nothing is left on the device; returns the spans."""
+    from helpers import serve_cfg, spec_draft_cfg
+
+    cfg = serve_cfg()
+    params = toy_params(cfg)
+    prompts = [np.arange(5, dtype=np.int32), np.arange(3, 10, dtype=np.int32)]
+    with obs.session(trace_path=path):
+        if kind == "speculative":
+            draft = spec_draft_cfg()
+            eng = dk.SpeculativeBatcher(params, toy_params(draft, 1), cfg,
+                                        draft, lanes=2, n_draft=2,
+                                        max_queue=2)
+        elif kind == "paged":
+            eng = dk.PagedBatcher(params, cfg, lanes=2, block=8, max_queue=2)
+        elif kind == "resize":
+            eng = dk.ContinuousBatcher(
+                params, cfg, lane_tiers=(1, 2), max_queue=1,
+                scale_up_after=1, scale_down_after=2, prompt_buckets=(8,))
+        else:
+            eng = dk.ContinuousBatcher(params, cfg, lanes=2, max_queue=2)
+        eng.enqueue(prompts[0], 6)
+        eng.step()
+        eng.step()
+        if kind in ("resize", "shutdown"):
+            assert eng._inflight is not None
+        eng.enqueue(prompts[1], 4)      # resize: scales up, flushing first
+        if kind == "shutdown":
+            eng.step()
+            eng.shutdown()              # flushes the round in flight
+        else:
+            for _ in range(40):
+                if not (eng.running() or eng.queued
+                        or eng._inflight is not None):
+                    break
+                eng.step()
+        assert eng._inflight is None
+    return [r for r in read_trace(path) if r["kind"] == "span"]
+
+
+@pytest.mark.parametrize("kind,lag", [
+    ("inflight", 1), ("shutdown", None), ("resize", None), ("paged", 0),
+    ("speculative", 0)])
+def test_collect_names_the_dispatch_it_reads(kind, lag, tmp_path):
+    """``serving.step``'s ``seq`` counts the engine's decode dispatches
+    from 1 with no hole; every ``serving.collect`` names a ``seq`` that
+    was dispatched before it began and that no earlier collect read,
+    and once the engine has drained every dispatch has been read — with
+    a round in flight (the read lags the dispatch by one call), after a
+    ``_flush_round`` (shutdown; a resize), and where each round is read
+    by the call that dispatched it (the paged engine; the speculative
+    one, whose read is inside its ``serving.step``)."""
+    spans = sorted(_scripted(kind, str(tmp_path / "t.jsonl")),
+                   key=lambda s: s["t0"])
+    steps = _steps(spans)
+    assert [s["fields"]["seq"] for s in steps] == list(
+        range(1, len(steps) + 1)) and len(steps) >= 4
+    read = []
+    for c in (s for s in spans if s["name"] == "serving.collect"):
+        assert set(c["fields"]) == {"seq", "wait_ms"}
+        dispatched = {s["fields"]["seq"] for s in steps
+                      if s["t0"] <= c["t0"]}
+        assert c["fields"]["seq"] in dispatched
+        assert c["fields"]["seq"] not in read
+        read.append(c["fields"]["seq"])
+    assert read == sorted(read) and len(read) == len(steps)
+    if lag is not None:
+        # Inside one call: the read is of the dispatch ``lag`` before
+        # (the collect's parent is the step's round; the step itself in
+        # the speculative engine).
+        key = "id" if kind == "speculative" else "parent"
+        owner = {s[key]: s["fields"]["seq"] for s in steps}
+        pairs = [(owner[c["parent"]], c["fields"]["seq"]) for c in spans
+                 if c["name"] == "serving.collect" and c["parent"] in owner]
+        assert pairs and all(k - r == lag for k, r in pairs)
+
+
+def test_host_ms_is_the_rounds_time_less_its_waits(rounds):
+    """``serving.round``'s ``host_ms``: written as the round closes, so
+    never more than its duration, and short of it by what the round's
+    ``serving.collect`` children waited for the device."""
+    rnds, spans = rounds
+    for rnd in rnds:
+        waited = sum(s["fields"]["wait_ms"] for s in spans
+                     if s["name"] == "serving.collect"
+                     and s["parent"] == rnd["id"])
+        own = rnd["dur"] * 1e3 - waited
+        assert 0 <= rnd["fields"]["host_ms"] <= own + 1e-6
+        # (what the span still does after the field is written: its own
+        # exit; a loaded box may be slow there, not by this much)
+        assert rnd["fields"]["host_ms"] == pytest.approx(own, abs=50)
+    assert any(s["name"] == "serving.collect" for s in spans)
+
+
+@pytest.mark.parametrize("kind", ["lanes", "paged"])
+def test_no_session_walks_no_lane_table_for_a_span(kind, tmp_path,
+                                                   monkeypatch):
+    """With no session ``step()`` evaluates no span argument that walks
+    the lane table or asks the model's shape rules: ``_step_attended``
+    and ``_attended`` are not called (they are once a span is live) —
+    and the transcripts are the traced run's."""
+    from distkeras_tpu.serving.engine import _LaneEngine
+    from helpers import serve_cfg
+
+    calls = {"_step_attended": 0, "_attended": 0}
+    for name in calls:
+        real = getattr(_LaneEngine, name)
+
+        def counted(self, *a, _real=real, _name=name):
+            calls[_name] += 1
+            return _real(self, *a)
+
+        monkeypatch.setattr(_LaneEngine, name, counted)
+    cfg = serve_cfg()
+    params = toy_params(cfg)
+
+    def serve():
+        make = (lambda: dk.PagedBatcher(params, cfg, lanes=2, block=8,
+                                        max_queue=2, prefill_chunk=8)
+                ) if kind == "paged" else (
+            lambda: dk.ContinuousBatcher(params, cfg, lanes=2, max_queue=2,
+                                         prefill_chunk=8,
+                                         prompt_buckets=(8,)))
+        eng = make()
+        rids = [eng.enqueue(np.arange(5, dtype=np.int32), 5)]
+        eng.step()
+        rids.append(eng.enqueue(np.arange(20, dtype=np.int32), 3))
+        while eng.running() or eng._inflight is not None:
+            eng.step()
+        return [eng.take(r).tokens.tolist() for r in rids]
+
+    plain = serve()
+    assert calls == {"_step_attended": 0, "_attended": 0}
+    with obs.session(trace_path=str(tmp_path / "t.jsonl")):
+        traced = serve()
+    assert calls["_step_attended"] > 0 and calls["_attended"] > 0
+    assert traced == plain
 
 
 # ------------------------------------------ names on the device timeline
@@ -647,6 +843,14 @@ def test_report_prints_the_overlapped_share_and_the_median_wait():
                   "wait_p50_ms": 5.0}
     assert ("overlap: 66.7% of 3 decode dispatches went out with the "
             "round before unread; wait for a round's tokens p50=5ms"
+            ) in render_report(build_report(recs))
+    # ``host_ms`` (the round's time less its waits) is printed beside
+    # the wait where the rounds carry it.
+    for r, ms in zip([r for r in recs if r["name"] == "serving.round"],
+                     (2.0, 3.0, 1.0, 1.75)):
+        r["fields"]["host_ms"] = ms
+    assert serving_rounds(recs)["overlap"]["host_p50_ms"] == 1.875
+    assert ("p50=5ms, the host's own work in a round p50=1.88ms"
             ) in render_report(build_report(recs))
     # A trace from before ``serving.collect`` reports none.
     old = [r for r in recs if r["name"] != "serving.collect"]

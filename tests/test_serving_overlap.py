@@ -383,5 +383,5 @@ def test_rounds_say_overlapped_and_collect_says_its_wait(params, rng,
     assert [r["fields"]["tokens"] for r in rounds] == [0, 1, 1, 1, 0]
     for s in spans:
         if s["name"] == "serving.collect":
-            assert set(s["fields"]) == {"n", "wait_ms"}
+            assert set(s["fields"]) == {"seq", "wait_ms"}
             assert 0 <= s["fields"]["wait_ms"] <= s["dur"] * 1e3 + 1e-6
