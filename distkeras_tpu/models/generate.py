@@ -18,6 +18,7 @@ axis like every other batch op.
 
 from __future__ import annotations
 
+import types
 import warnings
 
 import jax
@@ -628,7 +629,7 @@ def base_body_only(cfg: TransformerConfig, params, cache,
 
 def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
                     uniform_pos: bool = False, lane=None, n_real=None,
-                    with_routes: bool = False, live=None):
+                    with_routes: bool = False, live=None, chunk=None):
     """:func:`_decode_chunk`'s body for every call that
     :func:`base_body_only` does not hold back: ``tokens [B, T]`` at
     positions ``pos0[b] + (0..T-1)`` -> ``(logits [B, T, V] f32, cache)``
@@ -671,6 +672,21 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
     only the blocks before ``pos0[b]``, and its result is merged with
     the chunk's own term (:func:`_attend_before_and_chunk`) — the dense
     body's sum, without its read of the dead slots.
+
+    ``chunk = (rows [1, C], lane, off)`` (traced; a decode step — one
+    token a row, no ``lane`` — of an untyped one-pass stack only): the
+    step ALSO admits ``rows`` into lane ``lane`` at ``off``, in the
+    same layer body.  The lanes' tokens and the chunk's are embedded
+    and laid side by side as ONE row of ``B + C`` positions, so every
+    norm, projection and feed-forward product is one product a matrix
+    (they are row-wise) and a layer's weights stream once for both.
+    Attention splits the rows again — the lanes' as the step above
+    attends, the chunk's as an admission with ``lane=`` does — and
+    both sets of K/V are written at the end, the chunk's first (where
+    the admission before the step would have put it).  The slab is
+    read-only inside the loop, so no lane sees the chunk in this call:
+    ``lane`` must not decode here (the engine parks it).  The logits
+    are the lanes' ``[B, 1, V]``; the chunk's are never computed.
 
     **A typed stack** (``cfg.typed``: a layer is an (attention kind,
     feed-forward kind) pair) runs the same layer body, one scan a run
@@ -720,6 +736,19 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
         raise ValueError(
             "a typed stack takes multi-token chunks at one position for "
             "every row only (per-row chunks: speculative verification)")
+    if chunk is not None:
+        if (typed or cfg.n_passes != 1 or t_len != 1 or uniform_pos
+                or lane is not None):
+            raise ValueError(
+                "chunk= rides a decode step (one token a row, no lane=) "
+                "of an untyped one-pass stack")
+        c_rows, c_lane, c_off = chunk
+        c_off = jnp.reshape(c_off, (1,)).astype(jnp.int32)
+        c_len = c_rows.shape[1]
+        # The stream is one row: the lanes' tokens, then the chunk's.
+        # (Its B + C rows are not padded to a tile: 32 + 512 run in
+        # 12.62 ms, padded to 640 in 13.60; chip, PR 36.)
+        tokens = jnp.concatenate([tokens.reshape(1, b), c_rows], axis=1)
     # [R*L, B|lanes, S, kv, hd]; a typed stack's: [full layers, B|lanes,
     # kv, S, hd] and the rings [window layers, B|lanes, kv, ring + park, hd].
     ck_all, cv_all = cache["k"], cache["v"]
@@ -727,6 +756,10 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
     with jax.named_scope("embed"):
         x = params["tok_emb"][tokens].astype(dtype)           # [B, T, D]
         pos_ids = pos0[:, None] + jnp.arange(t_len)[None, :]  # [B, T]
+        if chunk is not None:                                 # [1, B + C]
+            pos_ids = jnp.concatenate(
+                [pos0[None, :], c_off[:, None] + jnp.arange(c_len)[None, :]],
+                axis=1)
         rope_ang = None
         if cfg.rope:
             rope_ang = rope_angles(pos_ids, cfg.head_dim,
@@ -734,15 +767,28 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
         else:
             x = x + params["pos_emb"][pos_ids].astype(dtype)
     sharded = is_partitioned(x)
-    bounded = chunk_attends_prefix(cfg, t_len, cache,
-                                   uniform_pos=uniform_pos, sharded=sharded)
-    per_lane = lane is None and decode_attends_prefix(
-        cfg, t_len, cache, uniform_pos=uniform_pos, sharded=sharded)
-    # The dense body's two masks: cache slots before the chunk, and
-    # the chunk's own causal triangle ([B, T, kv, g, S | T]).
-    before = (jnp.arange(s_len)[None, :] < pos0[:, None]
-              )[:, None, None, None, :]
-    causal = jnp.tril(jnp.ones((t_len, t_len), bool))[None, :, None, None, :]
+
+    def rows_at(pos0, t_len, lane, uniform_pos):
+        """How rows of ``t_len`` tokens from ``pos0[b]`` — every lane's,
+        or lane ``lane``'s alone — attend: the kernel their shapes
+        allow, and the dense body's two masks: cache slots before the
+        chunk, and the chunk's own causal triangle ([B, T, kv, g, S | T])."""
+        return types.SimpleNamespace(
+            pos0=pos0, lane=lane,
+            bounded=chunk_attends_prefix(
+                cfg, t_len, cache, uniform_pos=uniform_pos, sharded=sharded),
+            per_lane=lane is None and decode_attends_prefix(
+                cfg, t_len, cache, uniform_pos=uniform_pos, sharded=sharded),
+            before=(jnp.arange(s_len)[None, :] < pos0[:, None]
+                    )[:, None, None, None, :],
+            causal=jnp.tril(jnp.ones((t_len, t_len), bool)
+                            )[None, :, None, None, :])
+
+    own = rows_at(pos0, t_len, lane, uniform_pos)
+    bounded, per_lane, before, causal = (own.bounded, own.per_lane,
+                                         own.before, own.causal)
+    if chunk is not None:
+        admitted = rows_at(c_off, c_len, c_lane, True)
     scale = 1.0 / jnp.sqrt(jnp.float32(cfg.head_dim))
     if typed and cfg.kv_ring_planes:
         rk_all, rv_all = cache["k_win"], cache["v_win"]
@@ -764,7 +810,7 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
         band = causal & (t_ids[:, None] - t_ids[None, :] < window
                          )[None, :, None, None, :]
 
-    def plane_rows(a_all, plane):
+    def plane_rows(a_all, plane, lane=lane):
         """Plane ``plane``'s rows, for reading: of every row, or of
         lane ``lane`` alone."""
         if lane is not None:
@@ -773,23 +819,26 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
                 (1, 1) + a_all.shape[2:])[0]
         return jax.lax.dynamic_index_in_dim(a_all, plane, 0, keepdims=False)
 
-    def attend_plain(q, k, v, plane):
+    def attend_plain(q, k, v, plane, rows=own):
         """An untyped stack's attention over its token-major plane
-        ``[B, S, kv, hd]`` and the chunk's own K/V."""
+        ``[B, S, kv, hd]`` and the chunk's own K/V, of the rows ``rows``
+        describes (:func:`rows_at`; the call's own by default)."""
+        b, t_len = q.shape[:2]
+        pos0, lane = rows.pos0, rows.lane
         with jax.named_scope("kv_slab"):
             # This plane's rows, for reading (the per-lane kernel takes
             # the slab and the plane's index).
-            if lane is not None or not per_lane:
-                ck, cv = (plane_rows(a, plane) for a in (ck_all, cv_all))
-            if bounded:
+            if lane is not None or not rows.per_lane:
+                ck, cv = (plane_rows(a, plane, lane) for a in (ck_all, cv_all))
+            if rows.bounded:
                 at = (jnp.int32(0), pos0[0], jnp.int32(0), jnp.int32(0))
                 ck = jax.lax.dynamic_update_slice(ck, k, at)
                 cv = jax.lax.dynamic_update_slice(cv, v, at)
         with jax.named_scope("attn"):
-            if per_lane:
+            if rows.per_lane:
                 return _attend_before_and_chunk(q, k, v, ck_all, cv_all,
                                                 plane, pos0)
-            if bounded:
+            if rows.bounded:
                 return flash_prefix_attention(q.astype(ck.dtype), ck, cv,
                                               pos0[0])
             qg = q.astype(jnp.float32).reshape(
@@ -799,13 +848,23 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
             new = jnp.einsum("btcgk,buck->btcgu", qg,
                              k.astype(jnp.float32)) * scale
             probs = jax.nn.softmax(jnp.concatenate(
-                [jnp.where(before, old, -1e30),
-                 jnp.where(causal, new, -1e30)], axis=-1), axis=-1)
+                [jnp.where(rows.before, old, -1e30),
+                 jnp.where(rows.causal, new, -1e30)], axis=-1), axis=-1)
             return (jnp.einsum("btcgs,bsck->btcgk", probs[..., :s_len],
                                cv.astype(jnp.float32))
                     + jnp.einsum("btcgu,buck->btcgk", probs[..., s_len:],
                                  v.astype(jnp.float32))).reshape(
                 b, t_len, cfg.n_heads, cfg.head_dim)
+
+    def attend_round(q, k, v, plane):
+        """``chunk=``: the stream's one row split again — the lanes'
+        tokens attend as a decode step's rows, the chunk's as an
+        admission's one row — and laid side by side once more."""
+        by_lane = attend_plain(*(a[0, :b, None] for a in (q, k, v)), plane)
+        by_chunk = attend_plain(*(a[:, b:] for a in (q, k, v)), plane,
+                                admitted)
+        return jnp.concatenate([by_lane.astype(dtype)[None, :, 0],
+                                by_chunk.astype(dtype)], axis=1)
 
     def attend_typed(q, k, v, plane, kind):
         """A typed stack's attention: the plane before the chunk and
@@ -908,7 +967,9 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
             if rope_ang is not None and layer_rotates(cfg, kind):
                 q, k = rope_rotate(q, rope_ang), rope_rotate(k, rope_ang)
             k, v = k.astype(ck_all.dtype), v.astype(cv_all.dtype)
-        if kind is None:
+        if chunk is not None:
+            attn = attend_round(q, k, v, plane)
+        elif kind is None:
             attn = attend_plain(q, k, v, plane)
         elif kind[0] == "retention":
             with jax.named_scope("attn"):
@@ -919,7 +980,8 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
         with jax.named_scope("attn_proj"):
             attn = attn.astype(dtype)
             if cfg.fused_qkv:
-                a = jnp.einsum("btk,kd->btd", attn.reshape(b, t_len, -1),
+                a = jnp.einsum("btk,kd->btd",
+                               attn.reshape(x.shape[:2] + (-1,)),
                                lp["attn"]["wo"])
             else:
                 a = jnp.einsum("bthk,hkd->btd", attn, lp["attn"]["wo"])
@@ -959,6 +1021,8 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
                            n_real, with_routes)
     x, (new_k, new_v) = jax.lax.scan(one_pass, x, jnp.arange(cfg.n_passes))
     with jax.named_scope("head"):
+        if chunk is not None:   # the lanes' rows only: [B, 1, D]
+            x = x[0, :b, None]
         out = jnp.einsum("btd,vd->btv", x,
                          head_table(params, cfg).astype(dtype))
     with jax.named_scope("kv_slab"):
@@ -966,6 +1030,13 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
         new_k, new_v = (a.reshape((cfg.kv_planes,) + a.shape[2:])
                         for a in (new_k, new_v))
         zero = jnp.int32(0)
+        if chunk is not None:
+            # The chunk's [L, 1, C, kv, hd] into its lane, then the
+            # lanes' [L, B, 1, kv, hd] a row below.
+            at = (zero, c_lane, c_off[0], zero, zero)
+            ck_all = jax.lax.dynamic_update_slice(ck_all, new_k[:, :, b:], at)
+            cv_all = jax.lax.dynamic_update_slice(cv_all, new_v[:, :, b:], at)
+            new_k, new_v = (a[:, 0, :b, None] for a in (new_k, new_v))
         if uniform_pos:
             at = (zero, zero if lane is None else lane, pos0[0], zero, zero)
             ck_all = jax.lax.dynamic_update_slice(ck_all, new_k, at)
@@ -1085,7 +1156,8 @@ def _typed_tail(x, new, params, cache, pos0, cfg: TransformerConfig,
 
 def _decode_chunk(params, cache, tokens, pos0, cfg: TransformerConfig,
                   uniform_pos: bool = False, beam_anc=None, lane=None,
-                  n_real=None, with_routes: bool = False, live=None):
+                  n_real=None, with_routes: bool = False, live=None,
+                  chunk=None):
     """Process T new tokens per row against the cache in ONE pass:
     ``tokens [B, T]`` at global positions ``pos0[b] + (0..T-1)`` ->
     ``(logits [B, T, V] f32, cache)``.
@@ -1158,11 +1230,12 @@ def _decode_chunk(params, cache, tokens, pos0, cfg: TransformerConfig,
         return _chunk_in_place(params, cache, tokens, pos0, cfg,
                                uniform_pos=uniform_pos, lane=lane,
                                n_real=n_real, with_routes=with_routes,
-                               live=live)
+                               live=live, chunk=chunk)
     reject_extended(cfg, why)
-    if lane is not None:
+    if lane is not None or chunk is not None:
         raise ValueError(f"lane= (in-place admission into one lane of a "
-                         f"slab) does not compose with {why}: cut the "
+                         f"slab) and chunk= (an admission inside a decode "
+                         f"step) do not compose with {why}: cut the "
                          "lane out of the slab")
     dtype = jnp.dtype(cfg.dtype)
     b, t_len = tokens.shape

@@ -160,9 +160,15 @@ def serving_rounds(records: list[dict],
     the device sets the pace, near zero the host does — beside the
     median ``host_ms`` of the decoding rounds, the host's own work in
     a call.
+    ``fused`` (traces whose rounds carry the count): the admission
+    chunks of the decoding rounds (``chunks``) and how many of them
+    went out INSIDE a round's decode program (``fused``: a
+    continuation chunk that rides the step) and not as an admission
+    program of their own — how often that mechanism engages.
     ``attended``: the cache positions the admission
     programs' attention read (the field of that name on the admission
-    spans) — how many programs, their mean, and with ``max_len`` (the
+    spans; a fused chunk's is its step's ``chunk_attended``) — how many
+    programs, their mean, and with ``max_len`` (the
     engine's slots a lane, which no record carries) the share of the
     slab a program still reads: 1.0 on the dense path.
     ``attended_step``: the same for the decode dispatches
@@ -203,8 +209,15 @@ def serving_rounds(records: list[dict],
                  if "host_ms" in r["fields"]]
         if hosts:
             out["overlap"]["host_p50_ms"] = statistics.median(hosts)
+    if any("fused" in r["fields"] for r in live):
+        out["fused"] = {
+            "chunks": sum(r["fields"]["chunks"] for r in live),
+            "fused": sum(r["fields"].get("fused", 0) for r in live)}
     attended = [sp["fields"]["attended"] for sp in spans
                 if sp["name"] in _ADMIT and "attended" in sp["fields"]]
+    attended += [sp["fields"]["chunk_attended"] for sp in spans
+                 if sp["name"] == "serving.step"
+                 and "chunk_attended" in sp["fields"]]
     if attended:
         out["attended"] = {"programs": len(attended),
                            "mean": statistics.fmean(attended)}
@@ -382,8 +395,11 @@ def request_waterfall(records: list[dict], request_id: int) -> dict:
         "queue_wait_s": (admit["t0"] - t0) if admit and submit
         else None,
         "ttft_s": (emits[0]["t"] - t0) if emits and submit else None,
+        # (A ``serving.step`` carries a request's id only where its
+        # program took one of the request's chunks: a fused round.)
         "prefill_chunks": sum(1 for sp in mine_spans
-                              if sp["name"] == "serving.admit_chunk"),
+                              if sp["name"] in ("serving.admit_chunk",
+                                                "serving.step")),
         "tokens": sum(e["fields"].get("n") or 0 for e in final_emits),
         "reroutes": sum(1 for e in hops
                         if e["name"] == "router.reroute"),
@@ -572,11 +588,16 @@ def render_report(rep: dict, max_events: int = 60) -> str:
     if rounds:
         out.append("\n== serving rounds ==")
         out.append(f"  {rounds['rounds']} rounds, {rounds['idle']} idle; "
-                   f"most admission programs before one decode step: "
+                   f"most admission chunks before one decode step: "
                    f"{rounds['chunks_max']}")
         if rounds["mean"]:
             out.append("  mean per decoding round: " + "  ".join(
                 f"{k}={v:.4g}" for k, v in rounds["mean"].items()))
+        fu = rounds.get("fused")
+        if fu and fu["chunks"]:
+            out.append(f"  fused: {fu['fused']} of {fu['chunks']} admission "
+                       f"chunks ({fu['fused'] / fu['chunks']:.1%}) went "
+                       "through the layers inside a decode step's program")
         att = rounds.get("attended")
         if att:
             share = (f" = {att['share']:.1%} of the slab's slots a lane"

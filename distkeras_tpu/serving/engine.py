@@ -668,7 +668,8 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
         return True
 
     def _close_round(self, rnd, out, chunks: int, idle: bool,
-                     overlapped: bool = False, state_lanes: int = 0) -> None:
+                     overlapped: bool = False, state_lanes: int = 0,
+                     fused: bool = False) -> None:
         """The counts of one ``step()``, taken where the round ends
         (session active only): ONE pass over the lane table, set as
         the ``serving.lanes_busy`` gauge and written into the closing
@@ -680,7 +681,10 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
         dispatched for it, up to its budget (the round in flight
         counts: the count is the dispatch's, not the transcript's), an
         admitting lane what lies before its next chunk.  ``chunks`` is the admission
-        programs dispatched since the previous decode dispatch.
+        chunks dispatched since the previous decode dispatch, ``fused``
+        (0 / 1) whether one of them went out INSIDE this round's decode
+        program (``ContinuousBatcher._make_round_chunk``) and not as an
+        admission program of its own.
         ``host_ms`` is the call's time so far (this is its last act)
         less what its ``serving.collect`` spans waited for the device:
         the host's own work in the round."""
@@ -703,7 +707,7 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
         if rnd is not None:
             rnd.fields.update(
                 lanes_busy=busy, lanes_admitting=admitting,
-                kv_live=kv_live, chunks=chunks,
+                kv_live=kv_live, chunks=chunks, fused=int(fused),
                 tokens=sum(len(v) for v in out.values()))
             if self.cfg.kv_ring_planes:
                 # Ring planes: what of a lane's positions a window
@@ -737,11 +741,7 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
         prompt token" convention as monolithic admission)."""
         if not self._admitting:
             return
-        lane = self._admitting[0]
-        st = self._lane_state[lane]
-        start, rows = st.chunks.pop(0)
-        end = min(start + rows.shape[1], st.off + st.prompt_len - 1)
-        new, st.filled = max(end - st.filled, 0), max(end, st.filled)
+        lane, st, start, rows, end, new = self._pop_chunk()
         with obs.span("serving.admit_chunk", bucket=rows.shape[1],
                       positions=new, remaining=len(st.chunks),
                       request_id=st.request_id) as sp:
@@ -757,6 +757,19 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
             self._admitting.popleft()
             st.chunks = None
             self._finish_admission(lane, st)
+
+    def _pop_chunk(self) -> tuple:
+        """Take the next pending chunk (FIFO across admitting lanes) off
+        its lane's plan and move the lane's ``filled`` frontier past
+        it: ``(lane, its _Lane, start, rows, end, new)`` — the chunk's
+        real tokens end at ``end``, ``new`` of its positions were not
+        written before (the spans' ``positions``)."""
+        lane = self._admitting[0]
+        st = self._lane_state[lane]
+        start, rows = st.chunks.pop(0)
+        end = min(start + rows.shape[1], st.off + st.prompt_len - 1)
+        new, st.filled = max(end - st.filled, 0), max(end, st.filled)
+        return lane, st, start, rows, end, new
 
     def _real(self, n: int) -> dict:
         """``n_real=`` of an admission dispatch — how many of the

@@ -103,7 +103,12 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
     Full-cache configs only, and every chunk program compiles at
     construction (the ``serving_chunked`` compile session pins a
     zero-recompile serve phase).  The ``prefill_chunk`` width is added
-    to ``prompt_buckets``.
+    to ``prompt_buckets``.  Where the stack is the plain one (untyped,
+    one pass, in-place admission; one device, no tiers, ``step(1)``) a
+    full-width chunk that is neither its plan's first nor its last goes
+    through the layers INSIDE the round's decode program
+    (:meth:`_make_round_chunk`): one launch a round, the weights
+    streamed once, the same tokens.
 
     **Prefix pool** (round-10, ``prefix_pool=``): attach a
     :class:`~distkeras_tpu.serving.PrefixPool` and ``submit`` /
@@ -666,6 +671,9 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
             self._exec_admit(0, self._off, rows, None, **self._real(width))
             if self._admit_cont not in (None, self._admit):
                 self._exec_chunk(0, self._off, rows)
+        if self._round_chunk is not None:
+            self._exec_round_chunk(0, self._off, np.zeros(
+                (1, self.prefill_chunk), np.int32))
         if pool is not None:
             self._exec_reseed(0, 0)
         elif self._prefix_lane is not None:
@@ -744,16 +752,19 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
         routed = cfg.typed and "sparse" in cfg.ffn_types
 
         def one_step_p(params, cache, cur, pos, keys, temps, tps, mps,
-                       live=None):
+                       live=None, chunk=None):
+            # ``chunk`` (``_make_round_chunk`` only): an admission
+            # chunk that goes through the layers with this step's rows.
             routes = None
             if routed:
                 logits, cache, routes = _decode_chunk(
                     params, cache, cur[:, None], pos, cfg, with_routes=True,
-                    live=live)
+                    live=live, chunk=chunk)
                 routes = routes[:, :, 0]
             else:
                 logits, cache = _decode_chunk(
-                    params, cache, cur[:, None], pos, cfg, live=live)
+                    params, cache, cur[:, None], pos, cfg, live=live,
+                    chunk=chunk)
             logits = logits[:, 0]                      # [lanes, V]
             if per_request_sampling:
                 # Vectorized per-lane params: greedy lanes (t <= 0)
@@ -822,9 +833,10 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
             # pre-round-20 one.
             return one_step_p
 
-        def one_step(cache, cur, pos, keys, temps, tps, mps, *live):
+        def one_step(cache, cur, pos, keys, temps, tps, mps, *live,
+                     chunk=None):
             return one_step_p(self.params, cache, cur, pos, keys,
-                              temps, tps, mps, *live)
+                              temps, tps, mps, *live, chunk=chunk)
         return one_step
 
     def _make_step(self, n: int):
@@ -880,6 +892,37 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
                                      else (toks, routes))
         return jax.jit(step_n, donate_argnums=0)
 
+    def _make_round_chunk(self):
+        """The program of a round that holds a continuation chunk: ONE
+        decode step (``_build_one_step``'s: the same attention,
+        sampling and position advance as ``_make_step(1)``) whose layer
+        body also takes ``rows [1, prefill_chunk]`` into lane ``lane``
+        at ``off`` (``generate._chunk_in_place``'s ``chunk=``), so the
+        round streams the weights once where ``_admit`` + ``step_n``
+        stream them twice.  Returns the step's ``(cache, cur, pos,
+        toks [lanes, 1])``.  The slab is read-only inside the layers, so
+        the chunk's lane must stay parked through this round:
+        :meth:`_fusable_chunk` never hands it a plan's last chunk.  Its
+        name matches neither ``step_n`` nor ``_admit``: the by-name
+        readers of those two programs do not take this one for theirs."""
+        one_step = self._one_step
+
+        if self._hot_swap:
+            def round_chunk_p(params, cache, cur, pos, keys, temps, tps,
+                              mps, rows, lane, off):
+                cache, cur, pos, _ = one_step(
+                    params, cache, cur, pos, keys, temps, tps, mps,
+                    chunk=(rows, lane, off))
+                return cache, cur, pos, cur[:, None]
+            return jax.jit(round_chunk_p, donate_argnums=1)
+
+        def round_chunk(cache, cur, pos, keys, temps, tps, mps, rows, lane,
+                        off):
+            cache, cur, pos, _ = one_step(cache, cur, pos, keys, temps, tps,
+                                          mps, chunk=(rows, lane, off))
+            return cache, cur, pos, cur[:, None]
+        return jax.jit(round_chunk, donate_argnums=0)
+
     def _build_admission_programs(self) -> None:
         # Admission: prefill `width` positions of ONE lane (lane-sliced
         # cache write; padded tail slots stay masked until the decode
@@ -915,6 +958,19 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
         self._reseed_pool = (_make_lane_reseed(pooled=True,
                                                constrain=constrain)
                              if pooled else None)
+        # A round with a continuation chunk as ONE program — where the
+        # stack is the plain one (untyped, one pass, nothing the base
+        # body holds back), the chunk goes into the slab in place, the
+        # engine is on one device with no tiers, and the round is this
+        # class's own (the paged engine reads each round at once, the
+        # speculative one's rounds are its own).  Everything else keeps
+        # its two programs.
+        cfg = self.cfg
+        if (in_place and self.prefill_chunk is not None and not cfg.typed
+                and cfg.n_passes == 1 and self._overlap
+                and self.lane_tiers is None and self.mesh is None
+                and type(self).step is ContinuousBatcher.step):
+            self._round_chunk = self._make_round_chunk()
 
     # ------------------------------------------------------------ API
 
@@ -1072,6 +1128,17 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
                                       jnp.int32(lane), jnp.int32(start),
                                       *real)
         return self._admit_cont
+
+    def _exec_round_chunk(self, lane, start, rows):
+        """DISPATCH the decode step that also admits ``rows`` into
+        ``lane`` at ``start`` (:meth:`_make_round_chunk`); returns the
+        round's tokens ``[lanes, 1]`` still on the device, as
+        :meth:`_dispatch_step` does."""
+        self.cache, self.cur, self.pos, toks = self._round_chunk(
+            *self._pargs(), self.cache, self.cur, self.pos, self.keys,
+            self.temps, self.tps, self.mps, jnp.asarray(rows),
+            jnp.int32(lane), jnp.int32(start))
+        return toks
 
     def _finish_admission(self, lane, st):
         """Last chunk landed: un-park the lane — set its decode
@@ -1331,6 +1398,11 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
     # tables grow from the transcript) and reads each round at once.
     _overlap = True
 
+    # The program of a round that holds a continuation chunk
+    # (:meth:`_make_round_chunk`), on the engines that fuse it
+    # (``_build_admission_programs`` says which); None: two programs.
+    _round_chunk = None
+
     def step(self, n: int = 1):
         """Advance every lane ``n`` tokens in ONE device round;
         returns ``{lane: [tokens...]}`` for lanes that emitted.
@@ -1400,22 +1472,30 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
             # Tier hysteresis BEFORE the idle early-out: an idle
             # elastic engine must still step its lane count back down.
             self._maybe_scale_down()
-            self._run_pending_chunk()
+            # The pending chunk goes out as a program of its own, or —
+            # ``fused`` — inside this round's decode program.
+            fused = self._fusable_chunk(n)
+            if not fused:
+                self._run_pending_chunk()
             lanes = self._decoding()
             # ``_inflight`` is rebound only after the dispatch: one that
             # raises keeps the unread round for the next call.
             unread, launched = self._inflight, None
-            chunks = self._admit_programs
+            chunks = self._admit_programs + fused
             if lanes:
                 chaos.probe("serving.step")
                 self._admit_programs = 0
                 with obs.span("serving.step", n=n) as sp:
                     if sp is not None:
                         sp.fields["attended"] = self._step_attended(n)
-                    toks = self._dispatch_step(n)
+                    if fused:
+                        toks = self._dispatch_round_chunk(sp)
+                    else:
+                        toks = self._dispatch_step(n)
                     for a in jax.tree.leaves(toks):
                         a.copy_to_host_async()
-                    self._number_dispatch(sp, self._steps[n])
+                    self._number_dispatch(
+                        sp, self._round_chunk if fused else self._steps[n])
                 for _, s in lanes:
                     s.launched += n
                 launched = (toks, lanes, self._dispatch_seq)
@@ -1439,7 +1519,7 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
                 self._close_round(rnd, out, chunks, idle,
                                   overlapped=launched is not None
                                   and unread is not None,
-                                  state_lanes=len(lanes))
+                                  state_lanes=len(lanes), fused=fused)
             return out
 
     def _return_flushed(self, out: dict) -> None:
@@ -1465,6 +1545,38 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
             *self._pargs(), self.cache, self.cur, self.pos, self.keys,
             self.temps, self.tps, self.mps, *self._live())
         return toks
+
+    def _fusable_chunk(self, n: int) -> bool:
+        """Whether this round's pending chunk goes through the layers
+        WITH the decode step (:meth:`_make_round_chunk`): the engine
+        built that program, the round is one step, the chunk is a full
+        ``prefill_chunk`` wide with more of its plan to come (a plan's
+        last chunk un-parks its lane into this round's decode, which
+        must find the chunk in the slab: it stays a program of its own,
+        as a lane's first chunk and a bucket-padded tail do), and some
+        lane decodes.  Host comparisons on what the engine holds."""
+        if self._round_chunk is None or n != 1 or not self._admitting:
+            return False
+        chunks = self._lane_state[self._admitting[0]].chunks
+        return (len(chunks) > 1
+                and chunks[0][1].shape[1] == self.prefill_chunk
+                and bool(self._decoding()))
+
+    def _dispatch_round_chunk(self, sp):
+        """DISPATCH the decode step with the pending chunk inside it;
+        ``sp``, the round's ``serving.step`` span, takes what a
+        ``serving.admit_chunk`` span would have said (no such span is
+        opened: the admission readers divide the ``_admit`` programs'
+        device time by those spans' ``bucket``).  Returns the round's
+        tokens on the device, as :meth:`_dispatch_step` does."""
+        lane, st, start, rows, _, new = self._pop_chunk()
+        if sp is not None:
+            sp.fields.update(
+                bucket=rows.shape[1], positions=new,
+                remaining=len(st.chunks), request_id=st.request_id,
+                chunk_attended=self._attended(self.cache, start,
+                                              rows.shape[1]))
+        return self._exec_round_chunk(lane, start, rows)
 
     def _decoding(self) -> list:
         """``(lane, _Lane)`` of the lanes with a token still to decode:

@@ -33,10 +33,12 @@ ROUND_CHILDREN = ("serving.pump", "serving.admit_chunk", "serving.step",
 @pytest.fixture(scope="module")
 def rounds(tmp_path_factory):
     """A scripted run: B (5 tokens) decodes while A (21 tokens, three
-    chunks of 8) is admitted and prefilled between B's decode steps.
-    A call dispatches its decode round and reads the one before, so a
-    round's ``tokens`` are the previous dispatch's.  Returns the
-    ``serving.round`` spans in order and every span."""
+    chunks of 8) is admitted and prefilled between B's decode steps —
+    its middle chunk INSIDE B's decode program (round 2, ``fused``), its
+    last as a program of its own (round 3).  A call dispatches its
+    decode round and reads the one before, so a round's ``tokens`` are
+    the previous dispatch's.  Returns the ``serving.round`` spans in
+    order and every span."""
     path = str(tmp_path_factory.mktemp("rounds") / "t.jsonl")
     params = toy_params(CFG)
     with obs.session(trace_path=path):
@@ -46,7 +48,7 @@ def rounds(tmp_path_factory):
         eng.enqueue(np.arange(5), 8)        # B: one admission program
         eng.step()                          # 1: B's first step goes out
         eng.enqueue(np.arange(21), 4)       # A: first chunk [0, 8)
-        eng.step()                          # 2: A's chunk [8, 16), B decodes;
+        eng.step()                          # 2: A's chunk [8, 16) in B's step;
         #                                        B's first token comes back
         eng.step()                          # 3: A's chunk [12, 20), both decode
         eng.step()                          # 4: both tokens of round 3
@@ -56,11 +58,12 @@ def rounds(tmp_path_factory):
 
 @pytest.mark.parametrize("child", ROUND_CHILDREN)
 def test_round_is_parent_of(rounds, child):
-    """Round 2 runs every boundary: pump, a continuation chunk, the
-    decode dispatch, the read of the round before, the emit loop, the
-    reap — each a child of the round, inside its interval."""
+    """Round 3 runs every boundary: pump, a continuation chunk as a
+    program of its own, the decode dispatch, the read of the round
+    before, the emit loop, the reap — each a child of the round, inside
+    its interval."""
     rnds, spans = rounds
-    rnd = rnds[2]
+    rnd = rnds[3]
     (sp,) = [s for s in spans
              if s["name"] == child and s["parent"] == rnd["id"]]
     assert sp["depth"] == rnd["depth"] + 1
@@ -80,12 +83,13 @@ def test_idle_round_is_marked_and_dispatches_nothing(rounds):
     # ``bucket``) and carry the request's id.
     admits = [s for s in spans if s["name"] in ("serving.admit",
                                                 "serving.admit_chunk")]
-    assert [s["fields"]["bucket"] for s in admits] == [8, 8, 8, 8]
+    assert [s["fields"]["bucket"] for s in admits] == [8, 8, 8]
     # ``positions``: what each program writes that is neither padding
     # (B: 4 warm tokens in a bucket of 8) nor written before (A's
     # backed-up tail [12, 20) adds four); a request's sum to its
-    # prompt length less one.
-    assert [s["fields"]["positions"] for s in admits] == [4, 8, 8, 4]
+    # prompt length less one — with the chunk that rode a decode step
+    # (``test_a_fused_chunk_is_said_by_its_step``: A's [8, 16)).
+    assert [s["fields"]["positions"] for s in admits] == [4, 8, 4]
     assert all("request_id" in s["fields"] for s in admits)
 
 
@@ -96,18 +100,20 @@ def test_idle_round_is_marked_and_dispatches_nothing(rounds):
     (1, {"lanes_busy": 1, "lanes_admitting": 0, "kv_live": 5,
          "chunks": 1, "tokens": 0}),
     # The round PERF.md describes: A's first chunk ran at admission and
-    # a continuation chunk in this step(), so TWO admission programs
-    # stand between two decode dispatches.  A is mid-prefill: positions
-    # [0, 12) lie before its next chunk.  The token is round 1's.
+    # a continuation chunk in this step(), so TWO chunks stand between
+    # two decode dispatches — the second of them inside this round's
+    # decode program (``fused``).  A is mid-prefill: positions [0, 12)
+    # lie before its next chunk.  The token is round 1's.
     (2, {"lanes_busy": 2, "lanes_admitting": 1, "kv_live": 6 + 12,
-         "chunks": 2, "tokens": 1}),
-    # A's last chunk landed and it joined this decode: its 21 prompt
-    # tokens are in, its first token is the next input — and comes back
-    # a call later: the token emitted here is B's, of round 2.
+         "chunks": 2, "fused": 1, "tokens": 1}),
+    # A's last chunk landed (a program of its own: it un-parks A) and A
+    # joined this decode: its 21 prompt tokens are in, its first token
+    # is the next input — and comes back a call later: the token
+    # emitted here is B's, of round 2.
     (3, {"lanes_busy": 2, "lanes_admitting": 0, "kv_live": 7 + 21,
-         "chunks": 1, "tokens": 1}),
+         "chunks": 1, "fused": 0, "tokens": 1}),
     (4, {"lanes_busy": 2, "lanes_admitting": 0, "kv_live": 8 + 22,
-         "chunks": 0, "tokens": 2}),
+         "chunks": 0, "fused": 0, "tokens": 2}),
 ])
 def test_round_counts_equal_a_hand_count(rounds, i, want):
     rnds, _ = rounds
@@ -125,7 +131,7 @@ def test_attended_is_max_len_on_the_dense_path(rounds):
     reads all of the lane's slots, and says so."""
     _, spans = rounds
     assert [s["fields"]["attended"] for s in _admits(spans)] == [
-        CFG.max_len] * 4
+        CFG.max_len] * 3
 
 
 def _interpreted_kernels(monkeypatch):
@@ -193,7 +199,34 @@ def test_step_attended_is_every_slot_on_the_dense_path(rounds):
     assert [_n_attended(s) for s in steps] == [
         {"n": 1, "attended": 2 * CFG.max_len}] * 4
     assert all(set(s["fields"]) == {"n", "attended", "seq", "program"}
-               for s in steps)
+               for s in steps if "bucket" not in s["fields"])
+
+
+def test_a_fused_chunk_is_said_by_its_step(rounds):
+    """Round 2's decode program also admits A's chunk [8, 16): the
+    round's ONE ``serving.step`` says what a ``serving.admit_chunk``
+    span would have (``bucket``, ``positions``, ``remaining``,
+    ``request_id``; the chunk's reads as ``chunk_attended``, the decode
+    rows' stay ``attended``), names the third program, and NO
+    ``serving.admit_chunk`` span is opened for the chunk — the
+    admission reader divides the ``_admit`` programs' device time by
+    those spans' ``bucket``.  ``serving.round.fused`` counts it."""
+    rnds, spans = rounds
+    assert [r["fields"]["fused"] for r in rnds] == [0, 0, 1, 0, 0]
+    kids = [s for s in spans if s["parent"] == rnds[2]["id"]]
+    assert "serving.admit_chunk" not in {s["name"] for s in kids}
+    (step,) = [s for s in kids if s["name"] == "serving.step"]
+    (a_admit,) = [s for s in spans if s["name"] == "serving.admit"
+                  and s["fields"]["chunks"] == 3]
+    assert step["fields"] == {
+        "n": 1, "attended": 2 * CFG.max_len, "seq": 2,
+        "program": "jit_round_chunk", "bucket": 8, "positions": 8,
+        "remaining": 1, "request_id": a_admit["fields"]["request_id"],
+        "chunk_attended": CFG.max_len}
+    # The one admission chunk that is a span is A's last, in round 3.
+    (chunk,) = [s for s in spans if s["name"] == "serving.admit_chunk"]
+    assert chunk["parent"] == rnds[3]["id"]
+    assert chunk["fields"]["remaining"] == 0
 
 
 def test_step_attended_follows_the_lanes_on_the_bounded_path(tmp_path,
@@ -236,15 +269,27 @@ def test_step_attended_follows_the_lanes_on_the_bounded_path(tmp_path,
 # --------------------------- which program a span launched, which launch it read
 
 
-def _lowered_names(eng):
-    """``{"decode_step" | "admit": the lowered module's name}`` of an
-    engine's two programs: what an "XLA Modules" event of the device
-    trace is called (before its ``(id)``)."""
+def _lowered(eng, **how):
+    """``{"decode_step" | "admit" | "round_chunk": text}``: an engine's
+    three programs, lowered (the third where the engine fuses)."""
     out = {}
     for spec in eng.traced_for_analysis():
         key = "decode_step" if spec.name.endswith("decode_step") else "admit"
-        (out[key],) = re.findall(r"module @(\S+)",
-                                 spec.fn.lower(*spec.args).as_text())
+        out[key] = spec.fn.lower(*spec.args).as_text(**how)
+    if eng._round_chunk is not None:
+        out["round_chunk"] = eng._round_chunk.lower(
+            *eng._pargs(), eng.cache, eng.cur, eng.pos, eng.keys, eng.temps,
+            eng.tps, eng.mps, jnp.zeros((1, eng.prefill_chunk), jnp.int32),
+            jnp.int32(0), jnp.int32(0)).as_text(**how)
+    return out
+
+
+def _lowered_names(eng):
+    """The lowered modules' names of an engine's programs: what an "XLA
+    Modules" event of the device trace is called (before its ``(id)``)."""
+    out = {}
+    for key, text in _lowered(eng).items():
+        (out[key],) = re.findall(r"module @(\S+)", text)
     return out
 
 
@@ -253,7 +298,7 @@ def _lowered_names(eng):
 def declared(request, tmp_path_factory):
     """A chunked admission beside a decoding lane, by an engine that
     closes over its weights and by one that takes them as an argument
-    (other functions, other names): the spans, and the two programs'
+    (other functions, other names): the spans, and the three programs'
     lowered names."""
     path = str(tmp_path_factory.mktemp("declared") / "t.jsonl")
     with obs.session(trace_path=path):
@@ -269,17 +314,34 @@ def declared(request, tmp_path_factory):
     return spans, _lowered_names(eng)
 
 
-@pytest.mark.parametrize("span,program", [
-    ("serving.step", "decode_step"), ("serving.admit", "admit"),
-    ("serving.admit_chunk", "admit")])
-def test_program_is_the_lowered_modules_name(declared, span, program):
+@pytest.mark.parametrize("span,programs", [
+    ("serving.step", ("decode_step", "round_chunk")),
+    ("serving.admit", ("admit",)), ("serving.admit_chunk", ("admit",))])
+def test_program_is_the_lowered_modules_name(declared, span, programs):
     """A dispatching span's ``program`` is what the device trace will
     call the launch — taken from the jitted callable, so a renamed or
-    fused program changes it with no reader edited."""
+    fused program changes it with no reader edited.  A decode round
+    launches one of two: the plain step, or the step that also admits
+    the round's continuation chunk."""
     spans, lowered = declared
     said = {s["fields"]["program"] for s in spans if s["name"] == span}
-    assert said == {lowered[program]}
-    assert lowered[program].startswith("jit_")
+    assert said == {lowered[p] for p in programs}
+    assert all(lowered[p].startswith("jit_") for p in programs)
+    if span == "serving.step":
+        fused = {s["fields"]["program"] for s in spans
+                 if s["name"] == span and "bucket" in s["fields"]}
+        assert fused == {lowered["round_chunk"]}
+
+
+def test_the_fused_program_is_neither_readers(declared):
+    """``decode_step_ms`` finds its program by ``step_n``,
+    ``prefill_ms_per_ktok`` by ``_admit``: the third program's name
+    matches neither, so each metric keeps reading the program its
+    ``what`` says — the plain step, the standalone admission."""
+    name = declared[1]["round_chunk"]
+    assert name.startswith("jit_round_chunk")
+    for metric in ("decode_step_ms", "prefill_ms_per_ktok"):
+        assert not re.search(_pattern(metric), name), (metric, name)
 
 
 def _scripted(kind, path):
@@ -433,16 +495,13 @@ def _pattern(metric):
 
 @pytest.fixture(scope="module")
 def engine_programs():
-    """The decode-step and the admission program of a hot-swap engine
-    (the benchmark's), lowered: ``{"decode_step" | "admit": text}``."""
+    """The decode-step, the admission and the fused program of a
+    hot-swap engine (the benchmark's), lowered: ``{"decode_step" |
+    "admit" | "round_chunk": text}``."""
     params = toy_params(CFG)
     eng = dk.ContinuousBatcher(params, CFG, lanes=2, hot_swap=True,
                                prefill_chunk=8, prompt_buckets=(8,))
-    out = {}
-    for spec in eng.traced_for_analysis():
-        key = "decode_step" if spec.name.endswith("decode_step") else "admit"
-        out[key] = spec.fn.lower(*spec.args).as_text(debug_info=True)
-    return out
+    return _lowered(eng, debug_info=True)
 
 
 @pytest.mark.parametrize("program,metric", [
@@ -472,7 +531,9 @@ def _scopes_in(text):
     ("decode_step", {"loop_exit"}),
     # An admission discards the chunk's logits: the head is traced and
     # then pruned as dead code, so the program never computes it.
-    ("admit", {"head", "loop_exit"})])
+    ("admit", {"head", "loop_exit"}),
+    # The fused round's head runs over the lanes' rows.
+    ("round_chunk", {"loop_exit"})])
 def test_serving_programs_hold_every_scope(engine_programs, program,
                                            absent):
     assert _scopes_in(engine_programs[program]) \
@@ -815,6 +876,47 @@ def test_report_sets_the_decode_steps_attended_against_kv_live(attended,
     for r in recs:
         r["fields"].pop("attended", None)
     assert "attended_step" not in serving_rounds(recs)
+
+
+def test_report_prints_the_fused_share_of_chunks():
+    """Four decoding rounds by hand, five chunks between them, two of
+    which rode their round's decode program: the report says how often
+    the mechanism engaged, counts a fused chunk's reads among the
+    admissions', and a request's waterfall counts the chunk."""
+    from distkeras_tpu.obs.report import (build_report, render_report,
+                                          request_waterfall, serving_rounds)
+
+    counts = dict(lanes_busy=2, lanes_admitting=1, kv_live=10, tokens=1)
+    fused = dict(bucket=8, positions=8, remaining=1, request_id=7,
+                 chunk_attended=16)
+    recs = [
+        _span("serving.round", 0, 4, 1, chunks=2, fused=1, **counts),
+        _span("serving.admit", 0.5, 1, 2, 1, bucket=8, attended=8,
+              request_id=7),
+        _span("serving.step", 2, 1, 3, 1, n=1, **fused),
+        _span("serving.round", 5, 4, 4, chunks=1, fused=1, **counts),
+        _span("serving.step", 6, 1, 5, 4, n=1,
+              **dict(fused, remaining=0, chunk_attended=24)),
+        _span("serving.round", 10, 4, 6, chunks=2, fused=0, **counts),
+        _span("serving.admit_chunk", 10.5, 1, 7, 6, bucket=8, attended=32,
+              request_id=7),
+        _span("serving.step", 12, 1, 8, 6, n=1),
+        _span("serving.round", 15, 4, 9, chunks=0, fused=0, **counts),
+        _span("serving.step", 16, 1, 10, 9, n=1),
+        _span("serving.round", 20, 1, 11, chunks=0, fused=0, idle=True,
+              **dict(counts, tokens=0)),
+    ]
+    out = serving_rounds(recs)
+    assert out["fused"] == {"chunks": 5, "fused": 2}
+    assert out["attended"] == {"programs": 4, "mean": 20}
+    assert ("fused: 2 of 5 admission chunks (40.0%) went through the "
+            "layers inside a decode step's program"
+            ) in render_report(build_report(recs))
+    assert request_waterfall(recs, 7)["prefill_chunks"] == 3
+    # A trace from before the field reports no share.
+    for r in recs:
+        r["fields"].pop("fused", None)
+    assert "fused" not in serving_rounds(recs)
 
 
 def test_report_prints_the_overlapped_share_and_the_median_wait():
