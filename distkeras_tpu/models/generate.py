@@ -35,6 +35,8 @@ from distkeras_tpu.models.transformer import (
     ffn_apply,
     final_norm,
     head_table,
+    latent_kv_b,
+    latent_qkv,
     layer_rotates,
     moe_ffn,
     reject_extended,
@@ -59,6 +61,15 @@ from distkeras_tpu.ops.retention import (
     step_operands,
     use_ret_chunk_kernel,
     use_ret_kernel,
+)
+from distkeras_tpu.ops.latent import (
+    MLA_TAIL_PARTS,
+    mla_decode_attention,
+    mla_decode_block,
+    mla_decode_twin,
+    mla_prefix_attention,
+    use_mla_decode,
+    use_mla_prefix,
 )
 from distkeras_tpu.ops.attention import (
     DECODE_TAIL_PARTS,
@@ -126,6 +137,15 @@ def init_cache(cfg: TransformerConfig, batch: int, dtype=None,
             cache["s"] = jnp.zeros(z[:-1] + (cfg.head_dim,) * 2,
                                    jnp.float32)
             cache["z"] = jnp.zeros(z, jnp.float32)
+        if cfg.latent_planes:
+            # A fourth kind: ``lat``, ONE row a position a latent layer
+            # — the normed latent, the rotated key every head shares,
+            # zeros up to whole lane tiles (``cfg.latent_width``) — with
+            # no heads axis and no V: the values are the row's first
+            # ``kv_lora_rank`` columns.  Slots as a full plane's: a
+            # stale one is masked by position, nothing to clear.
+            cache["lat"] = jnp.zeros((cfg.latent_planes, batch, cfg.max_len,
+                                      cfg.latent_width), dtype)
         return cache
     dtype = jnp.int8 if kv_int8 else (dtype or jnp.dtype(cfg.dtype))
     shape = (cfg.kv_planes, batch, cfg.max_len, cfg.kv_heads, cfg.head_dim)
@@ -545,6 +565,42 @@ def decode_attends_prefix(cfg: TransformerConfig, t_len: int, cache,
                                  cache["k"].dtype, sharded=sharded))
 
 
+def latent_chunk_expands(cfg: TransformerConfig, t_len: int, cache,
+                         rows: int = 1, sharded: bool = False) -> bool:
+    """:func:`chunk_attends_prefix`'s question of a stack of LATENT
+    layers: whether a uniform ``t_len``-token chunk of ``rows`` rows
+    attends in the EXPANDED form through
+    ``ops.latent.mla_prefix_attention`` (one row — an admission — on a
+    TPU, shapes the kernel tiles: a block's keys and values rebuilt in
+    VMEM from the slab's rows where they lie, cost by the attended rows
+    ``[0, pos0 + T)``), or absorbed in the dense body over all
+    ``max_len``.  The serving engines ask it for their admission
+    spans' ``attended`` too."""
+    return (rows == 1 and t_len > 1 and bool(cfg.latent_planes)
+            and use_mla_prefix(
+                t_len, cfg.max_len, cfg.qk_nope_head_dim,
+                cfg.qk_rope_head_dim, cfg.v_head_dim, cfg.latent_width,
+                cfg.kv_lora_rank, cache["lat"].dtype, sharded=sharded))
+
+
+def latent_decode_bounded(cfg: TransformerConfig, cache,
+                          sharded: bool = False) -> bool:
+    """:func:`decode_attends_prefix`'s question of a stack of latent
+    layers: whether a decode step reads each lane's live blocks through
+    ``ops.latent.mla_decode_attention``, or its twin every slot."""
+    return bool(cfg.latent_planes) and use_mla_decode(
+        cfg.n_heads, cfg.max_len, cfg.latent_width, cfg.kv_lora_rank,
+        cache["lat"].dtype, sharded)
+
+
+def latent_read_unit(cfg: TransformerConfig, cache) -> int:
+    """Slots the smallest copy of ``mla_decode_attention`` brings in:
+    a lane at position ``p`` reads ``p`` rounded up to it."""
+    return mla_decode_block(cfg.n_heads, cfg.max_len, cfg.latent_width,
+                            cfg.kv_lora_rank,
+                            cache["lat"].dtype) // MLA_TAIL_PARTS
+
+
 def _decode_heads(cfg: TransformerConfig) -> tuple[int, int]:
     """``(query heads, K/V heads)`` of one row of the per-lane kernel:
     the model's, or — a typed stack's K/V-head-major planes — one K/V
@@ -724,7 +780,23 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
     (``use_ret_chunk_kernel``) — and ``n_real`` keeps
     an admission's padding out of it.  A row at position 0 starts from
     a zero state whatever its plane holds: a stale state is not masked
-    by position as stale slots are."""
+    by position as stale slots are.
+
+    A LATENT layer's plane holds one row a position (``init_cache``'s
+    ``lat``: the latent after its norm, the shared key after its
+    rotation) and no K or V.  One token a row (no ``lane``) attends in
+    the ABSORBED form — ``wkv_b``'s key half folded into the queries,
+    its value half into the output — each lane its live rows straight
+    from the slab, once for scores and values (on the TPU the kernel
+    ``ops.latent.mla_decode_attention``, elsewhere its twin), merged
+    with the token's own row; ``live [B]`` names the rows that decode:
+    every other row reads none of its plane.  One row's chunk on the
+    TPU attends EXPANDED (:func:`latent_chunk_expands`): its rows go
+    into the slab first, where it lies, and ``mla_prefix_attention``
+    rebuilds a block's keys and values in VMEM; every other chunk
+    absorbed in the dense body.  Slots as a full plane's: a
+    stale one is masked by position, padding lands past the frontier,
+    ``n_real`` is not needed."""
     dtype = jnp.dtype(cfg.dtype)
     b, t_len = tokens.shape
     n_layers, s_len = cfg.n_layers, cfg.max_len
@@ -762,7 +834,7 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
                 axis=1)
         rope_ang = None
         if cfg.rope:
-            rope_ang = rope_angles(pos_ids, cfg.head_dim,
+            rope_ang = rope_angles(pos_ids, cfg.rope_dim,
                                    cfg.rope_theta)[:, :, None, :]
         else:
             x = x + params["pos_emb"][pos_ids].astype(dtype)
@@ -952,8 +1024,107 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
             z_all = jax.lax.dynamic_update_slice(z_all, z_new[None], at[:-1])
         return y, (s_all, z_all)
 
+    def attend_latent(q_abs, lat, plane):
+        """A latent layer's attention in the absorbed form: ``q_abs [B,
+        T, H, W]`` float32 (``wkv_b``'s key half folded in, the rotary
+        part beside it) over its plane's rows before the chunk and the
+        chunk's own ``lat [B, T, W]``, one softmax; ``[B, T, H,
+        kv_lora_rank]``: the probabilities over the rows' latent part."""
+        lat_all, vals, w = cache["lat"], cfg.kv_lora_rank, cfg.latent_width
+        f32 = dict(preferred_element_type=jnp.float32)
+        qa = q_abs.astype(lat.dtype)
+        if lane is None and t_len == 1:
+            # A decode step: each lane its live rows straight from the
+            # slab, read once for scores and values (the kernel; off
+            # the TPU its twin), merged with the token's own row by
+            # their log-sum-exps.  A lane that does not decode
+            # (``live``) reads nothing.
+            at = pos0 if live is None else jnp.where(live > 0, pos0, 0)
+            fn = (mla_decode_attention if use_mla_decode(
+                cfg.n_heads, s_len, w, vals, lat.dtype, sharded)
+                else mla_decode_twin)
+            old, lse = fn(qa[:, 0], lat_all, plane, at, scale=lat_scale,
+                          values=vals)
+            own = jnp.einsum("bhw,bw->bh", qa[:, 0], lat[:, 0],
+                             **f32) * lat_scale
+            m = jnp.maximum(lse, own)
+            w_old, p_own = jnp.exp(lse - m), jnp.exp(own - m)
+            out = (w_old[..., None] * old + p_own[..., None]
+                   * lat[:, 0, None, :vals].astype(jnp.float32)
+                   ) / (w_old + p_own)[..., None]
+            return out[:, None]
+        with jax.named_scope("kv_slab"):
+            rows = plane_rows(lat_all, plane)              # [B | 1, S, W]
+        old = jnp.einsum("bthw,bsw->bths", qa, rows, **f32) * lat_scale
+        new = jnp.einsum("bthw,buw->bthu", qa, lat, **f32) * lat_scale
+        probs = jax.nn.softmax(jnp.concatenate(
+            [jnp.where(before[:, :, 0], old, -1e30),
+             jnp.where(causal[:, :, 0], new, -1e30)], axis=-1), axis=-1
+        ).astype(lat.dtype)
+        return (jnp.einsum("bths,bsv->bthv", probs[..., :s_len],
+                           rows[..., :vals], **f32)
+                + jnp.einsum("bthu,buv->bthv", probs[..., s_len:],
+                             lat[..., :vals], **f32))
+
+    lat_expands = (typed and uniform_pos and latent_chunk_expands(
+        cfg, t_len, cache, rows=b, sharded=sharded))
+    if typed and cfg.latent_planes:
+        lat_scale = 1.0 / float(np.sqrt(cfg.qk_nope_head_dim
+                                        + cfg.qk_rope_head_dim))
+
+    def latent_attn(h, lp, plane, slab):
+        """A latent layer's attention sublayer over the normed stream:
+        ``(its output [B, T, D], the chunk's rows [B, T, W] for the
+        plane, the slab)``.  ``slab``: the latent planes where the
+        chunk attends EXPANDED (``lat_expands``: one row's chunk on the
+        TPU) — its rows are then written into the slab here, in place,
+        and the kernel reads the lane's blocks from it, rebuilding
+        their keys and values in VMEM; None on the absorbed paths,
+        which leave the slab read-only and hand the rows to the end."""
+        attn = lp["attn"]
+        with jax.named_scope("attn_proj"):
+            q_nope, q_pe, c, k_pe = latent_qkv(attn, h, rope_ang, cfg)
+            wk, wv = latent_kv_b(attn, cfg)
+            # What is cached: the latent after its norm, the key after
+            # its rotation, rounded to the cache's dtype BEFORE the
+            # chunk attends them.
+            to_width = lambda a: jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [
+                (0, cfg.latent_width - a.shape[-1])])
+            lat = to_width(jnp.concatenate([c, k_pe], axis=-1)
+                           ).astype(cache["lat"].dtype)
+        if slab is not None:
+            row0 = jnp.int32(0) if lane is None else lane
+            with jax.named_scope("kv_slab"):
+                slab = jax.lax.dynamic_update_slice(
+                    slab, lat[None], (plane, row0, pos0[0], jnp.int32(0)))
+            with jax.named_scope("attn"):
+                o = mla_prefix_attention(
+                    q_nope[0], q_pe[0], attn["wkv_b"], slab, plane, row0,
+                    pos0[0], scale=lat_scale,
+                    rank=cfg.kv_lora_rank)[None].astype(dtype)
+            with jax.named_scope("attn_proj"):
+                return jnp.einsum(
+                    "btk,kd->btd", o.reshape(o.shape[:2] + (-1,)),
+                    attn["wo"]), lat, slab
+        with jax.named_scope("attn"):
+            with jax.named_scope("mla_absorb"):
+                q_lat = jnp.einsum("bthn,rhn->bthr", q_nope, wk,
+                                   preferred_element_type=jnp.float32)
+                q_abs = to_width(jnp.concatenate(
+                    [q_lat, q_pe.astype(jnp.float32)], axis=-1))
+            o_lat = attend_latent(q_abs, lat, plane)
+            with jax.named_scope("mla_absorb"):
+                o = jnp.einsum("bthr,rhv->bthv", o_lat.astype(dtype), wv)
+        with jax.named_scope("attn_proj"):
+            return jnp.einsum("btk,kd->btd", o.reshape(o.shape[:2] + (-1,)),
+                              attn["wo"]), lat, None
+
     def layer(x, lp, plane, kind=None, experts=None, state=None):
         h = _rms_norm(x, lp["ln1_scale"], eps) if pre_norm else x
+        if kind is not None and kind[0] == "latent":
+            a, lat, state = latent_attn(h, lp, plane, state)
+            return layer_tail(x, a, lp, kind, experts, state,
+                              () if lat_expands else (lat,))
         with jax.named_scope("attn_proj"):
             if cfg.fused_qkv:
                 q, k, v = split_qkv(
@@ -985,6 +1156,14 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
                                lp["attn"]["wo"])
             else:
                 a = jnp.einsum("bthk,hkd->btd", attn, lp["attn"]["wo"])
+        return layer_tail(x, a, lp, kind, experts, state,
+                          () if kind is not None
+                          and kind[0] == "retention" else (k, v))
+
+    def layer_tail(x, a, lp, kind, experts, state, cached):
+        """What follows a layer's attention ``a``: the residual sums
+        and the feed-forward; ``cached``: what the layer hands to its
+        plane (the scans' outputs)."""
         if cfg.post_norms:
             a = _rms_norm(a, lp["ln1_post_scale"], eps)
         # (The stream keeps the compute dtype whatever the weights'
@@ -1004,9 +1183,8 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
         with jax.named_scope("mlp"):
             x = x + y.astype(dtype)
         if kind is None:
-            return x, (k, v)
-        outs = () if kind[0] == "retention" else (k, v)
-        return (x, state), outs + (() if routes is None else (routes,))
+            return x, cached
+        return (x, state), cached + (() if routes is None else (routes,))
 
     def one_pass(x, r):
         x, kv = jax.lax.scan(
@@ -1015,7 +1193,8 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
         return final_norm(x, params, cfg).astype(dtype), kv
 
     if typed:
-        state = (cache["s"], cache["z"]) if cfg.state_planes else None
+        state = ((cache["s"], cache["z"]) if cfg.state_planes
+                 else cache["lat"] if lat_expands else None)
         return _typed_tail(*_typed_runs(params, x, cfg, layer, state),
                            params, cache, pos0, cfg, uniform_pos, lane,
                            n_real, with_routes)
@@ -1062,11 +1241,13 @@ def _typed_runs(params, x, cfg: TransformerConfig, layer, state=None):
     z)``, carried through every scan beside the stream and updated
     where they lie (None: no such layer).  Returns ``(x, new)``:
     ``new[kind]`` the runs' scan outputs of the attention kinds
-    ``"full"`` and ``"window"`` — ``(k, v)`` — ``new["routes"]`` the
-    sparse runs' routes, each a list of leaves stacked by layer, and
-    ``new["state"]`` the slabs after the last layer."""
-    new = {"full": [], "window": [], "routes": []}
-    planes = {"full": 0, "window": 0, "retention": 0}
+    ``"full"`` and ``"window"`` — ``(k, v)`` — and ``"latent"`` — the
+    rows, unless the layers wrote them into ``state`` (then the latent
+    slab) themselves — ``new["routes"]`` the sparse runs' routes, each
+    a list of leaves stacked by layer, and ``new["state"]`` the slabs
+    after the last layer."""
+    new = {"full": [], "window": [], "latent": [], "routes": []}
+    planes = {"full": 0, "window": 0, "retention": 0, "latent": 0}
     for group, first, count in cfg.layer_runs:
         kind = tuple(group.split("."))
         leaves, heavy = params["layers"][group], None
@@ -1085,7 +1266,10 @@ def _typed_runs(params, x, cfg: TransformerConfig, layer, state=None):
             lambda c, lw: layer(c[0], lw[0], p0 + lw[1], kind,
                                 heavy and heavy + (first + lw[1],), c[1]),
             (x, state), (leaves, jnp.arange(count)))
-        if kind[0] != "retention":
+        if kind[0] == "latent":
+            if len(outs) > (kind[1] == "sparse"):   # not written in place
+                new["latent"].append(outs[0])
+        elif kind[0] != "retention":
             new[kind[0]].append(outs[:2])
         if kind[1] == "sparse":
             new["routes"].append(outs[-1])
@@ -1108,7 +1292,10 @@ def _typed_tail(x, new, params, cache, pos0, cfg: TransformerConfig,
                          head_table(params, cfg).astype(dtype))
     cache = dict(cache)
     if new["state"] is not None:   # written where they lay, layer by layer
-        cache["s"], cache["z"] = new["state"]
+        if cfg.state_planes:
+            cache["s"], cache["z"] = new["state"]
+        else:
+            cache["lat"] = new["state"]
     zero = jnp.int32(0)
     row0 = zero if lane is None else lane
     with jax.named_scope("kv_slab"):
@@ -1148,6 +1335,22 @@ def _typed_tail(x, new, params, cache, pos0, cfg: TransformerConfig,
                             slab, a[:, r:r + 1],
                             (zero, jnp.int32(r), zero, start[r], zero))
                 cache[name] = slab
+        if new["latent"]:
+            # [latent layers, B, T, W] at the rows' positions: a stale
+            # slot is masked by position until it is written, so a new
+            # occupant clears nothing and an admission's padding lands
+            # past the frontier, as in a full plane (where a state says
+            # the opposite on both counts).
+            a, slab = jnp.concatenate(new["latent"], axis=0), cache["lat"]
+            if uniform_pos:
+                slab = jax.lax.dynamic_update_slice(
+                    slab, a, (zero, row0, pos0[0], zero))
+            else:
+                for r in range(b):
+                    slab = jax.lax.dynamic_update_slice(
+                        slab, a[:, r:r + 1],
+                        (zero, jnp.int32(r), pos0[r], zero))
+            cache["lat"] = slab
     if with_routes:
         return (out.astype(jnp.float32), cache,
                 jnp.concatenate(new["routes"], axis=0))

@@ -161,7 +161,8 @@ class TransformerConfig:
     # of one kind is one scan.  A stack whose lists name full attention
     # and the dense feed-forward everywhere is today's stack: the lists
     # are dropped (``__post_init__``) and it compiles today's programs.
-    # ``layer_types``: "window" | "full" | "retention" per layer; a
+    # ``layer_types``: "window" | "full" | "retention" | "latent" per
+    # layer; a
     # window layer attends its last ``sliding_window`` positions (self
     # included) — unlike ``attention_window``, which windows the WHOLE
     # stack and makes the serving lanes roll.  Served, a window layer's
@@ -197,6 +198,30 @@ class TransformerConfig:
     d_head: int | None = None
     qk_norm: bool = False
     norm_eps: float = 1e-6
+    # A LATENT layer (``layer_types`` "latent": multi-head latent
+    # attention): the queries through a low-rank pair ``attn/wq_a [D,
+    # q_lora_rank]`` (an RMSNorm, ``q_a_scale``) and ``wq_b``, to
+    # ``n_heads`` heads of ``qk_nope_head_dim + qk_rope_head_dim``; ONE
+    # joint projection ``wkv_a [D, kv_lora_rank + qk_rope_head_dim]`` to
+    # a latent (an RMSNorm, ``kv_a_scale``) and a rotary key that every
+    # head shares; ``wkv_b [kv_lora_rank, heads * (qk_nope_head_dim +
+    # v_head_dim)]`` rebuilds a head's keys and values from the latent.
+    # The rotation is of the ``qk_rope_head_dim`` columns alone, scores
+    # are over ``1 / sqrt(qk_nope_head_dim + qk_rope_head_dim)``.
+    # Served, the layer's plane holds the latent and the rotated key of
+    # a position — one row of ``latent_width`` values, no heads axis —
+    # and a decode step attends it in the ABSORBED form (``wkv_b``
+    # folded into the queries and the output).  Where the source pairs
+    # the rotary columns ``(2i, 2i + 1)`` (``rope_interleave``), the
+    # tree holds them de-interleaved (evens, then odds) and rotates
+    # halves split as every other layer does — a layout of the
+    # weights, no option: the scores are the same.  Such a stack's
+    # layers are all latent (its feed-forwards may differ).
+    q_lora_rank: int | None = None
+    kv_lora_rank: int | None = None
+    qk_nope_head_dim: int | None = None
+    qk_rope_head_dim: int | None = None
+    v_head_dim: int | None = None
 
     def __post_init__(self):
         # Lists arrive from JSON as lists; a config is a static jit
@@ -277,6 +302,26 @@ class TransformerConfig:
         return self.layer_types.count("retention") if self.typed else 0
 
     @property
+    def latent_planes(self) -> int:
+        """Latent planes of the decode cache: one per latent layer of
+        a typed stack."""
+        return self.layer_types.count("latent") if self.typed else 0
+
+    @property
+    def latent_width(self) -> int:
+        """Values a position holds in a latent plane: the latent and
+        the shared rotary key, rounded up to whole lane tiles of 128
+        (the slab's rows are what the kernels copy; an unpadded row of
+        576 would be stored as 640 by the TPU's tiled layout anyway)."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+    @property
+    def rope_dim(self) -> int:
+        """Columns the rotation turns: the head, or a latent stack's
+        ``qk_rope_head_dim``."""
+        return self.qk_rope_head_dim if self.latent_planes else self.head_dim
+
+    @property
     def head_dim(self) -> int:
         if self.d_head is not None:
             return self.d_head
@@ -311,7 +356,12 @@ _TYPED_KEYS = {"layer_types": None, "ffn_types": None,
                "sliding_window": None, "moe_held": None,
                "moe_d_ff": None, "moe_shared": 0, "moe_route_scale": 1.0,
                "rope_layer_types": None, "d_head": None, "qk_norm": False,
-               "norm_eps": 1e-6}
+               "norm_eps": 1e-6, "q_lora_rank": None, "kv_lora_rank": None,
+               "qk_nope_head_dim": None, "qk_rope_head_dim": None,
+               "v_head_dim": None}
+# A latent layer's shape keys: all of them, or none.
+_LATENT_KEYS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                "qk_rope_head_dim", "v_head_dim")
 
 
 def reject_extended(cfg: "TransformerConfig", path: str,
@@ -327,8 +377,11 @@ def reject_extended(cfg: "TransformerConfig", path: str,
           if getattr(cfg, k) != base and k not in allow]
     on += [k for k, base in _TYPED_KEYS.items() if getattr(cfg, k) != base]
     if cfg.n_passes > 1 or on:
-        if cfg.state_planes:   # the kind no other path knows, by name
-            on[on.index("layer_types")] = "layer_types: 'retention'"
+        # The kinds no other path knows, by name.
+        for planes, kind in ((cfg.state_planes, "retention"),
+                             (cfg.latent_planes, "latent")):
+            if planes:
+                on[on.index("layer_types")] = f"layer_types: '{kind}'"
         what = (f"a looped stack (n_passes={cfg.n_passes})"
                 if cfg.n_passes > 1 else
                 f"the extended block ({' / '.join(on)})")
@@ -420,6 +473,10 @@ def init_params(rng, cfg: TransformerConfig):
             f"{cfg.post_norms!r}")
     if cfg.typed:
         _validate_typed(cfg)
+    elif any(getattr(cfg, k) is not None for k in _LATENT_KEYS):
+        raise ValueError(
+            f"{_LATENT_KEYS} shape a latent layer: name the layers "
+            "(layer_types: 'latent')")
     elif cfg.num_experts:
         reject_extended(cfg, "a MoE feed-forward (num_experts > 0) "
                         "without ffn_types (the capacity dispatch)")
@@ -428,7 +485,7 @@ def init_params(rng, cfg: TransformerConfig):
     d, f, h, hd = cfg.d_model, cfg.d_ff, cfg.n_heads, cfg.head_dim
     kv = cfg.kv_heads
 
-    def group(gkey, L, sparse=None, retention=False):
+    def group(gkey, L, sparse=None, retention=False, latent=False):
         """``L`` layers of one kind, stacked on a leading axis; the
         whole stack where the layers do not differ (``sparse`` None:
         the old capacity-dispatch experts where ``num_experts``)."""
@@ -437,7 +494,27 @@ def init_params(rng, cfg: TransformerConfig):
         def stack(key, shape, fan_in):
             return _dense_init(key, (L, *shape), fan_in)
 
-        layers = {"attn": {
+        def latent_leaves():
+            rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+            dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                          cfg.v_head_dim)
+            # The two inner norms' scales are drawn (1 +- 0.25; a
+            # trained model's are learnt), so that leaving a norm out
+            # of a computation shows: at 1 it would hardly, the
+            # projections of a normed stream being near unit size.
+            scale = lambda key, n: 1.0 + 0.25 * jax.random.normal(
+                key, (L, n), jnp.float32)
+            return {
+                "wq_a": stack(gk[0], (d, rq), d),
+                "q_a_scale": scale(jax.random.fold_in(gk[0], 1), rq),
+                "wq_b": stack(gk[1], (rq, h * (dn + dr)), rq),
+                "wkv_a": stack(gk[2], (d, rkv + dr), d),
+                "kv_a_scale": scale(jax.random.fold_in(gk[2], 1), rkv),
+                "wkv_b": stack(jax.random.fold_in(gk[2], 2),
+                               (rkv, h * (dn + dv)), rkv),
+                "wo": stack(gk[3], (h * dv, d), h * dv)}
+
+        layers = {"attn": latent_leaves() if latent else {
             "wq": stack(gk[0], (d, h, hd), d),
             "wk": stack(gk[1], (d, kv, hd), d),
             "wv": stack(gk[2], (d, kv, hd), d),
@@ -446,7 +523,7 @@ def init_params(rng, cfg: TransformerConfig):
         if cfg.post_norms != "only":
             layers["ln1_scale"] = jnp.ones((L, d))
             layers["ln2_scale"] = jnp.ones((L, d))
-        if cfg.fused_qkv:  # the same draws, laid out as matrices
+        if cfg.fused_qkv and not latent:  # the same draws, as matrices
             a = layers["attn"]
             layers["attn"] = {
                 "wqkv": jnp.concatenate(
@@ -517,7 +594,8 @@ def init_params(rng, cfg: TransformerConfig):
             ".".join(kind): group(
                 jax.random.fold_in(keys[0], i), kinds.count(kind),
                 sparse=kind[1] == "sparse",
-                retention=kind[0] == "retention")
+                retention=kind[0] == "retention",
+                latent=kind[0] == "latent")
             for i, kind in enumerate(dict.fromkeys(kinds))}
     else:
         layers = group(None, cfg.n_layers)
@@ -548,7 +626,8 @@ def init_params(rng, cfg: TransformerConfig):
 def _validate_typed(cfg: "TransformerConfig") -> None:
     """What a typed stack's keys have to say together."""
     n = cfg.n_layers
-    for name, kinds in (("layer_types", ("window", "full", "retention")),
+    for name, kinds in (("layer_types", ("window", "full", "retention",
+                                         "latent")),
                         ("ffn_types", ("dense", "sparse"))):
         got = getattr(cfg, name)
         if len(got) != n or set(got) - set(kinds):
@@ -573,6 +652,23 @@ def _validate_typed(cfg: "TransformerConfig") -> None:
             "retention layers need an even head_dim and at most 5 query "
             f"heads a K/V head, got head_dim={cfg.head_dim}, n_heads="
             f"{cfg.n_heads}, n_kv_heads={cfg.kv_heads}")
+    given = [k for k in _LATENT_KEYS if getattr(cfg, k) is not None]
+    if "latent" in cfg.layer_types:
+        if (set(cfg.layer_types) != {"latent"} or len(given) != len(
+                _LATENT_KEYS) or not cfg.rope or cfg.qk_rope_head_dim % 2
+                or cfg.post_norms or cfg.qk_norm
+                or cfg.rope_layer_types is not None):
+            raise ValueError(
+                "latent layers (layer_types: 'latent') make a stack of "
+                f"their own, with every one of {_LATENT_KEYS} given, "
+                "rope=True over an even qk_rope_head_dim, and no "
+                "post_norms, qk_norm or rope_layer_types, got "
+                f"layer_types={cfg.layer_types}, "
+                + ", ".join(f"{k}={getattr(cfg, k)}" for k in _LATENT_KEYS))
+    elif given:
+        raise ValueError(
+            f"{given} shape a latent layer (layer_types: 'latent'); the "
+            "stack has none")
     if "sparse" in cfg.ffn_types:
         held = cfg.experts_held
         if not (1 <= cfg.moe_top_k <= cfg.num_experts) or not held or (
@@ -650,8 +746,10 @@ def _resolve_attention_fn(cfg: "TransformerConfig", attention_fn,
             q, k, v, True, window=window)
         # A retention layer's attention needs the layer's gate, which
         # no ``fn(q, k, v)`` takes: ``_attention_block`` runs it.
+        # (Nor a latent layer's, whose heads are rebuilt from a latent:
+        # ``latent_attention``.)
         return {"window": by_window(cfg.sliding_window),
-                "full": by_window(None), "retention": None}
+                "full": by_window(None), "retention": None, "latent": None}
     if attention_fn is None:
         return lambda q, k, v: flash_attention(
             q, k, v, True, window=cfg.attention_window,
@@ -730,6 +828,13 @@ MOE_SCOPES = ("moe_route", "moe_experts", "moe_shared")
 # ret_chunk (a chunk's own pairs and its query of the state before it;
 # on the TPU the kernel ``ret_chunk_fwd``, the new state included).
 RET_SCOPES = ("ret_gate", "ret_state", "ret_chunk")
+# In a latent layer: under ``attn_proj``, mla_q (the low-rank query
+# pair, its norm, the rotation) and mla_kv (the joint projection, the
+# latent's norm, the shared key's rotation); under ``attn``, mla_absorb
+# (``wkv_b`` folded into the queries and, after the attention, into its
+# output — the served path; the kernel ``mla_decode_fwd`` sits in
+# ``attn`` beside it).
+MLA_SCOPES = ("mla_q", "mla_kv", "mla_absorb")
 
 
 def _rms_norm(x, scale, eps=1e-6, scope="norm"):
@@ -782,6 +887,65 @@ def _attention_block(lp, x, attention_fn, rope_ang=None, kv_groups=1,
     with jax.named_scope("attn_proj"):
         out = jnp.einsum("bshk,hkd->bsd", out, lp["wo"])
     return (out, kv) if return_kv else out
+
+
+def latent_qkv(attn, h, rope_ang, cfg: TransformerConfig):
+    """A latent layer's projections of the normed stream ``h [B, T,
+    D]``: ``(q_nope [B, T, H, nope], q_pe [B, T, H, rope], c [B, T,
+    kv_lora_rank], k_pe [B, T, rope])`` — the queries through their
+    low-rank pair, the latent after its norm, the shared rotary key;
+    ``q_pe`` and ``k_pe`` rotated by ``rope_ang [B | 1, T, 1,
+    rope / 2]``, halves split: the tree holds the rotary columns of
+    ``wq_b`` and ``wkv_a`` DE-INTERLEAVED (evens, then odds) where the
+    source pairs ``(2i, 2i + 1)`` — a layout of the weights: the
+    scores are the same.  ONE definition for the expanded form
+    (:func:`latent_attention`) and the served, absorbed one
+    (``generate._chunk_in_place``)."""
+    eps = cfg.norm_eps
+    dn, dr, rkv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
+    with jax.named_scope("mla_q"):
+        cq = _rms_norm(jnp.einsum("btd,dr->btr", h, attn["wq_a"]),
+                       attn["q_a_scale"], eps)
+        q = jnp.einsum("btr,rk->btk", cq, attn["wq_b"])
+        q = q.reshape(q.shape[:2] + (cfg.n_heads, dn + dr))
+        q_nope, q_pe = q[..., :dn], rope_rotate(q[..., dn:], rope_ang)
+    with jax.named_scope("mla_kv"):
+        ckv = jnp.einsum("btd,dr->btr", h, attn["wkv_a"])
+        c = _rms_norm(ckv[..., :rkv], attn["kv_a_scale"], eps)
+        k_pe = rope_rotate(ckv[..., rkv:], rope_ang[:, :, 0])
+    return q_nope, q_pe, c, k_pe
+
+
+def latent_kv_b(attn, cfg: TransformerConfig):
+    """``wkv_b`` with the heads split out: ``(keys' [kv_lora_rank, H,
+    nope], values' [kv_lora_rank, H, v])``."""
+    w = attn["wkv_b"].reshape(cfg.kv_lora_rank, cfg.n_heads, -1)
+    return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+
+
+def latent_attention(attn, h, rope_ang, cfg: TransformerConfig):
+    """A latent layer's attention over ``h [B, T, D]`` in the EXPANDED
+    form, no cache: every head's keys and values rebuilt from the
+    latent, the shared rotary key beside each head's, causal softmax
+    in float32 over the materialised scores (the no-cache path; served,
+    the layer keeps the latent and attends it absorbed).  ``[B, T, H *
+    v_head_dim]`` before the output projection."""
+    with jax.named_scope("attn_proj"):
+        q_nope, q_pe, c, k_pe = latent_qkv(attn, h, rope_ang, cfg)
+        wk, wv = latent_kv_b(attn, cfg)
+        with jax.named_scope("mla_kv"):
+            k_nope = jnp.einsum("bsr,rhn->bshn", c, wk)
+            v = jnp.einsum("bsr,rhv->bshv", c, wv)
+    with jax.named_scope("attn"):
+        f32 = dict(preferred_element_type=jnp.float32)
+        score = (jnp.einsum("bthn,bshn->bhts", q_nope, k_nope, **f32)
+                 + jnp.einsum("bthr,bsr->bhts", q_pe, k_pe, **f32)
+                 ) / math.sqrt(q_nope.shape[-1] + q_pe.shape[-1])
+        t = h.shape[1]
+        score = jnp.where(jnp.tril(jnp.ones((t, t), bool)), score, -1e30)
+        out = jnp.einsum("bhts,bshv->bthv",
+                         jax.nn.softmax(score, axis=-1).astype(v.dtype), v)
+    return out.reshape(out.shape[:2] + (-1,))
 
 
 def _moe_gates(probs, cfg: TransformerConfig):
@@ -1055,16 +1219,22 @@ def block_apply(layer_params, x, cfg: TransformerConfig,
     eps, pre = cfg.norm_eps, cfg.post_norms != "only"
     h = _rms_norm(x, layer_params["ln1_scale"], eps) if pre else x
     attn_w = layer_params["attn"]
-    if cfg.fused_qkv:  # matrices (init_params): heads split out here
-        wq, wk, wv = split_qkv(attn_w["wqkv"], cfg)
-        attn_w = {**attn_w, "wq": wq, "wk": wk, "wv": wv,
-                  "wo": attn_w["wo"].reshape(-1, cfg.head_dim, cfg.d_model)}
-    a = _attention_block(attn_w, h, attention_fn, rope_ang,
-                         kv_groups=cfg.n_heads // cfg.kv_heads,
-                         return_kv=return_kv, eps=eps)
     kv = None
-    if return_kv:
-        a, kv = a
+    if kind is not None and kind[0] == "latent":
+        a = latent_attention(attn_w, h, rope_ang, cfg)
+        with jax.named_scope("attn_proj"):
+            a = jnp.einsum("btk,kd->btd", a, attn_w["wo"])
+    else:
+        if cfg.fused_qkv:  # matrices (init_params): heads split out here
+            wq, wk, wv = split_qkv(attn_w["wqkv"], cfg)
+            attn_w = {**attn_w, "wq": wq, "wk": wk, "wv": wv,
+                      "wo": attn_w["wo"].reshape(-1, cfg.head_dim,
+                                                 cfg.d_model)}
+        a = _attention_block(attn_w, h, attention_fn, rope_ang,
+                             kv_groups=cfg.n_heads // cfg.kv_heads,
+                             return_kv=return_kv, eps=eps)
+        if return_kv:
+            a, kv = a
     if cfg.post_norms:
         a = _rms_norm(a, layer_params["ln1_post_scale"], eps)
     # The residual sums (and the dropout before them) go to the
@@ -1108,7 +1278,7 @@ def _trunk(params, tokens, cfg: TransformerConfig,
         x = params["tok_emb"][tokens].astype(dtype)
         rope_ang = None
         if cfg.rope:
-            rope_ang = rope_angles(jnp.arange(s), cfg.head_dim,
+            rope_ang = rope_angles(jnp.arange(s), cfg.rope_dim,
                                    cfg.rope_theta)[None, :, None, :]
         else:
             x = x + params["pos_emb"][:s][None].astype(dtype)

@@ -298,6 +298,7 @@ def serving_kv_axis(plan, mesh: Mesh, cfg) -> str | None:
     a head count the axis cannot split would otherwise surface as an
     inscrutable GSPMD error at first trace.
     """
+    _reject_latent_plane(getattr(cfg, "latent_planes", 0))
     axis, culprit = None, None
     for path, head_dim, attr in _ATTN_HEAD_PATHS:
         for pat, spec in plan.rules:
@@ -345,6 +346,20 @@ def serving_kv_axis(plan, mesh: Mesh, cfg) -> str | None:
     return axis
 
 
+def _reject_latent_plane(found) -> None:
+    """A latent plane (``lat [planes, lanes, max_len, latent_width]``:
+    one row a position that every head reads) has no kv-heads
+    dimension to shard: placed by the rule below it would be cut over
+    its POSITIONS.  Said by name, not mis-placed."""
+    if found:
+        raise ValueError(
+            "a latent plane (layer_types: 'latent', the cache leaf "
+            "'lat') has no kv-heads dimension: serving_kv_axis / "
+            "kv_slab_specs place a cache by that dimension and cannot "
+            "place it (tensor-parallel heads over a cache with no heads "
+            "axis is not built)")
+
+
 def kv_slab_specs(tree, axis: str | None):
     """PartitionSpecs for a KV cache / paged block slab / prefix-pool
     slab: the kv-heads dimension shards over ``axis``, everything else
@@ -355,6 +370,7 @@ def kv_slab_specs(tree, axis: str | None):
     replicates everything (the pure-FSDP serving layout)."""
     def leaf(path, a):
         ndim = getattr(a, "ndim", len(a.shape))
+        _reject_latent_plane(leaf_name(path) == "lat")
         if axis is None:
             return P()
         hd = ndim - 1 if leaf_name(path).endswith("scale") else ndim - 2

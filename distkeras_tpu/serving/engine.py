@@ -27,7 +27,10 @@ from distkeras_tpu import obs
 from distkeras_tpu.models.generate import (_decode_chunk,
                                            chunk_attends_prefix,
                                            decode_attends_prefix,
-                                           decode_read_unit)
+                                           decode_read_unit,
+                                           latent_chunk_expands,
+                                           latent_decode_bounded,
+                                           latent_read_unit)
 from distkeras_tpu.serving.admission import _AdmissionMixin
 from distkeras_tpu.serving.elastic import _ElasticMixin
 
@@ -259,12 +262,15 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
         (``chunk_attends_prefix``, the question ``_decode_chunk``
         itself asks), ``max_len`` where it keeps the dense body.  Host
         integers known at dispatch; no device read."""
-        if not (self.cfg.kv_planes or self.cfg.kv_ring_planes):
+        cfg = self.cfg
+        if not (cfg.kv_planes or cfg.kv_ring_planes or cfg.latent_planes):
             return 0        # states only: no cache position is read
         sharded = self.mesh is not None and self.mesh.size > 1
-        if chunk_attends_prefix(self.cfg, width, cache, sharded=sharded):
+        bounded = (latent_chunk_expands if cfg.latent_planes
+                   else chunk_attends_prefix)
+        if bounded(cfg, width, cache, sharded=sharded):
             return start + width
-        return self.cfg.max_len
+        return cfg.max_len
 
     def _step_attended(self, n: int) -> int:
         """Cache slots the decode program's attention reads in a round
@@ -275,21 +281,31 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
         rounded up to the kernel's smallest copy; a free, done or admitting
         lane is counted as parked at ``max_len - 1``, its whole row (a
         done lane gets there a step at a time: an upper bound).  On
-        the dense path every lane reads ``max_len`` slots.  Host
+        the dense path every lane reads ``max_len`` slots.  Latent
+        planes (``ops.latent.mla_decode_attention``): a decoding lane's
+        position rounded up to that kernel's smallest copy, and NOTHING
+        for a lane that does not decode (the step's ``live`` mask).  Host
         integers from the lane table (the steps dispatched so far, so
         an unread round counts); no device read."""
-        cap = self.cfg.max_len
-        if not (self.cfg.kv_planes or self.cfg.kv_ring_planes):
+        cfg, cap = self.cfg, self.cfg.max_len
+        if not (cfg.kv_planes or cfg.kv_ring_planes or cfg.latent_planes):
             return 0
         sharded = self.mesh is not None and self.mesh.size > 1
-        if not decode_attends_prefix(self.cfg, 1, self.cache,
-                                     sharded=sharded):
+        # Latent planes: a lane that does not decode reads nothing (the
+        # step's ``live`` mask), on the kernel's path and its twin's.
+        latent = bool(cfg.latent_planes)
+        if latent:
+            unit = (latent_read_unit(cfg, self.cache)
+                    if latent_decode_bounded(cfg, self.cache, sharded)
+                    else cap)
+        elif not decode_attends_prefix(cfg, 1, self.cache, sharded=sharded):
             return n * len(self._lane_state) * cap
-        unit = decode_read_unit(self.cfg, 1, self.cache)
+        else:
+            unit = decode_read_unit(cfg, 1, self.cache)
         total = 0
         for st in self._lane_state:
             if st is None or st.done or st.chunks is not None:
-                total += n * cap
+                total += 0 if latent else n * cap
                 continue
             pos = st.off + st.prompt_len - 1 + st.launched
             total += sum(min(-(-(pos + j) // unit) * unit, cap)

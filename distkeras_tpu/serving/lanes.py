@@ -193,6 +193,16 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
     neither adds to a state nor decays it (``n_real``; a chunked tail
     stays on the grid, as with ring planes).
 
+    **Latent planes** (a stack of latent layers, ``layer_types``
+    ``"latent"``): a lane's plane holds ONE row a position — the
+    latent and the rotary key every head shares, ``latent_width``
+    values, no K/V-head dimension (``serving.kv_layout``'s
+    ``planes_latent``, ``bytes_per_slot_latent``).  Slots like a full
+    plane's: stale ones are masked by position, a new occupant clears
+    nothing, an admission's padding lands past the frontier.  The
+    decode step takes the ``live`` mask as with state planes, so that
+    a lane which does not decode reads none of its row.
+
     **Live weight push** (round 20, ``hot_swap=True``): every decode
     and admission program takes the param tree as an explicit jit
     argument (never donated), so :meth:`swap_params` can replace the
@@ -632,6 +642,12 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
             # and "" say the stack has none.
             state = ([self.cache["s"], self.cache["z"]]
                      if cfg.state_planes else [])
+            # A fourth kind where the layers are latent: one row of
+            # ``latent_width`` values a position a layer (the latent,
+            # the shared rotary key, zeros up to whole lane tiles), at
+            # every live position like a full plane's slot; 0 planes
+            # and width 0 say the stack has none.
+            lat = self.cache.get("lat")
             obs.event("serving.kv_layout", passes=cfg.n_passes,
                       layers=cfg.n_layers, planes=cfg.kv_planes,
                       bytes_per_slot=slab // slots, slots=slots,
@@ -644,7 +660,11 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
                       planes_state=cfg.state_planes,
                       state_bytes_per_lane=sum(
                           int(a.nbytes) for a in state) // self.lanes,
-                      state_dtype=str(state[0].dtype) if state else "")
+                      state_dtype=str(state[0].dtype) if state else "",
+                      planes_latent=cfg.latent_planes,
+                      latent_width=0 if lat is None else int(lat.shape[-1]),
+                      bytes_per_slot_latent=0 if lat is None else int(
+                          lat.nbytes) // slots)
             return
         # [planes, lanes, max_len, ...] (the paged store: [planes,
         # blocks, block, ...]): slots are rows x positions.
@@ -1593,10 +1613,13 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
         that does not decode is a parked write, masked by position; a
         state has no positions, and a step on an ADMITTING lane would
         decay and add to the very state its chunks are building — so
-        the step is told, and leaves the others' state unread.
+        the step is told, and leaves the others' state unread.  A
+        stack of latent layers takes the mask too: a parked lane stands
+        at ``max_len - 1``, and its step would read the whole row for
+        nothing — told, the kernel reads none of it.
         Nothing for every other engine: its programs keep their
         signature."""
-        if not self.cfg.state_planes:
+        if not (self.cfg.state_planes or self.cfg.latent_planes):
             return ()
         mask = np.zeros((len(self._lane_state),), np.int32)
         mask[[i for i, _ in self._decoding()]] = 1

@@ -194,21 +194,55 @@ def test_typed_programs_keep_their_names_and_hold_the_moe_scopes(params):
 # ------------------------------------------------ (c) the shares add up
 
 
-def _one_sparse_layer(p):
-    return jax.tree.map(lambda a: a[0], p["layers"]["full.sparse"])
+def _one_sparse_layer(p, group="full.sparse"):
+    return jax.tree.map(lambda a: a[0], p["layers"][group])
 
 
-def test_the_shares_add_up_to_the_uncut_layer(ref):
-    """Four chips of four experts each: the routed parts that the four
-    shares compute, with the shared expert counted once, are the uncut
-    reference's layer.  A share's weights are the whole model's for
-    its experts (``init_params`` draws an expert by its id)."""
-    whole_tc = {**TC, "moe_held": None}
-    whole = _one_sparse_layer(toy_params(tfm.TransformerConfig(**whole_tc)))
-    mine = _one_sparse_layer(toy_params(CFG))["moe"]
+# The stack above, and a latent + sparse stack at the router's width of
+# the latent cell (benchmarks/configs/joyai-llm-flash_l10-ep8.json): 256
+# experts in 8 shares of 32, top-8.
+LATENT_TC = dict(vocab_size=96, d_model=64, n_heads=4, n_layers=2, d_ff=96,
+                 max_len=64, rope=True, dtype="float32", ffn_gated=True,
+                 tie_head=False, layer_types=["latent"] * 2,
+                 ffn_types=["dense", "sparse"], q_lora_rank=48,
+                 kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+                 v_head_dim=16, num_experts=256,
+                 moe_top_k=8, moe_held=list(range(32)), moe_d_ff=16,
+                 moe_shared=1, moe_route_scale=2.5)
+
+
+@pytest.fixture(scope="module")
+def ref_joyai():
+    import importlib
+    import sys
+
+    sys.path.insert(0, os.path.join(REPO, "benchmarks"))
+    try:
+        return importlib.import_module("reference_joyai")
+    finally:
+        sys.path.remove(os.path.join(REPO, "benchmarks"))
+
+
+@pytest.mark.parametrize("model,tc,group,shares", [
+    ("kexaone", TC, "full.sparse", 4),
+    ("joyai", LATENT_TC, "latent.sparse", 8)])
+def test_the_shares_add_up_to_the_uncut_layer(request, model, tc, group,
+                                              shares):
+    """``shares`` chips of as many experts each: the routed parts that
+    the shares compute, with the shared expert counted once, are the
+    uncut reference's layer.  A share's weights are the whole model's
+    for its experts (``init_params`` draws an expert by its id)."""
+    ref = request.getfixturevalue("ref" if model == "kexaone"
+                                  else "ref_joyai")
+    cfg0 = tfm.TransformerConfig(**tc)
+    n = cfg0.num_experts // shares
+    whole_tc = {**tc, "moe_held": None}
+    whole = _one_sparse_layer(
+        toy_params(tfm.TransformerConfig(**whole_tc)), group)
+    mine = _one_sparse_layer(toy_params(cfg0), group)["moe"]
     for name in ("w13", "w2"):
         np.testing.assert_array_equal(mine[name],
-                                      np.asarray(whole["moe"][name])[:4])
+                                      np.asarray(whole["moe"][name])[:n])
     h = np.random.default_rng(5).normal(size=(23, 64)).astype(np.float32)
 
     @functools.partial(jax.jit, static_argnames="cfg")
@@ -218,21 +252,21 @@ def test_the_shares_add_up_to_the_uncut_layer(ref):
         return part, local, expert, tfm.moe_ffn(lp, h, cfg)
 
     total = np.zeros_like(h)
-    for chip in range(4):
-        held = list(range(4 * chip, 4 * chip + 4))
-        cfg = dataclasses.replace(CFG, moe_held=tuple(held))
+    for chip in range(shares):
+        held = list(range(n * chip, n * chip + n))
+        cfg = dataclasses.replace(cfg0, moe_held=tuple(held))
         lp = {**whole, "moe": {**whole["moe"], **{
             name: whole["moe"][name][np.asarray(held)]
             for name in ("w13", "w2")}}}
         part, local, expert, alone = share(lp, jnp.asarray(h), cfg)
-        assert ((np.asarray(local) < 4)
+        assert ((np.asarray(local) < n)
                 == np.isin(np.asarray(expert), held)).all()
         total += np.asarray(part)
         # ... and a share alone is the reference given the same share
         np.testing.assert_allclose(
             np.asarray(alone),
-            ref.sparse_layer(lp, {**TC, "moe_held": held}, h), atol=TOL)
-    shared = np.asarray(tfm.ffn_apply(whole["shared"], jnp.asarray(h), CFG))
+            ref.sparse_layer(lp, {**tc, "moe_held": held}, h), atol=TOL)
+    shared = np.asarray(tfm.ffn_apply(whole["shared"], jnp.asarray(h), cfg0))
     np.testing.assert_allclose(total + shared,
                                ref.sparse_layer(whole, whole_tc, h),
                                atol=TOL)

@@ -620,6 +620,10 @@ def test_state_planes_are_named_in_the_layout_and_the_rounds(retention_run,
     per_lane = 2 * 2 * (9 * 16 * 16 + 9 * 16) * 4     # layers, heads, s + z
     assert (layout["planes_state"], layout["state_bytes_per_lane"],
             layout["state_dtype"]) == (2, per_lane, "float32")
+    # ... and of latent planes that it has none (tests/test_latent.py
+    # pins a latent stack's fields, kernels and scopes)
+    assert (layout["planes_latent"], layout["latent_width"],
+            layout["bytes_per_slot_latent"]) == (0, 0, 0)
     mine = [r["fields"] for r in records
             if r.get("name") == "serving.round"]
     assert [r["state_lanes"] for r in mine][:3] == [1, 1, 2]
