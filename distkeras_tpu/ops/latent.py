@@ -272,6 +272,14 @@ def mla_prefix_twin(q_nope, q_pe, wkv_b, lat_all, plane, lane, off,
                       **f32)
 
 
+# Heads a step of the chunk kernel's head loop (scripts/sweep_mla.py
+# chunk: a 512-row chunk at P = 4,096 / 10,240 / 30,208 took 0.98 /
+# 2.17 / 6.05 ms one head at a time, 0.81 / 1.78 / 4.93 two, 0.73 /
+# 1.57 / 4.34 four, 0.70 / 1.49 / 4.05 eight — eight at 1.6x four's
+# code and 2x its compile time; chip, PR 38).
+MLA_PREFIX_HEAD_GROUP = 4
+
+
 def _mla_prefix_kernel(plane_ref, lane_ref, off_ref, qn_ref, qp_ref, w_ref,
                        lat_ref, o_ref, m_scr, l_scr, acc_scr, *, scale: float,
                        rank: int):
@@ -286,12 +294,21 @@ def _mla_prefix_kernel(plane_ref, lane_ref, off_ref, qn_ref, qp_ref, w_ref,
     rows' shared key (the columns after the latent, zero past it);
     online softmax a head in float32 (``ops.attention.
     _flash_prefix_kernel``'s arithmetic, its mask, its dead blocks
-    predicated away)."""
+    predicated away).
+
+    The heads go ``MLA_PREFIX_HEAD_GROUP`` at a time, every product of
+    a group issued before its first softmax, so one head's vector work
+    runs under the next heads' products (a head's chain alone leaves the
+    MXU idle through its softmax); heads past a whole number of groups
+    go one at a time."""
     heads, block_q, nope = qn_ref.shape
     block_k = lat_ref.shape[0]
+    group = min(MLA_PREFIX_HEAD_GROUP, heads)
     j = pl.program_id(1)
     row0 = off_ref[0] + pl.program_id(0) * block_q
     col0 = j * block_k
+    f32 = dict(preferred_element_type=jnp.float32)
+    nn, nt = (((1,), (0,)), ((), ())), (((1,), (1,)), ((), ()))
 
     @pl.when(j == 0)
     def _init():
@@ -301,23 +318,21 @@ def _mla_prefix_kernel(plane_ref, lane_ref, off_ref, qn_ref, qp_ref, w_ref,
 
     @pl.when(col0 <= row0 + block_q - 1)
     def _update():
+        # What every head shares, once a block.
         c = lat_ref[:, :rank]
         k_pe = lat_ref[:, rank:rank + qp_ref.shape[2]]
         rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
         cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
         keep = row0 + rows >= col0 + cols
-        nt = (((1,), (1,)), ((), ()))
 
-        def head(h, carry):
-            kv = jax.lax.dot_general(
-                c, w_ref[h], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32).astype(c.dtype)
-            logits = (jax.lax.dot_general(
-                qn_ref[h], kv[:, :nope], nt,
-                preferred_element_type=jnp.float32)
-                + jax.lax.dot_general(
-                    qp_ref[h], k_pe, nt,
-                    preferred_element_type=jnp.float32)) * scale
+        def products(h):          # the MXU's: rebuild, then the scores
+            kv = jax.lax.dot_general(c, w_ref[h], nn, **f32).astype(c.dtype)
+            logits = (jax.lax.dot_general(qn_ref[h], kv[:, :nope], nt, **f32)
+                      + jax.lax.dot_general(qp_ref[h], k_pe, nt, **f32)
+                      ) * scale
+            return logits, kv[:, nope:]
+
+        def softmax(h, logits, v):
             logits = jnp.where(keep, logits, NEG_INF)
             m = m_scr[h][:, :1]
             m_new = jnp.maximum(m, logits.max(axis=-1, keepdims=True))
@@ -325,13 +340,23 @@ def _mla_prefix_kernel(plane_ref, lane_ref, off_ref, qn_ref, qp_ref, w_ref,
             p = jnp.exp(logits - m_new)
             l_new = l_scr[h][:, :1] * corr + p.sum(axis=-1, keepdims=True)
             acc_scr[h] = acc_scr[h] * corr + jax.lax.dot_general(
-                p.astype(c.dtype), kv[:, nope:], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+                p.astype(v.dtype), v, nn, **f32)
             m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
             l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+
+        def heads_from(h0, n):
+            made = [products(h0 + u) for u in range(n)]
+            for u, (logits, v) in enumerate(made):
+                softmax(h0 + u, logits, v)
+
+        def step(g, carry):
+            heads_from(g * group, group)
             return carry
 
-        jax.lax.fori_loop(0, heads, head, 0)
+        whole = heads // group
+        jax.lax.fori_loop(0, whole, step, 0)
+        for h in range(whole * group, heads):
+            heads_from(h, 1)
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _finish():

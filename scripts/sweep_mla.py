@@ -1,8 +1,10 @@
 """Latent attention's two served paths alone, on the chip, at the
 latent cell's shapes (32 heads, rows of 640 = 512 latent + 64 rotary +
-64 zeros, bf16).  One JSON line a group; TPU only.
+64 zeros, bf16).  JSON lines; TPU only.
 
-    python scripts/sweep_mla.py [--iters N] [P ...]
+    python scripts/sweep_mla.py [--iters N] [decode|chunk] [P ...]
+
+Both groups run unless one is named.
 
 ``decode``: ``ops/latent.py::mla_decode_attention`` held to its
 ``jax.numpy`` twin on random rows (lanes at positions 0, 1, a block's
@@ -25,7 +27,8 @@ alternatives, built here alone to be timed beside it —
     K/V heads;
 ``kernel``: ``ops/latent.py::mla_prefix_attention`` — the expanded form
     with a block's keys and values rebuilt in VMEM, the rows read from
-    the slab where they lie.
+    the slab where they lie — its line says the heads a step of its
+    head loop (``MLA_PREFIX_HEAD_GROUP``).
 Host clock around ``iters`` calls of one jitted program each.
 """
 
@@ -42,7 +45,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from distkeras_tpu.ops.attention import flash_prefix_attention
-from distkeras_tpu.ops.latent import (mla_decode_attention, mla_decode_twin,
+from distkeras_tpu.ops.latent import (MLA_PREFIX_HEAD_GROUP,
+                                      mla_decode_attention, mla_decode_twin,
                                       mla_prefix_attention)
 
 H, W, RANK, ROPE, NOPE, V = 32, 640, 512, 64, 128, 128
@@ -152,7 +156,7 @@ def chunk(positions, iters):
         a, e = absorbed(plane, off), expanded(plane, off)
         k = kernel(plane, off)[None]
         print(json.dumps({
-            "chunk_prefix": p,
+            "chunk_prefix": p, "group": MLA_PREFIX_HEAD_GROUP,
             "kernel_ms": 1e3 * timed(kernel, plane, off, iters=iters),
             "kernel_differs_by": float(jnp.abs(
                 k.astype(jnp.float32) - e.astype(jnp.float32)).max()),
@@ -171,9 +175,14 @@ def main(argv):
     iters = 10
     if argv[:1] == ["--iters"]:
         iters, argv = int(argv[1]), argv[2:]
+    which = {"decode", "chunk"}
+    if argv[:1] in (["decode"], ["chunk"]):
+        which, argv = {argv[0]}, argv[1:]
     positions = [int(a) for a in argv] or [4096, 10240, 30208]
-    decode(positions, iters)
-    chunk(positions, iters)
+    if "decode" in which:
+        decode(positions, iters)
+    if "chunk" in which:
+        chunk(positions, iters)
 
 
 if __name__ == "__main__":

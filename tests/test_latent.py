@@ -209,6 +209,39 @@ def test_mla_decode_kernel_equals_its_twin(block_k):
         assert (np.asarray(lse)[[0, 5]] == mla.NEG_INF).all()
 
 
+@pytest.mark.parametrize("heads", [
+    2 * mla.MLA_PREFIX_HEAD_GROUP, 2 * mla.MLA_PREFIX_HEAD_GROUP + 1,
+    mla.MLA_PREFIX_HEAD_GROUP - 1], ids=["whole_groups", "remainder",
+                                          "under_a_group"])
+@pytest.mark.parametrize("off,t_len,block_q", [
+    (0, 16, 8),        # the lane's first chunk
+    (32, 16, 16),      # at a block's edge: whole blocks before it
+    (20, 16, 8),       # inside a block: spans two
+    (48, 16, 16),      # the plane's last rows
+    (56, 8, 8),        # a last chunk of one query block
+], ids=["first", "edge", "inside", "end", "last_rows"])
+def test_mla_prefix_kernel_equals_its_twin(off, t_len, block_q, heads):
+    """In the interpreter, float32: a chunk of ``t_len`` rows at ``off``
+    in lane 2 of plane 1, the slots past it stale and large (a block
+    masked wrongly reads them), heads a whole number of the kernel's
+    groups, one more (the remainder, one at a time) and fewer than a
+    group (one group of them all)."""
+    rank, nope, rope, v, width, s_len = 32, 16, 8, 16, 128, 64
+    ks = jax.random.split(jax.random.key(off), 4)
+    lat = jax.random.normal(ks[0], (2, 3, s_len, width), jnp.float32)
+    lat = lat.at[..., rank + rope:].set(0.0).at[1, 2, off + t_len:].set(1e4)
+    q_nope = jax.random.normal(ks[1], (t_len, heads, nope))
+    q_pe = jax.random.normal(ks[2], (t_len, heads, rope))
+    wkv_b = jax.random.normal(ks[3], (rank, heads * (nope + v))) / 6
+    want = mla.mla_prefix_twin(q_nope, q_pe, wkv_b, lat, 1, 2, off, 0.2,
+                               rank)
+    got = mla.mla_prefix_attention(
+        q_nope, q_pe, wkv_b, lat, jnp.int32(1), jnp.int32(2),
+        jnp.int32(off), scale=0.2, rank=rank, block_q=block_q, block_k=16,
+        interpret=True)
+    np.testing.assert_allclose(got, want, atol=TOL)
+
+
 def test_the_mla_kernels_lower_for_the_tpu_under_their_names():
     """The decode kernel's Pallas call is ``mla_decode_fwd`` at the
     benchmark's shapes, the chunk's ``mla_prefix_fwd``; no older
