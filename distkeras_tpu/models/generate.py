@@ -43,6 +43,8 @@ from distkeras_tpu.models.transformer import (
     rope_angles,
     rope_rotate,
     split_qkv,
+    ssm_in,
+    ssm_out,
 )
 from distkeras_tpu.models.quant import (
     deq,
@@ -61,6 +63,18 @@ from distkeras_tpu.ops.retention import (
     step_operands,
     use_ret_chunk_kernel,
     use_ret_kernel,
+)
+from distkeras_tpu.ops.ssm import (
+    causal_conv,
+    selective_inputs,
+    ssm_chunk_fwd,
+    ssm_chunked,
+    ssm_state_step,
+    ssm_step,
+    state_matrix,
+    step_operands as ssm_step_operands,
+    use_ssm_chunk_kernel,
+    use_ssm_kernel,
 )
 from distkeras_tpu.ops.latent import (
     MLA_TAIL_PARTS,
@@ -146,6 +160,21 @@ def init_cache(cfg: TransformerConfig, batch: int, dtype=None,
             # stale one is masked by position, nothing to clear.
             cache["lat"] = jnp.zeros((cfg.latent_planes, batch, cfg.max_len,
                                       cfg.latent_width), dtype)
+        if cfg.ssm_planes:
+            # A fifth kind: ``ssm``, one float32 STATE ``[ssm_state, E]``
+            # an ssm layer (the states down the sublanes, the channels
+            # along the lanes), and ``conv``, the conv's last ``K - 1``
+            # inputs side by side, ``[(K - 1) E]``, in the activations'
+            # dtype (``ops/ssm.py``): with the 3 rows a dimension of
+            # their own the TPU's tiled layouts of the slab disagreed
+            # between programs and every decode step copied the whole
+            # slab out and back (4 x 431 MB a step on a TPU v5e).  Like a
+            # retention layer's: of one size at every position, no slots.
+            cache["ssm"] = jnp.zeros((cfg.ssm_planes, batch, cfg.ssm_state,
+                                      cfg.ssm_inner), jnp.float32)
+            cache["conv"] = jnp.zeros(
+                (cfg.ssm_planes, batch, (cfg.ssm_conv - 1) * cfg.ssm_inner),
+                dtype)
         return cache
     dtype = jnp.int8 if kv_int8 else (dtype or jnp.dtype(cfg.dtype))
     shape = (cfg.kv_planes, batch, cfg.max_len, cfg.kv_heads, cfg.head_dim)
@@ -796,7 +825,17 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
     rebuilds a block's keys and values in VMEM; every other chunk
     absorbed in the dense body.  Slots as a full plane's: a
     stale one is masked by position, padding lands past the frontier,
-    ``n_real`` is not needed."""
+    ``n_real`` is not needed.
+
+    An SSM layer's plane is a float32 state and the conv's last inputs
+    (``init_cache``'s ``ssm`` and ``conv``), carried through the scans
+    and updated where they lie, as a retention layer's (``ssm_mix``):
+    ``live`` names the rows a decode step updates (on the TPU the kernel
+    ``ops.ssm.ssm_state_step``), a chunk goes through its row's state
+    (one row on the TPU: ``ssm_chunk_fwd`` where it lies), ``n_real``
+    keeps an admission's padding out of both, and a row at position 0
+    starts from zeros.  Full layers may stand among ssm layers: the
+    stack then holds slots and states side by side."""
     dtype = jnp.dtype(cfg.dtype)
     b, t_len = tokens.shape
     n_layers, s_len = cfg.n_layers, cfg.max_len
@@ -981,8 +1020,10 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
             b, t_len, cfg.n_heads, cfg.head_dim)
 
     def attend_state(h, q, k, v, lp, plane, state):
-        """A retention layer's attention, through its state."""
-        s_all, z_all = state
+        """A retention layer's attention, through its state (``state``'s
+        slabs ``s`` and ``z``)."""
+        s_all, z_all = state["s"], state["z"]
+        put = lambda s_all, z_all: {**state, "s": s_all, "z": z_all}
         fresh = pos0 == 0
         with jax.named_scope("ret_gate"):
             logg = log_gate(h, lp["attn"]["wg"], lp["attn"]["bg"])
@@ -998,7 +1039,7 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
                 y, s_all, z_all = step(ops, s_all, z_all, plane, on)
             return (y[:, :, :groups].reshape(b, 1, cfg.n_heads,
                                              cfg.head_dim),
-                    (s_all, z_all))
+                    put(s_all, z_all))
         # Chunks: each row through its own state (one row: the lane's).
         row0 = jnp.int32(0) if lane is None else lane
         if use_ret_chunk_kernel(cfg.head_dim, groups, t_len, b, s_all.dtype,
@@ -1008,7 +1049,7 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
                 y, s_all, z_all = ret_chunk_fwd(
                     q[0].astype(dtype), k[0], v[0], logg[0], s_all, z_all,
                     plane, row0, n_real, fresh[0])
-            return y[None], (s_all, z_all)
+            return y[None], put(s_all, z_all)
         at = (plane, row0) + (jnp.int32(0),) * 4
         with jax.named_scope("ret_state"):
             s_in = jax.lax.dynamic_slice(s_all, at, (1, b) + s_all.shape[2:])
@@ -1022,7 +1063,7 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
         with jax.named_scope("ret_state"):
             s_all = jax.lax.dynamic_update_slice(s_all, s_new[None], at)
             z_all = jax.lax.dynamic_update_slice(z_all, z_new[None], at[:-1])
-        return y, (s_all, z_all)
+        return y, put(s_all, z_all)
 
     def attend_latent(q_abs, lat, plane):
         """A latent layer's attention in the absorbed form: ``q_abs [B,
@@ -1072,16 +1113,17 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
         lat_scale = 1.0 / float(np.sqrt(cfg.qk_nope_head_dim
                                         + cfg.qk_rope_head_dim))
 
-    def latent_attn(h, lp, plane, slab):
+    def latent_attn(h, lp, plane, state):
         """A latent layer's attention sublayer over the normed stream:
         ``(its output [B, T, D], the chunk's rows [B, T, W] for the
-        plane, the slab)``.  ``slab``: the latent planes where the
+        plane, state)``.  ``state["lat"]``: the latent planes where the
         chunk attends EXPANDED (``lat_expands``: one row's chunk on the
         TPU) — its rows are then written into the slab here, in place,
         and the kernel reads the lane's blocks from it, rebuilding
-        their keys and values in VMEM; None on the absorbed paths,
+        their keys and values in VMEM; absent on the absorbed paths,
         which leave the slab read-only and hand the rows to the end."""
         attn = lp["attn"]
+        slab = state.get("lat")
         with jax.named_scope("attn_proj"):
             q_nope, q_pe, c, k_pe = latent_qkv(attn, h, rope_ang, cfg)
             wk, wv = latent_kv_b(attn, cfg)
@@ -1105,7 +1147,7 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
             with jax.named_scope("attn_proj"):
                 return jnp.einsum(
                     "btk,kd->btd", o.reshape(o.shape[:2] + (-1,)),
-                    attn["wo"]), lat, slab
+                    attn["wo"]), lat, {**state, "lat": slab}
         with jax.named_scope("attn"):
             with jax.named_scope("mla_absorb"):
                 q_lat = jnp.einsum("bthn,rhn->bthr", q_nope, wk,
@@ -1117,7 +1159,69 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
                 o = jnp.einsum("bthr,rhv->bthv", o_lat.astype(dtype), wv)
         with jax.named_scope("attn_proj"):
             return jnp.einsum("btk,kd->btd", o.reshape(o.shape[:2] + (-1,)),
-                              attn["wo"]), lat, None
+                              attn["wo"]), lat, state
+
+    def ssm_mix(h, lp, plane, state):
+        """An ssm layer's mixer over the normed stream, through its
+        plane — ``state``'s slabs ``ssm`` (the states) and ``conv`` (the
+        conv's tails), updated where they lie: ``(its output [B, T, D],
+        state)``.  One token a row (no ``lane``) is a decode step: on
+        the TPU the kernel ``ssm_state_step``, elsewhere the same
+        arithmetic; a row that ``live`` does not name keeps its state
+        and its tail.  A chunk goes through each row's (``lane``'s)
+        state — one row on the TPU: the kernel ``ssm_chunk_fwd`` on the
+        state where it lies, else the chunked form on a copy cut out
+        and put back — its padding (``n_real``) entering neither.  A
+        row at position 0 starts from a zero state and a zero tail."""
+        attn = lp["attn"]
+        s_all, c_all = state["ssm"], state["conv"]
+        e, k1 = cfg.ssm_inner, cfg.ssm_conv - 1
+        u, z = ssm_in(attn, h, cfg)
+        u = u.astype(c_all.dtype)
+        step = lane is None and t_len == 1
+        fresh = pos0 == 0
+        row0 = jnp.int32(0) if lane is None else lane
+        zero = jnp.int32(0)
+        at = (plane, row0, zero, zero)
+        with jax.named_scope("attn"), jax.named_scope("ssm_conv"):
+            tail = jax.lax.dynamic_slice(c_all, at[:3], (1, b, k1 * e))[0]
+            x, new_tail = causal_conv(u, tail, attn["conv_w"], attn["conv_b"],
+                                      n_real=None if step else n_real,
+                                      keep=~fresh)
+            if step and live is not None:
+                new_tail = jnp.where(live.astype(bool)[:, None], new_tail,
+                                     tail)
+            c_all = jax.lax.dynamic_update_slice(c_all, new_tail[None],
+                                                 at[:3])
+        with jax.named_scope("attn_proj"), jax.named_scope("ssm_proj"):
+            delta, bm, cm = selective_inputs(x, attn, eps, dtype)
+        a_mat = state_matrix(attn["A_log"])
+        d_vec = attn["D"].astype(jnp.float32)
+        with jax.named_scope("attn"), jax.named_scope("ssm_scan"):
+            if step:
+                rows, bc = ssm_step_operands(x[:, 0], delta[:, 0], bm[:, 0],
+                                             cm[:, 0], ~fresh)
+                on = (jnp.ones((b,), jnp.int32) if live is None
+                      else live.astype(jnp.int32))
+                fn = (ssm_state_step if use_ssm_kernel(e, s_all.dtype,
+                                                       sharded) else ssm_step)
+                y, s_all = fn(rows, bc, a_mat, d_vec, s_all, plane, on)
+                y = y[:, None]
+            elif use_ssm_chunk_kernel(e, t_len, b, s_all.dtype, sharded):
+                y, s_all = ssm_chunk_fwd(x[0], delta[0], bm[0], cm[0], a_mat,
+                                         d_vec, s_all, plane, row0, n_real,
+                                         fresh[0])
+                y = y[None]
+            else:
+                held = jax.lax.dynamic_slice(
+                    s_all, at, (1, b) + s_all.shape[2:])[0]
+                held = jnp.where(fresh[:, None, None], 0.0, held)
+                y, s_new = jax.vmap(
+                    ssm_chunked, in_axes=(0, 0, 0, 0, None, None, 0, None))(
+                    x, delta, bm, cm, a_mat, d_vec, held, n_real)
+                s_all = jax.lax.dynamic_update_slice(s_all, s_new[None], at)
+        return (ssm_out(attn, y, z, dtype),
+                {**state, "ssm": s_all, "conv": c_all})
 
     def layer(x, lp, plane, kind=None, experts=None, state=None):
         h = _rms_norm(x, lp["ln1_scale"], eps) if pre_norm else x
@@ -1125,6 +1229,9 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
             a, lat, state = latent_attn(h, lp, plane, state)
             return layer_tail(x, a, lp, kind, experts, state,
                               () if lat_expands else (lat,))
+        if kind is not None and kind[0] == "ssm":
+            a, state = ssm_mix(h, lp, plane, state)
+            return layer_tail(x, a, lp, kind, experts, state, ())
         with jax.named_scope("attn_proj"):
             if cfg.fused_qkv:
                 q, k, v = split_qkv(
@@ -1193,8 +1300,14 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
         return final_norm(x, params, cfg).astype(dtype), kv
 
     if typed:
-        state = ((cache["s"], cache["z"]) if cfg.state_planes
-                 else cache["lat"] if lat_expands else None)
+        # The slabs a layer updates where they lie, carried through the
+        # scans beside the stream: the retention layers' states, the
+        # ssm layers' states and conv tails, the latent planes where a
+        # chunk writes its rows itself.
+        state = {name: cache[name] for name, on in (
+            ("s", cfg.state_planes), ("z", cfg.state_planes),
+            ("ssm", cfg.ssm_planes), ("conv", cfg.ssm_planes),
+            ("lat", lat_expands)) if on}
         return _typed_tail(*_typed_runs(params, x, cfg, layer, state),
                            params, cache, pos0, cfg, uniform_pos, lane,
                            n_real, with_routes)
@@ -1233,13 +1346,15 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
     return out.astype(jnp.float32), {"k": ck_all, "v": cv_all}
 
 
-def _typed_runs(params, x, cfg: TransformerConfig, layer, state=None):
+def _typed_runs(params, x, cfg: TransformerConfig, layer, state):
     """A typed stack's layers over the stream ``x``: one ``lax.scan`` a
     run of consecutive layers of one kind, over that kind's slice of
     its group's stacked leaves (the whole group where the kind makes
-    one run).  ``state``: the retention layers' state slabs ``(s,
-    z)``, carried through every scan beside the stream and updated
-    where they lie (None: no such layer).  Returns ``(x, new)``:
+    one run).  ``state``: a dict of the slabs the layers update where
+    they lie (the retention layers' ``s``, ``z``; the ssm layers'
+    ``ssm``, ``conv``; the latent planes ``lat`` where a chunk writes
+    them itself), carried through every scan beside the stream.
+    Returns ``(x, new)``:
     ``new[kind]`` the runs' scan outputs of the attention kinds
     ``"full"`` and ``"window"`` — ``(k, v)`` — and ``"latent"`` — the
     rows, unless the layers wrote them into ``state`` (then the latent
@@ -1247,7 +1362,7 @@ def _typed_runs(params, x, cfg: TransformerConfig, layer, state=None):
     a list of leaves stacked by layer, and ``new["state"]`` the slabs
     after the last layer."""
     new = {"full": [], "window": [], "latent": [], "routes": []}
-    planes = {"full": 0, "window": 0, "retention": 0, "latent": 0}
+    planes = {"full": 0, "window": 0, "retention": 0, "latent": 0, "ssm": 0}
     for group, first, count in cfg.layer_runs:
         kind = tuple(group.split("."))
         leaves, heavy = params["layers"][group], None
@@ -1258,18 +1373,28 @@ def _typed_runs(params, x, cfg: TransformerConfig, layer, state=None):
             moe = dict(leaves["moe"])
             heavy = (moe.pop("w13"), moe.pop("w2"))
             leaves = {**leaves, "moe": moe}
-        if (first, count) != (0, cfg.layer_kinds.count(kind)):
-            leaves = jax.tree.map(lambda a: a[first:first + count], leaves)
         p0 = planes[kind[0]]
         planes[kind[0]] += count
+        if (first, count) == (0, cfg.layer_kinds.count(kind)):
+            xs, weights = (leaves, jnp.arange(count)), lambda lw: lw[0]
+        else:
+            # A run that is part of its group reads each layer out of
+            # the group's whole stack inside the scan: its slice taken
+            # before the scan would be a copy of the run's weights (2.2
+            # GB for 13 of 26 ssm layers at Jamba's widths, by the
+            # compiler's memory analysis).
+            xs = (jnp.arange(count), jnp.arange(count))
+            weights = lambda lw, leaves=leaves, first=first: jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(
+                    a, first + lw[0], 0, keepdims=False), leaves)
         (x, state), outs = jax.lax.scan(
-            lambda c, lw: layer(c[0], lw[0], p0 + lw[1], kind,
+            lambda c, lw: layer(c[0], weights(lw), p0 + lw[1], kind,
                                 heavy and heavy + (first + lw[1],), c[1]),
-            (x, state), (leaves, jnp.arange(count)))
+            (x, state), xs)
         if kind[0] == "latent":
             if len(outs) > (kind[1] == "sparse"):   # not written in place
                 new["latent"].append(outs[0])
-        elif kind[0] != "retention":
+        elif kind[0] not in ("retention", "ssm"):
             new[kind[0]].append(outs[:2])
         if kind[1] == "sparse":
             new["routes"].append(outs[-1])
@@ -1290,12 +1415,8 @@ def _typed_tail(x, new, params, cache, pos0, cfg: TransformerConfig,
     with jax.named_scope("head"):
         out = jnp.einsum("btd,vd->btv", x,
                          head_table(params, cfg).astype(dtype))
-    cache = dict(cache)
-    if new["state"] is not None:   # written where they lay, layer by layer
-        if cfg.state_planes:
-            cache["s"], cache["z"] = new["state"]
-        else:
-            cache["lat"] = new["state"]
+    # The slabs written where they lay, layer by layer.
+    cache = {**cache, **new["state"]}
     zero = jnp.int32(0)
     row0 = zero if lane is None else lane
     with jax.named_scope("kv_slab"):
@@ -1989,10 +2110,10 @@ def generate(params, prompt, cfg: TransformerConfig, max_new_tokens: int,
         # prompt position (its step recomputes identical K/V in place
         # and yields the logits that sample token p).
         # (A state is not a slot: recomputed, the last position would
-        # enter it twice.  A stack with retention layers prefills the
-        # prompt but for its last token.)
+        # enter it twice.  A stack with retention or ssm layers
+        # prefills the prompt but for its last token.)
         cache, _ = prefill(params,
-                           prompt[:, :-1] if cfg.state_planes else prompt,
+                           prompt[:, :-1] if cfg.holds_state else prompt,
                            cfg, last_logits=False, kv_int8=kv_int8)
         start = p - 1
     else:

@@ -38,6 +38,12 @@ from jax.sharding import PartitionSpec as P
 from distkeras_tpu.ops.attention import flash_attention
 from distkeras_tpu.ops.grouped import TILE_M as GROUP_TILE, grouped_matmul
 from distkeras_tpu.ops.retention import log_gate, retention_attention
+from distkeras_tpu.ops.ssm import (
+    causal_conv,
+    selective_inputs,
+    ssm_chunked,
+    state_matrix,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -161,8 +167,8 @@ class TransformerConfig:
     # of one kind is one scan.  A stack whose lists name full attention
     # and the dense feed-forward everywhere is today's stack: the lists
     # are dropped (``__post_init__``) and it compiles today's programs.
-    # ``layer_types``: "window" | "full" | "retention" | "latent" per
-    # layer; a
+    # ``layer_types``: "window" | "full" | "retention" | "latent" |
+    # "ssm" per layer; a
     # window layer attends its last ``sliding_window`` positions (self
     # included) — unlike ``attention_window``, which windows the WHOLE
     # stack and makes the serving lanes roll.  Served, a window layer's
@@ -222,6 +228,21 @@ class TransformerConfig:
     qk_nope_head_dim: int | None = None
     qk_rope_head_dim: int | None = None
     v_head_dim: int | None = None
+    # An SSM layer (``layer_types`` "ssm": the selective state-space
+    # mixer of Mamba-1 with Jamba's inner norms, ``ops/ssm.py``) in
+    # place of attention: ``attn/w_in [D, 2 E]`` (the conv's input and
+    # the gate, E = ``ssm_expand * d_model``), a depthwise causal conv
+    # of ``ssm_conv`` taps (``conv_w [K, E]``, ``conv_b``), ``w_x [E,
+    # ssm_dt_rank + 2 ssm_state]`` to the step's input, B and C (each
+    # through an RMSNorm: ``dt_scale``, ``b_scale``, ``c_scale``),
+    # ``w_dt [dt_rank, E]`` and ``b_dt`` to the step size, ``A_log
+    # [ssm_state, E]``, ``D [E]``, ``w_out [E, D]``.  Served, its plane
+    # is a float32 state ``[ssm_state, E]`` and the conv's last ``K - 1``
+    # inputs: of one size at every position.
+    ssm_state: int | None = None
+    ssm_conv: int | None = None
+    ssm_dt_rank: int | None = None
+    ssm_expand: int | None = None
 
     def __post_init__(self):
         # Lists arrive from JSON as lists; a config is a static jit
@@ -308,6 +329,25 @@ class TransformerConfig:
         return self.layer_types.count("latent") if self.typed else 0
 
     @property
+    def ssm_planes(self) -> int:
+        """SSM planes of the decode cache (a state and a conv tail
+        each): one per ssm layer of a typed stack."""
+        return self.layer_types.count("ssm") if self.typed else 0
+
+    @property
+    def ssm_inner(self) -> int:
+        """Channels of an ssm layer: ``ssm_expand * d_model``."""
+        return self.ssm_expand * self.d_model
+
+    @property
+    def holds_state(self) -> bool:
+        """Whether a lane's cache holds a STATE (retention or ssm
+        layers): no slots, so nothing stale is masked by position — a
+        new occupant clears it, a lane that does not decode must not
+        touch it, an admission's padding must not enter it."""
+        return bool(self.state_planes or self.ssm_planes)
+
+    @property
     def latent_width(self) -> int:
         """Values a position holds in a latent plane: the latent and
         the shared rotary key, rounded up to whole lane tiles of 128
@@ -358,10 +398,13 @@ _TYPED_KEYS = {"layer_types": None, "ffn_types": None,
                "rope_layer_types": None, "d_head": None, "qk_norm": False,
                "norm_eps": 1e-6, "q_lora_rank": None, "kv_lora_rank": None,
                "qk_nope_head_dim": None, "qk_rope_head_dim": None,
-               "v_head_dim": None}
+               "v_head_dim": None, "ssm_state": None, "ssm_conv": None,
+               "ssm_dt_rank": None, "ssm_expand": None}
 # A latent layer's shape keys: all of them, or none.
 _LATENT_KEYS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
                 "qk_rope_head_dim", "v_head_dim")
+# An ssm layer's: all of them, or none.
+_SSM_KEYS = ("ssm_state", "ssm_conv", "ssm_dt_rank", "ssm_expand")
 
 
 def reject_extended(cfg: "TransformerConfig", path: str,
@@ -378,10 +421,12 @@ def reject_extended(cfg: "TransformerConfig", path: str,
     on += [k for k, base in _TYPED_KEYS.items() if getattr(cfg, k) != base]
     if cfg.n_passes > 1 or on:
         # The kinds no other path knows, by name.
-        for planes, kind in ((cfg.state_planes, "retention"),
-                             (cfg.latent_planes, "latent")):
-            if planes:
-                on[on.index("layer_types")] = f"layer_types: '{kind}'"
+        kinds = [kind for planes, kind in (
+            (cfg.state_planes, "retention"), (cfg.latent_planes, "latent"),
+            (cfg.ssm_planes, "ssm")) if planes]
+        if kinds:
+            on[on.index("layer_types")] = "layer_types: " + ", ".join(
+                f"'{kind}'" for kind in kinds)
         what = (f"a looped stack (n_passes={cfg.n_passes})"
                 if cfg.n_passes > 1 else
                 f"the extended block ({' / '.join(on)})")
@@ -477,6 +522,10 @@ def init_params(rng, cfg: TransformerConfig):
         raise ValueError(
             f"{_LATENT_KEYS} shape a latent layer: name the layers "
             "(layer_types: 'latent')")
+    elif any(getattr(cfg, k) is not None for k in _SSM_KEYS):
+        raise ValueError(
+            f"{_SSM_KEYS} shape an ssm layer: name the layers "
+            "(layer_types: 'ssm')")
     elif cfg.num_experts:
         reject_extended(cfg, "a MoE feed-forward (num_experts > 0) "
                         "without ffn_types (the capacity dispatch)")
@@ -485,7 +534,8 @@ def init_params(rng, cfg: TransformerConfig):
     d, f, h, hd = cfg.d_model, cfg.d_ff, cfg.n_heads, cfg.head_dim
     kv = cfg.kv_heads
 
-    def group(gkey, L, sparse=None, retention=False, latent=False):
+    def group(gkey, L, sparse=None, retention=False, latent=False,
+              ssm=False):
         """``L`` layers of one kind, stacked on a leading axis; the
         whole stack where the layers do not differ (``sparse`` None:
         the old capacity-dispatch experts where ``num_experts``)."""
@@ -494,16 +544,49 @@ def init_params(rng, cfg: TransformerConfig):
         def stack(key, shape, fan_in):
             return _dense_init(key, (L, *shape), fan_in)
 
+        # The inner norms' scales are drawn (1 +- 0.25; a trained
+        # model's are learnt), so that leaving a norm out of a
+        # computation shows: at 1 it would hardly, the projections of a
+        # normed stream being near unit size.
+        scale = lambda key, n: 1.0 + 0.25 * jax.random.normal(
+            key, (L, n), jnp.float32)
+
+        def ssm_leaves():
+            n, k, r, e = (cfg.ssm_state, cfg.ssm_conv, cfg.ssm_dt_rank,
+                          cfg.ssm_inner)
+            uniform = lambda key, shape, lim: jax.random.uniform(
+                key, (L, *shape), jnp.float32, -lim, lim)
+            # Mamba-1's initialisation: the step size drawn log-uniform
+            # in [0.001, 0.1] and its bias the inverse softplus of it,
+            # ``A_log = log(1 .. N)`` on every channel, ``D = 1``, ``w_dt``
+            # uniform within 1/sqrt(dt_rank); the conv PyTorch's default
+            # (uniform within 1/sqrt(K)).  A bias drawn at 0 would make
+            # every state forget within a few positions.
+            dt = jnp.exp(jax.random.uniform(
+                jax.random.fold_in(gk[2], 4), (L, e), jnp.float32,
+                np.log(1e-3), np.log(1e-1)))
+            return {
+                "w_in": stack(gk[0], (d, 2 * e), d),
+                "conv_w": uniform(jax.random.fold_in(gk[1], 1), (k, e),
+                                  k ** -0.5),
+                "conv_b": uniform(jax.random.fold_in(gk[1], 2), (e,),
+                                  k ** -0.5),
+                "w_x": stack(gk[2], (e, r + 2 * n), e),
+                "dt_scale": scale(jax.random.fold_in(gk[2], 1), r),
+                "b_scale": scale(jax.random.fold_in(gk[2], 2), n),
+                "c_scale": scale(jax.random.fold_in(gk[2], 3), n),
+                "w_dt": uniform(jax.random.fold_in(gk[2], 5), (r, e),
+                                r ** -0.5),
+                "b_dt": dt + jnp.log(-jnp.expm1(-dt)),
+                "A_log": jnp.broadcast_to(jnp.log(jnp.arange(
+                    1, n + 1, dtype=jnp.float32))[None, :, None], (L, n, e)),
+                "D": jnp.ones((L, e)),
+                "w_out": stack(gk[3], (e, d), e)}
+
         def latent_leaves():
             rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
             dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                           cfg.v_head_dim)
-            # The two inner norms' scales are drawn (1 +- 0.25; a
-            # trained model's are learnt), so that leaving a norm out
-            # of a computation shows: at 1 it would hardly, the
-            # projections of a normed stream being near unit size.
-            scale = lambda key, n: 1.0 + 0.25 * jax.random.normal(
-                key, (L, n), jnp.float32)
             return {
                 "wq_a": stack(gk[0], (d, rq), d),
                 "q_a_scale": scale(jax.random.fold_in(gk[0], 1), rq),
@@ -514,7 +597,8 @@ def init_params(rng, cfg: TransformerConfig):
                                (rkv, h * (dn + dv)), rkv),
                 "wo": stack(gk[3], (h * dv, d), h * dv)}
 
-        layers = {"attn": latent_leaves() if latent else {
+        layers = {"attn": latent_leaves() if latent else ssm_leaves()
+                  if ssm else {
             "wq": stack(gk[0], (d, h, hd), d),
             "wk": stack(gk[1], (d, kv, hd), d),
             "wv": stack(gk[2], (d, kv, hd), d),
@@ -523,14 +607,14 @@ def init_params(rng, cfg: TransformerConfig):
         if cfg.post_norms != "only":
             layers["ln1_scale"] = jnp.ones((L, d))
             layers["ln2_scale"] = jnp.ones((L, d))
-        if cfg.fused_qkv and not latent:  # the same draws, as matrices
+        if cfg.fused_qkv and not (latent or ssm):  # the same draws, as matrices
             a = layers["attn"]
             layers["attn"] = {
                 "wqkv": jnp.concatenate(
                     [a[w].reshape(L, d, -1) for w in ("wq", "wk", "wv")],
                     axis=-1),
                 "wo": a["wo"].reshape(L, -1, d)}
-        if cfg.qk_norm:
+        if cfg.qk_norm and not ssm:
             layers["attn"]["q_scale"] = jnp.ones((L, hd))
             layers["attn"]["k_scale"] = jnp.ones((L, hd))
         if retention:
@@ -595,7 +679,7 @@ def init_params(rng, cfg: TransformerConfig):
                 jax.random.fold_in(keys[0], i), kinds.count(kind),
                 sparse=kind[1] == "sparse",
                 retention=kind[0] == "retention",
-                latent=kind[0] == "latent")
+                latent=kind[0] == "latent", ssm=kind[0] == "ssm")
             for i, kind in enumerate(dict.fromkeys(kinds))}
     else:
         layers = group(None, cfg.n_layers)
@@ -627,7 +711,7 @@ def _validate_typed(cfg: "TransformerConfig") -> None:
     """What a typed stack's keys have to say together."""
     n = cfg.n_layers
     for name, kinds in (("layer_types", ("window", "full", "retention",
-                                         "latent")),
+                                         "latent", "ssm")),
                         ("ffn_types", ("dense", "sparse"))):
         got = getattr(cfg, name)
         if len(got) != n or set(got) - set(kinds):
@@ -669,6 +753,18 @@ def _validate_typed(cfg: "TransformerConfig") -> None:
         raise ValueError(
             f"{given} shape a latent layer (layer_types: 'latent'); the "
             "stack has none")
+    given = [k for k in _SSM_KEYS if getattr(cfg, k) is not None]
+    if "ssm" in cfg.layer_types:
+        if len(given) != len(_SSM_KEYS) or min(
+                getattr(cfg, k) for k in _SSM_KEYS) < 1 or cfg.ssm_conv < 2:
+            raise ValueError(
+                "ssm layers (layer_types: 'ssm') need every one of "
+                f"{_SSM_KEYS}, each >= 1 (ssm_conv >= 2), got "
+                + ", ".join(f"{k}={getattr(cfg, k)}" for k in _SSM_KEYS))
+    elif given:
+        raise ValueError(
+            f"{given} shape an ssm layer (layer_types: 'ssm'); the stack "
+            "has none")
     if "sparse" in cfg.ffn_types:
         held = cfg.experts_held
         if not (1 <= cfg.moe_top_k <= cfg.num_experts) or not held or (
@@ -749,7 +845,8 @@ def _resolve_attention_fn(cfg: "TransformerConfig", attention_fn,
         # (Nor a latent layer's, whose heads are rebuilt from a latent:
         # ``latent_attention``.)
         return {"window": by_window(cfg.sliding_window),
-                "full": by_window(None), "retention": None, "latent": None}
+                "full": by_window(None), "retention": None, "latent": None,
+                "ssm": None}
     if attention_fn is None:
         return lambda q, k, v: flash_attention(
             q, k, v, True, window=cfg.attention_window,
@@ -835,6 +932,11 @@ RET_SCOPES = ("ret_gate", "ret_state", "ret_chunk")
 # output — the served path; the kernel ``mla_decode_fwd`` sits in
 # ``attn`` beside it).
 MLA_SCOPES = ("mla_q", "mla_kv", "mla_absorb")
+# In an ssm layer: under ``attn_proj``, ssm_proj (``w_in``, ``w_x``, the
+# inner norms and ``w_dt``); under ``attn``, ssm_conv (the causal conv
+# and its tail) and ssm_scan (the state's recurrence: the kernels
+# ``ssm_state_step`` and ``ssm_chunk_fwd`` sit here).
+SSM_SCOPES = ("ssm_proj", "ssm_conv", "ssm_scan")
 
 
 def _rms_norm(x, scale, eps=1e-6, scope="norm"):
@@ -914,6 +1016,43 @@ def latent_qkv(attn, h, rope_ang, cfg: TransformerConfig):
         c = _rms_norm(ckv[..., :rkv], attn["kv_a_scale"], eps)
         k_pe = rope_rotate(ckv[..., rkv:], rope_ang[:, :, 0])
     return q_nope, q_pe, c, k_pe
+
+
+def ssm_in(attn, h, cfg: TransformerConfig):
+    """An ssm layer's input product over the normed stream ``h [B, T,
+    D]``: ``(u, z)``, each ``[B, T, E]`` — the conv's input and the
+    gate."""
+    with jax.named_scope("attn_proj"), jax.named_scope("ssm_proj"):
+        uz = jnp.einsum("btd,de->bte", h, attn["w_in"])
+        return uz[..., :cfg.ssm_inner], uz[..., cfg.ssm_inner:]
+
+
+def ssm_out(attn, y, z, dtype):
+    """The gate and the output product: ``(y * silu(z)) · w_out``, the
+    gate in float32."""
+    with jax.named_scope("attn_proj"):
+        g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+        return jnp.einsum("bte,ed->btd", g.astype(dtype), attn["w_out"])
+
+
+def ssm_mixer(attn, h, cfg: TransformerConfig):
+    """An ssm layer's mixer over ``h [B, T, D]`` with no cache: the
+    conv from zeros, the recurrence from a zero state in its chunked
+    form (``ops.ssm.ssm_chunked``), ``[B, T, D]``."""
+    dtype = jnp.dtype(cfg.dtype)
+    u, z = ssm_in(attn, h, cfg)
+    b, _, e = u.shape
+    with jax.named_scope("attn"), jax.named_scope("ssm_conv"):
+        x, _ = causal_conv(u, jnp.zeros((b, (cfg.ssm_conv - 1) * e), u.dtype),
+                           attn["conv_w"], attn["conv_b"])
+    with jax.named_scope("attn_proj"), jax.named_scope("ssm_proj"):
+        delta, bm, cm = selective_inputs(x, attn, cfg.norm_eps, dtype)
+    with jax.named_scope("attn"), jax.named_scope("ssm_scan"):
+        y, _ = jax.vmap(ssm_chunked, in_axes=(0, 0, 0, 0, None, None, None))(
+            x, delta, bm, cm, state_matrix(attn["A_log"]),
+            attn["D"].astype(jnp.float32),
+            jnp.zeros((cfg.ssm_state, e), jnp.float32))
+    return ssm_out(attn, y, z, dtype)
 
 
 def latent_kv_b(attn, cfg: TransformerConfig):
@@ -1224,6 +1363,8 @@ def block_apply(layer_params, x, cfg: TransformerConfig,
         a = latent_attention(attn_w, h, rope_ang, cfg)
         with jax.named_scope("attn_proj"):
             a = jnp.einsum("btk,kd->btd", a, attn_w["wo"])
+    elif kind is not None and kind[0] == "ssm":
+        a = ssm_mixer(attn_w, h, cfg)
     else:
         if cfg.fused_qkv:  # matrices (init_params): heads split out here
             wq, wk, wv = split_qkv(attn_w["wqkv"], cfg)

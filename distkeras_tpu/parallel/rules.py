@@ -298,7 +298,9 @@ def serving_kv_axis(plan, mesh: Mesh, cfg) -> str | None:
     a head count the axis cannot split would otherwise surface as an
     inscrutable GSPMD error at first trace.
     """
-    _reject_latent_plane(getattr(cfg, "latent_planes", 0))
+    for kind in ("latent", "ssm"):
+        if getattr(cfg, f"{kind}_planes", 0):
+            _reject_headless_plane(kind)
     axis, culprit = None, None
     for path, head_dim, attr in _ATTN_HEAD_PATHS:
         for pat, spec in plan.rules:
@@ -346,18 +348,28 @@ def serving_kv_axis(plan, mesh: Mesh, cfg) -> str | None:
     return axis
 
 
-def _reject_latent_plane(found) -> None:
-    """A latent plane (``lat [planes, lanes, max_len, latent_width]``:
-    one row a position that every head reads) has no kv-heads
-    dimension to shard: placed by the rule below it would be cut over
-    its POSITIONS.  Said by name, not mis-placed."""
-    if found:
+# Cache leaves with no kv-heads dimension, by the layer kind that holds
+# them: a latent plane (``lat [planes, lanes, max_len, latent_width]``,
+# one row a position that every head reads) and an ssm plane (``ssm
+# [planes, lanes, ssm_state, E]``, ``conv [planes, lanes, (K - 1) E]``:
+# by channel).  Placed by the rule below they would be cut over their
+# positions or their states; tensor-parallel serving of such a layer
+# would split its channels or latent with its products, which no rule
+# here places.
+_HEADLESS_LEAVES = {"lat": "latent", "ssm": "ssm", "conv": "ssm"}
+
+
+def _reject_headless_plane(kind) -> None:
+    """Said by name, not mis-placed (``kind``: the layer kind, or None)."""
+    if kind:
+        leaves = " and ".join(repr(leaf) for leaf, k in
+                              _HEADLESS_LEAVES.items() if k == kind)
         raise ValueError(
-            "a latent plane (layer_types: 'latent', the cache leaf "
-            "'lat') has no kv-heads dimension: serving_kv_axis / "
+            f"a {kind} plane (layer_types: '{kind}', the cache leaf "
+            f"{leaves}) has no kv-heads dimension: serving_kv_axis / "
             "kv_slab_specs place a cache by that dimension and cannot "
-            "place it (tensor-parallel heads over a cache with no heads "
-            "axis is not built)")
+            f"place it (tensor-parallel serving of {kind} layers is not "
+            "built)")
 
 
 def kv_slab_specs(tree, axis: str | None):
@@ -370,7 +382,7 @@ def kv_slab_specs(tree, axis: str | None):
     replicates everything (the pure-FSDP serving layout)."""
     def leaf(path, a):
         ndim = getattr(a, "ndim", len(a.shape))
-        _reject_latent_plane(leaf_name(path) == "lat")
+        _reject_headless_plane(_HEADLESS_LEAVES.get(leaf_name(path)))
         if axis is None:
             return P()
         hd = ndim - 1 if leaf_name(path).endswith("scale") else ndim - 2

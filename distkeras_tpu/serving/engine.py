@@ -729,7 +729,7 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
                 # Ring planes: what of a lane's positions a window
                 # layer still holds (sum of min(position, window)).
                 rnd.fields["kv_live_window"] = kv_live_window
-            if self.cfg.state_planes:
+            if self.cfg.holds_state:
                 # Lanes whose state the step DISPATCHED here read and
                 # wrote (its ``live`` mask): the others' was left alone.
                 rnd.fields["state_lanes"] = state_lanes
@@ -793,7 +793,7 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
         planes or a state takes as one argument more — or nothing: every
         other engine's programs keep their signature."""
         cfg = self.cfg
-        return {"n_real": n} if cfg.kv_ring_planes or cfg.state_planes else {}
+        return {"n_real": n} if cfg.kv_ring_planes or cfg.holds_state else {}
 
     def _exec_chunk(self, lane, start, rows):  # pragma: no cover
         raise NotImplementedError(

@@ -181,9 +181,11 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
     ``kv_int8=``, ``lane_tiers=``, a window) rejects them by name.
 
     **State planes** (a stack with retention layers, ``layer_types``
-    ``"retention"``): such a layer's lane plane is a float32 state of
-    fixed size, not slots — ``max_len`` is positions there, not memory
-    (``serving.kv_layout``'s ``state_bytes_per_lane``;
+    ``"retention"``, or ssm layers, ``"ssm"``): such a layer's lane
+    plane is a float32 state of fixed size, not slots (an ssm layer's
+    with the conv's short tail beside it) — ``max_len`` is positions
+    there, not memory (``serving.kv_layout``'s ``state_bytes_per_lane``
+    and ``ssm_bytes_per_lane``;
     :meth:`memory_footprint` counts it under ``kv_bytes``).  Three
     things a K/V slab never needed: the decode step takes the lanes'
     ``live`` mask (:meth:`_live`) and leaves a free, finished or
@@ -191,7 +193,9 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
     from zero (a chunk or a step at position 0 clears the state: a
     stale state is not masked by position); and an admission's padding
     neither adds to a state nor decays it (``n_real``; a chunked tail
-    stays on the grid, as with ring planes).
+    stays on the grid, as with ring planes).  ssm layers may stand
+    among full layers: a lane then holds slots and state side by side,
+    each kept by its own rule.
 
     **Latent planes** (a stack of latent layers, ``layer_types``
     ``"latent"``): a lane's plane holds ONE row a position — the
@@ -648,6 +652,11 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
             # every live position like a full plane's slot; 0 planes
             # and width 0 say the stack has none.
             lat = self.cache.get("lat")
+            # A fifth where the stack has ssm layers: one float32 state
+            # and one conv tail a layer, of a fixed size at every
+            # position; 0 planes and 0 bytes say the stack has none.
+            ssm = ([self.cache["ssm"], self.cache["conv"]]
+                   if cfg.ssm_planes else [])
             obs.event("serving.kv_layout", passes=cfg.n_passes,
                       layers=cfg.n_layers, planes=cfg.kv_planes,
                       bytes_per_slot=slab // slots, slots=slots,
@@ -664,7 +673,10 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
                       planes_latent=cfg.latent_planes,
                       latent_width=0 if lat is None else int(lat.shape[-1]),
                       bytes_per_slot_latent=0 if lat is None else int(
-                          lat.nbytes) // slots)
+                          lat.nbytes) // slots,
+                      planes_ssm=cfg.ssm_planes,
+                      ssm_bytes_per_lane=sum(
+                          int(a.nbytes) for a in ssm) // self.lanes)
             return
         # [planes, lanes, max_len, ...] (the paged store: [planes,
         # blocks, block, ...]): slots are rows x positions.
@@ -1056,7 +1068,7 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
             raise ValueError(
                 f"chunked admission grid overflows the cache (chunk at "
                 f"{plan[-1][0]} + {w_chunk} > {self.cfg.max_len})")
-        if rem and (self.cfg.kv_ring_planes or self.cfg.state_planes):
+        if rem and (self.cfg.kv_ring_planes or self.cfg.holds_state):
             # Ring planes: the tail stays on the grid and its padding
             # goes unwritten (``n_real``) — a backed-up tail would ask a
             # ring for positions the chunk before has rolled out of it
@@ -1619,7 +1631,7 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
         nothing — told, the kernel reads none of it.
         Nothing for every other engine: its programs keep their
         signature."""
-        if not (self.cfg.state_planes or self.cfg.latent_planes):
+        if not (self.cfg.holds_state or self.cfg.latent_planes):
             return ()
         mask = np.zeros((len(self._lane_state),), np.int32)
         mask[[i for i, _ in self._decoding()]] = 1

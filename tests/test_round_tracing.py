@@ -624,6 +624,8 @@ def test_state_planes_are_named_in_the_layout_and_the_rounds(retention_run,
     # pins a latent stack's fields, kernels and scopes)
     assert (layout["planes_latent"], layout["latent_width"],
             layout["bytes_per_slot_latent"]) == (0, 0, 0)
+    # ... nor ssm planes (tests/test_ssm.py pins an ssm stack's fields)
+    assert (layout["planes_ssm"], layout["ssm_bytes_per_lane"]) == (0, 0)
     mine = [r["fields"] for r in records
             if r.get("name") == "serving.round"]
     assert [r["state_lanes"] for r in mine][:3] == [1, 1, 2]
@@ -708,6 +710,42 @@ def test_the_chunk_kernel_is_named_ret_chunk_fwd(monkeypatch):
     assert re.search(r'"ret_chunk_fwd/pallas_call"', text)
     for name in ("ret_chunk_fwd", "ret_chunk_fwd.7"):
         assert not re.search(_pattern("step_ret_state_roofline"), name)
+
+
+def test_the_ssm_kernels_are_named_and_no_other_reader_takes_them():
+    """The ssm layers' Pallas calls are ``ssm_state_step`` (a decode
+    step) and ``ssm_chunk_fwd`` (an admission chunk): the compiled
+    instructions, and with them the profile's events, carry the names
+    ``step_ssm_state_roofline`` and ``step_ssm_chunk_roofline`` look
+    for — and each name is matched by its own metric's pattern alone:
+    not the other's, nor ``step_n``, ``_admit``, ``^gmm``,
+    ``^ret_state_step`` or the latent kernels'."""
+    from distkeras_tpu.ops import ssm
+
+    sd = jax.ShapeDtypeStruct
+    f32, i32 = jnp.float32, jnp.int32
+    slab = sd((2, 3, 16, 256), f32)
+    texts = {
+        "ssm_state_step": jax.jit(ssm.ssm_state_step.__wrapped__).trace(
+            sd((3, 2, 256), f32), sd((3, 16, 3), f32), sd((16, 256), f32),
+            sd((256,), f32), slab, sd((), i32), sd((3,), i32)),
+        "ssm_chunk_fwd": jax.jit(ssm.ssm_chunk_fwd.__wrapped__).trace(
+            sd((64, 256), f32), sd((64, 256), f32), sd((64, 16), f32),
+            sd((64, 16), f32), sd((16, 256), f32), sd((256,), f32), slab,
+            sd((), i32), sd((), i32), sd((), i32), sd((), jnp.bool_))}
+    own = {"ssm_state_step": "step_ssm_state_roofline",
+           "ssm_chunk_fwd": "step_ssm_chunk_roofline"}
+    names = ("decode_step_ms", "prefill_ms_per_ktok", "step_moe_gmm_roofline",
+             "step_ret_state_roofline", "step_mla_decode_roofline",
+             "step_mla_prefix_roofline", "step_mla_hbm_roofline") + tuple(
+                 own.values())
+    for kernel, traced in texts.items():
+        text = traced.lower(lowering_platforms=("tpu",)).as_text(
+            debug_info=True)
+        assert f'kernel_name = "{kernel}"' in text
+        for metric in names:
+            hit = re.search(_pattern(metric), f"{kernel}.3") is not None
+            assert hit == (metric == own[kernel]), (kernel, metric)
 
 
 TRAIN_CFG = tfm.TransformerConfig(
