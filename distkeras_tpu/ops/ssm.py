@@ -39,9 +39,11 @@ and ``ssm_chunk_fwd`` (:func:`ssm_chunk_fwd`; one lane's prefill chunk:
 the recurrence sequential over the chunk's rows, channels along the
 lanes and the ``N`` states down the sublanes, ``exp(Delta A)`` formed
 in VMEM a row at a time, so nothing of size ``[rows, N, E]`` reaches
-HBM).  The products ``w_x`` and ``w_dt``, the inner norms and the conv
-stay in XLA beside them: ``Delta``, ``B`` and ``C`` are products of the
-conv's output, so the conv has to be done before either kernel starts.
+HBM; the rows go ``GROUP`` at a time, unrolled, so that what a row needs
+besides the state runs beside the row before).  The products ``w_x``
+and ``w_dt``, the inner norms and the conv stay in XLA beside them:
+``Delta``, ``B`` and ``C`` are products of the conv's output, so the
+conv has to be done before either kernel starts.
 """
 
 from __future__ import annotations
@@ -60,6 +62,8 @@ _LANES = 128
 SCAN_BLOCK = 16      # positions of one associative scan (chunked form)
 STEP_SUB = 512       # channels the decode kernel holds in registers at once
 CHUNK_TILE = 512     # channels of one grid step of the chunk kernel
+GROUP = 8            # rows of the chunk kernel's step: one sublane tile
+CHUNK_UNROLL = 4     # groups a trip of the chunk kernel's row loop
 VMEM_BYTES = 64 * 1024 * 1024
 
 
@@ -305,27 +309,77 @@ def ssm_state_step(rows, bc, a, d, s_all, plane, live,
 CHUNK_LENGTHS = (64, 128, 256, 512)
 
 
+def _row_sums(parts):
+    """Eight ``[GROUP, w]`` blocks ``q_0 .. q_7`` -> ONE ``[GROUP, w]``
+    whose row ``r`` is ``q_r`` summed over its rows: three rounds of
+    folds, a round halving the blocks, each fold adding a block to
+    itself rolled by ``k`` sublanes and keeping the rows whose bit ``k``
+    is clear from one block and the others from its partner.  The blocks
+    go in bit-reversed, so that row ``r`` comes out as ``q_r``'s sum."""
+    sub = jax.lax.broadcasted_iota(jnp.int32, parts[0].shape, 0)
+    parts = [parts[i] for i in (0, 4, 2, 6, 1, 5, 3, 7)]
+    for k in (4, 2, 1):
+        low = (sub & k) == 0
+        parts = [jnp.where(low, u + pltpu.roll(u, GROUP - k, 0),
+                           v + pltpu.roll(v, k, 0))
+                 for u, v in zip(parts[::2], parts[1::2])]
+    return parts[0]
+
+
 def _ssm_chunk_kernel(sc_ref, x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref,
-                      s_ref, y_ref, s_out):
+                      s_ref, y_ref, s_out, *, unroll: int = CHUNK_UNROLL):
     """One tile of channels of one lane's chunk: the tile's state ``[N,
     tile]`` comes in once, is carried in registers through the chunk's
-    REAL rows one at a time (``exp(Delta A)`` of a row formed here and
-    used at once) and goes out once.  ``sc_ref``: (plane, lane, n_real,
-    fresh)."""
+    REAL rows and goes out once.  The rows go GROUP at a time (one
+    sublane tile of ``x``, ``Delta`` and ``y``), the group's rows
+    unrolled: a row's ``exp(Delta A)``, ``Delta B x`` and ``C . s`` do
+    not wait on the row before, only ``s = exp(Delta A) s + Delta B x``
+    does, so they run beside its chain.  The group's ``y`` is stored as
+    whole registers.  A group's rows past ``n_real`` take ``Delta`` and
+    ``x`` 0 — ``exp(0) = 1`` and ``0 B`` add nothing, the state leaves
+    them as it came — and ``y`` 0; groups past the last real row are
+    only zeroed.  ``unroll`` groups a trip of the loop, the last few
+    one a trip.  ``sc_ref``: (plane, lane, n_real, fresh)."""
     n_real, fresh = sc_ref[2], sc_ref[3]
-    y_ref[...] = jnp.zeros_like(y_ref)
     a, dv = a_ref[...], d_ref[...]
-    s0 = jnp.where(fresh != 0, 0.0, s_ref[...])
+    n, tile = a.shape
+    sub = jax.lax.broadcasted_iota(jnp.int32, (GROUP, tile), 0)
 
-    def row(t, s):
-        dt = dt_ref[pl.ds(t, 1), :]                              # [1, tile]
-        x = x_ref[pl.ds(t, 1), :]
-        s = jnp.exp(dt * a) * s + (dt * x) * b_ref[t]           # b: [N, 1]
-        y_ref[pl.ds(t, 1), :] = (jnp.sum(s * c_ref[t], axis=0, keepdims=True)
-                                 + dv * x)
+    def group(g, s):
+        at = pl.multiple_of(g * GROUP, GROUP)
+        rows = pl.ds(at, GROUP)
+        real = at + sub < n_real
+        dt = jnp.where(real, dt_ref[rows, :], 0.0)              # [8, tile]
+        x = jnp.where(real, x_ref[rows, :], 0.0)
+        dtx = dt * x
+        b, c = b_ref[rows], c_ref[rows]                         # [8, N, 1]
+        parts = []
+        for r in range(GROUP):
+            s = jnp.exp(dt[r:r + 1] * a) * s + dtx[r:r + 1] * b[r]
+            p = s * c[r]
+            q = p[:GROUP]
+            for i in range(GROUP, n, GROUP):
+                q = q + p[i:i + GROUP]
+            parts.append(q)
+        y_ref[rows, :] = jnp.where(real, _row_sums(parts) + dv * x, 0.0)
         return s
 
-    s_out[...] = jax.lax.fori_loop(0, n_real, row, s0)
+    def groups(g, s):
+        for u in range(unroll):
+            s = group(g * unroll + u, s)
+        return s
+
+    used = (n_real + GROUP - 1) // GROUP
+    s = jnp.where(fresh != 0, 0.0, s_ref[...])
+    s = jax.lax.fori_loop(0, used // unroll, groups, s)
+    s_out[...] = jax.lax.fori_loop(used // unroll * unroll, used, group, s)
+
+    def zero(g, carry):
+        y_ref[pl.ds(pl.multiple_of(g * GROUP, GROUP), GROUP), :] = (
+            jnp.zeros((GROUP, tile), jnp.float32))
+        return carry
+
+    jax.lax.fori_loop(used, y_ref.shape[0] // GROUP, zero, 0)
 
 
 def use_ssm_chunk_kernel(e: int, t_len: int, rows: int, dtype,
@@ -348,10 +402,23 @@ def ssm_chunk_fwd(x, delta, bmat, cmat, a, d, s_all, plane, lane,
     slab aliased in and out with the plane and the lane
     scalar-prefetched into its index map, so the lane's state is read
     and written where it lies and no other block is touched.  Rows ``>=
-    n_real`` are padding (never reached: their ``y`` is 0); ``fresh``:
-    the state before the chunk is zero whatever the slab holds."""
+    n_real`` are padding (the state passes them unchanged, their ``y``
+    is 0); ``fresh``: the state before the chunk is zero whatever the
+    slab holds.  The rows and the ``N`` states go in whole sublane
+    tiles (multiples of GROUP)."""
+    return _chunk_call(_ssm_chunk_kernel, x, delta, bmat, cmat, a, d, s_all,
+                       plane, lane, n_real, fresh, interpret)
+
+
+def _chunk_call(kernel, x, delta, bmat, cmat, a, d, s_all, plane, lane,
+                n_real, fresh, interpret):
+    """The Pallas call of :func:`ssm_chunk_fwd` around the chunk kernel
+    ``kernel`` (``scripts/sweep_ssm.py`` hands it other forms)."""
     t_len, e = x.shape
     n = bmat.shape[1]
+    if t_len % GROUP or n % GROUP:
+        raise ValueError(f"ssm_chunk_fwd takes rows and states in multiples "
+                         f"of {GROUP}, not {t_len} and {n}")
     tile = CHUNK_TILE if e % CHUNK_TILE == 0 else _LANES
     n_real = jnp.int32(t_len) if n_real is None else n_real
     fresh = jnp.bool_(False) if fresh is None else fresh
@@ -364,7 +431,7 @@ def ssm_chunk_fwd(x, delta, bmat, cmat, a, d, s_all, plane, lane,
     f32 = jnp.float32
 
     def call(): return pl.pallas_call(
-        _ssm_chunk_kernel,
+        kernel,
         name="ssm_chunk_fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,

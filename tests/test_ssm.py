@@ -188,20 +188,33 @@ def test_kernels_lower_for_the_tpu_at_the_published_widths():
     assert "tpu_custom_call" in text and "ssm_chunk_fwd" in text
 
 
-@pytest.mark.parametrize("case", [
-    "chunk_of_64", "short_of_the_chunk", "fresh_over_garbage",
-    "two_chunks_in_a_row"])
+CHUNK_CASES = {                  # case: (rows of the chunk, n_real, fresh)
+    "chunk_of_64": (64, 64, False),
+    "short_of_the_chunk": (64, 41, False),
+    "fresh_over_garbage": (64, 64, True),
+    "two_chunks_in_a_row": (64, 64, True),
+    "one_row": (64, 1, False),
+    "under_a_group": (64, 7, False),
+    "one_group": (64, 8, False),
+    "one_past_a_group": (64, 9, False),
+    "chunk_of_128": (128, 128, False),
+    "fresh_short_of_a_group": (64, 45, True)}
+
+
+@pytest.mark.parametrize("case", list(CHUNK_CASES))
 def test_chunk_kernel_is_the_chunked_form(case):
     """``ssm_chunk_fwd`` in the TPU interpreter against the chunked form
     on the lane's state cut out of the slab: the outputs, the new state,
-    and every other (plane, lane) of the slab bit for bit what it was."""
-    n_real = 41 if case == "short_of_the_chunk" else 64
-    fresh = case in ("fresh_over_garbage", "two_chunks_in_a_row")
+    and every other (plane, lane) of the slab bit for bit what it was —
+    at row counts under, at, and one past a group of the kernel's row
+    loop (``ssm.GROUP``), a whole chunk, and a ``fresh`` one whose last
+    group is part padding."""
+    t_len, n_real, fresh = CHUNK_CASES[case]
     x, dt, bm, cm, a, d, s = _scan_inputs(128, seed=1)
     s0 = _draw(np.random.default_rng(2), 2, 3, 16, 256).at[1, 2].set(s)
-    if case == "fresh_over_garbage":
+    if fresh:
         s0 = s0.at[1, 2].multiply(1e6)
-    one, two = slice(0, 64), slice(64, 128)
+    one, two = slice(0, t_len), slice(64, 128)
     kernel = lambda part, slab, k, new: ssm.ssm_chunk_fwd(
         x[part], dt[part], bm[part], cm[part], a, d, slab, jnp.int32(1),
         jnp.int32(2), jnp.int32(k), jnp.bool_(new), interpret=True)
