@@ -1198,6 +1198,65 @@ def moe_route(moe, h, cfg: TransformerConfig):
                         / picked.sum(axis=-1, keepdims=True))
 
 
+def moe_group_rows(key, e: int, k: int):
+    """Where the held experts' grouped products take their rows from
+    and put them back, for ``key [N·k]``: each assignment's index among
+    the ``e`` held experts, ``e`` where its expert is absent (``k``
+    assignments a token, token-major).  Returns ``(padded [e], src
+    [rows], live [rows], at [N·k])``: a group's rows (its assignments
+    rounded up to row tiles), the token each row reads, whether the row
+    holds an assignment, and the row each assignment's result lies in.
+
+    The assignments go in the order of a STABLE sort by key: a group's
+    rows are its assignments in token order, those of absent experts
+    last.  That order is counted, not sorted: an assignment's place in
+    it is how many assignments come before it by (key, index), one
+    ``[N·k, N·k]`` comparison summed, and its row is its group's first
+    plus its place within the group.  Each held assignment's token is
+    scattered to its row; a row that none reaches is not ``live`` and
+    reads token 0.  No loop runs and nothing is sorted: a binary search
+    or a sort is a chain of small dependent steps on the chip, in every
+    sparse layer of every program."""
+    m = key.shape[0]
+    index = jnp.arange(m)
+    sizes = jnp.sum(key[:, None] == jnp.arange(e)[None, :], axis=0,
+                    dtype=jnp.int32)
+    place = jnp.sum((key[None, :] < key[:, None])
+                    | ((key[None, :] == key[:, None])
+                       & (index[None, :] < index[:, None])),
+                    axis=1, dtype=jnp.int32)
+    # Every group starts on a row tile of the kernel, so a group of
+    # up to a tile of rows is ONE visit of its expert's weights
+    # wherever it lies: packed end to end, a group that straddles a
+    # tile boundary is read twice, and how many do follows the
+    # routing (a 512-token chunk's ~512 held rows made 19 or 20
+    # visits by the seed's draw: serve_tok_s 1.8 % apart in two
+    # clusters on a TPU v5e).  The row count stays a static bound:
+    # all N*k assignments and a tile of slack a group.
+    # A group WITHOUT rows keeps one tile (of zero rows): every held
+    # expert's weights are read in every product, so the product's
+    # time follows the shapes and not the routing.  Skipped, an
+    # expert no token reached made a step faster by its 75 MB, and
+    # how many are reached is the router's skew: under seeded random
+    # weights one layer's products took 0.71 of another's in one
+    # run, and the same requests ran 0.9 to 5.7 % faster by the
+    # seed (6,979-7,282 tokens/s over 12 seeds; 6,865-6,934 with
+    # the tile kept; TPU v5e) — where a share of a deployment
+    # sees its experts reached by every chip's tokens.
+    tile = GROUP_TILE
+    padded = jnp.maximum(-(-sizes // tile), 1) * tile
+    first = jnp.cumsum(sizes) - sizes          # in the sorted order
+    base = jnp.cumsum(padded) - padded         # in the padded rows
+    slot = jnp.minimum(key, e - 1)
+    at = base[slot] + place - first[slot]
+    # An absent expert's assignment points past the last group's
+    # assignments: a row the caller selects away, and none it writes.
+    rows = -(-m // tile) * tile + e * tile
+    token = jnp.zeros((rows,), jnp.int32).at[
+        jnp.where(key < e, at, rows)].set(index // k + 1, mode="drop")
+    return padded, jnp.maximum(token - 1, 0), token > 0, at
+
+
 def moe_held_experts(moe, h, expert, gates, cfg: TransformerConfig,
                      layer=None):
     """The HELD experts' part of a sparse layer for ``h [N, D]`` routed
@@ -1207,14 +1266,13 @@ def moe_held_experts(moe, h, expert, gates, cfg: TransformerConfig,
     experts would add is left out).
 
     One path for a prefill chunk and a decode step: the N·k
-    assignments are sorted by held expert (those of absent experts
-    last) into rows grouped by expert, one grouped product a
-    projection over the held experts' stacked weights
-    (``ops.grouped.grouped_matmul``: on the TPU a kernel that walks
-    only the row tiles the groups fill), and each token gathers its k
-    rows back and sums them under its gates.  No capacity: the row
-    count is the assignments', so no token is dropped whatever the
-    routing.
+    assignments are grouped by held expert (:func:`moe_group_rows`)
+    into rows, one grouped product a projection over the held experts'
+    stacked weights (``ops.grouped.grouped_matmul``: on the TPU a
+    kernel that walks only the row tiles the groups fill), and each
+    token gathers its k rows back and sums them under its gates.  No
+    capacity: the row count is the assignments', so no token is
+    dropped whatever the routing.
 
     ``layer`` (traced int32): ``moe["w13"]`` and ``moe["w2"]`` are a
     GROUP's stacked leaves ``[layers, held, ...]`` and this is layer
@@ -1231,38 +1289,7 @@ def moe_held_experts(moe, h, expert, gates, cfg: TransformerConfig,
         table[list(held)] = np.arange(e, dtype=np.int32)
         local = jnp.asarray(table)[expert]                    # [N, k]
         key = local.reshape(-1)
-        order = jnp.argsort(key)
-        sizes = jnp.sum(key[:, None] == jnp.arange(e)[None, :], axis=0,
-                        dtype=jnp.int32)
-        # Every group starts on a row tile of the kernel, so a group of
-        # up to a tile of rows is ONE visit of its expert's weights
-        # wherever it lies: packed end to end, a group that straddles a
-        # tile boundary is read twice, and how many do follows the
-        # routing (a 512-token chunk's ~512 held rows made 19 or 20
-        # visits by the seed's draw: serve_tok_s 1.8 % apart in two
-        # clusters; chip, PR 31).  The row count stays a static bound:
-        # all N*k assignments and a tile of slack a group.
-        # A group WITHOUT rows keeps one tile (of zero rows): every held
-        # expert's weights are read in every product, so the product's
-        # time follows the shapes and not the routing.  Skipped, an
-        # expert no token reached made a step faster by its 75 MB, and
-        # how many are reached is the router's skew: under seeded random
-        # weights one layer's products took 0.71 of another's in one
-        # run, and the same requests ran 0.9 to 5.7 % faster by the
-        # seed (6,979-7,282 tokens/s over 12 seeds; 6,865-6,934 with
-        # the tile kept; chip, PR 31) — where a share of a deployment
-        # sees its experts reached by every chip's tokens.
-        tile = GROUP_TILE
-        padded = jnp.maximum(-(-sizes // tile), 1) * tile
-        first = jnp.cumsum(sizes) - sizes          # in the sorted order
-        base = jnp.cumsum(padded) - padded         # in the padded rows
-        n_rows = -(-n * k // tile) * tile + e * tile
-        row = jnp.arange(n_rows)
-        group = jnp.minimum(jnp.searchsorted(base + padded, row,
-                                             side="right"), e - 1)
-        within = row - base[group]
-        live = within < sizes[group]
-        src = order[jnp.clip(first[group] + within, 0, n * k - 1)] // k
+        padded, src, live, at = moe_group_rows(key, e, k)
         rows = jnp.where(live[:, None], h[src], 0)
         w13, w2 = moe["w13"], moe["w2"]
         if layer is not None:
@@ -1276,8 +1303,6 @@ def moe_held_experts(moe, h, expert, gates, cfg: TransformerConfig,
                            w2.astype(h.dtype), padded)
         # Each assignment's row back (an absent expert's: any row,
         # selected away), summed over a token's k under its gates.
-        slot = jnp.minimum(key, e - 1)
-        at = base[slot] + jnp.argsort(order) - first[slot]
         y = jnp.where((key < e)[:, None], y[at].astype(jnp.float32), 0.0)
         out = (y * gates.reshape(-1)[:, None]).reshape(n, k, -1).sum(axis=1)
         return out, local

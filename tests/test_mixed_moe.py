@@ -12,7 +12,9 @@ weights, tiny sizes (heads of 32 over a 64-wide stream, so that
     full forward; what the engine says of its slab and its rounds;
 (c) the shares add up: every share's routed part and the shared
     expert once = the uncut layer; no token is dropped when the
-    routing is forced onto one expert;
+    routing is forced onto one expert; the assignments counted into
+    their groups are where a stable sort puts them, bit for bit, and
+    the count lowers with no loop and no sort;
 (d) a stack typed full and dense everywhere is the untyped stack; the
     paths that do not run a typed stack say so by name.
 """
@@ -306,6 +308,140 @@ def test_a_held_expert_no_token_reached_keeps_its_tile(ref, monkeypatch):
         for sizes in seen)
     np.testing.assert_allclose(np.asarray(out),
                                ref.sparse_layer(lp, TC, h), atol=TOL)
+
+
+# --------------- (c') grouped by counting as a stable sort groups them
+
+
+def _sorted_held_experts(moe, h, expert, gates, cfg, layer=None):
+    """``moe_held_experts`` as it stood with its assignments grouped by
+    ``argsort`` and a row's group found by ``searchsorted`` — the
+    oracle, kept verbatim — returning ``(out, local, rows, src, at,
+    live)``."""
+    n, k = expert.shape
+    held = cfg.experts_held
+    e = len(held)
+    table = np.full((cfg.num_experts,), e, np.int32)
+    table[list(held)] = np.arange(e, dtype=np.int32)
+    local = jnp.asarray(table)[expert]                    # [N, k]
+    key = local.reshape(-1)
+    order = jnp.argsort(key)
+    sizes = jnp.sum(key[:, None] == jnp.arange(e)[None, :], axis=0,
+                    dtype=jnp.int32)
+    tile = tfm.GROUP_TILE
+    padded = jnp.maximum(-(-sizes // tile), 1) * tile
+    first = jnp.cumsum(sizes) - sizes          # in the sorted order
+    base = jnp.cumsum(padded) - padded         # in the padded rows
+    n_rows = -(-n * k // tile) * tile + e * tile
+    row = jnp.arange(n_rows)
+    group = jnp.minimum(jnp.searchsorted(base + padded, row,
+                                         side="right"), e - 1)
+    within = row - base[group]
+    live = within < sizes[group]
+    src = order[jnp.clip(first[group] + within, 0, n * k - 1)] // k
+    rows = jnp.where(live[:, None], h[src], 0)
+    w13, w2 = moe["w13"], moe["w2"]
+    if layer is not None:
+        padded = jax.lax.dynamic_update_slice(
+            jnp.zeros((w13.shape[0] * e,), jnp.int32), padded,
+            (layer * e,))
+        w13, w2 = (a.reshape((-1,) + a.shape[2:]) for a in (w13, w2))
+    up = tfm.grouped_matmul(rows, w13.astype(h.dtype), padded)
+    f = up.shape[-1] // 2
+    y = tfm.grouped_matmul(jax.nn.silu(up[:, :f]) * up[:, f:],
+                           w2.astype(h.dtype), padded)
+    slot = jnp.minimum(key, e - 1)
+    at = base[slot] + jnp.argsort(order) - first[slot]
+    y = jnp.where((key < e)[:, None], y[at].astype(jnp.float32), 0.0)
+    out = (y * gates.reshape(-1)[:, None]).reshape(n, k, -1).sum(axis=1)
+    return out, local, rows, src, at, live
+
+
+def _grouped_held_experts(moe, h, expert, gates, cfg, layer=None):
+    """The program's ``moe_held_experts`` and the rows, sources, places
+    back and live rows that its ``moe_group_rows`` gives."""
+    out, local = tfm.moe_held_experts(moe, h, expert, gates, cfg, layer)
+    e = len(cfg.experts_held)
+    _, src, live, at = tfm.moe_group_rows(local.reshape(-1), e,
+                                          expert.shape[1])
+    return out, local, jnp.where(live[:, None], h[src], 0), src, at, live
+
+
+def _routes(n, k, e, routing, rng):
+    """``expert [N, k]`` over 8e experts of which ``held`` (every
+    eighth id, from 3) are here: k distinct picks a token, as a router
+    makes them, or forced by ``routing``."""
+    held = np.arange(3, 8 * e, 8)
+    pick = np.argsort(rng.random((n, 8 * e)), axis=1)[:, :k]
+    absent = np.setdiff1d(np.arange(8 * e), held)
+    if routing == "absent":       # no assignment is on a held expert
+        pick = absent[pick % absent.size]
+    elif routing == "one":        # every assignment on held expert 5
+        pick = np.full((n, k), held[5])
+    elif routing == "half":       # held experts no token reached
+        pick = np.where(np.isin(pick, held[e // 2:]), absent[0], pick)
+    return tuple(held), pick.astype(np.int32)
+
+
+@pytest.mark.parametrize("n,k,e,routing,stacked", [
+    (29, 8, 32, "router", False),     # joyai's step
+    (202, 8, 16, "router", False),    # kexaone's step
+    (512, 8, 32, "router", False),    # joyai's chunk
+    (512, 8, 16, "router", False),    # kexaone's chunk
+    (29, 8, 32, "absent", False),
+    (29, 8, 32, "one", False),        # one group of two tiles
+    (202, 8, 16, "half", False),
+    (3, 5, 4, "router", False),       # N·k = 15: under a tile
+    (29, 8, 32, "router", True)])     # a group's stack, layer 1 of 3
+def test_grouping_by_compare_and_count_is_the_sorts_bit_for_bit(
+        n, k, e, routing, stacked):
+    """The assignments counted into place are the stable sort's: the
+    same rows, the same live rows and their sources, the same places
+    back, and so the same grouped products and the same ``out`` and
+    ``local``, equal and not close.  (A row that holds no assignment is
+    zero on both sides; the token it would have read is nobody's.)"""
+    rng = np.random.default_rng(n * 1000 + e)
+    held, expert = _routes(n, k, e, routing, rng)
+    cfg = dataclasses.replace(CFG, num_experts=8 * e, moe_top_k=k,
+                              moe_held=held, d_model=16, moe_d_ff=8)
+    layers = 3 if stacked else None
+    lead = (layers,) if stacked else ()
+    draw = lambda *s: jnp.asarray(rng.normal(size=s), jnp.bfloat16)
+    moe = {"w13": draw(*lead, e, 16, 16), "w2": draw(*lead, e, 8, 16)}
+    h = draw(n, 16)
+    gates = jnp.asarray(rng.random((n, k)), jnp.float32)
+    layer = jnp.int32(1) if stacked else None
+    want = jax.jit(_sorted_held_experts, static_argnums=4)(
+        moe, h, jnp.asarray(expert), gates, cfg, layer)
+    got = jax.jit(_grouped_held_experts, static_argnums=4)(
+        moe, h, jnp.asarray(expert), gates, cfg, layer)
+    got, want = ([np.asarray(a) for a in side] for side in (got, want))
+    live = want[5]
+    got[3], want[3] = got[3][live], want[3][live]
+    for name, a, b in zip(("out", "local", "rows", "src", "at", "live"),
+                          got, want):
+        np.testing.assert_array_equal(a, b, err_msg=name)
+    if routing == "router":
+        assert 0 < (got[1] < e).sum() < n * k
+
+
+@pytest.mark.parametrize("n", [29, 512])
+def test_grouping_lowers_to_no_loop_and_no_sort(n):
+    """At the latent cell's shapes (a step's 29 rows and a chunk's 512,
+    top-8 over 32 held experts of 2,048 x 768), the held experts' part
+    lowers with no ``while`` and no ``sort``: the sorted form held one
+    of each, a binary search and the argsorts."""
+    cfg = tfm.TransformerConfig(**{**LATENT_TC, "d_model": 2048,
+                                   "moe_d_ff": 768})
+    shape = jax.ShapeDtypeStruct
+    moe = {"w13": shape((32, 2048, 1536), jnp.bfloat16),
+           "w2": shape((32, 768, 2048), jnp.bfloat16)}
+    args = (moe, shape((n, 2048), jnp.bfloat16), shape((n, 8), jnp.int32),
+            shape((n, 8), jnp.float32))
+    for fn, loops in ((tfm.moe_held_experts, 0), (_sorted_held_experts, 1)):
+        text = jax.jit(fn, static_argnums=4).lower(*args, cfg).as_text()
+        assert text.count("stablehlo.while") == loops, fn.__name__
+        assert (text.count("stablehlo.sort") > 0) == bool(loops), fn.__name__
 
 
 # ------------------------------------------- (d) the edges of the stack
