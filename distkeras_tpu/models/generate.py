@@ -28,7 +28,6 @@ import numpy as np
 from distkeras_tpu.models.transformer import (
     TransformerConfig,
     _moe_dense_block,
-    _moe_gates,
     _rms_norm,
     _unembed,
     block_apply,
@@ -202,10 +201,10 @@ def prefill(params, prompt, cfg: TransformerConfig,
     the unused head anyway, the flag keeps eager callers cheap too).
 
     MoE configs prefill with the same capacity-free dense top-k
-    routing as ``_decode_step`` (``_moe_gates`` — Switch top-1 or
-    renormalized top-2) — every expert runs on every token (E x the
-    dense-FFN compute; prefill happens once) and the selected experts'
-    outputs are gathered, so prefilled and sequential prompt
+    routing as the cached decode (``transformer._moe_dense_block`` —
+    Switch top-1 or renormalized top-2): every expert runs on every
+    token (E x the dense-FFN compute) and the selected experts'
+    outputs are combined, so prefilled and sequential prompt
     processing match exactly (the train/decode MoE divergence caveat in
     ``generate`` is unchanged).
     """
@@ -272,8 +271,9 @@ def prefill(params, prompt, cfg: TransformerConfig,
 
 def _ancestry_attend(qg, ck, cv, anc_oh, mask_b, cfg: TransformerConfig,
                      w_beams: int, kv_scales=None):
-    """Beam ancestry attention for ONE position, shared by the
-    full-cache chunk body and the windowed ring-buffer body.
+    """Beam ancestry attention for ONE position: what a beam search's
+    layer attends in :func:`_chunk_in_place`, over a full cache or a
+    ring.
 
     ``qg [B, kv_heads, groups, hd]`` f32 queries (beam lanes tiled
     batch-major, B = bt * W), ``ck/cv [B, S, kv_heads, hd]`` the
@@ -316,253 +316,42 @@ def _ancestry_attend(qg, ck, cv, anc_oh, mask_b, cfg: TransformerConfig,
         b, cfg.n_heads, cfg.head_dim)
 
 
-_BASE_ONLY = "a windowed (rolling) or ragged-prompt (prompt_lengths) decode"
-
-
 def _decode_step(params, cache, tokens, pos, cfg: TransformerConfig,
                  pad_lens=None, beam_anc=None):
-    """One position: tokens [B] at position ``pos`` -> (logits [B, V], cache).
-
-    Attention reads the cache up to ``pos`` with a position mask (static
-    shapes; masked slots contribute exp(NEG_INF-ish) = 0).
+    """One position: tokens [B] at position ``pos`` -> (logits [B, V], cache)
+    — :func:`_decode_chunk` with T = 1, every row at ``pos``.
 
     ``pad_lens [B]`` supports left-padded batches (ragged prompts
     aligned at their ends): positions < pad_lens[i] are excluded from
     row i's attention forever, and position *ids* (rotary angles /
     pos_emb rows) count from the row's true start, so each row decodes
-    exactly as it would alone.
-
-    The plain path (no window, no padding) delegates to
-    :func:`_decode_chunk` with T = 1 — ONE layer-body definition for
-    both; only the ring-buffer slot arithmetic and the ragged pad
-    masking below justify a separate body.
-    """
-    dtype = jnp.dtype(cfg.dtype)
+    exactly as it would alone."""
     b = tokens.shape[0]
-    if cfg.attention_window is not None or pad_lens is not None:
-        reject_extended(cfg, _BASE_ONLY)
-    if cfg.attention_window is None and pad_lens is None:
-        out, cache = _decode_chunk(params, cache, tokens[:, None],
-                                   jnp.full((b,), pos, jnp.int32), cfg,
-                                   uniform_pos=True, beam_anc=beam_anc)
-        return out[:, 0], cache
-    if beam_anc is not None and pad_lens is not None:
-        raise ValueError("beam ancestry attention does not compose with "
-                         "pad_lens (beam search is uniform-prompt only)")
-    if beam_anc is not None:
-        anc, w_beams = beam_anc
-        anc_oh = jax.nn.one_hot(anc, w_beams, dtype=jnp.float32)
-    kv_q = "k_scale" in cache                   # int8 KV cache
-    with jax.named_scope("embed"):
-        x = embed_rows(params["tok_emb"], tokens, dtype)  # [B, D]
-        if pad_lens is None:
-            pos_ids = jnp.full((b,), pos)
-        else:
-            pos_ids = jnp.maximum(pos - pad_lens, 0)
-        rope_ang = None
-        if cfg.rope:
-            # [B, half] per-row angles; broadcast over heads.
-            rope_ang = rope_angles(pos_ids, cfg.head_dim,
-                                   cfg.rope_theta)[:, None, :]
-        else:
-            x = x + params["pos_emb"][pos_ids].astype(dtype)
-
-    ck_all, cv_all = cache["k"], cache["v"]     # [L, B, S, kv, hd]
-    if kv_q:
-        cks_all, cvs_all = cache["k_scale"], cache["v_scale"]
-    # [B, S, C] scale -> broadcast over the [B, C, G, S] logits.
-    sc_b = lambda s: s.transpose(0, 2, 1)[:, :, None, :]
-    for i in range(cfg.n_layers):
-        lp = jax.tree.map(lambda a: a[i], params["layers"])
-        h = _rms_norm(x, lp["ln1_scale"])
-        with jax.named_scope("attn_proj"):
-            # deq: int8 weights dequantize here (fused into the matmul
-            # read); plain trees pass through untouched.
-            q = jnp.einsum("bd,dhk->bhk", h, deq(lp["attn"]["wq"]))
-            # Cache dtype: the einsum promotes bf16 activations x f32
-            # weights to f32; the cache stays in the compute dtype.
-            k = jnp.einsum("bd,dhk->bhk", h, deq(lp["attn"]["wk"]))
-            v = jnp.einsum("bd,dhk->bhk", h, deq(lp["attn"]["wv"]))
-            if rope_ang is not None:
-                # Keys cache post-rotation (each key's rotation depends
-                # only on its own position), matching the training
-                # forward.
-                q, k = rope_rotate(q, rope_ang), rope_rotate(k, rope_ang)
-            if kv_q:  # post-rotation, like the bf16 cache
-                k, k_s = quantize_kv(k)               # scale [B, C]
-                v, v_s = quantize_kv(v)
-        # Windowed configs write the ring-buffer slot pos % C (identical
-        # to pos while pos < C): with window <= C the cache then
-        # supports generation beyond max_len (rolling decode) — the
-        # int8 scales ride the same slot arithmetic.
-        slot = jnp.asarray(pos % cfg.max_len if cfg.attention_window
-                           else pos, jnp.int32)
-        with jax.named_scope("kv_slab"):
-            ck_all = _layer_slab_update(ck_all, i, k[:, None], slot)
-            cv_all = _layer_slab_update(cv_all, i, v[:, None], slot)
-            ck, cv = ck_all[i], cv_all[i]
-            if kv_q:
-                cks_all = _layer_slab_update(cks_all, i, k_s[:, None],
-                                             slot)
-                cvs_all = _layer_slab_update(cvs_all, i, v_s[:, None],
-                                             slot)
-                cks, cvs = cks_all[i], cvs_all[i]
-
-        with jax.named_scope("attn"):
-            # GQA: grouped einsums read only the kv-head cache — never
-            # materialize an expanded per-query-head copy (that repeat
-            # would forfeit the cache-bandwidth saving that is GQA's point).
-            groups = cfg.n_heads // cfg.kv_heads
-            qg = q.astype(jnp.float32).reshape(
-                b, cfg.kv_heads, groups, cfg.head_dim)
-            span = jnp.arange(cfg.max_len)
-            if cfg.attention_window is not None:
-                # Ring-buffer band: slot s holds global position
-                # g = pos - ((pos - s) mod C).  Keep iff the position is
-                # real (g >= 0 — this also excludes every future slot while
-                # pos < C, so prefilled prompts stay causal) and inside the
-                # window (delta < W).  For pos < C this reduces exactly to
-                # span in (pos - W, pos]; for pos >= C it implements the
-                # rolling window.  Distances are pad-invariant, so the
-                # ragged pad mask below composes unchanged.
-                delta = jnp.mod(pos - span, cfg.max_len)
-                row_mask = (delta < cfg.attention_window) & (pos - delta >= 0)
-            else:
-                row_mask = span <= pos
-            if beam_anc is not None:
-                # Windowed beam ancestry: the ancestor map is SLOT-indexed
-                # (identical to positions until the ring wraps; under
-                # rolling decode the beam body retires stale entries as
-                # slots are rewritten) and only the band mask differs from
-                # the full-cache path.
-                bt = b // w_beams
-                mask_b = jnp.broadcast_to(row_mask[None, None, :],
-                                          (bt, w_beams, cfg.max_len))
-                attn = _ancestry_attend(qg, ck, cv, anc_oh, mask_b, cfg,
-                                        w_beams,
-                                        kv_scales=(cks, cvs) if kv_q
-                                        else None)
-            else:
-                logits = jnp.einsum("bcgk,bsck->bcgs", qg,
-                                    ck.astype(jnp.float32))
-                if kv_q:
-                    logits = logits * sc_b(cks)
-                logits = logits / jnp.sqrt(jnp.float32(cfg.head_dim))
-                mask = row_mask[None, None, None, :]
-                if pad_lens is not None:  # left-pad slots never attend
-                    mask = mask & (span[None, :] >= pad_lens[:, None]
-                                   )[:, None, None, :]
-                logits = jnp.where(mask, logits, -1e30)
-                probs = jax.nn.softmax(logits, axis=-1)
-                attn = jnp.einsum("bcgs,bsck->bcgk",
-                                  probs * sc_b(cvs) if kv_q else probs,
-                                  cv.astype(jnp.float32)).reshape(
-                    b, cfg.n_heads, cfg.head_dim)
-        with jax.named_scope("attn_proj"):
-            x = x + jnp.einsum("bhk,hkd->bd", attn.astype(dtype),
-                               deq(lp["attn"]["wo"]))
-
-        h = _rms_norm(x, lp["ln2_scale"])
-        with jax.named_scope("mlp"):
-            if cfg.num_experts:
-                # Decode-time MoE: dense top-k without capacity (batch is
-                # small; correctness over dispatch efficiency).  Same
-                # gate rule as training/prefill via _moe_gates.
-                router = jnp.einsum("bd,de->be", h.astype(jnp.float32),
-                                    lp["moe"]["wg"])
-                probs = jax.nn.softmax(router, axis=-1)
-                gates, expert = _moe_gates(probs, cfg)   # [B, k]
-                w1 = lp["moe"]["w1"][expert]  # [B, k, D, F]
-                w2 = lp["moe"]["w2"][expert]  # [B, k, F, D]
-                hk = jax.nn.gelu(jnp.einsum("bd,bkdf->bkf", h,
-                                            w1.astype(dtype)))
-                yk = jnp.einsum("bkf,bkfd->bkd", hk, w2.astype(dtype))
-                y = jnp.einsum("bkd,bk->bd", yk, gates.astype(dtype))
-            else:
-                y = jnp.einsum(
-                    "bf,fd->bd",
-                    jax.nn.gelu(jnp.einsum("bd,df->bf", h,
-                                           deq(lp["ffn"]["w1"]))),
-                    deq(lp["ffn"]["w2"]))
-            x = x + y
-
-    x = _rms_norm(x, params["ln_f_scale"])
-    # Vocab head: int8 trees contract the raw q table and scale the
-    # result (int8 stays the HBM operand by construction — see
-    # quant.unembed_logits), instead of dequantizing [V, d] per step.
-    with jax.named_scope("head"):
-        out = unembed_logits(x, params["tok_emb"], dtype)
-    cache = {"k": ck_all, "v": cv_all}
-    if kv_q:
-        cache["k_scale"], cache["v_scale"] = cks_all, cvs_all
-    return out.astype(jnp.float32), cache
-
-
-def _rows_update(cache_layer, rows, pos0):
-    """Write ``rows [B, T, kv, hd]`` into ``cache_layer [B, S, kv, hd]``
-    at per-row offsets ``pos0 [B]`` (a batched dynamic_update_slice —
-    XLA lowers the vmap to a scatter).  Callers clamp pos0 to S - T;
-    dynamic_update_slice would silently shift an out-of-range write."""
-    return jax.vmap(
-        lambda c, r, p: jax.lax.dynamic_update_slice(
-            c, r.astype(c.dtype),
-            (p,) + (0,) * (c.ndim - 1)))(cache_layer, rows, pos0)
-
-
-def _rows_update_ring(cache_layer, rows, pos0, max_len):
-    """Per-row T-span write at ring slots ``(pos0[b] + t) % max_len`` —
-    the modular generalization of :func:`_rows_update` for windowed
-    chunks that may WRAP mid-chunk (speculative decoding's divergent
-    per-row positions on a ring cache).  A per-row gather-scatter
-    (``c.at[idx].set``): a dynamic_update_slice span cannot wrap."""
-    t_len = rows.shape[1]
-    idx = (pos0[:, None] + jnp.arange(t_len)) % max_len    # [B, T]
-    return jax.vmap(lambda c, r, ix: c.at[ix].set(
-        r.astype(c.dtype)))(cache_layer, rows, idx)
-
-
-def _layer_slab_update(cache_all, i, rows, pos):
-    """Write ``rows [B, T, kv, hd]`` (all rows at position ``pos``) into
-    layer ``i`` of the stacked cache ``[L, B, S, kv, hd]`` — WITHOUT
-    slicing the layer out and restacking.
-
-    The decode loop is bandwidth-bound and the cache is its largest
-    buffer; the old per-layer ``cache[i]`` + ``jnp.stack`` pattern made
-    XLA materialize a full cache copy every step (~6.5 ms per tensor
-    per step at batch 64, the dominant term of the b64 decode cliff),
-    where this slab dynamic_update_slice stays in place (~0.1 ms;
-    measured 2026-07-31 on one v5e, not re-measured since).
-
-    Uniform-position writes only.  Per-row offsets (speculative
-    decoding) keep the per-layer ``_rows_update`` + one final stack:
-    scatters addressed through axis 1 of the stacked array compile to
-    layouts that cost MORE than the single stack copy (measured —
-    speculative throughput dropped 2.8x when routed through a
-    batch-axis vmap over the stacked cache).
-    """
-    zero = jnp.int32(0)
-    starts = (jnp.int32(i), zero, pos) + (zero,) * (cache_all.ndim - 3)
-    return jax.lax.dynamic_update_slice(
-        cache_all, rows.astype(cache_all.dtype)[None], starts)
+    out, cache = _decode_chunk(params, cache, tokens[:, None],
+                               jnp.full((b,), pos, jnp.int32), cfg,
+                               uniform_pos=True, beam_anc=beam_anc,
+                               pad_lens=pad_lens)
+    return out[:, 0], cache
 
 
 def chunk_attends_prefix(cfg: TransformerConfig, t_len: int, cache,
-                         uniform_pos: bool = True, beam: bool = False,
+                         uniform_pos: bool = True,
                          sharded: bool = False) -> bool:
     """Whether :func:`_decode_chunk` compiles the BOUNDED attention
     (``ops.attention.flash_prefix_attention``: the chunk's queries read
     cache slots ``[0, pos0 + T)`` only) for a ``t_len``-token chunk
-    against ``cache``, or its dense body over all ``max_len`` slots.
+    against ``cache``, or the dense attention over all ``max_len``
+    slots.
 
     One static predicate on what the call already holds: a multi-token
-    chunk at one position for every row, no beam ancestry, no ring, no
-    int8 scales; a TPU backend, one device, shapes the kernel tiles.
-    T = 1 (every decode step) and per-row positions (speculative
-    verify) are :func:`decode_attends_prefix`'s; windowed, int8 and CPU
-    calls keep the dense body, which is also the kernels' oracle in
-    tests.  The serving engines ask the same question for their
-    admission spans' ``attended`` field."""
-    return (uniform_pos and t_len > 1 and not beam
+    chunk at one position for every row, no ring, no int8 scales; a
+    TPU backend, one device, shapes the kernel tiles.  T = 1 (every
+    decode step, beam search's too) and per-row positions (speculative
+    verify) are :func:`decode_attends_prefix`'s; windowed, int8 and
+    CPU calls keep the dense attention, which is also the kernels'
+    oracle in tests.  The serving engines ask the same question for
+    their admission spans' ``attended`` field."""
+    return (uniform_pos and t_len > 1
             and cfg.attention_window is None and "k_scale" not in cache
             and bool(cfg.kv_planes)
             and use_flash_prefix(t_len, cfg.max_len, cfg.head_dim,
@@ -576,8 +365,8 @@ def decode_attends_prefix(cfg: TransformerConfig, t_len: int, cache,
     """Whether :func:`_chunk_in_place` compiles the PER-LANE bounded
     attention (``ops.attention.flash_decode_attention``: lane ``b``
     reads the blocks holding slots ``< pos0[b]`` of its row, straight
-    from the slab) or its dense body over all ``max_len`` slots of
-    every lane.
+    from the slab) or the dense attention over all ``max_len`` slots
+    of every lane.
 
     :func:`chunk_attends_prefix`'s sibling, for the calls that one
     leaves: T = 1 (every decode step) and per-row chunks (speculative
@@ -602,9 +391,9 @@ def latent_chunk_expands(cfg: TransformerConfig, t_len: int, cache,
     ``ops.latent.mla_prefix_attention`` (one row — an admission — on a
     TPU, shapes the kernel tiles: a block's keys and values rebuilt in
     VMEM from the slab's rows where they lie, cost by the attended rows
-    ``[0, pos0 + T)``), or absorbed in the dense body over all
-    ``max_len``.  The serving engines ask it for their admission
-    spans' ``attended`` too."""
+    ``[0, pos0 + T)``), or absorbed over all ``max_len`` slots.  The
+    serving engines ask it for their admission spans' ``attended``
+    too."""
     return (rows == 1 and t_len > 1 and bool(cfg.latent_planes)
             and use_mla_prefix(
                 t_len, cfg.max_len, cfg.qk_nope_head_dim,
@@ -689,34 +478,11 @@ def _attend_before_and_chunk(q, k, v, ck_all, cv_all, plane, pos0,
     return out.reshape(b, t_len, h, d)
 
 
-def base_body_only(cfg: TransformerConfig, params, cache,
-                   beam: bool = False) -> str | None:
-    """What keeps a :func:`_decode_chunk` call on the base body, by
-    name — or None: the call takes :func:`_chunk_in_place`.  One static
-    question on what the call already holds (the serving engines ask
-    it of their live state to choose the admission program): a ring, a
-    MoE feed-forward, beam ancestry, int8 scales in the cache, int8
-    weights, a cache the compiler partitions."""
-    if cfg.attention_window is not None:
-        return "a windowed (rolling) decode"
-    if cfg.num_experts and not cfg.typed:
-        return "a MoE feed-forward (num_experts > 0) without ffn_types"
-    if beam:
-        return "beam search"
-    if "k_scale" in cache:
-        return "the int8 KV cache (kv_int8)"
-    if is_quantized(params):
-        return "int8-quantized weights (models/quant)"
-    if is_partitioned(cache["k"]):
-        return "a partitioned cache (a tensor-parallel serving_plan)"
-    return None
-
-
 def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
                     uniform_pos: bool = False, lane=None, n_real=None,
-                    with_routes: bool = False, live=None, chunk=None):
-    """:func:`_decode_chunk`'s body for every call that
-    :func:`base_body_only` does not hold back: ``tokens [B, T]`` at
+                    with_routes: bool = False, live=None, chunk=None,
+                    beam_anc=None, pad_lens=None):
+    """:func:`_decode_chunk`'s body, for every call: ``tokens [B, T]`` at
     positions ``pos0[b] + (0..T-1)`` -> ``(logits [B, T, V] f32, cache)``
     — of the LAST pass where the stack is looped (``cfg.n_passes``
     applications of the same weights).  It implements the extended
@@ -732,8 +498,8 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
     **The slab is never copied.**  Inside the loops it is read-only: a
     layer attends its plane's slots STRICTLY BEFORE the chunk (mask
     ``slot < pos0[b]``) together with the chunk's own K/V, which it
-    holds in registers, under one softmax — the same sum the base body
-    takes over a cache it has already written into.  The chunk's K/V
+    holds in registers, under one softmax — the same sum as over a
+    cache the chunk had already been written into.  The chunk's K/V
     of every plane leave the loops as scan outputs and are written
     once, at the end, in place (the caller donates the cache): one
     ``dynamic_update_slice`` where every row is at one position, one
@@ -823,7 +589,7 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
     TPU attends EXPANDED (:func:`latent_chunk_expands`): its rows go
     into the slab first, where it lies, and ``mla_prefix_attention``
     rebuilds a block's keys and values in VMEM; every other chunk
-    absorbed in the dense body.  Slots as a full plane's: a
+    absorbed, over all ``max_len`` slots.  Slots as a full plane's: a
     stale one is masked by position, padding lands past the frontier,
     ``n_real`` is not needed.
 
@@ -835,7 +601,24 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
     (one row on the TPU: ``ssm_chunk_fwd`` where it lies), ``n_real``
     keeps an admission's padding out of both, and a row at position 0
     starts from zeros.  Full layers may stand among ssm layers: the
-    stack then holds slots and states side by side."""
+    stack then holds slots and states side by side.
+
+    **The base block's own features** (an extended stack refuses each:
+    :func:`_decode_chunk`) are each a substitution at the one place
+    where the layer differs, chosen by what the call holds and the
+    identity where it does not hold it: an ``attention_window`` ring
+    (the masks: slot ``s`` of a plane of ``max_len`` slots holds the
+    latest position ``= s (mod max_len)`` before the chunk, as a typed
+    ring's; the write at the end modulo ``max_len``); ``pad_lens``
+    (position ids from each row's start, its pad slots masked);
+    ``beam_anc`` (the attention is :func:`_ancestry_attend` over the
+    plane's rows with the row's own K/V written in); int8 K/V
+    (``"k_scale" in cache``: the scale planes take the writes the K/V
+    planes take and scale the logits and probabilities where they are
+    read); int8 weights (:func:`~distkeras_tpu.models.quant.deq` where
+    a layer reads them, the quantized embedding and head); an untyped
+    MoE (``transformer._moe_dense_block`` as the feed-forward); a
+    partitioned cache (no kernel: the dense attention)."""
     dtype = jnp.dtype(cfg.dtype)
     b, t_len = tokens.shape
     n_layers, s_len = cfg.n_layers, cfg.max_len
@@ -864,36 +647,63 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
     # kv, S, hd] and the rings [window layers, B|lanes, kv, ring + park, hd].
     ck_all, cv_all = cache["k"], cache["v"]
     eps, pre_norm = cfg.norm_eps, cfg.post_norms != "only"
+    # The base block's features (the docstring's last paragraph): an
+    # untyped ring of max_len slots, int8 K/V, int8 weights.
+    ring, kv_q = cfg.attention_window, "k_scale" in cache
+    quantized = is_quantized(params)
     with jax.named_scope("embed"):
-        x = params["tok_emb"][tokens].astype(dtype)           # [B, T, D]
+        x = embed_rows(params["tok_emb"], tokens, dtype)      # [B, T, D]
         pos_ids = pos0[:, None] + jnp.arange(t_len)[None, :]  # [B, T]
         if chunk is not None:                                 # [1, B + C]
             pos_ids = jnp.concatenate(
                 [pos0[None, :], c_off[:, None] + jnp.arange(c_len)[None, :]],
                 axis=1)
+        # A ragged row's position ids count from its first real token.
+        ids = (pos_ids if pad_lens is None
+               else jnp.maximum(pos_ids - pad_lens[:, None], 0))
         rope_ang = None
         if cfg.rope:
-            rope_ang = rope_angles(pos_ids, cfg.rope_dim,
+            rope_ang = rope_angles(ids, cfg.rope_dim,
                                    cfg.rope_theta)[:, :, None, :]
         else:
-            x = x + params["pos_emb"][pos_ids].astype(dtype)
-    sharded = is_partitioned(x)
+            x = x + params["pos_emb"][ids].astype(dtype)
+    sharded = is_partitioned(x) or is_partitioned(ck_all)
 
     def rows_at(pos0, t_len, lane, uniform_pos):
         """How rows of ``t_len`` tokens from ``pos0[b]`` — every lane's,
         or lane ``lane``'s alone — attend: the kernel their shapes
-        allow, and the dense body's two masks: cache slots before the
-        chunk, and the chunk's own causal triangle ([B, T, kv, g, S | T])."""
+        allow, and the dense attention's two masks: cache slots before
+        the chunk, and the chunk's own causal triangle ([B, T, kv, g, S
+        | T]).  In a ring, slot ``s`` holds the latest position ``p_s =
+        s (mod max_len)`` before the chunk, seen iff ``0 <= p_s`` and
+        within the window, and the triangle is a band; a ragged row's
+        pad slots are never seen."""
+        slots = jnp.arange(s_len)[None, :]
+        if ring is None:
+            before = (slots < pos0[:, None])[:, None, None, None, :]
+        else:
+            last = pos0[:, None] - 1
+            p_s = last - jnp.mod(last - slots, s_len)              # [B, S]
+            at = pos0[:, None] + jnp.arange(t_len)[None, :]         # [B, T]
+            before = ((p_s[:, None, :] >= 0)
+                      & (at[:, :, None] - p_s[:, None, :] < ring)
+                      )[:, :, None, None, :]
+        if pad_lens is not None:
+            before = before & (slots >= pad_lens[:, None]
+                               )[:, None, None, None, :]
+        causal = jnp.tril(jnp.ones((t_len, t_len), bool))
+        if ring is not None:
+            t_ids = jnp.arange(t_len)
+            causal = causal & (t_ids[:, None] - t_ids[None, :] < ring)
         return types.SimpleNamespace(
             pos0=pos0, lane=lane,
             bounded=chunk_attends_prefix(
                 cfg, t_len, cache, uniform_pos=uniform_pos, sharded=sharded),
-            per_lane=lane is None and decode_attends_prefix(
-                cfg, t_len, cache, uniform_pos=uniform_pos, sharded=sharded),
-            before=(jnp.arange(s_len)[None, :] < pos0[:, None]
-                    )[:, None, None, None, :],
-            causal=jnp.tril(jnp.ones((t_len, t_len), bool)
-                            )[None, :, None, None, :])
+            per_lane=(lane is None and pad_lens is None
+                      and decode_attends_prefix(
+                          cfg, t_len, cache, uniform_pos=uniform_pos,
+                          sharded=sharded)),
+            before=before, causal=causal[None, :, None, None, :])
 
     own = rows_at(pos0, t_len, lane, uniform_pos)
     bounded, per_lane, before, causal = (own.bounded, own.per_lane,
@@ -930,17 +740,52 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
                 (1, 1) + a_all.shape[2:])[0]
         return jax.lax.dynamic_index_in_dim(a_all, plane, 0, keepdims=False)
 
-    def attend_plain(q, k, v, plane, rows=own):
+    if beam_anc is not None:
+        # T = 1, every row at one position (_decode_chunk): the slots
+        # each lane saw before the step and its own, [B/W, W, S].
+        anc, w_beams = beam_anc
+        anc_oh = jax.nn.one_hot(anc, w_beams, dtype=jnp.float32)
+        beam_slot = pos0[0] if ring is None else jnp.mod(pos0[0], s_len)
+        seen = (before[:, 0, 0, 0] | (jnp.arange(s_len) == beam_slot)
+                ).reshape(b // w_beams, w_beams, s_len)
+
+    def attend_beams(q, k, v, plane, scales):
+        """Beam ancestry: the rows' own K/V (and int8 scales) written
+        into the plane's rows, each lane's history read through ``anc``
+        (:func:`_ancestry_attend`)."""
+        zero = jnp.int32(0)
+        with jax.named_scope("kv_slab"):
+            held = [jax.lax.dynamic_update_slice(
+                plane_rows(cache[name], plane), a,
+                (zero, beam_slot) + (zero,) * (a.ndim - 2))
+                for name, a in zip(("k", "v", "k_scale", "v_scale"),
+                                   (k, v) + (scales or ()))]
+        with jax.named_scope("attn"):
+            qg = q[:, 0].astype(jnp.float32).reshape(
+                b, cfg.kv_heads, groups, cfg.head_dim)
+            return _ancestry_attend(qg, held[0], held[1], anc_oh, seen, cfg,
+                                    w_beams, kv_scales=held[2:] or None
+                                    )[:, None]
+
+    def attend_plain(q, k, v, plane, rows=own, scales=None):
         """An untyped stack's attention over its token-major plane
         ``[B, S, kv, hd]`` and the chunk's own K/V, of the rows ``rows``
-        describes (:func:`rows_at`; the call's own by default)."""
+        describes (:func:`rows_at`; the call's own by default).
+        ``scales``: the chunk's int8 K/V scales ``[B, T, kv]``, beside
+        the cache's scale planes."""
         b, t_len = q.shape[:2]
         pos0, lane = rows.pos0, rows.lane
+        if beam_anc is not None:
+            return attend_beams(q, k, v, plane, scales)
         with jax.named_scope("kv_slab"):
             # This plane's rows, for reading (the per-lane kernel takes
             # the slab and the plane's index).
             if lane is not None or not rows.per_lane:
                 ck, cv = (plane_rows(a, plane, lane) for a in (ck_all, cv_all))
+            sc = (None,) * 4
+            if scales is not None:
+                sc = (plane_rows(cache["k_scale"], plane, lane), scales[0],
+                      plane_rows(cache["v_scale"], plane, lane), scales[1])
             if rows.bounded:
                 at = (jnp.int32(0), pos0[0], jnp.int32(0), jnp.int32(0))
                 ck = jax.lax.dynamic_update_slice(ck, k, at)
@@ -952,28 +797,36 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
             if rows.bounded:
                 return flash_prefix_attention(q.astype(ck.dtype), ck, cv,
                                               pos0[0])
+            # int8 K/V: a slot's scale [B, S | T, kv] multiplies its
+            # logits and its probabilities where they are read.
+            dq = lambda a, s: a if s is None else (
+                a * s.transpose(0, 2, 1)[:, None, :, None, :])
             qg = q.astype(jnp.float32).reshape(
                 b, t_len, cfg.kv_heads, groups, cfg.head_dim)
-            old = jnp.einsum("btcgk,bsck->btcgs", qg,
-                             ck.astype(jnp.float32)) * scale
-            new = jnp.einsum("btcgk,buck->btcgu", qg,
-                             k.astype(jnp.float32)) * scale
+            old = dq(jnp.einsum("btcgk,bsck->btcgs", qg,
+                                ck.astype(jnp.float32)), sc[0]) * scale
+            new = dq(jnp.einsum("btcgk,buck->btcgu", qg,
+                                k.astype(jnp.float32)), sc[1]) * scale
             probs = jax.nn.softmax(jnp.concatenate(
                 [jnp.where(rows.before, old, -1e30),
                  jnp.where(rows.causal, new, -1e30)], axis=-1), axis=-1)
-            return (jnp.einsum("btcgs,bsck->btcgk", probs[..., :s_len],
+            return (jnp.einsum("btcgs,bsck->btcgk",
+                               dq(probs[..., :s_len], sc[2]),
                                cv.astype(jnp.float32))
-                    + jnp.einsum("btcgu,buck->btcgk", probs[..., s_len:],
+                    + jnp.einsum("btcgu,buck->btcgk",
+                                 dq(probs[..., s_len:], sc[3]),
                                  v.astype(jnp.float32))).reshape(
                 b, t_len, cfg.n_heads, cfg.head_dim)
 
-    def attend_round(q, k, v, plane):
+    def attend_round(q, k, v, plane, scales=None):
         """``chunk=``: the stream's one row split again — the lanes'
         tokens attend as a decode step's rows, the chunk's as an
         admission's one row — and laid side by side once more."""
-        by_lane = attend_plain(*(a[0, :b, None] for a in (q, k, v)), plane)
-        by_chunk = attend_plain(*(a[:, b:] for a in (q, k, v)), plane,
-                                admitted)
+        lanes, rows = (lambda a: a[0, :b, None]), (lambda a: a[:, b:])
+        by_lane = attend_plain(*map(lanes, (q, k, v)), plane,
+                               scales=scales and tuple(map(lanes, scales)))
+        by_chunk = attend_plain(*map(rows, (q, k, v)), plane, admitted,
+                                scales=scales and tuple(map(rows, scales)))
         return jnp.concatenate([by_lane.astype(dtype)[None, :, 0],
                                 by_chunk.astype(dtype)], axis=1)
 
@@ -1224,6 +1077,10 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
                 {**state, "ssm": s_all, "conv": c_all})
 
     def layer(x, lp, plane, kind=None, experts=None, state=None):
+        if quantized:   # int8 weights, dequantised where they are read
+            lp = {**lp, **{part: {name: deq(w)
+                                  for name, w in lp[part].items()}
+                           for part in ("attn", "ffn")}}
         h = _rms_norm(x, lp["ln1_scale"], eps) if pre_norm else x
         if kind is not None and kind[0] == "latent":
             a, lat, state = latent_attn(h, lp, plane, state)
@@ -1244,11 +1101,16 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
                 k = _rms_norm(k, lp["attn"]["k_scale"], eps)
             if rope_ang is not None and layer_rotates(cfg, kind):
                 q, k = rope_rotate(q, rope_ang), rope_rotate(k, rope_ang)
-            k, v = k.astype(ck_all.dtype), v.astype(cv_all.dtype)
+            scales = None
+            if kv_q:    # post-rotation, like a cache in the compute dtype
+                (k, k_s), (v, v_s) = quantize_kv(k), quantize_kv(v)
+                scales = (k_s, v_s)
+            else:
+                k, v = k.astype(ck_all.dtype), v.astype(cv_all.dtype)
         if chunk is not None:
-            attn = attend_round(q, k, v, plane)
+            attn = attend_round(q, k, v, plane, scales)
         elif kind is None:
-            attn = attend_plain(q, k, v, plane)
+            attn = attend_plain(q, k, v, plane, scales=scales)
         elif kind[0] == "retention":
             with jax.named_scope("attn"):
                 attn, state = attend_state(h, q, k, v, lp, plane, state)
@@ -1264,8 +1126,8 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
             else:
                 a = jnp.einsum("bthk,hkd->btd", attn, lp["attn"]["wo"])
         return layer_tail(x, a, lp, kind, experts, state,
-                          () if kind is not None
-                          and kind[0] == "retention" else (k, v))
+                          () if kind is not None and kind[0] == "retention"
+                          else (k, v) + (scales or ()))
 
     def layer_tail(x, a, lp, kind, experts, state, cached):
         """What follows a layer's attention ``a``: the residual sums
@@ -1283,6 +1145,10 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
             if kind is not None and kind[1] == "sparse":
                 y, routes = moe_ffn(lp, h, cfg, with_routes=True,
                                     stacked=experts)
+            elif kind is None and cfg.num_experts:
+                # An untyped MoE: every expert on every token, the
+                # router's picks combined (no capacity).
+                y = _moe_dense_block(lp["moe"], h, cfg)
             else:
                 y = ffn_apply(lp["ffn"], h, cfg)
         if cfg.post_norms:
@@ -1311,39 +1177,59 @@ def _chunk_in_place(params, cache, tokens, pos0, cfg: TransformerConfig,
         return _typed_tail(*_typed_runs(params, x, cfg, layer, state),
                            params, cache, pos0, cfg, uniform_pos, lane,
                            n_real, with_routes)
-    x, (new_k, new_v) = jax.lax.scan(one_pass, x, jnp.arange(cfg.n_passes))
+    x, new = jax.lax.scan(one_pass, x, jnp.arange(cfg.n_passes))
     with jax.named_scope("head"):
         if chunk is not None:   # the lanes' rows only: [B, 1, D]
             x = x[0, :b, None]
-        out = jnp.einsum("btd,vd->btv", x,
-                         head_table(params, cfg).astype(dtype))
+        if quantized:
+            out = unembed_logits(x, params["tok_emb"], dtype)
+        else:
+            out = jnp.einsum("btd,vd->btv", x,
+                             head_table(params, cfg).astype(dtype))
+    # K, V and, int8, their scales: each slab takes the same writes.
+    names = ("k", "v", "k_scale", "v_scale")[:len(new)]
+    slabs = {name: cache[name] for name in names}
+
+    def put(name, a, at):
+        slabs[name] = jax.lax.dynamic_update_slice(
+            slabs[name], a, at[:slabs[name].ndim])
+
     with jax.named_scope("kv_slab"):
         # [R, L, B, T, kv, hd] -> planes first, then ONE write in place.
-        new_k, new_v = (a.reshape((cfg.kv_planes,) + a.shape[2:])
-                        for a in (new_k, new_v))
+        new = [a.reshape((cfg.kv_planes,) + a.shape[2:]) for a in new]
         zero = jnp.int32(0)
         if chunk is not None:
             # The chunk's [L, 1, C, kv, hd] into its lane, then the
             # lanes' [L, B, 1, kv, hd] a row below.
             at = (zero, c_lane, c_off[0], zero, zero)
-            ck_all = jax.lax.dynamic_update_slice(ck_all, new_k[:, :, b:], at)
-            cv_all = jax.lax.dynamic_update_slice(cv_all, new_v[:, :, b:], at)
-            new_k, new_v = (a[:, 0, :b, None] for a in (new_k, new_v))
+            for name, a in zip(names, new):
+                put(name, a[:, :, b:], at)
+            new = [a[:, 0, :b, None] for a in new]
+        # Where a row's chunk starts: its position, in a ring its slot.
+        start = pos0 if ring is None else jnp.mod(pos0, s_len)
         if uniform_pos:
-            at = (zero, zero if lane is None else lane, pos0[0], zero, zero)
-            ck_all = jax.lax.dynamic_update_slice(ck_all, new_k, at)
-            cv_all = jax.lax.dynamic_update_slice(cv_all, new_v, at)
+            at = (zero, zero if lane is None else lane, start[0], zero, zero)
+            for name, a in zip(names, new):
+                put(name, a, at)
         else:
             # One window over all planes per row, unrolled: a
             # ``fori_loop`` over the rows with both slabs as carry
             # trips a RET_CHECK in the TPU compiler (AOT, PR 27).
             for i in range(b):
-                at = (zero, jnp.int32(i), pos0[i], zero, zero)
-                ck_all = jax.lax.dynamic_update_slice(
-                    ck_all, new_k[:, i:i + 1], at)
-                cv_all = jax.lax.dynamic_update_slice(
-                    cv_all, new_v[:, i:i + 1], at)
-    return out.astype(jnp.float32), {"k": ck_all, "v": cv_all}
+                if ring is not None and t_len > 1:
+                    # A ring chunk at its row's own position may wrap
+                    # mid-chunk: a modular scatter.  _decode_chunk's
+                    # T + window <= max_len keeps what it overwrites,
+                    # and the chunk's later positions, out of every
+                    # live query's band.
+                    idx = jnp.mod(pos0[i] + jnp.arange(t_len), s_len)
+                    for name, a in zip(names, new):
+                        slabs[name] = slabs[name].at[:, i, idx].set(a[:, i])
+                    continue
+                at = (zero, jnp.int32(i), start[i], zero, zero)
+                for name, a in zip(names, new):
+                    put(name, a[:, i:i + 1], at)
+    return out.astype(jnp.float32), slabs
 
 
 def _typed_runs(params, x, cfg: TransformerConfig, layer, state):
@@ -1481,10 +1367,12 @@ def _typed_tail(x, new, params, cache, pos0, cfg: TransformerConfig,
 def _decode_chunk(params, cache, tokens, pos0, cfg: TransformerConfig,
                   uniform_pos: bool = False, beam_anc=None, lane=None,
                   n_real=None, with_routes: bool = False, live=None,
-                  chunk=None):
+                  chunk=None, pad_lens=None):
     """Process T new tokens per row against the cache in ONE pass:
     ``tokens [B, T]`` at global positions ``pos0[b] + (0..T-1)`` ->
-    ``(logits [B, T, V] f32, cache)``.
+    ``(logits [B, T, V] f32, cache)``.  The body is
+    :func:`_chunk_in_place`, for every call; this checks what the call
+    asks of it first.
 
     The chunked generalization of :func:`_decode_step` (T = 1 is the
     same math): queries attend every cached position <= their own
@@ -1492,13 +1380,6 @@ def _decode_chunk(params, cache, tokens, pos0, cfg: TransformerConfig,
     K/V land in the cache at per-row offsets, so rows at different
     positions (speculative decoding's per-row accept divergence) share
     one compiled program.
-
-    Two bodies.  Every PLAIN call — no ring, MoE, beam ancestry, int8
-    cache or weights, partitioned cache (:func:`base_body_only`) —
-    takes :func:`_chunk_in_place`: one traced layer under ``lax.scan``,
-    the slab written in place, the extended block and the pass loop of
-    a looped stack.  The rest of this docstring is the BASE block's
-    body below, which keeps those features and nothing else.
 
     Windowed (``attention_window``) configs run in three shapes:
     (a) the per-row path with T == 1 — each row writes its ring slot
@@ -1520,24 +1401,23 @@ def _decode_chunk(params, cache, tokens, pos0, cfg: TransformerConfig,
 
     Stale cache slots beyond a row's final position are harmless by
     construction: the position mask excludes them (for ring caches the
-    band-mask's implied-position formula sends slots the row has not
-    reached to negative positions), and every slot is rewritten before
-    the row's position passes it.
+    implied-position formula sends slots the row has not reached to
+    negative positions), and every slot is rewritten before the row's
+    position passes it.
 
     ``uniform_pos`` (static): promise that every row of ``pos0`` holds
-    the same value, so the cache write is one slab update instead of a
-    per-row scatter (see _layer_slab_update).  The plain decode loop
-    and prefix warm-up qualify; speculative decoding (per-row accept
-    divergence) does not.
+    the same value, so the cache write is one slab update instead of
+    one a row.  The plain decode loop and prefix warm-up qualify;
+    speculative decoding (per-row accept divergence) does not.
 
     ``beam_anc = (anc [B/W, W, S] int32, W)``: beam-search ancestry
-    attention (requires T == 1, uniform_pos, no window).  Rows are
-    beam lanes (batch-major tiling b*W + w); each lane writes its own
-    cache lane in place, and attention resolves lane ``w``'s history
-    through ``anc`` — position ``s`` is read from lane ``anc[b, w,
-    s]`` — by computing every (query-lane, source-lane) score and
-    folding a one-hot of ``anc`` into the softmax/PV einsums.  The
-    cache is read ONCE per step with no beam-reorder rewrite; the
+    attention (requires T == 1 and uniform_pos).  Rows are beam lanes
+    (batch-major tiling b*W + w); each lane writes its own cache lane
+    in place, and attention resolves lane ``w``'s history through
+    ``anc`` — position ``s`` (a ring's slot ``s``) is read from lane
+    ``anc[b, w, s]`` — by computing every (query-lane, source-lane)
+    score and folding a one-hot of ``anc`` into the softmax/PV einsums.
+    The cache is read ONCE per step with no beam-reorder rewrite; the
     price is score intermediates of ``B/W x W^2 x n_heads x S`` f32
     per layer — ~4 MB at the benched config (b8 W4 S1025 H8), but
     quadratic in beam width (b64 W8 S2048 H16 would be ~1 GB/layer;
@@ -1545,40 +1425,25 @@ def _decode_chunk(params, cache, tokens, pos0, cfg: TransformerConfig,
     the physical parent-gather of the cache, which cost more than the
     whole attention read (measured 2026-07-31 on one v5e, not
     re-measured since).
-    """
-    why = base_body_only(cfg, params, cache, beam=beam_anc is not None)
-    if why is None:
-        # Every plain call: one traced layer under scans, the slab
-        # written in place.  What follows is the BASE block's body for
-        # what that one does not express (the docstring above).
-        return _chunk_in_place(params, cache, tokens, pos0, cfg,
-                               uniform_pos=uniform_pos, lane=lane,
-                               n_real=n_real, with_routes=with_routes,
-                               live=live, chunk=chunk)
-    reject_extended(cfg, why)
-    if lane is not None or chunk is not None:
-        raise ValueError(f"lane= (in-place admission into one lane of a "
-                         f"slab) and chunk= (an admission inside a decode "
-                         f"step) do not compose with {why}: cut the "
-                         "lane out of the slab")
-    dtype = jnp.dtype(cfg.dtype)
-    b, t_len = tokens.shape
-    with jax.named_scope("embed"):
-        x = embed_rows(params["tok_emb"], tokens, dtype)      # [B, T, D]
-        pos_ids = pos0[:, None] + jnp.arange(t_len)[None, :]  # [B, T]
-        rope_ang = None
-        if cfg.rope:
-            rope_ang = rope_angles(pos_ids, cfg.head_dim,
-                                   cfg.rope_theta)[:, :, None, :]
-        else:
-            x = x + params["pos_emb"][pos_ids].astype(dtype)
 
-    kv_q = "k_scale" in cache                   # int8 KV cache
-    win = cfg.attention_window is not None
-    bounded = chunk_attends_prefix(
-        cfg, t_len, cache, uniform_pos=uniform_pos,
-        beam=beam_anc is not None, sharded=is_partitioned(x))
-    if (win and t_len > 1 and not uniform_pos
+    ``pad_lens [B]``: :func:`_decode_step`'s ragged rows (T = 1).
+    """
+    # What only the base block takes: an extended stack refuses each,
+    # by name.
+    for on, what in (
+            (cfg.attention_window is not None, "a windowed (rolling) decode"),
+            (pad_lens is not None, "a ragged-prompt (prompt_lengths) decode"),
+            (cfg.num_experts and not cfg.typed,
+             "a MoE feed-forward (num_experts > 0) without ffn_types"),
+            (beam_anc is not None, "beam search"),
+            ("k_scale" in cache, "the int8 KV cache (kv_int8)"),
+            (is_quantized(params), "int8-quantized weights (models/quant)"),
+            (is_partitioned(cache["k"]),
+             "a partitioned cache (a tensor-parallel serving_plan)")):
+        if on:
+            reject_extended(cfg, what)
+    t_len = tokens.shape[1]
+    if (cfg.attention_window is not None and t_len > 1 and not uniform_pos
             and t_len + cfg.attention_window > cfg.max_len):
         # A per-row ring chunk may WRAP, and then two invariants need
         # chunk + window <= max_len: an in-chunk future position
@@ -1594,155 +1459,19 @@ def _decode_chunk(params, cache, tokens, pos0, cfg: TransformerConfig,
             f"attention_window={cfg.attention_window} exceeds the ring "
             f"size (max_len={cfg.max_len}); shrink the chunk or grow "
             "the ring")
-    ck_all, cv_all = cache["k"], cache["v"]     # [L, B, S, kv, hd]
-    if kv_q:
-        cks_all, cvs_all = cache["k_scale"], cache["v_scale"]
-        new_ks, new_vs = [], []
-    new_k, new_v = [], []                       # per-row path accumulates
-    span = jnp.arange(cfg.max_len)
-    if win:
-        # Ring band mask, per row (see _decode_step's windowed body for
-        # the slot->implied-position derivation; here pos differs per
-        # row/chunk position).
-        delta = jnp.mod(pos_ids[:, :, None] - span[None, None, :],
-                        cfg.max_len)
-        mask = ((delta < cfg.attention_window)
-                & (pos_ids[:, :, None] - delta >= 0)
-                )[:, :, None, None, :]            # [B, T, 1, 1, S]
-        wr_pos = pos0 % cfg.max_len               # ring write slots
-    else:
-        mask = (span[None, None, :] <= pos_ids[:, :, None]
-                )[:, :, None, None, :]            # [B, T, 1, 1, S]
-        wr_pos = pos0
-    # [B, S, C] scale -> broadcast over the [B, T, C, G, S] logits.
-    sc_b = lambda s: s.transpose(0, 2, 1)[:, None, :, None, :]
     if beam_anc is not None:
-        anc, w_beams = beam_anc
-        if t_len != 1 or not uniform_pos or cfg.attention_window:
-            raise ValueError("beam ancestry attention requires T == 1, "
-                             "uniform positions, and no window")
-        # One-hot over source lanes, f32 for the einsum contractions.
-        anc_oh = jax.nn.one_hot(anc, w_beams, dtype=jnp.float32)
-    for i in range(cfg.n_layers):
-        lp = jax.tree.map(lambda a: a[i], params["layers"])
-        h = _rms_norm(x, lp["ln1_scale"])
-        with jax.named_scope("attn_proj"):
-            q = jnp.einsum("btd,dhk->bthk", h, deq(lp["attn"]["wq"]))
-            k = jnp.einsum("btd,dhk->bthk", h, deq(lp["attn"]["wk"]))
-            v = jnp.einsum("btd,dhk->bthk", h, deq(lp["attn"]["wv"]))
-            if rope_ang is not None:
-                q, k = rope_rotate(q, rope_ang), rope_rotate(k, rope_ang)
-            if kv_q:  # post-rotation, like the bf16 cache
-                k, k_s = quantize_kv(k)
-                v, v_s = quantize_kv(v)
-        with jax.named_scope("kv_slab"):
-            if uniform_pos:
-                ck_all = _layer_slab_update(ck_all, i, k, wr_pos[0])
-                cv_all = _layer_slab_update(cv_all, i, v, wr_pos[0])
-                ck, cv = ck_all[i], cv_all[i]
-                if kv_q:
-                    cks_all = _layer_slab_update(cks_all, i, k_s, wr_pos[0])
-                    cvs_all = _layer_slab_update(cvs_all, i, v_s, wr_pos[0])
-                    cks, cvs = cks_all[i], cvs_all[i]
-            else:
-                if win and t_len > 1:
-                    # A multi-token ring chunk at divergent row positions
-                    # can wrap mid-chunk: modular per-element scatter.
-                    upd = lambda c, r: _rows_update_ring(c, r, pos0,
-                                                         cfg.max_len)
-                else:
-                    upd = lambda c, r: _rows_update(c, r, wr_pos)
-                ck = upd(ck_all[i], k)
-                cv = upd(cv_all[i], v)
-                new_k.append(ck)
-                new_v.append(cv)
-                if kv_q:
-                    cks = upd(cks_all[i], k_s)
-                    cvs = upd(cvs_all[i], v_s)
-                    new_ks.append(cks)
-                    new_vs.append(cvs)
-
-        with jax.named_scope("attn"):
-            groups = cfg.n_heads // cfg.kv_heads
-            qg = q.astype(jnp.float32).reshape(
-                b, t_len, cfg.kv_heads, groups, cfg.head_dim)
-            if bounded:
-                # The chunk is in the cache already (kv_slab above), so
-                # one mask covers prefix and chunk; slots past
-                # pos0 + T are never read.
-                attn = flash_prefix_attention(q.astype(ck.dtype), ck, cv,
-                                              pos0[0])
-            elif beam_anc is not None:
-                # Ancestry attention (shared body: _ancestry_attend) — the
-                # cache is read once, W x the (tiny) decode attention
-                # FLOPs, and the one-hot selects each position's true
-                # ancestor lane.
-                bt = b // w_beams
-                mask_b = mask[:, 0, 0, 0, :].reshape(bt, w_beams,
-                                                     cfg.max_len)
-                attn = _ancestry_attend(
-                    qg[:, 0], ck, cv, anc_oh, mask_b, cfg, w_beams,
-                    kv_scales=(cks, cvs) if kv_q else None,
-                )[:, None]  # restore T = 1
-            else:
-                logits = jnp.einsum("btcgk,bsck->btcgs", qg,
-                                    ck.astype(jnp.float32))
-                if kv_q:
-                    logits = logits * sc_b(cks)
-                logits = logits / jnp.sqrt(jnp.float32(cfg.head_dim))
-                logits = jnp.where(mask, logits, -1e30)
-                probs = jax.nn.softmax(logits, axis=-1)
-                attn = jnp.einsum("btcgs,bsck->btcgk",
-                                  probs * sc_b(cvs) if kv_q else probs,
-                                  cv.astype(jnp.float32)).reshape(
-                    b, t_len, cfg.n_heads, cfg.head_dim)
-        with jax.named_scope("attn_proj"):
-            x = x + jnp.einsum("bthk,hkd->btd", attn.astype(dtype),
-                               deq(lp["attn"]["wo"]))
-
-        h = _rms_norm(x, lp["ln2_scale"])
-        with jax.named_scope("mlp"):
-            if cfg.num_experts and t_len > 1:
-                # Multi-token chunks take the batched dense-routing block
-                # (all experts on all tokens, one-hot combine): peak memory
-                # is [B, T, E, F] ACTIVATIONS, where the per-token weight
-                # gather below would materialize B*T*k copies of the [D, F]
-                # expert mats — GBs per layer at warm-chunk T.  Same math
-                # (_moe_gates shared), same decode-parity semantics.
-                y = _moe_dense_block(lp["moe"], h, cfg)
-            elif cfg.num_experts:
-                # T = 1 (the decode step): gather the k selected experts'
-                # slabs per row — fewer HBM bytes than all E at small
-                # batch, which is what the bandwidth-bound loop wants.
-                router = jnp.einsum("btd,de->bte", h.astype(jnp.float32),
-                                    lp["moe"]["wg"])
-                gates, expert = _moe_gates(jax.nn.softmax(router, -1), cfg)
-                w1 = lp["moe"]["w1"][expert]          # [B, T, k, D, F]
-                w2 = lp["moe"]["w2"][expert]
-                hk = jax.nn.gelu(jnp.einsum("btd,btkdf->btkf", h,
-                                            w1.astype(dtype)))
-                yk = jnp.einsum("btkf,btkfd->btkd", hk, w2.astype(dtype))
-                y = jnp.einsum("btkd,btk->btd", yk, gates.astype(dtype))
-            else:
-                y = jnp.einsum(
-                    "btf,fd->btd",
-                    jax.nn.gelu(jnp.einsum("btd,df->btf", h,
-                                           deq(lp["ffn"]["w1"]))),
-                    deq(lp["ffn"]["w2"]))
-            x = x + y
-
-    x = _rms_norm(x, params["ln_f_scale"])
-    with jax.named_scope("head"):
-        out = unembed_logits(x, params["tok_emb"], dtype)
-    if not uniform_pos:
-        with jax.named_scope("kv_slab"):
-            ck_all, cv_all = jnp.stack(new_k), jnp.stack(new_v)
-            if kv_q:
-                cks_all, cvs_all = jnp.stack(new_ks), jnp.stack(new_vs)
-    cache = {"k": ck_all, "v": cv_all}
-    if kv_q:
-        cache["k_scale"], cache["v_scale"] = cks_all, cvs_all
-    return out.astype(jnp.float32), cache
+        if t_len != 1 or not uniform_pos:
+            raise ValueError("beam ancestry attention requires T == 1 "
+                             "and uniform positions")
+        if pad_lens is not None:
+            raise ValueError(
+                "beam ancestry attention does not compose with pad_lens "
+                "(beam search is uniform-prompt only)")
+    return _chunk_in_place(params, cache, tokens, pos0, cfg,
+                           uniform_pos=uniform_pos, lane=lane,
+                           n_real=n_real, with_routes=with_routes,
+                           live=live, chunk=chunk, beam_anc=beam_anc,
+                           pad_lens=pad_lens)
 
 
 def top_k_mask(logits, k: int, exact: bool = False):

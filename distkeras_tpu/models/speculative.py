@@ -196,10 +196,10 @@ def speculative_generate(params, draft_params, prompt, cfg: TransformerConfig,
     Windowed configs compose (round-5): either model may run a
     rope + ``attention_window`` ring cache — including ROLLING past
     ``max_len`` — under ``window + n_draft + 1 <= max_len`` (verify
-    chunks write through _decode_chunk's modular ring scatter; the
-    bound keeps a rejected tail's slots outside every live query's
-    band).  Output parity with windowed ``generate`` is exact, wraps
-    included.
+    chunks write their ring slots modulo ``max_len`` in
+    ``generate._chunk_in_place``; the bound keeps a rejected tail's
+    slots outside every live query's band).  Output parity with
+    windowed ``generate`` is exact, wraps included.
     """
     from distkeras_tpu.models.generate import _device_tree
 

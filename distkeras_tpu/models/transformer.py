@@ -295,7 +295,7 @@ class TransformerConfig:
     def extended(self) -> bool:
         """Whether this config uses a switch above.  ``block_apply``
         (so ``apply`` and ``lm_loss``) and the cached decode body every
-        plain call takes (``generate._chunk_in_place``) implement them;
+        call takes (``generate._chunk_in_place``) implement them;
         every other path — :func:`reject_extended` — knows the base
         block only and says so."""
         return self.n_passes != 1 or any(
@@ -1156,14 +1156,15 @@ def _moe_block(lp, x, cfg: TransformerConfig):
 
 
 def _moe_dense_block(lp, x, cfg: TransformerConfig):
-    """Capacity-FREE top-k MoE over [B, S, D] — the batched twin of
-    _decode_step's per-token branch (models/generate.py): every expert
-    runs on every token (E x compute) and the router's picks are
-    gathered.  Used by generate.prefill so prefilled and sequential
-    prompt processing match exactly; training keeps :func:`_moe_block`
-    (capacity dispatch).  Unselected experts are zero-masked BEFORE the
-    combine so a non-finite value in an unpicked expert cannot poison
-    the token (0 * inf is NaN; where() is not).
+    """Capacity-FREE top-k MoE over [B, S, D]: every expert runs on
+    every token (E x compute) and the router's picks are combined.
+    The feed-forward of an untyped MoE stack's cached decode
+    (``generate._chunk_in_place``) and of ``generate.prefill``, so
+    prefilled and sequential prompt processing match exactly; training
+    keeps :func:`_moe_block` (capacity dispatch).  Unselected experts
+    are zero-masked BEFORE the combine so a non-finite value in an
+    unpicked expert cannot poison the token (0 * inf is NaN; where() is
+    not).
     """
     dtype = x.dtype
     router = jnp.einsum("bsd,de->bse", x.astype(jnp.float32), lp["wg"])

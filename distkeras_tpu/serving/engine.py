@@ -102,12 +102,12 @@ def _make_lane_admit(model_params, model_cfg, prefix_lane=None,
     the KV-slab constraint so GSPMD pins the cache layout inside the
     compiled program instead of inferring it per call).
 
-    ``in_place=True`` (an engine with no prefix to seed and a state
-    ``generate.base_body_only`` does not hold back): the chunk is
+    ``in_place=True`` (an engine with no prefix to seed): the chunk is
     written into lane ``lane`` of the slab IN PLACE — no lane cut out,
     none put back, nothing seeded (a previous occupant's slots are
-    masked until overwritten) — so ``seed`` changes nothing and the
-    engine uses ONE program for first and continuation chunks.
+    masked until overwritten, in a ring too) — so ``seed`` changes
+    nothing and the engine uses ONE program for first and continuation
+    chunks; ``seed=False`` is built only where a prefix is seeded.
 
     ``take_params=True`` builds the hot-swap spelling (round 20): the
     program takes the param tree as its FIRST argument instead of
@@ -122,11 +122,12 @@ def _make_lane_admit(model_params, model_cfg, prefix_lane=None,
         if in_place:
             # ``pool`` is then ``(n_real,)`` or empty: how many of the
             # rows are real, where the program writes ring planes.
-            return _decode_chunk(
+            out = _decode_chunk(
                 params, cache, rows,
                 jnp.reshape(off, (1,)).astype(jnp.int32), model_cfg,
                 uniform_pos=True, lane=lane,
                 n_real=pool[0] if pool else None)[1]
+            return constrain(out) if constrain is not None else out
         if constrain is not None:
             cache = constrain(cache)
         # "kv_slab": the lane cut out of the slab here and put back
@@ -260,7 +261,7 @@ class _LaneEngine(_AdmissionMixin, _ElasticMixin):
         ``serving.admit_chunk``: ``start + width`` where the program
         of that width was compiled with the bounded path
         (``chunk_attends_prefix``, the question ``_decode_chunk``
-        itself asks), ``max_len`` where it keeps the dense body.  Host
+        itself asks), ``max_len`` where it attends densely.  Host
         integers known at dispatch; no device read."""
         cfg = self.cfg
         if not (cfg.kv_planes or cfg.kv_ring_planes or cfg.latent_planes):

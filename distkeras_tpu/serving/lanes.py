@@ -34,7 +34,6 @@ from distkeras_tpu.models.generate import (
     _decode_chunk,
     _device_tree,
     _resolve_prompt_cache,
-    base_body_only,
     init_cache,
     min_p_mask,
     rolling_eligible,
@@ -963,12 +962,9 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
         # length and chunk offset shares it.
         pooled = self._prefix_pool is not None
         constrain = self._kv_constraint
-        # Nothing to seed and nothing that needs the base body: the
-        # chunk is written into the slab in place, and a first chunk
-        # and a continuation are ONE program.
-        in_place = (self._prefix_lane is None and not pooled
-                    and base_body_only(self.cfg, self.params,
-                                       self.cache) is None)
+        # Nothing to seed: the chunk is written into the slab in place,
+        # and a first chunk and a continuation are ONE program.
+        in_place = self._prefix_lane is None and not pooled
         self._admit = _make_lane_admit(self.params, self.cfg,
                                        prefix_lane=self._prefix_lane,
                                        pooled=pooled,
@@ -991,12 +987,11 @@ class ContinuousBatcher(_ElasticLanesMixin, _LaneEngine):
                                                constrain=constrain)
                              if pooled else None)
         # A round with a continuation chunk as ONE program — where the
-        # stack is the plain one (untyped, one pass, nothing the base
-        # body holds back), the chunk goes into the slab in place, the
-        # engine is on one device with no tiers, and the round is this
-        # class's own (the paged engine reads each round at once, the
-        # speculative one's rounds are its own).  Everything else keeps
-        # its two programs.
+        # stack is the plain one (untyped, one pass), the chunk goes
+        # into the slab in place, the engine is on one device with no
+        # tiers, and the round is this class's own (the paged engine
+        # reads each round at once, the speculative one's rounds are
+        # its own).  Everything else keeps its two programs.
         cfg = self.cfg
         if (in_place and self.prefill_chunk is not None and not cfg.typed
                 and cfg.n_passes == 1 and self._overlap

@@ -105,12 +105,12 @@ class SpeculativeBatcher(_LaneEngine):
                  max_queue: int = 0, clock=None, prefix_pool=None,
                  plan=None, mesh=None):
         # Windowed configs run ROLLING speculative lanes (round-7): the
-        # verify chunk writes through _decode_chunk's modular ring
-        # scatter under the same bound as solo speculative_generate —
-        # window + n_draft + 1 <= max_len keeps every rejected tail's
-        # slots outside every live query's band — and lanes decode past
-        # max_len with no total-length cap, exactly like rolling
-        # ContinuousBatcher lanes.  Crucially the DEGRADED path stays
+        # verify chunk writes its ring slots modulo max_len
+        # (generate._chunk_in_place) under the same bound as solo
+        # speculative_generate — window + n_draft + 1 <= max_len keeps
+        # every rejected tail's slots outside every live query's band —
+        # and lanes decode past max_len with no total-length cap,
+        # exactly like rolling ContinuousBatcher lanes.  Crucially the DEGRADED path stays
         # ring-compatible too: the target-only fallback advances the
         # same unbounded per-lane positions over the same ring slabs,
         # so a draft fault mid-wrap preserves greedy solo parity past

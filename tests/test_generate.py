@@ -1,5 +1,7 @@
 """KV-cached decoding: must reproduce the training-path forward exactly."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -1237,3 +1239,116 @@ def test_generate_top_p_one_equals_no_filter(rng):
     with pytest.raises(ValueError, match="min_p"):
         generate(params, prompt, CFG, 6, temperature=0.9, min_p=-0.1,
                  key=k)
+
+
+# ------------------- the base block's features through the one body
+
+BASE = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=4,
+                             n_kv_heads=2, n_layers=2, d_ff=64, max_len=16,
+                             rope=True)
+RING = dataclasses.replace(BASE, attention_window=8)
+MOE = dataclasses.replace(BASE, num_experts=4, moe_top_k=2)
+# case -> (config, rows, tokens a row, positions (one int: every row
+# there), int8 K/V, int8 weights, lane, extra arguments)
+FEATURES = {
+    "ring_token": (RING, 3, 1, [2, 17, 30], False, False, None, {}),
+    "ring_chunk": (RING, 2, 4, 20, False, False, None, {}),
+    "ring_rows": (RING, 2, 3, [14, 29], False, False, None, {}),
+    "ring_lane": (RING, 3, 4, 0, False, False, 1, {}),
+    "ragged": (BASE, 3, 1, 9, False, False, None,
+               {"pad_lens": [0, 3, 6]}),
+    "beam": (BASE, 4, 1, 9, False, False, None, {"beam": 2}),
+    "beam_ring": (RING, 4, 1, 21, False, False, None, {"beam": 2}),
+    "int8_kv": (BASE, 3, 1, [5, 11, 15], True, False, None, {}),
+    "int8_kv_chunk": (BASE, 2, 4, 6, True, False, None, {}),
+    "int8_kv_lane": (BASE, 3, 4, 4, True, False, 1, {}),
+    "int8_kv_beam": (BASE, 4, 1, 9, True, False, None, {"beam": 2}),
+    "int8_weights": (BASE, 3, 1, [3, 8, 12], False, True, None, {}),
+    "int8_weights_lane": (BASE, 3, 4, 5, False, True, 2, {}),
+    "moe": (MOE, 3, 1, [3, 8, 12], False, False, None, {}),
+    "moe_chunk": (MOE, 2, 4, 6, False, False, None, {}),
+    "partitioned": (BASE, 3, 1, [3, 8, 12], False, False, None,
+                    {"partitioned": True}),
+}
+
+
+def _noise_cache(cfg, rows, kv_int8, rng):
+    """A cache whose every slot holds something (a previous occupant's
+    K/V): what a position mask must keep out, it holds."""
+    cache = init_cache(cfg, rows, kv_int8=kv_int8)
+    out = {}
+    for name, a in cache.items():
+        if a.dtype == jnp.int8:
+            out[name] = jnp.asarray(rng.integers(-127, 128, a.shape), a.dtype)
+        elif name.endswith("_scale"):
+            out[name] = jnp.asarray(rng.uniform(0.005, 0.02, a.shape), a.dtype)
+        else:
+            out[name] = jnp.asarray(rng.normal(size=a.shape), a.dtype)
+    return out
+
+
+@pytest.mark.parametrize("case", sorted(FEATURES))
+def test_base_features_take_the_scanned_body(case, devices):
+    """Each feature only the base block has — a ring, ragged rows, beam
+    ancestry, int8 K/V, int8 weights, an untyped MoE, a partitioned
+    cache — is traced through the scanned body (a ``scan`` over the
+    layers: the unrolled body is gone) and gives the logits and the
+    cache of the body it was written in before (``helpers.
+    base_decode_chunk``), on a cache of noise; ``lane=`` (one lane of a
+    slab, in place) against that body on the lane cut out."""
+    from distkeras_tpu.models import generate as gen
+    from distkeras_tpu.models.quant import quantize_params
+    from helpers import base_decode_chunk
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    cfg, rows, t, pos, kv_int8, qw, lane, extra = FEATURES[case]
+    rng = np.random.default_rng(sorted(FEATURES).index(case))
+    params = toy_params(cfg)
+    if qw:
+        params = quantize_params(params)
+    cache = _noise_cache(cfg, rows, kv_int8, rng)
+    toks = jnp.asarray(rng.integers(0, 64, (1 if lane else rows, t)),
+                       jnp.int32)
+    pos0 = jnp.asarray(np.broadcast_to(pos, (1 if lane else rows,)),
+                       jnp.int32)
+    uniform = np.ndim(pos) == 0
+    kw = {"uniform_pos": uniform}
+    if "pad_lens" in extra:
+        kw["pad_lens"] = jnp.asarray(extra["pad_lens"], jnp.int32)
+    if "beam" in extra:
+        w = extra["beam"]
+        kw["beam_anc"] = (jnp.asarray(rng.integers(
+            0, w, (rows // w, w, cfg.max_len)), jnp.int32), w)
+    if extra.get("partitioned"):
+        mesh = jax.sharding.Mesh(np.asarray(devices[:2]), ("model",))
+        cache = jax.device_put(cache, NamedSharding(
+            mesh, PartitionSpec(None, None, None, "model")))
+    if lane is not None:
+        kw["lane"] = jnp.int32(lane)
+
+    def one_body(params, cache, toks, pos0):
+        return gen._decode_chunk(params, cache, toks, pos0, cfg, **kw)
+
+    eqns = jax.make_jaxpr(one_body)(params, cache, toks, pos0).eqns
+    assert any(e.primitive.name == "scan" for e in eqns)
+    logits, got = jax.jit(one_body)(params, cache, toks, pos0)
+
+    oracle_kw = {k: v for k, v in kw.items() if k != "lane"}
+    if lane is None:
+        want_logits, want = base_decode_chunk(params, cache, toks, pos0, cfg,
+                                              **oracle_kw)
+    else:
+        cut = jax.tree.map(lambda a: a[:, lane:lane + 1], cache)
+        want_logits, cut = base_decode_chunk(params, cut, toks, pos0, cfg,
+                                             **oracle_kw)
+        want = jax.tree.map(lambda a, c: a.at[:, lane:lane + 1].set(c),
+                            cache, cut)
+    np.testing.assert_allclose(logits, want_logits, atol=1e-4, rtol=1e-4)
+    assert sorted(got) == sorted(want)
+    for name in want:
+        a, b = np.asarray(got[name]), np.asarray(want[name])
+        if a.dtype == np.int8:      # a rounding step apart at most
+            assert np.abs(a.astype(int) - b.astype(int)).max() <= 1, name
+        else:
+            np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5,
+                                       err_msg=name)

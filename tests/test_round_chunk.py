@@ -111,8 +111,10 @@ def test_the_round_is_the_admission_then_the_step(cfg, lanes, chunk):
 
 
 def test_chunk_rides_a_plain_decode_step_only():
-    """``chunk=`` is for one token a row of an untyped one-pass stack
-    taking the in-place body; anything else is refused by name."""
+    """``chunk=`` is for one token a row of an untyped one-pass stack;
+    anything else is refused by name.  An int8 cache takes it too: the
+    scale planes beside the K/V planes, the same logits and slab as the
+    admission then the step."""
     params = toy_params(PLAIN)
     cache, cur, pos, rows = _state(PLAIN, 2, 8)
     chunk = (rows, jnp.int32(1), jnp.int32(8))
@@ -123,9 +125,23 @@ def test_chunk_rides_a_plain_decode_step_only():
     with pytest.raises(ValueError, match="one token a row"):
         gen._decode_chunk(params, cache, jnp.stack([cur, cur], 1), pos,
                           PLAIN, chunk=chunk)
-    with pytest.raises(ValueError, match="do not compose"):
-        gen._decode_chunk(params, gen.init_cache(PLAIN, 2, kv_int8=True),
-                          cur[:, None], pos, PLAIN, chunk=chunk)
+    rng = np.random.default_rng(1)
+    cache = {name: jnp.asarray(
+        rng.integers(-127, 128, a.shape) if a.dtype == jnp.int8
+        else rng.uniform(0.005, 0.02, a.shape), a.dtype)
+        for name, a in gen.init_cache(PLAIN, 2, kv_int8=True).items()}
+    _, admitted = gen._decode_chunk(params, cache, rows, chunk[2][None],
+                                    PLAIN, uniform_pos=True, lane=chunk[1])
+    want, want_cache = gen._decode_chunk(params, admitted, cur[:, None], pos,
+                                         PLAIN)
+    got, got_cache = gen._decode_chunk(params, cache, cur[:, None], pos,
+                                       PLAIN, chunk=chunk)
+    np.testing.assert_allclose(got[0], want[0], atol=TOL, rtol=0)
+    want_cache, got_cache = (_but_the_parked_slot(c, PLAIN)
+                             for c in (want_cache, got_cache))
+    for name in want_cache:
+        np.testing.assert_allclose(got_cache[name], want_cache[name],
+                                   atol=TOL, rtol=0)
 
 
 # ------------------------------------------------- through an engine
@@ -250,6 +266,37 @@ def test_a_chunk_with_no_lane_decoding_runs_alone(tmp_path):
                    if r["name"] == "serving.round")
 
 
+def test_an_int8_kv_engine_fuses_and_matches_solo(tmp_path):
+    """An int8 K/V engine fuses a prompt's middle chunks into the
+    decode rounds as the plain one does (the scale planes take the
+    chunk's writes beside the K/V planes), and every transcript is its
+    solo ``generate(kv_int8=True)`` run's."""
+    params = toy_params(CFG)
+    rng = np.random.default_rng(11)
+    prompts = [_prompt(rng, 5), _prompt(rng, 1 + 3 * CHUNK + 3)]
+    path = str(tmp_path / "t.jsonl")
+    with obs.session(trace_path=path):
+        with pytest.warns(RuntimeWarning, match="kv_int8"):
+            eng = dk.ContinuousBatcher(params, CFG, kv_int8=True, lanes=2,
+                                       max_queue=4, prefill_chunk=CHUNK,
+                                       prompt_buckets=(4, CHUNK))
+        assert eng._round_chunk is not None
+        rids = [eng.enqueue(prompts[0], 12)]
+        eng.step()
+        rids.append(eng.enqueue(prompts[1], 3))
+        for _ in range(200):
+            if all(eng.poll(r) is not None for r in rids):
+                break
+            eng.step()
+    for rid, prompt, n in zip(rids, prompts, (12, 3)):
+        np.testing.assert_array_equal(
+            eng.take(rid).tokens, np.asarray(generate(
+                params, prompt[None], CFG, n, kv_int8=True,
+                use_prefill=False))[0])
+    assert any(r["fields"].get("fused") for r in read_trace(path)
+               if r["kind"] == "span" and r["name"] == "serving.round")
+
+
 # ------------------------------------------------- the engines that never fuse
 
 TYPED = tfm.TransformerConfig(
@@ -274,10 +321,6 @@ def _unfused(kind):
         roll = serve_cfg(max_len=48, attention_window=16)
         return dk.ContinuousBatcher(toy_params(roll), roll, lanes=2,
                                     max_queue=4, prompt_buckets=(8, 32)), 1
-    if kind == "int8_kv":
-        with pytest.warns(RuntimeWarning, match="kv_int8"):
-            return dk.ContinuousBatcher(toy_params(CFG), CFG, kv_int8=True,
-                                        **chunked), 1
     if kind == "paged":
         return dk.PagedBatcher(toy_params(CFG), CFG, lanes=2, block=8,
                                max_queue=4, prefill_chunk=CHUNK), 1
@@ -297,8 +340,8 @@ def _unfused(kind):
 
 
 @pytest.mark.parametrize("kind", [
-    "typed", "looped", "rolling", "int8_kv", "paged", "speculative",
-    "tiered", "window_of_2"])
+    "typed", "looped", "rolling", "paged", "speculative", "tiered",
+    "window_of_2"])
 def test_no_fused_program_is_built_or_launched(kind, tmp_path):
     """Everything but the plain one-pass stack on one untiered device,
     a step at a time, takes the two programs it took: the engine holds
